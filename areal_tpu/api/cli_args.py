@@ -389,9 +389,11 @@ class JaxDecodeConfig:
     # device→host copies, and a resume promotes them back — fresh device
     # blocks + async upload — instead of re-running prefill. Turns
     # kv_pool_tokens from a hard capacity wall into a working-set knob;
-    # resumed token/logprob streams are bit-identical to never-evicted
-    # ones (the restored bytes ARE the original KV, and the slot's
-    # sampling base key travels with the entry).
+    # resumed token streams are identical to never-evicted ones (the
+    # restored bytes ARE the original KV, and the slot's sampling base
+    # key travels with the entry); logprobs agree to float32 rounding —
+    # the resume runs through differently-shaped compiled programs,
+    # whose reductions XLA may order differently.
     kv_host_pool_mb: float = 0.0
     # How decode attention reaches the paged pool:
     #   "paged" (default): attend IN PLACE over the pool through the block
@@ -428,9 +430,11 @@ class JaxDecodeConfig:
     # chunk that scores all draft positions in one forward over the paged
     # pool and accepts the longest prefix matching what greedy/sampling
     # would have emitted, plus the model's own bonus token. Accepted
-    # streams and logprobs are bit-identical to spec_decode="off"
+    # token streams are identical to spec_decode="off"
     # (fold_in(base_key, position) sampling keys are a pure function of
-    # token index); rejected draft rows are dead KV overwritten by the
+    # token index) and logprobs agree to float32 rounding (the verify
+    # chunk is a different compiled program from the decode chunk);
+    # rejected draft rows are dead KV overwritten by the
     # next write. Strong on math/code rollouts that quote their prompts
     # (and on greedy repetition); draftless passes fall back to normal
     # chunks, so non-repetitive workloads keep baseline throughput.
@@ -473,9 +477,11 @@ class JaxDecodeConfig:
     # rid-exact resume path; (3) peer fetch — on a router hint, the
     # server pulls a sibling's matching block run over the /kv_recv +
     # /kv_commit migration wire instead of re-prefilling. Deduped and
-    # fetched streams are bit-identical to the re-prefill oracle (same
+    # fetched token streams are identical to the re-prefill oracle (same
     # tokens + same weights => same KV bytes; sampling keys are
-    # per-request, not per-block). False restores pre-fabric behavior.
+    # per-request, not per-block), logprobs to float32 rounding (a
+    # suffix prefill is a different compiled program from a full one).
+    # False restores pre-fabric behavior.
     kv_fabric: bool = True
     # cap on content keys published in the /metrics digest (newest-chain
     # first); bounds the health-poll payload, not the index itself

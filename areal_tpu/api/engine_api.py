@@ -162,6 +162,11 @@ class TrainEngine(abc.ABC):
         raise NotImplementedError()
 
 
+class EngineDeadError(RuntimeError):
+    """The engine's serving loop has died: no request can succeed until it
+    is re-initialised, so callers fail at once instead of retrying."""
+
+
 class InferenceEngine(abc.ABC):
     """Rollout/generation engine contract (parity: engine_api.py:347)."""
 

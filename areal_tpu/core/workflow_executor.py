@@ -19,6 +19,7 @@ from typing import TYPE_CHECKING, Any, Callable
 import numpy as np
 
 from areal_tpu.api.cli_args import InferenceEngineConfig
+from areal_tpu.api.engine_api import EngineDeadError
 from areal_tpu.core.async_task_runner import AsyncTaskRunner, TaskResult
 from areal_tpu.core.sample_ledger import SampleLedger, SampleWAL
 from areal_tpu.core.staleness_manager import StalenessManager
@@ -204,6 +205,10 @@ class WorkflowExecutor:
                 # sick engine — release the slot but don't feed the
                 # consecutive-failure escalation
                 return
+            if isinstance(tr.exception, EngineDeadError):
+                # every later episode would fail the same way: surface the
+                # engine's own exception now, whatever is still in flight
+                raise tr.exception
             # A systematic failure (e.g. crashed decode engine) must surface
             # instead of spinning forever resubmitting doomed episodes.
             self._consecutive_failures += 1

@@ -77,7 +77,7 @@ from areal_tpu.api.cli_args import (
     InferenceEngineConfig,
     JaxDecodeConfig,
 )
-from areal_tpu.api.engine_api import InferenceEngine
+from areal_tpu.api.engine_api import EngineDeadError, InferenceEngine
 from areal_tpu.core import kv_fabric
 from areal_tpu.api.io_struct import (
     FinetuneSpec,
@@ -683,12 +683,9 @@ class JaxDecodeEngine(InferenceEngine):
                 f"{cfg.max_position_embeddings})"
             )
         self._build_mesh()
-        if self._param_shardings is not None:
-            self.params = jax.tree.map(
-                lambda x, s: jax.device_put(x, s),
-                self.params,
-                self._param_shardings,
-            )
+        self.params = jax.tree.map(
+            jax.device_put, self.params, self._param_shardings
+        )
         R = self.config.max_running_requests
         S = self.config.context_length
         kv_dtype = jnp.dtype(self.config.kv_cache_dtype)
@@ -798,11 +795,12 @@ class JaxDecodeEngine(InferenceEngine):
             cfg.head_dim_,
         )
         pool_dtype = jnp.int8 if self._kv_quant else kv_dtype
-        self._k_cache = jnp.zeros(shape, pool_dtype)
-        self._v_cache = jnp.zeros(shape, pool_dtype)
-        if self._cache_sharding is not None:
-            self._k_cache = jax.device_put(self._k_cache, self._cache_sharding)
-            self._v_cache = jax.device_put(self._v_cache, self._cache_sharding)
+        self._k_cache = jax.device_put(
+            jnp.zeros(shape, pool_dtype), self._cache_sharding
+        )
+        self._v_cache = jax.device_put(
+            jnp.zeros(shape, pool_dtype), self._cache_sharding
+        )
         self._k_scale = self._v_scale = None
         if self._kv_quant:
             # per-(row, head) f32 scales, paged like the data pool; the
@@ -811,15 +809,12 @@ class JaxDecodeEngine(InferenceEngine):
             sshape = (
                 cfg.num_hidden_layers, n_blocks, cfg.num_key_value_heads, bs
             )
-            self._k_scale = jnp.zeros(sshape, jnp.float32)
-            self._v_scale = jnp.zeros(sshape, jnp.float32)
-            if self._scale_sharding is not None:
-                self._k_scale = jax.device_put(
-                    self._k_scale, self._scale_sharding
-                )
-                self._v_scale = jax.device_put(
-                    self._v_scale, self._scale_sharding
-                )
+            self._k_scale = jax.device_put(
+                jnp.zeros(sshape, jnp.float32), self._scale_sharding
+            )
+            self._v_scale = jax.device_put(
+                jnp.zeros(sshape, jnp.float32), self._scale_sharding
+            )
         self._slot_lengths = np.zeros(R, dtype=np.int32)
         self._slot_rope_delta = np.zeros(R, dtype=np.int32)
         self._slot_used_freq = np.zeros(R, dtype=bool)
@@ -1245,14 +1240,12 @@ class JaxDecodeEngine(InferenceEngine):
         (heads/mlp/vocab over tp); the KV cache shards its kv-head dim when
         tp divides it, else stays replicated (GQA models with few kv heads).
         Gen-side dp = independent server replicas, handled by the launcher.
+
+        tp == 1 is the same code over one device: params and pools are
+        COMMITTED to it, so a colocated trainer's wider sharding never
+        leaks into the decode programs and nothing is placed by default.
         """
         tp = max(int(self.config.tensor_parallel_size), 1)
-        if tp == 1:
-            self.mesh = None
-            self._param_shardings = None
-            self._cache_sharding = None
-            self._scale_sharding = None
-            return
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from areal_tpu.models.qwen2 import param_logical_axes
@@ -1265,6 +1258,10 @@ class JaxDecodeEngine(InferenceEngine):
         )
         self.mesh = mesh_lib.build_mesh(
             ParallelStrategy(tensor_parallel_size=tp), devices[:tp]
+        )
+        logger.info(
+            f"decode mesh: tp={tp} on {devices[:tp]} "
+            f"({len(devices)} device(s) visible to this process)"
         )
         rules = mesh_lib.default_rules(fsdp=False)
         if self.model_config.num_key_value_heads % tp != 0:
@@ -3275,6 +3272,7 @@ class JaxDecodeEngine(InferenceEngine):
             )
             self._inflight.clear()
             # fail all outstanding futures
+            e = self._dead_error()
             for i, s in enumerate(self._slots):
                 if s is not None and s.future is not None and not s.future.done():
                     s.loop.call_soon_threadsafe(s.future.set_exception, e)
@@ -3710,9 +3708,17 @@ class JaxDecodeEngine(InferenceEngine):
                 )
 
     # -- InferenceEngine surface ---------------------------------------
+    def _dead_error(self) -> EngineDeadError:
+        err = EngineDeadError(
+            f"decode scheduler died ({self._thread_exc!r}); the engine must "
+            "be re-initialized"
+        )
+        err.__cause__ = self._thread_exc
+        return err
+
     async def agenerate(self, req: ModelRequest) -> ModelResponse:
         if self._thread_exc is not None:
-            raise RuntimeError("decode engine crashed") from self._thread_exc
+            raise self._dead_error()
         if req.gconfig.stop and self.tokenizer is None:
             raise ValueError(
                 "gconfig.stop (stop strings) requires the engine to be "
@@ -3745,9 +3751,7 @@ class JaxDecodeEngine(InferenceEngine):
         # this, such a request would wait forever on a future nobody
         # resolves.
         if self._thread_exc is not None:
-            raise RuntimeError(
-                "decode scheduler is dead; engine must be re-initialized"
-            ) from self._thread_exc
+            raise self._dead_error()
         return await future
 
     async def aprefill(self, req: ModelRequest) -> ModelResponse:
@@ -3764,7 +3768,7 @@ class JaxDecodeEngine(InferenceEngine):
         prompts fork the first member's prefill instead of re-running it.
         """
         if self._thread_exc is not None:
-            raise RuntimeError("decode engine crashed") from self._thread_exc
+            raise self._dead_error()
         if req.image_data and self._vision_params is None:
             raise NotImplementedError(
                 "JaxDecodeEngine has no vision tower installed; call "
@@ -3784,9 +3788,7 @@ class JaxDecodeEngine(InferenceEngine):
         )
         self._request_q.put(item)
         if self._thread_exc is not None:
-            raise RuntimeError(
-                "decode scheduler is dead; engine must be re-initialized"
-            ) from self._thread_exc
+            raise self._dead_error()
         return await future
 
     def generate(self, req: ModelRequest, timeout: float | None = None) -> ModelResponse:
@@ -4852,10 +4854,11 @@ class JaxDecodeEngine(InferenceEngine):
         self.pause_generation()
         try:
             with self._weight_lock:
-                # copy — the trainer will donate these buffers next step;
-                # device_put also reshards from the trainer's (fsdp/tp)
-                # layout onto the decode mesh's layout. Trainer weights are
-                # UNREPEATED — re-apply the GQA kv-head repeat first.
+                # copy (may_alias=False) — the trainer will donate these
+                # buffers next step; device_put also reshards from the
+                # trainer's (fsdp/tp) layout onto the decode mesh's layout.
+                # Trainer weights are UNREPEATED — re-apply the GQA kv-head
+                # repeat first.
                 params = self._repeat_kv_tree(params)
                 if self._w_quant:
                     # colocated trainers hand over fp master weights —
@@ -4864,16 +4867,13 @@ class JaxDecodeEngine(InferenceEngine):
                     from areal_tpu.models.qwen2 import quantize_weights
 
                     params = quantize_weights(params)
-                if self._param_shardings is not None:
-                    self.params = jax.tree.map(
-                        lambda x, s: jax.device_put(jnp.asarray(x), s),
-                        params,
-                        self._param_shardings,
-                    )
-                else:
-                    self.params = jax.tree.map(
-                        lambda x: jnp.copy(jnp.asarray(x)), params
-                    )
+                self.params = jax.tree.map(
+                    lambda x, s: jax.device_put(
+                        jnp.asarray(x), s, may_alias=False
+                    ),
+                    params,
+                    self._param_shardings,
+                )
                 self._lora_base.clear()  # whole tree replaced
                 self._invalidate_parked()
                 if model_config is not None:
@@ -5077,14 +5077,11 @@ class JaxDecodeEngine(InferenceEngine):
                     from areal_tpu.models.qwen2 import quantize_weights
 
                     host = quantize_weights(host)
-                if self._param_shardings is not None:
-                    self.params = jax.tree.map(
-                        lambda x, s: jax.device_put(jnp.asarray(x), s),
-                        host,
-                        self._param_shardings,
-                    )
-                else:
-                    self.params = jax.tree.map(jnp.asarray, host)
+                self.params = jax.tree.map(
+                    lambda x, s: jax.device_put(jnp.asarray(x), s),
+                    host,
+                    self._param_shardings,
+                )
                 self._lora_base.clear()  # whole tree replaced
                 self._invalidate_parked()
         finally:

@@ -73,7 +73,7 @@ logger = logging.getLogger("jax_engine")
 
 def _memory_analysis_dict(compiled) -> dict:
     """Per-program XLA memory analysis (bytes); {} where the backend does
-    not expose one (CPU returns a stub on some jaxlib versions)."""
+    not expose one."""
     try:
         ma = compiled.memory_analysis()
     except Exception as e:  # pragma: no cover - backend-dependent
@@ -398,7 +398,8 @@ class JaxTrainEngine(TrainEngine):
             self.mesh = mesh_lib.build_mesh(parallel_strategy)
         mesh_lib.set_current_mesh(self.mesh)
         logger.info(
-            f"mesh built: {dict(zip(self.mesh.axis_names, self.mesh.devices.shape))}"
+            f"mesh built: {dict(zip(self.mesh.axis_names, self.mesh.devices.shape))} "
+            f"on {self.mesh.devices.flatten().tolist()}"
         )
 
     def initialize(
@@ -1673,29 +1674,34 @@ class JaxTrainEngine(TrainEngine):
             train_flops_per_token(self.model_config, avg_ctx) * n_tokens
         ) / step_time / 1e12
         tokens_per_sec_per_chip = n_tokens / step_time / n_chips
-        dev_kind = jax.devices()[0].device_kind
-        mfu = tflops * 1e12 / n_chips / peak_flops(dev_kind)
+        out = dict(
+            n_tokens=float(n_tokens),
+            train_batch_time=step_time,
+            tokens_per_sec_per_chip=tokens_per_sec_per_chip,
+            tflops_per_chip=tflops / n_chips,
+        )
+        dev = jax.devices()[0]
+        if dev.platform == "tpu":
+            # utilization only against a known chip's peak: a CPU run
+            # reports none, and an unknown TPU is an error in peak_flops
+            out["mfu"] = (
+                tflops * 1e12 / n_chips / peak_flops(dev.device_kind)
+            )
         # "throughput/n_tokens" (not bare "n_tokens"): algorithm engines
         # register n_tokens as a bool-mask *denominator* in the same scope.
         # A colocated critic engine prefixes its series so actor and critic
         # don't average into one stream on the shared default tracker.
         p = "critic/" if self.config.is_critic else ""
-        stats_tracker.scalar(
-            **{
-                f"{p}time_perf/train_batch": step_time,
-                f"{p}throughput/n_tokens": float(n_tokens),
-                f"{p}throughput/tokens_per_sec_per_chip": tokens_per_sec_per_chip,
-                f"{p}throughput/tflops_per_chip": tflops / n_chips,
-                f"{p}throughput/mfu": mfu,
-            }
-        )
-        return dict(
-            n_tokens=float(n_tokens),
-            train_batch_time=step_time,
-            tokens_per_sec_per_chip=tokens_per_sec_per_chip,
-            tflops_per_chip=tflops / n_chips,
-            mfu=mfu,
-        )
+        scalars = {
+            f"{p}time_perf/train_batch": step_time,
+            f"{p}throughput/n_tokens": float(n_tokens),
+            f"{p}throughput/tokens_per_sec_per_chip": tokens_per_sec_per_chip,
+            f"{p}throughput/tflops_per_chip": tflops / n_chips,
+        }
+        if "mfu" in out:
+            scalars[f"{p}throughput/mfu"] = out["mfu"]
+        stats_tracker.scalar(**scalars)
+        return out
 
     def eval_batch(
         self,

@@ -1308,7 +1308,11 @@ async def _serve(args: argparse.Namespace) -> None:
 
         mc = ModelConfig(
             **{
-                **_json.loads(args.scratch_model),
+                # JSON turns the dataclass's tuple fields into lists
+                **{
+                    k: tuple(v) if isinstance(v, list) else v
+                    for k, v in _json.loads(args.scratch_model).items()
+                },
                 "dtype": args.dtype,
                 "param_dtype": args.dtype,
             }
@@ -1485,7 +1489,7 @@ def main(argv: list[str] | None = None) -> None:
     p.add_argument(
         "--scratch-model",
         default="",
-        help="JSON ModelConfig dict: serve a from-scratch tiny model "
+        help="JSON ModelConfig dict: serve that geometry from a seed "
              "(offline smoke / launcher E2E) instead of loading --model-path",
     )
     # knob: launcher-only — boot-time compile hint, not a config mirror

@@ -7,10 +7,13 @@ RECOVER_TIME_INTERVAL up to `recover_retries` times.
 
 TPU translation: the "LLM server" is our decode server
 (areal_tpu.launcher.decode_server), accelerator allocation is by TPU chip
-visibility (TPU_VISIBLE_CHIPS / JAX_PLATFORMS) rather than
-CUDA_VISIBLE_DEVICES, and trainer ranks are JAX processes (AREAL_TPU
-process env + jax.distributed) rather than torchrun ranks. Discovery stays
-name_resolve: servers self-register under names.gen_servers.
+visibility (`plan_chips` / `chip_env`: every child of a decoupled
+allocation, the trainer included, owns a disjoint chip set, because a chip
+belongs to one process at a time) rather than CUDA_VISIBLE_DEVICES, and
+trainer ranks are JAX processes (AREAL_TPU process env + jax.distributed)
+rather than torchrun ranks. Discovery stays name_resolve: servers
+self-register under names.gen_servers. This process never initialises a
+JAX backend: a parent that holds the chips would starve its children.
 
 Usage (mirrors `python -m areal.launcher.local entry.py --config c.yaml`):
 
@@ -19,6 +22,8 @@ Usage (mirrors `python -m areal.launcher.local entry.py --config c.yaml`):
 
 from __future__ import annotations
 
+import dataclasses
+import json
 import os
 import sys
 import time
@@ -36,6 +41,71 @@ from areal_tpu.utils.network import find_free_ports, gethostip
 logger = logging.getLogger("local_launcher")
 
 RECOVER_TIME_INTERVAL = 10.0  # parity: local.py:58
+
+# libtpu's TPU_CHIPS_PER_PROCESS_BOUNDS (x,y,z of the chip grid a process
+# sees) for a process that owns n chips of one host. Measured on a v5e 2x2
+# host (PERF.md, PR 21): chips 2,3 are a row, "2,1,1"; "1,2,1" is refused.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "2,1,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def plan_chips(alloc: AllocationMode) -> dict[str, list[int]]:
+    """Chips of this host for every child of a decoupled allocation, by job
+    name: each server replica takes gen-tp chips, the trainer the train
+    world size. The sets are pairwise disjoint, and a set of n chips starts
+    at a multiple of n (a two-chip process must own a whole row of the
+    host's chip grid), which can leave a chip unused."""
+    gen_tp = alloc.gen.tp_size
+    sizes = {
+        f"decode_server_{i}": gen_tp
+        for i in range(alloc.gen.data_parallel_size)
+    }
+    sizes["trainer_0"] = alloc.train.world_size
+    plan, free = {}, 0
+    for name, n in sizes.items():
+        first = -(-free // n) * n
+        plan[name] = list(range(first, first + n))
+        free = first + n
+    return plan
+
+
+def chip_env(chips: list[int], port: int) -> dict[str, str]:
+    """Environment that confines one child's TPU runtime to `chips` as a
+    process of its own, with its own mesh controller on `port`. Visibility
+    alone is not enough: without the bounds and the port, the second child
+    to start fails on libtpu's multi-process lockfile."""
+    if len(chips) not in _CHIP_BOUNDS:
+        raise ValueError(
+            f"cannot give one process {len(chips)} chips {chips} of a host: "
+            f"supported counts are {sorted(_CHIP_BOUNDS)}"
+        )
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[len(chips)],
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+        "TPU_MESH_CONTROLLER_PORT": str(port),
+    }
+
+
+def scratch_model_arg(model_path: str) -> str | None:
+    """`--scratch-model` JSON for a model path that names a geometry but no
+    weights: the offline sentinels (the canonical tiny model) or a
+    directory holding a config.json and no checkpoint. None when the
+    server should load `model_path`."""
+    from areal_tpu.models.smoke import OFFLINE_SENTINELS, SMOKE_MODEL_DICT
+
+    if model_path in OFFLINE_SENTINELS:
+        return json.dumps(SMOKE_MODEL_DICT)
+    if os.path.exists(os.path.join(model_path, "config.json")) and not any(
+        os.path.exists(os.path.join(model_path, f))
+        for f in ("model.safetensors", "model.safetensors.index.json")
+    ):
+        from areal_tpu.models.qwen2 import ModelConfig
+
+        return json.dumps(
+            dataclasses.asdict(ModelConfig.from_hf_config(model_path))
+        )
+    return None
 
 
 class DecodeServerHandle:
@@ -322,6 +392,16 @@ def run_experiment(
                 else 0
             )
             gen_tp = alloc.gen.tp_size if alloc.gen is not None else 1
+            # a CPU run (JAX_PLATFORMS=cpu, which every child inherits) has
+            # no chips to split
+            on_cpu = os.environ.get("JAX_PLATFORMS", "").startswith("cpu")
+            chips = plan_chips(alloc) if n_servers and not on_cpu else {}
+            chip_envs = {
+                name: chip_env(c, port)
+                for (name, c), port in zip(
+                    chips.items(), find_free_ports(len(chips))
+                )
+            }
             # Disaggregated role fleet (launcher.prefill_replicas): the
             # first K replicas launch as prefill (compute-bound, stream KV
             # out), the rest as decode (memory-bound, import + resume).
@@ -334,17 +414,7 @@ def run_experiment(
                     f"least one decode replica (gen dp = {n_servers})"
                 )
             for i in range(n_servers):
-                env = {}
-                if n_servers > 1 or gen_tp > 1:
-                    # Partition the host's chips between server replicas so
-                    # replica i's jax.devices() sees only its tp chips
-                    # (gen dp = independent replicas; without this every
-                    # replica would claim devices[:tp]).
-                    chips = ",".join(
-                        str(c) for c in range(i * gen_tp, (i + 1) * gen_tp)
-                    )
-                    env["TPU_VISIBLE_CHIPS"] = chips
-                    env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+                env = dict(chip_envs.get(f"decode_server_{i}", {}))
                 extra = ["--tp-size", str(gen_tp)] if gen_tp > 1 else []
                 # forward the experiment's decode config — without these the
                 # server silently runs its DEFAULTS (32k context, 64 slots,
@@ -369,16 +439,12 @@ def run_experiment(
                         ]
                 elif getattr(dec, "role", "unified") != "unified":
                     extra += ["--role", dec.role]
-                from areal_tpu.models.smoke import OFFLINE_SENTINELS
-
-                if model_path in OFFLINE_SENTINELS:
-                    # offline smoke: serve the canonical from-scratch tiny
-                    # model so the DECOUPLED path runs with no HF access
-                    import json as _json
-
-                    from areal_tpu.models.smoke import SMOKE_MODEL_DICT
-
-                    extra += ["--scratch-model", _json.dumps(SMOKE_MODEL_DICT)]
+                scratch = scratch_model_arg(model_path)
+                if scratch is not None:
+                    # no checkpoint to load: serve the geometry from a
+                    # seed (the trainer pushes its weights at step 1), so
+                    # the DECOUPLED path runs with no HF access
+                    extra += ["--scratch-model", scratch]
                 env.update(nr_env)
                 launcher.submit_decode_server(
                     i,
@@ -388,7 +454,11 @@ def run_experiment(
                 )
             if n_servers:
                 launcher.wait_decode_servers(n_servers)
-            launcher.submit_trainers(entrypoint, n_procs=1, env=nr_env)
+            launcher.submit_trainers(
+                entrypoint,
+                n_procs=1,
+                env={**chip_envs.get("trainer_0", {}), **nr_env},
+            )
             launcher.wait()
             launcher.stop_all()  # trainers done: tear down decode servers
             return

@@ -45,13 +45,6 @@ from jax.experimental.pallas import tpu as pltpu
 PADDING_SEGMENT = -1
 _NEG_INF = -1e30
 
-# jax >= 0.7 renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernels load on the 0.4.x jaxlib this container ships.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
@@ -197,7 +190,7 @@ def _fwd_call(
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(
@@ -403,7 +396,7 @@ def _bwd_call(
         out_shape=jax.ShapeDtypeStruct((nH, Tq, hd), q3.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(seg_q2, seg_k2, qpos2, kpos2, q3, k3, v3, do, lse3, delta3, dlse3)
@@ -449,7 +442,7 @@ def _bwd_call(
             pltpu.VMEM((block_k, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(seg_q2, seg_k2, qpos2, kpos2, q3, k3, v3, do, lse3, delta3, dlse3)

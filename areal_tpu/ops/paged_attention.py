@@ -14,13 +14,16 @@ Two implementations behind one signature, selected like `attn_impl`:
 - `"pallas"` (TPU): a split-KV flash-decode kernel. The block table is a
   scalar-prefetch operand, so each grid step's BlockSpec index map reads
   `bt[r, b]` and DMAs exactly that pool block HBM→VMEM — attention reads
-  KV *through the table*, nothing is ever copied HBM→HBM. Online-softmax
-  partial (max, sum, acc) scratch carries across the `nb` block steps of
-  each (slot, kv-head) program.
-- `"xla"` (CPU / tests / fallback): gathers the `nb` blocks per step and
-  runs the exact einsum sequence of the workspace `decode_step`, so its
-  logits are BITWISE identical to the workspace layout — that is what
-  lets the engine keep `kv_layout="workspace"` as a numerics oracle.
+  KV *through the table*, nothing is ever copied HBM→HBM. One grid step
+  takes the block's whole contiguous `(bsz, nKV*hd)` slab (every kv head:
+  Mosaic cannot window a single head's `(1, hd)` column out of an
+  `(nKV, hd)` minor pair) and scores all query heads against it with
+  block-diagonal queries. Online-softmax partial (max, sum, acc) scratch
+  carries across the `nb` block steps of each slot.
+- `"xla"` (CPU / tests): gathers the `nb` blocks per step and runs the
+  exact einsum sequence of the workspace `decode_step`, so its logits are
+  BITWISE identical to the workspace layout — that is what lets the
+  engine keep `kv_layout="workspace"` as a numerics oracle.
 
 The per-token KV *write* is not this op's job: `decode_step_paged`
 (models/qwen2.py) writes the single (block, offset) row with a dynamic
@@ -28,13 +31,13 @@ scatter — O(1) per token where the workspace path's one-hot masked
 rewrite touched the whole [R, S] cache per layer per step.
 
 Int8 pools (ops/kv_quant.py): `k_pool`/`v_pool` may arrive as
-(int8 data, f32 scales) tuples. The Pallas kernels then DMA the scale
-block through the SAME block-table index map as the data block and
-dequantize right after the HBM→VMEM transfer — attention math runs in
-f32 exactly as for fp pools, only the bytes moved from HBM are halved.
-The XLA fallback dequantizes immediately after its gather, before the
-workspace-identical einsum sequence, so both impls score the same
-effective values.
+(int8 data, f32 scales) tuples. The Pallas kernel then DMAs the scale
+block through the SAME block-table index map as the data block; the
+per-(row, kv head) scale factors out of the head_dim contraction, so it
+multiplies the scores (K) and the probabilities (V) in f32 — only the
+bytes moved from HBM are halved. The XLA impl dequantizes immediately
+after its gather, before the workspace-identical einsum sequence, so both
+impls score the same effective values.
 """
 
 from __future__ import annotations
@@ -68,7 +71,7 @@ def _default_interpret() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# XLA fallback: gather-per-block, workspace-identical arithmetic
+# XLA impl: gather-per-block, workspace-identical arithmetic
 # ---------------------------------------------------------------------------
 
 
@@ -115,21 +118,31 @@ def _paged_attention_xla(q, k_pool, v_pool, block_table, valid, sm_scale):
 # ---------------------------------------------------------------------------
 
 
-def _paged_attn_kernel(
+def _paged_kernel(
     bt_ref,  # [R, nb] scalar-prefetch block table
-    mask_ref,  # (1, bsz) int32 validity rows for this block
-    q_ref,  # (1, 1, group, hd)
-    k_ref,  # (1, bsz, 1, hd) — THE pool block bt[r, b], DMA'd in place
-    v_ref,  # (1, bsz, 1, hd)
-    o_ref,  # (1, 1, group, hd)
-    acc_ref,  # VMEM (group, hd) f32
-    m_ref,  # VMEM (group, 1) f32
-    l_ref,  # VMEM (group, 1) f32
-    *,
+    mask_ref,  # (1, 1, W, bsz) int32 validity rows for this block, per query
+    q_ref,  # (1, W*nHp, D) block-diagonal queries, D = nKV*hd
+    *refs,  # [sel], k, [k scales], v, [v scales], out, acc, m, l
     sm_scale: float,
+    quant: bool,
 ):
-    b = pl.program_id(2)
-    nb = pl.num_programs(2)
+    """One grid step = one pool block of one slot, ALL kv heads: the block
+    is the contiguous (bsz, nKV*hd) slab the pool stores, so every tile is
+    lane-dense whatever the head count. Query row i carries head i's query
+    in its kv head's hd lanes and zeros elsewhere, so `q @ k.T` is exactly
+    the per-head score; `p @ v` is exact on the head's own lanes (the
+    caller reads only those). Int8 pools: the per-(row, kv head) scale
+    factors out of the hd contraction, so it multiplies the SCORES (K) and
+    the PROBS (V) — the int8 tile feeds the MXU straight after the DMA."""
+    if quant:
+        # sel: (nKV, W*nHp, 1) f32 one-hot, query row -> its kv head
+        sel_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    b = pl.program_id(1)
+    nb = pl.num_programs(1)
+    rows = q_ref.shape[1]
+    W, bsz = mask_ref.shape[2], mask_ref.shape[3]
 
     @pl.when(b == 0)
     def _init():
@@ -137,13 +150,26 @@ def _paged_attn_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # [group, hd]
-    k = k_ref[0, :, 0].astype(jnp.float32)  # [bsz, hd]
+    def head_rows(sc_ref):
+        # (1, nKV, bsz) block scales -> [rows, bsz]: row i gets its kv head's
+        sc = sc_ref[0]
+        return sum(
+            sel_ref[h] * sc[h : h + 1, :] for h in range(sc.shape[0])
+        )
+
+    q = q_ref[0].astype(jnp.float32)  # [rows, D]
+    k = k_ref[0].astype(jnp.float32)  # [bsz, D]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
     s = s * sm_scale
-    s = jnp.where(mask_ref[0][None, :] != 0, s, _NEG_INF)
+    if quant:
+        s = s * head_rows(ks_ref)
+    # per-query causal horizon: mask row w applies to that query's heads
+    m2 = jnp.broadcast_to(
+        mask_ref[0, 0][:, None, :], (W, rows // W, bsz)
+    ).reshape(rows, bsz)
+    s = jnp.where(m2 != 0, s, _NEG_INF)
 
     m_prev = m_ref[:]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -153,7 +179,9 @@ def _paged_attn_kernel(
     p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
     l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
     m_ref[:] = m_new
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    if quant:
+        p = p * head_rows(vs_ref)
+    v = v_ref[0].astype(jnp.float32)
     acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -162,303 +190,80 @@ def _paged_attn_kernel(
     def _finalize():
         l = l_ref[:]
         safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
 
 
-def _paged_attn_kernel_q8(
-    bt_ref,  # [R, nb] scalar-prefetch block table
-    mask_ref,  # (1, bsz) int32 validity rows for this block
-    q_ref,  # (1, 1, group, hd)
-    k_ref,  # (1, bsz, 1, hd) int8 — THE pool block bt[r, b], DMA'd in place
-    ks_ref,  # (1, 1, bsz) f32 — that block's K scales, same page walk
-    v_ref,  # (1, bsz, 1, hd) int8
-    vs_ref,  # (1, 1, bsz) f32
-    o_ref,  # (1, 1, group, hd)
-    acc_ref,  # VMEM (group, hd) f32
-    m_ref,  # VMEM (group, 1) f32
-    l_ref,  # VMEM (group, 1) f32
-    *,
-    sm_scale: float,
-):
-    """The split-KV kernel for int8 pools: identical online-softmax body,
-    but each grid step also DMAs the block's per-row scales (a bsz-float
-    strip — tiny next to the halved KV bytes) and dequantizes immediately
-    after the HBM→VMEM transfer. Attention math stays f32."""
-    b = pl.program_id(2)
-    nb = pl.num_programs(2)
-
-    @pl.when(b == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)  # [group, hd]
-    # dequantize right after the DMA: int8 rows x per-row scales
-    k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]  # [bsz, hd]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = s * sm_scale
-    s = jnp.where(mask_ref[0][None, :] != 0, s, _NEG_INF)
-
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[:] = m_new
-    v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-    @pl.when(b == nb - 1)
-    def _finalize():
-        l = l_ref[:]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-
-
-def _paged_attention_pallas(
-    q, k_pool, v_pool, block_table, valid, sm_scale, interpret
-):
+def _paged_pallas(q, k_pool, v_pool, block_table, valid, sm_scale, interpret):
+    """q [R, W, nH, hd], valid [R, W, nb*bsz] -> [R, W, nH, hd]. Decoding is
+    the W == 1 case of the speculative verify."""
     (k_pool, k_scales), (v_pool, v_scales) = split_pool(k_pool), split_pool(v_pool)
-    R, nH, hd = q.shape
-    bsz, nKV = k_pool.shape[1], k_pool.shape[2]
+    R, W, nH, hd = q.shape
+    n_blocks, bsz, nKV, _ = k_pool.shape
     nb = block_table.shape[1]
     group = nH // nKV
+    D = nKV * hd
     if not interpret and bsz % 128 != 0:
         raise ValueError(
             f"pallas paged attention needs page_size % 128 == 0 on TPU "
             f"(got {bsz}); use impl='xla' or a 128-multiple page size"
         )
-    qg = q.reshape(R, nKV, group, hd)
-    mask = valid.astype(jnp.int32)  # [R, nb*bsz]
     quant = k_scales is not None
+    # heads padded to the f32 sublane tile so every in-kernel reshape and
+    # matmul operand is tile-aligned (Qwen2.5-0.5B has 14 heads)
+    nHp = -(-nH // 8) * 8
+    rows = W * nHp
+    kv_of_head = np.minimum(np.arange(nHp) // group, nKV - 1)
+    onehot = np.zeros((nHp, nKV), np.float32)
+    onehot[np.arange(nH), kv_of_head[:nH]] = 1.0
+    qp = jnp.pad(q, ((0, 0), (0, 0), (0, nHp - nH), (0, 0)))
+    q_exp = (
+        qp[:, :, :, None, :] * jnp.asarray(onehot, q.dtype)[:, :, None]
+    ).reshape(R, rows, D)
+    mask = valid.astype(jnp.int32).reshape(R, W, nb, bsz).swapaxes(1, 2)
 
     # the index map IS the page walk: block b of slot r comes straight
     # from the pool row the table names (scale strips walk the same map)
-    kv_spec = pl.BlockSpec((1, bsz, 1, hd), lambda r, h, b, bt: (bt[r, b], 0, h, 0))
-    sc_spec = pl.BlockSpec((1, 1, bsz), lambda r, h, b, bt: (bt[r, b], h, 0))
+    kv_spec = pl.BlockSpec((1, bsz, D), lambda r, b, bt: (bt[r, b], 0, 0))
+    sc_spec = pl.BlockSpec((1, nKV, bsz), lambda r, b, bt: (bt[r, b], 0, 0))
     in_specs = [
-        pl.BlockSpec((1, bsz), lambda r, h, b, bt: (r, b)),
-        pl.BlockSpec((1, 1, group, hd), lambda r, h, b, bt: (r, h, 0, 0)),
+        pl.BlockSpec((1, 1, W, bsz), lambda r, b, bt: (r, b, 0, 0)),
+        pl.BlockSpec((1, rows, D), lambda r, b, bt: (r, 0, 0)),
     ]
+    k2 = k_pool.reshape(n_blocks, bsz, D)
+    v2 = v_pool.reshape(n_blocks, bsz, D)
     if quant:
-        kernel = functools.partial(_paged_attn_kernel_q8, sm_scale=sm_scale)
-        in_specs += [kv_spec, sc_spec, kv_spec, sc_spec]
-        operands = (qg, k_pool, k_scales, v_pool, v_scales)
+        sel = jnp.asarray(np.tile(onehot, (W, 1)).T[:, :, None])
+        in_specs += [
+            pl.BlockSpec((nKV, rows, 1), lambda r, b, bt: (0, 0, 0)),
+            kv_spec, sc_spec, kv_spec, sc_spec,
+        ]
+        operands = (sel, k2, k_scales, v2, v_scales)
     else:
-        kernel = functools.partial(_paged_attn_kernel, sm_scale=sm_scale)
         in_specs += [kv_spec, kv_spec]
-        operands = (qg, k_pool, v_pool)
+        operands = (k2, v2)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(R, nKV, nb),
+        grid=(R, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, group, hd), lambda r, h, b, bt: (r, h, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, rows, D), lambda r, b, bt: (r, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((group, hd), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((rows, D), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_paged_kernel, sm_scale=sm_scale, quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, nKV, group, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, rows, D), q.dtype),
         interpret=interpret,
-    )(block_table, mask, *operands)
-    return out.reshape(R, nH, hd)
-
-
-# ---------------------------------------------------------------------------
-# q_len > 1 (speculative verify): W query positions per slot, one pass
-# ---------------------------------------------------------------------------
-
-
-def _paged_verify_kernel(
-    bt_ref,  # [R, nb] scalar-prefetch block table
-    mask_ref,  # (1, W, bsz) int32 validity rows for this block, per query
-    q_ref,  # (1, 1, W, group, hd)
-    k_ref,  # (1, bsz, 1, hd) — THE pool block bt[r, b], DMA'd once for all W
-    v_ref,  # (1, bsz, 1, hd)
-    o_ref,  # (1, 1, W, group, hd)
-    acc_ref,  # VMEM (W*group, hd) f32
-    m_ref,  # VMEM (W*group, 1) f32
-    l_ref,  # VMEM (W*group, 1) f32
-    *,
-    sm_scale: float,
-):
-    """The W=1 split-KV kernel generalized to W query positions: each grid
-    step still DMAs exactly ONE pool block, but scores all W queries
-    against it — the block read is amortized W-fold versus running the
-    single-query kernel over W virtual slots."""
-    b = pl.program_id(2)
-    nb = pl.num_programs(2)
-    W, group, hd = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
-    bsz = k_ref.shape[1]
-
-    @pl.when(b == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32).reshape(W * group, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)  # [bsz, hd]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = s * sm_scale
-    # per-query causal horizon: mask row w applies to that query's `group`
-    # score rows
-    m2 = jnp.broadcast_to(
-        mask_ref[0][:, None, :], (W, group, bsz)
-    ).reshape(W * group, bsz)
-    s = jnp.where(m2 != 0, s, _NEG_INF)
-
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[:] = m_new
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-    @pl.when(b == nb - 1)
-    def _finalize():
-        l = l_ref[:]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[:] / safe_l).reshape(W, group, hd).astype(
-            o_ref.dtype
-        )
-
-
-def _paged_verify_kernel_q8(
-    bt_ref,  # [R, nb] scalar-prefetch block table
-    mask_ref,  # (1, W, bsz) int32 validity rows for this block, per query
-    q_ref,  # (1, 1, W, group, hd)
-    k_ref,  # (1, bsz, 1, hd) int8 — THE pool block, DMA'd once for all W
-    ks_ref,  # (1, 1, bsz) f32 — that block's K scales
-    v_ref,  # (1, bsz, 1, hd) int8
-    vs_ref,  # (1, 1, bsz) f32
-    o_ref,  # (1, 1, W, group, hd)
-    acc_ref,  # VMEM (W*group, hd) f32
-    m_ref,  # VMEM (W*group, 1) f32
-    l_ref,  # VMEM (W*group, 1) f32
-    *,
-    sm_scale: float,
-):
-    """Int8 twin of the multi-query verify kernel: one block DMA (data +
-    scale strip) serves all W queries, dequantized right after the
-    transfer — same amortization, half the KV bytes."""
-    b = pl.program_id(2)
-    nb = pl.num_programs(2)
-    W, group, hd = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
-    bsz = k_ref.shape[1]
-
-    @pl.when(b == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32).reshape(W * group, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]  # [bsz, hd]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = s * sm_scale
-    m2 = jnp.broadcast_to(
-        mask_ref[0][:, None, :], (W, group, bsz)
-    ).reshape(W * group, bsz)
-    s = jnp.where(m2 != 0, s, _NEG_INF)
-
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[:] = m_new
-    v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-    @pl.when(b == nb - 1)
-    def _finalize():
-        l = l_ref[:]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[:] / safe_l).reshape(W, group, hd).astype(
-            o_ref.dtype
-        )
-
-
-def _paged_verify_pallas(
-    q, k_pool, v_pool, block_table, valid, sm_scale, interpret
-):
-    (k_pool, k_scales), (v_pool, v_scales) = split_pool(k_pool), split_pool(v_pool)
-    R, W, nH, hd = q.shape
-    bsz, nKV = k_pool.shape[1], k_pool.shape[2]
-    nb = block_table.shape[1]
-    group = nH // nKV
-    if not interpret and bsz % 128 != 0:
-        raise ValueError(
-            f"pallas paged attention needs page_size % 128 == 0 on TPU "
-            f"(got {bsz}); use impl='xla' or a 128-multiple page size"
-        )
-    # [R, nKV, W, group, hd]: kv-head is a grid axis, (W, group) ride in
-    # the q block so one block DMA serves every query position
-    qg = q.reshape(R, W, nKV, group, hd).transpose(0, 2, 1, 3, 4)
-    mask = valid.astype(jnp.int32)  # [R, W, nb*bsz]
-    quant = k_scales is not None
-
-    kv_spec = pl.BlockSpec((1, bsz, 1, hd), lambda r, h, b, bt: (bt[r, b], 0, h, 0))
-    sc_spec = pl.BlockSpec((1, 1, bsz), lambda r, h, b, bt: (bt[r, b], h, 0))
-    in_specs = [
-        pl.BlockSpec((1, W, bsz), lambda r, h, b, bt: (r, 0, b)),
-        pl.BlockSpec(
-            (1, 1, W, group, hd), lambda r, h, b, bt: (r, h, 0, 0, 0)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
-    ]
-    if quant:
-        kernel = functools.partial(_paged_verify_kernel_q8, sm_scale=sm_scale)
-        in_specs += [kv_spec, sc_spec, kv_spec, sc_spec]
-        operands = (qg, k_pool, k_scales, v_pool, v_scales)
-    else:
-        kernel = functools.partial(_paged_verify_kernel, sm_scale=sm_scale)
-        in_specs += [kv_spec, kv_spec]
-        operands = (qg, k_pool, v_pool)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(R, nKV, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, W, group, hd), lambda r, h, b, bt: (r, h, 0, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((W * group, hd), jnp.float32),
-            pltpu.VMEM((W * group, 1), jnp.float32),
-            pltpu.VMEM((W * group, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, nKV, W, group, hd), q.dtype),
-        interpret=interpret,
-    )(block_table, mask, *operands)
-    return out.transpose(0, 2, 1, 3, 4).reshape(R, W, nH, hd)
+    )(block_table, mask, q_exp, *operands)
+    # each head keeps the hd lanes of its own kv head
+    out = out.reshape(R, W, nHp, nKV, hd)
+    return out[:, :, np.arange(nH), kv_of_head[:nH]]
 
 
 def paged_attention_qlen(
@@ -501,7 +306,7 @@ def paged_attention_qlen(
         kc = _gather_dequant(kd, ks, idx, R, nb, bsz, nKV, hd, q.dtype)
         vc = _gather_dequant(vd, vs, idx, R, nb, bsz, nKV, hd, q.dtype)
         return verify_attention(q, kc, vc, valid, sm_scale=sm_scale)
-    return _paged_verify_pallas(
+    return _paged_pallas(
         q, k_pool, v_pool, block_table, valid, sm_scale, interpret
     )
 
@@ -537,6 +342,7 @@ def paged_attention(
     impl = resolve_impl(impl)
     if impl == "xla":
         return _paged_attention_xla(q, k_pool, v_pool, block_table, valid, sm_scale)
-    return _paged_attention_pallas(
-        q, k_pool, v_pool, block_table, valid, sm_scale, interpret
-    )
+    return _paged_pallas(
+        q[:, None], k_pool, v_pool, block_table, valid[:, None], sm_scale,
+        interpret,
+    )[:, 0]

@@ -14,10 +14,13 @@ HBM. Two implementations behind one signature, selected like
   transfer (the `_paged_attn_kernel_q8` discipline: the fp weights exist
   only tile-at-a-time in VMEM), accumulating in an f32 VMEM scratch
   across the K grid axis.
-- `"xla"` (CPU / tests / fallback): dequantize-then-matmul with the same
-  f32 op sequence, globally instead of tile-at-a-time. Identical math up
-  to float reassociation from the K tiling; tests/test_weight_quant.py
-  pins the two against each other in interpret mode.
+- `"xla"` (CPU / tests): dequantize-then-matmul with the same f32 op
+  sequence, globally instead of tile-at-a-time. Identical math up to
+  float reassociation from the K tiling; tests/test_weight_quant.py pins
+  the two against each other in interpret mode.
+
+The Pallas kernel never gives way to the XLA one: a [K, N] it cannot tile
+raises, naming the shape.
 
 The contraction layout is the one every quantized call site in
 models/qwen2.py uses: the weight's CONTRACTION axes lead and the x
@@ -47,8 +50,7 @@ TILE_N = 128
 
 
 def quant_matmul_tiles_ok(k: int, n: int) -> bool:
-    """True when the Pallas kernel can tile this [K, N] weight; callers
-    fall back to XLA otherwise (auto does this silently)."""
+    """True when the Pallas kernel can tile this [K, N] weight."""
     return k % TILE_K == 0 and n % TILE_N == 0
 
 
@@ -148,7 +150,14 @@ def quant_einsum(
     x2 = x.reshape(math.prod(batch) if batch else 1, kk)
     q2 = w_q.reshape(kk, nn)
     s_row = w_scale.reshape(nn)
-    if impl == "pallas" and quant_matmul_tiles_ok(kk, nn):
+    if impl == "pallas":
+        if not quant_matmul_tiles_ok(kk, nn):
+            raise ValueError(
+                f"quant_einsum impl='pallas' cannot tile the [K={kk}, N={nn}]"
+                f" weight {tuple(w_q.shape)}: K and N must be multiples of "
+                f"{TILE_K} and {TILE_N}; serve this model with "
+                "weight_dtype='fp'"
+            )
         out2 = _quant_matmul_pallas(x2, q2, s_row, x.dtype, interpret)
     else:
         out2 = _quant_matmul_xla(x2, q2, s_row, x.dtype)

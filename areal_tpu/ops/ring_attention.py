@@ -226,9 +226,10 @@ def ring_flash_attention(
     )
     tok = P(axis_names)
     qkv_spec = P(axis_names, head_axis, None)
-    return mesh_lib.manual_shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, tok),
         out_specs=qkv_spec,
+        check_vma=False,
     )(q, k, v, segment_ids.astype(jnp.int32))

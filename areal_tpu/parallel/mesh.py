@@ -338,24 +338,3 @@ def constrain(
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, PartitionSpec(*fixed))
     )
-
-
-def manual_shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Fully-manual shard_map across jax API generations.
-
-    jax >= 0.6 exposes `jax.shard_map` (with `check_vma`); older releases
-    (this container ships 0.4.x) only have
-    `jax.experimental.shard_map.shard_map` (with `check_rep`). Both are the
-    same primitive for the fully-manual case ring attention needs — every
-    mesh axis manual, replication checking off.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )

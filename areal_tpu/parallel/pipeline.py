@@ -16,10 +16,8 @@ program over *stage-stacked* arrays:
   handling dp/sp/tp automatically inside each stage),
 - activations hop stage→stage with `jnp.roll(y, 1, axis=0)` — a static
   rotation XLA lowers to the same neighbour collective-permute a manual
-  ppermute would emit. (An earlier revision used a partial-manual
-  `shard_map` with explicit ppermutes; the stage-stacked form is
-  numerically identical, and — unlike partial-auto shard_map — also
-  compiles on the 0.4.x jax this repo must still run on.)
+  ppermute would emit, with dp/sp/tp inside each stage left to the
+  partitioner instead of a partial-manual `shard_map`.
 
 Three schedules:
 

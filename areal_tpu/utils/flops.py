@@ -40,7 +40,10 @@ def peak_flops(device_kind: str) -> float:
     for sub, f in PEAK_FLOPS:
         if sub in kind:
             return f
-    return 100e12  # unknown accelerator / CPU: nominal figure
+    raise ValueError(
+        f"no peak FLOP/s known for device kind {device_kind!r}; add it to "
+        "PEAK_FLOPS with its source"
+    )
 
 
 def forward_flops_per_token(model_cfg, avg_context: float) -> float:

@@ -72,7 +72,10 @@ def hbm_bytes(device_kind: str) -> int:
     for sub, b in HBM_BYTES:
         if sub in kind:
             return b
-    return 16 * GiB  # conservative default
+    raise ValueError(
+        f"no HBM capacity known for device kind {device_kind!r}; add it to "
+        "HBM_BYTES"
+    )
 
 
 def _dtype_bytes(dtype) -> int:

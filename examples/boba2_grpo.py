@@ -38,10 +38,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from areal_tpu.platforms import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 # Known 7B-class tensor geometries, keyed by the tail of the model path.
 # The plan check must work on machines with no checkpoint and no network
 # (ModelConfig.from_hf_config needs local files), so the recipe carries the

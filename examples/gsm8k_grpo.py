@@ -9,8 +9,14 @@ local launcher (DECOUPLED — `allocation_mode: "jax:d1t1+d1"` style).
 
 Usage:
 
-  # fully offline smoke (CPU or one chip; synthetic arithmetic dataset):
-  python examples/gsm8k_grpo.py --config examples/configs/arith_grpo_smoke.yaml
+  # fully offline smoke on the CPU (tiny model, synthetic arithmetic dataset):
+  JAX_PLATFORMS=cpu python examples/gsm8k_grpo.py \
+      --config examples/configs/arith_grpo_smoke.yaml
+
+  # the same loop at Qwen2.5-0.5B width on one TPU chip, weights from a seed
+  # (what chip_smoke.py runs):
+  python examples/gsm8k_grpo.py \
+      --config examples/configs/qwen2.5_0.5b_grpo_smoke.yaml
 
   # single-host TPU, colocated decode + train, Qwen2.5-0.5B on GSM8K:
   python examples/gsm8k_grpo.py --config examples/configs/gsm8k_grpo.yaml
@@ -26,11 +32,9 @@ Override any config field with key=value, e.g. `actor.optimizer.lr=1e-5`.
 import os
 import sys
 
+import numpy as np
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from areal_tpu.platforms import honor_jax_platforms_env
-
-honor_jax_platforms_env()  # make JAX_PLATFORMS=cpu smoke runs stay on CPU
 
 from areal_tpu.api.alloc_mode import AllocationMode, AllocationType
 from areal_tpu.api.cli_args import GRPOConfig, load_expr_config, save_config
@@ -129,7 +133,12 @@ def build_rollout(config: GRPOConfig, alloc: AllocationMode, actor, tokenizer):
     return rollout, WeightUpdateMeta.from_memory(alloc)
 
 
-def main(args):
+def main(args, before_teardown=None):
+    """Run the loop; returns each step's list of per-minibatch stats.
+
+    `before_teardown(actor, rollout)`, if given, is called after the last
+    step while both engines are still live (chip_smoke.py reads weight
+    versions, shardings and the loaded programs there)."""
     config, _ = load_expr_config(args, GRPOConfig)
     config: GRPOConfig
 
@@ -305,6 +314,7 @@ def main(args):
         config.total_train_epochs * steps_per_epoch
     )
 
+    history = []
     for global_step in range(start_step, max_steps):
         epoch = global_step // steps_per_epoch
         step = global_step % steps_per_epoch
@@ -324,6 +334,15 @@ def main(args):
                 batch = rollout.rollout_batch(
                     next(iter(train_dataloader)), workflow=workflow
                 )
+        # weight versions the batch's generated tokens were sampled under
+        # (the staleness the gate admitted; current version = global_step)
+        gen_versions = np.asarray(batch["versions"])[
+            np.asarray(batch["loss_mask"]) > 0
+        ]
+        stats_tracker.scalar(
+            rollout_version_min=float(gen_versions.min()),
+            rollout_version_max=float(gen_versions.max()),
+        )
 
         if config.actor.recompute_logprob or config.actor.use_decoupled_loss:
             with stats_tracker.record_timing("recompute_logp"):
@@ -376,13 +395,17 @@ def main(args):
 
         stats[0].update(stats_tracker.export_all())
         stats_logger.commit(epoch, step, global_step, stats)
+        history.append(stats)
         rollout.resume()
 
+    if before_teardown is not None:
+        before_teardown(actor, rollout)
     stats_logger.close()
     rollout.destroy()
     if ref is not None:
         ref.destroy()
     actor.destroy()
+    return history
 
 
 if __name__ == "__main__":

@@ -18,10 +18,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from areal_tpu.platforms import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 from areal_tpu.api.alloc_mode import AllocationMode
 from areal_tpu.api.cli_args import PPOConfig, load_expr_config, save_config
 from areal_tpu.api.io_struct import FinetuneSpec, StepInfo

@@ -18,10 +18,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from areal_tpu.platforms import honor_jax_platforms_env
-
-honor_jax_platforms_env()  # make JAX_PLATFORMS=cpu smoke runs stay on CPU
-
 import numpy as np
 
 from areal_tpu.api.alloc_mode import AllocationMode

@@ -19,10 +19,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from areal_tpu.platforms import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 import numpy as np
 
 from areal_tpu.api.alloc_mode import AllocationMode

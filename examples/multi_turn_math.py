@@ -28,10 +28,6 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 
-from areal_tpu.platforms import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 from gsm8k_grpo import main as grpo_main
 
 

@@ -182,6 +182,17 @@ def test_device_kind_spellings():
     assert peak_flops("TPU v5") == 459e12
 
 
+def test_unknown_device_kind_is_an_error():
+    """No made-up capacity or peak for a device the tables do not list."""
+    from areal_tpu.utils.flops import peak_flops
+
+    for kind in ("cpu", "TPU v9 imaginary"):
+        with pytest.raises(ValueError, match="device kind"):
+            hbm.hbm_bytes(kind)
+        with pytest.raises(ValueError, match="device kind"):
+            peak_flops(kind)
+
+
 def test_decode_paged_pool_vs_dense():
     """The paged pool's reservation is the knob: 64 slots x 32k dense
     reserves ~2M KV rows; a 256k-token pool is 8x smaller, and the

@@ -8,11 +8,13 @@ devices) at the commit that introduced this test; a legitimate numerical
 change (e.g. a different reduction order) must regenerate them
 consciously, not silently.
 
-Goldens regenerated 2026-08 for the current container: the original values
-came from a different jax/XLA build whose CPU reduction orders differ
-(~5% loss drift at this toy scale). The train path itself was cleared
-first — the repo's seed commit and HEAD produce bit-identical losses in
-this container, so the drift is environmental, not a code regression.
+The values depend on the installed JAX: the parameter init draws from its
+random stream (`jax_threefry_partitionable` changed that stream between
+releases) and XLA:CPU picks the reduction orders. The golden file names the
+versions that wrote it; after an upgrade, re-record it on purpose with
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=. python tests/test_sft_golden.py
 """
 
 import json
@@ -34,7 +36,7 @@ from areal_tpu.utils.data import pad_sequences_to_tensors
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden_sft_losses.json")
 
 
-def test_sft_losses_match_golden(cpu_devices):
+def _sft_losses() -> list[float]:
     cfg = TrainEngineConfig(
         experiment_name="golden",
         trial_name="t",
@@ -78,6 +80,33 @@ def test_sft_losses_match_golden(cpu_devices):
             float(eng.train_lm(pad_sequences_to_tensors(seqs))["loss"])
         )
     eng.destroy()
+    return losses
+
+
+def test_sft_losses_match_golden(cpu_devices):
     with open(GOLDEN) as f:
         golden = json.load(f)
-    np.testing.assert_allclose(losses, golden, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(
+        _sft_losses(), golden["losses"], rtol=1e-4, atol=1e-5,
+        err_msg=f"golden written by {golden['written_by']}",
+    )
+
+
+if __name__ == "__main__":
+    import jax
+    import jaxlib
+
+    with open(GOLDEN, "w") as f:
+        json.dump(
+            {
+                "written_by": {
+                    "jax": jax.__version__,
+                    "jaxlib": jaxlib.__version__,
+                    "numpy": np.__version__,
+                },
+                "losses": _sft_losses(),
+            },
+            f,
+            indent=1,
+        )
+        f.write("\n")

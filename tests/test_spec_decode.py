@@ -17,6 +17,8 @@ import time
 import numpy as np
 import pytest
 
+from _numerics import assert_logprobs_close
+
 import jax
 
 from areal_tpu.api.cli_args import (
@@ -159,7 +161,7 @@ def test_greedy_bit_identical_spec(cpu_devices):
     off, on, m = _gather_spec_pair(make_reqs)
     for i, (a, b) in enumerate(zip(off, on)):
         assert a.output_tokens == b.output_tokens, i
-        assert a.output_logprobs == b.output_logprobs, i
+        assert_logprobs_close(a.output_logprobs, b.output_logprobs, i)
         assert a.stop_reason == b.stop_reason, i
     # the spec engine really dispatched verify chunks and drafted tokens
     assert m["spec_chunks_total"] > 0
@@ -198,7 +200,7 @@ def test_greedy_bit_identical_spec_suffix_prefill(cpu_devices):
     on, m = run("ngram")
     for i, (a, b) in enumerate(zip(off, on)):
         assert a.output_tokens == b.output_tokens, i
-        assert a.output_logprobs == b.output_logprobs, i
+        assert_logprobs_close(a.output_logprobs, b.output_logprobs, i)
     assert m["suffix_prefills_total"] >= 1, m
 
 
@@ -228,7 +230,7 @@ def test_sampled_bit_identical_spec(cpu_devices):
     off, on, m = _gather_spec_pair(make_reqs)
     for i, (a, b) in enumerate(zip(off, on)):
         assert a.output_tokens == b.output_tokens, i
-        assert a.output_logprobs == b.output_logprobs, i
+        assert_logprobs_close(a.output_logprobs, b.output_logprobs, i)
     assert m["spec_chunks_total"] > 0
 
 
@@ -406,7 +408,7 @@ def test_layout_parity_with_spec(cpu_devices):
     pg = run("paged")
     for i, (a, b) in enumerate(zip(ws, pg)):
         assert a.output_tokens == b.output_tokens, i
-        assert a.output_logprobs == b.output_logprobs, i
+        assert_logprobs_close(a.output_logprobs, b.output_logprobs, i)
 
 
 def test_paged_verify_op_pallas_matches_xla(cpu_devices):

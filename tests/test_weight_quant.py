@@ -350,16 +350,26 @@ def test_fp_streams_bit_identical_to_golden(cpu_devices, kv_layout):
         with open(GOLDEN) as f:
             golden = json.load(f)
     if os.environ.get("AREAL_WRITE_GOLDEN"):
+        import jaxlib
+
         golden[kv_layout] = got
+        # the streams follow the installed JAX's random stream and XLA:CPU
+        # codegen: the file says which installation it pins
+        golden["written_by"] = {
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            "numpy": np.__version__,
+        }
         with open(GOLDEN, "w") as f:
             json.dump(golden, f, indent=1, sort_keys=True)
         pytest.skip("golden regenerated")
     assert kv_layout in golden, f"golden missing; regen {GOLDEN}"
+    who = f"golden written by {golden.get('written_by')}"
     for gname in ("greedy", "sampled"):
-        assert got[gname]["tokens"] == golden[kv_layout][gname]["tokens"]
+        assert got[gname]["tokens"] == golden[kv_layout][gname]["tokens"], who
         assert (
             got[gname]["logprobs"] == golden[kv_layout][gname]["logprobs"]
-        ), gname
+        ), (gname, who)
 
 
 # -- 5. serving + push invariants ---------------------------------------
@@ -494,33 +504,35 @@ def test_int8_drift_vs_fp_oracle_bounded_and_deterministic(
     property of the scheme, not noise). Random weights are the worst
     case for argmax flips, so the bound is on logprobs, not tokens."""
     g = _GREEDY if gname == "greedy" else _SAMPLED
-    prompt = _prompt(40, seed=19)
+    # several prompts: with random weights the very first sampled token
+    # can already differ between fp and int8, which leaves one prompt
+    # nothing to measure the drift on
+    prompts = [_prompt(40, seed=s) for s in (19, 20, 21)]
 
     def run(dt):
         e = _engine(weight_dtype=dt)
         try:
-            return _stream(e, g, prompt)
+            return [_stream(e, g, p) for p in prompts]
         finally:
             e.destroy()
 
-    fp_t, fp_l = run("fp")
-    i8_t, i8_l = run("int8")
-    i8_t2, i8_l2 = run("int8")
-    assert i8_t == i8_t2 and i8_l == i8_l2
+    fp, i8, i8_again = run("fp"), run("int8"), run("int8")
+    assert i8 == i8_again
 
-    matched = 0
-    for a, b in zip(fp_t, i8_t):
-        if a != b:
-            break
-        matched += 1
-    assert matched >= 1
-    deltas = [abs(a - b) for a, b in zip(fp_l[:matched], i8_l[:matched])]
+    deltas = []
+    for (fp_t, fp_l), (i8_t, i8_l) in zip(fp, i8):
+        matched = 0
+        for a, b in zip(fp_t, i8_t):
+            if a != b:
+                break
+            matched += 1
+        deltas += [abs(a - b) for a, b in zip(fp_l[:matched], i8_l[:matched])]
     # measured drift, pinned: per-channel int8 weights on this tiny f32
     # model stay well under 0.25 logprob on the matched prefix (seen
     # ~0.03 typical); a scheme regression (wrong contraction axis,
     # double quantization, scale downcast) blows far past this
-    if deltas:
-        assert max(deltas) < 0.25, (matched, deltas)
+    assert deltas, "no token-matched prefix on any prompt"
+    assert max(deltas) < 0.25, deltas
 
 
 # -- 6. LoRA on a quantized base ----------------------------------------

@@ -191,6 +191,23 @@ def test_failure_streak_escalates_but_releases_slots(executor):
     assert stats.running == unprocessed + executor.runner.inflight
 
 
+def test_dead_engine_fails_wait_at_once(executor):
+    """One episode failing with EngineDeadError (the decode scheduler died)
+    ends wait() with that error — not a 16-failure streak that a small run
+    never reaches, and not the 3600 s default timeout."""
+    from areal_tpu.api.engine_api import EngineDeadError
+
+    class DeadWorkflow(RolloutWorkflow):
+        async def arun_episode(self, engine, data):
+            raise EngineDeadError("decode scheduler died (lowering error)")
+
+    executor.submit({"value": 0}, workflow=DeadWorkflow())
+    t0 = time.monotonic()
+    with pytest.raises(EngineDeadError, match="lowering error"):
+        executor.wait(1)
+    assert time.monotonic() - t0 < 10
+
+
 def test_cancelled_episode_not_counted_as_failure():
     """A drained (cancelled) episode releases its slot but must not feed
     the consecutive-failure escalation."""

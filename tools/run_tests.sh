@@ -21,7 +21,7 @@ run_slow() {
   run "$@"
 }
 
-# Fast gate (~3 min)
+# Fast gate (~10 min)
 run tests/ -m "not slow"
 
 # Slow batches, serial, grouped by resource profile (~12 min total).
