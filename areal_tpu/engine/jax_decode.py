@@ -686,6 +686,8 @@ class JaxDecodeEngine(InferenceEngine):
         self.params = jax.tree.map(
             jax.device_put, self.params, self._param_shardings
         )
+        if self._vision_params is not None:
+            self._place_vision_params()
         R = self.config.max_running_requests
         S = self.config.context_length
         kv_dtype = jnp.dtype(self.config.kv_cache_dtype)
@@ -952,6 +954,22 @@ class JaxDecodeEngine(InferenceEngine):
             f"vision tower loaded: depth={vcfg.depth} embed={vcfg.embed_dim}"
         )
 
+    def _place_vision_params(self) -> None:
+        """Commit the tower to the decode mesh, sharded like the decoder
+        (heads/mlp over tp)."""
+        from areal_tpu.models.qwen2_vl import vision_param_logical_axes
+        from areal_tpu.parallel import mesh as mesh_lib
+
+        rules = mesh_lib.default_rules(fsdp=False)
+        self._vision_params = jax.tree.map(
+            lambda x, a: jax.device_put(
+                x, mesh_lib.named_sharding(self.mesh, a, rules)
+            ),
+            self._vision_params,
+            vision_param_logical_axes(self._vision_config),
+            is_leaf=lambda x: isinstance(x, tuple),
+        )
+
     def set_vision_model(
         self,
         vision_params,
@@ -964,24 +982,11 @@ class JaxDecodeEngine(InferenceEngine):
         preprocessed patch dicts in the HF AutoProcessor's output format:
         {"pixel_values": [N, patch_dim] WINDOW-MAJOR rows,
         "image_grid_thw": [n, 3]}. `mrope_sections` enables Qwen2-VL m-rope
-        position assignment (rope_scaling.mrope_section)."""
-        params = jax.tree.map(lambda x: jnp.asarray(x), vision_params)
-        if self.mesh is not None:
-            # shard the tower like the decoder (heads/mlp over tp)
-            from areal_tpu.models.qwen2_vl import vision_param_logical_axes
-            from areal_tpu.parallel import mesh as mesh_lib
+        position assignment (rope_scaling.mrope_section).
 
-            rules = mesh_lib.default_rules(fsdp=False)
-            axes = vision_param_logical_axes(vision_config)
-            params = jax.tree.map(
-                lambda x, a: jax.device_put(
-                    x, mesh_lib.named_sharding(self.mesh, a, rules)
-                ),
-                params,
-                axes,
-                is_leaf=lambda x: isinstance(x, tuple),
-            )
-        self._vision_params = params
+        Like `set_model`, call it before `initialize()`, which places the
+        tower on the decode mesh."""
+        self._vision_params = jax.tree.map(jnp.asarray, vision_params)
         self._vision_config = vision_config
         self._image_token_id = int(image_token_id)
         self._mrope_sections = (

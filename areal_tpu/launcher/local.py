@@ -25,10 +25,16 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import subprocess
 import sys
 import time
 
 from areal_tpu.api.alloc_mode import AllocationMode, AllocationType
+from areal_tpu.api.cli_args import (
+    BaseExperimentConfig,
+    TrainEngineConfig,
+    load_expr_config,
+)
 from areal_tpu.launcher.base import (
     JobFailure,
     JobInfo,
@@ -43,17 +49,24 @@ logger = logging.getLogger("local_launcher")
 RECOVER_TIME_INTERVAL = 10.0  # parity: local.py:58
 
 # libtpu's TPU_CHIPS_PER_PROCESS_BOUNDS (x,y,z of the chip grid a process
-# sees) for a process that owns n chips of one host. Measured on a v5e 2x2
-# host (PERF.md, PR 21): chips 2,3 are a row, "2,1,1"; "1,2,1" is refused.
-_CHIP_BOUNDS = {1: "1,1,1", 2: "2,1,1", 4: "2,2,1", 8: "2,4,1"}
+# sees) for a process that owns n chips of a 2x2 host. Which way a pair of
+# chips lies differs from host to host: chips 2,3 were an x-pair on one v5e
+# host and a y-pair on the next, and the runtime exits 1 on the wrong one
+# (PERF.md, PR 21). So `chip_env` tries a pair both ways in a throwaway
+# child. No larger host was probed: any other count is refused.
+_CHIP_BOUNDS = {1: ("1,1,1",), 2: ("2,1,1", "1,2,1"), 4: ("2,2,1",)}
+# every probe on the chip answered, ok or refused, within 12-21 s; a child
+# still silent after this is hung, and subprocess.run raises
+_PROBE_TIMEOUT_S = 120
 
 
 def plan_chips(alloc: AllocationMode) -> dict[str, list[int]]:
     """Chips of this host for every child of a decoupled allocation, by job
     name: each server replica takes gen-tp chips, the trainer the train
     world size. The sets are pairwise disjoint, and a set of n chips starts
-    at a multiple of n (a two-chip process must own a whole row of the
-    host's chip grid), which can leave a chip unused."""
+    at a multiple of n (a two-chip process must own two neighbours of the
+    host's chip grid: chips 2k and 2k+1 were neighbours on every host
+    probed), which can leave a chip unused."""
     gen_tp = alloc.gen.tp_size
     sizes = {
         f"decode_server_{i}": gen_tp
@@ -68,38 +81,62 @@ def plan_chips(alloc: AllocationMode) -> dict[str, list[int]]:
     return plan
 
 
-def chip_env(chips: list[int], port: int) -> dict[str, str]:
-    """Environment that confines one child's TPU runtime to `chips` as a
-    process of its own, with its own mesh controller on `port`. Visibility
-    alone is not enough: without the bounds and the port, the second child
-    to start fails on libtpu's multi-process lockfile."""
-    if len(chips) not in _CHIP_BOUNDS:
-        raise ValueError(
-            f"cannot give one process {len(chips)} chips {chips} of a host: "
-            f"supported counts are {sorted(_CHIP_BOUNDS)}"
-        )
+def _chip_env(chips: list[int], port: int, bounds: str) -> dict[str, str]:
     return {
         "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips),
-        "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[len(chips)],
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
         "TPU_PROCESS_BOUNDS": "1,1,1",
         "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
         "TPU_MESH_CONTROLLER_PORT": str(port),
     }
 
 
-def scratch_model_arg(model_path: str) -> str | None:
-    """`--scratch-model` JSON for a model path that names a geometry but no
-    weights: the offline sentinels (the canonical tiny model) or a
-    directory holding a config.json and no checkpoint. None when the
-    server should load `model_path`."""
+def chip_env(chips: list[int], port: int) -> dict[str, str]:
+    """Environment that confines one child's TPU runtime to `chips` as a
+    process of its own, with its own mesh controller on `port`. Visibility
+    alone is not enough: without the bounds and the port, the second child
+    to start fails on libtpu's multi-process lockfile. Where the bounds
+    depend on the host, each candidate is tried in a child that starts the
+    runtime and exits, before the real child is given the chips."""
+    candidates = _CHIP_BOUNDS.get(len(chips))
+    if candidates is None:
+        raise ValueError(
+            f"cannot give one process {len(chips)} chips {chips} of a host: "
+            f"supported counts are {sorted(_CHIP_BOUNDS)}"
+        )
+    if len(candidates) == 1:
+        return _chip_env(chips, port, candidates[0])
+    for bounds in candidates:
+        env = _chip_env(chips, port, bounds)
+        probe = subprocess.run(
+            [sys.executable, "-c", "import jax; jax.devices()"],
+            env={**os.environ, **env},
+            capture_output=True,
+            timeout=_PROBE_TIMEOUT_S,
+        )
+        logger.info(
+            f"chips {chips} as one process, bounds {bounds}: "
+            f"{'ok' if probe.returncode == 0 else 'refused'}"
+        )
+        if probe.returncode == 0:
+            return env
+    raise RuntimeError(
+        f"the TPU runtime gave chips {chips} to one process under none of the "
+        f"bounds {candidates}; its own log is under /tmp/tpu_logs"
+    )
+
+
+def scratch_model_arg(model_path: str, init_from_scratch: bool) -> str | None:
+    """`--scratch-model` JSON when the servers are to build their weights
+    from a seed: for the offline sentinels (the canonical tiny model), and
+    for a directory's config.json only when the experiment says
+    `actor.init_from_scratch`. None otherwise: the server loads
+    `model_path`, and a directory that holds no weights fails there."""
     from areal_tpu.models.smoke import OFFLINE_SENTINELS, SMOKE_MODEL_DICT
 
     if model_path in OFFLINE_SENTINELS:
         return json.dumps(SMOKE_MODEL_DICT)
-    if os.path.exists(os.path.join(model_path, "config.json")) and not any(
-        os.path.exists(os.path.join(model_path, f))
-        for f in ("model.safetensors", "model.safetensors.index.json")
-    ):
+    if init_from_scratch:
         from areal_tpu.models.qwen2 import ModelConfig
 
         return json.dumps(
@@ -374,6 +411,7 @@ def run_experiment(
         config.experiment_name, config.trial_name, config.cluster.fileroot
     )
     model_path = getattr(config.decode, "model_path", "") or config.tokenizer_path
+    init_from_scratch = config.actor.init_from_scratch
     attempt = 0
     while True:
         try:
@@ -439,7 +477,7 @@ def run_experiment(
                         ]
                 elif getattr(dec, "role", "unified") != "unified":
                     extra += ["--role", dec.role]
-                scratch = scratch_model_arg(model_path)
+                scratch = scratch_model_arg(model_path, init_from_scratch)
                 if scratch is not None:
                     # no checkpoint to load: serve the geometry from a
                     # seed (the trainer pushes its weights at step 1), so
@@ -477,20 +515,26 @@ def run_experiment(
             raise
 
 
+@dataclasses.dataclass
+class LauncherView(BaseExperimentConfig):
+    """What the launcher reads of an experiment config, whatever its class:
+    the base fields, and of the actor whether its weights come from a
+    checkpoint or a seed (the decode servers must start from the same).
+    The trainer subprocess re-parses the full subclass config."""
+
+    actor: TrainEngineConfig = dataclasses.field(
+        default_factory=TrainEngineConfig
+    )
+
+
 def main(argv: list[str] | None = None) -> None:
     """CLI: python -m areal_tpu.launcher.local entry.py --config cfg.yaml [k=v]"""
-    from areal_tpu.api.cli_args import BaseExperimentConfig, load_expr_config
-
     argv = list(sys.argv[1:] if argv is None else argv)
     assert argv and argv[0].endswith(".py"), (
         "usage: python -m areal_tpu.launcher.local entry.py --config cfg.yaml"
     )
     entry = argv[0]
-    # subset view: the launcher only consumes cluster/allocation/launcher
-    # fields; the trainer subprocess re-parses the full subclass config
-    config, _ = load_expr_config(
-        argv[1:], BaseExperimentConfig, ignore_unknown=True
-    )
+    config, _ = load_expr_config(argv[1:], LauncherView, ignore_unknown=True)
     max_restarts = (
         config.recover.retries
         if config.recover.mode in ("auto", "fault")

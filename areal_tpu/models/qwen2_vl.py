@@ -178,7 +178,7 @@ def vision_param_shapes(cfg: VisionConfig) -> dict:
 
 def vision_param_logical_axes(cfg: VisionConfig) -> dict:
     """Logical axes for the tower (same table as the decoder: heads/mlp
-    shard over tp). Applied by JaxDecodeEngine when a decode mesh exists."""
+    shard over tp). Applied by JaxDecodeEngine.initialize."""
 
     def walk(tree, path=()):
         if isinstance(tree, tuple):
@@ -196,7 +196,7 @@ def vision_param_logical_axes(cfg: VisionConfig) -> dict:
                 return (*prefix, "mlp", "embed")
             if name in ("fc1_bias", "gate_bias", "up_bias"):
                 return (*prefix, "mlp")
-            return (*prefix,) + (None,) * len(tree)
+            return (*prefix,) + (None,) * (len(tree) - len(prefix))
         return {k: walk(v, path + (k,)) for k, v in tree.items()}
 
     return walk(vision_param_shapes(cfg))

@@ -79,7 +79,7 @@ def loaded_program_text(client, module_name: str) -> str:
 
 
 def inspect_engines(actor, rollout, n_steps: int) -> None:
-    """Called by `main` after the last step with both engines still live."""
+    """Called at the end of the last step with both engines still live."""
     import jax
 
     from areal_tpu.models.qwen2 import resolve_attn_impl
@@ -183,6 +183,7 @@ def main() -> int:
     print("== loop phase (examples/gsm8k_grpo.py main) ==", flush=True)
     sys.path.insert(0, os.path.join(REPO, "examples"))
     import gsm8k_grpo
+    import numpy as np
 
     from areal_tpu.utils._native import load_datapack
 
@@ -193,8 +194,18 @@ def main() -> int:
 
     config, _ = load_expr_config(argv, GRPOConfig)
 
-    def before_teardown(actor, rollout):
-        inspect_engines(actor, rollout, config.total_train_steps)
+    # (oldest, newest) weight version each step's trained tokens were
+    # sampled under: the staleness the gate admitted
+    sampled_under = []
+
+    def after_step(global_step, batch, actor, rollout):
+        # prompt tokens carry version -1, padding has no attention
+        v = np.asarray(batch["versions"])
+        v = v[(np.asarray(batch["attention_mask"]) > 0) & (v >= 0)]
+        check(v.size > 0, f"step {global_step}: the batch holds generated tokens")
+        sampled_under.append((int(v.min()), int(v.max())))
+        if global_step == config.total_train_steps - 1:
+            inspect_engines(actor, rollout, config.total_train_steps)
 
     check(
         config.async_training and config.decode.page_size == 128
@@ -207,7 +218,7 @@ def main() -> int:
         ">= 4 samples, >= 128 new tokens, >= 3 steps",
     )
     t0 = time.monotonic()
-    history = gsm8k_grpo.main(argv, before_teardown=before_teardown)
+    history = gsm8k_grpo.main(argv, after_step=after_step)
     loop_s = time.monotonic() - t0
 
     check(len(history) == config.total_train_steps,
@@ -228,13 +239,13 @@ def main() -> int:
         check(head["grpo_actor/n_valid_tokens"] > 0,
               f"step {i}: trained on {int(head['grpo_actor/n_valid_tokens'])} "
               f"generated tokens, sampled under weight versions "
-              f"{int(head['rollout_version_min'])}..{int(head['rollout_version_max'])}")
+              f"{sampled_under[i][0]}..{sampled_under[i][1]}")
         phases = {k[len("timeperf/"):]: v for k, v in head.items()
                   if k.startswith("timeperf/") and v >= 0.05}
         step_s.append(sum(v for k, v in head.items() if k.startswith("timeperf/")))
         print(f"  step {i} wall seconds: "
               + " ".join(f"{k}={v:.2f}" for k, v in phases.items()))
-    pushed = max(int(h[0]["rollout_version_max"]) for h in history)
+    pushed = max(newest for _, newest in sampled_under)
     check(pushed >= 1, f"rollouts were generated on pushed weights (up to v{pushed})")
 
     print(f"batches packed by: {'native libdatapack.so' if load_datapack() else 'numpy'}")

@@ -32,8 +32,6 @@ Override any config field with key=value, e.g. `actor.optimizer.lr=1e-5`.
 import os
 import sys
 
-import numpy as np
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from areal_tpu.api.alloc_mode import AllocationMode, AllocationType
@@ -133,12 +131,13 @@ def build_rollout(config: GRPOConfig, alloc: AllocationMode, actor, tokenizer):
     return rollout, WeightUpdateMeta.from_memory(alloc)
 
 
-def main(args, before_teardown=None):
+def main(args, after_step=None):
     """Run the loop; returns each step's list of per-minibatch stats.
 
-    `before_teardown(actor, rollout)`, if given, is called after the last
-    step while both engines are still live (chip_smoke.py reads weight
-    versions, shardings and the loaded programs there)."""
+    `after_step(global_step, batch, actor, rollout)`, if given, is called at
+    the end of every step, rollouts still paused and both engines live
+    (chip_smoke.py reads the batch's weight versions there and, on the last
+    step, the engines' shardings and loaded programs)."""
     config, _ = load_expr_config(args, GRPOConfig)
     config: GRPOConfig
 
@@ -334,16 +333,6 @@ def main(args, before_teardown=None):
                 batch = rollout.rollout_batch(
                     next(iter(train_dataloader)), workflow=workflow
                 )
-        # weight versions the batch's generated tokens were sampled under
-        # (the staleness the gate admitted; current version = global_step)
-        gen_versions = np.asarray(batch["versions"])[
-            np.asarray(batch["loss_mask"]) > 0
-        ]
-        stats_tracker.scalar(
-            rollout_version_min=float(gen_versions.min()),
-            rollout_version_max=float(gen_versions.max()),
-        )
-
         if config.actor.recompute_logprob or config.actor.use_decoupled_loss:
             with stats_tracker.record_timing("recompute_logp"):
                 batch["prox_logp"] = actor.compute_logp(batch)
@@ -396,10 +385,10 @@ def main(args, before_teardown=None):
         stats[0].update(stats_tracker.export_all())
         stats_logger.commit(epoch, step, global_step, stats)
         history.append(stats)
+        if after_step is not None:
+            after_step(global_step, batch, actor, rollout)
         rollout.resume()
 
-    if before_teardown is not None:
-        before_teardown(actor, rollout)
     stats_logger.close()
     rollout.destroy()
     if ref is not None:

@@ -84,10 +84,18 @@ def test_no_mfu_without_a_known_peak():
 @pytest.mark.parametrize(
     "mode", ["jax:d1t1+d1", "jax:d2t1+d2", "jax:d1t2+d2", "jax:d1t1+d2"]
 )
-def test_launcher_gives_every_child_disjoint_chips(mode):
+def test_launcher_gives_every_child_disjoint_chips(mode, monkeypatch):
+    import subprocess
+
     from areal_tpu.api.alloc_mode import AllocationMode
     from areal_tpu.launcher.local import chip_env, plan_chips
 
+    # a host whose chip pairs lie along y: the runtime refuses "2,1,1"
+    def probe(cmd, env, **kwargs):
+        refused = env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "2,1,1"
+        return subprocess.CompletedProcess(cmd, int(refused))
+
+    monkeypatch.setattr(subprocess, "run", probe)
     alloc = AllocationMode.from_str(mode)
     plan = plan_chips(alloc)
     assert len(plan["trainer_0"]) == alloc.train.world_size
@@ -101,6 +109,18 @@ def test_launcher_gives_every_child_disjoint_chips(mode):
     envs = [chip_env(chips, 9000 + i) for i, chips in enumerate(plan.values())]
     assert len({e["TPU_VISIBLE_CHIPS"] for e in envs}) == len(envs)
     assert len({e["TPU_MESH_CONTROLLER_PORT"] for e in envs}) == len(envs)
+    # a pair gets the bounds the runtime accepted, the rest the only ones
+    assert [e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs] == [
+        {1: "1,1,1", 2: "1,2,1"}[len(chips)] for chips in plan.values()
+    ]
+    monkeypatch.setattr(
+        subprocess, "run", lambda cmd, **kw: subprocess.CompletedProcess(cmd, 1)
+    )
+    with pytest.raises(RuntimeError, match="none of the bounds"):
+        chip_env([2, 3], 9000)
+    # only the chip counts probed on the 2x2 host have bounds
+    with pytest.raises(ValueError, match="8 chips"):
+        chip_env(list(range(8)), 9000)
 
 
 def test_quant_matmul_pallas_never_gives_way_to_xla():

@@ -162,6 +162,40 @@ def test_wait_no_matching_jobs_returns(launcher):
     assert time.monotonic() - t0 < 5
 
 
+def test_servers_build_from_a_seed_only_when_the_config_says_so():
+    """The config's `actor.init_from_scratch` decides, not the directory's
+    contents: a checkpoint directory whose weights are missing must reach
+    the server's loader and fail there, not be served from a seed."""
+    import json
+    import os
+
+    from areal_tpu.api.cli_args import load_expr_config
+    from areal_tpu.launcher.local import LauncherView, scratch_model_arg
+    from areal_tpu.models.smoke import SMOKE_MODEL_DICT
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    geometry_only = os.path.join(repo, "examples/configs/qwen2.5-0.5b")
+    assert os.listdir(geometry_only) == ["config.json"]
+    assert scratch_model_arg(geometry_only, init_from_scratch=False) is None
+    built = json.loads(scratch_model_arg(geometry_only, init_from_scratch=True))
+    assert (built["hidden_size"], built["vocab_size"]) == (896, 151936)
+    for flag in (False, True):
+        assert json.loads(scratch_model_arg("", flag)) == SMOKE_MODEL_DICT
+
+    # the launcher's view of a GRPO YAML carries the flag, overrides included
+    argv = [
+        "--config",
+        os.path.join(repo, "examples/configs/qwen2.5_0.5b_grpo_smoke.yaml"),
+        "actor.ppo_n_minibatches=1",  # a field only the trainer's class has
+    ]
+    view, _ = load_expr_config(argv, LauncherView, ignore_unknown=True)
+    assert view.actor.init_from_scratch
+    view, _ = load_expr_config(
+        argv + ["actor.init_from_scratch=false"], LauncherView, ignore_unknown=True
+    )
+    assert not view.actor.init_from_scratch
+
+
 @pytest.mark.slow
 def test_decoupled_e2e_smoke(tmp_path):
     """Full DECOUPLED-mode E2E, fully offline: run_experiment spawns a
