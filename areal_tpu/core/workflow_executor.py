@@ -23,7 +23,7 @@ from areal_tpu.api.engine_api import EngineDeadError
 from areal_tpu.core.async_task_runner import AsyncTaskRunner, TaskResult
 from areal_tpu.core.sample_ledger import SampleLedger, SampleWAL
 from areal_tpu.core.staleness_manager import StalenessManager
-from areal_tpu.utils import logging, stats_tracker
+from areal_tpu.utils import logging, perf_tracer, stats_tracker
 from areal_tpu.utils.data import concat_padded_tensors, cycle_dataloader
 
 if TYPE_CHECKING:
@@ -154,7 +154,11 @@ class WorkflowExecutor:
         check_format = self.config.check_trajectory_format
 
         async def episode():
+            # after the fact and not a `with`: episodes interleave on the
+            # runner's one thread
+            t0 = time.monotonic()
             traj = await workflow.arun_episode(engine, data)
+            perf_tracer.record("rollout/episode", t0, time.monotonic(), rid=rid)
             if traj is not None and check_format:
                 check_trajectory_format(traj)
             if traj is not None and should_accept is not None and not should_accept(traj):
@@ -299,31 +303,33 @@ class WorkflowExecutor:
     ) -> dict[str, Any]:
         """Async pipeline heart: keep ≥2 batches of episodes in flight and
         return one training batch when ready (workflow_executor.py:561-598)."""
-        if self._data_generator is None:
-            self._data_generator = cycle_dataloader(dataloader)
-        batch_size = dataloader.batch_size
-        assert batch_size is not None
-        while True:
-            self.runner.health_check()
-            capacity = self.staleness_manager.get_capacity(self._version)
-            pending_total = (
-                self._pending_inputs.qsize()
-                + self.runner.inflight
-                + len(self._result_cache)
-            )
-            # keep two batches in the pipeline
-            if capacity + batch_size > 0 and pending_total < 2 * batch_size:
-                items = next(self._data_generator)
-                if isinstance(items, dict):
-                    items = [items]
-                for item in items:
-                    self.submit(item, workflow, workflow_builder, should_accept)
-            self._admit_pending()
-            self._collect()
-            if len(self._result_cache) >= batch_size:
-                with stats_tracker.record_timing("prepare_batch/concat"):
-                    return self.wait(batch_size, timeout=1)
-            time.sleep(ROLLOUT_POLL_WAIT_TIME / 10)
+        # the wait for the staleness gate and the episodes, as the loop sees it
+        with perf_tracer.span("rollout/prepare_batch", version=self._version):
+            if self._data_generator is None:
+                self._data_generator = cycle_dataloader(dataloader)
+            batch_size = dataloader.batch_size
+            assert batch_size is not None
+            while True:
+                self.runner.health_check()
+                capacity = self.staleness_manager.get_capacity(self._version)
+                pending_total = (
+                    self._pending_inputs.qsize()
+                    + self.runner.inflight
+                    + len(self._result_cache)
+                )
+                # keep two batches in the pipeline
+                if capacity + batch_size > 0 and pending_total < 2 * batch_size:
+                    items = next(self._data_generator)
+                    if isinstance(items, dict):
+                        items = [items]
+                    for item in items:
+                        self.submit(item, workflow, workflow_builder, should_accept)
+                self._admit_pending()
+                self._collect()
+                if len(self._result_cache) >= batch_size:
+                    with stats_tracker.record_timing("prepare_batch/concat"):
+                        return self.wait(batch_size, timeout=1)
+                time.sleep(ROLLOUT_POLL_WAIT_TIME / 10)
 
     def get_stats(self):
         return self.staleness_manager.get_stats()

@@ -65,6 +65,7 @@ import threading
 import time
 import traceback
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -101,7 +102,7 @@ from areal_tpu.models.qwen2 import (
     verify_step_paged,
 )
 from areal_tpu.parallel import mesh as mesh_lib
-from areal_tpu.utils import logging
+from areal_tpu.utils import logging, perf_tracer
 from areal_tpu.utils.lock import OrderedLock
 
 logger = logging.getLogger("jax_decode")
@@ -223,6 +224,7 @@ def _make_sample_fn(use_topp: bool):
     variant its batchmates forced. Reported logprobs are always exact
     log-softmax over the FULL vocab for the chosen token."""
 
+    @jax.named_scope("sample")
     def sample(logits, subkeys, temps, top_ps, greedy):
         logits = logits.astype(jnp.float32)
         logprobs_all = jax.nn.log_softmax(logits, axis=-1)
@@ -364,6 +366,7 @@ class _Inflight:
     version: int  # weight version the chunk was produced under
     t_dispatch: float
     n_chunk: int
+    chunk: int = 0  # the dispatch's number (chunks_dispatched_total), for spans
     # -- speculative verify chunks (spec_decode="ngram") ---------------
     # spec_w > 0 marks a verify chunk of q-width spec_w (= draft bucket
     # + 1 bonus); n_chunk == spec_w then bounds the PER-SLOT emission,
@@ -1093,7 +1096,7 @@ class JaxDecodeEngine(InferenceEngine):
             img_tok = self._image_token_id
             quant = self._kv_quant
 
-            def prefill_and_write(
+            def prefill_embed(
                 params, kq, vq, ids, positions, bt_row, true_len, img_embeds,
                 cos, sin,
             ):
@@ -1139,7 +1142,7 @@ class JaxDecodeEngine(InferenceEngine):
                 return join_pool(kp, ksc), join_pool(vp, vsc)
 
             self._embed_prefill_fns[key] = jax.jit(
-                prefill_and_write, donate_argnums=(1, 2)
+                prefill_embed, donate_argnums=(1, 2)
             )
         return self._embed_prefill_fns[key]
 
@@ -1424,6 +1427,7 @@ class JaxDecodeEngine(InferenceEngine):
                     # SAME chunk, making streams depend on chunk
                     # boundaries — park/resume and migration bit-identity
                     # would break).
+                    @jax.named_scope("decode_step")
                     def step(carry, _):
                         tokens, lengths, kpc, vpc, counts = carry
                         logits, kpc, vpc = decode_step_paged(
@@ -1454,13 +1458,15 @@ class JaxDecodeEngine(InferenceEngine):
                     R = bt.shape[0]
                     idx = bt.reshape(-1)
                     lengths0 = lengths
-                    kc = jnp.take(kp, idx, axis=1).reshape(
-                        L, R, nb * bsz, nkv, hd
-                    )
-                    vc = jnp.take(vp, idx, axis=1).reshape(
-                        L, R, nb * bsz, nkv, hd
-                    )
+                    with jax.named_scope("pool_read"):
+                        kc = jnp.take(kp, idx, axis=1).reshape(
+                            L, R, nb * bsz, nkv, hd
+                        )
+                        vc = jnp.take(vp, idx, axis=1).reshape(
+                            L, R, nb * bsz, nkv, hd
+                        )
 
+                    @jax.named_scope("decode_step")
                     def step(carry, _):
                         tokens, lengths, kc, vc, counts = carry
                         logits, kc, vc = decode_step(
@@ -1499,12 +1505,13 @@ class JaxDecodeEngine(InferenceEngine):
                     )[..., 0]
                     dblock = jnp.where(active[:, None], dblock, 0)
                     doff = jnp.where(active[:, None], pos % bsz, 0)
-                    kp = kp.at[:, dblock.reshape(-1), doff.reshape(-1)].set(
-                        rows_k.reshape(L, R * n_chunk, nkv, hd)
-                    )
-                    vp = vp.at[:, dblock.reshape(-1), doff.reshape(-1)].set(
-                        rows_v.reshape(L, R * n_chunk, nkv, hd)
-                    )
+                    with jax.named_scope("pool_write"):
+                        kp = kp.at[:, dblock.reshape(-1), doff.reshape(-1)].set(
+                            rows_k.reshape(L, R * n_chunk, nkv, hd)
+                        )
+                        vp = vp.at[:, dblock.reshape(-1), doff.reshape(-1)].set(
+                            rows_v.reshape(L, R * n_chunk, nkv, hd)
+                        )
                     if freq:
                         return kp, vp, last, lengths, toks, logps, counts
                     return kp, vp, last, lengths, toks, logps
@@ -1514,13 +1521,15 @@ class JaxDecodeEngine(InferenceEngine):
                 L, _, bsz, nkv, hd = kp.shape
                 R = bt.shape[0]
                 idx = bt.reshape(-1)
-                kc = jnp.take(kp, idx, axis=1).reshape(
-                    L, R, nb * bsz, nkv, hd
-                )
-                vc = jnp.take(vp, idx, axis=1).reshape(
-                    L, R, nb * bsz, nkv, hd
-                )
+                with jax.named_scope("pool_read"):
+                    kc = jnp.take(kp, idx, axis=1).reshape(
+                        L, R, nb * bsz, nkv, hd
+                    )
+                    vc = jnp.take(vp, idx, axis=1).reshape(
+                        L, R, nb * bsz, nkv, hd
+                    )
 
+                @jax.named_scope("decode_step")
                 def step(carry, _):
                     tokens, lengths, kc, vc, counts = carry
                     logits, kc, vc = decode_step(
@@ -1536,12 +1545,13 @@ class JaxDecodeEngine(InferenceEngine):
                 (last, lengths, kc, vc, counts), (toks, logps) = (
                     jax.lax.scan(step, init, None, length=n_chunk)
                 )
-                kp = kp.at[:, idx].set(
-                    kc.reshape(L, R * nb, bsz, nkv, hd)
-                )
-                vp = vp.at[:, idx].set(
-                    vc.reshape(L, R * nb, bsz, nkv, hd)
-                )
+                with jax.named_scope("pool_write"):
+                    kp = kp.at[:, idx].set(
+                        kc.reshape(L, R * nb, bsz, nkv, hd)
+                    )
+                    vp = vp.at[:, idx].set(
+                        vc.reshape(L, R * nb, bsz, nkv, hd)
+                    )
                 if freq:
                     return kp, vp, last, lengths, toks, logps, counts
                 return kp, vp, last, lengths, toks, logps
@@ -1609,18 +1619,20 @@ class JaxDecodeEngine(InferenceEngine):
             else:
                 L, _, bsz, nkv, hd = kp.shape
                 idx = bt.reshape(-1)
-                kc = jnp.take(kp, idx, axis=1).reshape(
-                    L, R, nb * bsz, nkv, hd
-                )
-                vc = jnp.take(vp, idx, axis=1).reshape(
-                    L, R, nb * bsz, nkv, hd
-                )
+                with jax.named_scope("pool_read"):
+                    kc = jnp.take(kp, idx, axis=1).reshape(
+                        L, R, nb * bsz, nkv, hd
+                    )
+                    vc = jnp.take(vp, idx, axis=1).reshape(
+                        L, R, nb * bsz, nkv, hd
+                    )
                 logits, kc, vc = verify_step(
                     params, tokens, lengths, kc, vc, cfg,
                     active=active, rope_offset=rope_delta,
                 )
-                kp = kp.at[:, idx].set(kc.reshape(L, R * nb, bsz, nkv, hd))
-                vp = vp.at[:, idx].set(vc.reshape(L, R * nb, bsz, nkv, hd))
+                with jax.named_scope("pool_write"):
+                    kp = kp.at[:, idx].set(kc.reshape(L, R * nb, bsz, nkv, hd))
+                    vp = vp.at[:, idx].set(vc.reshape(L, R * nb, bsz, nkv, hd))
             V = logits.shape[-1]
             # flatten [R, W] positions to R*W rows and reuse the chunk
             # loop's sampler verbatim: position base+j samples with
@@ -1827,7 +1839,8 @@ class JaxDecodeEngine(InferenceEngine):
             cfg = self.model_config
             quant = self._kv_quant
 
-            def batched(params, kq, vq, ids_b, positions, bts_b, lens_b):
+            def prefill_batched(params, kq, vq, ids_b, positions, bts_b,
+                                lens_b):
                 from areal_tpu.ops.kv_quant import (
                     join_pool, quantize_kv, scales_blocked, split_pool,
                 )
@@ -1875,7 +1888,7 @@ class JaxDecodeEngine(InferenceEngine):
                 return join_pool(kp, ksc), join_pool(vp, vsc)
 
             self._batched_prefill_fns[key] = jax.jit(
-                batched, donate_argnums=(1, 2)
+                prefill_batched, donate_argnums=(1, 2)
             )
         return self._batched_prefill_fns[key]
 
@@ -1888,7 +1901,7 @@ class JaxDecodeEngine(InferenceEngine):
         transformer prefill both replace."""
         if True not in self._fork_fns:
 
-            def copy_block(kq, vq, src_b, dst_b):
+            def fork_block(kq, vq, src_b, dst_b):
                 # tree-mapped so int8 operands copy the scale block through
                 # the same block ids as the data block (fp: bare arrays)
                 def cp(pool):
@@ -1897,7 +1910,7 @@ class JaxDecodeEngine(InferenceEngine):
 
                 return jax.tree.map(cp, kq), jax.tree.map(cp, vq)
 
-            self._fork_fns[True] = jax.jit(copy_block, donate_argnums=(0, 1))
+            self._fork_fns[True] = jax.jit(fork_block, donate_argnums=(0, 1))
         return self._fork_fns[True]
 
     def _device_fork(self, src: int, dst: int, covered: int) -> None:
@@ -2108,7 +2121,7 @@ class JaxDecodeEngine(InferenceEngine):
             cfg = self.model_config
             quant = self._kv_quant
 
-            def suffix_prefill(params, kq, vq, bt_row, ids, suffix_len,
+            def prefill_suffix(params, kq, vq, bt_row, ids, suffix_len,
                                prefix_len):
                 from areal_tpu.models.qwen2 import prefill_with_prefix
                 from areal_tpu.ops.kv_quant import (
@@ -2183,7 +2196,7 @@ class JaxDecodeEngine(InferenceEngine):
                 return join_pool(kp, ksc), join_pool(vp, vsc)
 
             self._suffix_prefill_fns[key] = jax.jit(
-                suffix_prefill, donate_argnums=(1, 2)
+                prefill_suffix, donate_argnums=(1, 2)
             )
         return self._suffix_prefill_fns[key]
 
@@ -2858,8 +2871,7 @@ class JaxDecodeEngine(InferenceEngine):
                 bsz = self._alloc.block_size
                 nb = -(-max(pb, plen + sb) // bsz)
                 fn = self._get_suffix_prefill_fn(sb, pb, nb)
-                t_pf = time.monotonic()
-                with self._weight_lock:
+                with self._prefill_dispatch(sb), self._weight_lock:
                     kq, vq = self._kv_operands()
                     self._set_kv_operands(*fn(
                         self.params,
@@ -2870,7 +2882,6 @@ class JaxDecodeEngine(InferenceEngine):
                         len(suffix),
                         plen,
                     ))
-                self._note_prefill_wall(time.monotonic() - t_pf)
                 self._register_prefix(slot_idx, list(prompt[:-1]))
             elif resumed is None and P > 1 and not promoted:
                 pre = P - 1
@@ -2898,8 +2909,7 @@ class JaxDecodeEngine(InferenceEngine):
                     fn = self._get_embed_prefill_fn(
                         bucket, int(img_embeds.shape[0])
                     )
-                    t_pf = time.monotonic()
-                    with self._weight_lock:
+                    with self._prefill_dispatch(bucket), self._weight_lock:
                         kq, vq = self._kv_operands()
                         self._set_kv_operands(*fn(
                             self.params,
@@ -2913,7 +2923,6 @@ class JaxDecodeEngine(InferenceEngine):
                             cos,
                             sin,
                         ))
-                    self._note_prefill_wall(time.monotonic() - t_pf)
                 elif is_wave_dup:
                     # duplicate within this admission wave: fork from the
                     # primary once its (deferred) prefill has run
@@ -2942,6 +2951,8 @@ class JaxDecodeEngine(InferenceEngine):
                 q_s = max(item.admit_t - item.start_time, 0.0)
                 self._ttft_queue_ms.append(q_s * 1000.0)
                 self._queue_secs_total += q_s
+            perf_tracer.record("request/queue", item.start_time, item.admit_t,
+                               rid=item.rid, slot=slot_idx)
             if item.prefill_only:
                 prefill_done.append(slot_idx)
             # One base key per REQUEST, assigned at its first admission in
@@ -2987,10 +2998,16 @@ class JaxDecodeEngine(InferenceEngine):
             self._retire(slot_idx)
         return admitted
 
-    def _note_prefill_wall(self, dt: float, n: int = 1) -> None:
-        """Record prefill dispatch wall for `n` admitted slots (TTFT
-        split). On CPU this is the compute itself; on TPU it is the
-        dispatch cost — the honest host-side share of TTFT either way."""
+    @contextmanager
+    def _prefill_dispatch(self, bucket: int, n: int = 1):
+        """One prefill program for `n` admitted slots: its span, and its
+        dispatch wall in the TTFT split. On CPU that is the compute itself;
+        on TPU it is the dispatch cost — the honest host-side share of TTFT
+        either way."""
+        t0 = time.monotonic()
+        with perf_tracer.span("decode/prefill", bucket=bucket, batch=n):
+            yield
+        dt = time.monotonic() - t0
         with self._metrics_lock:
             per = dt / max(n, 1)
             for _ in range(max(n, 1)):
@@ -3016,43 +3033,42 @@ class JaxDecodeEngine(InferenceEngine):
                 B = 8 if rest >= 8 else 4 if rest >= 4 else 2 if rest >= 2 else 1
                 group = entries[i : i + B]
                 i += B
-                t_pf = time.monotonic()
-                if B == 1:
-                    slot_idx, ids, pre, _, _ = group[0]
-                    fn = self._get_prefill_fn(bucket)
-                    with self._weight_lock:
-                        kq, vq = self._kv_operands()
-                        self._set_kv_operands(*fn(
-                            self.params,
-                            kq,
-                            vq,
-                            jnp.asarray(ids),
-                            jnp.asarray(positions),
-                            self._alloc.row(slot_idx, nb_w),
-                            pre,
-                        ))
-                else:
-                    fn = self._get_batched_prefill_fn(bucket, B)
-                    with self._weight_lock:
-                        kq, vq = self._kv_operands()
-                        self._set_kv_operands(*fn(
-                            self.params,
-                            kq,
-                            vq,
-                            jnp.asarray(
-                                np.stack([g[1] for g in group])
-                            ),
-                            jnp.asarray(positions),
-                            jnp.asarray(
-                                np.stack(
-                                    [self._alloc.row(g[0], nb_w) for g in group]
-                                )
-                            ),
-                            jnp.asarray(
-                                np.array([g[2] for g in group], np.int32)
-                            ),
-                        ))
-                self._note_prefill_wall(time.monotonic() - t_pf, n=B)
+                with self._prefill_dispatch(bucket, B):
+                    if B == 1:
+                        slot_idx, ids, pre, _, _ = group[0]
+                        fn = self._get_prefill_fn(bucket)
+                        with self._weight_lock:
+                            kq, vq = self._kv_operands()
+                            self._set_kv_operands(*fn(
+                                self.params,
+                                kq,
+                                vq,
+                                jnp.asarray(ids),
+                                jnp.asarray(positions),
+                                self._alloc.row(slot_idx, nb_w),
+                                pre,
+                            ))
+                    else:
+                        fn = self._get_batched_prefill_fn(bucket, B)
+                        with self._weight_lock:
+                            kq, vq = self._kv_operands()
+                            self._set_kv_operands(*fn(
+                                self.params,
+                                kq,
+                                vq,
+                                jnp.asarray(
+                                    np.stack([g[1] for g in group])
+                                ),
+                                jnp.asarray(positions),
+                                jnp.asarray(
+                                    np.stack(
+                                        [self._alloc.row(g[0], nb_w) for g in group]
+                                    )
+                                ),
+                                jnp.asarray(
+                                    np.array([g[2] for g in group], np.int32)
+                                ),
+                            ))
                 for slot_idx, _, _, _, covered_t in group:
                     self._register_prefix(slot_idx, list(covered_t))
         for dst, src, covered_t, bucket in forks:
@@ -3196,13 +3212,22 @@ class JaxDecodeEngine(InferenceEngine):
             self._complete(item, stop_reason=item.stop_reason or "stop")
 
     def _complete(self, item: _Slot, stop_reason: str) -> None:
+        t_done = time.monotonic()
+        if item.admit_t and item.ttft != float("inf"):
+            # admission to the first token on the host (the prefill program
+            # and the first chunk), then to the last
+            t_first = item.start_time + item.ttft
+            perf_tracer.record("request/prefill", item.admit_t, t_first,
+                               rid=item.rid)
+            perf_tracer.record("request/decode", t_first, t_done, rid=item.rid,
+                               tokens=len(item.tokens), stop=stop_reason)
         resp = ModelResponse(
             input_tokens=list(item.prompt),
             output_tokens=list(item.tokens),
             output_logprobs=list(item.logprobs),
             output_versions=list(item.versions),
             stop_reason=stop_reason,  # type: ignore[arg-type]
-            latency=time.monotonic() - item.start_time,
+            latency=t_done - item.start_time,
             ttft=item.ttft,
             itl=list(item.itl),
             tokenizer=self.tokenizer,
@@ -3239,11 +3264,21 @@ class JaxDecodeEngine(InferenceEngine):
                         paused, worked = True, False
                     else:
                         paused = False
-                        admitted = self._admit()
+                        admitted = False
+                        if self._overflow or not self._request_q.empty():
+                            # (with nothing queued _admit does nothing)
+                            with perf_tracer.span("decode/admit"):
+                                admitted = self._admit()
                         active = self._active_mask()
                         dispatched = False
                         if active.any():
-                            rec = self._dispatch_chunk(active)
+                            with perf_tracer.span(
+                                "decode/dispatch_chunk",
+                                chunk=self._chunks_dispatched + 1,
+                                active=int(active.sum()),
+                                version=self._version,
+                            ):
+                                rec = self._dispatch_chunk(active)
                             if rec is not None:
                                 self._inflight.append(rec)
                                 dispatched = True
@@ -3267,7 +3302,13 @@ class JaxDecodeEngine(InferenceEngine):
                                     self._last_ready_t = None
                         worked = dispatched or admitted or drained
                 if paused:
-                    time.sleep(0.005)
+                    # one span for the whole pause (a weight commit, an
+                    # abort), not one per poll; nothing can be dispatched
+                    # meanwhile, so there is nothing to drain again
+                    with perf_tracer.span("decode/paused", version=self._version):
+                        while (self._gen_paused.is_set()
+                               and not self._shutdown.is_set()):
+                            time.sleep(0.005)
                 elif not worked:
                     time.sleep(0.002)
         except BaseException as e:  # noqa: BLE001
@@ -3446,7 +3487,8 @@ class JaxDecodeEngine(InferenceEngine):
                 jnp.asarray(np.array(self._slot_lengths)),  # no-alias copy
             )
             self._patch_slots.clear()
-        ctl = self._refresh_ctl()
+        with perf_tracer.span("decode/refresh_ctl"):
+            ctl = self._refresh_ctl()
         # the effective (saturation-refined) active mask gets its own
         # cached device buffer: it changes only when a slot joins, leaves,
         # or crosses its max_new_tokens horizon
@@ -3525,6 +3567,7 @@ class JaxDecodeEngine(InferenceEngine):
                 version=version_at_chunk,
                 t_dispatch=t_dispatch,
                 n_chunk=spec_w,
+                chunk=self._chunks_dispatched,
                 spec_w=spec_w,
                 accepted=accepted,
                 draft_lens=dlens_np,
@@ -3611,13 +3654,24 @@ class JaxDecodeEngine(InferenceEngine):
             version=version_at_chunk,
             t_dispatch=t_dispatch,
             n_chunk=n_chunk,
+            chunk=self._chunks_dispatched,
         )
 
     def _consume_chunk(self, rec: "_Inflight") -> None:
-        toks = np.asarray(rec.toks)  # [n_chunk, R]
-        logps = np.asarray(rec.logps)
+        with perf_tracer.span("decode/consume_chunk", chunk=rec.chunk,
+                              version=rec.version):
+            with perf_tracer.span("decode/wait_device", chunk=rec.chunk):
+                toks = np.asarray(rec.toks)  # [n_chunk, R]
+                logps = np.asarray(rec.logps)
+                acc = np.asarray(rec.accepted) if rec.spec_w > 0 else None
+            self._apply_chunk(rec, toks, logps, acc)
+
+    def _apply_chunk(self, rec: "_Inflight", toks: np.ndarray,
+                     logps: np.ndarray, acc: np.ndarray | None) -> None:
+        """The host's share of a chunk once its tokens are here: reconcile
+        with slots retired since the dispatch, extend the requests, scan for
+        stops, retire and complete."""
         spec = rec.spec_w > 0
-        acc = np.asarray(rec.accepted) if spec else None
         t_ready = time.monotonic()
         n_chunk = rec.n_chunk
         # dispatch→ready is the device window; anything between the
@@ -3869,6 +3923,23 @@ class JaxDecodeEngine(InferenceEngine):
 
     def continue_generation(self):
         self._gen_paused.clear()
+
+    @contextmanager
+    def _weight_swap(self):
+        """Every way new weights come in: pause on the chunk boundary, swap
+        under the weight lock, resume. An external pause is preserved (an
+        external /pause_generation is not cancelled by the swap's own)."""
+        was_paused = self._gen_paused.is_set()
+        with perf_tracer.span("weights/pause", version=self._version):
+            self.pause_generation()
+        try:
+            with perf_tracer.span("weights/commit", version=self._version), \
+                    self._weight_lock:
+                yield
+        finally:
+            if not was_paused:
+                with perf_tracer.span("weights/resume", version=self._version):
+                    self.continue_generation()
 
     def prewarm(
         self,
@@ -4855,52 +4926,46 @@ class JaxDecodeEngine(InferenceEngine):
         afterwards (an external /pause_generation is not cancelled by the
         weight swap's internal pause)."""
         assert params is not None
-        was_paused = self._gen_paused.is_set()
-        self.pause_generation()
-        try:
-            with self._weight_lock:
-                # copy (may_alias=False) — the trainer will donate these
-                # buffers next step; device_put also reshards from the
-                # trainer's (fsdp/tp) layout onto the decode mesh's layout.
-                # Trainer weights are UNREPEATED — re-apply the GQA kv-head
-                # repeat first.
-                params = self._repeat_kv_tree(params)
-                if self._w_quant:
-                    # colocated trainers hand over fp master weights —
-                    # quantize on install (idempotent if already {"q",
-                    # "scale"}), matching the quantized sharding tree
-                    from areal_tpu.models.qwen2 import quantize_weights
+        with self._weight_swap():
+            # copy (may_alias=False) — the trainer will donate these
+            # buffers next step; device_put also reshards from the
+            # trainer's (fsdp/tp) layout onto the decode mesh's layout.
+            # Trainer weights are UNREPEATED — re-apply the GQA kv-head
+            # repeat first.
+            params = self._repeat_kv_tree(params)
+            if self._w_quant:
+                # colocated trainers hand over fp master weights —
+                # quantize on install (idempotent if already {"q",
+                # "scale"}), matching the quantized sharding tree
+                from areal_tpu.models.qwen2 import quantize_weights
 
-                    params = quantize_weights(params)
-                self.params = jax.tree.map(
-                    lambda x, s: jax.device_put(
-                        jnp.asarray(x), s, may_alias=False
-                    ),
-                    params,
-                    self._param_shardings,
+                params = quantize_weights(params)
+            self.params = jax.tree.map(
+                lambda x, s: jax.device_put(
+                    jnp.asarray(x), s, may_alias=False
+                ),
+                params,
+                self._param_shardings,
+            )
+            self._lora_base.clear()  # whole tree replaced
+            self._invalidate_parked()
+            if model_config is not None:
+                decode_cfg = dataclasses.replace(
+                    model_config,
+                    dtype=self.config.dtype,
+                    param_dtype=self.config.dtype,
                 )
-                self._lora_base.clear()  # whole tree replaced
-                self._invalidate_parked()
-                if model_config is not None:
+                if self._kv_repeat > 1:
+                    self._orig_model_config = decode_cfg
                     decode_cfg = dataclasses.replace(
-                        model_config,
-                        dtype=self.config.dtype,
-                        param_dtype=self.config.dtype,
+                        decode_cfg,
+                        num_key_value_heads=decode_cfg.num_key_value_heads
+                        * self._kv_repeat,
                     )
-                    if self._kv_repeat > 1:
-                        self._orig_model_config = decode_cfg
-                        decode_cfg = dataclasses.replace(
-                            decode_cfg,
-                            num_key_value_heads=decode_cfg.num_key_value_heads
-                            * self._kv_repeat,
-                        )
-                    if self.model_config is not None and decode_cfg != self.model_config:
-                        # cache shapes depend only on L/nKV/hd which cannot
-                        # change for the same run
-                        self.model_config = decode_cfg
-        finally:
-            if not was_paused:
-                self.continue_generation()
+                if self.model_config is not None and decode_cfg != self.model_config:
+                    # cache shapes depend only on L/nKV/hd which cannot
+                    # change for the same run
+                    self.model_config = decode_cfg
 
     def _apply_lora_delta(
         self, named: dict, scale: float
@@ -5005,93 +5070,81 @@ class JaxDecodeEngine(InferenceEngine):
             raise ValueError(
                 "lora delta push requires lora_scale (= lora_alpha / rank)"
             )
-        was_paused = self._gen_paused.is_set()
-        self.pause_generation()
-        try:
-            with self._weight_lock:
-                dtype = jnp.dtype(self.config.dtype)
+        with self._weight_swap():
+            dtype = jnp.dtype(self.config.dtype)
 
-                def cast(new, old):
-                    # quantized engines preserve each leaf's RESIDENT dtype
-                    # (int8 `.../q`, f32 `.../scale`, serve dtype for fp
-                    # leaves) — the producer already quantized, casting to
-                    # the serve dtype would corrupt the int8 payload. fp
-                    # engines keep the original serve-dtype cast bitwise.
-                    tgt = old.dtype if self._w_quant else dtype
-                    if isinstance(new, jax.Array):
-                        arr = new.astype(tgt)  # merged delta: on device
-                    else:
-                        arr = jnp.asarray(np.asarray(new), dtype=tgt)
-                    assert arr.shape == old.shape, (arr.shape, old.shape)
-                    if isinstance(old, jax.Array) and hasattr(old, "sharding"):
-                        arr = jax.device_put(arr, old.sharding)
-                    return arr
+            def cast(new, old):
+                # quantized engines preserve each leaf's RESIDENT dtype
+                # (int8 `.../q`, f32 `.../scale`, serve dtype for fp
+                # leaves) — the producer already quantized, casting to
+                # the serve dtype would corrupt the int8 payload. fp
+                # engines keep the original serve-dtype cast bitwise.
+                tgt = old.dtype if self._w_quant else dtype
+                if isinstance(new, jax.Array):
+                    arr = new.astype(tgt)  # merged delta: on device
+                else:
+                    arr = jnp.asarray(np.asarray(new), dtype=tgt)
+                assert arr.shape == old.shape, (arr.shape, old.shape)
+                if isinstance(old, jax.Array) and hasattr(old, "sharding"):
+                    arr = jax.device_put(arr, old.sharding)
+                return arr
 
-                # wire tensors carry the trainer's (unrepeated) kv heads
-                install = self._repeat_kv_named(plain)
-                # a full-tree push overwrites kernels a delta snapshot may
-                # reference — those snapshots are stale, drop them (a
-                # quantized kernel arrives as `<path>/q` + `<path>/scale`
-                # wire names, but the snapshot is keyed by `<path>`)
-                for k in install:
-                    self._lora_base.pop(k, None)
-                    if k.endswith(("/q", "/scale")):
-                        self._lora_base.pop(k.rsplit("/", 1)[0], None)
-                if lora_named:
-                    install.update(
-                        self._apply_lora_delta(lora_named, float(lora_scale))
-                    )
-                try:
-                    self.params = set_named(self.params, install, cast=cast)
-                except KeyError as e:
-                    # the usual cause: producer and consumer disagree on
-                    # weight_dtype — quantized kernels live under `/q` +
-                    # `/scale` suffixed names, fp kernels under the bare
-                    # path, so EVERY kernel name misses the target tree
-                    raise KeyError(
-                        f"{e.args[0]} — engine serves weight_dtype="
-                        f"{self.config.weight_dtype!r}; an fp<->int8 "
-                        "producer/consumer mismatch shifts every kernel "
-                        "wire name by the '/q' + '/scale' suffix (set "
-                        "WeightUpdateMeta.weight_dtype to the engine's "
-                        "serving dtype)"
-                    ) from e
-                self._invalidate_parked()
-                if version is not None:
-                    self._version = int(version)
-                    if self._executor is not None:
-                        self._executor.set_version(int(version))
-        finally:
-            if not was_paused:
-                self.continue_generation()
+            # wire tensors carry the trainer's (unrepeated) kv heads
+            install = self._repeat_kv_named(plain)
+            # a full-tree push overwrites kernels a delta snapshot may
+            # reference — those snapshots are stale, drop them (a
+            # quantized kernel arrives as `<path>/q` + `<path>/scale`
+            # wire names, but the snapshot is keyed by `<path>`)
+            for k in install:
+                self._lora_base.pop(k, None)
+                if k.endswith(("/q", "/scale")):
+                    self._lora_base.pop(k.rsplit("/", 1)[0], None)
+            if lora_named:
+                install.update(
+                    self._apply_lora_delta(lora_named, float(lora_scale))
+                )
+            try:
+                self.params = set_named(self.params, install, cast=cast)
+            except KeyError as e:
+                # the usual cause: producer and consumer disagree on
+                # weight_dtype — quantized kernels live under `/q` +
+                # `/scale` suffixed names, fp kernels under the bare
+                # path, so EVERY kernel name misses the target tree
+                raise KeyError(
+                    f"{e.args[0]} — engine serves weight_dtype="
+                    f"{self.config.weight_dtype!r}; an fp<->int8 "
+                    "producer/consumer mismatch shifts every kernel "
+                    "wire name by the '/q' + '/scale' suffix (set "
+                    "WeightUpdateMeta.weight_dtype to the engine's "
+                    "serving dtype)"
+                ) from e
+            self._invalidate_parked()
+            if version is not None:
+                self._version = int(version)
+                if self._executor is not None:
+                    self._executor.set_version(int(version))
 
     def update_weights_from_disk(self, meta: WeightUpdateMeta):
         """Reload weights from an HF checkpoint dir. Preserves an external
         pause (see update_weights_from_distributed)."""
         assert meta.path is not None
-        was_paused = self._gen_paused.is_set()
-        self.pause_generation()
-        try:
-            with self._weight_lock:
-                # HF checkpoints carry the original (unrepeated) kv heads.
-                load_cfg = self._orig_model_config or self.model_config
-                host = self._repeat_kv_tree(
-                    hf_io.load_hf_params(meta.path, load_cfg)
-                )
-                if self._w_quant:
-                    from areal_tpu.models.qwen2 import quantize_weights
+        with self._weight_swap():
+            # HF checkpoints carry the original (unrepeated) kv heads.
+            load_cfg = self._orig_model_config or self.model_config
+            host = self._repeat_kv_tree(
+                hf_io.load_hf_params(meta.path, load_cfg)
+            )
+            if self._w_quant:
+                from areal_tpu.models.qwen2 import quantize_weights
 
-                    host = quantize_weights(host)
-                self.params = jax.tree.map(
-                    lambda x, s: jax.device_put(jnp.asarray(x), s),
-                    host,
-                    self._param_shardings,
-                )
-                self._lora_base.clear()  # whole tree replaced
-                self._invalidate_parked()
-        finally:
-            if not was_paused:
-                self.continue_generation()
+                host = quantize_weights(host)
+            self.params = jax.tree.map(
+                lambda x, s: jax.device_put(jnp.asarray(x), s),
+                host,
+                self._param_shardings,
+            )
+            self._lora_base.clear()  # whole tree replaced
+            self._invalidate_parked()
 
     def set_version(self, version: int) -> None:
         self._version = version

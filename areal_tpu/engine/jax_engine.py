@@ -61,7 +61,7 @@ from areal_tpu.models.qwen2 import (
     segment_ids_from_cu_seqlens,
 )
 from areal_tpu.parallel import mesh as mesh_lib
-from areal_tpu.utils import logging, name_resolve, names
+from areal_tpu.utils import logging, name_resolve, names, perf_tracer
 from areal_tpu.utils.data import (
     MicroBatchList,
     split_padded_tensor_dict_into_mb_list,
@@ -358,6 +358,8 @@ class JaxTrainEngine(TrainEngine):
         self._mb_sharding = None
         self._grad_step_cache: dict[int, Callable] = {}
         self._fwd_cache: dict[int, Callable] = {}
+        # (program, jitted fn, shape key) dispatched at least once: see _run
+        self._programs_seen: set[tuple] = set()
         self._apply_update_fn = None
         self._zero_grads_fn = None
         self._push_cast_fn = None
@@ -959,8 +961,10 @@ class JaxTrainEngine(TrainEngine):
             # the TPU analogue of the reference NCCL broadcast
             # (fsdp_engine.py:298-401).
             assert self.rollout_engine is not None
+            with perf_tracer.span("weights/stage", version=self.get_version()):
+                params = self._export_params()
             self.rollout_engine.update_weights_from_distributed(
-                meta, self._export_params(), self.model_config
+                meta, params, self.model_config
             )
         elif meta.type == "disk":
             start = time.monotonic()
@@ -1122,14 +1126,17 @@ class JaxTrainEngine(TrainEngine):
         ) else f"push-{version}"
 
         def _stage():
-            engine.stage_weights(
-                named, push_id=push_id, chunk_mb=chunk_mb, inflight=inflight
-            )
+            with perf_tracer.span("weights/stage", version=version):
+                engine.stage_weights(
+                    named, push_id=push_id, chunk_mb=chunk_mb,
+                    inflight=inflight,
+                )
 
         def _commit():
-            engine.commit_staged(
-                push_id, version=version, lora_scale=lora_scale
-            )
+            with perf_tracer.span("weights/commit", version=version):
+                engine.commit_staged(
+                    push_id, version=version, lora_scale=lora_scale
+                )
 
         def _abort():
             engine.abort_push(push_id)
@@ -1478,7 +1485,8 @@ class JaxTrainEngine(TrainEngine):
             x, aux = out if with_aux else (out, None)
             if hidden_mode:
                 x = LMHead(x, params, model_cfg)
-            res = loss_fn(x, mb)
+            with jax.named_scope("loss"):
+                res = loss_fn(x, mb)
             loss, stats = res if aux_mode else (res, {})
             if with_aux:
                 loss = loss + model_cfg.router_aux_loss_coef * aux
@@ -1502,9 +1510,10 @@ class JaxTrainEngine(TrainEngine):
             # differently from the donated accumulator and fall back to
             # "involuntary full rematerialization" reshards on every step.
             grads = jax.lax.with_sharding_constraint(grads, grad_sh)
-            acc = jax.tree.map(
-                lambda a, g: a + g.astype(grad_dtype) * weight, acc, grads
-            )
+            with jax.named_scope("grad_accum"):
+                acc = jax.tree.map(
+                    lambda a, g: a + g.astype(grad_dtype) * weight, acc, grads
+                )
             return loss, stats, acc
 
         fn = jax.jit(
@@ -1530,16 +1539,18 @@ class JaxTrainEngine(TrainEngine):
         optimizer = self.optimizer
 
         def apply_update(params, opt_state, grads, total_weight):
-            grads = jax.tree.map(lambda g: g / total_weight, grads)
-            gnorm = optax.global_norm(grads)
-            if clip and clip > 0:
-                scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                grads = jax.tree.map(lambda g: g * scale, grads)
-            grads = jax.tree.map(
-                lambda g, p: g.astype(p.dtype), grads, params
-            )
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("grad_norm"):
+                grads = jax.tree.map(lambda g: g / total_weight, grads)
+                gnorm = optax.global_norm(grads)
+                if clip and clip > 0:
+                    scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                    grads = jax.tree.map(lambda g: g * scale, grads)
+            with jax.named_scope("optimizer"):
+                grads = jax.tree.map(
+                    lambda g, p: g.astype(p.dtype), grads, params
+                )
+                updates, opt_state = optimizer.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, gnorm
 
         # NOTE: grads (arg 2) are NOT donated — they have no same-shaped
@@ -1559,13 +1570,30 @@ class JaxTrainEngine(TrainEngine):
     def _zero_grads(self):
         if not hasattr(self, "_zero_grads_fn") or self._zero_grads_fn is None:
             grad_dtype = jnp.dtype(self.config.grad_reduce_dtype)
-            self._zero_grads_fn = jax.jit(
-                lambda p: jax.tree.map(
+
+            def zero_grads(p):
+                return jax.tree.map(
                     lambda x: jnp.zeros(x.shape, grad_dtype), p
-                ),
-                out_shardings=self._grad_shardings(),
+                )
+
+            self._zero_grads_fn = jax.jit(
+                zero_grads, out_shardings=self._grad_shardings()
             )
-        return self._zero_grads_fn(self._trainable_sub(self.params))
+        return self._run("zero_grads", self._zero_grads_fn, None,
+                         self._trainable_sub(self.params))
+
+    def _run(self, program: str, fn: Callable, shape_key, *args):
+        """Dispatch a jitted program. The first call of a new (program,
+        shape) traces and compiles (or loads from the persistent cache)
+        inside the call: it gets a `train/compile` span and counts in
+        `train_batch`'s `compiles`."""
+        key = (program, id(fn), shape_key)
+        if key in self._programs_seen:
+            return fn(*args)
+        self._programs_seen.add(key)
+        with perf_tracer.span("train/compile", program=program,
+                              shape=str(shape_key)):
+            return fn(*args)
 
     def train_batch(
         self,
@@ -1579,7 +1607,6 @@ class JaxTrainEngine(TrainEngine):
         mesh_lib.set_current_mesh(self.mesh)
         assert self.optimizer is not None, "engine has no optimizer"
         from areal_tpu.core import fault_injection
-        from areal_tpu.utils.perf_tracer import annotate, maybe_xprof_step
 
         # chaos seam: a trainer dying inside an optimizer step (weights
         # half-applied in HBM, nothing durable) — see bench chaostrain
@@ -1587,69 +1614,95 @@ class JaxTrainEngine(TrainEngine):
 
         t_start = time.perf_counter()
         # env-gated device-trace window (AREAL_TPU_XPROF_DIR [+ _STEPS])
-        maybe_xprof_step(self._step_count, owner=id(self))
-        mb_list = split_padded_tensor_dict_into_mb_list(
-            input_, self.config.mb_spec
-        )
-        weights = [float(loss_weight_fn(mb)) for mb in mb_list.mbs]
-        total_weight = float(sum(weights)) or 1.0
+        perf_tracer.maybe_xprof_step(self._step_count, owner=id(self))
+        span = perf_tracer.span
+        seen_before = len(self._programs_seen)
         aux_stats: dict[str, float] = {}
-        xprof = annotate("train_batch")
-        xprof.__enter__()
-        try:
+        with perf_tracer.step_span("train/train_batch", self._step_count):
+            with span("train/split_mbs"):
+                mb_list = split_padded_tensor_dict_into_mb_list(
+                    input_, self.config.mb_spec
+                )
+                weights = [float(loss_weight_fn(mb)) for mb in mb_list.mbs]
+                total_weight = float(sum(weights)) or 1.0
+                # tokens the micro-batches hold, padding included
+                mb_tokens = [int(mb["cu_seqlens"][-1]) for mb in mb_list.mbs]
             if self._pp_size > 1:
                 # pipelined path: all micro-batches stream through the pp
                 # stages inside ONE jitted step (fill/steady/drain), one backward
-                stacked = self._stack_mbs(mb_list.mbs)
+                with span("train/upload_mb", tokens=sum(mb_tokens)):
+                    stacked = self._stack_mbs(mb_list.mbs)
                 pip_step = self._get_pipelined_grad_step(loss_fn)
-                losses, mb_stats, acc = pip_step(
-                    self.params, stacked, jnp.asarray(weights, jnp.float32)
-                )
-                losses = list(np.asarray(losses))
-                w_arr = np.asarray(weights, np.float64)
-                for k, v in mb_stats.items():
-                    aux_stats[k] = float(
-                        (np.asarray(v, np.float64) * w_arr).sum() / total_weight
+                with span("train/grad_step", tokens=sum(mb_tokens)):
+                    losses, mb_stats, acc = self._run(
+                        "pip_grad_step", pip_step,
+                        stacked["input_ids"].shape, self.params, stacked,
+                        jnp.asarray(weights, jnp.float32),
                     )
+                with span("train/read_stats"):
+                    losses = list(np.asarray(losses))
+                    w_arr = np.asarray(weights, np.float64)
+                    for k, v in mb_stats.items():
+                        aux_stats[k] = float(
+                            (np.asarray(v, np.float64) * w_arr).sum()
+                            / total_weight
+                        )
             else:
                 grad_step = self._get_grad_step(loss_fn)
                 acc = self._zero_grads()
                 losses = []
                 mb_stat_list: list[dict] = []
-                for mb, w in zip(mb_list.mbs, weights):
-                    dev_mb = self._device_mb(mb)
-                    loss, mb_stats, acc = grad_step(self.params, acc, w, dev_mb)
+                for mb, w, n_tok in zip(mb_list.mbs, weights, mb_tokens):
+                    with span("train/upload_mb", tokens=n_tok):
+                        dev_mb = self._device_mb(mb)
+                    with span("train/grad_step", tokens=n_tok):
+                        loss, mb_stats, acc = self._run(
+                            "grad_step", grad_step, n_tok,
+                            self.params, acc, w, dev_mb,
+                        )
                     losses.append(loss)
                     # keep device arrays — float() here would sync per
                     # micro-batch and serialize the accumulation pipeline
                     mb_stat_list.append(mb_stats)
-                for mb_stats, w in zip(mb_stat_list, weights):
-                    for k, v in mb_stats.items():
-                        aux_stats[k] = aux_stats.get(k, 0.0) + float(v) * w
+                with span("train/read_stats"):
+                    # the first float() waits for every grad_step above
+                    for mb_stats, w in zip(mb_stat_list, weights):
+                        for k, v in mb_stats.items():
+                            aux_stats[k] = aux_stats.get(k, 0.0) + float(v) * w
                 aux_stats = {k: v / total_weight for k, v in aux_stats.items()}
             apply_update = self._get_apply_update()
-            new_trainable, self.opt_state, gnorm = apply_update(
-                self._trainable_sub(self.params), self.opt_state, acc, total_weight
-            )
-            self.params = self._merge_trainable(self.params, new_trainable)
-            gnorm_f = float(gnorm)  # blocks until the step is done on device
-        finally:
-            xprof.__exit__(None, None, None)
-        step_time = time.perf_counter() - t_start
-        self._step_count += 1
-        lr = float(self.lr_schedule(self._step_count))
-        loss_avg = float(
-            sum(float(l) * w for l, w in zip(losses, weights)) / total_weight
-        )
-        stats = dict(
-            loss=loss_avg,
-            grad_norm=gnorm_f,
-            lr=lr,
-            n_mbs=len(mb_list.mbs),
-            update_steps=self._step_count,
-            **aux_stats,
-        )
-        stats.update(self._throughput_stats(input_, step_time))
+            with span("train/apply_update"):
+                new_trainable, self.opt_state, gnorm = self._run(
+                    "apply_update", apply_update, None,
+                    self._trainable_sub(self.params), self.opt_state, acc,
+                    total_weight,
+                )
+                self.params = self._merge_trainable(self.params, new_trainable)
+            with span("train/wait_device"):
+                gnorm_f = float(gnorm)  # blocks until the step is done on device
+            step_time = time.perf_counter() - t_start
+            self._step_count += 1
+            with span("train/step_stats"):
+                # (the learning rate and the losses are small device reads)
+                lr = float(self.lr_schedule(self._step_count))
+                loss_avg = float(
+                    sum(float(l) * w for l, w in zip(losses, weights))
+                    / total_weight
+                )
+                stats = dict(
+                    loss=loss_avg,
+                    grad_norm=gnorm_f,
+                    lr=lr,
+                    n_mbs=len(mb_list.mbs),
+                    update_steps=self._step_count,
+                    # with n_tokens, the useful share of what the device was given
+                    padded_tokens=float(sum(mb_tokens)),
+                    # programs this step ran for the first time (a steady-state
+                    # step that compiles names itself here)
+                    compiles=len(self._programs_seen) - seen_before,
+                    **aux_stats,
+                )
+                stats.update(self._throughput_stats(input_, step_time))
         return stats
 
     def _throughput_stats(
@@ -1838,7 +1891,13 @@ class JaxTrainEngine(TrainEngine):
         fwd_step = self._fwd_cache[key]
 
         for mb, sample_idx in zip(mb_list.mbs, mb_list.forward_indices):
-            out = np.asarray(fwd_step(self.params, self._device_mb(mb)))
+            n_tok = int(mb["cu_seqlens"][-1])
+            with perf_tracer.span("train/upload_mb", tokens=n_tok):
+                dev_mb = self._device_mb(mb)
+            with perf_tracer.span("train/fwd_step", tokens=n_tok):
+                out = self._run("fwd_step", fwd_step, n_tok, self.params, dev_mb)
+            with perf_tracer.span("train/wait_device"):
+                out = np.asarray(out)
             # Split mb output back into sequences; drop the pad tail (the
             # appended fake sequence is the last cu_seqlens entry if padded).
             cu = np.asarray(mb["cu_seqlens"])

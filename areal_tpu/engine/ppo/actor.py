@@ -31,7 +31,7 @@ from areal_tpu.api.cli_args import MicroBatchSpec, PPOActorConfig
 from areal_tpu.api.engine_api import TrainEngine
 from areal_tpu.engine.jax_engine import JaxTrainEngine
 from areal_tpu.ops.gae import gae_padded_jit
-from areal_tpu.utils import logging, stats_tracker
+from areal_tpu.utils import logging, perf_tracer, stats_tracker
 from areal_tpu.utils.data import KLEstimator, Normalization
 from areal_tpu.utils.datapack import ffd_allocate
 from areal_tpu.utils.functional import (
@@ -276,14 +276,18 @@ class PPOActor:
 
         all_stats = []
         ent_trace: list[float] = []
-        for mb in _split_minibatches(data, cfg.ppo_n_minibatches):
-            train_stat = self.engine.train_batch(
-                mb,
-                loss_fn=loss_fn,
-                loss_weight_fn=lambda x: float(
-                    np.asarray(x["loss_mask"]).sum()
-                ),
-            )
+        for i, mb in enumerate(
+            _split_minibatches(data, cfg.ppo_n_minibatches)
+        ):
+            with perf_tracer.span("train/minibatch", step=self._update_steps,
+                                  minibatch=i):
+                train_stat = self.engine.train_batch(
+                    mb,
+                    loss_fn=loss_fn,
+                    loss_weight_fn=lambda x: float(
+                        np.asarray(x["loss_mask"]).sum()
+                    ),
+                )
             if "entropy" in train_stat:
                 ent_trace.append(float(train_stat["entropy"]))
             stats_tracker.scalar(**train_stat)
@@ -366,13 +370,15 @@ class JaxPPOActor(JaxTrainEngine):
         self.actor = PPOActor(config, self)
 
     def compute_logp(self, *args, **kwargs):
-        return self.actor.compute_logp(*args, **kwargs)
+        with perf_tracer.span("train/compute_logp", step=self.actor._update_steps):
+            return self.actor.compute_logp(*args, **kwargs)
 
     def compute_advantages(self, *args, **kwargs) -> None:
         self.actor.compute_advantages(*args, **kwargs)
 
     def ppo_update(self, *args, **kwargs) -> list[dict[str, float]]:
-        return self.actor.ppo_update(*args, **kwargs)
+        with perf_tracer.span("train/ppo_update", step=self.actor._update_steps):
+            return self.actor.ppo_update(*args, **kwargs)
 
 
 def grpo_loss_fn(

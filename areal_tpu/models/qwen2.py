@@ -1184,6 +1184,7 @@ def resolve_attn_impl(cfg: ModelConfig) -> str:
     return "flash"
 
 
+@jax.named_scope("attn")
 def attention(
     layer_p: dict,
     x: jax.Array,
@@ -1194,74 +1195,77 @@ def attention(
     cfg: ModelConfig,
 ) -> jax.Array:
     """Packed multi-head GQA attention over one 1-D token stream [T, H]."""
-    nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
-    q = _w_einsum("th,hnd->tnd", x, layer_p["q_kernel"], 1)
-    k = _w_einsum("th,hnd->tnd", x, layer_p["k_kernel"], 1)
-    v = _w_einsum("th,hnd->tnd", x, layer_p["v_kernel"], 1)
-    if cfg.lora_rank:
-        q = _with_lora(layer_p, "q_kernel", q, x, cfg)
-        k = _with_lora(layer_p, "k_kernel", k, x, cfg)
-        v = _with_lora(layer_p, "v_kernel", v, x, cfg)
-    if cfg.qkv_bias:
-        q = q + layer_p["q_bias"]
-        k = k + layer_p["k_bias"]
-        v = v + layer_p["v_bias"]
-    if cfg.qk_norm:
-        q = _norm(q, layer_p["q_norm"], cfg)
-        k = _norm(k, layer_p["k_norm"], cfg)
+    with jax.named_scope("qkv"):
+        q = _w_einsum("th,hnd->tnd", x, layer_p["q_kernel"], 1)
+        k = _w_einsum("th,hnd->tnd", x, layer_p["k_kernel"], 1)
+        v = _w_einsum("th,hnd->tnd", x, layer_p["v_kernel"], 1)
+        if cfg.lora_rank:
+            q = _with_lora(layer_p, "q_kernel", q, x, cfg)
+            k = _with_lora(layer_p, "k_kernel", k, x, cfg)
+            v = _with_lora(layer_p, "v_kernel", v, x, cfg)
+        if cfg.qkv_bias:
+            q = q + layer_p["q_bias"]
+            k = k + layer_p["k_bias"]
+            v = v + layer_p["v_bias"]
+        if cfg.qk_norm:
+            q = _norm(q, layer_p["q_norm"], cfg)
+            k = _norm(k, layer_p["k_norm"], cfg)
     if cfg.pos_embed == "rope":
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with jax.named_scope("rope"):
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
     q = _cstr(q, "tokens", "act_heads", None)
     k = _cstr(k, "tokens", "act_kv_heads", None)
     v = _cstr(v, "tokens", "act_kv_heads", None)
-
+    nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     T = x.shape[0]
     impl = resolve_attn_impl(cfg)
-    if impl == "flash":
-        from areal_tpu.ops.flash_attention import flash_attention
+    with jax.named_scope("attention"):
+        if impl == "flash":
+            from areal_tpu.ops.flash_attention import flash_attention
 
-        out = flash_attention(q, k, v, segment_ids)
-    elif impl == "ring":
-        from areal_tpu.ops.ring_attention import (
-            ring_flash_attention,
-            zigzag_eligible,
-        )
+            out = flash_attention(q, k, v, segment_ids)
+        elif impl == "ring":
+            from areal_tpu.ops.ring_attention import (
+                ring_flash_attention,
+                zigzag_eligible,
+            )
 
-        # Same predicate forward() used when (not) permuting the stream —
-        # the two sites must agree or positions would be misread.
-        out = ring_flash_attention(
-            q, k, v, segment_ids,
-            zigzag=cfg.cp_zigzag and zigzag_eligible(T),
-        )
-    elif impl == "chunked":
-        from areal_tpu.ops.chunked_attention import chunked_attention
+            # Same predicate forward() used when (not) permuting the stream —
+            # the two sites must agree or positions would be misread.
+            out = ring_flash_attention(
+                q, k, v, segment_ids,
+                zigzag=cfg.cp_zigzag and zigzag_eligible(T),
+            )
+        elif impl == "chunked":
+            from areal_tpu.ops.chunked_attention import chunked_attention
 
-        out = chunked_attention(
-            q, k, v, segment_ids, sliding_window=cfg.sliding_window
-        )
-    else:
-        # GQA: broadcast kv heads to query heads via grouped einsum.
-        group = nH // nKV
-        if mask is None:
-            mask = segment_causal_mask(segment_ids, cfg.sliding_window)
-        qg = q.reshape(T, nKV, group, hd)
-        scores = jnp.einsum("tkgd,skd->kgts", qg, k).astype(jnp.float32)
-        scores = scores / np.sqrt(hd)
-        scores = jnp.where(mask[None, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        out = jnp.einsum("kgts,skd->tkgd", probs, v)
-        out = out.reshape(T, nH, hd)
+            out = chunked_attention(
+                q, k, v, segment_ids, sliding_window=cfg.sliding_window
+            )
+        else:
+            # GQA: broadcast kv heads to query heads via grouped einsum.
+            group = nH // nKV
+            if mask is None:
+                mask = segment_causal_mask(segment_ids, cfg.sliding_window)
+            qg = q.reshape(T, nKV, group, hd)
+            scores = jnp.einsum("tkgd,skd->kgts", qg, k).astype(jnp.float32)
+            scores = scores / np.sqrt(hd)
+            scores = jnp.where(mask[None, None, :, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            out = jnp.einsum("kgts,skd->tkgd", probs, v)
+            out = out.reshape(T, nH, hd)
     out = _cstr(out, "tokens", "act_heads", None)
-    proj = _w_einsum("tnd,ndh->th", out, layer_p["o_kernel"], 2)
-    if cfg.lora_rank:
-        d = _lora_delta(
-            layer_p, "o_kernel", out.reshape(T, nH * hd), cfg
-        )
-        if d is not None:
-            proj = proj + d
-    if cfg.attn_out_bias:
-        proj = proj + layer_p["o_bias"]
+    with jax.named_scope("o_proj"):
+        proj = _w_einsum("tnd,ndh->th", out, layer_p["o_kernel"], 2)
+        if cfg.lora_rank:
+            d = _lora_delta(
+                layer_p, "o_kernel", out.reshape(T, nH * hd), cfg
+            )
+            if d is not None:
+                proj = proj + d
+        if cfg.attn_out_bias:
+            proj = proj + layer_p["o_bias"]
     return _cstr(proj, "tokens", "act_embed")
 
 
@@ -1272,6 +1276,7 @@ def _with_lora(layer_p, leaf, y, x, cfg):
     return y if d is None else y + d
 
 
+@jax.named_scope("mlp")
 def mlp(layer_p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     act = act_fn(cfg)
     if cfg.mlp_style == "fc":
@@ -1299,6 +1304,7 @@ def _moe_group_size(T: int, target: int) -> int:
     return s
 
 
+@jax.named_scope("mlp")
 def moe_mlp(
     layer_p: dict,
     x: jax.Array,
@@ -1420,6 +1426,7 @@ def _maybe_remat(layer_fn, cfg: ModelConfig):
     return jax.checkpoint(layer_fn, static_argnums=(6,), policy=policy)
 
 
+@jax.named_scope("layer")
 def decoder_layer(
     layer_p: dict,
     x: jax.Array,
@@ -1492,22 +1499,23 @@ def forward(
     # (dp) shards on the hidden dim makes SPMD pass them through the gather
     # output, which then collides with the tokens-over-(dp,sp) layout every
     # consumer wants and forces a full-remat reshard in the backward.
-    table = _cstr(params["embed"]["embedding"], "vocab", None)
-    x = _cstr(
-        _scale_embed(table[input_ids].astype(compute_dtype), cfg),
-        "tokens",
-        "act_embed",
-    )
-    if cfg.pos_embed == "learned":
-        # Same gather rule as the token table above: hidden dim must be
-        # UNSHARDED going into the gather or its fsdp shards collide with
-        # the tokens-over-(dp,sp) activation layout (full-remat reshard).
-        ptab = _cstr(params["pos_embed"]["embedding"], None, None)
+    with jax.named_scope("embed"):
+        table = _cstr(params["embed"]["embedding"], "vocab", None)
         x = _cstr(
-            x + ptab[position_ids].astype(compute_dtype),
+            _scale_embed(table[input_ids].astype(compute_dtype), cfg),
             "tokens",
             "act_embed",
         )
+        if cfg.pos_embed == "learned":
+            # Same gather rule as the token table above: hidden dim must be
+            # UNSHARDED going into the gather or its fsdp shards collide with
+            # the tokens-over-(dp,sp) activation layout (full-remat reshard).
+            ptab = _cstr(params["pos_embed"]["embedding"], None, None)
+            x = _cstr(
+                x + ptab[position_ids].astype(compute_dtype),
+                "tokens",
+                "act_embed",
+            )
     cos, sin = rope_table(position_ids, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
     # Dense path: build the [T,T] mask ONCE here (outside the per-layer remat
     # region); flash/ring never materialise it.
@@ -1536,7 +1544,8 @@ def forward(
             )
             aux_total = aux_total + aux
 
-    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_bias"))
+    with jax.named_scope("final_norm"):
+        x = _norm(x, params["final_norm"], cfg, params.get("final_norm_bias"))
     if return_hidden:
         assert not cfg.is_critic, "fused head path is for LM heads only"
         out_axes: tuple[str | None, ...] = ("tokens", "act_embed")
@@ -1548,18 +1557,9 @@ def forward(
         )
         out = values[:, 0].astype(jnp.float32)
         out_axes = ("tokens",)
-    elif cfg.tie_word_embeddings:
-        out = jnp.einsum(
-            "th,vh->tv", x, params["embed"]["embedding"].astype(compute_dtype)
-        ).astype(jnp.float32)
-        out_axes = ("tokens", "act_vocab")
-        out = _cstr(out, *out_axes)
     else:
-        out = jnp.einsum(
-            "th,hv->tv", x, params["lm_head"]["kernel"]
-        ).astype(jnp.float32)
         out_axes = ("tokens", "act_vocab")
-        out = _cstr(out, *out_axes)
+        out = _cstr(_lm_head(params, x, cfg), *out_axes)
     if zz_inv is not None:
         # Invert the zig-zag layout so loss functions / callers see the
         # contiguous packed order they built the micro-batch in.
@@ -1841,22 +1841,71 @@ def segment_ids_from_cu_seqlens(cu_seqlens: np.ndarray, total: int) -> np.ndarra
 # ---------------------------------------------------------------------------
 
 
+def _lm_head(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """f32 logits [..., V] of post-final-norm hidden states [..., H]."""
+    with jax.named_scope("lm_head"):
+        if cfg.tie_word_embeddings:
+            table = params["embed"]["embedding"].astype(jnp.dtype(cfg.dtype))
+            logits = jnp.einsum("...h,vh->...v", x, table)
+        else:
+            logits = jnp.einsum("...h,hv->...v", x, params["lm_head"]["kernel"])
+        return logits.astype(jnp.float32)
+
+
+def _final_logits(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    with jax.named_scope("final_norm"):
+        x = _norm(x, params["final_norm"], cfg, params.get("final_norm_bias"))
+    return _lm_head(params, x, cfg)
+
+
+def _embed_tokens(params: dict, tokens, positions, cfg: ModelConfig) -> jax.Array:
+    """Token (and learned position) embeddings of the decode-side steps."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = _scale_embed(
+            params["embed"]["embedding"][tokens].astype(compute_dtype), cfg
+        )
+        if cfg.pos_embed == "learned":
+            x = x + params["pos_embed"]["embedding"][positions].astype(
+                compute_dtype
+            )
+        return x
+
+
+def _attn_out_mlp(layer_p: dict, x, attn_out, cfg: ModelConfig, valid):
+    """What every decode-side layer body ends with: the output projection of
+    `attn_out` [N, nH, hd] and the MLP, each with its residual."""
+    with jax.named_scope("attn"), jax.named_scope("o_proj"):
+        proj = _w_einsum("tnd,ndh->th", attn_out, layer_p["attn"]["o_kernel"], 2)
+        if cfg.attn_out_bias:
+            proj = proj + layer_p["attn"]["o_bias"]
+    x = x + proj
+    h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
+    if cfg.num_experts:
+        y, _ = moe_mlp(layer_p["mlp"], h, cfg, valid=valid)
+    else:
+        y = mlp(layer_p["mlp"], h, cfg)
+    return x + y
+
+
 def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig):
     """Shared QKV projection + norm + rope. x: [..., H] with leading dims
     matching cos/sin's leading dims."""
-    q = _w_einsum("...h,hnd->...nd", x, layer_p["q_kernel"], 1)
-    k = _w_einsum("...h,hnd->...nd", x, layer_p["k_kernel"], 1)
-    v = _w_einsum("...h,hnd->...nd", x, layer_p["v_kernel"], 1)
-    if cfg.qkv_bias:
-        q = q + layer_p["q_bias"]
-        k = k + layer_p["k_bias"]
-        v = v + layer_p["v_bias"]
-    if cfg.qk_norm:
-        q = _norm(q, layer_p["q_norm"], cfg)
-        k = _norm(k, layer_p["k_norm"], cfg)
+    with jax.named_scope("qkv"):
+        q = _w_einsum("...h,hnd->...nd", x, layer_p["q_kernel"], 1)
+        k = _w_einsum("...h,hnd->...nd", x, layer_p["k_kernel"], 1)
+        v = _w_einsum("...h,hnd->...nd", x, layer_p["v_kernel"], 1)
+        if cfg.qkv_bias:
+            q = q + layer_p["q_bias"]
+            k = k + layer_p["k_bias"]
+            v = v + layer_p["v_bias"]
+        if cfg.qk_norm:
+            q = _norm(q, layer_p["q_norm"], cfg)
+            k = _norm(k, layer_p["k_norm"], cfg)
     cos_b = cos[..., None, :].astype(q.dtype)
     sin_b = sin[..., None, :].astype(q.dtype)
 
+    @jax.named_scope("rope")
     def rot(t):
         d2 = t.shape[-1] // 2
         t1, t2 = t[..., :d2], t[..., d2:]
@@ -1907,15 +1956,16 @@ def prefill(
     must then be the absolute positions (prefix_len + arange). One layer
     body serves both modes so the paths cannot drift apart."""
     compute_dtype = jnp.dtype(cfg.dtype)
-    if input_embeds is not None:
-        x = input_embeds.astype(compute_dtype)
-    else:
-        x = params["embed"]["embedding"][input_ids].astype(compute_dtype)
-    x = _scale_embed(x, cfg)
-    if cfg.pos_embed == "learned":
-        x = x + params["pos_embed"]["embedding"][position_ids].astype(
-            compute_dtype
-        )
+    with jax.named_scope("embed"):
+        if input_embeds is not None:
+            x = input_embeds.astype(compute_dtype)
+        else:
+            x = params["embed"]["embedding"][input_ids].astype(compute_dtype)
+        x = _scale_embed(x, cfg)
+        if cfg.pos_embed == "learned":
+            x = x + params["pos_embed"]["embedding"][position_ids].astype(
+                compute_dtype
+            )
     if rope_cos is not None:
         cos, sin = rope_cos, rope_sin
     else:
@@ -1943,34 +1993,30 @@ def prefill(
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     group = nH // nKV
 
+    @jax.named_scope("layer")
     def layer(x, inputs):
         if with_prefix:
             layer_p, pk, pv = inputs
         else:
             layer_p = inputs
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-        q, k, v = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-        if with_prefix:
-            kk = jnp.concatenate([pk.astype(k.dtype), k], axis=0)
-            vv = jnp.concatenate([pv.astype(v.dtype), v], axis=0)
-        else:
-            kk, vv = k, v
-        qg = q.reshape(T, nKV, group, hd)
-        scores = jnp.einsum("tkgd,skd->kgts", qg, kk).astype(jnp.float32)
-        scores = scores / np.sqrt(hd)
-        scores = jnp.where(mask[None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        attn_out = jnp.einsum("kgts,skd->tkgd", probs, vv).reshape(T, nH, hd)
-        proj = _w_einsum("tnd,ndh->th", attn_out, layer_p["attn"]["o_kernel"], 2)
-        if cfg.attn_out_bias:
-            proj = proj + layer_p["attn"]["o_bias"]
-        x = x + proj
-        h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
-        if cfg.num_experts:
-            y, _ = moe_mlp(layer_p["mlp"], h, cfg, valid=valid)
-        else:
-            y = mlp(layer_p["mlp"], h, cfg)
-        x = x + y
+        with jax.named_scope("attn"):
+            q, k, v = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
+            with jax.named_scope("attention"):
+                if with_prefix:
+                    kk = jnp.concatenate([pk.astype(k.dtype), k], axis=0)
+                    vv = jnp.concatenate([pv.astype(v.dtype), v], axis=0)
+                else:
+                    kk, vv = k, v
+                qg = q.reshape(T, nKV, group, hd)
+                scores = jnp.einsum("tkgd,skd->kgts", qg, kk).astype(jnp.float32)
+                scores = scores / np.sqrt(hd)
+                scores = jnp.where(mask[None, None], scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+                attn_out = jnp.einsum(
+                    "kgts,skd->tkgd", probs, vv
+                ).reshape(T, nH, hd)
+        x = _attn_out_mlp(layer_p, x, attn_out, cfg, valid)
         return x, (k, v)
 
     if cfg.scan_layers:
@@ -1995,14 +2041,8 @@ def prefill(
 
     if not with_logits:
         return None, ks, vs
-    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_bias"))
-    if cfg.tie_word_embeddings:
-        logits = jnp.einsum(
-            "th,vh->tv", x, params["embed"]["embedding"].astype(compute_dtype)
-        )
-    else:
-        logits = jnp.einsum("th,hv->tv", x, params["lm_head"]["kernel"])
-    return logits.astype(jnp.float32), ks, vs
+    logits = _final_logits(params, x, cfg)
+    return logits, ks, vs
 
 
 def prefill_with_prefix(
@@ -2067,18 +2107,11 @@ def decode_step(
     reduces exactly to standard 1-D rope at that scalar — so the shared
     decode step stays mrope-correct with just this offset.
     """
-    compute_dtype = jnp.dtype(cfg.dtype)
     R = tokens.shape[0]
     S = k_cache.shape[2]
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     group = nH // nKV
-    x = _scale_embed(
-        params["embed"]["embedding"][tokens].astype(compute_dtype), cfg
-    )  # [R, H]
-    if cfg.pos_embed == "learned":
-        x = x + params["pos_embed"]["embedding"][positions].astype(
-            compute_dtype
-        )
+    x = _embed_tokens(params, tokens, positions, cfg)  # [R, H]
     rope_pos = positions if rope_offset is None else positions + rope_offset
     cos, sin = rope_table(rope_pos, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)  # [R, hd/2]
     valid = jnp.arange(S)[None, :] <= positions[:, None]  # [R, S]
@@ -2087,6 +2120,7 @@ def decode_step(
             jnp.arange(S)[None, :] > positions[:, None] - cfg.sliding_window
         )
 
+    @jax.named_scope("kv_write")
     def write(cache_l, new):  # [R, S, nKV, hd] <- [R, nKV, hd]
         onehot = jnp.arange(S)[None, :] == positions[:, None]
         if active is not None:
@@ -2100,30 +2134,24 @@ def decode_step(
             new[:, None] * onehot[..., None, None]
         )
 
+    @jax.named_scope("layer")
     def layer(x, inputs):
         layer_p, kc, vc = inputs
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-        q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-        kc = write(kc, k_new.astype(kc.dtype))
-        vc = write(vc, v_new.astype(vc.dtype))
-        qg = q.reshape(R, nKV, group, hd)
-        scores = jnp.einsum("rkgd,rskd->rkgs", qg, kc.astype(q.dtype))
-        scores = (scores / np.sqrt(hd)).astype(jnp.float32)
-        scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        attn_out = jnp.einsum(
-            "rkgs,rskd->rkgd", probs, vc.astype(x.dtype)
-        ).reshape(R, nH, hd)
-        proj = _w_einsum("rnd,ndh->rh", attn_out, layer_p["attn"]["o_kernel"], 2)
-        if cfg.attn_out_bias:
-            proj = proj + layer_p["attn"]["o_bias"]
-        x = x + proj
-        h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
-        if cfg.num_experts:
-            y, _ = moe_mlp(layer_p["mlp"], h, cfg, valid=active)
-        else:
-            y = mlp(layer_p["mlp"], h, cfg)
-        x = x + y
+        with jax.named_scope("attn"):
+            q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
+            kc = write(kc, k_new.astype(kc.dtype))
+            vc = write(vc, v_new.astype(vc.dtype))
+            with jax.named_scope("attention"):
+                qg = q.reshape(R, nKV, group, hd)
+                scores = jnp.einsum("rkgd,rskd->rkgs", qg, kc.astype(q.dtype))
+                scores = (scores / np.sqrt(hd)).astype(jnp.float32)
+                scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+                attn_out = jnp.einsum(
+                    "rkgs,rskd->rkgd", probs, vc.astype(x.dtype)
+                ).reshape(R, nH, hd)
+        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active)
         return x, (kc, vc)
 
     if cfg.scan_layers:
@@ -2140,14 +2168,8 @@ def decode_step(
             vcs.append(vc)
         k_cache, v_cache = jnp.stack(kcs), jnp.stack(vcs)
 
-    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_bias"))
-    if cfg.tie_word_embeddings:
-        logits = jnp.einsum(
-            "rh,vh->rv", x, params["embed"]["embedding"].astype(compute_dtype)
-        )
-    else:
-        logits = jnp.einsum("rh,hv->rv", x, params["lm_head"]["kernel"])
-    return logits.astype(jnp.float32), k_cache, v_cache
+    logits = _final_logits(params, x, cfg)
+    return logits, k_cache, v_cache
 
 
 def decode_step_paged(
@@ -2195,21 +2217,13 @@ def decode_step_paged(
     from areal_tpu.ops.kv_quant import join_pool, quantize_kv, split_pool
     from areal_tpu.ops.paged_attention import paged_attention
 
-    compute_dtype = jnp.dtype(cfg.dtype)
     R = tokens.shape[0]
     k_data, _ = split_pool(k_pool)
     bsz = k_data.shape[2]
     nb = block_tables.shape[1]
     span = nb * bsz
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
-    group = nH // nKV
-    x = _scale_embed(
-        params["embed"]["embedding"][tokens].astype(compute_dtype), cfg
-    )  # [R, H]
-    if cfg.pos_embed == "learned":
-        x = x + params["pos_embed"]["embedding"][positions].astype(
-            compute_dtype
-        )
+    x = _embed_tokens(params, tokens, positions, cfg)  # [R, H]
     rope_pos = positions if rope_offset is None else positions + rope_offset
     cos, sin = rope_table(rope_pos, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
     valid = jnp.arange(span)[None, :] <= positions[:, None]  # [R, span]
@@ -2229,6 +2243,8 @@ def decode_step_paged(
         dest_block = jnp.where(active, dest_block, 0)
         dest_off = jnp.where(active, dest_off, 0)
 
+    @jax.named_scope("kv_write")
+    @jax.named_scope("pool_write")
     def write(pool_l, new):  # [n_blocks, bsz, nKV, hd] <- [R, nKV, hd] fp
         data, scales = split_pool(pool_l)
         if scales is None:
@@ -2241,25 +2257,20 @@ def decode_step_paged(
             scales.at[dest_block, :, dest_off].set(s_row),
         )
 
+    @jax.named_scope("layer")
     def layer(x, inputs):
         layer_p, kp, vp = inputs
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-        q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-        kp = write(kp, k_new)
-        vp = write(vp, v_new)
-        attn_out = paged_attention(
-            q.reshape(R, nH, hd), kp, vp, block_tables, valid, impl=attn_impl
-        )
-        proj = _w_einsum("rnd,ndh->rh", attn_out, layer_p["attn"]["o_kernel"], 2)
-        if cfg.attn_out_bias:
-            proj = proj + layer_p["attn"]["o_bias"]
-        x = x + proj
-        h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
-        if cfg.num_experts:
-            y, _ = moe_mlp(layer_p["mlp"], h, cfg, valid=active)
-        else:
-            y = mlp(layer_p["mlp"], h, cfg)
-        x = x + y
+        with jax.named_scope("attn"):
+            q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
+            kp = write(kp, k_new)
+            vp = write(vp, v_new)
+            with jax.named_scope("attention"):
+                attn_out = paged_attention(
+                    q.reshape(R, nH, hd), kp, vp, block_tables, valid,
+                    impl=attn_impl,
+                )
+        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active)
         return x, (kp, vp)
 
     if cfg.scan_layers:
@@ -2282,14 +2293,8 @@ def decode_step_paged(
         k_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *kps)
         v_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *vps)
 
-    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_bias"))
-    if cfg.tie_word_embeddings:
-        logits = jnp.einsum(
-            "rh,vh->rv", x, params["embed"]["embedding"].astype(compute_dtype)
-        )
-    else:
-        logits = jnp.einsum("rh,hv->rv", x, params["lm_head"]["kernel"])
-    return logits.astype(jnp.float32), k_pool, v_pool
+    logits = _final_logits(params, x, cfg)
+    return logits, k_pool, v_pool
 
 
 def verify_step(
@@ -2318,20 +2323,12 @@ def verify_step(
     """
     from areal_tpu.ops.chunked_attention import verify_attention
 
-    compute_dtype = jnp.dtype(cfg.dtype)
     R, W = tokens.shape
     S = k_cache.shape[2]
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     positions = positions0[:, None] + jnp.arange(W, dtype=positions0.dtype)
     flat_pos = positions.reshape(-1)  # [R*W]
-    x = _scale_embed(
-        params["embed"]["embedding"][tokens.reshape(-1)].astype(compute_dtype),
-        cfg,
-    )  # [R*W, H]
-    if cfg.pos_embed == "learned":
-        x = x + params["pos_embed"]["embedding"][flat_pos].astype(
-            compute_dtype
-        )
+    x = _embed_tokens(params, tokens.reshape(-1), flat_pos, cfg)  # [R*W, H]
     rope_pos = (
         positions if rope_offset is None else positions + rope_offset[:, None]
     ).reshape(-1)
@@ -2349,6 +2346,7 @@ def verify_step(
         None if active is None else jnp.repeat(active, W, axis=0)
     )
 
+    @jax.named_scope("kv_write")
     def write(cache_l, new):  # [R, S, nKV, hd] <- [R*W, nKV, hd]
         new_r = new.reshape(R, W, nKV, hd)
         if active is not None:
@@ -2361,26 +2359,20 @@ def verify_step(
             new_r = jnp.where(active[:, None, None, None], new_r, old)
         return cache_l.at[row_idx, pos_c].set(new_r)
 
+    @jax.named_scope("layer")
     def layer(x, inputs):
         layer_p, kc, vc = inputs
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-        q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-        kc = write(kc, k_new.astype(kc.dtype))
-        vc = write(vc, v_new.astype(vc.dtype))
-        attn_out = verify_attention(
-            q.reshape(R, W, nH, hd), kc.astype(q.dtype), vc.astype(q.dtype),
-            valid,
-        ).reshape(R * W, nH, hd)
-        proj = _w_einsum("tnd,ndh->th", attn_out, layer_p["attn"]["o_kernel"], 2)
-        if cfg.attn_out_bias:
-            proj = proj + layer_p["attn"]["o_bias"]
-        x = x + proj
-        h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
-        if cfg.num_experts:
-            y, _ = moe_mlp(layer_p["mlp"], h, cfg, valid=active_flat)
-        else:
-            y = mlp(layer_p["mlp"], h, cfg)
-        x = x + y
+        with jax.named_scope("attn"):
+            q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
+            kc = write(kc, k_new.astype(kc.dtype))
+            vc = write(vc, v_new.astype(vc.dtype))
+            with jax.named_scope("attention"):
+                attn_out = verify_attention(
+                    q.reshape(R, W, nH, hd), kc.astype(q.dtype),
+                    vc.astype(q.dtype), valid,
+                ).reshape(R * W, nH, hd)
+        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat)
         return x, (kc, vc)
 
     if cfg.scan_layers:
@@ -2397,15 +2389,9 @@ def verify_step(
             vcs.append(vc)
         k_cache, v_cache = jnp.stack(kcs), jnp.stack(vcs)
 
-    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_bias"))
-    if cfg.tie_word_embeddings:
-        logits = jnp.einsum(
-            "th,vh->tv", x, params["embed"]["embedding"].astype(compute_dtype)
-        )
-    else:
-        logits = jnp.einsum("th,hv->tv", x, params["lm_head"]["kernel"])
+    logits = _final_logits(params, x, cfg)
     return (
-        logits.astype(jnp.float32).reshape(R, W, -1),
+        logits.reshape(R, W, -1),
         k_cache,
         v_cache,
     )
@@ -2435,7 +2421,6 @@ def verify_step_paged(
     from areal_tpu.ops.kv_quant import quantize_kv, split_pool
     from areal_tpu.ops.paged_attention import paged_attention_qlen
 
-    compute_dtype = jnp.dtype(cfg.dtype)
     R, W = tokens.shape
     k_data, _ = split_pool(k_pool)
     bsz = k_data.shape[2]
@@ -2444,14 +2429,7 @@ def verify_step_paged(
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     positions = positions0[:, None] + jnp.arange(W, dtype=positions0.dtype)
     flat_pos = positions.reshape(-1)
-    x = _scale_embed(
-        params["embed"]["embedding"][tokens.reshape(-1)].astype(compute_dtype),
-        cfg,
-    )  # [R*W, H]
-    if cfg.pos_embed == "learned":
-        x = x + params["pos_embed"]["embedding"][flat_pos].astype(
-            compute_dtype
-        )
+    x = _embed_tokens(params, tokens.reshape(-1), flat_pos, cfg)  # [R*W, H]
     rope_pos = (
         positions if rope_offset is None else positions + rope_offset[:, None]
     ).reshape(-1)
@@ -2479,6 +2457,8 @@ def verify_step_paged(
         None if active is None else jnp.repeat(active, W, axis=0)
     )
 
+    @jax.named_scope("kv_write")
+    @jax.named_scope("pool_write")
     def write(pool_l, new):  # [n_blocks, bsz, nKV, hd] <- [R*W, nKV, hd] fp
         data, scales = split_pool(pool_l)
         if scales is None:
@@ -2491,26 +2471,20 @@ def verify_step_paged(
             scales.at[dest_block_f, :, dest_off_f].set(s_rows),
         )
 
+    @jax.named_scope("layer")
     def layer(x, inputs):
         layer_p, kp, vp = inputs
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-        q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-        kp = write(kp, k_new)
-        vp = write(vp, v_new)
-        attn_out = paged_attention_qlen(
-            q.reshape(R, W, nH, hd), kp, vp, block_tables, valid,
-            impl=attn_impl,
-        ).reshape(R * W, nH, hd)
-        proj = _w_einsum("tnd,ndh->th", attn_out, layer_p["attn"]["o_kernel"], 2)
-        if cfg.attn_out_bias:
-            proj = proj + layer_p["attn"]["o_bias"]
-        x = x + proj
-        h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
-        if cfg.num_experts:
-            y, _ = moe_mlp(layer_p["mlp"], h, cfg, valid=active_flat)
-        else:
-            y = mlp(layer_p["mlp"], h, cfg)
-        x = x + y
+        with jax.named_scope("attn"):
+            q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
+            kp = write(kp, k_new)
+            vp = write(vp, v_new)
+            with jax.named_scope("attention"):
+                attn_out = paged_attention_qlen(
+                    q.reshape(R, W, nH, hd), kp, vp, block_tables, valid,
+                    impl=attn_impl,
+                ).reshape(R * W, nH, hd)
+        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat)
         return x, (kp, vp)
 
     if cfg.scan_layers:
@@ -2533,15 +2507,9 @@ def verify_step_paged(
         k_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *kps)
         v_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *vps)
 
-    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_bias"))
-    if cfg.tie_word_embeddings:
-        logits = jnp.einsum(
-            "th,vh->tv", x, params["embed"]["embedding"].astype(compute_dtype)
-        )
-    else:
-        logits = jnp.einsum("th,hv->tv", x, params["lm_head"]["kernel"])
+    logits = _final_logits(params, x, cfg)
     return (
-        logits.astype(jnp.float32).reshape(R, W, -1),
+        logits.reshape(R, W, -1),
         k_pool,
         v_pool,
     )

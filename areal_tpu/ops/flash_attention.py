@@ -158,6 +158,7 @@ def _fwd_call(
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q), lambda h, i, j: (0, i)),
@@ -374,6 +375,7 @@ def _bwd_call(
     )
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_dq",
         grid=(nH, Tq // block_q, Tk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q), lambda h, i, j: (0, i)),
@@ -411,6 +413,7 @@ def _bwd_call(
     # dk/dv computed per *query* head, then reduced over the GQA group.
     dk_h, dv_h = pl.pallas_call(
         dkv_kernel,
+        name="flash_dkv",
         grid=(nH, Tk // block_k, Tq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q), lambda h, jk, iq: (0, iq)),

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("xent")
 def chunked_label_logprobs(
     hidden: jax.Array,
     head_w: jax.Array,
@@ -132,6 +133,7 @@ def chunked_label_logprobs(
     return logp
 
 
+@jax.named_scope("xent")
 def chunked_clamped_entropy(
     hidden: jax.Array,
     head_w: jax.Array,

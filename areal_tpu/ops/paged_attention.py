@@ -75,6 +75,7 @@ def _default_interpret() -> bool:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("pool_read")
 def _gather_dequant(pool, scales, idx, R, nb, bsz, nKV, hd, dtype):
     """Gather `idx` blocks into [R, nb*bsz, nKV, hd]; int8 pools are
     dequantized right after the gather (the seam the Pallas kernel puts
@@ -229,8 +230,11 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, sm_scale, interpret):
         pl.BlockSpec((1, 1, W, bsz), lambda r, b, bt: (r, b, 0, 0)),
         pl.BlockSpec((1, rows, D), lambda r, b, bt: (r, 0, 0)),
     ]
-    k2 = k_pool.reshape(n_blocks, bsz, D)
-    v2 = v_pool.reshape(n_blocks, bsz, D)
+    with jax.named_scope("pool_read"):
+        # the kernel walks pages of [bsz, nKV * hd] rows: a relayout of the
+        # layer's whole pool slice when (nKV, hd) is not already lane-dense
+        k2 = k_pool.reshape(n_blocks, bsz, D)
+        v2 = v_pool.reshape(n_blocks, bsz, D)
     if quant:
         sel = jnp.asarray(np.tile(onehot, (W, 1)).T[:, :, None])
         in_specs += [
@@ -254,6 +258,7 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, sm_scale, interpret):
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, sm_scale=sm_scale, quant=quant),
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, rows, D), q.dtype),
         interpret=interpret,

@@ -92,6 +92,7 @@ def _quant_matmul_pallas(x2, q2, s_row, out_dtype, interpret):
     grid = (tp // TILE_T, nn // TILE_N, kk // TILE_K)
     out = pl.pallas_call(
         _quant_matmul_kernel,
+        name="quant_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((TILE_T, TILE_K), lambda i, j, k: (i, k)),

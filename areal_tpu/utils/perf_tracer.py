@@ -1,178 +1,163 @@
-"""Chrome-trace (catapult) JSON performance tracer.
+"""The one way the program marks time: `span(name, **ids)`.
 
-Parity target: areal/utils/perf_tracer.py:127 (PerfTracer) — sync/async trace
-scopes with categories (compute/comm/io/sync/scheduler), per-rank trace files
-merged into one, env-var initialisation, atexit save. Viewable in
-chrome://tracing or Perfetto; complements (does not replace) jax.profiler
-xprof traces for on-device kernel timing.
+A span always enters a `jax.profiler.TraceAnnotation("areal/<name>", **ids)`,
+so while a device trace is running (`maybe_xprof_step`, or the benchmark's
+`--trace 1`) it lies on the trace's clock beside the device's operations; with
+no trace running that costs a flag test. With `AREAL_TPU_PERF_TRACE=1` it is
+also kept in memory, on `time.monotonic_ns` and with the span that was open
+around it on its thread, and written as Chrome-trace JSON
+(chrome://tracing, Perfetto, `tools/trace_report.py`) when the process exits,
+under `AREAL_TPU_PERF_TRACE_DIR`.
+
+`ids` tie spans together: `rid` a request, `step` a trainer step, `chunk` a
+decode chunk, `version` a weight version. `PERF.md` lists every span.
 """
 
 from __future__ import annotations
 
 import atexit
+import itertools
 import json
 import os
 import threading
 import time
-from contextlib import contextmanager
 from pathlib import Path
 
-_CATEGORIES = ("compute", "comm", "io", "sync", "scheduler", "misc")
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+PREFIX = "areal/"
 
 
-class PerfTracer:
-    def __init__(self, rank: int = 0, save_path: str | None = None, enabled: bool = True):
+class Recorder:
+    """Finished spans of one process, in memory."""
+
+    def __init__(self, rank: int = 0, save_path: str | None = None):
         self.rank = rank
         self.save_path = save_path
-        self.enabled = enabled
-        self._events: list[dict] = []
+        # (id, name, start_ns, end_ns, parent id or None, thread, ids)
+        self.spans: list[tuple] = []
+        self._ids = itertools.count(1)
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
-        if enabled and save_path:
-            atexit.register(self.save)
+        self._open = threading.local()  # .stack: ids of the thread's open spans
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
-
-    @contextmanager
-    def trace_scope(self, name: str, category: str = "compute", **args):
-        if not self.enabled:
-            yield
-            return
-        start = self._now_us()
+    def _stack(self) -> list[int]:
         try:
-            yield
-        finally:
-            end = self._now_us()
-            with self._lock:
-                self._events.append(
-                    dict(
-                        name=name,
-                        cat=category if category in _CATEGORIES else "misc",
-                        ph="X",
-                        ts=start,
-                        dur=end - start,
-                        pid=self.rank,
-                        tid=threading.get_ident() % 100000,
-                        args=args,
-                    )
-                )
+            return self._open.stack
+        except AttributeError:
+            self._open.stack = []
+            return self._open.stack
 
-    # Async (flow) events for cross-thread spans, e.g. a rollout's lifetime.
-    def atrace_begin(self, name: str, aid: str, category: str = "scheduler"):
-        if not self.enabled:
-            return
+    def add(self, name, start_ns, end_ns, parent, ids, sid=None) -> None:
+        row = (sid or next(self._ids), name, int(start_ns), int(end_ns), parent,
+               threading.get_ident(), ids)
         with self._lock:
-            self._events.append(
-                dict(name=name, cat=category, ph="b", id=aid, ts=self._now_us(),
-                     pid=self.rank, tid=0)
-            )
-
-    def atrace_end(self, name: str, aid: str, category: str = "scheduler"):
-        if not self.enabled:
-            return
-        with self._lock:
-            self._events.append(
-                dict(name=name, cat=category, ph="e", id=aid, ts=self._now_us(),
-                     pid=self.rank, tid=0)
-            )
-
-    def instant(self, name: str, category: str = "misc", **args):
-        if not self.enabled:
-            return
-        with self._lock:
-            self._events.append(
-                dict(name=name, cat=category, ph="i", ts=self._now_us(),
-                     pid=self.rank, tid=0, s="p", args=args)
-            )
+            self.spans.append(row)
 
     def save(self, path: str | None = None) -> str | None:
         path = path or self.save_path
-        if not path or not self.enabled:
+        if not path:
             return None
+        with self._lock:
+            spans = list(self.spans)
+        events = [
+            dict(name=name, ph="X", ts=start / 1e3, dur=(end - start) / 1e3,
+                 pid=self.rank, tid=thread % 100000,
+                 args={**ids, "span": sid, "parent": parent})
+            for sid, name, start, end, parent, thread, ids in spans
+        ]
         p = Path(path)
         p.parent.mkdir(parents=True, exist_ok=True)
-        with self._lock:
-            events = list(self._events)
         with open(p, "w") as f:
             json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
         return str(p)
 
-    @staticmethod
-    def merge(rank_files: list[str], out_path: str) -> str:
-        """Merge per-rank trace files into one (reference merges under flock)."""
-        merged: list[dict] = []
-        for rf in rank_files:
-            try:
-                with open(rf) as f:
-                    merged.extend(json.load(f).get("traceEvents", []))
-            except (OSError, json.JSONDecodeError):
-                continue
-        with open(out_path, "w") as f:
-            json.dump({"traceEvents": merged, "displayTimeUnit": "ms"}, f)
-        return out_path
+
+# None: recording is off. _UNREAD: the environment has not been looked at yet.
+_UNREAD = object()
+_recorder: Recorder | None | object = _UNREAD
 
 
-_tracer: PerfTracer | None = None
+def init_from_env(rank: int = 0) -> Recorder | None:
+    """(Re)read AREAL_TPU_PERF_TRACE / AREAL_TPU_PERF_TRACE_DIR. Called on
+    the first span if nobody called it before; a process that knows its rank
+    calls it itself."""
+    global _recorder
+    if os.environ.get("AREAL_TPU_PERF_TRACE", "0") in ("1", "true"):
+        trace_dir = os.environ.get("AREAL_TPU_PERF_TRACE_DIR", "/tmp/areal_tpu/traces")
+        # the pid keeps the processes of one host (trainer, decode servers)
+        # from writing over each other
+        path = os.path.join(trace_dir, f"trace-rank{rank}-{os.getpid()}.json")
+        _recorder = Recorder(rank, path)
+        atexit.register(_recorder.save)
+    else:
+        _recorder = None
+    return _recorder
 
 
-def init_from_env(rank: int = 0) -> PerfTracer:
-    """Initialise the global tracer from AREAL_TPU_PERF_TRACE* env vars."""
-    global _tracer
-    enabled = os.environ.get("AREAL_TPU_PERF_TRACE", "0") in ("1", "true")
-    trace_dir = os.environ.get("AREAL_TPU_PERF_TRACE_DIR", "/tmp/areal_tpu/traces")
-    path = os.path.join(trace_dir, f"trace-rank{rank}.json") if enabled else None
-    _tracer = PerfTracer(rank=rank, save_path=path, enabled=enabled)
-    return _tracer
+def recorder() -> Recorder | None:
+    """The process's recorder, or None when recording is off."""
+    return init_from_env() if _recorder is _UNREAD else _recorder
 
 
-def get() -> PerfTracer:
-    global _tracer
-    if _tracer is None:
-        _tracer = init_from_env()
-    return _tracer
+class span:
+    """`with span("decode/dispatch_chunk", chunk=n): ...`"""
+
+    __slots__ = ("name", "ids", "_step", "_ann", "_rec", "_sid", "_parent", "_t0")
+
+    def __init__(self, name: str, **ids):
+        self.name = name
+        self.ids = ids
+        self._step = None
+
+    def __enter__(self):
+        # (an annotation starts when it is made, so it is made here)
+        if self._step is None:
+            self._ann = TraceAnnotation(PREFIX + self.name, **self.ids)
+        else:
+            self._ann = StepTraceAnnotation(PREFIX + self.name,
+                                            step_num=self._step, **self.ids)
+        self._ann.__enter__()
+        rec = self._rec = recorder()
+        if rec is not None:
+            stack = rec._stack()
+            self._parent = stack[-1] if stack else None
+            self._sid = next(rec._ids)
+            stack.append(self._sid)
+            self._t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        rec = self._rec
+        if rec is not None:
+            t1 = time.monotonic_ns()
+            rec._stack().pop()
+            rec.add(self.name, self._t0, t1, self._parent, self.ids, self._sid)
+        self._ann.__exit__(*exc)
+        return False
 
 
-def trace_scope(name: str, category: str = "compute", **args):
-    return get().trace_scope(name, category, **args)
+def step_span(name: str, step: int, **ids) -> span:
+    """A span the profiler's tools group by step (`StepTraceAnnotation`)."""
+    s = span(name, step=step, **ids)
+    s._step = step
+    return s
+
+
+def record(name: str, start: float, end: float, **ids) -> None:
+    """A span known only after the fact (a request's wait in the queue is
+    known at admission): `start` and `end` are `time.monotonic()` seconds. It
+    goes into the in-memory record only, with no parent."""
+    rec = recorder()
+    if rec is not None:
+        rec.add(name, start * 1e9, end * 1e9, None, ids)
 
 
 # ---------------------------------------------------------------------------
-# XLA-level profiling (xprof). The catapult tracer above captures HOST-side
-# scheduling; device kernel timelines come from jax.profiler, which writes
-# tensorboard/xplane traces (the TPU counterpart of the reference's kineto/
-# perfetto CUDA kernel stats, realhf/base/monitor.py:428). Enable per-run
-# with AREAL_TPU_XPROF_DIR=/path or scoped via `xprof_trace()`.
+# Device traces of a real run. AREAL_TPU_XPROF_DIR=/path makes the train
+# engine capture a jax.profiler trace of the steps AREAL_TPU_XPROF_STEPS
+# (default "2-4", inclusive, after the warm-up compiles); the spans above are
+# in it. Read it with `python tools/trace_report.py /path`, xprof or Perfetto.
 # ---------------------------------------------------------------------------
-
-
-@contextmanager
-def xprof_trace(log_dir: str | None = None):
-    """Capture a jax.profiler device trace around the enclosed block.
-
-    No-op when no directory is configured (arg or AREAL_TPU_XPROF_DIR) —
-    profiling stays opt-in and free when off."""
-    import jax
-
-    target = log_dir or os.environ.get("AREAL_TPU_XPROF_DIR")
-    if not target:
-        yield None
-        return
-    os.makedirs(target, exist_ok=True)
-    jax.profiler.start_trace(target)
-    try:
-        yield target
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named device-trace region (shows up in xprof timelines); safe and
-    ~free when no trace is active."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
-
 
 # jax.profiler supports ONE process-global trace; "owner" records which
 # engine claimed the window so co-resident engines (PPO actor + critic
@@ -192,20 +177,17 @@ def _xprof_flush() -> None:
 
 
 def maybe_xprof_step(step: int, owner: object = None) -> None:
-    """Env-gated capture window for training loops: with
-    AREAL_TPU_XPROF_DIR set, starts a jax.profiler trace at the first step
-    of AREAL_TPU_XPROF_STEPS (default "2-4", inclusive, after warmup
-    compiles) and stops it after the last. Called by the train engine at
-    the top of every train_batch; free when the env var is unset.
+    """Called by the train engine at the top of every train_batch; free when
+    AREAL_TPU_XPROF_DIR is unset.
 
     `owner` identifies the calling engine; the window is claimed by the
     first owner to reach the start step and only that owner's step counter
     advances/ends it."""
-    import jax
-
     target = os.environ.get("AREAL_TPU_XPROF_DIR")
     if not target or _xprof_state["done"]:
         return
+    import jax
+
     lo, _, hi = os.environ.get("AREAL_TPU_XPROF_STEPS", "2-4").partition("-")
     lo, hi = int(lo), int(hi or lo)
     if not _xprof_state["active"] and lo <= step <= hi:

@@ -23,6 +23,8 @@ from threading import Lock
 
 import numpy as np
 
+from areal_tpu.utils import perf_tracer
+
 
 class ReduceType(Enum):
     AVG_MIN_MAX = auto()
@@ -82,9 +84,11 @@ class DistributedStatsTracker:
     # -- recording ------------------------------------------------------
     @contextmanager
     def record_timing(self, key: str):
+        """The `timeperf/<key>` series, and the span `step/<key>`."""
         start = time.perf_counter()
         try:
-            yield
+            with perf_tracer.span("step/" + key):
+                yield
         finally:
             with self.lock:
                 full_key = f"timeperf/{key}"
@@ -112,10 +116,12 @@ class DistributedStatsTracker:
 
     def stat(
         self,
-        denominator: str,
+        denominator: str | None,
         reduce_type: ReduceType | None = None,
         **kwargs,
     ):
+        """`denominator=None`: every element counts (one call under one lock,
+        which a thread other than the exporting one needs)."""
         with self.lock:
             for key, value in kwargs.items():
                 arr = _to_numpy(value).astype(np.float32)
@@ -124,24 +130,25 @@ class DistributedStatsTracker:
                 if reduce_type == ReduceType.SCALAR:
                     raise ValueError("cannot use SCALAR reduce type for an array")
                 full_key = self._full_key(key)
-                denom_key = self._full_key(denominator)
-                if denom_key not in self.stats:
-                    raise ValueError(
-                        f"denominator `{denom_key}` does not exist; record it first"
-                    )
-                denom = self.stats[denom_key][-1]
-                if denom.shape != arr.shape:
-                    raise ValueError(
-                        f"shape mismatch between `{full_key}` {arr.shape} and "
-                        f"denominator `{denom_key}` {denom.shape}"
-                    )
-                self.denominators[full_key] = denom_key
+                if denominator is not None:
+                    denom_key = self._full_key(denominator)
+                    if denom_key not in self.stats:
+                        raise ValueError(
+                            f"denominator `{denom_key}` does not exist; record it first"
+                        )
+                    denom = self.stats[denom_key][-1]
+                    if denom.shape != arr.shape:
+                        raise ValueError(
+                            f"shape mismatch between `{full_key}` {arr.shape} and "
+                            f"denominator `{denom_key}` {denom.shape}"
+                        )
+                    self.denominators[full_key] = denom_key
+                    self._denom_snapshots[full_key].append(denom)
                 if reduce_type is not None:
                     self._set_reduce_type(full_key, reduce_type)
                 elif full_key not in self.reduce_types:
                     self._set_reduce_type(full_key, ReduceType.AVG_MIN_MAX)
                 self.stats[full_key].append(arr)
-                self._denom_snapshots[full_key].append(denom)
 
     def _set_reduce_type(self, key: str, reduce_type: ReduceType):
         if not isinstance(reduce_type, ReduceType):
@@ -242,9 +249,14 @@ def scalar(**kwargs):
     return DEFAULT.scalar(**kwargs)
 
 
-def stat(denominator: str, reduce_type: ReduceType | None = None, **kwargs):
+def stat(denominator: str | None, reduce_type: ReduceType | None = None, **kwargs):
     return DEFAULT.stat(denominator, reduce_type, **kwargs)
 
 
 def export_all(reduce_fn=None, reset=True) -> dict[str, float]:
-    return DEFAULT.export(reduce_fn=reduce_fn, reset=reset)
+    """Every tracker's stats: the default one and the named ones (`get`),
+    each of which prefixes its keys with its name."""
+    out: dict[str, float] = {}
+    for tracker in list(_trackers.values()):
+        out.update(tracker.export(reduce_fn=reduce_fn, reset=reset))
+    return out

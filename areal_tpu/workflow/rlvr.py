@@ -20,7 +20,7 @@ from areal_tpu.api.cli_args import GenerationHyperparameters
 from areal_tpu.api.io_struct import ModelRequest
 from areal_tpu.api.reward_api import AsyncRewardWrapper
 from areal_tpu.api.workflow_api import RolloutWorkflow
-from areal_tpu.utils import logging
+from areal_tpu.utils import logging, stats_tracker
 from areal_tpu.utils.data import pad_sequences_to_tensors
 
 logger = logging.getLogger("rlvr")
@@ -73,6 +73,15 @@ class RLVRWorkflow(RolloutWorkflow):
         )
 
         version = engine.get_version()
+        # the traffic as it really is: rollout/prompt_len and
+        # rollout/output_len (avg, min, max per export) of every sample.
+        # A tracker of its own: episodes run on the rollout thread, and the
+        # default tracker's scopes belong to the training thread.
+        stats_tracker.get("rollout").stat(
+            None,
+            prompt_len=np.array([r.input_len for r in resps], np.float32),
+            output_len=np.array([r.output_len for r in resps], np.float32),
+        )
         results = []
         for resp in resps:
             seq = resp.input_tokens + resp.output_tokens

@@ -1,80 +1,199 @@
-"""PerfTracer catapult emitter + xprof hooks (ref: areal/tests/
-test_perf_tracer.py over areal/utils/perf_tracer.py)."""
+"""`perf_tracer.span`, the one primitive the program marks time with: always
+a `TraceAnnotation`, and with AREAL_TPU_PERF_TRACE=1 also a record in memory
+that is written as Chrome JSON. `maybe_xprof_step` is the operator's way to a
+device trace of a real run."""
 
+import glob
 import json
 import os
+import threading
+import time
 
-from areal_tpu.utils import perf_tracer
-from areal_tpu.utils.perf_tracer import PerfTracer
+import pytest
 
-
-def test_scopes_async_and_instant_round_trip(tmp_path):
-    out = str(tmp_path / "t.json")
-    tr = PerfTracer(rank=3, save_path=out)
-    with tr.trace_scope("fwd", "compute", step=1):
-        pass
-    tr.atrace_begin("rollout", "r1")
-    tr.atrace_end("rollout", "r1")
-    tr.instant("weights_pushed", "comm", version=2)
-    with tr.trace_scope("oddcat", "not-a-category"):
-        pass
-    assert tr.save() == out
-    events = json.load(open(out))["traceEvents"]
-    by_ph = {}
-    for e in events:
-        by_ph.setdefault(e["ph"], []).append(e)
-    assert len(by_ph["X"]) == 2 and by_ph["X"][0]["name"] == "fwd"
-    assert by_ph["X"][0]["args"] == {"step": 1}
-    assert by_ph["X"][1]["cat"] == "misc"  # unknown category folded
-    assert [e["ph"] for e in by_ph["b"] + by_ph["e"]] == ["b", "e"]
-    assert by_ph["i"][0]["args"]["version"] == 2
-    assert all(e["pid"] == 3 for e in events)
+from areal_tpu.utils import perf_tracer, stats_tracker
 
 
-def test_disabled_tracer_is_free_and_saves_nothing(tmp_path):
-    tr = PerfTracer(rank=0, save_path=str(tmp_path / "x.json"), enabled=False)
-    with tr.trace_scope("a"):
-        pass
-    tr.instant("b")
-    assert tr.save() is None
-    assert not os.path.exists(tmp_path / "x.json")
+@pytest.fixture
+def recording(tmp_path, monkeypatch):
+    """Recording on, into tmp_path; off again (and nothing left registered
+    in the module) afterwards."""
+    monkeypatch.setenv("AREAL_TPU_PERF_TRACE", "1")
+    monkeypatch.setenv("AREAL_TPU_PERF_TRACE_DIR", str(tmp_path))
+    rec = perf_tracer.init_from_env(rank=0)
+    yield rec
+    monkeypatch.setenv("AREAL_TPU_PERF_TRACE", "0")
+    perf_tracer.init_from_env()
 
 
-def test_merge_ranks(tmp_path):
-    files = []
-    for r in (0, 1):
-        tr = PerfTracer(rank=r, save_path=str(tmp_path / f"r{r}.json"))
-        with tr.trace_scope(f"work{r}"):
+@pytest.fixture
+def not_recording(monkeypatch):
+    monkeypatch.setenv("AREAL_TPU_PERF_TRACE", "0")
+    assert perf_tracer.init_from_env() is None
+
+
+def _by_name(rec):
+    return {row[1]: row for row in rec.spans}
+
+
+def test_nesting_gives_each_span_its_parent(recording):
+    with perf_tracer.span("outer"):
+        with perf_tracer.span("inner"):
+            with perf_tracer.span("leaf"):
+                pass
+        with perf_tracer.span("second"):
             pass
-        files.append(tr.save())
-    merged = PerfTracer.merge(
-        files + [str(tmp_path / "missing.json")], str(tmp_path / "m.json")
-    )
-    events = json.load(open(merged))["traceEvents"]
-    assert {e["pid"] for e in events} == {0, 1}
+    rows = _by_name(recording)
+    outer_id = rows["outer"][0]
+    assert rows["outer"][4] is None
+    assert rows["inner"][4] == outer_id and rows["second"][4] == outer_id
+    assert rows["leaf"][4] == rows["inner"][0]
+    # a child lies inside its parent on the clock
+    assert rows["outer"][2] <= rows["inner"][2] <= rows["inner"][3] <= rows["outer"][3]
+
+
+def test_ids_are_kept_with_the_span(recording):
+    with perf_tracer.span("decode/dispatch_chunk", chunk=7, active=128, version=3):
+        pass
+    assert _by_name(recording)["decode/dispatch_chunk"][6] == {
+        "chunk": 7, "active": 128, "version": 3}
+
+
+def test_record_after_the_fact_has_no_parent_and_the_given_times(recording):
+    t1 = time.monotonic()
+    with perf_tracer.span("around"):
+        perf_tracer.record("request/queue", t1 - 2.5, t1, rid="r1", slot=4)
+    row = _by_name(recording)["request/queue"]
+    assert row[4] is None and row[6] == {"rid": "r1", "slot": 4}
+    assert row[3] - row[2] == pytest.approx(2.5e9, abs=1e3)
+    assert row[3] == pytest.approx(t1 * 1e9, abs=1e3)
+
+
+def test_threads_keep_their_own_stacks(recording):
+    started, release = threading.Event(), threading.Event()
+
+    def worker():
+        with perf_tracer.span("worker/outer"):
+            started.set()
+            assert release.wait(10)
+            with perf_tracer.span("worker/inner"):
+                pass
+
+    t = threading.Thread(target=worker)
+    with perf_tracer.span("main/outer"):
+        t.start()
+        assert started.wait(10)
+        with perf_tracer.span("main/inner"):  # opened while worker/outer is open
+            pass
+        release.set()
+        t.join(10)
+        assert not t.is_alive()
+    rows = _by_name(recording)
+    assert rows["main/inner"][4] == rows["main/outer"][0]
+    assert rows["worker/inner"][4] == rows["worker/outer"][0]
+    assert rows["worker/outer"][4] is None
+    assert rows["worker/inner"][5] != rows["main/inner"][5]  # thread ids
+
+
+def test_an_exception_closes_the_span_and_restores_the_stack(recording):
+    with pytest.raises(ValueError):
+        with perf_tracer.span("fails"):
+            raise ValueError("x")
+    with perf_tracer.span("after"):
+        pass
+    rows = _by_name(recording)
+    assert "fails" in rows and rows["after"][4] is None
+
+
+def test_off_keeps_nothing_and_writes_nothing(tmp_path, monkeypatch, not_recording):
+    monkeypatch.setenv("AREAL_TPU_PERF_TRACE_DIR", str(tmp_path))
+    with perf_tracer.span("a", step=1):
+        with perf_tracer.step_span("b", 2):
+            pass
+    perf_tracer.record("c", 0.0, 1.0)
+    assert perf_tracer.recorder() is None
+    assert os.listdir(tmp_path) == []
+
+
+def test_save_writes_chrome_json_with_ids_and_parents(recording, tmp_path):
+    with perf_tracer.span("train/ppo_update", step=5):
+        with perf_tracer.span("train/minibatch", step=5, minibatch=0):
+            time.sleep(0.002)
+    path = recording.save()
+    assert path == recording.save_path and path.startswith(str(tmp_path))
+    assert os.path.basename(path) == f"trace-rank0-{os.getpid()}.json"
+    events = {e["name"]: e for e in json.load(open(path))["traceEvents"]}
+    up, mb = events["train/ppo_update"], events["train/minibatch"]
+    assert up["ph"] == "X" and up["pid"] == 0 and up["args"]["step"] == 5
+    assert mb["args"]["parent"] == up["args"]["span"] and mb["args"]["minibatch"] == 0
+    assert mb["dur"] >= 2000 and up["ts"] <= mb["ts"]  # microseconds
+
+
+def test_record_timing_emits_the_step_span_and_the_series(recording):
+    with stats_tracker.record_timing("train_step"):
+        with perf_tracer.span("train/ppo_update"):
+            pass
+    rows = _by_name(recording)
+    assert rows["train/ppo_update"][4] == rows["step/train_step"][0]
+    assert stats_tracker.export_all()["timeperf/train_step"] >= 0.0
+
+
+def test_step_span_carries_the_step(recording):
+    with perf_tracer.step_span("train/train_batch", 12, tokens=8192):
+        pass
+    assert _by_name(recording)["train/train_batch"][6] == {"step": 12, "tokens": 8192}
 
 
 def test_init_from_env(tmp_path, monkeypatch):
     monkeypatch.setenv("AREAL_TPU_PERF_TRACE", "1")
     monkeypatch.setenv("AREAL_TPU_PERF_TRACE_DIR", str(tmp_path))
-    tr = perf_tracer.init_from_env(rank=5)
-    assert tr.enabled and tr.save_path.endswith("trace-rank5.json")
+    rec = perf_tracer.init_from_env(rank=5)
+    assert rec is perf_tracer.recorder() and rec.rank == 5
+    assert rec.save_path == str(tmp_path / f"trace-rank5-{os.getpid()}.json")
     monkeypatch.setenv("AREAL_TPU_PERF_TRACE", "0")
-    tr = perf_tracer.init_from_env(rank=5)
-    assert not tr.enabled
+    assert perf_tracer.init_from_env(rank=5) is None
+    assert perf_tracer.recorder() is None
 
 
-def test_xprof_trace_noop_without_dir(monkeypatch):
-    monkeypatch.delenv("AREAL_TPU_XPROF_DIR", raising=False)
-    with perf_tracer.xprof_trace() as t:
-        assert t is None
+def test_the_environment_is_read_on_the_first_span(tmp_path, monkeypatch):
+    monkeypatch.setenv("AREAL_TPU_PERF_TRACE", "1")
+    monkeypatch.setenv("AREAL_TPU_PERF_TRACE_DIR", str(tmp_path))
+    monkeypatch.setattr(perf_tracer, "_recorder", perf_tracer._UNREAD)
+    with perf_tracer.span("first"):
+        pass
+    try:
+        assert [row[1] for row in perf_tracer.recorder().spans] == ["first"]
+    finally:
+        monkeypatch.setenv("AREAL_TPU_PERF_TRACE", "0")
+        perf_tracer.init_from_env()
+
+
+def test_spans_are_on_a_running_profile(tmp_path, not_recording):
+    """With a profile running a span is in the trace under `areal/<name>`,
+    whether or not recording is on: that is what puts the program's phases
+    on the device trace's clock."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with perf_tracer.span("decode/consume_chunk", chunk=3):
+            jax.block_until_ready(jax.jit(lambda x: x * 2)(jnp.ones(16)))
+        with perf_tracer.step_span("train/train_batch", 4):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = glob.glob(str(tmp_path) + "/**/*.xplane.pb", recursive=True)
+    names = {e.name for p in ProfileData.from_file(pb).planes
+             for line in p.lines for e in line.events}
+    assert "areal/decode/consume_chunk" in names
+    assert any(n.startswith("areal/train/train_batch") for n in names)
 
 
 def test_maybe_xprof_step_window(tmp_path, monkeypatch):
     """The env-gated window starts at the first configured step and stops
     exactly once after the last — captured via the real jax profiler."""
-    import glob
-
     monkeypatch.setenv("AREAL_TPU_XPROF_DIR", str(tmp_path))
     monkeypatch.setenv("AREAL_TPU_XPROF_STEPS", "1-2")
     monkeypatch.setitem(perf_tracer._xprof_state, "active", False)
@@ -88,3 +207,20 @@ def test_maybe_xprof_step_window(tmp_path, monkeypatch):
     assert perf_tracer._xprof_state["done"]
     assert not perf_tracer._xprof_state["active"]
     assert glob.glob(str(tmp_path) + "/**/*.xplane.pb", recursive=True)
+
+
+def test_trace_report_reads_a_span_record(recording, tmp_path):
+    """tools/trace_report.py on the Chrome JSON: count, total and self time."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from tools import trace_report
+
+    t = time.monotonic()
+    perf_tracer.record("train/ppo_update", t, t + 2.0)
+    perf_tracer.record("train/minibatch", t + 0.5, t + 1.0)
+    perf_tracer.record("train/minibatch", t + 1.0, t + 1.25)
+    rows = {r[0]: r for r in trace_report.report(recording.save())["spans"]}
+    assert rows["areal/train/minibatch"][1:3] == (2, pytest.approx(0.75, abs=1e-5))
+    assert rows["areal/train/ppo_update"][1] == 1
+    assert rows["areal/train/ppo_update"][3] == pytest.approx(1.25, abs=1e-5)  # self

@@ -1,0 +1,243 @@
+"""The names a device trace shows: every jitted program's module name, the
+`jax.named_scope` path of what runs inside it, and (compiled for a described
+v5e, no chip) the Pallas kernels' custom calls. A reader of PERF.md section 5
+and every `device_op_time` / `device_module_time` metric file match on these,
+so a refactor that loses one fails here and not in a benchmark run."""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from areal_tpu.api.alloc_mode import ParallelStrategy
+from areal_tpu.api.cli_args import (
+    GenerationHyperparameters,
+    InferenceEngineConfig,
+    JaxDecodeConfig,
+    MicroBatchSpec,
+    OptimizerConfig,
+    PPOActorConfig,
+)
+from areal_tpu.api.io_struct import FinetuneSpec, ModelRequest
+from areal_tpu.models.qwen2 import ModelConfig, init_params
+
+TINY = ModelConfig(
+    vocab_size=64, hidden_size=32, intermediate_size=64, num_hidden_layers=2,
+    num_attention_heads=4, num_key_value_heads=2, dtype="float32",
+    param_dtype="float32",
+)
+
+# every program of the decode engine and the trainer, as `XLA Modules` shows
+# them (`jit_<function name>`); the benchmark's patterns are `^jit_chunk`,
+# `^jit_grad_step`, `^jit_fwd_step`, `^jit_apply_update`, `^jit_prefill`
+PROGRAMS = (
+    "chunk", "verify_chunk", "prefill_batched", "prefill_suffix", "prefill_embed",
+    "patch", "fork_block", "fwd_step", "grad_step", "apply_update", "zero_grads",
+)
+
+
+class _JitSpy:
+    """Stands in for `jax.jit`: notes each program's name when it is made,
+    and its lowered text (with debug info: the scope paths) at its first
+    call."""
+
+    def __init__(self):
+        self.real = jax.jit
+        self.made: list[str] = []
+        self.text: dict[str, str] = {}
+
+    def __call__(self, fn, **kw):
+        jitted = self.real(fn, **kw)
+        name = getattr(fn, "__name__", "<unnamed>")
+        self.made.append(name)
+        spy = self
+
+        class Program:
+            def __call__(self, *a, **k):
+                if name not in spy.text:
+                    spy.text[name] = jitted.lower(*a, **k).as_text(debug_info=True)
+                return jitted(*a, **k)
+
+            def __getattr__(self, attr):
+                return getattr(jitted, attr)
+
+        return Program()
+
+
+@pytest.fixture(scope="module")
+def programs(cpu_devices):
+    """Runs a tiny decode engine (in-pool paged chunk, as on the chip) and a
+    tiny PPO trainer once, with every `jax.jit` of theirs observed."""
+    from areal_tpu.engine.jax_decode import JaxDecodeEngine
+    from areal_tpu.engine.ppo.actor import JaxPPOActor
+
+    spy = _JitSpy()
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax, "jit", spy)
+    try:
+        eng = JaxDecodeEngine(
+            JaxDecodeConfig(context_length=256, max_running_requests=4,
+                            new_tokens_per_chunk=4, page_size=128, dtype="float32",
+                            kv_cache_dtype="float32", paged_attn_impl="pallas"),
+            InferenceEngineConfig())
+        eng.set_model(init_params(TINY, jax.random.PRNGKey(0)), TINY)
+        eng.initialize()
+        try:
+            eng.generate(ModelRequest(
+                input_ids=[1, 5, 9, 13, 2],
+                gconfig=GenerationHyperparameters(greedy=True, max_new_tokens=6)),
+                timeout=300)
+            # the programs this request did not need are made, not run
+            eng._get_verify_fn(False, 1, 3)
+            eng._get_suffix_prefill_fn(64, 64, 1)
+            eng._get_embed_prefill_fn(64, 4)
+            eng._get_block_copy_fn()
+            eng._get_patch_fn()
+        finally:
+            eng.destroy()
+
+        actor = JaxPPOActor(PPOActorConfig(
+            experiment_name="t", trial_name="t", path="", init_from_scratch=True,
+            dtype="float32", mb_spec=MicroBatchSpec(max_tokens_per_mb=512),
+            optimizer=OptimizerConfig(lr=5e-3, warmup_steps_proportion=0.0,
+                                      lr_scheduler_type="constant"),
+            gradient_checkpointing=True, group_size=2, ppo_n_minibatches=1,
+            kl_ctl=0.0, use_decoupled_loss=False, recompute_logprob=True))
+        actor.model_config = TINY
+        actor.create_process_group(ParallelStrategy(data_parallel_size=8))
+        actor.initialize(None, FinetuneSpec(1, 64, 8))
+        try:
+            B, T = 4, 8
+            ids = np.tile(np.arange(1, T + 1, dtype=np.int64), (B, 1))
+            batch = dict(
+                input_ids=ids, attention_mask=np.ones((B, T), np.int64),
+                loss_mask=np.pad(np.ones((B, 5), np.int64), ((0, 0), (3, 0))),
+                rewards=np.array([1.0, 0.0, 1.0, 0.0], np.float32),
+                logprobs=np.zeros((B, T), np.float32))
+            batch["prox_logp"] = actor.compute_logp(batch)
+            actor.compute_advantages(batch)
+            stats = actor.ppo_update(batch)
+        finally:
+            actor.destroy()
+    finally:
+        mp.undo()
+    return spy, stats
+
+
+def test_every_program_has_its_pinned_name(programs):
+    spy, _ = programs
+    assert set(PROGRAMS) <= set(spy.made), sorted(set(PROGRAMS) - set(spy.made))
+    assert "<lambda>" not in spy.made and "<unnamed>" not in spy.made
+    for name in ("chunk", "prefill_batched", "fwd_step", "grad_step", "apply_update"):
+        assert f"@jit_{name}" in spy.text[name], name
+
+
+SCOPES = {
+    # the decode chunk: the scan body, the model step inside it, the pool
+    # (the lowered text names the layer scan's body relative to itself)
+    "chunk": ["decode_step", "decode_step/embed", "layer/attn/qkv", "layer/attn/rope",
+              "layer/attn/kv_write/pool_write",
+              "layer/attn/attention/pool_read", "layer/attn/attention/paged_attention",
+              "layer/attn/o_proj", "layer/mlp", "decode_step/final_norm",
+              "decode_step/lm_head", "decode_step/sample"],
+    "prefill_batched": ["embed", "layer/attn/qkv", "layer/attn/rope",
+                        "layer/attn/attention", "layer/attn/o_proj", "layer/mlp"],
+    "fwd_step": ["embed", "layer/attn/qkv", "layer/attn/rope", "layer/attn/attention",
+                 "layer/attn/o_proj", "layer/mlp", "final_norm", "xent"],
+    "grad_step": ["embed", "layer/attn/qkv", "layer/attn/attention", "layer/attn/o_proj",
+                  "layer/mlp", "final_norm", "xent", "loss", "grad_accum"],
+    "apply_update": ["grad_norm", "optimizer"],
+}
+
+
+def _has_scope(text: str, path: str) -> bool:
+    """Some operation lies under the scope path; autodiff and vmap wrap each
+    component (`transpose(jvp(layer))`, `vmap(embed)`)."""
+    parts = [r"(?:\w+\()*" + re.escape(c) + r"\)*" for c in path.split("/")]
+    return re.search(r'[/"]' + "/".join(parts) + "/", text) is not None
+
+
+@pytest.mark.parametrize("program", sorted(SCOPES))
+def test_program_holds_its_scopes(programs, program):
+    spy, _ = programs
+    missing = [s for s in SCOPES[program] if not _has_scope(spy.text[program], s)]
+    assert not missing, f"{program}: no operation under {missing}"
+
+
+def test_train_batch_counts_padding_and_first_calls(programs):
+    _, stats = programs
+    s = stats[0]
+    # 4 sequences of 8 tokens: 32 real ones in a micro-batch padded to a bucket
+    assert s["n_tokens"] == 32.0 and s["padded_tokens"] >= s["n_tokens"]
+    assert s["padded_tokens"] % 128 == 0
+    # zero_grads, grad_step and apply_update ran for the first time
+    assert s["compiles"] == 3
+
+
+# ---------------------------------------------------------------------------
+# Compiled for a described v5e (no chip): Mosaic accepts the kernels at the
+# benchmark's head shapes, and the custom calls carry the kernels' names.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps the topology from being described
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).compile().as_text()
+
+
+def test_paged_kernel_is_named_at_the_1p5b_head_shape(one_chip):
+    from areal_tpu.ops.paged_attention import paged_attention
+
+    R, nH, nKV, hd, bsz, nb, n_blocks = 128, 12, 2, 128, 128, 10, 1281
+
+    def step(q, kp, vp, bt, valid):
+        with jax.named_scope("layer"):
+            return paged_attention(q, kp, vp, bt, valid, impl="pallas", interpret=False)
+
+    hlo = _compile(
+        step, one_chip, ((R, nH, hd), jnp.bfloat16),
+        ((n_blocks, bsz, nKV, hd), jnp.bfloat16), ((n_blocks, bsz, nKV, hd), jnp.bfloat16),
+        ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_))
+    assert "tpu_custom_call" in hlo
+    assert "%paged_attention" in hlo and "%layer" not in hlo
+
+
+def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):
+    from areal_tpu.ops.flash_attention import flash_attention
+
+    T, nH, nKV, hd = 8192, 14, 2, 64
+
+    def loss(q, k, v, seg):
+        return flash_attention(q, k, v, seg, interpret=False).astype(jnp.float32).sum()
+
+    def step(q, k, v, seg):
+        return jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2))(q, k, v, seg)
+
+    hlo = _compile(
+        step, one_chip, ((T, nH, hd), jnp.bfloat16), ((T, nKV, hd), jnp.bfloat16),
+        ((T, nKV, hd), jnp.bfloat16), ((T,), jnp.int32))
+    for kernel in ("%flash_fwd", "%flash_dq", "%flash_dkv"):
+        assert kernel in hlo, kernel
+    # what the transformations around a kernel used to name it
+    for wrapper in ("%checkpoint", "%rematted_computation", "%closed_call"):
+        assert wrapper not in hlo, wrapper

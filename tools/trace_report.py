@@ -1,0 +1,379 @@
+"""What a device trace, or a span record, says about one run.
+
+    python tools/trace_report.py <path> [--gap-ms 1.0] [--top 20]
+
+`<path>` is a directory the JAX profiler wrote into (`AREAL_TPU_XPROF_DIR` of
+a real run, `.bench_work/<cell>/trace` of a benchmark run with `--trace 1`),
+an `.xplane.pb`, or the Chrome JSON that `AREAL_TPU_PERF_TRACE=1` leaves in
+`AREAL_TPU_PERF_TRACE_DIR`. From a device trace it prints
+
+  device     busy and idle share of the window, per chip
+  kernels    device time by operation name (`%flash_fwd`, `%paged_attention`,
+             `%fusion`, ...): inclusive, and what is not spent in nested operations
+  scopes     device self time by the program's `jax.named_scope` path, from
+             the `op_name` the trace keeps in each operation's metadata
+  spans      the program's `areal/` spans (and the benchmark's `bench/`): count,
+             total and self time (total minus the spans nested in it)
+  idle gaps  every gap of chip 0 over `--gap-ms`, summed by the innermost
+             `areal/` span open at its middle on any host thread
+
+and from a span record the spans table alone. Device and host events of one
+trace share a clock. The arithmetic on intervals is the benchmark's
+(`benchmark/lib/xplane.py`: `load`, `union`, `busy`); what this file adds is
+what a later benchmark PR lifts into readers.
+"""
+
+from __future__ import annotations
+
+import argparse
+import gzip
+import json
+import os
+import re
+import sys
+from collections import defaultdict
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.lib import xplane  # noqa: E402
+
+PROGRAM_PREFIX = "areal/"
+PREFIXES = (PROGRAM_PREFIX, xplane.SPAN_PREFIX)
+# name-stack components that are transformations, not the program's scopes
+_NOISE = re.compile(r"^(main|while|body|cond|closed_call|checkpoint|rematted_computation|"
+                    r"branch_\d+_fun|pjit|core_call|custom_jvp_call|custom_vjp_call(_jaxpr)?|"
+                    r"shard_map|remat\d*|scan)$")
+
+
+def _keep(name: str) -> bool:
+    return name.startswith(PREFIXES)
+
+
+def load(path: str) -> tuple[dict, str | None]:
+    """(trace in `xplane.load`'s structure, the .xplane.pb it came from)."""
+    if path.endswith((".json", ".json.gz")):
+        opener = gzip.open if path.endswith(".gz") else open
+        with opener(path, "rt") as f:
+            data = json.load(f)
+        if "planes" in data:  # a recorded trace kept as JSON (tests)
+            return data, None
+        return spans_record(data), None
+    pb = path if path.endswith(".pb") else xplane.find_xplane(path)
+    return xplane.load(pb, keep_host=_keep), pb
+
+
+def spans_record(chrome: dict) -> dict:
+    """`perf_tracer`'s Chrome JSON as one host plane, a line per thread."""
+    lines: dict[int, list] = defaultdict(list)
+    for e in chrome.get("traceEvents", []):
+        if e.get("ph") == "X":
+            lines[e["tid"]].append([PROGRAM_PREFIX + e["name"], e["ts"] * 1e3, e["dur"] * 1e3])
+    return {"planes": [{"name": "/host:CPU", "lines": [
+        {"name": f"thread {tid}", "events": evs} for tid, evs in sorted(lines.items())]}]}
+
+
+def scope_of(op_name: str) -> str:
+    """`jit(chunk)/while/body/closed_call/decode_step/layer/attn/...` ->
+    `chunk/decode_step/layer/attn/...`; the backward of a scope (its
+    `transpose(jvp(...))`) gets a `bwd:` in front."""
+    out = []
+    for part in op_name.rstrip(":").split("/"):
+        bwd = part.startswith("transpose(")
+        part = re.sub(r"^(?:transpose\(|jvp\(|jit\(|vmap\()+", "", part).rstrip(")")
+        if not part or _NOISE.match(part):
+            continue
+        out.append(("bwd:" if bwd else "") + part)
+    return "/".join(out)
+
+
+def _varint(buf: memoryview, i: int) -> tuple[int, int]:
+    val = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        val |= (byte & 0x7F) << shift
+        shift += 7
+        if byte < 0x80:
+            return val, i
+
+
+def _fields(buf: memoryview):
+    """(field number, wire type, value) of one protobuf message: a varint's
+    value, or the bytes of a length-delimited field. Enough of the wire format
+    for the profiler's XSpace, whose Python reader (`ProfileData`) gives an
+    event's own stats but not those of its metadata, where `op_name` is."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        field, wire = key >> 3, key & 7
+        if wire == 0:
+            val, i = _varint(buf, i)
+            yield field, wire, val
+        else:
+            if wire == 2:
+                size, i = _varint(buf, i)
+            else:
+                size = {1: 8, 5: 4}[wire]
+            yield field, wire, buf[i:i + size]
+            i += size
+
+
+def _map_value(entry: memoryview) -> memoryview | None:
+    return next((v for f, w, v in _fields(entry) if f == 2 and w == 2), None)
+
+
+def op_names(pb: str) -> dict[str, dict[str, str]]:
+    """{device plane: {an operation's name in the trace (its HLO line): its
+    `op_name`, the JAX name stack}}, from the planes' event metadata
+    (XPlane.event_metadata[..].stats, the stat called `tf_op`)."""
+    with open(pb, "rb") as f:
+        space = memoryview(f.read())
+    out: dict[str, dict[str, str]] = {}
+    for f_no, wire, plane in _fields(space):
+        if f_no != 1 or wire != 2:
+            continue
+        name, events, stat_names = "", [], {}
+        for f2, w2, v in _fields(plane):
+            if f2 == 2 and w2 == 2:
+                name = bytes(v).decode()
+            elif f2 == 4 and w2 == 2:  # map<int64, XEventMetadata>
+                events.append(_map_value(v))
+            elif f2 == 5 and w2 == 2:  # map<int64, XStatMetadata>
+                meta = dict((f3, v3) for f3, _, v3 in _fields(_map_value(v)))
+                stat_names[meta.get(1)] = bytes(meta.get(2, b"")).decode()
+        if not xplane.DEVICE_PLANE.match(name):
+            continue
+        wanted = {i for i, n in stat_names.items() if n in ("tf_op", "op_name")}
+        table = out.setdefault(name, {})
+        for meta in events:
+            ev_name, op = None, None
+            for f3, w3, v3 in _fields(meta):
+                if f3 == 2 and w3 == 2:
+                    ev_name = bytes(v3).decode(errors="replace")
+                elif f3 == 5 and w3 == 2:  # XStat
+                    stat = dict((f4, v4) for f4, _, v4 in _fields(v3))
+                    if stat.get(1) in wanted and 5 in stat:
+                        op = bytes(stat[5]).decode(errors="replace")
+            if ev_name and op:
+                table[ev_name] = op
+    return out
+
+
+def op_scopes(pb: str) -> dict[str, list[str | None]]:
+    """Per device plane, the scope path of each `XLA Ops` event, in the
+    events' order (which is `xplane.load`'s); None where an event has none."""
+    from jax.profiler import ProfileData
+
+    names = op_names(pb)
+    out = {}
+    for plane in ProfileData.from_file(pb).planes:
+        table = names.get(plane.name)
+        if not table:
+            continue
+        cache: dict[str, str | None] = {}
+        for line in plane.lines:
+            if line.name != xplane.OPS_LINE:
+                continue
+            scopes = []
+            for e in line.events:
+                if e.name not in cache:
+                    raw = table.get(e.name)
+                    cache[e.name] = scope_of(raw) if raw else None
+                scopes.append(cache[e.name])
+            out[plane.name] = scopes
+    return out
+
+
+def self_times(events: list) -> list[float]:
+    """Each event's duration minus the events nested in it (one line's
+    events nest properly or follow each other)."""
+    order = sorted(range(len(events)), key=lambda i: (events[i][1], -events[i][2]))
+    own = [e[2] for e in events]
+    stack: list[int] = []
+    for i in order:
+        _, start, dur = events[i]
+        while stack and events[stack[-1]][1] + events[stack[-1]][2] <= start:
+            stack.pop()
+        if stack:
+            own[stack[-1]] -= dur
+        stack.append(i)
+    return [max(x, 0.0) for x in own]
+
+
+def _in(events, lo, hi):
+    return [i for i, e in enumerate(events) if lo <= e[1] < hi]
+
+
+def kernel_table(trace: dict, lo: float, hi: float) -> list[tuple[str, float, float, int]]:
+    """[(name, inclusive s, self s, events)], mean over chips, by operation
+    name without its number: `%flash_fwd.3 custom-call ...` -> `%flash_fwd custom-call`."""
+    planes = xplane.device_planes(trace)
+    acc: dict[str, list[float]] = defaultdict(lambda: [0.0, 0.0, 0])
+    for p in planes:
+        events = xplane._line(p, xplane.OPS_LINE)
+        own = self_times(events)
+        for i in _in(events, lo, hi):
+            parts = events[i][0].split(" ")
+            key = re.sub(r"\.\d+$", "", parts[0]) + (" " + parts[1] if len(parts) > 1 else "")
+            a = acc[key]
+            a[0] += events[i][2]
+            a[1] += own[i]
+            a[2] += 1
+    n = max(len(planes), 1)
+    return sorted(((k, v[0] / 1e9 / n, v[1] / 1e9 / n, v[2] // n) for k, v in acc.items()),
+                  key=lambda r: -r[2])
+
+
+def scope_table(trace: dict, scopes: dict, lo: float, hi: float, depth: int) -> list[tuple[str, float]]:
+    """[(scope path cut to `depth` components, self seconds)], mean over chips."""
+    planes = xplane.device_planes(trace)
+    acc: dict[str, float] = defaultdict(float)
+    for p in planes:
+        events = xplane._line(p, xplane.OPS_LINE)
+        names = scopes.get(p["name"])
+        if not names or len(names) != len(events):
+            continue
+        own = self_times(events)
+        for i in _in(events, lo, hi):
+            path = names[i] or "(no op_name)"
+            acc["/".join(path.split("/")[:depth])] += own[i]
+    n = max(len(planes), 1)
+    return sorted(((k, v / 1e9 / n) for k, v in acc.items()), key=lambda r: -r[1])
+
+
+def host_lines(trace: dict) -> list[tuple[str, list]]:
+    return [(line["name"], line["events"]) for p in trace["planes"]
+            if not xplane.DEVICE_PLANE.match(p["name"]) for line in p["lines"]]
+
+
+def span_table(trace: dict) -> list[tuple[str, int, float, float]]:
+    """[(span, count, total s, self s)] over every host thread."""
+    acc: dict[str, list[float]] = defaultdict(lambda: [0, 0.0, 0.0])
+    for _, events in host_lines(trace):
+        events = [e for e in events if _keep(e[0])]
+        for e, own in zip(events, self_times(events)):
+            a = acc[e[0]]
+            a[0] += 1
+            a[1] += e[2]
+            a[2] += own
+    return sorted(((k, int(v[0]), v[1] / 1e9, v[2] / 1e9) for k, v in acc.items()),
+                  key=lambda r: -r[2])
+
+
+def idle_gaps(trace: dict, lo: float, hi: float, min_ns: float) -> list[dict]:
+    """Every gap of chip 0 in [lo, hi] of at least `min_ns`:
+    {"start", "seconds", "span", "thread"}; `span` is the innermost `areal/`
+    span open at the gap's middle on any host thread (the shortest, if
+    several threads have one), else the innermost `bench/` span in brackets,
+    else None."""
+    planes = xplane.device_planes(trace)
+    if not planes:
+        return []
+    busy = xplane.union(xplane._clip(xplane._line(planes[0], xplane.OPS_LINE), lo, hi))
+    gaps, t = [], lo
+    for a, b in busy:
+        if a - t >= min_ns:
+            gaps.append((t, a))
+        t = max(t, b)
+    if hi - t >= min_ns:
+        gaps.append((t, hi))
+    threads = host_lines(trace)
+    out = []
+    for a, b in gaps:
+        mid = (a + b) / 2
+        best = {}
+        for thread, events in threads:
+            for name, start, dur in events:
+                if start <= mid <= start + dur and name != xplane.WINDOW_SPAN:
+                    prefix = PROGRAM_PREFIX if name.startswith(PROGRAM_PREFIX) else "other"
+                    if prefix not in best or dur < best[prefix][2]:
+                        best[prefix] = (name, thread, dur)
+        if PROGRAM_PREFIX in best:
+            span, thread = best[PROGRAM_PREFIX][:2]
+        elif "other" in best:
+            span, thread = f"[{best['other'][0]}]", best["other"][1]
+        else:
+            span, thread = None, None
+        out.append({"start": a, "seconds": (b - a) / 1e9, "span": span, "thread": thread})
+    return out
+
+
+def gaps_by_span(gaps: list[dict], lo: float) -> list[tuple]:
+    """[(span, thread, count, total s, longest s, first at s, last at s)],
+    the last two from the window's start. A span still open when the profiler
+    stops is not in the trace, so gaps late in the window may have none."""
+    acc: dict[tuple, list[float]] = defaultdict(lambda: [0, 0.0, 0.0, float("inf"), 0.0])
+    for g in gaps:
+        a = acc[(g["span"] or "no span on any thread", g["thread"] or "-")]
+        a[0] += 1
+        a[1] += g["seconds"]
+        a[2] = max(a[2], g["seconds"])
+        a[3] = min(a[3], (g["start"] - lo) / 1e9)
+        a[4] = max(a[4], (g["start"] - lo) / 1e9)
+    return sorted(((k[0], k[1], int(v[0]), v[1], v[2], v[3], v[4]) for k, v in acc.items()),
+                  key=lambda r: -r[3])
+
+
+def report(path: str, gap_ms: float = 1.0, top: int = 20, depth: int = 4) -> dict:
+    """Everything `main` prints, as data."""
+    trace, pb = load(path)
+    out: dict = {"spans": span_table(trace)}
+    if not xplane.device_planes(trace):
+        return out
+    lo, hi = xplane.window(trace)
+    out["window_s"] = (hi - lo) / 1e9
+    out["busy"] = xplane.busy(trace, lo, hi)
+    out["kernels"] = kernel_table(trace, lo, hi)[:top]
+    out["scopes"] = scope_table(trace, op_scopes(pb), lo, hi, depth)[:top] if pb else []
+    gaps = idle_gaps(trace, lo, hi, gap_ms * 1e6)
+    out["gaps"] = gaps
+    out["gaps_by_span"] = gaps_by_span(gaps, lo)
+    return out
+
+
+def main(argv) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("path")
+    ap.add_argument("--gap-ms", type=float, default=1.0)
+    ap.add_argument("--top", type=int, default=20)
+    ap.add_argument("--depth", type=int, default=4, help="components of a scope path kept")
+    ap.add_argument("--json", action="store_true", help="print the report as one JSON object")
+    args = ap.parse_args(argv)
+    r = report(args.path, args.gap_ms, args.top, args.depth)
+    if args.json:
+        print(json.dumps(r))
+        return 0
+    if "busy" in r:
+        b = r["busy"]
+        print(f"device: window {r['window_s']:.3f} s, busy {b['busy_s']:.3f} s, idle "
+              f"{100 * (1 - b['busy_s'] / b['window_s']):.2f}% (per chip busy: "
+              + ", ".join(f"{s:.3f}" for s in b["per_device_s"]) + ")")
+        print(f"\nkernels: device seconds by operation name (mean over chips), top {args.top} by self time")
+        print(f"  {'inclusive':>10} {'self':>10} {'events':>7}  name")
+        for name, incl, own, n in r["kernels"]:
+            print(f"  {incl:10.4f} {own:10.4f} {n:7d}  {name}")
+        print(f"\nscopes: device self seconds by named_scope path (depth {args.depth})")
+        if not r["scopes"]:
+            print("  (the trace carries no op_name for its operations)")
+        for name, secs in r["scopes"]:
+            print(f"  {secs:10.4f}  {name}")
+    print("\nspans: host spans, every thread")
+    print(f"  {'count':>6} {'total s':>10} {'self s':>10}  name")
+    for name, n, total, own in r["spans"]:
+        print(f"  {n:6d} {total:10.4f} {own:10.4f}  {name}")
+    if "gaps" in r:
+        gaps = r["gaps"]
+        print(f"\nidle gaps of chip 0 over {args.gap_ms} ms: {len(gaps)}, "
+              f"{sum(g['seconds'] for g in gaps):.4f} s in all")
+        print(f"  {'count':>6} {'total ms':>10} {'longest ms':>11} {'from s':>8} {'to s':>8}  "
+              "innermost areal/ span at the gap's middle (thread)")
+        for span, thread, n, total, longest, first, last in r["gaps_by_span"]:
+            print(f"  {n:6d} {1e3 * total:10.2f} {1e3 * longest:11.2f} {first:8.3f} {last:8.3f}  "
+                  f"{span} ({thread})")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
