@@ -7,7 +7,7 @@ realhf generation engine realhf/impl/model/nn/real_llm_generate.py).
 
 TPU-first design:
 - **Static-shape continuous batching**: R fixed decode slots over a PAGED
-  KV pool [L, n_blocks, block_size, nKV, hd] with host-side per-slot block
+  KV pool [L, n_blocks, block_size, nKV*hd] with host-side per-slot block
   tables (engine/kv_pool.py) — reserved KV tracks tokens actually held,
   not R x context worst case, and prefix forks are block-table aliasing.
   The batched decode step and the chunked decode loop compile ONCE per
@@ -694,11 +694,12 @@ class JaxDecodeEngine(InferenceEngine):
         R = self.config.max_running_requests
         S = self.config.context_length
         kv_dtype = jnp.dtype(self.config.kv_cache_dtype)
-        # Paged KV pool: [L, n_blocks, block_size, nKV, hd] + host-side
-        # per-slot block tables (engine/kv_pool.py). kv_pool_tokens=None
-        # provisions the dense worst case (R x S), so default behavior and
-        # memory are unchanged; a budget makes reserved memory track the
-        # tokens actually held.
+        # Paged KV pool: [L, n_blocks, block_size, nKV*hd] + host-side
+        # per-slot block tables (engine/kv_pool.py): a row holds its kv
+        # heads side by side, which is the page the paged kernel reads.
+        # kv_pool_tokens=None provisions the dense worst case (R x S), so
+        # default behavior and memory are unchanged; a budget makes
+        # reserved memory track the tokens actually held.
         bs = min(int(self.config.page_size), S)
         max_bps = -(-S // bs)
         if self.config.kv_layout not in ("paged", "workspace"):
@@ -796,8 +797,7 @@ class JaxDecodeEngine(InferenceEngine):
             cfg.num_hidden_layers,
             n_blocks,
             bs,
-            cfg.num_key_value_heads,
-            cfg.head_dim_,
+            cfg.num_key_value_heads * cfg.head_dim_,
         )
         pool_dtype = jnp.int8 if self._kv_quant else kv_dtype
         self._k_cache = jax.device_put(
@@ -1122,7 +1122,7 @@ class JaxDecodeEngine(InferenceEngine):
                 )
                 kp, ksc = split_pool(kq)
                 vp, vsc = split_pool(vq)
-                L, _, bsz, nkv, hd = kp.shape
+                L, _, bsz, D = kp.shape
                 nb_w = bt_row.shape[0]
                 pad = nb_w * bsz - bucket
                 if pad:
@@ -1134,10 +1134,10 @@ class JaxDecodeEngine(InferenceEngine):
                     ksc = ksc.at[:, bt_row].set(scales_blocked(sk, nb_w, bsz))
                     vsc = vsc.at[:, bt_row].set(scales_blocked(sv, nb_w, bsz))
                 kp = kp.at[:, bt_row].set(
-                    k.reshape(L, nb_w, bsz, nkv, hd).astype(kp.dtype)
+                    k.reshape(L, nb_w, bsz, D).astype(kp.dtype)
                 )
                 vp = vp.at[:, bt_row].set(
-                    v.reshape(L, nb_w, bsz, nkv, hd).astype(vp.dtype)
+                    v.reshape(L, nb_w, bsz, D).astype(vp.dtype)
                 )
                 return join_pool(kp, ksc), join_pool(vp, vsc)
 
@@ -1298,8 +1298,10 @@ class JaxDecodeEngine(InferenceEngine):
             if self.model_config.num_key_value_heads % tp == 0
             else None
         )
+        # rows are [nKV*hd] with each head contiguous, so the tp axis
+        # splits the row by whole heads
         self._cache_sharding = NamedSharding(
-            self.mesh, P(None, None, None, kv_axis, None)
+            self.mesh, P(None, None, None, kv_axis)
         )
         # int8 scale pools are [L, n_blocks, nKV, block_size]
         self._scale_sharding = NamedSharding(
@@ -1339,9 +1341,14 @@ class JaxDecodeEngine(InferenceEngine):
           Pallas impl (TPU) the scan attends DIRECTLY over the pool
           through the [R, nb] block table (ops/paged_attention.py — each
           grid step DMAs one pool block HBM→VMEM) and each step's cache
-          write is a dynamic scatter of the single (block, offset) row —
-          O(1) per token; the pool round-trips through the jit untouched
-          except for the written rows. With the XLA impl (CPU/fallback)
+          write is a dynamic scatter of the single (layer, block, offset)
+          row — O(1) per token. The pool is donated, stored in the layout
+          the kernel reads and carried WHOLE through the token loop and
+          the layer loop, so the compiled chunk updates it in place; PR
+          24's trace of the 1.5B chunk on a v5e refuted the earlier claim
+          here (the pool was relaid, sliced and copied, 4.7 GB a token
+          step), and tests/test_pool_in_place.py now holds the traced
+          program to it. With the XLA impl (CPU/fallback)
           a per-step in-pool gather measures ~20% SLOWER than the
           workspace loop on XLA:CPU (the one-hot write fuses into the
           attention einsum there; a fresh gather each step does not), so
@@ -1385,6 +1392,7 @@ class JaxDecodeEngine(InferenceEngine):
         paged = self.config.kv_layout == "paged"
         paged_impl = self._paged_impl
         quant = self._kv_quant
+        hd = cfg.head_dim_
 
         # sampler shared with the speculative verify chunk (see
         # _make_sample_fn) — per-slot exactness and the top_p==1 primary-key
@@ -1454,16 +1462,16 @@ class JaxDecodeEngine(InferenceEngine):
                     # rows this chunk produced — the full block
                     # scatter-back is the half of the round trip XLA:CPU
                     # can drop without losing the one-hot-write fusion
-                    L, _, bsz, nkv, hd = kp.shape
+                    L, _, bsz, D = kp.shape
                     R = bt.shape[0]
                     idx = bt.reshape(-1)
                     lengths0 = lengths
                     with jax.named_scope("pool_read"):
                         kc = jnp.take(kp, idx, axis=1).reshape(
-                            L, R, nb * bsz, nkv, hd
+                            L, R, nb * bsz, -1, hd
                         )
                         vc = jnp.take(vp, idx, axis=1).reshape(
-                            L, R, nb * bsz, nkv, hd
+                            L, R, nb * bsz, -1, hd
                         )
 
                     @jax.named_scope("decode_step")
@@ -1507,10 +1515,10 @@ class JaxDecodeEngine(InferenceEngine):
                     doff = jnp.where(active[:, None], pos % bsz, 0)
                     with jax.named_scope("pool_write"):
                         kp = kp.at[:, dblock.reshape(-1), doff.reshape(-1)].set(
-                            rows_k.reshape(L, R * n_chunk, nkv, hd)
+                            rows_k.reshape(L, R * n_chunk, D)
                         )
                         vp = vp.at[:, dblock.reshape(-1), doff.reshape(-1)].set(
-                            rows_v.reshape(L, R * n_chunk, nkv, hd)
+                            rows_v.reshape(L, R * n_chunk, D)
                         )
                     if freq:
                         return kp, vp, last, lengths, toks, logps, counts
@@ -1518,15 +1526,15 @@ class JaxDecodeEngine(InferenceEngine):
 
                 # workspace: gather each slot's blocks into a contiguous
                 # workspace, scan, scatter the blocks back
-                L, _, bsz, nkv, hd = kp.shape
+                L, _, bsz, D = kp.shape
                 R = bt.shape[0]
                 idx = bt.reshape(-1)
                 with jax.named_scope("pool_read"):
                     kc = jnp.take(kp, idx, axis=1).reshape(
-                        L, R, nb * bsz, nkv, hd
+                        L, R, nb * bsz, -1, hd
                     )
                     vc = jnp.take(vp, idx, axis=1).reshape(
-                        L, R, nb * bsz, nkv, hd
+                        L, R, nb * bsz, -1, hd
                     )
 
                 @jax.named_scope("decode_step")
@@ -1546,12 +1554,8 @@ class JaxDecodeEngine(InferenceEngine):
                     jax.lax.scan(step, init, None, length=n_chunk)
                 )
                 with jax.named_scope("pool_write"):
-                    kp = kp.at[:, idx].set(
-                        kc.reshape(L, R * nb, bsz, nkv, hd)
-                    )
-                    vp = vp.at[:, idx].set(
-                        vc.reshape(L, R * nb, bsz, nkv, hd)
-                    )
+                    kp = kp.at[:, idx].set(kc.reshape(L, R * nb, bsz, D))
+                    vp = vp.at[:, idx].set(vc.reshape(L, R * nb, bsz, D))
                 if freq:
                     return kp, vp, last, lengths, toks, logps, counts
                 return kp, vp, last, lengths, toks, logps
@@ -1603,6 +1607,7 @@ class JaxDecodeEngine(InferenceEngine):
         cfg = self.model_config
         paged = self.config.kv_layout == "paged"
         paged_impl = self._paged_impl
+        hd = cfg.head_dim_
         sample = _make_sample_fn(use_topp)
 
         def verify_chunk(params, kp, vp, bt, last_tokens, lengths, active,
@@ -1617,22 +1622,22 @@ class JaxDecodeEngine(InferenceEngine):
                     attn_impl=paged_impl,
                 )
             else:
-                L, _, bsz, nkv, hd = kp.shape
+                L, _, bsz, D = kp.shape
                 idx = bt.reshape(-1)
                 with jax.named_scope("pool_read"):
                     kc = jnp.take(kp, idx, axis=1).reshape(
-                        L, R, nb * bsz, nkv, hd
+                        L, R, nb * bsz, -1, hd
                     )
                     vc = jnp.take(vp, idx, axis=1).reshape(
-                        L, R, nb * bsz, nkv, hd
+                        L, R, nb * bsz, -1, hd
                     )
                 logits, kc, vc = verify_step(
                     params, tokens, lengths, kc, vc, cfg,
                     active=active, rope_offset=rope_delta,
                 )
                 with jax.named_scope("pool_write"):
-                    kp = kp.at[:, idx].set(kc.reshape(L, R * nb, bsz, nkv, hd))
-                    vp = vp.at[:, idx].set(vc.reshape(L, R * nb, bsz, nkv, hd))
+                    kp = kp.at[:, idx].set(kc.reshape(L, R * nb, bsz, D))
+                    vp = vp.at[:, idx].set(vc.reshape(L, R * nb, bsz, D))
             V = logits.shape[-1]
             # flatten [R, W] positions to R*W rows and reuse the chunk
             # loop's sampler verbatim: position base+j samples with
@@ -1857,7 +1862,7 @@ class JaxDecodeEngine(InferenceEngine):
                 ks, vs = jax.vmap(core)(ids_b, lens_b)  # [B, L, bucket, ...]
                 kp, ksc = split_pool(kq)
                 vp, vsc = split_pool(vq)
-                L, _, bsz, nkv, hd = kp.shape
+                L, _, bsz, D = kp.shape
                 nb_w = bts_b.shape[1]
                 pad = nb_w * bsz - bucket
                 for b in range(B):  # static unroll: B is a compile key
@@ -1880,10 +1885,10 @@ class JaxDecodeEngine(InferenceEngine):
                             scales_blocked(sv, nb_w, bsz)
                         )
                     kp = kp.at[:, bts_b[b]].set(
-                        k.reshape(L, nb_w, bsz, nkv, hd).astype(kp.dtype)
+                        k.reshape(L, nb_w, bsz, D).astype(kp.dtype)
                     )
                     vp = vp.at[:, bts_b[b]].set(
-                        v.reshape(L, nb_w, bsz, nkv, hd).astype(vp.dtype)
+                        v.reshape(L, nb_w, bsz, D).astype(vp.dtype)
                     )
                 return join_pool(kp, ksc), join_pool(vp, vsc)
 
@@ -1932,18 +1937,32 @@ class JaxDecodeEngine(InferenceEngine):
     # -- host KV tier (kv_host_pool_mb) --------------------------------
     def _get_host_gather_fn(self):
         """Gather one slot's first `nb` pool blocks into fresh
-        [L, nb, bs, nKV, hd] buffers for the device→host offload copy.
-        NOT donated: the pool stays intact (its blocks are freed by the
-        host-side allocator after the gather is dispatched). jit
-        re-specialises per nb; the trace is a pair of takes."""
+        [L, nb, bs, nKV, hd] buffers for the device→host offload copy:
+        the host tier and the wire keep the logical shape, so the heads
+        are split out of the gathered rows here (the pool itself stays
+        [L, n_blocks, bs, nKV*hd]). NOT donated: the pool stays intact
+        (its blocks are freed by the host-side allocator after the gather
+        is dispatched). jit re-specialises per nb; the trace is a pair of
+        takes."""
         if self._host_gather_fn is None:
+            from areal_tpu.ops.kv_quant import join_pool, split_pool
+
+            hd = self.model_config.head_dim_
 
             def gather(kq, vq, bt_row):
-                # tree-mapped: int8 operands gather the scale blocks too —
-                # the host entry (and the migration wire) then carries the
+                # int8 operands gather the scale blocks too — the host
+                # entry (and the migration wire) then carries the
                 # quantized bytes + scales AS-IS, no requantization
-                take = lambda pool: jnp.take(pool, bt_row, axis=1)  # noqa: E731
-                return jax.tree.map(take, kq), jax.tree.map(take, vq)
+                def take(pool):
+                    data, scales = split_pool(pool)
+                    data = jnp.take(data, bt_row, axis=1)
+                    if scales is not None:
+                        scales = jnp.take(scales, bt_row, axis=1)
+                    return join_pool(
+                        data.reshape(*data.shape[:3], -1, hd), scales
+                    )
+
+                return take(kq), take(vq)
 
             self._host_gather_fn = jax.jit(gather)
         return self._host_gather_fn
@@ -1960,9 +1979,13 @@ class JaxDecodeEngine(InferenceEngine):
                 # tree-mapped: int8 host entries upload (data, scales)
                 # pairs — the stored int8 bytes land verbatim (the astype
                 # is an identity there), so a promoted stream reads the
-                # exact bytes the offload gathered
+                # exact bytes the offload gathered. Host data blocks are
+                # [L, nb, bs, nKV, hd]: their heads fold back into the row
+                # (a no-op for the [L, nb, nKV, bs] scale blocks)
                 def put(pool, host):
-                    return pool.at[:, bt_row].set(host.astype(pool.dtype))
+                    return pool.at[:, bt_row].set(
+                        host.astype(pool.dtype).reshape(*host.shape[:3], -1)
+                    )
 
                 return jax.tree.map(put, kq, hk), jax.tree.map(put, vq, hv)
 
@@ -2131,7 +2154,9 @@ class JaxDecodeEngine(InferenceEngine):
 
                 kp, ksc = split_pool(kq)
                 vp, vsc = split_pool(vq)
-                L, _, bsz, nkv, hd = kp.shape
+                L, _, bsz, D = kp.shape
+                hd = cfg.head_dim_
+                nkv = D // hd
                 ws_k = jnp.take(kp, bt_row, axis=1).reshape(
                     L, nb * bsz, nkv, hd
                 )
@@ -2187,12 +2212,8 @@ class JaxDecodeEngine(InferenceEngine):
                 ws_v = jax.lax.dynamic_update_slice(
                     ws_v, vs.astype(vp.dtype), (0, prefix_len, 0, 0)
                 )
-                kp = kp.at[:, bt_row].set(
-                    ws_k.reshape(L, nb, bsz, nkv, hd)
-                )
-                vp = vp.at[:, bt_row].set(
-                    ws_v.reshape(L, nb, bsz, nkv, hd)
-                )
+                kp = kp.at[:, bt_row].set(ws_k.reshape(L, nb, bsz, D))
+                vp = vp.at[:, bt_row].set(ws_v.reshape(L, nb, bsz, D))
                 return join_pool(kp, ksc), join_pool(vp, vsc)
 
             self._suffix_prefill_fns[key] = jax.jit(
@@ -4758,7 +4779,9 @@ class JaxDecodeEngine(InferenceEngine):
             vs = None if vs is None else np.asarray(vs)
         except (KeyError, TypeError, ValueError):
             return "rejected"
-        L, _, bs, nkv, hd = self._k_cache.shape
+        L, _, bs, _ = self._k_cache.shape
+        nkv = self.model_config.num_key_value_heads
+        hd = self.model_config.head_dim_
         if meta_only:
             # cheap-drain session (fleet KV fabric): identity only — the
             # resume claims the sampling base key and rebuilds the blocks

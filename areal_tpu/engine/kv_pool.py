@@ -6,15 +6,22 @@ not worst-case dense rows). The dense [slots, context_length] layout of
 rounds 1-4 reserved 100% of worst-case KV upfront: at 32k context x 64
 slots that is the whole HBM budget even when every live sequence is short.
 
-TPU-first shape: one pool tensor [L, n_blocks, block_size, nKV, hd] per
-K/V. Block tables are HOST-side numpy (the scheduler thread owns them; the
+TPU-first shape: one pool tensor [L, n_blocks, block_size, nKV*hd] per
+K/V: a row holds its kv heads side by side, which is the page the paged
+kernel DMAs, so no program ever relays the pool (the `[..., nKV, hd]`
+minor pair this module used to name cost a relayout of a layer's whole
+slice per layer per token on the v5e). Host-tier entries and the
+migration wire keep the logical `[L, nb, block_size, nKV, hd]` shape.
+Block tables are HOST-side numpy (the scheduler thread owns them; the
 jitted kernels receive the relevant table slice as a traced operand each
 dispatch, so table mutation never recompiles anything). Device access is
 layout-dependent (`JaxDecodeConfig.kv_layout`):
 
 - `"paged"` (default): decode attends DIRECTLY over the pool through the
   block table (ops/paged_attention.py) and each step's KV write is a
-  dynamic-update of the single (block, offset) row — no copies at all.
+  scatter of the single (layer, block, offset) row into the pool the
+  chunk carries whole — no copy of the pool or of a layer's slice
+  (tests/test_pool_in_place.py).
 - `"workspace"` (the numerics oracle): the chunk kernel gathers each
   slot's first `nb` blocks into a contiguous workspace, runs the scan,
   and scatters the blocks back — two HBM copies of the active KV per

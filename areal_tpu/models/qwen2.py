@@ -2172,12 +2172,53 @@ def decode_step(
     return logits, k_cache, v_cache
 
 
+@jax.named_scope("kv_write")
+@jax.named_scope("pool_write")
+def _write_pool_rows(pool, new, layer, dest_block, dest_off):
+    """Scatter `new` [N, nKV, hd] fp rows into the WHOLE pool
+    `[L, n_blocks, bsz, nKV*hd]` at `(layer, dest_block[n], dest_off[n])`.
+    The pool is the operand and the result of one scatter and nothing
+    else, so a donated, carried pool is updated in place. Int8 pools
+    quantize AT the scatter: the int8 row and its [N, nKV] scale row land
+    through the same block id (scales are [L, n_blocks, nKV, bsz])."""
+    from areal_tpu.ops.kv_quant import quantize_kv, split_pool
+
+    data, scales = split_pool(pool)
+    n = new.shape[0]
+    if scales is None:
+        return data.at[layer, dest_block, dest_off].set(
+            new.astype(data.dtype).reshape(n, -1)
+        )
+    q_rows, s_rows = quantize_kv(new)
+    return (
+        data.at[layer, dest_block, dest_off].set(q_rows.reshape(n, -1)),
+        scales.at[layer, dest_block, :, dest_off].set(s_rows),
+    )
+
+
+def _scan_layers_carrying(layer, carry, params, cfg: ModelConfig):
+    """Run `layer(carry, layer_params, layer_index) -> carry` over every
+    layer. The KV pool rides in `carry` WHOLE: passed to `lax.scan` as
+    xs/ys it would be sliced out and stacked back per layer, and the fresh
+    ys buffer cannot alias the token-step scan's carry, which cost a copy
+    of both pools every token step (PERF.md, PR 24's trace)."""
+    if cfg.scan_layers:
+        return jax.lax.scan(
+            lambda c, xs: (layer(c, *xs), None),
+            carry,
+            (params["layers"], jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)),
+        )[0]
+    for i in range(cfg.num_hidden_layers):
+        carry = layer(carry, params[f"layers_{i}"], i)
+    return carry
+
+
 def decode_step_paged(
     params: dict,
     tokens: jax.Array,  # [R] current input token per slot
     positions: jax.Array,  # [R] logical index the new token occupies
-    k_pool,  # [L, n_blocks, bsz, nKV, hd] paged KV pool, or (int8, scales)
-    v_pool,  # [L, n_blocks, bsz, nKV, hd] or (int8 data, f32 scales)
+    k_pool,  # [L, n_blocks, bsz, nKV*hd] paged KV pool, or (int8, scales)
+    v_pool,  # [L, n_blocks, bsz, nKV*hd] or (int8 data, f32 scales)
     block_tables: jax.Array,  # [R, nb] int32: each slot's pool blocks
     cfg: ModelConfig,
     active: jax.Array | None = None,  # [R] bool: slot holds a live request
@@ -2192,12 +2233,13 @@ def decode_step_paged(
 
     - **Write is O(1), not O(S).** `decode_step`'s cache write is a
       one-hot masked rewrite of the whole [R, S] cache; here the new
-      row's pool coordinates `(block_tables[r, p // bsz], p % bsz)` are
-      computed from the slot position and written with a single dynamic
-      scatter of R rows. Inactive slots are redirected to the reserved
-      null block 0 (never read as valid data), so retired donors' and
-      parked slots' KV is untouched — the same guarantee the masked
-      one-hot write gave. Write-collision safety between active slots is
+      row's pool coordinates `(layer, block_tables[r, p // bsz], p % bsz)`
+      are computed from the slot position and written with a single
+      dynamic scatter of R rows into the WHOLE pool, which the layer loop
+      carries (never slices: `_scan_layers_carrying`). Inactive slots are
+      redirected to the reserved null block 0 (never read as valid data),
+      so retired donors' and parked slots' KV is untouched — the same
+      guarantee the masked one-hot write gave. Write-collision safety between active slots is
       the pool invariant: aliased (prefix-shared) blocks sit strictly
       below every writer's position and the boundary block is private
       (engine/kv_pool.py).
@@ -2214,15 +2256,13 @@ def decode_step_paged(
     through its int8 representation — token streams are a pure function
     of the quantized pool state, invariant to chunk boundaries.
     """
-    from areal_tpu.ops.kv_quant import join_pool, quantize_kv, split_pool
     from areal_tpu.ops.paged_attention import paged_attention
 
     R = tokens.shape[0]
-    k_data, _ = split_pool(k_pool)
-    bsz = k_data.shape[2]
+    bsz = jax.tree.leaves(k_pool)[0].shape[2]
     nb = block_tables.shape[1]
     span = nb * bsz
-    nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
+    nH, hd = cfg.num_attention_heads, cfg.head_dim_
     x = _embed_tokens(params, tokens, positions, cfg)  # [R, H]
     rope_pos = positions if rope_offset is None else positions + rope_offset
     cos, sin = rope_table(rope_pos, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
@@ -2243,56 +2283,25 @@ def decode_step_paged(
         dest_block = jnp.where(active, dest_block, 0)
         dest_off = jnp.where(active, dest_off, 0)
 
-    @jax.named_scope("kv_write")
-    @jax.named_scope("pool_write")
-    def write(pool_l, new):  # [n_blocks, bsz, nKV, hd] <- [R, nKV, hd] fp
-        data, scales = split_pool(pool_l)
-        if scales is None:
-            return data.at[dest_block, dest_off].set(new.astype(data.dtype))
-        # quantize AT the scatter: int8 row + its [R, nKV] scale row land
-        # through the same block id (scales are [n_blocks, nKV, bsz])
-        q_row, s_row = quantize_kv(new)
-        return (
-            data.at[dest_block, dest_off].set(q_row),
-            scales.at[dest_block, :, dest_off].set(s_row),
-        )
-
     @jax.named_scope("layer")
-    def layer(x, inputs):
-        layer_p, kp, vp = inputs
+    def layer(carry, layer_p, li):
+        x, kp, vp = carry
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         with jax.named_scope("attn"):
             q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-            kp = write(kp, k_new)
-            vp = write(vp, v_new)
+            kp = _write_pool_rows(kp, k_new, li, dest_block, dest_off)
+            vp = _write_pool_rows(vp, v_new, li, dest_block, dest_off)
             with jax.named_scope("attention"):
                 attn_out = paged_attention(
-                    q.reshape(R, nH, hd), kp, vp, block_tables, valid,
+                    q.reshape(R, nH, hd), kp, vp, block_tables, valid, li,
                     impl=attn_impl,
                 )
         x = _attn_out_mlp(layer_p, x, attn_out, cfg, active)
-        return x, (kp, vp)
+        return x, kp, vp
 
-    if cfg.scan_layers:
-        x, (k_pool, v_pool) = jax.lax.scan(
-            layer, x, (params["layers"], k_pool, v_pool)
-        )
-    else:
-        kps, vps = [], []
-        for i in range(cfg.num_hidden_layers):
-            x, (kp, vp) = layer(
-                x,
-                (
-                    params[f"layers_{i}"],
-                    jax.tree.map(lambda p: p[i], k_pool),
-                    jax.tree.map(lambda p: p[i], v_pool),
-                ),
-            )
-            kps.append(kp)
-            vps.append(vp)
-        k_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *kps)
-        v_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *vps)
-
+    x, k_pool, v_pool = _scan_layers_carrying(
+        layer, (x, k_pool, v_pool), params, cfg
+    )
     logits = _final_logits(params, x, cfg)
     return logits, k_pool, v_pool
 
@@ -2401,8 +2410,8 @@ def verify_step_paged(
     params: dict,
     tokens: jax.Array,  # [R, W]: draft inputs, column 0 = the last token
     positions0: jax.Array,  # [R] base index column 0 occupies
-    k_pool,  # [L, n_blocks, bsz, nKV, hd], or (int8 data, f32 scales)
-    v_pool,  # [L, n_blocks, bsz, nKV, hd] or (int8 data, f32 scales)
+    k_pool,  # [L, n_blocks, bsz, nKV*hd], or (int8 data, f32 scales)
+    v_pool,  # [L, n_blocks, bsz, nKV*hd] or (int8 data, f32 scales)
     block_tables: jax.Array,  # [R, nb]
     cfg: ModelConfig,
     active: jax.Array | None = None,
@@ -2418,15 +2427,13 @@ def verify_step_paged(
     pool block once for all W queries). Int8 pools quantize the W rows at
     this scatter and return (data, scales) tuples, exactly as
     `decode_step_paged` does for its single row."""
-    from areal_tpu.ops.kv_quant import quantize_kv, split_pool
     from areal_tpu.ops.paged_attention import paged_attention_qlen
 
     R, W = tokens.shape
-    k_data, _ = split_pool(k_pool)
-    bsz = k_data.shape[2]
+    bsz = jax.tree.leaves(k_pool)[0].shape[2]
     nb = block_tables.shape[1]
     span = nb * bsz
-    nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
+    nH, hd = cfg.num_attention_heads, cfg.head_dim_
     positions = positions0[:, None] + jnp.arange(W, dtype=positions0.dtype)
     flat_pos = positions.reshape(-1)
     x = _embed_tokens(params, tokens.reshape(-1), flat_pos, cfg)  # [R*W, H]
@@ -2457,56 +2464,25 @@ def verify_step_paged(
         None if active is None else jnp.repeat(active, W, axis=0)
     )
 
-    @jax.named_scope("kv_write")
-    @jax.named_scope("pool_write")
-    def write(pool_l, new):  # [n_blocks, bsz, nKV, hd] <- [R*W, nKV, hd] fp
-        data, scales = split_pool(pool_l)
-        if scales is None:
-            return data.at[dest_block_f, dest_off_f].set(
-                new.astype(data.dtype)
-            )
-        q_rows, s_rows = quantize_kv(new)
-        return (
-            data.at[dest_block_f, dest_off_f].set(q_rows),
-            scales.at[dest_block_f, :, dest_off_f].set(s_rows),
-        )
-
     @jax.named_scope("layer")
-    def layer(x, inputs):
-        layer_p, kp, vp = inputs
+    def layer(carry, layer_p, li):
+        x, kp, vp = carry
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         with jax.named_scope("attn"):
             q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-            kp = write(kp, k_new)
-            vp = write(vp, v_new)
+            kp = _write_pool_rows(kp, k_new, li, dest_block_f, dest_off_f)
+            vp = _write_pool_rows(vp, v_new, li, dest_block_f, dest_off_f)
             with jax.named_scope("attention"):
                 attn_out = paged_attention_qlen(
-                    q.reshape(R, W, nH, hd), kp, vp, block_tables, valid,
+                    q.reshape(R, W, nH, hd), kp, vp, block_tables, valid, li,
                     impl=attn_impl,
                 ).reshape(R * W, nH, hd)
         x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat)
-        return x, (kp, vp)
+        return x, kp, vp
 
-    if cfg.scan_layers:
-        x, (k_pool, v_pool) = jax.lax.scan(
-            layer, x, (params["layers"], k_pool, v_pool)
-        )
-    else:
-        kps, vps = [], []
-        for i in range(cfg.num_hidden_layers):
-            x, (kp, vp) = layer(
-                x,
-                (
-                    params[f"layers_{i}"],
-                    jax.tree.map(lambda p: p[i], k_pool),
-                    jax.tree.map(lambda p: p[i], v_pool),
-                ),
-            )
-            kps.append(kp)
-            vps.append(vp)
-        k_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *kps)
-        v_pool = jax.tree.map(lambda *xs: jnp.stack(xs), *vps)
-
+    x, k_pool, v_pool = _scan_layers_carrying(
+        layer, (x, k_pool, v_pool), params, cfg
+    )
     logits = _final_logits(params, x, cfg)
     return (
         logits.reshape(R, W, -1),

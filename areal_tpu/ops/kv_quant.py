@@ -13,7 +13,7 @@ drift: symmetric per-row, per-kv-head absmax int8.
 
 Storage layout (per K and per V):
 
-    data   [L, n_blocks, block_size, nKV, hd]   int8   (the pool)
+    data   [L, n_blocks, block_size, nKV*hd]    int8   (the pool)
     scales [L, n_blocks, nKV, block_size]       f32    (the scale pool)
 
 The scale pool is paged EXACTLY like the data pool — same block ids, same

@@ -1,7 +1,12 @@
 """Paged-attention decode kernel: attend over the KV pool IN PLACE.
 
-The decode engine's KV lives in a paged pool `[n_blocks, bsz, nKV, hd]`
-(per layer) with host-side `[R, nb]` block tables (engine/kv_pool.py).
+The decode engine's KV lives in a paged pool `[L, n_blocks, bsz, nKV*hd]`
+(every layer in one array, each row the kv heads side by side) with
+host-side `[R, nb]` block tables (engine/kv_pool.py). The op takes the
+WHOLE pool and a layer index: a per-layer slice of a scan carry is a copy
+of the slice, and a pool stored `[..., nKV, hd]` has to be relaid into
+rows before every call — on the v5e those two cost three quarters of a
+decode chunk (PERF.md, PR 24's trace), so neither exists any more.
 Until this op existed, the chunk kernel gathered every active slot's
 blocks into a contiguous workspace, scanned decode steps over it, and
 scattered the blocks back — two full HBM copies of the active KV per
@@ -12,23 +17,24 @@ the largest remaining device-side cost after the host-gap work.
 Two implementations behind one signature, selected like `attn_impl`:
 
 - `"pallas"` (TPU): a split-KV flash-decode kernel. The block table is a
-  scalar-prefetch operand, so each grid step's BlockSpec index map reads
-  `bt[r, b]` and DMAs exactly that pool block HBM→VMEM — attention reads
-  KV *through the table*, nothing is ever copied HBM→HBM. One grid step
-  takes the block's whole contiguous `(bsz, nKV*hd)` slab (every kv head:
-  Mosaic cannot window a single head's `(1, hd)` column out of an
-  `(nKV, hd)` minor pair) and scores all query heads against it with
-  block-diagonal queries. Online-softmax partial (max, sum, acc) scratch
-  carries across the `nb` block steps of each slot.
+  scalar-prefetch operand and the layer index a second, so each grid
+  step's BlockSpec index map reads `(layer, bt[r, b])` and DMAs exactly
+  that pool block HBM→VMEM — attention reads KV *through the table*, in
+  the layout the pool is stored in (tests/test_pool_in_place.py holds
+  the traced programs to that). One grid step takes the block's whole
+  contiguous `(bsz, nKV*hd)` slab (every kv head) and scores all query
+  heads against it with block-diagonal queries. Online-softmax partial
+  (max, sum, acc) scratch carries across the `nb` block steps of each
+  slot.
 - `"xla"` (CPU / tests): gathers the `nb` blocks per step and runs the
   exact einsum sequence of the workspace `decode_step`, so its logits are
   BITWISE identical to the workspace layout — that is what lets the
   engine keep `kv_layout="workspace"` as a numerics oracle.
 
 The per-token KV *write* is not this op's job: `decode_step_paged`
-(models/qwen2.py) writes the single (block, offset) row with a dynamic
-scatter — O(1) per token where the workspace path's one-hot masked
-rewrite touched the whole [R, S] cache per layer per step.
+(models/qwen2.py) scatters the single (layer, block, offset) row into the
+pool it carries — O(1) per token where the workspace path's one-hot
+masked rewrite touched the whole [R, S] cache per layer per step.
 
 Int8 pools (ops/kv_quant.py): `k_pool`/`v_pool` may arrive as
 (int8 data, f32 scales) tuples. The Pallas kernel then DMAs the scale
@@ -76,28 +82,28 @@ def _default_interpret() -> bool:
 
 
 @jax.named_scope("pool_read")
-def _gather_dequant(pool, scales, idx, R, nb, bsz, nKV, hd, dtype):
-    """Gather `idx` blocks into [R, nb*bsz, nKV, hd]; int8 pools are
-    dequantized right after the gather (the seam the Pallas kernel puts
-    right after its DMA), so both impls score the same effective values."""
-    c = jnp.take(pool, idx, axis=0).reshape(R, nb * bsz, nKV, hd)
+def _gather_dequant(pool, block_table, layer, hd, dtype):
+    """Gather one layer's `block_table` blocks into [R, nb*bsz, nKV, hd]
+    (the heads are split out of the row AFTER the gather, on the gathered
+    rows); int8 pools are dequantized right after the gather (the seam the
+    Pallas kernel puts right after its DMA), so both impls score the same
+    effective values."""
+    data, scales = split_pool(pool)
+    R, nb = block_table.shape
+    bsz = data.shape[2]
+    c = data[layer, block_table].reshape(R, nb * bsz, -1, hd)
     if scales is None:
         return c
-    sc = scales_rowmajor(
-        jnp.take(scales, idx, axis=0).reshape(R, nb, nKV, bsz)
-    )  # [R, nb*bsz, nKV]
+    sc = scales_rowmajor(scales[layer, block_table])  # [R, nb*bsz, nKV]
     return dequantize_kv(c, sc, dtype)
 
 
-def _paged_attention_xla(q, k_pool, v_pool, block_table, valid, sm_scale):
-    (k_pool, k_scales), (v_pool, v_scales) = split_pool(k_pool), split_pool(v_pool)
+def _paged_attention_xla(q, k_pool, v_pool, block_table, valid, layer, sm_scale):
     R, nH, hd = q.shape
-    bsz, nKV = k_pool.shape[1], k_pool.shape[2]
-    nb = block_table.shape[1]
+    kc = _gather_dequant(k_pool, block_table, layer, hd, q.dtype)
+    vc = _gather_dequant(v_pool, block_table, layer, hd, q.dtype)
+    nKV = kc.shape[2]
     group = nH // nKV
-    idx = block_table.reshape(-1)
-    kc = _gather_dequant(k_pool, k_scales, idx, R, nb, bsz, nKV, hd, q.dtype)
-    vc = _gather_dequant(v_pool, v_scales, idx, R, nb, bsz, nKV, hd, q.dtype)
     # the exact op/cast sequence of the workspace decode_step attention —
     # bitwise-equal logits are the parity contract with kv_layout="workspace"
     qg = q.reshape(R, nKV, group, hd)
@@ -121,6 +127,7 @@ def _paged_attention_xla(q, k_pool, v_pool, block_table, valid, sm_scale):
 
 def _paged_kernel(
     bt_ref,  # [R, nb] scalar-prefetch block table
+    layer_ref,  # [1] scalar-prefetch layer index (read by the index maps)
     mask_ref,  # (1, 1, W, bsz) int32 validity rows for this block, per query
     q_ref,  # (1, W*nHp, D) block-diagonal queries, D = nKV*hd
     *refs,  # [sel], k, [k scales], v, [v scales], out, acc, m, l
@@ -152,14 +159,14 @@ def _paged_kernel(
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def head_rows(sc_ref):
-        # (1, nKV, bsz) block scales -> [rows, bsz]: row i gets its kv head's
-        sc = sc_ref[0]
+        # (nKV, bsz) block scales -> [rows, bsz]: row i gets its kv head's
+        sc = sc_ref[...]
         return sum(
             sel_ref[h] * sc[h : h + 1, :] for h in range(sc.shape[0])
         )
 
     q = q_ref[0].astype(jnp.float32)  # [rows, D]
-    k = k_ref[0].astype(jnp.float32)  # [bsz, D]
+    k = k_ref[...].astype(jnp.float32)  # [bsz, D]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -182,7 +189,7 @@ def _paged_kernel(
     m_ref[:] = m_new
     if quant:
         p = p * head_rows(vs_ref)
-    v = v_ref[0].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
     acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -194,15 +201,16 @@ def _paged_kernel(
         o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
 
 
-def _paged_pallas(q, k_pool, v_pool, block_table, valid, sm_scale, interpret):
+def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
+                  interpret):
     """q [R, W, nH, hd], valid [R, W, nb*bsz] -> [R, W, nH, hd]. Decoding is
     the W == 1 case of the speculative verify."""
     (k_pool, k_scales), (v_pool, v_scales) = split_pool(k_pool), split_pool(v_pool)
     R, W, nH, hd = q.shape
-    n_blocks, bsz, nKV, _ = k_pool.shape
+    _, _, bsz, D = k_pool.shape
     nb = block_table.shape[1]
+    nKV = D // hd
     group = nH // nKV
-    D = nKV * hd
     if not interpret and bsz % 128 != 0:
         raise ValueError(
             f"pallas paged attention needs page_size % 128 == 0 on TPU "
@@ -223,33 +231,34 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, sm_scale, interpret):
     mask = valid.astype(jnp.int32).reshape(R, W, nb, bsz).swapaxes(1, 2)
 
     # the index map IS the page walk: block b of slot r comes straight
-    # from the pool row the table names (scale strips walk the same map)
-    kv_spec = pl.BlockSpec((1, bsz, D), lambda r, b, bt: (bt[r, b], 0, 0))
-    sc_spec = pl.BlockSpec((1, nKV, bsz), lambda r, b, bt: (bt[r, b], 0, 0))
+    # from the pool page (layer, table entry) names, as the pool stores it
+    # (scale strips walk the same map)
+    def page(r, b, bt, layer):
+        return layer[0], bt[r, b], 0, 0
+
+    kv_spec = pl.BlockSpec((None, None, bsz, D), page)
+    sc_spec = pl.BlockSpec((None, None, nKV, bsz), page)
     in_specs = [
-        pl.BlockSpec((1, 1, W, bsz), lambda r, b, bt: (r, b, 0, 0)),
-        pl.BlockSpec((1, rows, D), lambda r, b, bt: (r, 0, 0)),
+        pl.BlockSpec((1, 1, W, bsz), lambda r, b, bt, layer: (r, b, 0, 0)),
+        pl.BlockSpec((1, rows, D), lambda r, b, bt, layer: (r, 0, 0)),
     ]
-    with jax.named_scope("pool_read"):
-        # the kernel walks pages of [bsz, nKV * hd] rows: a relayout of the
-        # layer's whole pool slice when (nKV, hd) is not already lane-dense
-        k2 = k_pool.reshape(n_blocks, bsz, D)
-        v2 = v_pool.reshape(n_blocks, bsz, D)
     if quant:
         sel = jnp.asarray(np.tile(onehot, (W, 1)).T[:, :, None])
         in_specs += [
-            pl.BlockSpec((nKV, rows, 1), lambda r, b, bt: (0, 0, 0)),
+            pl.BlockSpec((nKV, rows, 1), lambda r, b, bt, layer: (0, 0, 0)),
             kv_spec, sc_spec, kv_spec, sc_spec,
         ]
-        operands = (sel, k2, k_scales, v2, v_scales)
+        operands = (sel, k_pool, k_scales, v_pool, v_scales)
     else:
         in_specs += [kv_spec, kv_spec]
-        operands = (k2, v2)
+        operands = (k_pool, v_pool)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(R, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, D), lambda r, b, bt: (r, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (1, rows, D), lambda r, b, bt, layer: (r, 0, 0)
+        ),
         scratch_shapes=[
             pltpu.VMEM((rows, D), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
@@ -265,7 +274,8 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, sm_scale, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(block_table, mask, q_exp, *operands)
+    )(block_table, jnp.asarray(layer, jnp.int32).reshape(1), mask, q_exp,
+      *operands)
     # each head keeps the hd lanes of its own kv head
     out = out.reshape(R, W, nHp, nKV, hd)
     return out[:, :, np.arange(nH), kv_of_head[:nH]]
@@ -273,10 +283,11 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, sm_scale, interpret):
 
 def paged_attention_qlen(
     q: jax.Array,  # [R, W, nH, hd]: W query positions per slot
-    k_pool,  # [n_blocks, bsz, nKV, hd] ONE layer's pool, or (int8, scales)
-    v_pool,  # [n_blocks, bsz, nKV, hd] or (int8 data, f32 scales)
+    k_pool,  # [L, n_blocks, bsz, nKV*hd] the WHOLE pool, or (int8, scales)
+    v_pool,  # [L, n_blocks, bsz, nKV*hd] or (int8 data, f32 scales)
     block_table: jax.Array,  # [R, nb] int32 pool-block ids per slot
     valid: jax.Array,  # [R, W, nb*bsz] bool per-query attendable rows
+    layer,  # int or int32 scalar: the layer whose pages are read
     *,
     impl: str = "auto",
     sm_scale: float | None = None,
@@ -303,16 +314,12 @@ def paged_attention_qlen(
     if impl == "xla":
         from areal_tpu.ops.chunked_attention import verify_attention
 
-        (kd, ks), (vd, vs) = split_pool(k_pool), split_pool(v_pool)
-        R, W, nH, hd = q.shape
-        bsz, nKV = kd.shape[1], kd.shape[2]
-        nb = block_table.shape[1]
-        idx = block_table.reshape(-1)
-        kc = _gather_dequant(kd, ks, idx, R, nb, bsz, nKV, hd, q.dtype)
-        vc = _gather_dequant(vd, vs, idx, R, nb, bsz, nKV, hd, q.dtype)
+        hd = q.shape[-1]
+        kc = _gather_dequant(k_pool, block_table, layer, hd, q.dtype)
+        vc = _gather_dequant(v_pool, block_table, layer, hd, q.dtype)
         return verify_attention(q, kc, vc, valid, sm_scale=sm_scale)
     return _paged_pallas(
-        q, k_pool, v_pool, block_table, valid, sm_scale, interpret
+        q, k_pool, v_pool, block_table, valid, layer, sm_scale, interpret
     )
 
 
@@ -323,10 +330,11 @@ def paged_attention_qlen(
 
 def paged_attention(
     q: jax.Array,  # [R, nH, hd] query (one decode step per slot)
-    k_pool,  # [n_blocks, bsz, nKV, hd] ONE layer's pool, or (int8, scales)
-    v_pool,  # [n_blocks, bsz, nKV, hd] or (int8 data, f32 scales)
+    k_pool,  # [L, n_blocks, bsz, nKV*hd] the WHOLE pool, or (int8, scales)
+    v_pool,  # [L, n_blocks, bsz, nKV*hd] or (int8 data, f32 scales)
     block_table: jax.Array,  # [R, nb] int32 pool-block ids per slot
     valid: jax.Array,  # [R, nb*bsz] bool: logical rows each slot attends
+    layer,  # int or int32 scalar: the layer whose pages are read
     *,
     impl: str = "auto",
     sm_scale: float | None = None,
@@ -335,7 +343,7 @@ def paged_attention(
     """Decode attention of R single-token queries over paged KV.
 
     Logical row s of slot r lives at pool position
-    `(block_table[r, s // bsz], s % bsz)`; `valid` carries the causal
+    `(layer, block_table[r, s // bsz], s % bsz)`; `valid` carries the causal
     (and sliding-window) mask over those logical rows, so unallocated
     table tail entries (null block 0) are read but never scored. Returns
     `[R, nH, hd]` in q's dtype.
@@ -346,8 +354,10 @@ def paged_attention(
         interpret = _default_interpret()
     impl = resolve_impl(impl)
     if impl == "xla":
-        return _paged_attention_xla(q, k_pool, v_pool, block_table, valid, sm_scale)
+        return _paged_attention_xla(
+            q, k_pool, v_pool, block_table, valid, layer, sm_scale
+        )
     return _paged_pallas(
-        q[:, None], k_pool, v_pool, block_table, valid[:, None], sm_scale,
-        interpret,
+        q[:, None], k_pool, v_pool, block_table, valid[:, None], layer,
+        sm_scale, interpret,
     )[:, 0]

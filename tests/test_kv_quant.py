@@ -132,11 +132,12 @@ def test_scale_layout_helpers_invert():
 # -- 2. kernel agreement on quantized pools ----------------------------
 
 
-def _quantized_pool(rng, nblocks=10, bsz=8, nkv=2, hd=16):
-    kp = rng.randn(nblocks, bsz, nkv, hd).astype(np.float32)
+def _quantized_pool(rng, nblocks=10, bsz=8, nkv=2, hd=16, layers=2):
+    kp = rng.randn(layers, nblocks, bsz, nkv, hd).astype(np.float32)
     q, s = quantize_kv(jnp.asarray(kp))
-    # scale pool layout: [n_blocks, nKV, block_size]
-    return q, jnp.swapaxes(s, -1, -2)
+    # stored layout: data [L, n_blocks, block_size, nKV*hd] (heads side by
+    # side in the row), scale pool [L, n_blocks, nKV, block_size]
+    return q.reshape(layers, nblocks, bsz, nkv * hd), jnp.swapaxes(s, -1, -2)
 
 
 def test_pallas_and_xla_agree_on_int8_pools():
@@ -153,9 +154,9 @@ def test_pallas_and_xla_agree_on_int8_pools():
 
     q1 = jnp.asarray(rng.randn(R, nH, hd).astype(np.float32))
     valid1 = jnp.asarray(rng.rand(R, nb * bsz) < 0.7).at[:, 0].set(True)
-    o_xla = paged_attention(q1, (qk, sk), (qv, sv), bt, valid1, impl="xla")
+    o_xla = paged_attention(q1, (qk, sk), (qv, sv), bt, valid1, 1, impl="xla")
     o_pl = paged_attention(
-        q1, (qk, sk), (qv, sv), bt, valid1, impl="pallas", interpret=True
+        q1, (qk, sk), (qv, sv), bt, valid1, 1, impl="pallas", interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(o_xla), np.asarray(o_pl), atol=2e-5, rtol=1e-5
@@ -166,10 +167,10 @@ def test_pallas_and_xla_agree_on_int8_pools():
         jnp.asarray(rng.rand(R, W, nb * bsz) < 0.7).at[:, :, 0].set(True)
     )
     ow_xla = paged_attention_qlen(
-        qw, (qk, sk), (qv, sv), bt, validw, impl="xla"
+        qw, (qk, sk), (qv, sv), bt, validw, 1, impl="xla"
     )
     ow_pl = paged_attention_qlen(
-        qw, (qk, sk), (qv, sv), bt, validw, impl="pallas", interpret=True
+        qw, (qk, sk), (qv, sv), bt, validw, 1, impl="pallas", interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(ow_xla), np.asarray(ow_pl), atol=2e-5, rtol=1e-5

@@ -48,22 +48,27 @@ TINY = ModelConfig(
 # ---------------------------------------------------------------------------
 
 
-def _random_pool(rng, n_blocks, bsz, nKV, hd):
-    k = rng.standard_normal((n_blocks, bsz, nKV, hd)).astype(np.float32)
-    v = rng.standard_normal((n_blocks, bsz, nKV, hd)).astype(np.float32)
-    return jnp.asarray(k), jnp.asarray(v)
+_LAYERS, _LAYER = 3, 1  # the op reads ONE layer of the whole stacked pool
 
 
-def _dense_reference(q, kp, vp, bt, valid):
+def _random_pool(rng, n_blocks, bsz, nKV, hd, dtype=np.float32):
+    """The stored layout: [L, n_blocks, bsz, nKV*hd], heads side by side."""
+    shape = (_LAYERS, n_blocks, bsz, nKV * hd)
+    k = rng.standard_normal(shape).astype(np.float32)
+    v = rng.standard_normal(shape).astype(np.float32)
+    return jnp.asarray(k, dtype), jnp.asarray(v, dtype)
+
+
+def _dense_reference(q, kp, vp, bt, valid, layer=_LAYER):
     """Gather + plain masked softmax attention in f64-free numpy."""
     R, nH, hd = q.shape
-    bsz, nKV = kp.shape[1], kp.shape[2]
+    bsz, nKV = kp.shape[2], kp.shape[3] // hd
     nb = bt.shape[1]
     group = nH // nKV
-    kc = np.asarray(kp)[np.asarray(bt).reshape(-1)].reshape(
+    kc = np.asarray(kp)[layer][np.asarray(bt).reshape(-1)].reshape(
         R, nb * bsz, nKV, hd
     )
-    vc = np.asarray(vp)[np.asarray(bt).reshape(-1)].reshape(
+    vc = np.asarray(vp)[layer][np.asarray(bt).reshape(-1)].reshape(
         R, nb * bsz, nKV, hd
     )
     qg = np.asarray(q).reshape(R, nKV, group, hd)
@@ -90,7 +95,7 @@ def test_paged_attention_xla_vs_dense(cpu_devices):
     )
     lengths = np.array([5, 17, nb * bsz - 1], np.int32)
     valid = jnp.asarray(np.arange(nb * bsz)[None, :] <= lengths[:, None])
-    out = paged_attention(q, kp, vp, bt, valid, impl="xla")
+    out = paged_attention(q, kp, vp, bt, valid, _LAYER, impl="xla")
     ref = _dense_reference(q, kp, vp, bt, valid)
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5, rtol=1e-5)
 
@@ -109,10 +114,48 @@ def test_paged_attention_pallas_vs_xla(cpu_devices):
     )
     lengths = np.array([0, 9, 30, nb * bsz - 1], np.int32)
     valid = jnp.asarray(np.arange(nb * bsz)[None, :] <= lengths[:, None])
-    a = paged_attention(q, kp, vp, bt, valid, impl="xla")
-    b = paged_attention(q, kp, vp, bt, valid, impl="pallas", interpret=True)
+    a = paged_attention(q, kp, vp, bt, valid, _LAYER, impl="xla")
+    b = paged_attention(
+        q, kp, vp, bt, valid, jnp.int32(_LAYER), impl="pallas", interpret=True
+    )
     np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5
+    )
+
+
+@pytest.mark.parametrize(
+    "nH,nKV,hd", [(14, 2, 64), (12, 2, 128)], ids=["0.5b", "1.5b"]
+)
+def test_paged_attention_pallas_at_published_heads(cpu_devices, nH, nKV, hd):
+    """The kernel at the head shapes the chip runs (Qwen2.5-0.5B: 14/2 of
+    64; the rollout cell's 1.5B: 12/2 of 128) and its page of 128 rows,
+    reading layer `_LAYER` of a bf16 4-D pool through the table, against a
+    float32 einsum over the gathered rows."""
+    rng = np.random.default_rng(4)
+    R, bsz, nb = 3, 128, 2
+    n_blocks = 1 + R * nb
+    kp, vp = _random_pool(rng, n_blocks, bsz, nKV, hd, jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((R, nH, hd)), jnp.bfloat16)
+    bt = jnp.asarray(
+        rng.permutation(np.arange(1, n_blocks)).astype(np.int32).reshape(R, nb)
+    )
+    lengths = np.array([0, 130, nb * bsz - 1], np.int32)
+    valid = jnp.asarray(np.arange(nb * bsz)[None, :] <= lengths[:, None])
+    out = paged_attention(
+        q, kp, vp, bt, valid, jnp.int32(_LAYER), impl="pallas", interpret=True
+    )
+    f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
+    kc = f32(kp)[_LAYER][bt].reshape(R, nb * bsz, nKV, hd)
+    vc = f32(vp)[_LAYER][bt].reshape(R, nb * bsz, nKV, hd)
+    qg = f32(q).reshape(R, nKV, nH // nKV, hd)
+    scores = jnp.einsum("rkgd,rskd->rkgs", qg, kc) / np.sqrt(hd)
+    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+    ref = jnp.einsum(
+        "rkgs,rskd->rkgd", jax.nn.softmax(scores, axis=-1), vc
+    ).reshape(R, nH, hd)
+    # the output is rounded to bf16 once; everything before it is f32
+    np.testing.assert_allclose(
+        np.asarray(f32(out)), np.asarray(ref), atol=2e-2, rtol=2e-2
     )
 
 
@@ -136,10 +179,10 @@ def test_decode_step_paged_matches_workspace(cpu_devices):
     L, nKV, hd = TINY.num_hidden_layers, TINY.num_key_value_heads, TINY.head_dim_
     R, bsz, nb, n_blocks = 3, 8, 3, 10
     kp = jnp.asarray(
-        rng.standard_normal((L, n_blocks, bsz, nKV, hd)).astype(np.float32)
+        rng.standard_normal((L, n_blocks, bsz, nKV * hd)).astype(np.float32)
     )
     vp = jnp.asarray(
-        rng.standard_normal((L, n_blocks, bsz, nKV, hd)).astype(np.float32)
+        rng.standard_normal((L, n_blocks, bsz, nKV * hd)).astype(np.float32)
     )
     bt = jnp.asarray(
         rng.choice(np.arange(1, n_blocks), size=(R, nb), replace=False)
@@ -156,8 +199,8 @@ def test_decode_step_paged_matches_workspace(cpu_devices):
     logits_ws, kc2, vc2 = decode_step(
         params, tokens, positions, kc, vc, TINY, active=active
     )
-    kp_ws = kp.at[:, idx].set(kc2.reshape(L, R * nb, bsz, nKV, hd))
-    vp_ws = vp.at[:, idx].set(vc2.reshape(L, R * nb, bsz, nKV, hd))
+    kp_ws = kp.at[:, idx].set(kc2.reshape(L, R * nb, bsz, nKV * hd))
+    vp_ws = vp.at[:, idx].set(vc2.reshape(L, R * nb, bsz, nKV * hd))
 
     logits_pg, kp_pg, vp_pg = decode_step_paged(
         params, tokens, positions, kp, vp, bt, TINY, active=active,

@@ -420,8 +420,9 @@ def test_paged_verify_op_pallas_matches_xla(cpu_devices):
     R, W, nH, nKV, hd, bsz, nb = 3, 4, 4, 2, 16, 8, 3
     n_blocks = 1 + R * nb
     q = rng.randn(R, W, nH, hd).astype(np.float32)
-    kp = rng.randn(n_blocks, bsz, nKV, hd).astype(np.float32)
-    vp = rng.randn(n_blocks, bsz, nKV, hd).astype(np.float32)
+    # the stored layout: [L, n_blocks, bsz, nKV*hd]; the op reads layer 1
+    kp = rng.randn(2, n_blocks, bsz, nKV * hd).astype(np.float32)
+    vp = rng.randn(2, n_blocks, bsz, nKV * hd).astype(np.float32)
     bt = np.arange(1, 1 + R * nb, dtype=np.int32).reshape(R, nb)
     base = np.array([5, 11, 0], dtype=np.int32)
     pos = base[:, None] + np.arange(W)[None, :]
@@ -429,7 +430,7 @@ def test_paged_verify_op_pallas_matches_xla(cpu_devices):
     import jax.numpy as jnp
 
     args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(bt), jnp.asarray(valid))
+            jnp.asarray(bt), jnp.asarray(valid), 1)
     out_x = paged_attention_qlen(*args, impl="xla")
     out_p = paged_attention_qlen(*args, impl="pallas", interpret=True)
     np.testing.assert_allclose(

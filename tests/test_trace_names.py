@@ -137,10 +137,13 @@ def test_every_program_has_its_pinned_name(programs):
 
 SCOPES = {
     # the decode chunk: the scan body, the model step inside it, the pool
-    # (the lowered text names the layer scan's body relative to itself)
+    # (the lowered text names the layer scan's body relative to itself).
+    # No `pool_read` here: in-pool the kernel alone reads the pool, in the
+    # layout it is stored in (tests/test_pool_in_place.py); the scope names
+    # the gathers of the xla impl and the workspace layout
     "chunk": ["decode_step", "decode_step/embed", "layer/attn/qkv", "layer/attn/rope",
               "layer/attn/kv_write/pool_write",
-              "layer/attn/attention/pool_read", "layer/attn/attention/paged_attention",
+              "layer/attn/attention/paged_attention",
               "layer/attn/o_proj", "layer/mlp", "decode_step/final_norm",
               "decode_step/lm_head", "decode_step/sample"],
     "prefill_batched": ["embed", "layer/attn/qkv", "layer/attn/rope",
@@ -210,14 +213,17 @@ def test_paged_kernel_is_named_at_the_1p5b_head_shape(one_chip):
 
     R, nH, nKV, hd, bsz, nb, n_blocks = 128, 12, 2, 128, 128, 10, 1281
 
-    def step(q, kp, vp, bt, valid):
+    L = 28  # the whole stacked pool, as the engine stores it, and a layer index
+    pool = ((L, n_blocks, bsz, nKV * hd), jnp.bfloat16)
+
+    def step(q, kp, vp, bt, valid, li):
         with jax.named_scope("layer"):
-            return paged_attention(q, kp, vp, bt, valid, impl="pallas", interpret=False)
+            return paged_attention(q, kp, vp, bt, valid, li, impl="pallas",
+                                   interpret=False)
 
     hlo = _compile(
-        step, one_chip, ((R, nH, hd), jnp.bfloat16),
-        ((n_blocks, bsz, nKV, hd), jnp.bfloat16), ((n_blocks, bsz, nKV, hd), jnp.bfloat16),
-        ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_))
+        step, one_chip, ((R, nH, hd), jnp.bfloat16), pool, pool,
+        ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_), ((), jnp.int32))
     assert "tpu_custom_call" in hlo
     assert "%paged_attention" in hlo and "%layer" not in hlo
 

@@ -164,29 +164,35 @@ def flash_chunk_case(T=1024, nH=N_HEADS, nKV=N_KV, hd=HEAD_DIM):
     )
 
 
-def paged_case(W, int8, R=16, nb=4):
+def paged_case(W, int8, nH, nKV, hd, R=16, nb=4, L=3, layer=2):
     """Paged decode (W == 1) / speculative verify (W > 1) kernel against
-    the op's XLA implementation, slots at different lengths."""
+    the op's XLA implementation, slots at different lengths. The pool is
+    the engine's: every layer stacked, [L, n_blocks, page, nKV*hd], read
+    at a layer index that is traced, as the layer scan's is."""
     n_blocks = R * nb + 1
     keys = jax.random.split(jax.random.PRNGKey(2), 3)
-    shape = (n_blocks, PAGE, N_KV, HEAD_DIM)
+    shape = (L, n_blocks, PAGE, nKV, hd)
     kp = jax.random.normal(keys[0], shape, jnp.bfloat16)
     vp = jax.random.normal(keys[1], shape, jnp.bfloat16)
+    rows = lambda a: a.reshape(L, n_blocks, PAGE, nKV * hd)  # noqa: E731
     if int8:
         (kq, ks), (vq, vs) = quantize_kv(kp), quantize_kv(vp)
-        # scale pools are [n_blocks, nKV, page]
-        kp, vp = (kq, jnp.swapaxes(ks, -1, -2)), (vq, jnp.swapaxes(vs, -1, -2))
-    q = jax.random.normal(keys[2], (R, W, N_HEADS, HEAD_DIM), jnp.bfloat16)
+        # scale pools are [L, n_blocks, nKV, page]
+        kp = (rows(kq), jnp.swapaxes(ks, -1, -2))
+        vp = (rows(vq), jnp.swapaxes(vs, -1, -2))
+    else:
+        kp, vp = rows(kp), rows(vp)
+    q = jax.random.normal(keys[2], (R, W, nH, hd), jnp.bfloat16)
     bt = jnp.arange(1, n_blocks, dtype=jnp.int32).reshape(R, nb)
     # slot r's first query sits at position 3 + 31 r (crosses page edges)
     base = 3 + 31 * jnp.arange(R)
     pos = base[:, None] + jnp.arange(W)[None, :]
     valid = jnp.arange(nb * PAGE)[None, None, :] <= pos[:, :, None]
     if W == 1:
-        args = (q[:, 0], kp, vp, bt, valid[:, 0])
+        args = (q[:, 0], kp, vp, bt, valid[:, 0], jnp.int32(layer))
         fn = paged_attention
     else:
-        args = (q, kp, vp, bt, valid)
+        args = (q, kp, vp, bt, valid, jnp.int32(layer))
         fn = paged_attention_qlen
     out = jax.jit(lambda *a: fn(*a, impl="pallas", interpret=False))(*args)
     ref = jax.jit(lambda *a: fn(*a, impl="xla"))(*args)
@@ -267,15 +273,22 @@ def cases():
         "default on >1 chip (ring)",
         flash_chunk_case,
     ))
-    out.append(("paged_attention bf16 14/2/64 page=128", "default",
-                lambda: paged_case(1, False)))
-    out.append(("paged_attention int8 14/2/64 page=128", 'kv_dtype="int8"',
-                lambda: paged_case(1, True)))
-    out.append(("paged_attention_qlen W=5 bf16", 'spec_decode="ngram"',
-                lambda: paged_case(5, False)))
-    out.append(("paged_attention_qlen W=5 int8",
-                'spec_decode="ngram" + kv_dtype="int8"',
-                lambda: paged_case(5, True)))
+    # the decode engine's head shapes: 0.5B (the loop below) and 1.5B (the
+    # rollout cell): rows of 128 and of 256 lanes
+    for nH, nKV, hd in ((N_HEADS, N_KV, HEAD_DIM), (12, 2, 128)):
+        heads = f"{nH}/{nKV}/{hd}"
+        for W, int8, path in (
+            (1, False, "default"),
+            (1, True, 'kv_dtype="int8"'),
+            (5, False, 'spec_decode="ngram"'),
+            (5, True, 'spec_decode="ngram" + kv_dtype="int8"'),
+        ):
+            op = "paged_attention" if W == 1 else f"paged_attention_qlen W={W}"
+            out.append((
+                f"{op} {'int8' if int8 else 'bf16'} {heads} page={PAGE}",
+                path,
+                lambda a=(W, int8, nH, nKV, hd): paged_case(*a),
+            ))
     for k_dims, out_dims in (
         ((HIDDEN,), (N_HEADS, HEAD_DIM)),  # q
         ((HIDDEN,), (N_KV, HEAD_DIM)),  # k, v
