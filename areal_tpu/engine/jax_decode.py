@@ -373,6 +373,8 @@ class _Inflight:
     # the true count is accepted[i] + 1.
     spec_w: int = 0
     accepted: Any = None  # jax [R] accepted draft tokens per slot
+    # MoE models: jax int32 [2], the chunk's expert load (see _get_chunk_fn)
+    moe_load: Any = None
     draft_lens: np.ndarray | None = None  # [R] host draft lengths dispatched
 
 
@@ -587,6 +589,11 @@ class JaxDecodeEngine(InferenceEngine):
         self._chunk_wall_itl_ms: deque = deque(maxlen=512)
         self._chunks_dispatched = 0
         self._runahead_discarded = 0  # run-ahead tokens dropped at reconcile
+        # MoE models: token-expert pairs the decode chunks computed (live
+        # slots, all layers and token steps), and those of the busiest
+        # expert of each layer and step: their ratio x E is max-over-mean load
+        self._moe_pairs = 0
+        self._moe_hot_pairs = 0
         self._chunk_fns: dict[bool, Callable] = {}
         # speculative verify-chunk variants, keyed (use_topp, nb, W)
         self._verify_fns: dict[tuple, Callable] = {}
@@ -849,6 +856,8 @@ class JaxDecodeEngine(InferenceEngine):
             self._chunk_wall_itl_ms = deque(maxlen=512)
             self._chunks_dispatched = 0
             self._runahead_discarded = 0
+            self._moe_pairs = 0
+            self._moe_hot_pairs = 0
             self._spec_hist = np.zeros(
                 max(int(self.config.spec_k), 1) + 1, dtype=np.int64
             )
@@ -1393,6 +1402,7 @@ class JaxDecodeEngine(InferenceEngine):
         paged_impl = self._paged_impl
         quant = self._kv_quant
         hd = cfg.head_dim_
+        moe = bool(cfg.num_experts)
 
         # sampler shared with the speculative verify chunk (see
         # _make_sample_fn) — per-slot exactness and the top_p==1 primary-key
@@ -1423,6 +1433,26 @@ class JaxDecodeEngine(InferenceEngine):
                     return tok, logp, lengths, counts
 
                 counts_init = counts0 if freq else jnp.zeros((), jnp.float32)
+                # MoE models: [pairs, busiest expert's pairs] summed over
+                # the chunk's steps and layers, live slots only, returned
+                # with the tokens. None (no leaf) for a dense model, whose
+                # compiled chunk is what it was.
+                load_init = jnp.zeros(2, jnp.int32) if moe else None
+
+                def model_step(step_fn, load, *a, **kw):
+                    """step_fn's (logits, k, v) and the load carried on."""
+                    if not moe:
+                        return (*step_fn(*a, **kw), None)
+                    *out, step_load = step_fn(*a, **kw, moe_load=True)
+                    return (*out, load + step_load)
+
+                def outputs(kp, vp, last, lengths, toks, logps, counts, load):
+                    out = (kp, vp, last, lengths, toks, logps)
+                    if freq:
+                        out += (counts,)
+                    if moe:
+                        out += (load,)
+                    return out
 
                 if paged and (paged_impl == "pallas" or quant):
                     # in-pool: the pool itself is the scan carry (donated,
@@ -1437,8 +1467,9 @@ class JaxDecodeEngine(InferenceEngine):
                     # would break).
                     @jax.named_scope("decode_step")
                     def step(carry, _):
-                        tokens, lengths, kpc, vpc, counts = carry
-                        logits, kpc, vpc = decode_step_paged(
+                        tokens, lengths, kpc, vpc, counts, load = carry
+                        logits, kpc, vpc, load = model_step(
+                            decode_step_paged, load,
                             params, tokens, lengths, kpc, vpc, bt, cfg,
                             active=active, rope_offset=rope_delta,
                             attn_impl=paged_impl,
@@ -1446,15 +1477,13 @@ class JaxDecodeEngine(InferenceEngine):
                         tok, logp, lengths, counts = finish_step(
                             logits, tokens, lengths, counts
                         )
-                        return (tok, lengths, kpc, vpc, counts), (tok, logp)
+                        return (tok, lengths, kpc, vpc, counts, load), (tok, logp)
 
-                    init = (last_tokens, lengths, kp, vp, counts_init)
-                    (last, lengths, kp, vp, counts), (toks, logps) = (
+                    init = (last_tokens, lengths, kp, vp, counts_init, load_init)
+                    (last, lengths, kp, vp, counts, load), (toks, logps) = (
                         jax.lax.scan(step, init, None, length=n_chunk)
                     )
-                    if freq:
-                        return kp, vp, last, lengths, toks, logps, counts
-                    return kp, vp, last, lengths, toks, logps
+                    return outputs(kp, vp, last, lengths, toks, logps, counts, load)
 
                 if paged:
                     # xla impl: gather once, scan the (bitwise-identical)
@@ -1476,18 +1505,19 @@ class JaxDecodeEngine(InferenceEngine):
 
                     @jax.named_scope("decode_step")
                     def step(carry, _):
-                        tokens, lengths, kc, vc, counts = carry
-                        logits, kc, vc = decode_step(
+                        tokens, lengths, kc, vc, counts, load = carry
+                        logits, kc, vc, load = model_step(
+                            decode_step, load,
                             params, tokens, lengths, kc, vc, cfg,
                             active=active, rope_offset=rope_delta,
                         )
                         tok, logp, lengths, counts = finish_step(
                             logits, tokens, lengths, counts
                         )
-                        return (tok, lengths, kc, vc, counts), (tok, logp)
+                        return (tok, lengths, kc, vc, counts, load), (tok, logp)
 
-                    init = (last_tokens, lengths, kc, vc, counts_init)
-                    (last, lengths, kc, vc, counts), (toks, logps) = (
+                    init = (last_tokens, lengths, kc, vc, counts_init, load_init)
+                    (last, lengths, kc, vc, counts, load), (toks, logps) = (
                         jax.lax.scan(step, init, None, length=n_chunk)
                     )
                     # delta write-back: the n_chunk rows per slot starting
@@ -1520,9 +1550,7 @@ class JaxDecodeEngine(InferenceEngine):
                         vp = vp.at[:, dblock.reshape(-1), doff.reshape(-1)].set(
                             rows_v.reshape(L, R * n_chunk, D)
                         )
-                    if freq:
-                        return kp, vp, last, lengths, toks, logps, counts
-                    return kp, vp, last, lengths, toks, logps
+                    return outputs(kp, vp, last, lengths, toks, logps, counts, load)
 
                 # workspace: gather each slot's blocks into a contiguous
                 # workspace, scan, scatter the blocks back
@@ -1539,26 +1567,25 @@ class JaxDecodeEngine(InferenceEngine):
 
                 @jax.named_scope("decode_step")
                 def step(carry, _):
-                    tokens, lengths, kc, vc, counts = carry
-                    logits, kc, vc = decode_step(
+                    tokens, lengths, kc, vc, counts, load = carry
+                    logits, kc, vc, load = model_step(
+                        decode_step, load,
                         params, tokens, lengths, kc, vc, cfg, active=active,
                         rope_offset=rope_delta,
                     )
                     tok, logp, lengths, counts = finish_step(
                         logits, tokens, lengths, counts
                     )
-                    return (tok, lengths, kc, vc, counts), (tok, logp)
+                    return (tok, lengths, kc, vc, counts, load), (tok, logp)
 
-                init = (last_tokens, lengths, kc, vc, counts_init)
-                (last, lengths, kc, vc, counts), (toks, logps) = (
+                init = (last_tokens, lengths, kc, vc, counts_init, load_init)
+                (last, lengths, kc, vc, counts, load), (toks, logps) = (
                     jax.lax.scan(step, init, None, length=n_chunk)
                 )
                 with jax.named_scope("pool_write"):
                     kp = kp.at[:, idx].set(kc.reshape(L, R * nb, bsz, D))
                     vp = vp.at[:, idx].set(vc.reshape(L, R * nb, bsz, D))
-                if freq:
-                    return kp, vp, last, lengths, toks, logps, counts
-                return kp, vp, last, lengths, toks, logps
+                return outputs(kp, vp, last, lengths, toks, logps, counts, load)
 
             return chunk
 
@@ -3619,28 +3646,17 @@ class JaxDecodeEngine(InferenceEngine):
                     self._freq_counts = jnp.zeros(
                         (R, self.model_config.vocab_size), jnp.float32
                     )
-                (
-                    kq,
-                    vq,
-                    self._dev_last,
-                    self._dev_lengths,
-                    toks,
-                    logps,
-                    self._freq_counts,
-                ) = chunk_fn(*args, ctl["freq_pens"], self._freq_counts)
+                out = chunk_fn(*args, ctl["freq_pens"], self._freq_counts)
             else:
-                (
-                    kq,
-                    vq,
-                    self._dev_last,
-                    self._dev_lengths,
-                    toks,
-                    logps,
-                ) = chunk_fn(*args)
+                out = chunk_fn(*args)
+            kq, vq, self._dev_last, self._dev_lengths, toks, logps, *rest = out
+            if use_freq:
+                self._freq_counts = rest.pop(0)
+            moe_load = rest.pop(0) if self.model_config.num_experts else None
             self._set_kv_operands(kq, vq)
         # start the device-to-host copies now; _consume_chunk's np.asarray
         # then only waits for data that isn't already on the host
-        for arr in (toks, logps):
+        for arr in (toks, logps, moe_load):
             copy_async = getattr(arr, "copy_to_host_async", None)
             if copy_async is not None:
                 copy_async()
@@ -3676,6 +3692,7 @@ class JaxDecodeEngine(InferenceEngine):
             t_dispatch=t_dispatch,
             n_chunk=n_chunk,
             chunk=self._chunks_dispatched,
+            moe_load=moe_load,
         )
 
     def _consume_chunk(self, rec: "_Inflight") -> None:
@@ -3685,6 +3702,11 @@ class JaxDecodeEngine(InferenceEngine):
                 toks = np.asarray(rec.toks)  # [n_chunk, R]
                 logps = np.asarray(rec.logps)
                 acc = np.asarray(rec.accepted) if rec.spec_w > 0 else None
+                if rec.moe_load is not None:
+                    pairs, hot = np.asarray(rec.moe_load).tolist()
+                    with self._metrics_lock:
+                        self._moe_pairs += pairs
+                        self._moe_hot_pairs += hot
             self._apply_chunk(rec, toks, logps, acc)
 
     def _apply_chunk(self, rec: "_Inflight", toks: np.ndarray,
@@ -4274,14 +4296,7 @@ class JaxDecodeEngine(InferenceEngine):
         ctl = self._refresh_ctl()
         with self._weight_lock:
             kq, vq = self._kv_operands()
-            (
-                kq,
-                vq,
-                self._dev_last,
-                self._dev_lengths,
-                _toks,
-                _logps,
-            ) = chunk_fn(
+            kq, vq, self._dev_last, self._dev_lengths, *_ = chunk_fn(
                 self.params,
                 kq,
                 vq,
@@ -5232,6 +5247,7 @@ class JaxDecodeEngine(InferenceEngine):
             gen_tokens = self._gen_token_count
             chunks_dispatched = self._chunks_dispatched
             runahead_discarded = self._runahead_discarded
+            moe_pairs, moe_hot_pairs = self._moe_pairs, self._moe_hot_pairs
             table_uploads = self._table_uploads
             ws_copy_bytes = self._ws_copy_bytes
             spec_hist = self._spec_hist.copy()
@@ -5321,6 +5337,10 @@ class JaxDecodeEngine(InferenceEngine):
             "decode_runahead_chunks": int(self.config.decode_runahead_chunks),
             "chunks_dispatched_total": chunks_dispatched,
             "runahead_discarded_tokens_total": runahead_discarded,
+            # MoE decode: pairs computed for live slots, and the busiest
+            # expert's share of them per layer and token step (0 for dense)
+            "moe_pairs_total": moe_pairs,
+            "moe_hot_expert_pairs_total": moe_hot_pairs,
             "device_busy_s": round(dev_busy_s, 6),
             "device_idle_s": round(dev_idle_s, 6),
             "device_idle_frac": (

@@ -23,15 +23,16 @@ pytree:
 
 Covers the reference's model families of record (realhf/api/from_hf/
 registry: qwen2, qwen3, llama, mistral, gemma, mixtral, qwen2_moe/qwen3_moe)
-— one decoder parameterized by flags rather than one module per family:
-activation (`hidden_act`), Gemma's zero-centered RMSNorm + sqrt(H)
-embedding scaling, Mixtral/Qwen2-MoE routing conventions and the Qwen2-MoE
-shared expert.
+and OLMoE — one decoder parameterized by flags rather than one module per
+family: activation (`hidden_act`), Gemma's zero-centered RMSNorm + sqrt(H)
+embedding scaling, Mixtral/Qwen2-MoE/OLMoE routing conventions, the
+Qwen2-MoE shared expert and OLMoE's full-width q/k norm.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 from dataclasses import dataclass
@@ -51,6 +52,15 @@ def _cstr(x: jax.Array, *logical_axes: str | None) -> jax.Array:
     from areal_tpu.parallel import mesh as mesh_lib
 
     return mesh_lib.constrain(x, *logical_axes)
+
+
+# `model_type`s `ModelConfig.from_hf_config` knows how to read. Anything
+# else raises: a config.json of an unlisted family would otherwise load as a
+# dense qwen2-shaped model with whatever keys happen to match.
+MODEL_TYPES = (
+    "qwen2", "qwen3", "llama", "mistral", "gemma", "gemma2", "gpt2",
+    "mixtral", "qwen2_moe", "qwen3_moe", "olmoe",
+)
 
 
 @dataclass(frozen=True)
@@ -79,8 +89,11 @@ class ModelConfig:
     model_type: str = "qwen2"
     # Qwen2/2.5: bias on qkv projections; Llama: none.
     qkv_bias: bool = True
-    # Qwen3: per-head RMSNorm on q and k.
+    # Qwen3: per-head RMSNorm on q and k. OLMoE norms the WHOLE q and k
+    # projections (nH*hd and nKV*hd wide) before the split into heads:
+    # `qk_norm_full`, set from the model type.
     qk_norm: bool = False
+    qk_norm_full: bool = False
     # Sliding-window attention (Mistral v0.1-class): each token attends at
     # most `sliding_window` positions back within its segment. None = full
     # causal. Served by the dense/prefill/decode paths; the Pallas
@@ -128,12 +141,12 @@ class ModelConfig:
     # (parity: the reference's AutoModelForTokenClassification path,
     # areal/engine/base_hf_engine.py:180-187)
     is_critic: bool = False
-    # -- MoE (Qwen3-MoE / Mixtral-class; reference MoE support lives in
-    # Megatron EP + realhf/impl/model/modules/moe/{router,experts}.py) --
-    # num_experts == 0 means dense MLP. Dispatch is GShard-style grouped
-    # einsum with a capacity factor: expert weights are stacked [E, ...] and
-    # sharded over the "experts" logical axis, so under GSPMD the dispatch
-    # einsums lower to all-to-alls over the EP mesh axes.
+    # -- MoE (Qwen3-MoE / Mixtral / OLMoE-class; reference MoE support
+    # lives in Megatron EP + realhf/impl/model/modules/moe/{router,experts}.py)
+    # -- num_experts == 0 means dense MLP. Routing is exact (dropless):
+    # token-expert pairs are sorted by expert and run through a grouped
+    # (ragged) matmul over the stacked [E, ...] expert kernels, which are
+    # sharded over the "experts" logical axis (see `moe_mlp`).
     num_experts: int = 0
     num_experts_per_tok: int = 8
     moe_intermediate_size: int | None = None
@@ -141,12 +154,7 @@ class ModelConfig:
     # through a sigmoid gate (0 = no shared expert).
     shared_expert_intermediate_size: int = 0
     norm_topk_prob: bool = True
-    capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.0
-    # token-group size for dispatch (memory of the dispatch tensor scales
-    # T * moe_group_size * top_k; smaller groups = less memory, slightly
-    # worse balance)
-    moe_group_size: int = 1024
     # vocab chunk for the fused LM-head loss (ops/fused_xent.py): peak
     # logits transient is [tokens, loss_vocab_chunk]
     loss_vocab_chunk: int = 16384
@@ -174,6 +182,12 @@ class ModelConfig:
         else:
             hf = dict(path_or_dict)
         model_type = hf.get("model_type", "qwen2")
+        if model_type not in MODEL_TYPES:
+            raise NotImplementedError(
+                f"model_type {model_type!r} is not in the registry "
+                f"{MODEL_TYPES}: loading it as a dense qwen2-shaped model "
+                "would silently drop what makes it that family"
+            )
         if model_type == "gpt2":
             # GPT2Config uses its own key names; normalize them up front so
             # the shared kw block below reads one schema.
@@ -259,7 +273,8 @@ class ModelConfig:
             max_position_embeddings=hf.get("max_position_embeddings", 32768),
             model_type=model_type,
             qkv_bias=model_type in ("qwen2", "qwen2_moe"),
-            qk_norm=model_type in ("qwen3", "qwen3_moe"),
+            qk_norm=model_type in ("qwen3", "qwen3_moe", "olmoe"),
+            qk_norm_full=model_type == "olmoe",
             # act_fn raises on anything unsupported, so an exotic
             # hidden_act fails loudly at trace time instead of silently
             # running silu.
@@ -305,6 +320,25 @@ class ModelConfig:
                 moe_intermediate_size=hf["intermediate_size"],
                 norm_topk_prob=True,
                 router_aux_loss_coef=hf.get("router_aux_loss_coef", 0.0),
+            )
+        elif model_type == "olmoe":
+            # OLMoE (transformers modeling_olmoe.py): softmax over all
+            # experts in float32, top-k, weights NOT renormalised; experts
+            # are `intermediate_size` wide (config.json has no expert-width
+            # key of its own); no shared expert, no biases. `clip_qkv`
+            # (a clamp on q/k/v) is null in the published configs.
+            if hf.get("clip_qkv") is not None or hf.get("attention_bias"):
+                raise NotImplementedError(
+                    "olmoe with clip_qkv (a q/k/v clamp) or attention_bias "
+                    "set is not implemented"
+                )
+            kw.update(
+                num_experts=hf.get("num_experts", 64),
+                num_experts_per_tok=hf.get("num_experts_per_tok", 8),
+                moe_intermediate_size=hf["intermediate_size"],
+                norm_topk_prob=hf.get("norm_topk_prob", False),
+                router_aux_loss_coef=hf.get("router_aux_loss_coef", 0.0),
+                rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
             )
         elif model_type == "gemma":
             # Gemma-1 (reference: realhf/api/from_hf/gemma.py — GeGLU MLP,
@@ -428,8 +462,9 @@ def _layer_shapes(cfg: ModelConfig) -> dict:
     if cfg.attn_out_bias:
         shapes["attn"]["o_bias"] = (H,)
     if cfg.qk_norm:
-        shapes["attn"]["q_norm"] = (hd,)
-        shapes["attn"]["k_norm"] = (hd,)
+        full = cfg.qk_norm_full
+        shapes["attn"]["q_norm"] = (nH * hd,) if full else (hd,)
+        shapes["attn"]["k_norm"] = (nKV * hd,) if full else (hd,)
     if cfg.norm_type == "layernorm":
         shapes["input_norm_bias"] = (H,)
         shapes["post_attn_norm_bias"] = (H,)
@@ -986,15 +1021,19 @@ def _norm(
     return rms_norm(x, weight, cfg.rms_norm_eps, cfg.norm_zero_centered)
 
 
+def _activation(hidden_act: str):
+    if hidden_act == "silu":
+        return jax.nn.silu
+    if hidden_act in ("gelu_pytorch_tanh", "gelu_new"):
+        return lambda x: jax.nn.gelu(x, approximate=True)
+    if hidden_act == "gelu":
+        return lambda x: jax.nn.gelu(x, approximate=False)
+    raise NotImplementedError(f"hidden_act={hidden_act!r}")
+
+
 def act_fn(cfg: ModelConfig):
     """MLP activation from cfg.hidden_act (HF ACT2FN-compatible subset)."""
-    if cfg.hidden_act == "silu":
-        return jax.nn.silu
-    if cfg.hidden_act in ("gelu_pytorch_tanh", "gelu_new"):
-        return lambda x: jax.nn.gelu(x, approximate=True)
-    if cfg.hidden_act == "gelu":
-        return lambda x: jax.nn.gelu(x, approximate=False)
-    raise NotImplementedError(f"hidden_act={cfg.hidden_act!r}")
+    return _activation(cfg.hidden_act)
 
 
 def _scale_embed(x: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -1184,6 +1223,20 @@ def resolve_attn_impl(cfg: ModelConfig) -> str:
     return "flash"
 
 
+def _qk_norm(q, k, layer_p: dict, cfg: ModelConfig):
+    """RMSNorm of q [..., nH, hd] and k [..., nKV, hd]: per head (Qwen3,
+    weight [hd]) or over the whole projection before the split into heads
+    (OLMoE, weights [nH*hd] and [nKV*hd])."""
+    if not cfg.qk_norm_full:
+        return _norm(q, layer_p["q_norm"], cfg), _norm(k, layer_p["k_norm"], cfg)
+
+    def full(t, w):
+        flat = t.reshape(*t.shape[:-2], t.shape[-2] * t.shape[-1])
+        return _norm(flat, w, cfg).reshape(t.shape)
+
+    return full(q, layer_p["q_norm"]), full(k, layer_p["k_norm"])
+
+
 @jax.named_scope("attn")
 def attention(
     layer_p: dict,
@@ -1208,8 +1261,7 @@ def attention(
             k = k + layer_p["k_bias"]
             v = v + layer_p["v_bias"]
         if cfg.qk_norm:
-            q = _norm(q, layer_p["q_norm"], cfg)
-            k = _norm(k, layer_p["k_norm"], cfg)
+            q, k = _qk_norm(q, k, layer_p, cfg)
     if cfg.pos_embed == "rope":
         with jax.named_scope("rope"):
             q = apply_rope(q, cos, sin)
@@ -1296,12 +1348,65 @@ def mlp(layer_p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     return _cstr(out, "tokens", "act_embed")
 
 
-def _moe_group_size(T: int, target: int) -> int:
-    """Largest divisor of T that is <= target (T is static under jit)."""
-    s = min(T, target)
-    while T % s != 0:
-        s -= 1
-    return s
+def _expert_mixture_plain(act, x, expert, gates, gate_k, up_k, down_k):
+    """y[t] = sum_k gates[t, k] * expert[t, k](x[t]) over stacked SwiGLU
+    experts `[E, H, M]`, `[E, H, M]`, `[E, M, H]`. `expert` [T, K] int32;
+    the id E routes a pair nowhere. The T*K pairs are sorted by expert and
+    the three matmuls run as grouped (ragged) matmuls."""
+    T, K = expert.shape
+    E, H = gate_k.shape[0], x.shape[-1]
+    with jax.named_scope("dispatch"):
+        flat = expert.reshape(T * K)
+        order = jnp.argsort(flat, stable=True)  # pair rows, by expert; E last
+        live = (flat < E)[order]
+        group_sizes = jnp.sum(
+            flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
+            axis=0,
+            dtype=jnp.int32,
+        )
+        # rows past the last group are not written by the grouped matmul:
+        # zero them going in and coming out, so nothing (and no gradient)
+        # of a pad row is ever read
+        xs = jnp.where(live[:, None], x[order // K], 0)  # [T*K, H]
+    with jax.named_scope("experts"):
+        h_gate = jax.lax.ragged_dot(xs, gate_k, group_sizes)
+        h_up = jax.lax.ragged_dot(xs, up_k, group_sizes)
+        ys = jax.lax.ragged_dot(act(h_gate) * h_up, down_k, group_sizes)
+    with jax.named_scope("combine"):
+        ys = jnp.where(live[:, None], ys, 0)
+        # back to pair order [T, K, H] by the inverse permutation (a gather,
+        # not a scatter-add), then the gate-weighted sum in float32
+        pairs = ys[jnp.argsort(order)].reshape(T, K, H).astype(jnp.float32)
+        return jnp.einsum("tkh,tk->th", pairs, gates).astype(x.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _expert_mixture(hidden_act: str):
+    """`_expert_mixture_plain` for the activation of that name, made safe under
+    `jax.vmap` (the decode engine's batched prefill vmaps a whole prefill,
+    and `ragged_dot` has no batching rule for it): exact routing treats
+    tokens independently, so a batch of sequences is folded into more
+    tokens of one call. `custom_vmap` has no reverse mode, so the trainer
+    differentiates the plain function through a `custom_vjp` around it."""
+    from jax.custom_batching import custom_vmap
+
+    plain = functools.partial(_expert_mixture_plain, _activation(hidden_act))
+    folded = custom_vmap(plain)
+
+    @folded.def_vmap
+    def _fold(axis_size, in_batched, x, expert, gates, *kernels):
+        if any(in_batched[3:]):
+            raise NotImplementedError("vmap over the expert kernels")
+        x, expert, gates = (
+            a if b else jnp.broadcast_to(a, (axis_size, *a.shape))
+            for a, b in zip((x, expert, gates), in_batched[:3])
+        )
+        y = folded(*(a.reshape(-1, a.shape[-1]) for a in (x, expert, gates)), *kernels)
+        return y.reshape(axis_size, -1, y.shape[-1]), True
+
+    mixture = jax.custom_vjp(folded)
+    mixture.defvjp(lambda *args: jax.vjp(plain, *args), lambda vjp, ct: vjp(ct))
+    return mixture
 
 
 @jax.named_scope("mlp")
@@ -1310,68 +1415,56 @@ def moe_mlp(
     x: jax.Array,
     cfg: ModelConfig,
     valid: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Grouped GShard-style MoE: top-k routing with a per-group capacity,
-    dense dispatch/combine einsums, experts stacked [E, ...].
+    with_load: bool = False,
+) -> tuple:
+    """Exact (dropless) top-k MoE: every one of a token's K experts is
+    computed, whatever the routing skew, in training, prefill and decode.
 
-    Returns (y [T, H], aux_loss scalar). Under GSPMD the dispatch einsums
-    contract the group/token dims against E-sharded expert weights — XLA
-    lowers that to all-to-alls over the mesh axes backing the "experts"
-    logical axis, which IS expert parallelism (no hand-written NCCL
-    grouped-GEMM path as in the reference's Megatron EP).
+    The T*K token-expert pairs are sorted by expert and the three expert
+    matmuls run as grouped (ragged) matmuls over the stacked [E, ...]
+    kernels (`jax.lax.ragged_dot`: a Mosaic grouped-matmul kernel on TPU,
+    a masked dense product on the CPU), then each token sums its K rows
+    under its gate weights. There is no capacity and no token group: a
+    token's result is a function of its own row alone, never of which
+    other tokens share the batch (decode log-probabilities and the
+    trainer's recomputed ones rest on that). `valid` [T] bool: pad rows of
+    a prefill bucket and dead decode slots are sorted past the last group,
+    so they are routed to no expert, cost no expert arithmetic and add
+    nothing.
+
+    Returns (y [T, H], aux_loss scalar), and with `with_load` also the
+    int32 pair `[pairs computed, pairs of the busiest expert]` over the
+    valid tokens (the decode engine's expert-load counters).
     """
-    T, H = x.shape
     E, K = cfg.num_experts, cfg.num_experts_per_tok
-    S = _moe_group_size(T, cfg.moe_group_size)
-    G = T // S
-    C = max(1, int(np.ceil(S * K / E * cfg.capacity_factor)))
 
-    router_logits = jnp.einsum(
-        "th,he->te", x.astype(jnp.float32), layer_p["router_kernel"].astype(jnp.float32)
+    with jax.named_scope("router"):
+        # float32 all the way: HIGHEST keeps a TPU from rounding float32
+        # operands to bf16 (bf16 operands are exact either way)
+        router_logits = jnp.einsum(
+            "th,he->te",
+            x.astype(jnp.float32),
+            layer_p["router_kernel"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        probs = jax.nn.softmax(router_logits, axis=-1)  # [T, E] float32
+        gate_vals, topk_idx = jax.lax.top_k(probs, K)  # [T, K]
+        if cfg.norm_topk_prob:
+            gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+        if valid is not None:
+            # expert id E sorts last and belongs to no group
+            topk_idx = jnp.where(valid[:, None], topk_idx, E)
+            gate_vals = jnp.where(valid[:, None], gate_vals, 0)
+
+    y = _expert_mixture(cfg.hidden_act)(
+        x, topk_idx, gate_vals,
+        layer_p["gate_kernel"], layer_p["up_kernel"], layer_p["down_kernel"],
     )
-    probs = jax.nn.softmax(router_logits, axis=-1)  # [T, E]
-    gate_vals, topk_idx = jax.lax.top_k(probs, K)  # [T, K]
-    if cfg.norm_topk_prob:
-        gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-    if valid is not None:
-        # Pad tokens neither claim expert capacity nor produce output.
-        gate_vals = gate_vals * valid[:, None].astype(gate_vals.dtype)
-
-    xg = x.reshape(G, S, H)
-    idx_g = topk_idx.reshape(G, S, K)
-    gates_g = gate_vals.reshape(G, S, K)
-    valid_g = None if valid is None else valid.reshape(G, S)
-
-    # Capacity assignment: k-th choices claim slots after all (k-1)-th
-    # choices (mesh-tf convention); overflow tokens are dropped for that
-    # expert (their gate weight is simply lost — capacity_factor > 1 keeps
-    # drops rare under balanced routing).
-    dispatch = jnp.zeros((G, S, E, C), dtype=x.dtype)
-    combine = jnp.zeros((G, S, E, C), dtype=jnp.float32)
-    counts = jnp.zeros((G, E), dtype=jnp.int32)
-    for k in range(K):
-        oh = jax.nn.one_hot(idx_g[..., k], E, dtype=jnp.int32)  # [G, S, E]
-        if valid_g is not None:
-            oh = oh * valid_g[..., None].astype(jnp.int32)
-        pos = jnp.cumsum(oh, axis=1) - oh + counts[:, None, :]  # [G, S, E]
-        keep = (pos < C) & (oh > 0)
-        slot_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32) * keep[..., None]
-        dispatch = dispatch + slot_oh.astype(x.dtype)
-        combine = combine + slot_oh * gates_g[..., k][..., None, None]
-        counts = counts + oh.sum(axis=1)
-
-    act = act_fn(cfg)
-    xe = jnp.einsum("gsec,gsh->gech", dispatch, xg)  # [G, E, C, H]
-    h_gate = jnp.einsum("gech,ehm->gecm", xe, layer_p["gate_kernel"])
-    h_up = jnp.einsum("gech,ehm->gecm", xe, layer_p["up_kernel"])
-    he = act(h_gate) * h_up
-    ye = jnp.einsum("gecm,emh->gech", he, layer_p["down_kernel"])
-    y = jnp.einsum("gsec,gech->gsh", combine.astype(ye.dtype), ye)
-    y = y.reshape(T, H).astype(x.dtype)
 
     if cfg.shared_expert_intermediate_size:
         # Qwen2-MoE shared expert: dense SwiGLU mixed in via a per-token
         # sigmoid gate (HF Qwen2MoeSparseMoeBlock semantics).
+        act = act_fn(cfg)
         s_gate = jnp.einsum("th,hm->tm", x, layer_p["shared_gate_kernel"])
         s_up = jnp.einsum("th,hm->tm", x, layer_p["shared_up_kernel"])
         sh = _cstr(act(s_gate) * s_up, "tokens", "act_mlp")
@@ -1401,6 +1494,9 @@ def moe_mlp(
         frac = assign.mean(axis=(0, 1))
         mean_prob = probs.mean(axis=0)
     aux = E * jnp.sum(frac * mean_prob)
+    if with_load:
+        per_expert = assign.sum(axis=(0, 1)).astype(jnp.int32)  # valid pairs
+        return y, aux, jnp.stack([per_expert.sum(), per_expert.max()])
     return y, aux
 
 
@@ -1872,15 +1968,21 @@ def _embed_tokens(params: dict, tokens, positions, cfg: ModelConfig) -> jax.Arra
         return x
 
 
-def _attn_out_mlp(layer_p: dict, x, attn_out, cfg: ModelConfig, valid):
+def _attn_out_mlp(layer_p: dict, x, attn_out, cfg: ModelConfig, valid,
+                  moe_load: bool = False):
     """What every decode-side layer body ends with: the output projection of
-    `attn_out` [N, nH, hd] and the MLP, each with its residual."""
+    `attn_out` [N, nH, hd] and the MLP, each with its residual. With
+    `moe_load` (MoE models only) returns (x, this layer's expert load, see
+    `moe_mlp`)."""
     with jax.named_scope("attn"), jax.named_scope("o_proj"):
         proj = _w_einsum("tnd,ndh->th", attn_out, layer_p["attn"]["o_kernel"], 2)
         if cfg.attn_out_bias:
             proj = proj + layer_p["attn"]["o_bias"]
     x = x + proj
     h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
+    if moe_load:
+        y, _, load = moe_mlp(layer_p["mlp"], h, cfg, valid=valid, with_load=True)
+        return x + y, load
     if cfg.num_experts:
         y, _ = moe_mlp(layer_p["mlp"], h, cfg, valid=valid)
     else:
@@ -1900,8 +2002,7 @@ def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig):
             k = k + layer_p["k_bias"]
             v = v + layer_p["v_bias"]
         if cfg.qk_norm:
-            q = _norm(q, layer_p["q_norm"], cfg)
-            k = _norm(k, layer_p["k_norm"], cfg)
+            q, k = _qk_norm(q, k, layer_p, cfg)
     cos_b = cos[..., None, :].astype(q.dtype)
     sin_b = sin[..., None, :].astype(q.dtype)
 
@@ -2092,13 +2193,15 @@ def decode_step(
     cfg: ModelConfig,
     active: jax.Array | None = None,  # [R] bool: slot holds a live request
     rope_offset: jax.Array | None = None,  # [R] added to rope pos only
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+    moe_load: bool = False,  # MoE only: also return the step's expert load
+) -> tuple:
     """One batched decode step over R slots.
 
     Writes this step's K/V at `positions` and attends over s <= position
-    per slot. Returns (logits [R, V], k_cache, v_cache). `active` keeps
-    MoE routing of dead slots from claiming expert capacity shared with
-    live ones.
+    per slot. Returns (logits [R, V], k_cache, v_cache), and with
+    `moe_load` a fourth: int32 [pairs, busiest expert's pairs] summed over
+    layers (`moe_mlp`). `active` keeps dead slots out of MoE routing: they
+    reach no expert and are not counted.
 
     `rope_offset` shifts the ROTARY position only (cache index unchanged):
     Qwen2-VL m-rope compresses an image's positions to max(t, h, w) per
@@ -2151,24 +2254,25 @@ def decode_step(
                 attn_out = jnp.einsum(
                     "rkgs,rskd->rkgd", probs, vc.astype(x.dtype)
                 ).reshape(R, nH, hd)
+        if moe_load:
+            x, load = _attn_out_mlp(layer_p, x, attn_out, cfg, active, True)
+            return x, (kc, vc, load)
         x = _attn_out_mlp(layer_p, x, attn_out, cfg, active)
         return x, (kc, vc)
 
     if cfg.scan_layers:
-        x, (k_cache, v_cache) = jax.lax.scan(
-            layer, x, (params["layers"], k_cache, v_cache)
-        )
+        x, ys = jax.lax.scan(layer, x, (params["layers"], k_cache, v_cache))
     else:
-        kcs, vcs = [], []
+        per_layer = []
         for i in range(cfg.num_hidden_layers):
-            x, (kc, vc) = layer(
-                x, (params[f"layers_{i}"], k_cache[i], v_cache[i])
-            )
-            kcs.append(kc)
-            vcs.append(vc)
-        k_cache, v_cache = jnp.stack(kcs), jnp.stack(vcs)
+            x, y = layer(x, (params[f"layers_{i}"], k_cache[i], v_cache[i]))
+            per_layer.append(y)
+        ys = tuple(jnp.stack(col) for col in zip(*per_layer))
+    k_cache, v_cache = ys[:2]
 
     logits = _final_logits(params, x, cfg)
+    if moe_load:
+        return logits, k_cache, v_cache, ys[2].sum(axis=0)
     return logits, k_cache, v_cache
 
 
@@ -2224,7 +2328,8 @@ def decode_step_paged(
     active: jax.Array | None = None,  # [R] bool: slot holds a live request
     rope_offset: jax.Array | None = None,  # [R] added to rope pos only
     attn_impl: str = "auto",  # ops/paged_attention.py impl select
-) -> tuple[jax.Array, Any, Any]:
+    moe_load: bool = False,  # MoE only: also return the step's expert load
+) -> tuple:
     """One batched decode step attending DIRECTLY over the paged pool.
 
     The in-pool twin of `decode_step` (same embed/rope/mlp/lm-head body;
@@ -2285,7 +2390,7 @@ def decode_step_paged(
 
     @jax.named_scope("layer")
     def layer(carry, layer_p, li):
-        x, kp, vp = carry
+        x, kp, vp, load = carry
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         with jax.named_scope("attn"):
             q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
@@ -2296,13 +2401,19 @@ def decode_step_paged(
                     q.reshape(R, nH, hd), kp, vp, block_tables, valid, li,
                     impl=attn_impl,
                 )
-        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active)
-        return x, kp, vp
+        if moe_load:
+            x, layer_load = _attn_out_mlp(layer_p, x, attn_out, cfg, active, True)
+            return x, kp, vp, load + layer_load
+        return _attn_out_mlp(layer_p, x, attn_out, cfg, active), kp, vp, None
 
-    x, k_pool, v_pool = _scan_layers_carrying(
-        layer, (x, k_pool, v_pool), params, cfg
+    # the load rides in the carry as None (no leaf) unless asked for
+    load0 = jnp.zeros(2, jnp.int32) if moe_load else None
+    x, k_pool, v_pool, load = _scan_layers_carrying(
+        layer, (x, k_pool, v_pool, load0), params, cfg
     )
     logits = _final_logits(params, x, cfg)
+    if moe_load:
+        return logits, k_pool, v_pool, load
     return logits, k_pool, v_pool
 
 

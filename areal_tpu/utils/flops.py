@@ -20,6 +20,8 @@ MoE: ff work is per-activated-expert (top_k), not per-parameter.
 
 from __future__ import annotations
 
+from areal_tpu.utils.hbm import expert_width
+
 
 # bf16 peak FLOP/s per chip by NORMALIZED device-kind substring (first
 # match wins; normalization strips spaces/dashes/underscores so GKE-style
@@ -68,9 +70,7 @@ def forward_flops_per_token(model_cfg, avg_context: float) -> float:
     attn = 4 * avg_context * nH * hd
     n_experts = getattr(model_cfg, "num_experts", 0) or 0
     if n_experts:
-        ff = getattr(model_cfg, "moe_intermediate_size", None) or (
-            model_cfg.intermediate_size
-        )
+        ff = expert_width(model_cfg)
         top_k = getattr(model_cfg, "num_experts_per_tok", 1) or 1
         mlp = 6 * d * ff * top_k + 2 * d * n_experts  # experts + router
     else:

@@ -91,6 +91,17 @@ def _dtype_bytes(dtype) -> int:
     raise ValueError(f"unrecognized dtype {dtype!r}")
 
 
+def expert_width(cfg) -> int:
+    """Width of one routed expert, read where the model reads it
+    (`ModelConfig.moe_intermediate_size_`: OLMoE and Mixtral experts are
+    `intermediate_size` wide); duck-typed configs fall back to the raw keys."""
+    return (
+        getattr(cfg, "moe_intermediate_size_", None)
+        or getattr(cfg, "moe_intermediate_size", None)
+        or cfg.intermediate_size
+    )
+
+
 def param_count(cfg) -> int:
     """Exact decoder parameter count for models/qwen2.py's layout."""
     d = cfg.hidden_size
@@ -105,9 +116,13 @@ def param_count(cfg) -> int:
         attn += (nH + 2 * nKV) * hd
     if getattr(cfg, "attn_out_bias", False):
         attn += d
+    if getattr(cfg, "qk_norm", False):
+        # per head (Qwen3) or over the whole q and k projections (OLMoE)
+        full = getattr(cfg, "qk_norm_full", False)
+        attn += (nH + nKV) * hd if full else 2 * hd
     n_experts = getattr(cfg, "num_experts", 0) or 0
     if n_experts:
-        ff = getattr(cfg, "moe_intermediate_size", None) or cfg.intermediate_size
+        ff = expert_width(cfg)
         mlp = n_experts * 3 * d * ff + d * n_experts  # experts + router
         shared = getattr(cfg, "shared_expert_intermediate_size", 0) or 0
         if shared:

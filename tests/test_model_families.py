@@ -133,8 +133,7 @@ def test_mixtral_numerical_parity(tmp_path):
     torch.manual_seed(0)
     model = MixtralForCausalLM(hf_cfg).eval().float()
     model_dir = _save_tiny(model, tmp_path, "mixtral")
-    # ample capacity: HF routes without drops; match it for the golden check
-    cfg, params = _parity(model, model_dir, 96, capacity_factor=8.0)
+    cfg, params = _parity(model, model_dir, 96)
     assert cfg.num_experts == 4 and cfg.norm_topk_prob
     assert cfg.moe_intermediate_size_ == 48
 
@@ -175,10 +174,54 @@ def test_qwen2_moe_numerical_parity(tmp_path):
     torch.manual_seed(0)
     model = Qwen2MoeForCausalLM(hf_cfg).eval().float()
     model_dir = _save_tiny(model, tmp_path, "qwen2_moe")
-    cfg, params = _parity(model, model_dir, 96, capacity_factor=8.0)
+    cfg, params = _parity(model, model_dir, 96)
     _decode_consistency(cfg, params)
     assert cfg.shared_expert_intermediate_size == 48
     assert cfg.qkv_bias and not cfg.norm_topk_prob
+
+
+def test_olmoe_numerical_parity(tmp_path):
+    """OLMoE: RMSNorm over the WHOLE q and k projections, float32 softmax
+    over all experts then an unnormalised top-k, experts as wide as
+    `intermediate_size`, no biases, untied head."""
+    from transformers import OlmoeConfig, OlmoeForCausalLM
+
+    hf_cfg = OlmoeConfig(
+        vocab_size=96,
+        hidden_size=32,
+        intermediate_size=16,
+        num_hidden_layers=2,
+        num_attention_heads=4,
+        num_key_value_heads=4,
+        num_experts=8,
+        num_experts_per_tok=2,
+        norm_topk_prob=False,
+        max_position_embeddings=128,
+        attn_implementation="eager",
+    )
+    torch.manual_seed(0)
+    model = OlmoeForCausalLM(hf_cfg).eval().float()
+    with torch.no_grad():  # HF inits norm weights to 1: a dropped norm hides
+        for n, p in model.named_parameters():
+            if "norm" in n:
+                p.add_(torch.randn_like(p) * 0.1)
+    model_dir = _save_tiny(model, tmp_path, "olmoe")
+    cfg, params = _parity(model, model_dir, 96)
+    _decode_consistency(cfg, params)
+    assert cfg.num_experts == 8 and cfg.moe_intermediate_size_ == 16
+    assert cfg.qk_norm and cfg.qk_norm_full and not cfg.norm_topk_prob
+    assert not cfg.qkv_bias and not cfg.tie_word_embeddings
+    assert params["layers"]["attn"]["q_norm"].shape == (2, 32)
+
+    out = save_hf_params(params, cfg, str(tmp_path / "ckpt"))
+    reloaded = load_hf_params(out, cfg, dtype="float32")
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-6
+        ),
+        params,
+        reloaded,
+    )
 
 
 def test_gpt2_numerical_parity(tmp_path):

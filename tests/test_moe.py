@@ -45,7 +45,7 @@ def test_moe_param_shapes_and_axes_align():
 
 
 def test_moe_mlp_matches_explicit_topk_reference():
-    """Dispatch/combine einsums == naive per-token top-k mixture (ample capacity)."""
+    """Sorted grouped matmuls == naive per-token top-k mixture."""
     rng = np.random.RandomState(0)
     T, H, E, K, Mm = 64, 16, 4, 2, 8
     cfg = ModelConfig(
@@ -53,7 +53,6 @@ def test_moe_mlp_matches_explicit_topk_reference():
         num_experts=E,
         num_experts_per_tok=K,
         moe_intermediate_size=Mm,
-        capacity_factor=8.0,  # no drops
         norm_topk_prob=True,
     )
     p = {
@@ -81,27 +80,42 @@ def test_moe_mlp_matches_explicit_topk_reference():
     assert np.isfinite(float(aux)) and float(aux) >= 1.0 - 1e-3  # >= 1 by Cauchy-Schwarz
 
 
-def test_moe_capacity_drops_tokens():
-    """With capacity 1 slot per expert, most assignments are dropped and the
-    output magnitude shrinks — but shapes and finiteness hold."""
+def test_moe_skewed_router_loses_no_pair():
+    """A router that sends most tokens to one expert loses no pair: there is
+    no capacity, so every token's output is its own expert's, exactly (this
+    test asserted the drop in the days of `capacity_factor`)."""
     rng = np.random.RandomState(1)
-    T, H, E = 32, 8, 2
+    T, H, E = 32, 8, 4
     cfg = ModelConfig(
         hidden_size=H, num_experts=E, num_experts_per_tok=1,
-        moe_intermediate_size=4, capacity_factor=0.06,  # C = 1
+        moe_intermediate_size=4, norm_topk_prob=False,
     )
+    router = rng.randn(H, E) * 0.01
+    x = rng.randn(T, H)
+    # bias the router along the tokens' mean direction towards expert 2
+    x[:, 0] = np.abs(x[:, 0]) + 1.0
+    router[0, 2] = 6.0
     p = {
-        "router_kernel": jnp.asarray(rng.randn(H, E), jnp.float32),
+        "router_kernel": jnp.asarray(router, jnp.float32),
         "gate_kernel": jnp.asarray(rng.randn(E, H, 4), jnp.float32),
         "up_kernel": jnp.asarray(rng.randn(E, H, 4), jnp.float32),
         "down_kernel": jnp.asarray(rng.randn(E, 4, H), jnp.float32),
     }
-    x = jnp.asarray(rng.randn(T, H), jnp.float32)
-    y, _ = moe_mlp(p, x, cfg)
-    assert y.shape == (T, H)
-    # dropped tokens produce zero rows
-    nonzero_rows = int((np.abs(np.asarray(y)).sum(-1) > 1e-6).sum())
-    assert nonzero_rows <= 2 * E  # at most C(=1) tokens per expert survive
+    x = jnp.asarray(x, jnp.float32)
+    y, _, load = moe_mlp(p, x, cfg, with_load=True)
+    probs = jax.nn.softmax(x @ p["router_kernel"], axis=-1)
+    idx = np.asarray(jnp.argmax(probs, axis=-1))
+    assert (idx == 2).sum() >= T - 4  # the skew is real: one expert, nearly all
+    assert load.tolist() == [T, int(np.bincount(idx, minlength=E).max())]
+    y_ref = np.zeros((T, H), np.float32)
+    for t in range(T):
+        e = int(idx[t])
+        xt = np.asarray(x[t])
+        g, u = xt @ np.asarray(p["gate_kernel"][e]), xt @ np.asarray(p["up_kernel"][e])
+        y_ref[t] = float(probs[t, e]) * ((g / (1 + np.exp(-g))) * u) @ np.asarray(
+            p["down_kernel"][e])
+    np.testing.assert_allclose(np.asarray(y), y_ref, atol=2e-4, rtol=2e-4)
+    assert (np.abs(np.asarray(y)).sum(-1) > 1e-6).all()  # no zero (dropped) row
 
 
 @pytest.mark.slow

@@ -170,6 +170,79 @@ def test_program_holds_its_scopes(programs, program):
     assert not missing, f"{program}: no operation under {missing}"
 
 
+TINY_MOE = ModelConfig(
+    vocab_size=64, hidden_size=32, intermediate_size=16, num_hidden_layers=2,
+    num_attention_heads=4, num_key_value_heads=4, dtype="float32", param_dtype="float32",
+    model_type="olmoe", qkv_bias=False, qk_norm=True, qk_norm_full=True, num_experts=8,
+    num_experts_per_tok=2, moe_intermediate_size=16, norm_topk_prob=False, attn_impl="dense",
+)
+# what the sparse-expert layer adds under `layer/mlp`, in every program that
+# runs it (PERF.md section 3)
+MOE_SCOPES = ["layer/mlp/router", "layer/mlp/dispatch", "layer/mlp/experts",
+              "layer/mlp/combine"]
+
+
+@pytest.fixture(scope="module")
+def moe_programs(cpu_devices):
+    """A tiny OLMoE-shaped decode engine (in-pool paged chunk, batched
+    prefill) and the packed training forward with its gradient, observed."""
+    from areal_tpu.engine.jax_decode import JaxDecodeEngine
+    from areal_tpu.models.qwen2 import forward
+
+    spy = _JitSpy()
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax, "jit", spy)
+    try:
+        params = init_params(TINY_MOE, jax.random.PRNGKey(0))
+        eng = JaxDecodeEngine(
+            JaxDecodeConfig(context_length=256, max_running_requests=4,
+                            new_tokens_per_chunk=4, page_size=128, dtype="float32",
+                            kv_cache_dtype="float32", paged_attn_impl="pallas"),
+            InferenceEngineConfig())
+        eng.set_model(params, TINY_MOE)
+        eng.initialize()
+        try:
+            eng.generate(ModelRequest(
+                input_ids=[1, 5, 9, 13, 2],
+                gconfig=GenerationHyperparameters(greedy=True, max_new_tokens=6)),
+                timeout=300)
+            counters = eng.get_metrics()
+        finally:
+            eng.destroy()
+
+        def grad_step(p, ids):
+            T = ids.shape[0]
+            return jax.grad(lambda q: forward(
+                q, ids, jnp.arange(T), jnp.zeros(T, jnp.int32), TINY_MOE).sum())(p)
+
+        jax.jit(grad_step)(params, jnp.arange(16, dtype=jnp.int32))
+    finally:
+        mp.undo()
+    return spy, counters
+
+
+@pytest.mark.parametrize("program", ["chunk", "prefill_batched", "grad_step"])
+def test_moe_program_holds_the_expert_scopes(moe_programs, program):
+    spy, _ = moe_programs
+    missing = [s for s in MOE_SCOPES if not _has_scope(spy.text[program], s)]
+    assert not missing, f"{program}: no operation under {missing}"
+    assert "ragged_dot" in spy.text[program]
+
+
+def test_moe_engine_counts_expert_load(moe_programs):
+    """`moe_pairs_total` and `moe_hot_expert_pairs_total` come back with the
+    chunk's tokens: one live slot, 2 experts a token, 2 layers, no expert
+    twice in a step."""
+    _, m = moe_programs
+    assert m["moe_pairs_total"] > 0 and m["moe_pairs_total"] % 4 == 0
+    assert m["moe_hot_expert_pairs_total"] * 2 == m["moe_pairs_total"]
+
+
+def test_dense_engine_reports_no_expert_load(programs):
+    spy, _ = programs
+    assert "ragged_dot" not in spy.text["chunk"] and "ragged_dot" not in spy.text["grad_step"]
+
+
 def test_train_batch_counts_padding_and_first_calls(programs):
     _, stats = programs
     s = stats[0]
@@ -226,6 +299,47 @@ def test_paged_kernel_is_named_at_the_1p5b_head_shape(one_chip):
         ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_), ((), jnp.int32))
     assert "tpu_custom_call" in hlo
     assert "%paged_attention" in hlo and "%layer" not in hlo
+
+
+def test_paged_kernel_is_named_at_olmoes_head_shape(one_chip):
+    """16 query and 16 KV heads of 128: a pool row of 4,096 lanes (K and V
+    2,048 each), the query block `[slots, 16, 2048]`, the cell's pool."""
+    from areal_tpu.ops.paged_attention import paged_attention
+
+    R, nH, nKV, hd, bsz, nb, L = 64, 16, 16, 128, 128, 10, 8
+    pool = ((L, R * nb + 1, bsz, nKV * hd), jnp.bfloat16)
+
+    def step(q, kp, vp, bt, valid, li):
+        return paged_attention(q, kp, vp, bt, valid, li, impl="pallas", interpret=False)
+
+    hlo = _compile(
+        step, one_chip, ((R, nH, hd), jnp.bfloat16), pool, pool,
+        ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_), ((), jnp.int32))
+    assert "tpu_custom_call" in hlo and "%paged_attention" in hlo
+
+
+@pytest.mark.parametrize("tokens", [64, 2048], ids=["decode_step", "batched_prefill"])
+def test_grouped_expert_matmuls_are_named_at_olmoes_widths(one_chip, tokens):
+    """XLA:TPU lowers `jax.lax.ragged_dot` to a Mosaic grouped matmul of its
+    own: three `%ragged-dot-none` custom calls a layer, which the
+    `expert_matmul_*` metrics match on."""
+    from areal_tpu.models.qwen2 import moe_mlp
+
+    H, M, E, K = 2048, 1024, 64, 8
+    cfg = ModelConfig(hidden_size=H, num_experts=E, num_experts_per_tok=K,
+                      moe_intermediate_size=M, norm_topk_prob=False)
+
+    def layer_mlp(x, valid, router, gate, up, down):
+        p = dict(router_kernel=router, gate_kernel=gate, up_kernel=up, down_kernel=down)
+        return moe_mlp(p, x, cfg, valid=valid, with_load=True)
+
+    bf = jnp.bfloat16
+    hlo = _compile(
+        layer_mlp, one_chip, ((tokens, H), bf), ((tokens,), jnp.bool_), ((H, E), bf),
+        ((E, H, M), bf), ((E, H, M), bf), ((E, M, H), bf))
+    names = set(re.findall(r"%(ragged-dot-none(?:\.\d+)?) = ", hlo))
+    assert len(names) == 3, names
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
 
 
 def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):

@@ -203,6 +203,47 @@ def paged_case(W, int8, nH, nKV, hd, R=16, nb=4, L=3, layer=2):
     return err < 0.03 and bool(jnp.all(jnp.isfinite(out))), f"maxerr={err:.4f}"
 
 
+def moe_case(T, H=2048, M=1024, E=64, K=8, dead_every=5):
+    """The exact MoE mixture (`models/qwen2.py:moe_mlp`: pairs sorted by
+    expert, XLA's grouped matmul for `jax.lax.ragged_dot`) at OLMoE's
+    published widths against every expert run densely on every token in
+    float32 and masked by the top-k; some rows are dead slots."""
+    from areal_tpu.models.qwen2 import ModelConfig, moe_mlp
+
+    cfg = ModelConfig(hidden_size=H, num_experts=E, num_experts_per_tok=K,
+                      moe_intermediate_size=M, norm_topk_prob=False)
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    p = {
+        "router_kernel": jax.random.normal(ks[0], (H, E), jnp.bfloat16) / H**0.5,
+        "gate_kernel": jax.random.normal(ks[1], (E, H, M), jnp.bfloat16) / H**0.5,
+        "up_kernel": jax.random.normal(ks[2], (E, H, M), jnp.bfloat16) / H**0.5,
+        "down_kernel": jax.random.normal(ks[3], (E, M, H), jnp.bfloat16) / M**0.5,
+    }
+    x = jax.random.normal(ks[4], (T, H), jnp.bfloat16)
+    valid = jnp.arange(T) % dead_every != 0
+
+    def dense(p, x):
+        with jax.default_matmul_precision("highest"):
+            x32 = x.astype(jnp.float32)
+            probs = jax.nn.softmax(x32 @ p["router_kernel"].astype(jnp.float32), -1)
+            w, idx = jax.lax.top_k(probs, K)
+            dw = (jax.nn.one_hot(idx, E) * w[..., None]).sum(1) * valid[:, None]
+
+            def one(acc, xs):
+                g, u, d, w_e = (a.astype(jnp.float32) for a in xs)
+                return acc + w_e[:, None] * ((jax.nn.silu(x32 @ g) * (x32 @ u)) @ d), None
+
+            return jax.lax.scan(one, jnp.zeros((T, H), jnp.float32),
+                                (p["gate_kernel"], p["up_kernel"], p["down_kernel"], dw.T))[0]
+
+    y, _, load = jax.jit(lambda p, x: moe_mlp(p, x, cfg, valid=valid, with_load=True))(p, x)
+    ref = jax.jit(dense)(p, x)
+    err = _rel(y.astype(jnp.float32), ref)
+    dead = float(jnp.abs(y[~valid].astype(jnp.float32)).max())
+    ok = err < 0.03 and dead == 0.0 and int(load[0]) == int(valid.sum()) * K
+    return ok, f"relerr={err:.4f} dead_rows_max={dead} pairs={int(load[0])} hot={int(load[1])}"
+
+
 def quant_matmul_case(k_dims, out_dims, T=16):
     """Int8 dequant-matmul kernel against the op's XLA implementation."""
     kx, kw = jax.random.split(jax.random.PRNGKey(3))
@@ -273,9 +314,9 @@ def cases():
         "default on >1 chip (ring)",
         flash_chunk_case,
     ))
-    # the decode engine's head shapes: 0.5B (the loop below) and 1.5B (the
-    # rollout cell): rows of 128 and of 256 lanes
-    for nH, nKV, hd in ((N_HEADS, N_KV, HEAD_DIM), (12, 2, 128)):
+    # the decode engine's head shapes: 0.5B (the loop below), 1.5B and
+    # OLMoE (the rollout cells): rows of 128, of 256 and of 2,048 lanes
+    for nH, nKV, hd in ((N_HEADS, N_KV, HEAD_DIM), (12, 2, 128), (16, 16, 128)):
         heads = f"{nH}/{nKV}/{hd}"
         for W, int8, path in (
             (1, False, "default"),
@@ -289,6 +330,13 @@ def cases():
                 path,
                 lambda a=(W, int8, nH, nKV, hd): paged_case(*a),
             ))
+    # OLMoE's experts: a decode step's rows and a batched prefill's
+    for T in (64, 2048):
+        out.append((
+            f"moe_mlp exact top-8 of 64 (ragged_dot) T={T} 2048x1024",
+            "default for MoE models",
+            lambda T=T: moe_case(T),
+        ))
     for k_dims, out_dims in (
         ((HIDDEN,), (N_HEADS, HEAD_DIM)),  # q
         ((HIDDEN,), (N_KV, HEAD_DIM)),  # k, v
