@@ -1348,13 +1348,23 @@ def mlp(layer_p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     return _cstr(out, "tokens", "act_embed")
 
 
-def _expert_mixture_plain(act, x, expert, gates, gate_k, up_k, down_k):
+def _expert_mixture_plain(
+    act, E, x, expert, gates, gate_k, up_k, down_k, first_group=None
+):
     """y[t] = sum_k gates[t, k] * expert[t, k](x[t]) over stacked SwiGLU
-    experts `[E, H, M]`, `[E, H, M]`, `[E, M, H]`. `expert` [T, K] int32;
-    the id E routes a pair nowhere. The T*K pairs are sorted by expert and
-    the three matmuls run as grouped (ragged) matmuls."""
+    experts `[G, H, M]`, `[G, H, M]`, `[G, M, H]`. `expert` [T, K] int32
+    in [0, E]; the id E routes a pair nowhere. The T*K pairs are sorted by
+    expert and the three matmuls run as grouped (ragged) matmuls.
+
+    G is E (one layer's kernels, `first_group` None: the trainer, and
+    unstacked layers), or a multiple of it: the kernels of every layer
+    `[L*E, ...]`, of which this call's E experts are the groups from
+    `first_group` (int32 scalar, `li * E`) on. The other groups are empty
+    and cost the grouped matmul nothing; what it buys is that a layer loop
+    hands the matmul the stacked leaf itself and not a slice of it, which
+    XLA:TPU copies for the custom call (`_scan_stacked`)."""
     T, K = expert.shape
-    E, H = gate_k.shape[0], x.shape[-1]
+    G, H = gate_k.shape[0], x.shape[-1]
     with jax.named_scope("dispatch"):
         flat = expert.reshape(T * K)
         order = jnp.argsort(flat, stable=True)  # pair rows, by expert; E last
@@ -1364,6 +1374,10 @@ def _expert_mixture_plain(act, x, expert, gates, gate_k, up_k, down_k):
             axis=0,
             dtype=jnp.int32,
         )
+        if first_group is not None:
+            group_sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros(G, jnp.int32), group_sizes, (first_group,)
+            )
         # rows past the last group are not written by the grouped matmul:
         # zero them going in and coming out, so nothing (and no gradient)
         # of a pad row is ever read
@@ -1381,27 +1395,30 @@ def _expert_mixture_plain(act, x, expert, gates, gate_k, up_k, down_k):
 
 
 @functools.lru_cache(maxsize=None)
-def _expert_mixture(hidden_act: str):
-    """`_expert_mixture_plain` for the activation of that name, made safe under
-    `jax.vmap` (the decode engine's batched prefill vmaps a whole prefill,
-    and `ragged_dot` has no batching rule for it): exact routing treats
-    tokens independently, so a batch of sequences is folded into more
-    tokens of one call. `custom_vmap` has no reverse mode, so the trainer
+def _expert_mixture(hidden_act: str, num_experts: int):
+    """`_expert_mixture_plain` for that activation and number of experts,
+    made safe under `jax.vmap` (the decode engine's batched prefill vmaps a
+    whole prefill, and `ragged_dot` has no batching rule for it): exact
+    routing treats tokens independently, so a batch of sequences is folded
+    into more tokens of one call; the kernels and the first group stay as
+    they are. `custom_vmap` has no reverse mode, so the trainer
     differentiates the plain function through a `custom_vjp` around it."""
     from jax.custom_batching import custom_vmap
 
-    plain = functools.partial(_expert_mixture_plain, _activation(hidden_act))
+    plain = functools.partial(
+        _expert_mixture_plain, _activation(hidden_act), num_experts
+    )
     folded = custom_vmap(plain)
 
     @folded.def_vmap
-    def _fold(axis_size, in_batched, x, expert, gates, *kernels):
-        if any(in_batched[3:]):
+    def _fold(axis_size, in_batched, x, expert, gates, *unbatched):
+        if any(jax.tree.leaves(in_batched[3:])):
             raise NotImplementedError("vmap over the expert kernels")
         x, expert, gates = (
             a if b else jnp.broadcast_to(a, (axis_size, *a.shape))
             for a, b in zip((x, expert, gates), in_batched[:3])
         )
-        y = folded(*(a.reshape(-1, a.shape[-1]) for a in (x, expert, gates)), *kernels)
+        y = folded(*(a.reshape(-1, a.shape[-1]) for a in (x, expert, gates)), *unbatched)
         return y.reshape(axis_size, -1, y.shape[-1]), True
 
     mixture = jax.custom_vjp(folded)
@@ -1456,9 +1473,12 @@ def moe_mlp(
             topk_idx = jnp.where(valid[:, None], topk_idx, E)
             gate_vals = jnp.where(valid[:, None], gate_vals, 0)
 
-    y = _expert_mixture(cfg.hidden_act)(
+    # a forward-only scan over stacked layers hands over every layer's
+    # kernels and where this layer's groups start (`_scan_stacked`)
+    y = _expert_mixture(cfg.hidden_act, E)(
         x, topk_idx, gate_vals,
         layer_p["gate_kernel"], layer_p["up_kernel"], layer_p["down_kernel"],
+        layer_p.get("first_group"),
     )
 
     if cfg.shared_expert_intermediate_size:
@@ -2019,6 +2039,40 @@ def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig):
     return rot(q), rot(k), v
 
 
+_EXPERT_KERNELS = ("gate_kernel", "up_kernel", "down_kernel")
+
+
+def _scan_stacked(step, carry, params, cfg: ModelConfig, *xs):
+    """`lax.scan(step, carry, (params["layers"], *xs))` over the stacked
+    layers of a forward-only program, with an MoE model's expert kernels
+    read in place. As `xs` each layer's `[E, H, M]` kernels would reach the
+    grouped matmul as a slice of the stacked leaf; XLA:TPU runs that matmul
+    as a custom call, which cannot read through a slice, so the slice was
+    copied: 0.8 GB a layer a token step at OLMoE's widths, 46% of a decode
+    chunk (PERF.md, PR 27). So the three leaves stay out of `xs`: the body
+    closes over them whole, reshaped `[L, E, ...] -> [L*E, ...]` (a
+    bitcast), and the layer's mlp carries `first_group`, the index of its
+    first expert among the L*E groups (`_expert_mixture_plain`). Not for
+    the trainer: the gradient of a whole stack a layer would cost it
+    L-fold. A dense model has no such leaves and scans as written."""
+    layers = params["layers"]
+    E = cfg.num_experts
+    if not E:
+        return jax.lax.scan(step, carry, (layers, *xs))
+    mlp = layers["mlp"]
+    stack = {k: mlp[k].reshape(-1, *mlp[k].shape[2:]) for k in _EXPERT_KERNELS}
+    per_layer = {k: v for k, v in mlp.items() if k not in stack}
+    per_layer["first_group"] = jnp.arange(
+        0, cfg.num_hidden_layers * E, E, dtype=jnp.int32
+    )
+
+    def in_place(c, inputs):
+        layer_p = {**inputs[0], "mlp": {**inputs[0]["mlp"], **stack}}
+        return step(c, (layer_p, *inputs[1:]))
+
+    return jax.lax.scan(in_place, carry, ({**layers, "mlp": per_layer}, *xs))
+
+
 def prefill(
     params: dict,
     input_ids: jax.Array,
@@ -2096,15 +2150,13 @@ def prefill(
 
     @jax.named_scope("layer")
     def layer(x, inputs):
-        if with_prefix:
-            layer_p, pk, pv = inputs
-        else:
-            layer_p = inputs
+        layer_p, *prefix = inputs
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         with jax.named_scope("attn"):
             q, k, v = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
             with jax.named_scope("attention"):
                 if with_prefix:
+                    pk, pv = prefix
                     kk = jnp.concatenate([pk.astype(k.dtype), k], axis=0)
                     vv = jnp.concatenate([pv.astype(v.dtype), v], axis=0)
                 else:
@@ -2120,22 +2172,13 @@ def prefill(
         x = _attn_out_mlp(layer_p, x, attn_out, cfg, valid)
         return x, (k, v)
 
+    prefix = (prefix_k, prefix_v) if with_prefix else ()
     if cfg.scan_layers:
-        xs = (
-            (params["layers"], prefix_k, prefix_v)
-            if with_prefix
-            else params["layers"]
-        )
-        x, (ks, vs) = jax.lax.scan(layer, x, xs)
+        x, (ks, vs) = _scan_stacked(layer, x, params, cfg, *prefix)
     else:
         ks_list, vs_list = [], []
         for i in range(cfg.num_hidden_layers):
-            inputs = (
-                (params[f"layers_{i}"], prefix_k[i], prefix_v[i])
-                if with_prefix
-                else params[f"layers_{i}"]
-            )
-            x, (k, v) = layer(x, inputs)
+            x, (k, v) = layer(x, (params[f"layers_{i}"], *(p[i] for p in prefix)))
             ks_list.append(k)
             vs_list.append(v)
         ks, vs = jnp.stack(ks_list), jnp.stack(vs_list)
@@ -2261,7 +2304,7 @@ def decode_step(
         return x, (kc, vc)
 
     if cfg.scan_layers:
-        x, ys = jax.lax.scan(layer, x, (params["layers"], k_cache, v_cache))
+        x, ys = _scan_stacked(layer, x, params, cfg, k_cache, v_cache)
     else:
         per_layer = []
         for i in range(cfg.num_hidden_layers):
@@ -2307,10 +2350,12 @@ def _scan_layers_carrying(layer, carry, params, cfg: ModelConfig):
     ys buffer cannot alias the token-step scan's carry, which cost a copy
     of both pools every token step (PERF.md, PR 24's trace)."""
     if cfg.scan_layers:
-        return jax.lax.scan(
+        return _scan_stacked(
             lambda c, xs: (layer(c, *xs), None),
             carry,
-            (params["layers"], jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)),
+            params,
+            cfg,
+            jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32),
         )[0]
     for i in range(cfg.num_hidden_layers):
         carry = layer(carry, params[f"layers_{i}"], i)
@@ -2496,8 +2541,8 @@ def verify_step(
         return x, (kc, vc)
 
     if cfg.scan_layers:
-        x, (k_cache, v_cache) = jax.lax.scan(
-            layer, x, (params["layers"], k_cache, v_cache)
+        x, (k_cache, v_cache) = _scan_stacked(
+            layer, x, params, cfg, k_cache, v_cache
         )
     else:
         kcs, vcs = [], []

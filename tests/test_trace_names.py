@@ -342,6 +342,45 @@ def test_grouped_expert_matmuls_are_named_at_olmoes_widths(one_chip, tokens):
     assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
 
 
+def test_stacked_decode_step_reads_olmoes_experts_in_place(one_chip):
+    """A decode step over two stacked layers at OLMoE's widths: the layer
+    loop holds three `%ragged-dot-none` calls whose weight operand is the
+    whole stacked leaf, and nothing (the parent had a
+    `%dynamic-slice_bitcast_fusion` per kernel, 0.68 ms each on the chip)
+    has one layer's kernels as its result."""
+    from areal_tpu.models.qwen2 import decode_step_paged, param_shapes
+
+    L, R, nb, bsz = 2, 64, 2, 128
+    cfg = ModelConfig(
+        vocab_size=50304, hidden_size=2048, intermediate_size=1024, num_hidden_layers=L,
+        num_attention_heads=16, num_key_value_heads=16, model_type="olmoe", qkv_bias=False,
+        qk_norm=True, qk_norm_full=True, num_experts=64, num_experts_per_tok=8,
+        moe_intermediate_size=1024, norm_topk_prob=False, tie_word_embeddings=False,
+        dtype="bfloat16", param_dtype="bfloat16")
+    bf = jnp.bfloat16
+    arg = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(lambda s: arg(s, bf), param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    pool = arg((L, R * nb + 1, bsz, 16 * 128), bf)
+
+    def step(params, kp, vp, bt, tokens, positions, active):
+        return decode_step_paged(params, tokens, positions, kp, vp, bt, cfg,
+                                 active=active, attn_impl="pallas", moe_load=True)
+
+    hlo = jax.jit(step, donate_argnums=(1, 2)).trace(
+        params, pool, pool, arg((R, nb), jnp.int32), arg((R,), jnp.int32),
+        arg((R,), jnp.int32), arg((R,), jnp.bool_),
+    ).lower(lowering_platforms=("tpu",)).compile().as_text()
+    calls = re.findall(r"%ragged-dot-none(?:\.\d+)? = \S+ custom-call\(([^)]*)\)", hlo)
+    assert len(calls) == 3, calls
+    # each reads the stacked leaf itself: `[L*E, H, M]`, a bitcast of the argument
+    stacked = set(re.findall(
+        r"(%\S+) = bf16\[128,(?:2048,1024|1024,2048)\]\S* (?:bitcast|get-tuple-element)\(", hlo))
+    assert all(set(c.replace(" ", "").split(",")) & stacked for c in calls), (calls, stacked)
+    copied = re.findall(r"%(\S+) = bf16\[64,(?:2048,1024|1024,2048)\]", hlo)
+    assert not copied, copied
+
+
 def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):
     from areal_tpu.ops.flash_attention import flash_attention
 

@@ -203,23 +203,39 @@ def paged_case(W, int8, nH, nKV, hd, R=16, nb=4, L=3, layer=2):
     return err < 0.03 and bool(jnp.all(jnp.isfinite(out))), f"maxerr={err:.4f}"
 
 
-def moe_case(T, H=2048, M=1024, E=64, K=8, dead_every=5):
+def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5):
     """The exact MoE mixture (`models/qwen2.py:moe_mlp`: pairs sorted by
     expert, XLA's grouped matmul for `jax.lax.ragged_dot`) at OLMoE's
     published widths against every expert run densely on every token in
-    float32 and masked by the top-k; some rows are dead slots."""
+    float32 and masked by the top-k; some rows are dead slots, the live
+    groups are uneven and every seventh expert is empty (row 0 of the
+    router kernel votes it down).
+
+    With `layers` > 1, as a stacked layer loop of the decode engine calls
+    it (`_scan_stacked`): the grouped matmul's operand is the kernels of
+    all the layers `[layers*E, H, M]`, layer `li`'s experts are the groups
+    from `li*E` on and the others are empty; also timed against the same
+    call on that layer's own `[E, H, M]` kernels, since the empty groups
+    must cost nothing."""
+    import time
+
     from areal_tpu.models.qwen2 import ModelConfig, moe_mlp
 
     cfg = ModelConfig(hidden_size=H, num_experts=E, num_experts_per_tok=K,
                       moe_intermediate_size=M, norm_topk_prob=False)
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
-    p = {
-        "router_kernel": jax.random.normal(ks[0], (H, E), jnp.bfloat16) / H**0.5,
-        "gate_kernel": jax.random.normal(ks[1], (E, H, M), jnp.bfloat16) / H**0.5,
-        "up_kernel": jax.random.normal(ks[2], (E, H, M), jnp.bfloat16) / H**0.5,
-        "down_kernel": jax.random.normal(ks[3], (E, M, H), jnp.bfloat16) / M**0.5,
+    G = layers * E
+    router = jax.random.normal(ks[0], (H, E), jnp.bfloat16) / H**0.5
+    stack = {
+        "gate_kernel": jax.random.normal(ks[1], (G, H, M), jnp.bfloat16) / H**0.5,
+        "up_kernel": jax.random.normal(ks[2], (G, H, M), jnp.bfloat16) / H**0.5,
+        "down_kernel": jax.random.normal(ks[3], (G, M, H), jnp.bfloat16) / M**0.5,
     }
-    x = jax.random.normal(ks[4], (T, H), jnp.bfloat16)
+    # the layer's own kernels, and what the engine's call hands over
+    p = {k: w[li * E:(li + 1) * E] for k, w in stack.items()}
+    p["router_kernel"] = router.at[0, ::7].set(-10.0)
+    called = p if layers == 1 else {**p, **stack, "first_group": jnp.int32(li * E)}
+    x = jax.random.normal(ks[4], (T, H), jnp.bfloat16).at[:, 0].set(8.0)
     valid = jnp.arange(T) % dead_every != 0
 
     def dense(p, x):
@@ -236,12 +252,27 @@ def moe_case(T, H=2048, M=1024, E=64, K=8, dead_every=5):
             return jax.lax.scan(one, jnp.zeros((T, H), jnp.float32),
                                 (p["gate_kernel"], p["up_kernel"], p["down_kernel"], dw.T))[0]
 
-    y, _, load = jax.jit(lambda p, x: moe_mlp(p, x, cfg, valid=valid, with_load=True))(p, x)
+    mlp = jax.jit(lambda p, x: moe_mlp(p, x, cfg, valid=valid, with_load=True))
+    y, _, load = mlp(called, x)
     ref = jax.jit(dense)(p, x)
     err = _rel(y.astype(jnp.float32), ref)
     dead = float(jnp.abs(y[~valid].astype(jnp.float32)).max())
     ok = err < 0.03 and dead == 0.0 and int(load[0]) == int(valid.sum()) * K
-    return ok, f"relerr={err:.4f} dead_rows_max={dead} pairs={int(load[0])} hot={int(load[1])}"
+    detail = f"relerr={err:.4f} dead_rows_max={dead} pairs={int(load[0])} hot={int(load[1])}"
+    if layers == 1:
+        return ok, detail
+
+    def ms(p, n=30):
+        mlp(p, x)[0].block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = mlp(p, x)[0]
+        out.block_until_ready()
+        return (time.perf_counter() - t0) / n * 1e3
+
+    t_own, t_stack = ms(p), ms(called)
+    return ok and t_stack < 1.25 * t_own, (
+        f"{detail} ms_per_call: {G} groups {t_stack:.3f}, {E} groups {t_own:.3f}")
 
 
 def quant_matmul_case(k_dims, out_dims, T=16):
@@ -337,6 +368,11 @@ def cases():
             "default for MoE models",
             lambda T=T: moe_case(T),
         ))
+    out.append((
+        "moe_mlp 64 live of 512 groups (8 layers' experts in place) T=64 2048x1024",
+        "default for MoE models with stacked layers",
+        lambda: moe_case(64, layers=8, li=5),
+    ))
     for k_dims, out_dims in (
         ((HIDDEN,), (N_HEADS, HEAD_DIM)),  # q
         ((HIDDEN,), (N_KV, HEAD_DIM)),  # k, v
