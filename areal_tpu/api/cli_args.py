@@ -309,14 +309,13 @@ class JaxDecodeConfig:
     kv_cache_dtype: str = "bfloat16"
     # Paged-pool storage scheme (parity surface: SGLang's fp8/int8 KV
     # cache serving):
-    #   "fp" (default): the pool stores kv_cache_dtype verbatim — the
-    #     pre-quantization behavior, bit for bit, and the numerics oracle
+    #   "fp" (default): the pool stores kv_cache_dtype verbatim — what
     #     int8 drift is measured against.
     #   "int8": the pool stores int8 with per-(row, kv-head) f32 scales
-    #     (ops/kv_quant.py; requires kv_layout="paged"). Rows are
-    #     quantized ONCE at the decode/verify/prefill scatters and
-    #     dequantized inside the paged-attention kernels right after each
-    #     block's HBM→VMEM DMA — the same MB of pool holds ~2x the
+    #     (ops/kv_quant.py). Rows are quantized ONCE at the
+    #     decode/verify/prefill scatters and dequantized inside the
+    #     paged-attention kernels right after each block's HBM→VMEM DMA —
+    #     the same MB of pool holds ~2x the
     #     sessions, and every byte-moving path (host-tier swaps, session
     #     export/import, /drain migration) ships the quantized blocks +
     #     scales as-is, halving swap and wire bytes too. Mixed-dtype
@@ -395,19 +394,12 @@ class JaxDecodeConfig:
     # the resume runs through differently-shaped compiled programs,
     # whose reductions XLA may order differently.
     kv_host_pool_mb: float = 0.0
-    # How decode attention reaches the paged pool:
-    #   "paged" (default): attend IN PLACE over the pool through the block
-    #     table (ops/paged_attention.py) with an O(1) per-token cache
-    #     write — no per-chunk gather/scatter of the active KV.
-    #   "workspace": the legacy layout — gather each slot's blocks into a
-    #     contiguous workspace, scan the chunk, scatter back (two HBM
-    #     copies of the active KV per chunk). Kept as the numerics oracle;
-    #     tokens/logprobs are identical between the two layouts.
-    kv_layout: str = "paged"
-    # Kernel for the in-pool attention read: "pallas" (TPU split-KV
-    # flash-decode kernel; requires page_size % 128 == 0), "xla"
-    # (gather-per-block fallback, bitwise-equal to the workspace path),
-    # or "auto" (pallas on TPU, xla elsewhere).
+    # Decode attends IN PLACE over the paged pool through the block table
+    # (ops/paged_attention.py) with an O(1) per-token cache write. This
+    # picks the kernel for that attention read: "pallas" (TPU split-KV
+    # flash-decode kernel; requires page_size % 128 == 0), "xla" (gathers
+    # the slot's blocks each step), or "auto" (pallas on TPU, xla
+    # elsewhere).
     paged_attn_impl: str = "auto"
     hbm_utilization: float = 0.85
     max_prefill_tokens: int = 8192
@@ -445,13 +437,8 @@ class JaxDecodeConfig:
     # longest trailing n-gram matched against the slot's earlier context
     # (matching tries spec_ngram_max down to 1, longest match wins)
     spec_ngram_max: int = 3
-    enable_prefix_caching: bool = True
-    disable_radix_cache: bool = False
-    schedule_policy: str = "fcfs"
     skip_tokenizer_init: bool = False
     log_level: str = "info"
-    enable_metrics: bool = False
-    decode_log_interval: int = 40
     # Server-side idempotency table (launcher/decode_server.py): /generate
     # requests carrying an `xid` delivery id are deduplicated — a retry of
     # an in-flight submission awaits the SAME engine future and a replay of

@@ -95,10 +95,8 @@ from areal_tpu.engine.kv_pool import (
 from areal_tpu.models import hf_io
 from areal_tpu.models.qwen2 import (
     ModelConfig,
-    decode_step,
     decode_step_paged,
     prefill,
-    verify_step,
     verify_step_paged,
 )
 from areal_tpu.parallel import mesh as mesh_lib
@@ -564,10 +562,6 @@ class JaxDecodeEngine(InferenceEngine):
         self._dev_table = None
         self._dev_table_key: tuple[int, int] | None = None
         self._table_uploads = 0
-        # Workspace-layout HBM round-trip accounting (gather + scatter of
-        # the active KV per chunk); stays 0 on kv_layout="paged" — the
-        # delta IS the traffic the in-pool path eliminates.
-        self._ws_copy_bytes = 0
         # Device-chained per-slot state (last sampled token, slot length):
         # outputs of chunk k feed chunk k+1 directly. Slots whose host
         # truth diverged (retire rewind, fresh admission) are listed in
@@ -709,11 +703,6 @@ class JaxDecodeEngine(InferenceEngine):
         # reserved memory track the tokens actually held.
         bs = min(int(self.config.page_size), S)
         max_bps = -(-S // bs)
-        if self.config.kv_layout not in ("paged", "workspace"):
-            raise ValueError(
-                f"kv_layout={self.config.kv_layout!r} not in "
-                "('paged', 'workspace')"
-            )
         from areal_tpu.ops.kv_quant import KV_DTYPES
 
         if self.config.kv_dtype not in KV_DTYPES:
@@ -721,13 +710,6 @@ class JaxDecodeEngine(InferenceEngine):
                 f"kv_dtype={self.config.kv_dtype!r} not in {KV_DTYPES}"
             )
         self._kv_quant = self.config.kv_dtype == "int8"
-        if self._kv_quant and self.config.kv_layout != "paged":
-            # the workspace layout IS the fp numerics oracle — quantizing
-            # it would leave nothing to measure drift against
-            raise ValueError(
-                "kv_dtype='int8' requires kv_layout='paged' "
-                "(kv_layout='workspace' stays the fp numerics oracle)"
-            )
         if getattr(self.config, "role", "unified") not in (
             "unified", "prefill", "decode",
         ):
@@ -752,8 +734,7 @@ class JaxDecodeEngine(InferenceEngine):
                 f"spec_ngram_max={self.config.spec_ngram_max})"
             )
         if (
-            self.config.kv_layout == "paged"
-            and self._paged_impl == "pallas"
+            self._paged_impl == "pallas"
             and jax.default_backend() == "tpu"
             and bs % 128 != 0
         ):
@@ -774,7 +755,7 @@ class JaxDecodeEngine(InferenceEngine):
         # exactly the pre-tier behavior). PHYSICAL bytes per block: int8
         # pools store 1 byte/element plus one f32 scale per (row, head) —
         # every byte counter downstream (host budget, swap totals,
-        # migration totals, workspace-copy totals) derives from this, so
+        # migration totals) derives from this, so
         # none of them can silently assume the fp element size.
         kv_elem = (
             1 if self._kv_quant
@@ -848,7 +829,6 @@ class JaxDecodeEngine(InferenceEngine):
         self._patch_slots = set()
         with self._metrics_lock:
             self._table_uploads = 0
-            self._ws_copy_bytes = 0
             self._dev_busy_s = 0.0
             self._dev_idle_s = 0.0
             self._last_ready_t = None
@@ -1326,9 +1306,8 @@ class JaxDecodeEngine(InferenceEngine):
         slot, so slicing the FIRST bucket rows is always sufficient.
 
         Parked/retired slots may hold KV beyond the bucket; that is safe
-        because decode_step's cache write is masked by `active` — an
-        inactive slot's rows pass through the slice + write-back
-        unchanged, and rows past the bucket are never touched at all."""
+        because an inactive slot's write is redirected into null block 0
+        (decode_step_paged), so its own rows are never touched at all."""
         S = self.config.context_length
         lens = self._slot_lengths[active]
         if grow is None:
@@ -1344,34 +1323,19 @@ class JaxDecodeEngine(InferenceEngine):
         """Chunked decode loop; static sampler variants.
 
         `nb`: blocks per slot this chunk (the attention span is
-        nb * block_size). The KV access pattern is `config.kv_layout`:
-
-        - `"paged"` (default): no per-chunk KV round trip. With the
-          Pallas impl (TPU) the scan attends DIRECTLY over the pool
-          through the [R, nb] block table (ops/paged_attention.py — each
-          grid step DMAs one pool block HBM→VMEM) and each step's cache
-          write is a dynamic scatter of the single (layer, block, offset)
-          row — O(1) per token. The pool is donated, stored in the layout
-          the kernel reads and carried WHOLE through the token loop and
-          the layer loop, so the compiled chunk updates it in place; PR
-          24's trace of the 1.5B chunk on a v5e refuted the earlier claim
-          here (the pool was relaid, sliced and copied, 4.7 GB a token
-          step), and tests/test_pool_in_place.py now holds the traced
-          program to it. With the XLA impl (CPU/fallback)
-          a per-step in-pool gather measures ~20% SLOWER than the
-          workspace loop on XLA:CPU (the one-hot write fuses into the
-          attention einsum there; a fresh gather each step does not), so
-          the xla paged body instead gathers ONCE, scans the bitwise-
-          identical decode_step, and writes back ONLY the n_chunk rows
-          the chunk produced — half the workspace layout's copy traffic
-          and measurably faster, with bit-equal output.
-        - `"workspace"` (numerics oracle): gather each slot's first nb
-          blocks into a contiguous [L, R, nb*block_size] workspace, scan
-          over it, scatter the blocks back — two HBM copies of the
-          active KV per chunk, and an O(S) one-hot masked cache rewrite
-          per layer per step inside decode_step. Aliased (prefix-shared)
-          blocks are never modified by the scan, so the duplicate
-          scatter writes identical bytes (see kv_pool.py).
+        nb * block_size). The pool is donated, stored in the layout the
+        paged kernel reads, and is itself the token loop's and the layer
+        loop's carry: each step writes its one `(layer, block, offset)` row
+        per slot with a dynamic scatter, O(1) a token, and attends over the
+        pool through the [R, nb] block table (models/qwen2.decode_step_paged),
+        so the compiled chunk updates the pool in place and moves no KV
+        besides (tests/test_pool_in_place.py holds the traced program to
+        it). `paged_attn_impl` selects only the attention read inside the
+        step: the Pallas kernel (each grid step DMAs one pool block
+        HBM->VMEM; through Mosaic on a TPU) or XLA's gather of the slot's
+        blocks a step. Every read goes through the pool as stored, so
+        streams do not depend on where a chunk ends, for fp and int8 pools
+        alike.
 
         `use_topp=False` (the common RL rollout setting, top_p == 1):
         plain categorical over temperature-scaled logits. `use_topp=True`:
@@ -1398,10 +1362,7 @@ class JaxDecodeEngine(InferenceEngine):
             return self._chunk_fns[key_]
         cfg = self.model_config
         n_chunk = self.config.new_tokens_per_chunk
-        paged = self.config.kv_layout == "paged"
         paged_impl = self._paged_impl
-        quant = self._kv_quant
-        hd = cfg.head_dim_
         moe = bool(cfg.num_experts)
 
         # sampler shared with the speculative verify chunk (see
@@ -1409,10 +1370,10 @@ class JaxDecodeEngine(InferenceEngine):
         # rule live there
         sample = _make_sample_fn(use_topp)
 
-        # ONE step body for both sampler variants AND both KV layouts:
-        # use_freq / kv_layout are python-static, so the counts carry and
-        # the penalty lines only trace when requested — shared decode
-        # logic cannot diverge between the compiled fns.
+        # ONE step body for both sampler variants: use_freq is
+        # python-static, so the counts carry and the penalty lines only
+        # trace when requested — shared decode logic cannot diverge
+        # between the compiled fns.
         def make_chunk(freq: bool):
             def chunk(params, kp, vp, bt, last_tokens, lengths, active,
                       base_keys, temps, top_ps, greedy, rope_delta,
@@ -1439,153 +1400,34 @@ class JaxDecodeEngine(InferenceEngine):
                 # compiled chunk is what it was.
                 load_init = jnp.zeros(2, jnp.int32) if moe else None
 
-                def model_step(step_fn, load, *a, **kw):
-                    """step_fn's (logits, k, v) and the load carried on."""
-                    if not moe:
-                        return (*step_fn(*a, **kw), None)
-                    *out, step_load = step_fn(*a, **kw, moe_load=True)
-                    return (*out, load + step_load)
-
-                def outputs(kp, vp, last, lengths, toks, logps, counts, load):
-                    out = (kp, vp, last, lengths, toks, logps)
-                    if freq:
-                        out += (counts,)
-                    if moe:
-                        out += (load,)
-                    return out
-
-                if paged and (paged_impl == "pallas" or quant):
-                    # in-pool: the pool itself is the scan carry (donated,
-                    # so XLA updates it in place), the write is an O(1)
-                    # row scatter, and attention reads through the block
-                    # table — no gather, no scatter. Int8 pools take this
-                    # branch on BOTH impls: every read must round-trip the
-                    # quantized representation (the xla gather-once path
-                    # below would attend fp rows written earlier in the
-                    # SAME chunk, making streams depend on chunk
-                    # boundaries — park/resume and migration bit-identity
-                    # would break).
-                    @jax.named_scope("decode_step")
-                    def step(carry, _):
-                        tokens, lengths, kpc, vpc, counts, load = carry
-                        logits, kpc, vpc, load = model_step(
-                            decode_step_paged, load,
-                            params, tokens, lengths, kpc, vpc, bt, cfg,
-                            active=active, rope_offset=rope_delta,
-                            attn_impl=paged_impl,
-                        )
-                        tok, logp, lengths, counts = finish_step(
-                            logits, tokens, lengths, counts
-                        )
-                        return (tok, lengths, kpc, vpc, counts, load), (tok, logp)
-
-                    init = (last_tokens, lengths, kp, vp, counts_init, load_init)
-                    (last, lengths, kp, vp, counts, load), (toks, logps) = (
-                        jax.lax.scan(step, init, None, length=n_chunk)
-                    )
-                    return outputs(kp, vp, last, lengths, toks, logps, counts, load)
-
-                if paged:
-                    # xla impl: gather once, scan the (bitwise-identical)
-                    # workspace decode_step, then write back ONLY the
-                    # rows this chunk produced — the full block
-                    # scatter-back is the half of the round trip XLA:CPU
-                    # can drop without losing the one-hot-write fusion
-                    L, _, bsz, D = kp.shape
-                    R = bt.shape[0]
-                    idx = bt.reshape(-1)
-                    lengths0 = lengths
-                    with jax.named_scope("pool_read"):
-                        kc = jnp.take(kp, idx, axis=1).reshape(
-                            L, R, nb * bsz, -1, hd
-                        )
-                        vc = jnp.take(vp, idx, axis=1).reshape(
-                            L, R, nb * bsz, -1, hd
-                        )
-
-                    @jax.named_scope("decode_step")
-                    def step(carry, _):
-                        tokens, lengths, kc, vc, counts, load = carry
-                        logits, kc, vc, load = model_step(
-                            decode_step, load,
-                            params, tokens, lengths, kc, vc, cfg,
-                            active=active, rope_offset=rope_delta,
-                        )
-                        tok, logp, lengths, counts = finish_step(
-                            logits, tokens, lengths, counts
-                        )
-                        return (tok, lengths, kc, vc, counts, load), (tok, logp)
-
-                    init = (last_tokens, lengths, kc, vc, counts_init, load_init)
-                    (last, lengths, kc, vc, counts, load), (toks, logps) = (
-                        jax.lax.scan(step, init, None, length=n_chunk)
-                    )
-                    # delta write-back: the n_chunk rows per slot starting
-                    # at the pre-chunk length. Inactive slots never wrote
-                    # (masked one-hot), so their "rows" are unmodified
-                    # gather copies — redirected into the null block 0
-                    # anyway so stale positions can't touch live data.
-                    steps = jnp.arange(n_chunk, dtype=lengths0.dtype)
-                    pos = jnp.clip(
-                        lengths0[:, None] + steps[None, :], 0, nb * bsz - 1
-                    )  # [R, n_chunk]
-                    rows_k = jnp.take_along_axis(
-                        kc, pos[None, :, :, None, None], axis=2
-                    )
-                    rows_v = jnp.take_along_axis(
-                        vc, pos[None, :, :, None, None], axis=2
-                    )
-                    blk = jnp.clip(pos // bsz, 0, nb - 1)
-                    dblock = jnp.take_along_axis(
-                        jnp.broadcast_to(bt[:, None, :], (R, n_chunk, nb)),
-                        blk[..., None],
-                        axis=2,
-                    )[..., 0]
-                    dblock = jnp.where(active[:, None], dblock, 0)
-                    doff = jnp.where(active[:, None], pos % bsz, 0)
-                    with jax.named_scope("pool_write"):
-                        kp = kp.at[:, dblock.reshape(-1), doff.reshape(-1)].set(
-                            rows_k.reshape(L, R * n_chunk, D)
-                        )
-                        vp = vp.at[:, dblock.reshape(-1), doff.reshape(-1)].set(
-                            rows_v.reshape(L, R * n_chunk, D)
-                        )
-                    return outputs(kp, vp, last, lengths, toks, logps, counts, load)
-
-                # workspace: gather each slot's blocks into a contiguous
-                # workspace, scan, scatter the blocks back
-                L, _, bsz, D = kp.shape
-                R = bt.shape[0]
-                idx = bt.reshape(-1)
-                with jax.named_scope("pool_read"):
-                    kc = jnp.take(kp, idx, axis=1).reshape(
-                        L, R, nb * bsz, -1, hd
-                    )
-                    vc = jnp.take(vp, idx, axis=1).reshape(
-                        L, R, nb * bsz, -1, hd
-                    )
-
+                # the pool itself is the scan carry (donated, so XLA
+                # updates it in place), the write is an O(1) row scatter,
+                # and attention reads through the block table
                 @jax.named_scope("decode_step")
                 def step(carry, _):
-                    tokens, lengths, kc, vc, counts, load = carry
-                    logits, kc, vc, load = model_step(
-                        decode_step, load,
-                        params, tokens, lengths, kc, vc, cfg, active=active,
-                        rope_offset=rope_delta,
+                    tokens, lengths, kpc, vpc, counts, load = carry
+                    logits, kpc, vpc, *step_load = decode_step_paged(
+                        params, tokens, lengths, kpc, vpc, bt, cfg,
+                        active=active, rope_offset=rope_delta,
+                        attn_impl=paged_impl, moe_load=moe,
                     )
+                    if moe:
+                        load = load + step_load[0]
                     tok, logp, lengths, counts = finish_step(
                         logits, tokens, lengths, counts
                     )
-                    return (tok, lengths, kc, vc, counts, load), (tok, logp)
+                    return (tok, lengths, kpc, vpc, counts, load), (tok, logp)
 
-                init = (last_tokens, lengths, kc, vc, counts_init, load_init)
-                (last, lengths, kc, vc, counts, load), (toks, logps) = (
+                init = (last_tokens, lengths, kp, vp, counts_init, load_init)
+                (last, lengths, kp, vp, counts, load), (toks, logps) = (
                     jax.lax.scan(step, init, None, length=n_chunk)
                 )
-                with jax.named_scope("pool_write"):
-                    kp = kp.at[:, idx].set(kc.reshape(L, R * nb, bsz, D))
-                    vp = vp.at[:, idx].set(vc.reshape(L, R * nb, bsz, D))
-                return outputs(kp, vp, last, lengths, toks, logps, counts, load)
+                out = (kp, vp, last, lengths, toks, logps)
+                if freq:
+                    out += (counts,)
+                if moe:
+                    out += (load,)
+                return out
 
             return chunk
 
@@ -1613,8 +1455,7 @@ class JaxDecodeEngine(InferenceEngine):
     def _get_verify_fn(self, use_topp: bool, nb: int, W: int):
         """Speculative VERIFY chunk (spec_decode="ngram"): one forward
         scores W = draft_bucket + 1 token positions per slot over the
-        paged pool (models/qwen2.verify_step_paged; the workspace layout
-        runs the gather → verify_step → scatter oracle), samples every
+        paged pool (models/qwen2.verify_step_paged), samples every
         position with the SAME fold_in(base_key, position) keys and
         sampler the chunked decode loop uses, and accepts the longest
         draft prefix that matches what sampling emitted plus the model's
@@ -1632,9 +1473,7 @@ class JaxDecodeEngine(InferenceEngine):
         if key_ in self._verify_fns:
             return self._verify_fns[key_]
         cfg = self.model_config
-        paged = self.config.kv_layout == "paged"
         paged_impl = self._paged_impl
-        hd = cfg.head_dim_
         sample = _make_sample_fn(use_topp)
 
         def verify_chunk(params, kp, vp, bt, last_tokens, lengths, active,
@@ -1642,29 +1481,11 @@ class JaxDecodeEngine(InferenceEngine):
                          drafts, draft_lens):
             R = last_tokens.shape[0]
             tokens = jnp.concatenate([last_tokens[:, None], drafts], axis=1)
-            if paged:
-                logits, kp, vp = verify_step_paged(
-                    params, tokens, lengths, kp, vp, bt, cfg,
-                    active=active, rope_offset=rope_delta,
-                    attn_impl=paged_impl,
-                )
-            else:
-                L, _, bsz, D = kp.shape
-                idx = bt.reshape(-1)
-                with jax.named_scope("pool_read"):
-                    kc = jnp.take(kp, idx, axis=1).reshape(
-                        L, R, nb * bsz, -1, hd
-                    )
-                    vc = jnp.take(vp, idx, axis=1).reshape(
-                        L, R, nb * bsz, -1, hd
-                    )
-                logits, kc, vc = verify_step(
-                    params, tokens, lengths, kc, vc, cfg,
-                    active=active, rope_offset=rope_delta,
-                )
-                with jax.named_scope("pool_write"):
-                    kp = kp.at[:, idx].set(kc.reshape(L, R * nb, bsz, D))
-                    vp = vp.at[:, idx].set(vc.reshape(L, R * nb, bsz, D))
+            logits, kp, vp = verify_step_paged(
+                params, tokens, lengths, kp, vp, bt, cfg,
+                active=active, rope_offset=rope_delta,
+                attn_impl=paged_impl,
+            )
             V = logits.shape[-1]
             # flatten [R, W] positions to R*W rows and reuse the chunk
             # loop's sampler verbatim: position base+j samples with
@@ -3588,24 +3409,8 @@ class JaxDecodeEngine(InferenceEngine):
             # (spec_w - accepted - 1) back out, and retire rewinds set the
             # absolute end as for normal chunks
             self._slot_lengths[active] += spec_w
-            # same per-chunk KV copy accounting as the normal chunk: the
-            # workspace verify gathers + scatters its blocks, the paged
-            # xla verify gathers (inside each layer's attention — same
-            # total bytes), the Pallas verify reads in place
-            copies = (
-                2 if self.config.kv_layout == "workspace"
-                else 1 if self._paged_impl == "xla"
-                else 0
-            )
             with self._metrics_lock:
                 self._chunks_dispatched += 1
-                if copies:
-                    # PHYSICAL bytes: _block_nbytes is dtype-aware (int8
-                    # data + f32 scales), so the counter cannot report fp
-                    # bytes for a quantized pool
-                    self._ws_copy_bytes += (
-                        copies * R * nb * self._block_nbytes
-                    )
             return _Inflight(
                 toks=toks,
                 logps=logps,
@@ -3664,24 +3469,8 @@ class JaxDecodeEngine(InferenceEngine):
         # ensure / bucket choice covers this (unconsumed) chunk's growth;
         # retire rewinds overwrite this with the absolute true end
         self._slot_lengths[active] += n_chunk
-        # Per-chunk KV copy accounting (surfaced via get_metrics for the
-        # pagedattn bench comparison): workspace pays gather AND scatter
-        # of k+v; the paged xla impl keeps only the gather (delta
-        # write-back is O(R·n_chunk) rows, negligible); the Pallas
-        # in-pool impl copies nothing. Int8 on the xla impl runs the
-        # in-pool scan — a per-step gather per layer, honestly n_chunk
-        # gathers of the (already halved) physical block bytes.
-        copies = (
-            2 if self.config.kv_layout == "workspace"
-            else 0 if self._paged_impl == "pallas"
-            else n_chunk if self._kv_quant
-            else 1
-        )
         with self._metrics_lock:
             self._chunks_dispatched += 1
-            if copies:
-                # dtype-aware physical bytes (int8 data + f32 scales)
-                self._ws_copy_bytes += copies * R * nb * self._block_nbytes
         return _Inflight(
             toks=toks,
             logps=logps,
@@ -4199,15 +3988,14 @@ class JaxDecodeEngine(InferenceEngine):
                     jnp.zeros(R, dtype=jnp.int32),
                     jnp.asarray(np.array(self._slot_lengths)),
                 )
-                # the ghost compiles below warm whichever (layout,
-                # kv_dtype) variants the live config selects — an int8
+                # the ghost compiles below warm whichever kv_dtype
+                # variants the live config selects — an int8
                 # engine ghost-compiles the QUANTIZED chunk/verify fns, so
                 # the first quantized wave never eats a compile; skips name
                 # the dtype so an operator can tell WHICH pool variant will
                 # stall
                 kvd = (
-                    f"{self.config.kv_layout}/{self.config.kv_dtype}"
-                    f"/w:{self.config.weight_dtype}"
+                    f"{self.config.kv_dtype}/w:{self.config.weight_dtype}"
                 )
                 for b in buckets:
                     nb = -(-b // self._alloc.block_size)
@@ -4283,14 +4071,11 @@ class JaxDecodeEngine(InferenceEngine):
     def _ghost_chunk(self, use_topp: bool, nb: int) -> None:
         """Dispatch one decode chunk with every slot inactive: engine
         state (live KV, lengths, sampling streams) is unchanged — only
-        the jit variant's compile happens. On the workspace layout the
-        masked writes + identity gather/scatter round-trip identical
-        bytes; on the paged layout every inactive slot's write is
+        the jit variant's compile happens. Every inactive slot's write is
         redirected into the reserved null block 0, which is never read
-        as valid data (kv_pool.py), so live blocks stay bit-identical
-        there too. Compiles whichever layout's chunk variant
-        `config.kv_layout` selects — the run-ahead scheduler's first
-        overlapped dispatch must never trace either."""
+        as valid data (kv_pool.py), so live blocks stay bit-identical —
+        the run-ahead scheduler's first overlapped dispatch must never
+        trace."""
         R = self.config.max_running_requests
         chunk_fn = self._get_chunk_fn(use_topp, False, nb)
         ctl = self._refresh_ctl()
@@ -4314,9 +4099,8 @@ class JaxDecodeEngine(InferenceEngine):
 
     def _ghost_verify(self, use_topp: bool, nb: int, W: int) -> None:
         """Dispatch one VERIFY chunk with every slot inactive: same
-        engine-state-preserving contract as `_ghost_chunk` (paged writes
-        park in the reserved null block 0, the workspace verify write
-        rounds inactive rows through unchanged), only the jit variant's
+        engine-state-preserving contract as `_ghost_chunk` (the writes
+        park in the reserved null block 0), only the jit variant's
         compile happens."""
         R = self.config.max_running_requests
         verify_fn = self._get_verify_fn(use_topp, nb, W)
@@ -5249,7 +5033,6 @@ class JaxDecodeEngine(InferenceEngine):
             runahead_discarded = self._runahead_discarded
             moe_pairs, moe_hot_pairs = self._moe_pairs, self._moe_hot_pairs
             table_uploads = self._table_uploads
-            ws_copy_bytes = self._ws_copy_bytes
             spec_hist = self._spec_hist.copy()
             spec_chunk_slots = self._spec_chunk_slots
             spec_drafted = self._spec_drafted
@@ -5415,7 +5198,6 @@ class JaxDecodeEngine(InferenceEngine):
                 round(prefix_hits / prefix_total, 6) if prefix_total else 0.0
             ),
             "preemptions_total": self._n_preemptions,
-            "kv_layout": self.config.kv_layout,
             # pool storage dtype + PHYSICAL bytes per block (int8 data +
             # f32 scales when quantized): every byte counter here derives
             # from kv_block_nbytes, so none assumes the fp element size
@@ -5520,9 +5302,6 @@ class JaxDecodeEngine(InferenceEngine):
             # dirty-tracked block-table uploads: chunks_dispatched_total -
             # this = steady-state dispatches that skipped the copy+upload
             "block_table_uploads_total": table_uploads,
-            # per-chunk KV copy traffic: workspace = gather + scatter,
-            # paged/xla = gather only, paged/pallas = 0 (in-pool reads)
-            "kv_workspace_copy_bytes_total": ws_copy_bytes,
             # speculative decoding (spec_decode="ngram"): histogram of
             # accepted draft tokens per (slot, verify chunk), draft hit
             # rate, and the rejected-token waste — the knobs-vs-payoff

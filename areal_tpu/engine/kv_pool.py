@@ -14,18 +14,11 @@ slice per layer per token on the v5e). Host-tier entries and the
 migration wire keep the logical `[L, nb, block_size, nKV, hd]` shape.
 Block tables are HOST-side numpy (the scheduler thread owns them; the
 jitted kernels receive the relevant table slice as a traced operand each
-dispatch, so table mutation never recompiles anything). Device access is
-layout-dependent (`JaxDecodeConfig.kv_layout`):
-
-- `"paged"` (default): decode attends DIRECTLY over the pool through the
-  block table (ops/paged_attention.py) and each step's KV write is a
-  scatter of the single (layer, block, offset) row into the pool the
-  chunk carries whole — no copy of the pool or of a layer's slice
-  (tests/test_pool_in_place.py).
-- `"workspace"` (the numerics oracle): the chunk kernel gathers each
-  slot's first `nb` blocks into a contiguous workspace, runs the scan,
-  and scatters the blocks back — two HBM copies of the active KV per
-  chunk (the cost the dense engine's bucketed slice already paid).
+dispatch, so table mutation never recompiles anything). Decode attends
+DIRECTLY over the pool through the block table (ops/paged_attention.py)
+and each step's KV write is a scatter of the single (layer, block,
+offset) row into the pool the chunk carries whole — no copy of the pool
+or of a layer's slice (tests/test_pool_in_place.py).
 
 `version` is a monotonic mutation counter: every table write (ensure
 growth, free, fork) bumps it, so the engine can skip re-uploading the
@@ -35,8 +28,7 @@ Sharing: a prefix fork ALIASES the donor's full blocks (refcount bump — a
 table write, no data movement) and device-copies only the one partial
 block at the shared boundary. Aliased blocks are never written: decode
 writes at position >= slot length >= the shared-prefix boundary, and the
-boundary block is always the copied one, so the post-chunk scatter writes
-identical bytes through every alias (benign duplicate scatter).
+boundary block is always the copied one.
 
 Block 0 is a reserved null block: unallocated table entries point at it,
 so uniform-width gathers of short slots read (masked) garbage instead of

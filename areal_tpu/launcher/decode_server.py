@@ -237,7 +237,6 @@ class DecodeServer:
                 "context_length": self.config.context_length,
                 "max_running_requests": self.config.max_running_requests,
                 "decode_runahead_chunks": self.config.decode_runahead_chunks,
-                "kv_layout": self.config.kv_layout,
                 "kv_dtype": getattr(self.config, "kv_dtype", "fp"),
                 "weight_dtype": getattr(self.config, "weight_dtype", "fp"),
                 "kv_host_pool_mb": self.config.kv_host_pool_mb,
@@ -1276,7 +1275,6 @@ async def _serve(args: argparse.Namespace) -> None:
         max_running_requests=args.max_running_requests,
         new_tokens_per_chunk=args.new_tokens_per_chunk,
         decode_runahead_chunks=args.decode_runahead_chunks,
-        kv_layout=args.kv_layout,
         kv_dtype=args.kv_dtype,
         weight_dtype=args.weight_dtype,
         kv_host_pool_mb=args.kv_host_pool_mb,
@@ -1398,20 +1396,12 @@ def main(argv: list[str] | None = None) -> None:
              "synchronous loop; output is bit-identical either way)",
     )
     p.add_argument(
-        "--kv-layout",
-        default="paged",
-        choices=["paged", "workspace"],
-        help="decode KV access: 'paged' attends in place over the paged "
-             "pool through the block table (no per-chunk gather/scatter); "
-             "'workspace' is the legacy copy-in/copy-out numerics oracle",
-    )
-    p.add_argument(
         "--kv-dtype",
         default="fp",
         choices=["fp", "int8"],
-        help="paged-pool storage: 'fp' keeps kv_cache_dtype (the numerics "
-             "oracle); 'int8' stores the pool quantized with per-row/"
-             "per-head scales (needs --kv-layout paged) — ~2x the resident "
+        help="paged-pool storage: 'fp' keeps kv_cache_dtype; 'int8' "
+             "stores the pool quantized with per-row/per-head scales — "
+             "~2x the resident "
              "sessions per MB, and swaps/migration ship the quantized "
              "bytes as-is (mixed-dtype fleets reject imports as honest "
              "misses). Drift is measured (bench.py --mode kvquant), not "

@@ -799,7 +799,6 @@ class FleetSupervisor:
             )
             logger.info(
                 f"replica {handle.addr} booted: role={info.get('role')} "
-                f"kv_layout={info.get('kv_layout')} "
                 f"kv_dtype={info.get('kv_dtype')} "
                 f"weight_dtype={info.get('weight_dtype')} "
                 f"version={info.get('version')}"

@@ -2227,98 +2227,6 @@ def prefill_with_prefix(
     return ks, vs
 
 
-def decode_step(
-    params: dict,
-    tokens: jax.Array,  # [R] current input token per slot
-    positions: jax.Array,  # [R] index the new token occupies
-    k_cache: jax.Array,  # [L, R, S, nKV, hd]
-    v_cache: jax.Array,  # [L, R, S, nKV, hd]
-    cfg: ModelConfig,
-    active: jax.Array | None = None,  # [R] bool: slot holds a live request
-    rope_offset: jax.Array | None = None,  # [R] added to rope pos only
-    moe_load: bool = False,  # MoE only: also return the step's expert load
-) -> tuple:
-    """One batched decode step over R slots.
-
-    Writes this step's K/V at `positions` and attends over s <= position
-    per slot. Returns (logits [R, V], k_cache, v_cache), and with
-    `moe_load` a fourth: int32 [pairs, busiest expert's pairs] summed over
-    layers (`moe_mlp`). `active` keeps dead slots out of MoE routing: they
-    reach no expert and are not counted.
-
-    `rope_offset` shifts the ROTARY position only (cache index unchanged):
-    Qwen2-VL m-rope compresses an image's positions to max(t, h, w) per
-    span, so a VLM slot's text position = cache_len + per-request delta.
-    Text tokens under m-rope use one scalar for all three sections, which
-    reduces exactly to standard 1-D rope at that scalar — so the shared
-    decode step stays mrope-correct with just this offset.
-    """
-    R = tokens.shape[0]
-    S = k_cache.shape[2]
-    nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
-    group = nH // nKV
-    x = _embed_tokens(params, tokens, positions, cfg)  # [R, H]
-    rope_pos = positions if rope_offset is None else positions + rope_offset
-    cos, sin = rope_table(rope_pos, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)  # [R, hd/2]
-    valid = jnp.arange(S)[None, :] <= positions[:, None]  # [R, S]
-    if cfg.sliding_window is not None:
-        valid = valid & (
-            jnp.arange(S)[None, :] > positions[:, None] - cfg.sliding_window
-        )
-
-    @jax.named_scope("kv_write")
-    def write(cache_l, new):  # [R, S, nKV, hd] <- [R, nKV, hd]
-        onehot = jnp.arange(S)[None, :] == positions[:, None]
-        if active is not None:
-            # inactive slots must not touch the cache: retired slots can
-            # still be prefix-KV donors and parked slots hold KV a resume
-            # needs — an unmasked write would clobber row positions[r]
-            # (e.g. row 0 of every retired slot) each step.
-            onehot = onehot & active[:, None]
-        onehot = onehot.astype(cache_l.dtype)
-        return cache_l * (1 - onehot[..., None, None]) + (
-            new[:, None] * onehot[..., None, None]
-        )
-
-    @jax.named_scope("layer")
-    def layer(x, inputs):
-        layer_p, kc, vc = inputs
-        h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-        with jax.named_scope("attn"):
-            q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-            kc = write(kc, k_new.astype(kc.dtype))
-            vc = write(vc, v_new.astype(vc.dtype))
-            with jax.named_scope("attention"):
-                qg = q.reshape(R, nKV, group, hd)
-                scores = jnp.einsum("rkgd,rskd->rkgs", qg, kc.astype(q.dtype))
-                scores = (scores / np.sqrt(hd)).astype(jnp.float32)
-                scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-                attn_out = jnp.einsum(
-                    "rkgs,rskd->rkgd", probs, vc.astype(x.dtype)
-                ).reshape(R, nH, hd)
-        if moe_load:
-            x, load = _attn_out_mlp(layer_p, x, attn_out, cfg, active, True)
-            return x, (kc, vc, load)
-        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active)
-        return x, (kc, vc)
-
-    if cfg.scan_layers:
-        x, ys = _scan_stacked(layer, x, params, cfg, k_cache, v_cache)
-    else:
-        per_layer = []
-        for i in range(cfg.num_hidden_layers):
-            x, y = layer(x, (params[f"layers_{i}"], k_cache[i], v_cache[i]))
-            per_layer.append(y)
-        ys = tuple(jnp.stack(col) for col in zip(*per_layer))
-    k_cache, v_cache = ys[:2]
-
-    logits = _final_logits(params, x, cfg)
-    if moe_load:
-        return logits, k_cache, v_cache, ys[2].sum(axis=0)
-    return logits, k_cache, v_cache
-
-
 @jax.named_scope("kv_write")
 @jax.named_scope("pool_write")
 def _write_pool_rows(pool, new, layer, dest_block, dest_off):
@@ -2375,26 +2283,35 @@ def decode_step_paged(
     attn_impl: str = "auto",  # ops/paged_attention.py impl select
     moe_load: bool = False,  # MoE only: also return the step's expert load
 ) -> tuple:
-    """One batched decode step attending DIRECTLY over the paged pool.
+    """One batched decode step over R slots, attending DIRECTLY over the
+    paged pool.
 
-    The in-pool twin of `decode_step` (same embed/rope/mlp/lm-head body;
-    the two must stay output-equivalent — tests/test_paged_attention.py
-    pins it). Differences, both per layer per step:
+    Writes this step's K/V row per slot and attends over s <= position
+    through the block table. Returns (logits [R, V], k_pool, v_pool), and
+    with `moe_load` a fourth: int32 [pairs, busiest expert's pairs] summed
+    over layers (`moe_mlp`). `active` keeps dead slots out of MoE routing:
+    they reach no expert and are not counted.
 
-    - **Write is O(1), not O(S).** `decode_step`'s cache write is a
-      one-hot masked rewrite of the whole [R, S] cache; here the new
-      row's pool coordinates `(layer, block_tables[r, p // bsz], p % bsz)`
-      are computed from the slot position and written with a single
-      dynamic scatter of R rows into the WHOLE pool, which the layer loop
-      carries (never slices: `_scan_layers_carrying`). Inactive slots are
-      redirected to the reserved null block 0 (never read as valid data),
-      so retired donors' and parked slots' KV is untouched — the same
-      guarantee the masked one-hot write gave. Write-collision safety between active slots is
-      the pool invariant: aliased (prefix-shared) blocks sit strictly
-      below every writer's position and the boundary block is private
-      (engine/kv_pool.py).
+    `rope_offset` shifts the ROTARY position only (pool row unchanged):
+    Qwen2-VL m-rope compresses an image's positions to max(t, h, w) per
+    span, so a VLM slot's text position = cache_len + per-request delta.
+    Text tokens under m-rope use one scalar for all three sections, which
+    reduces exactly to standard 1-D rope at that scalar — so the shared
+    decode step stays mrope-correct with just this offset.
+
+    - **The write is O(1).** The new row's pool coordinates
+      `(layer, block_tables[r, p // bsz], p % bsz)` are computed from the
+      slot position and written with a single dynamic scatter of R rows
+      into the WHOLE pool, which the layer loop carries (never slices:
+      `_scan_layers_carrying`). Inactive slots are redirected to the
+      reserved null block 0 (never read as valid data): retired slots can
+      still be prefix-KV donors and parked slots hold KV a resume needs,
+      so their rows must stay untouched. Write-collision safety between
+      active slots is the pool invariant: aliased (prefix-shared) blocks
+      sit strictly below every writer's position and the boundary block
+      is private (engine/kv_pool.py).
     - **Attention reads through the block table** (ops/paged_attention):
-      no workspace gather/scatter round-trip per chunk.
+      no KV is gathered or copied outside the attention read itself.
 
     Int8 pools: `k_pool`/`v_pool` arrive as (int8 data, f32 scales)
     tuples (ops/kv_quant.py) and are returned in the same form. The new
@@ -2405,6 +2322,9 @@ def decode_step_paged(
     inside ops/paged_attention, so the row just written is read back
     through its int8 representation — token streams are a pure function
     of the quantized pool state, invariant to chunk boundaries.
+
+    `tests/test_paged_attention.py` holds the step to the trainer's
+    `forward` and to this write contract.
     """
     from areal_tpu.ops.paged_attention import paged_attention
 
@@ -2462,106 +2382,6 @@ def decode_step_paged(
     return logits, k_pool, v_pool
 
 
-def verify_step(
-    params: dict,
-    tokens: jax.Array,  # [R, W]: draft inputs, column 0 = the last token
-    positions0: jax.Array,  # [R] base index column 0 occupies
-    k_cache: jax.Array,  # [L, R, S, nKV, hd]
-    v_cache: jax.Array,  # [L, R, S, nKV, hd]
-    cfg: ModelConfig,
-    active: jax.Array | None = None,  # [R] bool
-    rope_offset: jax.Array | None = None,  # [R] added to rope pos only
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Speculative VERIFY step over the workspace cache: score W token
-    positions per slot in ONE forward (q_len = W self-extension) instead
-    of W sequential `decode_step`s.
-
-    Column j of `tokens` sits at position `positions0 + j`; its KV row is
-    written there and its logits predict the token at the NEXT position —
-    exactly what `decode_step` would have produced had it been fed the
-    same inputs one at a time (the bit-parity contract the engine's
-    speculative accept relies on; tests/test_spec_decode.py pins it).
-    Rejected positions' rows are simply dead: the next write at that
-    position overwrites them, and the causal mask (`s <= position`) hides
-    them from every query that matters before then. Returns
-    (logits [R, W, V] f32, k_cache, v_cache).
-    """
-    from areal_tpu.ops.chunked_attention import verify_attention
-
-    R, W = tokens.shape
-    S = k_cache.shape[2]
-    nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
-    positions = positions0[:, None] + jnp.arange(W, dtype=positions0.dtype)
-    flat_pos = positions.reshape(-1)  # [R*W]
-    x = _embed_tokens(params, tokens.reshape(-1), flat_pos, cfg)  # [R*W, H]
-    rope_pos = (
-        positions if rope_offset is None else positions + rope_offset[:, None]
-    ).reshape(-1)
-    cos, sin = rope_table(rope_pos, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
-    # per-query causal horizon over the slot's rows
-    valid = jnp.arange(S)[None, None, :] <= positions[:, :, None]  # [R, W, S]
-    if cfg.sliding_window is not None:
-        valid = valid & (
-            jnp.arange(S)[None, None, :]
-            > positions[:, :, None] - cfg.sliding_window
-        )
-    pos_c = jnp.clip(positions, 0, S - 1)
-    row_idx = jnp.arange(R)[:, None]
-    active_flat = (
-        None if active is None else jnp.repeat(active, W, axis=0)
-    )
-
-    @jax.named_scope("kv_write")
-    def write(cache_l, new):  # [R, S, nKV, hd] <- [R*W, nKV, hd]
-        new_r = new.reshape(R, W, nKV, hd)
-        if active is not None:
-            # inactive slots (and stale positions) must round-trip their
-            # rows unchanged — same guarantee decode_step's masked one-hot
-            # write gives retired donors and parked KV
-            old = jnp.take_along_axis(
-                cache_l, pos_c[..., None, None], axis=1
-            )
-            new_r = jnp.where(active[:, None, None, None], new_r, old)
-        return cache_l.at[row_idx, pos_c].set(new_r)
-
-    @jax.named_scope("layer")
-    def layer(x, inputs):
-        layer_p, kc, vc = inputs
-        h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-        with jax.named_scope("attn"):
-            q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-            kc = write(kc, k_new.astype(kc.dtype))
-            vc = write(vc, v_new.astype(vc.dtype))
-            with jax.named_scope("attention"):
-                attn_out = verify_attention(
-                    q.reshape(R, W, nH, hd), kc.astype(q.dtype),
-                    vc.astype(q.dtype), valid,
-                ).reshape(R * W, nH, hd)
-        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat)
-        return x, (kc, vc)
-
-    if cfg.scan_layers:
-        x, (k_cache, v_cache) = _scan_stacked(
-            layer, x, params, cfg, k_cache, v_cache
-        )
-    else:
-        kcs, vcs = [], []
-        for i in range(cfg.num_hidden_layers):
-            x, (kc, vc) = layer(
-                x, (params[f"layers_{i}"], k_cache[i], v_cache[i])
-            )
-            kcs.append(kc)
-            vcs.append(vc)
-        k_cache, v_cache = jnp.stack(kcs), jnp.stack(vcs)
-
-    logits = _final_logits(params, x, cfg)
-    return (
-        logits.reshape(R, W, -1),
-        k_cache,
-        v_cache,
-    )
-
-
 def verify_step_paged(
     params: dict,
     tokens: jax.Array,  # [R, W]: draft inputs, column 0 = the last token
@@ -2574,11 +2394,23 @@ def verify_step_paged(
     rope_offset: jax.Array | None = None,
     attn_impl: str = "auto",
 ) -> tuple[jax.Array, Any, Any]:
-    """The in-pool twin of `verify_step` (see its contract): W positions
-    per slot scored in one forward DIRECTLY over the paged pool. The KV
-    write is an O(W) row scatter through the block table (inactive slots
-    redirect to the reserved null block 0, like `decode_step_paged`), and
-    attention reads through the table with per-query causal masks
+    """Speculative VERIFY step: score W token positions per slot in ONE
+    forward (q_len = W self-extension) DIRECTLY over the paged pool,
+    instead of W sequential `decode_step_paged` calls.
+
+    Column j of `tokens` sits at position `positions0 + j`; its KV row is
+    written there and its logits predict the token at the NEXT position —
+    what `decode_step_paged` would have produced had it been fed the same
+    inputs one at a time (the contract the engine's speculative accept
+    relies on; tests/test_paged_attention.py and tests/test_spec_decode.py
+    hold it). Rejected positions' rows are simply dead: the next write at
+    that position overwrites them, and the causal mask (`s <= position`)
+    hides them from every query that matters before then. Returns
+    (logits [R, W, V] f32, k_pool, v_pool).
+
+    The KV write is an O(W) row scatter through the block table (inactive
+    slots redirect to the reserved null block 0, like `decode_step_paged`),
+    and attention reads through the table with per-query causal masks
     (ops/paged_attention.paged_attention_qlen — the Pallas impl DMAs each
     pool block once for all W queries). Int8 pools quantize the W rows at
     this scatter and return (data, scales) tuples, exactly as

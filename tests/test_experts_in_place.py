@@ -159,12 +159,6 @@ def _pools(seed):
     return jax.random.normal(k, shape), jax.random.normal(v, shape)
 
 
-def _caches(seed):
-    k, v = jax.random.split(jax.random.PRNGKey(seed))
-    shape = (L, R, CONTEXT, nKV, hd)
-    return jax.random.normal(k, shape), jax.random.normal(v, shape)
-
-
 def _decode_paged(p, cfg):
     kp, vp = _pools(1)
     return qwen2.decode_step_paged(
@@ -177,20 +171,6 @@ def _verify_paged(p, cfg):
     return qwen2.verify_step_paged(
         p, jnp.arange(R * 3, dtype=jnp.int32).reshape(R, 3) + 1,
         jnp.array([17, 0, 33, 5]), kp, vp, BT, cfg, active=ACTIVE, attn_impl="xla")
-
-
-def _decode_workspace(p, cfg):
-    kc, vc = _caches(3)
-    return qwen2.decode_step(
-        p, jnp.array([3, 9, 27, 5]), jnp.array([17, 0, 33, 5]), kc, vc, cfg,
-        active=ACTIVE, moe_load=True)
-
-
-def _verify_workspace(p, cfg):
-    kc, vc = _caches(4)
-    return qwen2.verify_step(
-        p, jnp.arange(R * 3, dtype=jnp.int32).reshape(R, 3) + 1,
-        jnp.array([17, 0, 33, 5]), kc, vc, cfg, active=ACTIVE)
 
 
 def _prefill_padded(p, cfg):
@@ -219,7 +199,6 @@ def _prefill_vmapped(p, cfg):
 
 STEPS = {
     "decode_step_paged": _decode_paged, "verify_step_paged": _verify_paged,
-    "decode_step": _decode_workspace, "verify_step": _verify_workspace,
     "prefill": _prefill_padded, "prefill_with_prefix": _prefill_suffix,
     "vmapped_prefill": _prefill_vmapped,
 }

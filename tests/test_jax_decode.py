@@ -610,9 +610,9 @@ def test_bucketed_chunk_attention_parity(cpu_devices):
 @pytest.mark.slow
 def test_parked_long_sequence_survives_bucketed_chunks(cpu_devices):
     """A parked long sequence must survive other slots' bucketed chunks:
-    decode_step's active-masked cache write means the short request can
+    an inactive slot's write goes to null block 0, so the short request can
     run on a small bucket while the parked slot's KV (partly inside,
-    partly beyond the bucket) passes through untouched — and the parked
+    partly beyond the bucket) stays untouched — and the parked
     request then resumes with the exact greedy continuation."""
     from areal_tpu.engine.jax_decode import _Slot
 
@@ -680,7 +680,7 @@ def test_retired_donor_survives_later_chunks(cpu_devices):
     while others keep chunking, then a same-prompt request forks from the
     retired donor's registered prefix. The fork must be exact — i.e.
     later chunks must not have written into the retired slot's rows
-    (decode_step masks inactive-slot writes)."""
+    (decode_step_paged redirects inactive-slot writes to null block 0)."""
     from areal_tpu.engine.jax_decode import _Slot
 
     cfg = JaxDecodeConfig(

@@ -80,7 +80,6 @@ def _engine(*, role="unified", host_mb=0.0, R=3, context=256, page=8,
         max_running_requests=R,
         new_tokens_per_chunk=chunk,
         page_size=page,
-        kv_layout="paged",
         paged_attn_impl="xla",
         kv_host_pool_mb=host_mb,
         role=role,

@@ -7,8 +7,8 @@ Coverage layers:
    (the `HostKVEntry` resume shape); `export_session` / `import_session`
    move a session between engines BIT-IDENTICALLY — a decode engine that
    imported a migrated session continues the stream with zero transformer
-   prefill and emits the same tokens AND logprobs (greedy and sampled,
-   both kv layouts) as a never-migrated oracle.
+   prefill and emits the same tokens AND logprobs (greedy and sampled)
+   as a never-migrated oracle.
 2. Staleness: an import whose KV was computed under a different weight
    version is rejected as an honest miss (tombstoned), and the resume
    re-prefills under the current weights — the cross-replica extension of
@@ -71,14 +71,13 @@ def _params():
     return _PARAMS
 
 
-def _engine(*, role="unified", kv_layout="paged", host_mb=0.0, R=3,
-            context=256, page=8, chunk=4, seed=1):
+def _engine(*, role="unified", host_mb=0.0, R=3, context=256, page=8,
+            chunk=4, seed=1):
     cfg = JaxDecodeConfig(
         context_length=context,
         max_running_requests=R,
         new_tokens_per_chunk=chunk,
         page_size=page,
-        kv_layout=kv_layout,
         paged_attn_impl="xla",
         kv_host_pool_mb=host_mb,
         role=role,
@@ -164,12 +163,11 @@ def test_prefill_only_parks_exact_coverage_and_resumes_locally():
         eng.destroy()
 
 
-@pytest.mark.parametrize("kv_layout", ["paged", "workspace"])
 @pytest.mark.parametrize("gname", ["greedy", "sampled"])
-def test_export_import_stream_bit_identity(kv_layout, gname):
+def test_export_import_stream_bit_identity(gname):
     g = _GREEDY if gname == "greedy" else _SAMPLED
     prompt = _prompt(44, seed=5)
-    oracle = _engine(kv_layout=kv_layout)
+    oracle = _engine()
     try:
         ro = oracle.generate(
             ModelRequest(rid="m", input_ids=prompt, gconfig=g), timeout=120
@@ -177,7 +175,7 @@ def test_export_import_stream_bit_identity(kv_layout, gname):
     finally:
         oracle.destroy()
 
-    pre = _engine(role="prefill", kv_layout=kv_layout)
+    pre = _engine(role="prefill")
     try:
         _prefill(pre, ModelRequest(rid="m", input_ids=prompt, gconfig=g))
         sess = pre.export_session("m")
@@ -207,7 +205,7 @@ def test_export_import_stream_bit_identity(kv_layout, gname):
     assert np.array_equal(np.asarray(k), sess["k"])
     assert np.array_equal(np.asarray(v), sess["v"])
 
-    dec = _engine(role="decode", kv_layout=kv_layout)
+    dec = _engine(role="decode")
     try:
         assert dec.import_session(meta, k, v) == "ok"
         m0 = dec.get_metrics()

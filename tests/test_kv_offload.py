@@ -8,9 +8,9 @@ Three layers of coverage:
    bookkeeping incl. the take/restore promotion dance.
 2. The engine invariant the tier is FOR: a stream that was interrupted,
    EVICTED to host RAM and promoted back is bit-identical — tokens AND
-   logprobs — to the never-evicted oracle, greedy and sampled, on both
-   `kv_layout`s, at `decode_runahead_chunks=1` with `spec_decode="ngram"`
-   on (the acceptance matrix of the issue). The restored bytes ARE the
+   logprobs — to the never-evicted oracle, greedy and sampled, at
+   `decode_runahead_chunks=1` with `spec_decode="ngram"` on (the
+   acceptance matrix of the issue). The restored bytes ARE the
    original KV and the slot's sampling base key travels with the entry,
    so fold_in(original_key, position) sampling makes the whole stream a
    pure function of token index again.
@@ -191,8 +191,8 @@ class DigitTok:
         return "".join(str(i % 10) for i in ids)
 
 
-def _engine(params, host_mb, *, R=2, kv_layout="paged", spec="ngram",
-            pool_tokens=None, context=256, page=8, chunk=4, runahead=1):
+def _engine(params, host_mb, *, R=2, spec="ngram", pool_tokens=None,
+            context=256, page=8, chunk=4, runahead=1):
     cfg = JaxDecodeConfig(
         context_length=context,
         max_running_requests=R,
@@ -201,7 +201,6 @@ def _engine(params, host_mb, *, R=2, kv_layout="paged", spec="ngram",
         kv_pool_tokens=pool_tokens,
         kv_host_pool_mb=host_mb,
         decode_runahead_chunks=runahead,
-        kv_layout=kv_layout,
         paged_attn_impl="xla",
         spec_decode=spec,
         spec_k=3,
@@ -309,14 +308,12 @@ def _run_fillers(eng, prompts, g):
     return out["r"]
 
 
-def _oracle_streams(params, prompts, gconfigs, kv_layout, spec):
+def _oracle_streams(params, prompts, gconfigs, spec):
     """Never-evicted reference: same engine settings but enough slots (and
     the dense full-provisioned pool) that nothing is ever parked-out or
     preempted — every request runs straight through. Per-slot sampling
     purity makes slot geometry irrelevant to the streams."""
-    eng = _engine(
-        params, 0, R=len(prompts) + 1, kv_layout=kv_layout, spec=spec
-    )
+    eng = _engine(params, 0, R=len(prompts) + 1, spec=spec)
     try:
 
         async def _main():
@@ -348,13 +345,11 @@ def _oracle_streams(params, prompts, gconfigs, kv_layout, spec):
 # -- 2. bit-identity vs the never-evicted oracle ------------------------
 
 
-@pytest.mark.parametrize("kv_layout", ["paged", "workspace"])
 @pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
-def test_evicted_resume_bit_identical_to_oracle(cpu_devices, kv_layout, greedy):
+def test_evicted_resume_bit_identical_to_oracle(cpu_devices, greedy):
     """park -> LRU-evict -> host offload -> promote: the resumed stream's
     tokens AND logprobs equal the never-evicted oracle's, greedy and
-    sampled, on both kv_layouts, at runahead=1 with spec_decode="ngram"
-    on. Sampled identity is what the traveling base key buys: every
+    sampled, at runahead=1 with spec_decode="ngram" on. Sampled identity is what the traveling base key buys: every
     position samples with fold_in(original_key, position) regardless of
     where the interrupt/eviction landed."""
     params = init_params(TINY, jax.random.PRNGKey(0))
@@ -364,11 +359,9 @@ def test_evicted_resume_bit_identical_to_oracle(cpu_devices, kv_layout, greedy):
         greedy=greedy, temperature=1.0, top_p=1.0, max_new_tokens=48
     )
     g_fill = replace(g, max_new_tokens=12)
-    oracle = _oracle_streams(
-        params, prompts, [g, g_fill, g_fill], kv_layout, "ngram"
-    )
+    oracle = _oracle_streams(params, prompts, [g, g_fill, g_fill], "ngram")
 
-    eng = _engine(params, 64, R=2, kv_layout=kv_layout, spec="ngram")
+    eng = _engine(params, 64, R=2, spec="ngram")
     try:
         rid = str(uuid.uuid4())
         seg1 = _interrupt_first_segment(eng, rid, prompts[0], g)
@@ -389,7 +382,7 @@ def test_evicted_resume_bit_identical_to_oracle(cpu_devices, kv_layout, greedy):
     a_tokens = list(seg1.output_tokens) + list(seg2.output_tokens)
     a_logps = list(seg1.output_logprobs) + list(seg2.output_logprobs)
     oa_tokens, oa_logps = oracle[tuple(prompts[0])]
-    tag = f"[{kv_layout}/{'greedy' if greedy else 'sampled'}]"
+    tag = f"[{'greedy' if greedy else 'sampled'}]"
     assert a_tokens == oa_tokens, (
         f"{tag} evicted resume diverged from the never-evicted oracle:\n"
         f"{a_tokens}\n{oa_tokens}"
@@ -480,9 +473,7 @@ def test_host_miss_falls_back_to_reprefill(cpu_devices):
     ]
     g = GenerationHyperparameters(greedy=True, max_new_tokens=48)
     g_fill = replace(g, max_new_tokens=12)
-    oracle = _oracle_streams(
-        params, prompts, [g, g, g_fill, g_fill], "paged", "ngram"
-    )
+    oracle = _oracle_streams(params, prompts, [g, g, g_fill, g_fill], "ngram")
 
     host_mb = (6 * _TINY_BLOCK_NBYTES) / (1024 * 1024)
     eng = _engine(params, host_mb, R=2, spec="ngram", chunk=2)
@@ -528,9 +519,7 @@ def test_disabled_host_tier_reproduces_todays_behavior(cpu_devices):
     prompts = [[int(x) for x in rng.integers(1, 60, 8)] for _ in range(3)]
     g = GenerationHyperparameters(greedy=True, max_new_tokens=48)
     g_fill = replace(g, max_new_tokens=12)
-    oracle = _oracle_streams(
-        params, prompts, [g, g_fill, g_fill], "paged", "ngram"
-    )
+    oracle = _oracle_streams(params, prompts, [g, g_fill, g_fill], "ngram")
 
     eng = _engine(params, 0, R=2, spec="ngram")
     try:
@@ -568,9 +557,7 @@ def test_weight_update_invalidates_host_tier(cpu_devices):
     prompts = [[int(x) for x in rng.integers(1, 60, 8)] for _ in range(3)]
     g = GenerationHyperparameters(greedy=True, max_new_tokens=48)
     g_fill = replace(g, max_new_tokens=12)
-    oracle = _oracle_streams(
-        params, prompts, [g, g_fill, g_fill], "paged", "off"
-    )
+    oracle = _oracle_streams(params, prompts, [g, g_fill, g_fill], "off")
 
     eng = _engine(params, 64, R=2, spec="off")
     try:

@@ -10,8 +10,7 @@ Coverage layers:
    decode (W=1) and multi-query verify — so `paged_attn_impl` cannot
    change a quantized stream's numerics beyond float reassociation.
 3. Engine invariants:
-   - config gate: kv_dtype="int8" requires kv_layout="paged" (workspace
-     stays the fp numerics oracle); unknown dtypes rejected.
+   - config gate: unknown dtypes rejected.
    - quantized-to-quantized bit-identity: park -> LRU-evict -> host
      offload -> promote, and export -> wire (pack/unpack with scale
      blocks) -> import on a second replica, both reproduce the
@@ -187,7 +186,6 @@ def _engine(*, kv_dtype="int8", role="unified", host_mb=0.0, R=3,
         max_running_requests=R,
         new_tokens_per_chunk=chunk,
         page_size=page,
-        kv_layout="paged",
         kv_dtype=kv_dtype,
         paged_attn_impl="xla",
         kv_host_pool_mb=host_mb,
@@ -247,17 +245,6 @@ _SAMPLED = GenerationHyperparameters(
 
 
 # -- 3a. config gate ----------------------------------------------------
-
-
-def test_int8_requires_paged_layout(cpu_devices):
-    cfg = JaxDecodeConfig(
-        kv_layout="workspace", kv_dtype="int8",
-        dtype="float32", kv_cache_dtype="float32",
-    )
-    eng = JaxDecodeEngine(cfg, InferenceEngineConfig())
-    eng.set_model(_params(), TINY)
-    with pytest.raises(ValueError, match="kv_layout='paged'"):
-        eng.initialize()
 
 
 def test_unknown_kv_dtype_rejected(cpu_devices):

@@ -17,15 +17,15 @@ pytestmark = pytest.mark.slow
 import jax
 
 from areal_tpu.models.hf_io import load_hf_params, save_hf_params
-from areal_tpu.models.qwen2 import ModelConfig, decode_step, forward, prefill
+from areal_tpu.models.qwen2 import ModelConfig, decode_step_paged, forward, prefill
 
 torch = pytest.importorskip("torch")
 
 
 def _decode_consistency(cfg, params, T=10, atol=2e-3):
-    """prefill + decode_step must agree with the packed training forward —
-    the decode engine serves THESE functions, and family-specific terms
-    (o_bias, wpe, shared expert) are easy to drop from one path only."""
+    """prefill + decode_step_paged must agree with the packed training
+    forward — the decode engine serves THESE functions, and family-specific
+    terms (o_bias, wpe, shared expert) are easy to drop from one path only."""
     rng = np.random.RandomState(7)
     ids = rng.randint(0, cfg.vocab_size, (T,))
     ref = np.asarray(
@@ -34,21 +34,29 @@ def _decode_consistency(cfg, params, T=10, atol=2e-3):
     logits, ks, vs = prefill(params, ids[:-1], np.arange(T - 1), cfg)
     np.testing.assert_allclose(np.asarray(logits), ref[:-1], atol=atol, rtol=1e-3)
 
+    # slot 0 holds the prompt in blocks 1 and 2 of a paged pool; slot 1 is
+    # dead and writes to null block 0
     L = cfg.num_hidden_layers
-    nKV, hd = cfg.num_key_value_heads, cfg.head_dim_
-    S, R = T + 4, 2
-    k_cache = np.zeros((L, R, S, nKV, hd), np.float32)
-    v_cache = np.zeros((L, R, S, nKV, hd), np.float32)
-    k_cache[:, 0, : T - 1] = np.asarray(ks)
-    v_cache[:, 0, : T - 1] = np.asarray(vs)
-    lg, _, _ = decode_step(
+    D = cfg.num_key_value_heads * cfg.head_dim_
+    bsz, R = 8, 2
+    nb = -(-(T + 1) // bsz)
+    k_pool = np.zeros((L, 1 + R * nb, bsz, D), np.float32)
+    v_pool = np.zeros((L, 1 + R * nb, bsz, D), np.float32)
+    bt = np.arange(1, 1 + R * nb, dtype=np.int32).reshape(R, nb)
+    for pool, rows in ((k_pool, ks), (v_pool, vs)):
+        rows = np.asarray(rows).reshape(L, T - 1, D)
+        for p in range(T - 1):
+            pool[:, bt[0, p // bsz], p % bsz] = rows[:, p]
+    lg, _, _ = decode_step_paged(
         params,
         np.array([ids[-1], 0], np.int32),
         np.array([T - 1, 0], np.int32),
-        k_cache,
-        v_cache,
+        k_pool,
+        v_pool,
+        bt,
         cfg,
         active=np.array([True, False]),
+        attn_impl="xla",
     )
     np.testing.assert_allclose(np.asarray(lg)[0], ref[-1], atol=atol, rtol=1e-3)
 

@@ -1,15 +1,20 @@
-"""In-pool paged-attention decode: op-level correctness and engine-level
-layout parity.
+"""In-pool paged-attention decode: the op, the two model steps and the engine.
 
-The decode engine's `kv_layout="paged"` path (the default) must produce
-the SAME streams as the retained `kv_layout="workspace"` numerics oracle:
-identical greedy tokens, and per-token logprobs that are bitwise equal on
-the XLA gather impl (it reproduces the workspace op sequence exactly) /
-allclose (fp32, atol 1e-4) on the Pallas split-KV kernel. The engine
-sweep covers the full scheduling surface the ISSUE names: prefix forks
-(duplicate prompts), suffix prefills (conversation extensions past the
-shared-prefix threshold), retire-mid-chunk reconcile under run-ahead,
-and frequency-penalty + top-p sampling.
+There is one decode path: the pool is the chunk's carry, each step scatters
+its one row per slot and attends through the block table
+(`models/qwen2.decode_step_paged`, `verify_step_paged`). What holds it:
+
+- the op against a plain reference, and the Pallas kernel (interpret mode
+  here) against the XLA gather;
+- the decode step against the trainer's `forward` over prompt + token (what
+  a decode log-probability has to equal for PPO), and its write contract:
+  the row at `(layer, bt[r, p // bsz], p % bsz)` holds the new K/V, an
+  inactive slot's write lands in null block 0, every other byte stays;
+- the verify step against W sequential decode steps;
+- the engine on both attention impls over the full scheduling surface
+  (prefix forks, same-wave duplicates, suffix prefill, retire-mid-chunk
+  under run-ahead, frequency penalty, top-p), with and without speculation,
+  and streams that do not depend on where a chunk ends.
 """
 
 import asyncio
@@ -21,6 +26,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from _numerics import assert_logprobs_close
+
 from areal_tpu.api.cli_args import (
     GenerationHyperparameters,
     InferenceEngineConfig,
@@ -28,7 +35,14 @@ from areal_tpu.api.cli_args import (
 )
 from areal_tpu.api.io_struct import ModelRequest
 from areal_tpu.engine.jax_decode import JaxDecodeEngine
-from areal_tpu.models.qwen2 import ModelConfig, decode_step, init_params
+from areal_tpu.models.qwen2 import (
+    ModelConfig,
+    decode_step_paged,
+    forward,
+    init_params,
+    prefill,
+    verify_step_paged,
+)
 from areal_tpu.ops.paged_attention import paged_attention, resolve_impl
 
 TINY = ModelConfig(
@@ -167,71 +181,193 @@ def test_resolve_impl(cpu_devices):
         resolve_impl("cuda")
 
 
-def test_decode_step_paged_matches_workspace(cpu_devices):
-    """One decode step: the paged write (O(1) dynamic scatter) + in-pool
-    attention must produce the same logits as decode_step over the
-    gathered workspace, and must write the SAME bytes into the written
-    row while leaving every other live block untouched."""
-    from areal_tpu.models.qwen2 import decode_step_paged
+# ---------------------------------------------------------------------------
+# model steps: against the trainer's forward, the write contract, verify
+# against sequential decode
+# ---------------------------------------------------------------------------
 
-    rng = np.random.default_rng(2)
+_L, _NKV, _HD = TINY.num_hidden_layers, TINY.num_key_value_heads, TINY.head_dim_
+_BSZ, _NB = 8, 4  # a slot spans 32 rows
+_PROMPTS = [21, 7, 13]  # prompt + the token fed: slot 0's step lands in block 2
+_ACTIVE = np.array([True, True, False])  # slot 2 is parked with KV it must keep
+
+
+def _prefilled_pool(params, seed=2):
+    """A pool of noise with each slot's prompt (all but its last token)
+    prefilled into the slot's blocks, as the engine's prefill leaves it.
+    Returns (kp, vp, bt, ids per slot); the block ids are shuffled."""
+    rng = np.random.default_rng(seed)
+    R = len(_PROMPTS)
+    n_blocks = 1 + R * _NB + 2  # null block 0, the slots', two nobody owns
+    shape = (_L, n_blocks, _BSZ, _NKV * _HD)
+    kp = rng.standard_normal(shape).astype(np.float32)
+    vp = rng.standard_normal(shape).astype(np.float32)
+    bt = rng.permutation(np.arange(1, 1 + R * _NB)).astype(np.int32).reshape(R, _NB)
+    ids = [rng.integers(1, TINY.vocab_size, T) for T in _PROMPTS]
+    for r, x in enumerate(ids):
+        n = len(x) - 1
+        _, ks, vs = prefill(params, x[:-1], np.arange(n), TINY, with_logits=False)
+        for pool, rows in ((kp, ks), (vp, vs)):
+            rows = np.asarray(rows).reshape(_L, n, _NKV * _HD)
+            for p in range(n):
+                pool[:, bt[r, p // _BSZ], p % _BSZ] = rows[:, p]
+    return jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt), ids
+
+
+def _written(bt, positions, active):
+    """Mask [n_blocks, bsz] of the rows a step may write: each active
+    slot's `(bt[r, p // bsz], p % bsz)` and, if a slot is inactive, row 0 of
+    null block 0."""
+    mask = np.zeros((1 + bt.size + 2, _BSZ), bool)
+    for r, ps in enumerate(positions.reshape(len(active), -1)):
+        for p in ps:
+            if active[r]:
+                mask[bt[r, p // _BSZ], p % _BSZ] = True
+            else:
+                mask[0, 0] = True
+    return mask
+
+
+def _assert_rows_are_prefills(params, kp, vp, bt, r, ids, positions):
+    """Slot r's pool rows at `positions` hold the K/V a prefill of `ids`
+    computes there, in every layer."""
+    T = len(ids)
+    _, ks, vs = prefill(params, ids, np.arange(T), TINY, with_logits=False)
+    for pool, rows in ((kp, ks), (vp, vs)):
+        for p in positions:
+            np.testing.assert_allclose(
+                np.asarray(pool)[:, bt[r, p // _BSZ], p % _BSZ],
+                np.asarray(rows)[:, p].reshape(_L, -1),
+                rtol=1e-5, atol=1e-5, err_msg=f"slot {r} position {p}",
+            )
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_decode_step_paged_matches_forward(cpu_devices, impl):
+    """Prefill a prompt into the pool, step once, and the step's logits are
+    the trainer's: `forward` over prompt + token at the same positions (the
+    slow `test_model_families.py` check, on the step that ships)."""
     params = init_params(TINY, jax.random.PRNGKey(0))
-    L, nKV, hd = TINY.num_hidden_layers, TINY.num_key_value_heads, TINY.head_dim_
-    R, bsz, nb, n_blocks = 3, 8, 3, 10
-    kp = jnp.asarray(
-        rng.standard_normal((L, n_blocks, bsz, nKV * hd)).astype(np.float32)
+    kp, vp, bt, ids = _prefilled_pool(params)
+    tokens = jnp.asarray([x[-1] for x in ids], jnp.int32)
+    positions = jnp.asarray([len(x) - 1 for x in ids], jnp.int32)
+    logits, _, _ = decode_step_paged(
+        params, tokens, positions, kp, vp, bt, TINY,
+        active=jnp.asarray(_ACTIVE), attn_impl=impl,
     )
-    vp = jnp.asarray(
-        rng.standard_normal((L, n_blocks, bsz, nKV * hd)).astype(np.float32)
-    )
-    bt = jnp.asarray(
-        rng.choice(np.arange(1, n_blocks), size=(R, nb), replace=False)
-        .astype(np.int32)
-    )
-    tokens = jnp.asarray([3, 7, 11], jnp.int32)
-    positions = jnp.asarray([4, 11, 20], jnp.int32)
-    active = jnp.asarray([True, True, False])
+    for r in np.flatnonzero(_ACTIVE):
+        T = len(ids[r])
+        ref = forward(
+            params, ids[r], np.arange(T), np.zeros(T, np.int32), TINY
+        )[-1]
+        assert_logprobs_close(
+            jax.nn.log_softmax(logits[r]), jax.nn.log_softmax(ref), f"slot {r}"
+        )
 
-    # workspace oracle: gather, step, scatter
-    idx = bt.reshape(-1)
-    kc = jnp.take(kp, idx, axis=1).reshape(L, R, nb * bsz, nKV, hd)
-    vc = jnp.take(vp, idx, axis=1).reshape(L, R, nb * bsz, nKV, hd)
-    logits_ws, kc2, vc2 = decode_step(
-        params, tokens, positions, kc, vc, TINY, active=active
-    )
-    kp_ws = kp.at[:, idx].set(kc2.reshape(L, R * nb, bsz, nKV * hd))
-    vp_ws = vp.at[:, idx].set(vc2.reshape(L, R * nb, bsz, nKV * hd))
 
-    logits_pg, kp_pg, vp_pg = decode_step_paged(
-        params, tokens, positions, kp, vp, bt, TINY, active=active,
-        attn_impl="xla",
+def test_decode_step_paged_write_contract(cpu_devices):
+    """After one step the row at `(layer, bt[r, p // bsz], p % bsz)` holds
+    the new K/V of every active slot (what a prefill of prompt + token
+    computes for that position), the inactive slot's write lands in null
+    block 0, and every other byte of both pools is what it was."""
+    params = init_params(TINY, jax.random.PRNGKey(0))
+    kp, vp, bt, ids = _prefilled_pool(params)
+    tokens = jnp.asarray([x[-1] for x in ids], jnp.int32)
+    positions = np.asarray([len(x) - 1 for x in ids], np.int32)
+    _, kp2, vp2 = decode_step_paged(
+        params, tokens, jnp.asarray(positions), kp, vp, bt, TINY,
+        active=jnp.asarray(_ACTIVE), attn_impl="xla",
     )
-    np.testing.assert_array_equal(np.asarray(logits_ws), np.asarray(logits_pg))
-    # every block except the reserved null block 0 (paged parks inactive
-    # writes there; workspace masks them) must match bit for bit
-    np.testing.assert_array_equal(
-        np.asarray(kp_ws)[:, 1:], np.asarray(kp_pg)[:, 1:]
+    bt = np.asarray(bt)
+    mask = _written(bt, positions, _ACTIVE)
+    assert mask.sum() == _ACTIVE.sum() + 1
+    for before, after in ((kp, kp2), (vp, vp2)):
+        before, after = np.asarray(before), np.asarray(after)
+        np.testing.assert_array_equal(after[:, ~mask], before[:, ~mask])
+        # the parked slot's row went to the null block, not to its own
+        assert (after[:, 0, 0] != before[:, 0, 0]).any()
+    for r in np.flatnonzero(_ACTIVE):
+        _assert_rows_are_prefills(params, kp2, vp2, bt, r, ids[r], positions[r:r + 1])
+
+
+def test_verify_step_paged_write_contract(cpu_devices):
+    """The same for the verify step's W rows a slot: they land at
+    `positions0 + j` through the table (here across a block boundary),
+    hold what a prefill computes there, and nothing else changes."""
+    W = 3
+    params = init_params(TINY, jax.random.PRNGKey(0))
+    kp, vp, bt, ids = _prefilled_pool(params)
+    rng = np.random.default_rng(5)
+    ext = [np.concatenate([x, rng.integers(1, TINY.vocab_size, W - 1)]) for x in ids]
+    base = np.asarray([len(x) - 1 for x in ids], np.int32)
+    tokens = jnp.asarray(np.stack([x[-W:] for x in ext]), jnp.int32)
+    _, kp2, vp2 = verify_step_paged(
+        params, tokens, jnp.asarray(base), kp, vp, bt, TINY,
+        active=jnp.asarray(_ACTIVE), attn_impl="xla",
     )
-    np.testing.assert_array_equal(
-        np.asarray(vp_ws)[:, 1:], np.asarray(vp_pg)[:, 1:]
+    bt = np.asarray(bt)
+    positions = base[:, None] + np.arange(W)
+    assert positions[1, 0] // _BSZ != positions[1, -1] // _BSZ
+    mask = _written(bt, positions, _ACTIVE)
+    assert mask.sum() == W * _ACTIVE.sum() + 1
+    for before, after in ((kp, kp2), (vp, vp2)):
+        np.testing.assert_array_equal(
+            np.asarray(after)[:, ~mask], np.asarray(before)[:, ~mask]
+        )
+    for r in np.flatnonzero(_ACTIVE):
+        _assert_rows_are_prefills(params, kp2, vp2, bt, r, ext[r], positions[r])
+
+
+@pytest.mark.parametrize("W", [2, 4])
+def test_verify_step_paged_matches_sequential_decode(cpu_devices, W):
+    """One verify forward over W positions gives the logits and the pool
+    that W decode steps give, fed the same tokens one at a time: the
+    contract the engine's speculative accept relies on."""
+    params = init_params(TINY, jax.random.PRNGKey(0))
+    kp, vp, bt, ids = _prefilled_pool(params)
+    rng = np.random.default_rng(6)
+    tokens = np.concatenate(
+        [np.asarray([[x[-1]] for x in ids]),
+         rng.integers(1, TINY.vocab_size, (len(ids), W - 1))], axis=1,
+    ).astype(np.int32)
+    base = jnp.asarray([len(x) - 1 for x in ids], jnp.int32)
+    active = jnp.asarray(_ACTIVE)
+    logits_v, kp_v, vp_v = verify_step_paged(
+        params, jnp.asarray(tokens), base, kp, vp, bt, TINY,
+        active=active, attn_impl="xla",
     )
+    kp_s, vp_s = kp, vp
+    for j in range(W):
+        logits_j, kp_s, vp_s = decode_step_paged(
+            params, jnp.asarray(tokens[:, j]), base + j, kp_s, vp_s, bt, TINY,
+            active=active, attn_impl="xla",
+        )
+        for r in np.flatnonzero(_ACTIVE):
+            assert_logprobs_close(
+                jax.nn.log_softmax(logits_v[r, j]),
+                jax.nn.log_softmax(logits_j[r]),
+                f"slot {r} column {j}",
+            )
+    for seq, ver in ((kp_s, kp_v), (vp_s, vp_v)):
+        np.testing.assert_allclose(
+            np.asarray(ver)[:, 1:], np.asarray(seq)[:, 1:], rtol=1e-5, atol=1e-5
+        )
 
 
 # ---------------------------------------------------------------------------
-# engine level: full-trace layout parity
+# engine level: the full trace on both attention impls, chunk boundaries
 # ---------------------------------------------------------------------------
 
 _BASE = [1, 5, 9, 13, 2, 4, 6, 8]  # shared prompt for fork coverage
 
 
-def _engine(layout: str, impl: str = "auto", **kw):
+def _engine(impl: str = "auto", **kw):
     cfg = JaxDecodeConfig(
         context_length=kw.pop("context_length", 256),
         max_running_requests=kw.pop("max_running_requests", 4),
         new_tokens_per_chunk=kw.pop("new_tokens_per_chunk", 4),
         page_size=kw.pop("page_size", 16),
         decode_runahead_chunks=kw.pop("decode_runahead_chunks", 1),
-        kv_layout=layout,
         paged_attn_impl=impl,
         dtype="float32",
         kv_cache_dtype="float32",
@@ -306,8 +442,8 @@ def _run_trace(eng):
     return asyncio.run(main())
 
 
-def _trace_and_metrics(layout, impl="auto"):
-    eng = _engine(layout, impl)
+def _trace_and_metrics(impl, **kw):
+    eng = _engine(impl, **kw)
     try:
         out = _run_trace(eng)
         m = eng.get_metrics()
@@ -316,50 +452,58 @@ def _trace_and_metrics(layout, impl="auto"):
     return out, m
 
 
-def test_engine_layout_parity_xla(cpu_devices):
-    """kv_layout='paged' (xla impl) vs 'workspace': bitwise-identical
-    tokens AND logprobs across forks, suffix prefill, retire-mid-chunk
-    under run-ahead, and freq-penalty/top-p sampling."""
-    ws, m_ws = _trace_and_metrics("workspace")
-    pg, m_pg = _trace_and_metrics("paged", "xla")
-    assert len(ws) == len(pg)
-    for i, (a, b) in enumerate(zip(ws, pg)):
+@pytest.mark.parametrize("spec", ["off", "ngram"])
+def test_engine_impl_parity(cpu_devices, spec):
+    """The XLA gather and the Pallas split-KV kernel (interpret mode here)
+    give the same streams across forks, same-wave duplicates, suffix
+    prefill, retire-mid-chunk under run-ahead, and freq-penalty/top-p
+    sampling: identical tokens and stop reasons, log-probabilities to 1e-4
+    (the kernel reduces in another order), also with speculation on."""
+    xla, m_xla = _trace_and_metrics("xla", spec_decode=spec)
+    pal, m_pal = _trace_and_metrics("pallas", spec_decode=spec)
+    assert len(xla) == len(pal)
+    for i, (a, b) in enumerate(zip(xla, pal)):
         assert a.output_tokens == b.output_tokens, i
-        assert a.output_logprobs == b.output_logprobs, i
         assert a.stop_reason == b.stop_reason, i
-    # the trace really exercised the sharing paths, on both engines
-    for m in (m_ws, m_pg):
-        assert m["prefix_forks_total"] >= 1, m
-        assert m["suffix_prefills_total"] >= 1, m
-        assert m["prefix_cache_hit_rate"] > 0.0, m
-    # and the layouts differ where they should: workspace pays gather +
-    # scatter per chunk; the paged xla impl keeps only the gather (the
-    # scatter-back half of the round trip is eliminated — exactly half
-    # the bytes on the same chunk trace)
-    assert m_ws["kv_workspace_copy_bytes_total"] > 0
-    assert (
-        m_pg["kv_workspace_copy_bytes_total"]
-        == m_ws["kv_workspace_copy_bytes_total"] // 2
-    ), (m_pg["kv_workspace_copy_bytes_total"],
-        m_ws["kv_workspace_copy_bytes_total"])
-    assert m_pg["kv_layout"] == "paged"
-
-
-def test_engine_layout_parity_pallas(cpu_devices):
-    """The Pallas split-KV kernel (interpret mode on CPU) keeps greedy
-    streams identical and logprobs allclose (fp32, atol 1e-4)."""
-    ws, _ = _trace_and_metrics("workspace")
-    pg, m_pg = _trace_and_metrics("paged", "pallas")
-    # the true in-pool path copies NOTHING per chunk
-    assert m_pg["kv_workspace_copy_bytes_total"] == 0
-    for i, (a, b) in enumerate(zip(ws, pg)):
-        assert a.output_tokens == b.output_tokens, i
         np.testing.assert_allclose(
             np.asarray(a.output_logprobs),
             np.asarray(b.output_logprobs),
             atol=1e-4,
             err_msg=str(i),
         )
+    # the trace really exercised the sharing paths, on both engines
+    for m in (m_xla, m_pal):
+        assert m["prefix_forks_total"] >= 1, m
+        assert m["suffix_prefills_total"] >= 1, m
+        assert m["prefix_cache_hit_rate"] > 0.0, m
+        assert (m["spec_chunks_total"] > 0) == (spec == "ngram"), m
+
+
+@pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
+def test_streams_do_not_depend_on_chunk_boundaries(cpu_devices, greedy):
+    """The default engine reads every row back through the pool, so a stream
+    is the same whether its chunks end every 2 tokens or every 8: same
+    tokens, log-probabilities to float32 rounding (two compiled programs)."""
+    g = GenerationHyperparameters(
+        greedy=greedy, temperature=0.9, top_p=0.95, max_new_tokens=19
+    )
+    prompts = [[3, 1, 4, 1, 5], [9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3, 2, 3, 8], [2, 7]]
+
+    def run(n_chunk):
+        eng = _engine(new_tokens_per_chunk=n_chunk)
+        try:
+            # one at a time: admission order fixes each slot's sampling key
+            return [
+                eng.generate(ModelRequest(input_ids=list(p), gconfig=g), timeout=300)
+                for p in prompts
+            ]
+        finally:
+            eng.destroy()
+
+    for i, (a, b) in enumerate(zip(run(2), run(8))):
+        assert len(a.output_tokens) == 19
+        assert a.output_tokens == b.output_tokens, i
+        assert_logprobs_close(a.output_logprobs, b.output_logprobs, i)
 
 
 def test_block_table_upload_dirty_tracking(cpu_devices):
@@ -367,7 +511,7 @@ def test_block_table_upload_dirty_tracking(cpu_devices):
     are keyed on (allocator mutation version, nb), so a long generation
     with a stable slot set uploads only when admission/retire/growth
     actually moved the table."""
-    eng = _engine("paged", "xla", new_tokens_per_chunk=2)
+    eng = _engine("xla", new_tokens_per_chunk=2)
     try:
 
         async def main():
@@ -391,7 +535,7 @@ def test_prewarm_covers_paged_variants(cpu_devices):
     """Prewarm on a paged engine must ghost-compile the paged chunk
     variants (and the patch fn) so the first overlapped dispatch never
     traces: after prewarm, serving a request compiles nothing new."""
-    eng = _engine("paged", "xla")
+    eng = _engine("xla")
     try:
         eng.prewarm(prompt_len=8, new_tokens=4, sampler_top_ps=(1.0,))
         compiled = set(eng._chunk_fns)
@@ -415,9 +559,7 @@ def test_prewarm_covers_paged_variants(cpu_devices):
 def test_fragmentation_metric(cpu_devices):
     """kv_pool_fragmentation counts the free-block remainder that cannot
     back another max-context admission."""
-    eng = _engine(
-        "paged", "xla", context_length=64, page_size=16, kv_pool_tokens=112
-    )
+    eng = _engine("xla", context_length=64, page_size=16, kv_pool_tokens=112)
     try:
         m = eng.get_metrics()
         # 7 usable blocks, max_bps = 4 -> one full-context reservation

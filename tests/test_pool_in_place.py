@@ -10,7 +10,10 @@ reshape, slice, stack, copy or cast has an operand or a result as large as one
 layer's slice of the pool, no scan takes the pool as `xs` or gives it as `ys`,
 and the lowered programs alias every donated pool argument to a result. On the
 v5e those operations were three quarters of a decode chunk (PERF.md, PRs 24
-and 25); a change that brings one back fails here, on the CPU.
+and 25); a change that brings one back fails here, on the CPU. The default
+engine here (the XLA attention read in place of the kernel) is the same
+program with that one read swapped: it gathers a slot's blocks inside
+`layer/attn/attention` and holds no workspace anywhere else.
 """
 
 import re
@@ -114,9 +117,9 @@ def engine(request, cpu_devices):
         eng.destroy()
 
 
-def _programs(eng):
+def _programs(eng, nb=2, W=3):
     """(name, jitted program, arguments) as the scheduler dispatches them."""
-    nb, W, bucket, B = 2, 3, 32, 2
+    bucket, B = 32, 2
     kq, vq = eng._kv_operands()
     step_args = (
         eng.params, kq, vq, jnp.zeros((R, nb), jnp.int32),
@@ -177,3 +180,68 @@ def test_program_aliases_the_pool_to_its_results(engine, program):
         for j, a in enumerate(jax.tree.leaves(args[1:3]))
     }
     assert aliased == want, f"{name}: {aliased} != {want}"
+
+
+# -- the default engine on the CPU: the same program, one read swapped ----------
+
+
+@pytest.fixture(scope="module")
+def default_engine(cpu_devices):
+    """`paged_attn_impl` left at "auto": the XLA read off a TPU."""
+    eng = JaxDecodeEngine(
+        JaxDecodeConfig(
+            context_length=CONTEXT, max_running_requests=R,
+            new_tokens_per_chunk=CHUNK, page_size=PAGE, dtype="float32",
+            kv_cache_dtype="bfloat16", spec_decode="ngram", spec_k=2,
+        ),
+        InferenceEngineConfig(),
+    )
+    eng.set_model(init_params(TINY, jax.random.PRNGKey(0)), TINY)
+    eng.initialize()
+    eng.pause_generation()
+    try:
+        yield eng
+    finally:
+        eng.destroy()
+
+
+@pytest.mark.parametrize("program", ["chunk", "verify_chunk"])
+def test_default_engine_carries_the_pool_and_gathers_only_to_attend(
+    default_engine, program
+):
+    """The token loop and the layer loop carry the pool whole (never `xs` or
+    `ys`, never moved), and a value as large as one layer of a gathered
+    workspace `[R, nb*bsz, nKV, hd]` exists only under
+    `layer/attn/attention`, where the XLA read gathers the slot's blocks
+    (`pool_read`); one as large as `[L, R, nb*bsz, nKV, hd]` nowhere."""
+    assert default_engine._paged_impl == "xla"
+    nb, W = 4, 3
+    name, fn, args = next(
+        p for p in _programs(default_engine, nb=nb, W=W) if p[0] == program
+    )
+    pool = default_engine._k_cache
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    movers = _pool_movers(jaxpr, int(np.prod(pool.shape[1:])))
+    assert not movers, f"{name} moves the pool: {movers}"
+    scans = [e for e in _walk(jaxpr) if e.primitive.name == "scan"]
+    carrying = [
+        e for e in scans
+        if sum(v.aval.shape == pool.shape for v in e.outvars[: e.params["num_carry"]]) == 2
+    ]
+    # the token loop (the chunk only) and the layer loop
+    assert len(carrying) == len(scans) == (2 if program == "chunk" else 1)
+    layer_ws = R * nb * PAGE * pool.shape[-1]
+    gathered, outside = 0, []
+    for eqn in _walk(jaxpr):
+        scope = str(eqn.source_info.name_stack)
+        for v in eqn.outvars:
+            shape = getattr(v.aval, "shape", ())
+            if shape == pool.shape or int(np.prod(shape)) < layer_ws:
+                continue
+            assert int(np.prod(shape)) < TINY.num_hidden_layers * layer_ws, (scope, shape)
+            if "layer/attn/attention" in scope:
+                gathered += scope.endswith("attention/pool_read")
+            else:
+                outside.append(f"{eqn.primitive.name} {shape} under {scope!r}")
+    assert gathered, "the walk never reached the XLA read's gather"
+    assert not outside, f"{name} holds a workspace outside attention: {outside}"

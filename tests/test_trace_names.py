@@ -139,8 +139,9 @@ SCOPES = {
     # the decode chunk: the scan body, the model step inside it, the pool
     # (the lowered text names the layer scan's body relative to itself).
     # No `pool_read` here: in-pool the kernel alone reads the pool, in the
-    # layout it is stored in (tests/test_pool_in_place.py); the scope names
-    # the gathers of the xla impl and the workspace layout
+    # layout it is stored in; the scope names the xla impl's gather of a
+    # slot's blocks, under `layer/attn/attention` and nowhere else
+    # (tests/test_pool_in_place.py holds the default CPU engine to that)
     "chunk": ["decode_step", "decode_step/embed", "layer/attn/qkv", "layer/attn/rope",
               "layer/attn/kv_write/pool_write",
               "layer/attn/attention/paged_attention",

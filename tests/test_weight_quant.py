@@ -19,7 +19,7 @@ Coverage layers:
    kernel-shaped (4D-weight) contractions; misaligned shapes fall back
    instead of mis-tiling.
 4. weight_dtype="fp" is the numerics ORACLE: greedy + sampled streams
-   on both kv_layouts pinned bit-for-bit against a committed golden
+   pinned bit-for-bit against a committed golden
    (regenerate with AREAL_WRITE_GOLDEN=1 after an INTENTIONAL numerics
    change) — the int8 fast path must not perturb the default path.
 5. Serving + push invariants: unknown weight_dtype rejected; the
@@ -285,13 +285,12 @@ def test_misaligned_shapes_fall_back_not_mistile():
 # -- engine helpers -----------------------------------------------------
 
 
-def _engine(*, weight_dtype="fp", kv_layout="workspace", R=3, chunk=4,
+def _engine(*, weight_dtype="fp", R=3, chunk=4,
             context=160, params=None, seed=1):
     cfg = JaxDecodeConfig(
         context_length=context,
         max_running_requests=R,
         new_tokens_per_chunk=chunk,
-        kv_layout=kv_layout,
         weight_dtype=weight_dtype,
         dtype="float32",
         kv_cache_dtype="float32",
@@ -328,15 +327,14 @@ GOLDEN = os.path.join(
 )
 
 
-@pytest.mark.parametrize("kv_layout", ["workspace", "paged"])
-def test_fp_streams_bit_identical_to_golden(cpu_devices, kv_layout):
+def test_fp_streams_bit_identical_to_golden(cpu_devices):
     """The default path must stay BITWISE what it was before the int8
     fast path landed: weight_dtype="fp" routes every matmul through the
     exact pre-existing jnp.einsum call (no quantize, no dequant, no
     recast), so its streams are pinned token-for-token AND
     logprob-for-logprob against the committed golden. Regenerate with
     AREAL_WRITE_GOLDEN=1 only for an INTENTIONAL numerics change."""
-    eng = _engine(weight_dtype="fp", kv_layout=kv_layout)
+    eng = _engine(weight_dtype="fp")
     try:
         got = {}
         for gname, g in (("greedy", _GREEDY), ("sampled", _SAMPLED)):
@@ -352,7 +350,7 @@ def test_fp_streams_bit_identical_to_golden(cpu_devices, kv_layout):
     if os.environ.get("AREAL_WRITE_GOLDEN"):
         import jaxlib
 
-        golden[kv_layout] = got
+        golden = dict(got)
         # the streams follow the installed JAX's random stream and XLA:CPU
         # codegen: the file says which installation it pins
         golden["written_by"] = {
@@ -363,13 +361,11 @@ def test_fp_streams_bit_identical_to_golden(cpu_devices, kv_layout):
         with open(GOLDEN, "w") as f:
             json.dump(golden, f, indent=1, sort_keys=True)
         pytest.skip("golden regenerated")
-    assert kv_layout in golden, f"golden missing; regen {GOLDEN}"
+    assert "greedy" in golden, f"golden missing; regen {GOLDEN}"
     who = f"golden written by {golden.get('written_by')}"
     for gname in ("greedy", "sampled"):
-        assert got[gname]["tokens"] == golden[kv_layout][gname]["tokens"], who
-        assert (
-            got[gname]["logprobs"] == golden[kv_layout][gname]["logprobs"]
-        ), (gname, who)
+        assert got[gname]["tokens"] == golden[gname]["tokens"], who
+        assert got[gname]["logprobs"] == golden[gname]["logprobs"], (gname, who)
 
 
 # -- 5. serving + push invariants ---------------------------------------
