@@ -58,14 +58,18 @@ from areal_tpu.models.qwen2 import (
     lora_param_axes,
     merge_lora,
     param_logical_axes,
+    resolve_attn_impl,
     segment_ids_from_cu_seqlens,
 )
+from areal_tpu.ops.flash_attention import live_block_counts
+from areal_tpu.ops.ring_attention import cp_ring_shards, zigzag_eligible
 from areal_tpu.parallel import mesh as mesh_lib
 from areal_tpu.utils import logging, name_resolve, names, perf_tracer
 from areal_tpu.utils.data import (
     MicroBatchList,
     split_padded_tensor_dict_into_mb_list,
     unpack_sequence,
+    zigzag_indices,
 )
 
 logger = logging.getLogger("jax_engine")
@@ -1177,6 +1181,28 @@ class JaxTrainEngine(TrainEngine):
         out["position_ids"] = pos
         return out
 
+    def _attn_live_block_pct(self, mbs: list[dict[str, Any]]) -> float:
+        """Live block pairs over all block pairs of these micro-batches, by
+        the flash kernels' own rule on the host's segment ids (NumPy; no
+        device read), in the layout the model gives the kernels: the packed
+        row whole, or in ring shards, zig-zag permuted where the model
+        permutes."""
+        cfg = self.model_config
+        ring = cfg is not None and resolve_attn_impl(cfg) == "ring"
+        live = visits = 0
+        for mb in mbs:
+            cu = np.asarray(mb["cu_seqlens"])
+            total = int(cu[-1])
+            seg = segment_ids_from_cu_seqlens(cu, total)
+            pos = np.arange(total, dtype=np.int32)
+            n = cp_ring_shards(total, self.mesh) if ring else 0
+            if n and cfg.cp_zigzag and zigzag_eligible(total, self.mesh):
+                perm = zigzag_indices(total, n)
+                seg, pos = seg[perm], pos[perm]
+            a, b = live_block_counts(seg, pos, total // max(n, 1))
+            live, visits = live + a, visits + b
+        return 100.0 * live / max(visits, 1)
+
     def _device_mb(self, mb: dict[str, Any]) -> dict[str, jax.Array]:
         """One packed micro-batch on device with the token sharding."""
         return {
@@ -1678,6 +1704,10 @@ class JaxTrainEngine(TrainEngine):
                     total_weight,
                 )
                 self.params = self._merge_trainable(self.params, new_trainable)
+            # host work while the device runs the update: the share of the
+            # flash kernels' block pairs that can hold a valid (query, key)
+            # pair (the rest they skip)
+            live_pct = self._attn_live_block_pct(mb_list.mbs)
             with span("train/wait_device"):
                 gnorm_f = float(gnorm)  # blocks until the step is done on device
             step_time = time.perf_counter() - t_start
@@ -1697,6 +1727,7 @@ class JaxTrainEngine(TrainEngine):
                     update_steps=self._step_count,
                     # with n_tokens, the useful share of what the device was given
                     padded_tokens=float(sum(mb_tokens)),
+                    attn_live_block_pct=live_pct,
                     # programs this step ran for the first time (a steady-state
                     # step that compiles names itself here)
                     compiles=len(self._programs_seen) - seen_before,

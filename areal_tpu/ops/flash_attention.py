@@ -14,18 +14,37 @@ designed for our packed layout:
   O(T^2) score matrix, which is what makes 32k-token generations trainable.
 - GQA is expressed in the BlockSpec index maps: query head h reads KV head
   h // (nH // nKV) — no KV replication in HBM.
-- fp32 accumulation for scores/softmax/output accumulation; bf16 matmul
-  inputs feed the MXU.
+- every kernel casts its q/k/v/dO blocks to float32 before the matmuls, so
+  scores, softmax, the output accumulation and all gradients are float32
+  arithmetic (bf16 operands for the MXU are not used here).
 - backward is two more Pallas kernels (dq; dk/dv per query head reduced over
   the GQA group outside) wired through jax.custom_vjp, with the standard
   delta = rowsum(dO * O) trick so the backward never materialises probs.
 
 Causality is decided by explicit global token-position arrays (qpos/kpos),
 not block indices — that is what lets the SAME kernel serve both the local
-case (positions = arange, with whole above-diagonal blocks skipped via
-pl.when) and the ring-attention case (areal_tpu/ops/ring_attention.py),
-where the kv chunk comes from another shard and carries an arbitrary
-position offset.
+case (positions = arange) and the ring-attention case
+(areal_tpu/ops/ring_attention.py), where the kv chunk comes from another
+shard and carries an arbitrary position offset.
+
+Block liveness. A (query block, key block) pair can hold a valid (query,
+key) pair only if (1) the blocks' segment-id intervals, taken over their
+non-pad tokens, overlap, and (2) some non-pad query is not before every
+non-pad key: max(qpos) >= min(kpos). Both are necessary conditions of
+`_mask_for`, whatever the packing, pad tail, ring offset or zig-zag layout
+(ids need not be monotone). `block_liveness` is that rule as a [nq, nk]
+table, on NumPy or JAX arrays. The wrappers compute it once a call (XLA, on
+four int32 a block) and pack it 32 key blocks a word (`live_table`); the
+three kernels read their pair's bit from SMEM and run `_compute` only where
+it is set. A dead pair's contribution would be alpha = 1, p = 0, so skipping
+it is exact to the bit. With positions = arange, test 2 is the causal
+above-diagonal skip; a packed row of short sequences keeps only the blocks
+near its diagonal; a ring step that brings a later shard, or one with no
+sequence in common, is skipped whole. A skipped pair keeps its place in the
+grid (the table is a blocked SMEM operand, which batches under `vmap`; only
+a scalar-prefetch operand could shorten the index maps' walk, and `vmap`
+turns that into a loop over rows): about 0.18 us a step, fetches or none
+(PERF.md, PR 29).
 
 The kernel also returns the per-row log-sum-exp and differentiates through
 it (ds = p * (dp - delta + dlse)) so sharded callers can merge partial
@@ -39,6 +58,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -59,11 +79,98 @@ def _mask_for(seg_q, seg_k, qpos, kpos):
 
 
 # ---------------------------------------------------------------------------
+# Block liveness
+# ---------------------------------------------------------------------------
+
+_INT_MAX = 2**31 - 1
+_INT_MIN = -(2**31)
+# rows of a `block_bounds` table
+_SEG_LO, _SEG_HI, _POS_LO, _POS_HI = range(4)
+
+
+def block_bounds(seg, pos, block: int):
+    """[4, T // block] int32: each block's (min id, max id, min position,
+    max position) over its non-pad tokens. An all-pad block gets the empty
+    intervals (INT_MAX, INT_MIN), which overlap nothing. NumPy in, NumPy out;
+    JAX in, JAX out."""
+    xp = jnp if isinstance(seg, jax.Array) else np
+    seg = seg.reshape(-1, block)
+    pos = pos.reshape(-1, block)
+    real = seg != PADDING_SEGMENT
+    lo = lambda x: xp.where(real, x, _INT_MAX).min(axis=1)  # noqa: E731
+    hi = lambda x: xp.where(real, x, _INT_MIN).max(axis=1)  # noqa: E731
+    return xp.stack([lo(seg), hi(seg), lo(pos), hi(pos)]).astype(xp.int32)
+
+
+def block_liveness(seg_q, seg_k, qpos, kpos, block_q: int, block_k: int):
+    """[Tq // block_q, Tk // block_k] bool: False only where `_mask_for` over
+    the block pair is all False. True where the id intervals overlap and
+    some query is not before every key: necessary conditions, so a live
+    entry may still hold no valid pair when ids are not contiguous."""
+    qb = block_bounds(seg_q, qpos, block_q)[:, :, None]
+    kb = block_bounds(seg_k, kpos, block_k)[:, None, :]
+    return (
+        (qb[_SEG_LO] <= kb[_SEG_HI])
+        & (kb[_SEG_LO] <= qb[_SEG_HI])
+        & (qb[_POS_HI] >= kb[_POS_LO])
+    )
+
+
+def live_block_counts(seg, pos, shard_len: int, block: int = 512):
+    """(live, all) block pairs the three kernels visit for one packed row,
+    counted on the host (NumPy) as the wrappers lay the row out: ring shards
+    of `shard_len` tokens (the row's own length when it is not sharded),
+    each padded to whole blocks of `_fit_block(block, shard_len)`."""
+    blk = _fit_block(block, shard_len)
+    pad = -shard_len % blk
+    rows = lambda x, fill: np.pad(  # noqa: E731
+        np.asarray(x).reshape(-1, shard_len), ((0, 0), (0, pad)),
+        constant_values=fill,
+    ).reshape(-1)
+    seg, pos = rows(seg, PADDING_SEGMENT), rows(pos, 0)
+    live = block_liveness(seg, seg, pos, pos, blk, blk)
+    return int(live.sum()), live.size
+
+
+def _words(nk: int) -> int:
+    """int32 words a query block's row of `live_table` takes."""
+    return -(-nk // 32)
+
+
+def live_table(seg_q, seg_k, qpos, kpos, block_q: int, block_k: int):
+    """`block_liveness` as the kernels read it, [1, nq * words] int32: pair
+    (i, j) is bit j % 32 of word i * words + j // 32."""
+    live = block_liveness(seg_q, seg_k, qpos, kpos, block_q, block_k)
+    nq, nk = live.shape
+    words = _words(nk)
+    live = jnp.pad(live, ((0, 0), (0, words * 32 - nk))).reshape(nq, words, 32)
+    bits = live.astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32)
+    packed = bits.sum(axis=-1, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(packed, jnp.int32).reshape(1, -1)
+
+
+def _table_spec(nq: int, nk: int) -> pl.BlockSpec:
+    # The whole table in SMEM, fetched once: its block index never changes
+    # (under vmap the batching rule gives each row its own). Two-dimensional
+    # so that the batched block's last two dims equal the array's.
+    return pl.BlockSpec(
+        (1, nq * _words(nk)), lambda *_: (0, 0), memory_space=pltpu.SMEM
+    )
+
+
+def _when_live(live_ref, i, j, nk):
+    """`pl.when` on the bit of (query block i, key block j)."""
+    word = live_ref[0, i * _words(nk) + (j >> 5)]
+    return pl.when(((word >> (j & 31)) & 1) == 1)
+
+
+# ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(
+    live_ref,
     seg_q_ref,
     seg_k_ref,
     qpos_ref,
@@ -78,9 +185,6 @@ def _fwd_kernel(
     l_ref,
     *,
     sm_scale: float,
-    block_q: int,
-    block_k: int,
-    skip_blocks: bool,
 ):
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -92,6 +196,7 @@ def _fwd_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
+    @_when_live(live_ref, i, j, pl.num_programs(2))
     def _compute():
         q = q_ref[0].astype(jnp.float32)  # [Bq, hd]
         k = k_ref[0].astype(jnp.float32)  # [Bk, hd]
@@ -123,13 +228,6 @@ def _fwd_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    if skip_blocks:
-        # Positions are plain arange: kv blocks strictly above the diagonal
-        # can be skipped wholesale (~2x fwd saving for causal).
-        pl.when(j * block_k <= i * block_q + (block_q - 1))(_compute)
-    else:
-        _compute()
-
     @pl.when(j == nk - 1)
     def _finalize():
         l = l_ref[:]
@@ -140,8 +238,8 @@ def _fwd_kernel(
 
 
 def _fwd_call(
-    q3, k3, v3, seg_q, seg_k, qpos, kpos, sm_scale, block_q, block_k,
-    skip_blocks, interpret,
+    q3, k3, v3, seg_q, seg_k, qpos, kpos, live, sm_scale, block_q, block_k,
+    interpret,
 ):
     """q3: [nH, Tq, hd]; k3/v3: [nKV, Tk, hd]. Returns (o [nH,Tq,hd], lse [nH,Tq])."""
     nH, Tq, hd = q3.shape
@@ -149,18 +247,12 @@ def _fwd_call(
     group = nH // nKV
     grid = (nH, Tq // block_q, Tk // block_k)
 
-    kernel = functools.partial(
-        _fwd_kernel,
-        sm_scale=sm_scale,
-        block_q=block_q,
-        block_k=block_k,
-        skip_blocks=skip_blocks,
-    )
     o, lse = pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, sm_scale=sm_scale),
         name="flash_fwd",
         grid=grid,
         in_specs=[
+            _table_spec(Tq // block_q, Tk // block_k),
             pl.BlockSpec((1, block_q), lambda h, i, j: (0, i)),
             pl.BlockSpec((1, block_k), lambda h, i, j: (0, j)),
             pl.BlockSpec((1, block_q), lambda h, i, j: (0, i)),
@@ -195,6 +287,7 @@ def _fwd_call(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(
+        live,
         seg_q.reshape(1, Tq),
         seg_k.reshape(1, Tk),
         qpos.reshape(1, Tq),
@@ -220,6 +313,7 @@ def _scores(q, k, seg_q, seg_k, qpos, kpos, sm_scale):
 
 
 def _bwd_dq_kernel(
+    live_ref,
     seg_q_ref,
     seg_k_ref,
     qpos_ref,
@@ -235,9 +329,6 @@ def _bwd_dq_kernel(
     dq_acc_ref,
     *,
     sm_scale: float,
-    block_q: int,
-    block_k: int,
-    skip_blocks: bool,
 ):
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -247,6 +338,7 @@ def _bwd_dq_kernel(
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
+    @_when_live(live_ref, i, j, pl.num_programs(2))
     def _compute():
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
@@ -268,17 +360,13 @@ def _bwd_dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    if skip_blocks:
-        pl.when(j * block_k <= i * block_q + (block_q - 1))(_compute)
-    else:
-        _compute()
-
     @pl.when(j == nk - 1)
     def _finalize():
         dq_ref[0] = dq_acc_ref[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
+    live_ref,
     seg_q_ref,
     seg_k_ref,
     qpos_ref,
@@ -296,9 +384,6 @@ def _bwd_dkv_kernel(
     dv_acc_ref,
     *,
     sm_scale: float,
-    block_q: int,
-    block_k: int,
-    skip_blocks: bool,
 ):
     jk = pl.program_id(1)
     iq = pl.program_id(2)
@@ -309,6 +394,7 @@ def _bwd_dkv_kernel(
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
+    @_when_live(live_ref, iq, jk, pl.num_programs(1))
     def _compute():
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
@@ -335,11 +421,6 @@ def _bwd_dkv_kernel(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    if skip_blocks:
-        pl.when(iq * block_q + (block_q - 1) >= jk * block_k)(_compute)
-    else:
-        _compute()
-
     @pl.when(iq == nq - 1)
     def _finalize():
         dk_ref[0] = dk_acc_ref[:].astype(dk_ref.dtype)
@@ -347,8 +428,8 @@ def _bwd_dkv_kernel(
 
 
 def _bwd_call(
-    q3, k3, v3, seg_q, seg_k, qpos, kpos, o, lse, do, dlse,
-    sm_scale, block_q, block_k, skip_blocks, interpret,
+    q3, k3, v3, seg_q, seg_k, qpos, kpos, live, o, lse, do, dlse,
+    sm_scale, block_q, block_k, interpret,
 ):
     nH, Tq, hd = q3.shape
     nKV, Tk, _ = k3.shape
@@ -366,18 +447,15 @@ def _bwd_call(
     delta3 = delta.reshape(nH, 1, Tq)
     dlse3 = dlse.reshape(nH, 1, Tq)
 
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel,
-        sm_scale=sm_scale,
-        block_q=block_q,
-        block_k=block_k,
-        skip_blocks=skip_blocks,
+    operands = (
+        live, seg_q2, seg_k2, qpos2, kpos2, q3, k3, v3, do, lse3, delta3, dlse3
     )
+    table_spec = [_table_spec(Tq // block_q, Tk // block_k)]
     dq = pl.pallas_call(
-        dq_kernel,
+        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale),
         name="flash_dq",
         grid=(nH, Tq // block_q, Tk // block_k),
-        in_specs=[
+        in_specs=table_spec + [
             pl.BlockSpec((1, block_q), lambda h, i, j: (0, i)),
             pl.BlockSpec((1, block_k), lambda h, i, j: (0, j)),
             pl.BlockSpec((1, block_q), lambda h, i, j: (0, i)),
@@ -401,21 +479,14 @@ def _bwd_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-    )(seg_q2, seg_k2, qpos2, kpos2, q3, k3, v3, do, lse3, delta3, dlse3)
+    )(*operands)
 
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel,
-        sm_scale=sm_scale,
-        block_q=block_q,
-        block_k=block_k,
-        skip_blocks=skip_blocks,
-    )
     # dk/dv computed per *query* head, then reduced over the GQA group.
     dk_h, dv_h = pl.pallas_call(
-        dkv_kernel,
+        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale),
         name="flash_dkv",
         grid=(nH, Tk // block_k, Tq // block_q),
-        in_specs=[
+        in_specs=table_spec + [
             pl.BlockSpec((1, block_q), lambda h, jk, iq: (0, iq)),
             pl.BlockSpec((1, block_k), lambda h, jk, iq: (0, jk)),
             pl.BlockSpec((1, block_q), lambda h, jk, iq: (0, iq)),
@@ -448,7 +519,7 @@ def _bwd_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-    )(seg_q2, seg_k2, qpos2, kpos2, q3, k3, v3, do, lse3, delta3, dlse3)
+    )(*operands)
 
     dk = dk_h.reshape(nKV, group, Tk, hd).sum(axis=1).astype(k3.dtype)
     dv = dv_h.reshape(nKV, group, Tk, hd).sum(axis=1).astype(v3.dtype)
@@ -460,39 +531,41 @@ def _bwd_call(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
 def _flash(
-    q3, k3, v3, seg_q, seg_k, qpos, kpos,
-    sm_scale, block_q, block_k, skip_blocks, interpret,
+    q3, k3, v3, seg_q, seg_k, qpos, kpos, live,
+    sm_scale, block_q, block_k, interpret,
 ):
+    """live: `live_table` of the four id and position vectors. It only says
+    which block pairs to skip: more bits set give the same result."""
     return _fwd_call(
-        q3, k3, v3, seg_q, seg_k, qpos, kpos,
-        sm_scale, block_q, block_k, skip_blocks, interpret,
+        q3, k3, v3, seg_q, seg_k, qpos, kpos, live,
+        sm_scale, block_q, block_k, interpret,
     )
 
 
 def _flash_fwd(
-    q3, k3, v3, seg_q, seg_k, qpos, kpos,
-    sm_scale, block_q, block_k, skip_blocks, interpret,
+    q3, k3, v3, seg_q, seg_k, qpos, kpos, live,
+    sm_scale, block_q, block_k, interpret,
 ):
     o, lse = _fwd_call(
-        q3, k3, v3, seg_q, seg_k, qpos, kpos,
-        sm_scale, block_q, block_k, skip_blocks, interpret,
+        q3, k3, v3, seg_q, seg_k, qpos, kpos, live,
+        sm_scale, block_q, block_k, interpret,
     )
-    return (o, lse), (q3, k3, v3, seg_q, seg_k, qpos, kpos, o, lse)
+    return (o, lse), (q3, k3, v3, seg_q, seg_k, qpos, kpos, live, o, lse)
 
 
-def _flash_bwd(sm_scale, block_q, block_k, skip_blocks, interpret, res, cts):
-    q3, k3, v3, seg_q, seg_k, qpos, kpos, o, lse = res
+def _flash_bwd(sm_scale, block_q, block_k, interpret, res, cts):
+    q3, k3, v3, seg_q, seg_k, qpos, kpos, live, o, lse = res
     do, dlse = cts
     if dlse is None or isinstance(dlse, jax.custom_derivatives.SymbolicZero):
         dlse = jnp.zeros_like(lse)
     dq, dk, dv = _bwd_call(
-        q3, k3, v3, seg_q, seg_k, qpos, kpos, o, lse, do,
+        q3, k3, v3, seg_q, seg_k, qpos, kpos, live, o, lse, do,
         dlse.astype(jnp.float32),
-        sm_scale, block_q, block_k, skip_blocks, interpret,
+        sm_scale, block_q, block_k, interpret,
     )
-    return dq, dk, dv, None, None, None, None
+    return (dq, dk, dv) + (None,) * 5
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -560,7 +633,8 @@ def flash_attention_chunk(
 
     o3, lse = _flash(
         q3, k3, v3, seg_q, seg_k, qpos, kpos,
-        sm_scale, block_q, block_k, False, interpret,
+        live_table(seg_q, seg_k, qpos, kpos, block_q, block_k),
+        sm_scale, block_q, block_k, interpret,
     )
     return jnp.swapaxes(o3, 0, 1)[:Tq], jnp.swapaxes(lse, 0, 1)[:Tq]
 
@@ -604,6 +678,7 @@ def flash_attention(
 
     o3, _ = _flash(
         q3, k3, v3, seg, seg, pos, pos,
-        sm_scale, block_q, block_k, True, interpret,
+        live_table(seg, seg, pos, pos, block_q, block_k),
+        sm_scale, block_q, block_k, interpret,
     )
     return jnp.swapaxes(o3, 0, 1)[:T]

@@ -37,8 +37,11 @@ import time
 REPO = os.path.dirname(os.path.abspath(__file__))
 CONFIG = os.path.join(REPO, "examples", "configs", "qwen2.5_0.5b_grpo_smoke.yaml")
 MODEL_DIR = os.path.join(REPO, "examples", "configs", "qwen2.5-0.5b")
-# a hang is a failure too: past this, dump every thread's stack and exit
-WATCHDOG_S = 1100
+# a hang is a failure too: past this, dump every thread's stack and exit.
+# A whole run is 740 s on one chip (the kernel phase 600); on a four-chip
+# host that compiled 1.6 times slower the kernel phase alone passed 1,080 s
+# and a limit of 1,100 cut the loop phase at its first compile (PR 29).
+WATCHDOG_S = 2000
 
 
 def check(cond: bool, what: str) -> None:

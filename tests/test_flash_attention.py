@@ -6,7 +6,16 @@ import numpy as np
 import pytest
 
 from areal_tpu.models.qwen2 import PADDING_SEGMENT, segment_causal_mask
-from areal_tpu.ops.flash_attention import flash_attention
+from areal_tpu.ops.flash_attention import (
+    _NEG_INF,
+    _flash,
+    _mask_for,
+    block_liveness,
+    flash_attention,
+    flash_attention_chunk,
+    live_block_counts,
+    live_table,
+)
 
 
 def dense_reference(q, k, v, seg):
@@ -44,16 +53,173 @@ def make_inputs(T, nH, nKV, hd, seed=0, n_seqs=3, pad=0):
     return q, k, v, jnp.asarray(seg)
 
 
+# packings the block-liveness skip treats differently: (n_seqs, pad) of
+# `make_inputs`. Many short segments leave most block pairs dead; a single
+# segment leaves every pair under the diagonal live; all pad leaves none.
+PACKINGS = {
+    "many_short": lambda T: dict(n_seqs=T // 24, pad=T // 5),
+    "single_segment": lambda T: dict(n_seqs=1, pad=0),
+    "all_pad": lambda T: dict(n_seqs=1, pad=T),
+}
+
+
+def brute_force_liveness(seg_q, seg_k, qpos, kpos, block_q, block_k):
+    """[nq, nk]: does `_mask_for` over the block pair hold any valid pair."""
+    mask = np.asarray(_mask_for(*map(jnp.asarray, (seg_q, seg_k, qpos, kpos))))
+    nq, nk = len(seg_q) // block_q, len(seg_k) // block_k
+    return mask.reshape(nq, block_q, nk, block_k).any(axis=(1, 3))
+
+
+def random_packing(T, seed, mean_len, pad):
+    """Sequences of random lengths packed end to end, then a pad tail."""
+    rng = np.random.RandomState(seed)
+    seg = np.full(T, PADDING_SEGMENT, np.int32)
+    start = sid = 0
+    while start < T - pad:
+        end = min(start + int(rng.randint(1, 2 * mean_len)), T - pad)
+        seg[start:end] = sid
+        start, sid = end, sid + 1
+    return seg
+
+
 @pytest.mark.parametrize(
-    "T,nH,nKV,hd,pad",
+    "T,block,mean_len,pad",
     [
-        (256, 4, 4, 64, 0),
-        (256, 4, 2, 64, 37),  # GQA + ragged pad tail
-        (384, 8, 2, 32, 5),
+        (2048, 128, 40, 300),  # several sequences a block
+        (2048, 128, 150, 1),  # a boundary in most blocks
+        (4096, 512, 473, 700),  # the trainer's cell: 16 sequences a row
+        (2048, 128, 5000, 0),  # one sequence: the causal triangle
+        (1024, 128, 100, 1024),  # all pad: nothing live
+        (1024, 256, 60, 511),  # the pad tail starts inside a block
     ],
 )
-def test_forward_matches_dense(T, nH, nKV, hd, pad):
-    q, k, v, seg = make_inputs(T, nH, nKV, hd, pad=pad)
+def test_liveness_is_exact_on_contiguous_packings(T, block, mean_len, pad):
+    for seed in range(3):
+        seg = random_packing(T, seed, mean_len, pad)
+        pos = np.arange(T, dtype=np.int32)
+        live = block_liveness(seg, seg, pos, pos, block, block)
+        brute = brute_force_liveness(seg, seg, pos, pos, block, block)
+        np.testing.assert_array_equal(live, brute)
+        assert live_block_counts(seg, pos, T, block) == (int(brute.sum()), brute.size)
+    if pad == T:
+        assert not live.any()
+    if mean_len > T:
+        np.testing.assert_array_equal(live, np.tril(np.ones_like(live)))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_liveness_never_kills_a_valid_pair_when_ids_are_scattered(seed):
+    """No assumption that ids are monotone or contiguous: random ids with
+    pads sprinkled in, random positions. The rule may keep a block that
+    holds no pair, never drop one that does."""
+    rng = np.random.RandomState(seed)
+    Tq, Tk, bq, bk = 1024, 768, 128, 256
+    seg_q = rng.randint(-1, 6, Tq).astype(np.int32)
+    seg_k = rng.randint(-1, 6, Tk).astype(np.int32)
+    # ids in bands, so that some intervals do not overlap
+    seg_q[seg_q >= 0] += 7 * (np.arange(Tq) // 256)[seg_q >= 0]
+    seg_k[seg_k >= 0] += 7 * (np.arange(Tk) // 256)[seg_k >= 0]
+    qpos = rng.randint(0, 2000, Tq).astype(np.int32)
+    kpos = rng.randint(0, 2000, Tk).astype(np.int32)
+    live = block_liveness(seg_q, seg_k, qpos, kpos, bq, bk)
+    brute = brute_force_liveness(seg_q, seg_k, qpos, kpos, bq, bk)
+    assert not (brute & ~live).any()
+    assert not live.all()  # and it does skip something here
+
+
+
+@pytest.mark.parametrize("nk", [1, 5, 32, 40, 70])
+def test_live_table_packs_32_key_blocks_a_word(nk):
+    block, nq = 8, 6
+    rng = np.random.RandomState(nk)
+    seg_q = (rng.randint(0, 3, nq * block) + np.arange(nq * block) // 16).astype(np.int32)
+    seg_k = (rng.randint(0, 3, nk * block) + np.arange(nk * block) // 200).astype(np.int32)
+    qpos = rng.randint(0, 300, nq * block).astype(np.int32)
+    kpos = rng.randint(200, 900, nk * block).astype(np.int32)
+    live = block_liveness(seg_q, seg_k, qpos, kpos, block, block)
+    table = np.asarray(live_table(*map(jnp.asarray, (seg_q, seg_k, qpos, kpos)), block, block))
+    words = -(-nk // 32)
+    assert table.shape == (1, nq * words) and table.dtype == np.int32
+    i, j = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
+    bits = (table[0, i * words + j // 32] >> (j % 32)) & 1
+    np.testing.assert_array_equal(bits.astype(bool), live)
+    assert 0 < live.sum() and (nk == 1 or live.sum() < live.size)
+
+
+def test_forward_past_32_key_blocks():
+    """40 key blocks: a query block's row of the table spans two words."""
+    T, nH, nKV, hd = 5120, 1, 1, 32
+    q, k, v, seg = make_inputs(T, nH, nKV, hd, seed=3, n_seqs=9, pad=200)
+    out = flash_attention(q, k, v, seg, block_q=128, block_k=128, interpret=True)
+    ref = dense_reference(q, k, v, seg)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+def _flash_both_ways(q, k, v, seg_q, seg_k, qpos, kpos, block):
+    """(out, lse, dq, dk, dv) of the skipping kernels and of the same kernels
+    with every block pair forced live."""
+    q3, k3, v3 = (jnp.swapaxes(x, 0, 1) for x in (q, k, v))
+    rng = np.random.RandomState(11)
+    do = jnp.asarray(rng.randn(*q3.shape), jnp.float32)
+    dlse = jnp.asarray(rng.randn(*q3.shape[:2]), jnp.float32)
+    scale = q.shape[-1] ** -0.5
+
+    def run(live):
+        def f(q3, k3, v3):
+            return _flash(q3, k3, v3, seg_q, seg_k, qpos, kpos, live,
+                          scale, block, block, True)
+
+        (o, lse), vjp = jax.vjp(f, q3, k3, v3)
+        return (o, lse) + vjp((do, dlse))
+
+    live = live_table(seg_q, seg_k, qpos, kpos, block, block)
+    return run(live), run(jnp.full_like(live, -1))  # -1: every bit set
+
+
+@pytest.mark.parametrize("packing", list(PACKINGS))
+def test_skipping_equals_all_live_to_the_bit(packing):
+    T, nH, nKV, hd, block = 512, 4, 2, 32, 128
+    q, k, v, seg = make_inputs(T, nH, nKV, hd, seed=4, **PACKINGS[packing](T))
+    pos = jnp.arange(T, dtype=jnp.int32)
+    live = np.asarray(block_liveness(seg, seg, pos, pos, block, block))
+    assert {"many_short": 0 < live.sum() < 10, "single_segment": live.sum() == 10,
+            "all_pad": live.sum() == 0}[packing], live
+    skipping, forced = _flash_both_ways(q, k, v, seg, seg, pos, pos, block)
+    for a, b, name in zip(skipping, forced, ("out", "lse", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+
+
+def test_dead_ring_chunk_equals_all_live_to_the_bit():
+    """A visiting kv chunk from LATER in the stream: no block is visited,
+    the output is 0, lse is _NEG_INF and every gradient is 0, as when the
+    whole chunk is computed and masked."""
+    T, nH, nKV, hd, block = 256, 4, 2, 32, 128
+    q, k, v, seg = make_inputs(T, nH, nKV, hd, seed=6, n_seqs=2, pad=0)
+    qpos = jnp.arange(T, dtype=jnp.int32)
+    kpos = qpos + T
+    assert not np.asarray(block_liveness(seg, seg, qpos, kpos, block, block)).any()
+    skipping, forced = _flash_both_ways(q, k, v, seg, seg, qpos, kpos, block)
+    for a, b, name in zip(skipping, forced, ("out", "lse", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    out, lse = flash_attention_chunk(
+        q, k, v, seg, seg, qpos, kpos, block_q=block, block_k=block, interpret=True)
+    assert not np.asarray(out).any()
+    np.testing.assert_array_equal(np.asarray(lse), np.float32(_NEG_INF))
+    assert not any(np.asarray(g).any() for g in skipping[2:])
+
+
+@pytest.mark.parametrize(
+    "T,nH,nKV,hd,packing",
+    [
+        (256, 4, 4, 64, dict(pad=0)),
+        (256, 4, 2, 64, dict(pad=37)),  # GQA + ragged pad tail
+        (384, 8, 2, 32, dict(pad=5)),
+    ]
+    + [(512, 4, 2, 32, PACKINGS[name](512)) for name in PACKINGS],
+    ids=["mha", "gqa_pad_tail", "gqa8"] + list(PACKINGS),
+)
+def test_forward_matches_dense(T, nH, nKV, hd, packing):
+    q, k, v, seg = make_inputs(T, nH, nKV, hd, **packing)
     out = flash_attention(q, k, v, seg, block_q=128, block_k=128, interpret=True)
     ref = dense_reference(q, k, v, seg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
@@ -77,6 +243,42 @@ def test_backward_matches_dense():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4, err_msg=name
         )
+
+
+@pytest.mark.parametrize("packing", list(PACKINGS))
+def test_gradients_match_dense(packing):
+    T, nH, nKV, hd = 256, 2, 1, 32
+    q, k, v, seg = make_inputs(T, nH, nKV, hd, seed=8, **PACKINGS[packing](T))
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(jnp.sin(attend(q, k, v)))
+
+    gf = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, seg, block_q=128, block_k=128, interpret=True)), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss(lambda q, k, v: dense_reference(q, k, v, seg)),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gd, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4, err_msg=name
+        )
+
+
+def test_vmap_with_a_segment_row_each():
+    """As `prefill_batched` calls it: the model forward under `jax.vmap`,
+    each row its own prompt length, so each row its own bounds table."""
+    B, T, nH, nKV, hd = 3, 256, 4, 2, 32
+    rows = [make_inputs(T, nH, nKV, hd, seed=20 + b, n_seqs=1 + 2 * b, pad=40 * b)
+            for b in range(B)]
+    q, k, v, seg = (jnp.stack(x) for x in zip(*rows))
+    attend = lambda q, k, v, seg: flash_attention(  # noqa: E731
+        q, k, v, seg, block_q=128, block_k=128, interpret=True)
+    out = jax.jit(jax.vmap(attend))(q, k, v, seg)
+    for b in range(B):
+        np.testing.assert_array_equal(
+            np.asarray(out[b]), np.asarray(attend(q[b], k[b], v[b], seg[b])))
+        np.testing.assert_allclose(
+            np.asarray(out[b]), np.asarray(dense_reference(q[b], k[b], v[b], seg[b])),
+            atol=2e-5, rtol=2e-5)
 
 
 def test_nonaligned_length_padding():

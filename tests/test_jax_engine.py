@@ -1,6 +1,9 @@
 """Train-engine tests on the 8-virtual-device CPU mesh (parity with
 areal/tests/test_train_engine.py's mock-input pattern, :21-48)."""
 
+import dataclasses
+import types
+
 import numpy as np
 import pytest
 
@@ -13,13 +16,16 @@ from areal_tpu.api.cli_args import (
     TrainEngineConfig,
 )
 from areal_tpu.api.io_struct import FinetuneSpec, SaveLoadMeta
+from areal_tpu.engine.jax_engine import JaxTrainEngine
 from areal_tpu.engine.sft.lm_engine import (
     JaxLMEngine,
     compute_packed_sft_loss,
     sft_loss_weight,
 )
 from areal_tpu.models.qwen2 import ModelConfig
-from areal_tpu.utils.data import pad_sequences_to_tensors
+from areal_tpu.parallel import mesh as mesh_lib
+from areal_tpu.utils.data import pad_sequences_to_tensors, zigzag_indices
+from tests.test_flash_attention import brute_force_liveness, random_packing
 
 TINY_MODEL = ModelConfig(
     vocab_size=64,
@@ -119,6 +125,49 @@ def test_save_load_roundtrip(engine, tmp_path):
     engine.load(SaveLoadMeta(path=str(tmp_path / "ckpt"), with_optim=True))
     loss_after = engine.evaluate_lm(batch)
     assert abs(loss_before - loss_after) < 1e-4
+
+
+@pytest.mark.parametrize(
+    "attn_impl,dp,zigzag",
+    [("flash", 1, False), ("ring", 4, False), ("ring", 4, True), ("dense", 1, False)],
+    ids=["one_chip", "ring4", "ring4_zigzag", "dense_reads_as_flash"],
+)
+def test_attn_live_block_pct_is_the_numpy_count(cpu_devices, attn_impl, dp, zigzag):
+    """`train_batch`'s counter: the flash kernels' liveness rule on the host's
+    segment ids, in the layout the model hands the kernels, against a brute
+    force count over `_mask_for` (contiguous layouts: exact)."""
+    mesh = mesh_lib.build_mesh(
+        ParallelStrategy(data_parallel_size=dp), devices=cpu_devices[:dp]
+    )
+    cfg = dataclasses.replace(TINY_MODEL, attn_impl=attn_impl, cp_zigzag=zigzag)
+    fake = types.SimpleNamespace(model_config=cfg, mesh=mesh)
+    mbs, live, visits = [], 0, 0
+    for seed, total in enumerate((8192, 4096, 2560)):
+        seg = random_packing(total, seed, mean_len=400, pad=300)
+        seg[seg < 0] = seg.max() + 1  # the packer's pad tail: a segment of its own
+        cu = np.r_[0, np.flatnonzero(np.diff(seg)) + 1, total]
+        mbs.append(dict(cu_seqlens=cu))
+        pos = np.arange(total, dtype=np.int32)
+        if zigzag:
+            perm = zigzag_indices(total, dp)
+            seg, pos = seg[perm], pos[perm]
+        Tl = total // dp
+        block = min(512, Tl)
+        # each shard padded to whole blocks, as the kernels' wrappers pad it
+        shard = lambda x, i, fill: np.pad(  # noqa: E731
+            x[i * Tl:(i + 1) * Tl], (0, -Tl % block), constant_values=fill)
+        for qi in range(dp):
+            for ki in range(dp):
+                brute = brute_force_liveness(
+                    shard(seg, qi, -1), shard(seg, ki, -1), shard(pos, qi, 0),
+                    shard(pos, ki, 0), block, block)
+                live, visits = live + int(brute.sum()), visits + brute.size
+    pct = JaxTrainEngine._attn_live_block_pct(fake, mbs)
+    if zigzag:
+        assert 100.0 * live / visits <= pct < 40.0
+    else:
+        assert pct == pytest.approx(100.0 * live / visits)
+        assert 5.0 < pct < 40.0
 
 
 def test_loss_weight_counts_answer_tokens():

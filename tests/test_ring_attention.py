@@ -6,9 +6,17 @@ import numpy as np
 import pytest
 
 from areal_tpu.api.alloc_mode import ParallelStrategy
-from areal_tpu.ops.ring_attention import ring_flash_attention
+from areal_tpu.ops.flash_attention import block_liveness
+from areal_tpu.ops.ring_attention import _shard_positions, ring_flash_attention
 from areal_tpu.parallel import mesh as mesh_lib
-from tests.test_flash_attention import dense_reference, make_inputs
+from areal_tpu.utils.data import zigzag_indices
+from tests.test_flash_attention import (
+    PACKINGS,
+    brute_force_liveness,
+    dense_reference,
+    make_inputs,
+    random_packing,
+)
 
 
 @pytest.fixture()
@@ -52,6 +60,52 @@ def test_ring_gradients_match(sp_mesh):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=3e-4, rtol=3e-4, err_msg=name
         )
+
+
+@pytest.mark.parametrize("zigzag", [False, True], ids=["contiguous", "zigzag"])
+@pytest.mark.parametrize("n,mean_len", [(4, 473), (4, 5000), (2, 90), (8, 250)])
+def test_liveness_of_every_ring_step(n, mean_len, zigzag):
+    """Each (query shard, visiting kv shard) of a packed row, with the ring
+    body's own position maps: a block pair is live wherever the mask over it
+    has a true entry; on the contiguous layout exactly there; and a step
+    that brings a later shard, or one with no sequence in common, is dead."""
+    Tl, block = 1024, 128
+    T = n * Tl
+    seg_row = random_packing(T, seed=n, mean_len=mean_len, pad=T // 11)
+    if zigzag:
+        seg_row = seg_row[zigzag_indices(T, n)]
+    shard = lambda x, i: x[i * Tl:(i + 1) * Tl]  # noqa: E731
+    positions = [np.asarray(_shard_positions(jnp.int32(i), Tl, n, zigzag))
+                 for i in range(n)]
+    dead_steps = 0
+    for qi in range(n):
+        for ki in range(n):
+            args = (shard(seg_row, qi), shard(seg_row, ki), positions[qi],
+                    positions[ki], block, block)
+            live, brute = block_liveness(*args), brute_force_liveness(*args)
+            assert not (brute & ~live).any(), (qi, ki)
+            if not zigzag:
+                np.testing.assert_array_equal(live, brute, err_msg=f"{qi},{ki}")
+                if ki > qi:
+                    assert not live.any()
+            dead_steps += not live.any()
+    # the whole ring visits far fewer blocks than it holds
+    assert dead_steps >= (n * (n - 1) // 2 if not zigzag else 0)
+    if mean_len < Tl and n > 2 and not zigzag:
+        assert dead_steps > n * (n - 1) // 2  # also steps two shards back
+
+
+@pytest.mark.parametrize("packing", list(PACKINGS))
+def test_ring_matches_dense_on_packings(sp_mesh, packing):
+    """The ring over 4 shards of 128 tokens, where most ring steps of
+    `many_short` and every step of `all_pad` are skipped whole."""
+    T, nH, nKV, hd = 512, 4, 2, 32
+    q, k, v, seg = make_inputs(T, nH, nKV, hd, seed=13, **PACKINGS[packing](T))
+    out = ring_flash_attention(q, k, v, seg, mesh=sp_mesh, interpret=True)
+    ref = dense_reference(q, k, v, seg)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
+    )
 
 
 def test_ring_fallback_no_mesh():

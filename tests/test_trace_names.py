@@ -250,6 +250,8 @@ def test_train_batch_counts_padding_and_first_calls(programs):
     # 4 sequences of 8 tokens: 32 real ones in a micro-batch padded to a bucket
     assert s["n_tokens"] == 32.0 and s["padded_tokens"] >= s["n_tokens"]
     assert s["padded_tokens"] % 128 == 0
+    # one micro-batch of one 128-token block: the only block pair is live
+    assert s["attn_live_block_pct"] == 100.0
     # zero_grads, grad_step and apply_update ran for the first time
     assert s["compiles"] == 3
 
@@ -401,3 +403,43 @@ def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):
     # what the transformations around a kernel used to name it
     for wrapper in ("%checkpoint", "%rematted_computation", "%closed_call"):
         assert wrapper not in hlo, wrapper
+
+
+@pytest.mark.parametrize("nH,nKV", [(12, 2), (16, 16)], ids=["1p5b", "olmoe"])
+def test_flash_kernel_batches_under_vmap_at_the_rollout_head_shapes(one_chip, nH, nKV):
+    """The decode engine's batched prefill `vmap`s the model forward, each
+    row with its own segment ids, so each with its own liveness table: the
+    table is a blocked SMEM operand, which the batching rule folds into one
+    kernel over a longer grid (a scalar-prefetch operand would become a
+    sequential loop over the rows)."""
+    from areal_tpu.ops.flash_attention import flash_attention
+
+    B, T, hd = 16, 256, 128
+    bf = jnp.bfloat16
+    hlo = _compile(
+        jax.vmap(lambda q, k, v, seg: flash_attention(q, k, v, seg, interpret=False)),
+        one_chip, ((B, T, nH, hd), bf), ((B, T, nKV, hd), bf), ((B, T, nKV, hd), bf),
+        ((B, T), jnp.int32))
+    kernels = [line.split(" = ")[0].split("%")[-1] for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and "flash_fwd" in kernels[0], kernels
+    assert " while(" not in hlo
+
+
+def test_ring_step_kernels_are_named_at_the_fsdp4_shard_shape(one_chip):
+    """`flash_attention_chunk`, forward and both backward kernels, at a ring
+    shard of `train-1.5b-fsdp4`: 4,096 tokens, 12/2 heads of 128."""
+    from areal_tpu.ops.flash_attention import flash_attention_chunk
+
+    T, nH, nKV, hd = 4096, 12, 2, 128
+
+    def loss(q, k, v, seg_q, seg_k, qpos, kpos):
+        o, lse = flash_attention_chunk(q, k, v, seg_q, seg_k, qpos, kpos, interpret=False)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    bf = jnp.bfloat16
+    hlo = _compile(
+        jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2)), one_chip, ((T, nH, hd), bf),
+        ((T, nKV, hd), bf), ((T, nKV, hd), bf), *[((T,), jnp.int32)] * 4)
+    for kernel in ("%flash_fwd", "%flash_dq", "%flash_dkv"):
+        assert kernel in hlo, kernel

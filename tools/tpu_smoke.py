@@ -59,18 +59,43 @@ def _packed_segments(T):
     return jnp.where(idx >= pad_from, PADDING_SEGMENT, seg).astype(jnp.int32), pad_from
 
 
+def _many_segments(T):
+    """A trainer's row: sequences of 40-700 tokens packed end to end (most
+    512-blocks hold a boundary, most block pairs no valid pair) and a pad
+    tail of a block and a half."""
+    rng = np.random.RandomState(T)
+    pad_from = T - 768
+    seg = np.full(T, PADDING_SEGMENT, np.int32)
+    start = sid = 0
+    while start < pad_from:
+        end = min(start + int(rng.randint(40, 700)), pad_from)
+        seg[start:end] = sid
+        start, sid = end, sid + 1
+    return jnp.asarray(seg), pad_from
+
+
+def _segment_mask(seg, rows):
+    """[len(rows), T]: which keys of a packed row each of `rows` may see."""
+    pos = jnp.arange(seg.shape[0])
+    return (
+        (seg[rows][:, None] == seg[None, :])
+        & (pos[rows][:, None] >= pos[None, :])
+        & (seg[rows][:, None] != PADDING_SEGMENT)
+    )
+
+
 def _rel(a, b):
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     return float(jnp.max(jnp.abs(a - b)) / (1e-3 + jnp.max(jnp.abs(b))))
 
 
-def flash_case(T, nH, nKV, hd):
+def flash_case(T, nH, nKV, hd, segments=_packed_segments):
     """flash_attention forward + backward against the dense reference."""
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(kq, (T, nH, hd), jnp.bfloat16)
     k = jax.random.normal(kk, (T, nKV, hd), jnp.bfloat16)
     v = jax.random.normal(kv, (T, nKV, hd), jnp.bfloat16)
-    seg, pad_from = _packed_segments(T)
+    seg, pad_from = segments(T)
     sm_scale = hd**-0.5
     w = (seg != PADDING_SEGMENT)[:, None, None].astype(jnp.float32)
 
@@ -84,14 +109,7 @@ def flash_case(T, nH, nKV, hd):
         )
     )(q, k, v)
     g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
-    pos = jnp.arange(T)
-
-    def mask_rows(rows):
-        return (
-            (seg[rows][:, None] == seg[None, :])
-            & (pos[rows][:, None] >= pos[None, :])
-            & (seg[rows][:, None] != PADDING_SEGMENT)
-        )
+    mask_rows = functools.partial(_segment_mask, seg)
 
     if T > 8192:
         # A dense [T, T] reference is infeasible here, which is the point of
@@ -103,7 +121,7 @@ def flash_case(T, nH, nKV, hd):
         finite = all(bool(jnp.all(jnp.isfinite(g))) for g in g_flash)
         return fwd < 0.06 and finite, f"fwd_maxerr={fwd:.4f} bwd_finite={finite}"
 
-    mask = mask_rows(pos)
+    mask = mask_rows(jnp.arange(T))
 
     def loss_ref(q, k, v):
         o, _ = _masked_attention(q, k, v, mask, sm_scale)
@@ -119,18 +137,49 @@ def flash_case(T, nH, nKV, hd):
     return fwd < 0.06 and bwd < 0.06, f"fwd_maxerr={fwd:.4f} bwd_relerr={bwd:.4f}"
 
 
-def flash_chunk_case(T=1024, nH=N_HEADS, nKV=N_KV, hd=HEAD_DIM):
-    """flash_attention_chunk (ring attention's per-step kernel): local
-    queries against a kv chunk that sits EARLIER in the stream, forward
-    (out, lse) and backward through both outputs."""
+def flash_vmap_case(B=4, T=256, nH=12, nKV=2, hd=128):
+    """flash_attention under `jax.vmap` with each row's own segment ids, as
+    the decode engine's batched prefill calls it: one prompt a row, each of
+    another length, the rest of the row pad."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(kq, (B, T, nH, hd), jnp.bfloat16)
+    k = jax.random.normal(kk, (B, T, nKV, hd), jnp.bfloat16)
+    v = jax.random.normal(kv, (B, T, nKV, hd), jnp.bfloat16)
+    lens = jnp.asarray([T - 37 * b for b in range(B)])
+    seg = jnp.where(
+        jnp.arange(T)[None, :] < lens[:, None], 0, PADDING_SEGMENT
+    ).astype(jnp.int32)
+    sm_scale = hd**-0.5
+    out = jax.jit(jax.vmap(
+        lambda q, k, v, seg: flash_attention(
+            q, k, v, seg, sm_scale=sm_scale, interpret=False
+        )
+    ))(q, k, v, seg)
+    err = 0.0
+    for b in range(B):
+        mask = _segment_mask(seg[b], jnp.arange(T))
+        o_ref, _ = _masked_attention(q[b], k[b], v[b], mask, sm_scale)
+        valid = np.asarray(seg[b] != PADDING_SEGMENT)
+        err = max(err, float(
+            jnp.max(jnp.abs((out[b].astype(jnp.float32) - o_ref)[valid]))
+        ))
+    return err < 0.06, f"fwd_maxerr={err:.4f} rows={B}"
+
+
+def flash_chunk_case(later=False, T=1024, nH=N_HEADS, nKV=N_KV, hd=HEAD_DIM):
+    """flash_attention_chunk (ring attention's per-step kernel), forward
+    (out, lse) and backward through both outputs: local queries against a
+    kv chunk that sits EARLIER in the stream and is half dead (its first
+    512-block is another segment's), or against one that sits LATER and is
+    wholly dead (no kernel visits a block: out 0, lse -1e30, gradients 0)."""
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(kq, (T, nH, hd), jnp.bfloat16)
     k = jax.random.normal(kk, (T, nKV, hd), jnp.bfloat16)
     v = jax.random.normal(kv, (T, nKV, hd), jnp.bfloat16)
     seg_k = jnp.where(jnp.arange(T) < T // 2, 0, 1).astype(jnp.int32)
     seg_q = jnp.ones((T,), jnp.int32)  # continues the chunk's second segment
-    kpos = jnp.arange(T, dtype=jnp.int32)
-    qpos = kpos + T
+    qpos = jnp.arange(T, dtype=jnp.int32) + T
+    kpos = qpos + T if later else qpos - T
     sm_scale = hd**-0.5
     mask = (seg_q[:, None] == seg_k[None, :]) & (qpos[:, None] >= kpos[None, :])
 
@@ -340,11 +389,24 @@ def cases():
             "default",
             lambda a=(T, nH, nKV, hd): flash_case(*a),
         ))
+    # the trainer's packing: the block-liveness skip at work
+    for T, nH, nKV, hd in ((4096, 14, 2, 64), (4096, 12, 2, 128)):
+        out.append((
+            f"flash_attention fwd+bwd many segments + pad tail T={T} {nH}/{nKV}/{hd}",
+            "default",
+            lambda a=(T, nH, nKV, hd): flash_case(*a, segments=_many_segments),
+        ))
     out.append((
-        "flash_attention_chunk fwd+bwd T=1024 14/2/64",
-        "default on >1 chip (ring)",
-        flash_chunk_case,
+        "flash_attention under vmap, a segment row each, B=4 T=256 12/2/128",
+        "default (batched prefill)",
+        flash_vmap_case,
     ))
+    for later, what in ((False, "earlier, half-dead"), (True, "later, dead")):
+        out.append((
+            f"flash_attention_chunk fwd+bwd T=1024 14/2/64 ({what} kv chunk)",
+            "default on >1 chip (ring)",
+            lambda later=later: flash_chunk_case(later),
+        ))
     # the decode engine's head shapes: 0.5B (the loop below), 1.5B and
     # OLMoE (the rollout cells): rows of 128, of 256 and of 2,048 lanes
     for nH, nKV, hd in ((N_HEADS, N_KV, HEAD_DIM), (12, 2, 128), (16, 16, 128)):
