@@ -87,6 +87,7 @@ from areal_tpu.api.io_struct import (
     WeightUpdateMeta,
 )
 from areal_tpu.engine.kv_pool import (
+    WindowRing,
     HostKVEntry,
     HostKVStore,
     KVBlockAllocator,
@@ -94,6 +95,8 @@ from areal_tpu.engine.kv_pool import (
 )
 from areal_tpu.models import hf_io
 from areal_tpu.models.qwen2 import (
+    PREFILL_DENSE_MAX,
+    decode_load_len,
     ModelConfig,
     decode_step_paged,
     prefill,
@@ -431,6 +434,11 @@ class JaxDecodeEngine(InferenceEngine):
         self._k_scale = None
         self._v_scale = None
         self._kv_quant = False
+        # a mixed stack (window and full layers): the pools are
+        # {"full": paged pool over the full layers, "window": ring pool over
+        # the window layers} and `_ring` accounts for the rings; None for a
+        # uniform stack, whose pools are bare arrays
+        self._ring: WindowRing | None = None
         # int8 weight serving (ISSUE 16): dense matmul kernels live as
         # {"q","scale"} pytree leaves; False serves the fp oracle path
         self._w_quant = False
@@ -588,6 +596,12 @@ class JaxDecodeEngine(InferenceEngine):
         # expert of each layer and step: their ratio x E is max-over-mean load
         self._moe_pairs = 0
         self._moe_hot_pairs = 0
+        # where the chip holds a share of the experts: pairs whose expert
+        # lives on another chip; a mixed stack: cached rows the chunks'
+        # attention read, by kind of layer
+        self._moe_absent_pairs = 0
+        self._kv_full_rows_read = 0
+        self._kv_window_rows_read = 0
         self._chunk_fns: dict[bool, Callable] = {}
         # speculative verify-chunk variants, keyed (use_topp, nb, W)
         self._verify_fns: dict[tuple, Callable] = {}
@@ -749,6 +763,14 @@ class JaxDecodeEngine(InferenceEngine):
             )
         else:
             n_blocks = R * max_bps + 1
+        self._ring = None
+        if cfg.mixed:
+            self._refuse_unserved_for_mixed(bs)
+            if cfg.cache_layers["window"]:
+                self._ring = WindowRing(R, cfg.sliding_window, bs)
+            # a prefix shared in part, or found by content, would need the
+            # donor's ring at a length it no longer holds
+            self._fabric_on = False
         self._alloc = KVBlockAllocator(R, n_blocks, bs, max_bps)
         # host-RAM tier under the pool: budgeted by kv_host_pool_mb
         # (0 = disabled — eviction drops KV and resume re-prefills,
@@ -764,7 +786,8 @@ class JaxDecodeEngine(InferenceEngine):
         kv_scale_bytes = 4 if self._kv_quant else 0
         block_nbytes = (
             2  # K and V
-            * cfg.num_hidden_layers
+            * (len(cfg.cache_layers["full"]) if cfg.mixed
+               else cfg.num_hidden_layers)
             * bs
             * cfg.num_key_value_heads
             * (cfg.head_dim_ * kv_elem + kv_scale_bytes)
@@ -788,12 +811,30 @@ class JaxDecodeEngine(InferenceEngine):
             cfg.num_key_value_heads * cfg.head_dim_,
         )
         pool_dtype = jnp.int8 if self._kv_quant else kv_dtype
-        self._k_cache = jax.device_put(
-            jnp.zeros(shape, pool_dtype), self._cache_sharding
-        )
-        self._v_cache = jax.device_put(
-            jnp.zeros(shape, pool_dtype), self._cache_sharding
-        )
+
+        def new_pool():
+            if not cfg.mixed:
+                return jax.device_put(
+                    jnp.zeros(shape, pool_dtype), self._cache_sharding
+                )
+            # two kinds of cache side by side: pages through the block
+            # table for the full layers, a fixed ring of pages a slot for
+            # the window layers (engine/kv_pool.py)
+            blocks = {
+                "full": n_blocks,
+                "window": self._ring.n_blocks if self._ring else 0,
+            }
+            return {
+                kind: jax.device_put(
+                    jnp.zeros((len(layers), blocks[kind], *shape[2:]), pool_dtype),
+                    self._cache_sharding,
+                )
+                for kind, layers in cfg.cache_layers.items()
+                if layers
+            }
+
+        self._k_cache = new_pool()
+        self._v_cache = new_pool()
         self._k_scale = self._v_scale = None
         if self._kv_quant:
             # per-(row, head) f32 scales, paged like the data pool; the
@@ -838,6 +879,9 @@ class JaxDecodeEngine(InferenceEngine):
             self._runahead_discarded = 0
             self._moe_pairs = 0
             self._moe_hot_pairs = 0
+            self._moe_absent_pairs = 0
+            self._kv_full_rows_read = 0
+            self._kv_window_rows_read = 0
             self._spec_hist = np.zeros(
                 max(int(self.config.spec_k), 1) + 1, dtype=np.int64
             )
@@ -871,6 +915,51 @@ class JaxDecodeEngine(InferenceEngine):
         )
         self._thread.start()
         return self
+
+    def _refuse_unserved_for_mixed(self, block_size: int) -> None:
+        """A mixed stack's cache is two pools, one of them a ring that is
+        written over as a request grows. What moves, stores or re-reads KV
+        as one block-table-addressed array is not served for it yet: say so
+        here, with the reason, and not at the first request that needs it."""
+        cfg, c = self.model_config, self.config
+        refused = []
+        if c.kv_dtype != "fp":
+            refused.append(
+                f"kv_dtype={c.kv_dtype!r}: the ring pool has no scale pool"
+            )
+        if float(c.kv_host_pool_mb) > 0:
+            refused.append(
+                "kv_host_pool_mb > 0: the host tier gathers one block-table-"
+                "addressed pool; a ring's rows are not in it"
+            )
+        if getattr(c, "role", "unified") != "unified":
+            refused.append(
+                f"role={c.role!r}: export and import of parked KV "
+                "(migration) move the paged pool alone"
+            )
+        if cfg.cache_layers["window"]:
+            from areal_tpu.models.qwen2 import ring_slack
+
+            slack = ring_slack(cfg.sliding_window, block_size)
+            if c.spec_decode == "ngram" and int(c.spec_k) > slack:
+                refused.append(
+                    f"spec_decode='ngram' with spec_k={c.spec_k}: a verify "
+                    f"chunk of {int(c.spec_k) + 1} rows does not fit the "
+                    f"ring's {slack} rows of slack past the window"
+                )
+        if refused:
+            raise NotImplementedError(
+                f"{cfg.model_type} (window and full layers in one stack) is "
+                "not served with: " + "; ".join(refused)
+            )
+
+    def _no_migration_for_mixed(self, what: str) -> None:
+        if self.model_config is not None and self.model_config.mixed:
+            raise NotImplementedError(
+                f"{what}: a mixed stack's parked KV is a paged pool and a "
+                "ring; the migration wire and the host tier carry the paged "
+                "pool alone"
+            )
 
     def destroy(self):
         self._shutdown.set()
@@ -1398,7 +1487,9 @@ class JaxDecodeEngine(InferenceEngine):
                 # the chunk's steps and layers, live slots only, returned
                 # with the tokens. None (no leaf) for a dense model, whose
                 # compiled chunk is what it was.
-                load_init = jnp.zeros(2, jnp.int32) if moe else None
+                load_init = (
+                    jnp.zeros(decode_load_len(cfg), jnp.int32) if moe else None
+                )
 
                 # the pool itself is the scan carry (donated, so XLA
                 # updates it in place), the write is an O(1) row scatter,
@@ -1675,7 +1766,10 @@ class JaxDecodeEngine(InferenceEngine):
                     vc,
                     jnp.asarray(ids)[None],
                     positions,
-                    jnp.asarray(bt_row, dtype=jnp.int32)[None],
+                    # (a mixed stack: the row and the slot's ring blocks)
+                    jax.tree.map(
+                        lambda t: jnp.asarray(t, dtype=jnp.int32)[None], bt_row
+                    ),
                     jnp.asarray([true_len], dtype=jnp.int32),
                 )
 
@@ -1691,6 +1785,54 @@ class JaxDecodeEngine(InferenceEngine):
         if key not in self._batched_prefill_fns:
             cfg = self.model_config
             quant = self._kv_quant
+
+            def write_mixed(kq, vq, ks, vs, tables, lens_b):
+                """The prompts' rows into the two pools of a mixed stack:
+                the full layers' through the block table as below, and of
+                the window layers the pages the ring holds at the prompt's
+                end. `tables` is (block-table rows [B, nb_w], ring blocks
+                [B, pages])."""
+                bts_b, ring_b = tables if isinstance(tables, tuple) else (tables, None)
+                layers = cfg.cache_layers
+                out = []
+                for pool, rows in ((kq, ks), (vq, vs)):
+                    pool = dict(pool)
+                    if layers["full"]:
+                        fp = pool["full"]
+                        Lf, _, bsz, D = fp.shape
+                        nb_w = bts_b.shape[1]
+                        r = rows[:, np.asarray(layers["full"])]
+                        r = jnp.pad(
+                            r, ((0, 0), (0, 0), (0, nb_w * bsz - bucket),
+                                (0, 0), (0, 0))
+                        ).reshape(B, Lf, nb_w, bsz, D).astype(fp.dtype)
+                        for b in range(B):
+                            fp = fp.at[:, bts_b[b]].set(r[b])
+                        pool["full"] = fp
+                    if layers["window"]:
+                        wp = pool["window"]
+                        Lw, _, bsz, D = wp.shape
+                        pages = ring_b.shape[1]
+                        n_pg = -(-bucket // bsz)
+                        r = rows[:, np.asarray(layers["window"])]
+                        r = jnp.pad(
+                            r, ((0, 0), (0, 0), (0, n_pg * bsz - bucket),
+                                (0, 0), (0, 0))
+                        ).reshape(B, Lw, n_pg, bsz, D).astype(wp.dtype)
+                        for b in range(B):
+                            # ring column c holds the last page <= the
+                            # prompt's last whose index is c mod pages; one
+                            # that does not exist yet (a short prompt) writes
+                            # page 0's rows, which no mask ever admits there
+                            last = jnp.maximum(lens_b[b] - 1, 0) // bsz
+                            c = jnp.arange(pages, dtype=last.dtype)
+                            pg = jnp.maximum(last - (last - c) % pages, 0)
+                            wp = wp.at[:, ring_b[b]].set(
+                                jnp.take(r[b], pg, axis=1)
+                            )
+                        pool["window"] = wp
+                    out.append(pool)
+                return tuple(out)
 
             def prefill_batched(params, kq, vq, ids_b, positions, bts_b,
                                 lens_b):
@@ -1708,6 +1850,8 @@ class JaxDecodeEngine(InferenceEngine):
                     return k, v
 
                 ks, vs = jax.vmap(core)(ids_b, lens_b)  # [B, L, bucket, ...]
+                if cfg.mixed:
+                    return write_mixed(kq, vq, ks, vs, bts_b, lens_b)
                 kp, ksc = split_pool(kq)
                 vp, vsc = split_pool(vq)
                 L, _, bsz, D = kp.shape
@@ -1745,6 +1889,14 @@ class JaxDecodeEngine(InferenceEngine):
             )
         return self._batched_prefill_fns[key]
 
+    def _prefill_tables(self, slot: int, nb_w: int):
+        """What a prefill program scatters a slot's rows through: its
+        block-table row, and for a mixed stack with it its ring blocks."""
+        row = self._alloc.row(slot, nb_w)
+        if self._ring is None:
+            return row
+        return row, self._ring.blocks(slot)
+
     def _get_block_copy_fn(self):
         """Copy ONE pool block (the fork boundary's partial block).
 
@@ -1761,13 +1913,38 @@ class JaxDecodeEngine(InferenceEngine):
                     blk = jnp.take(pool, src_b[None], axis=1)
                     return pool.at[:, dst_b[None]].set(blk)
 
-                return jax.tree.map(cp, kq), jax.tree.map(cp, vq)
+                def one(pool):
+                    if isinstance(pool, dict):
+                        # a mixed stack: the boundary block is the paged
+                        # pool's; the ring is copied whole (_get_ring_copy_fn)
+                        return {**pool, "full": cp(pool["full"])}
+                    return jax.tree.map(cp, pool)
+
+                return one(kq), one(vq)
 
             self._fork_fns[True] = jax.jit(fork_block, donate_argnums=(0, 1))
         return self._fork_fns[True]
 
+    def _get_ring_copy_fn(self):
+        """Copy one slot's ring pages onto another's (a fork of a mixed
+        stack: the window layers' rows cannot be aliased, each slot writes
+        over its own)."""
+        if "ring" not in self._fork_fns:
+
+            def fork_ring(kq, vq, src_b, dst_b):
+                def cp(pool):
+                    ring = pool["window"]
+                    return {**pool, "window": ring.at[:, dst_b].set(ring[:, src_b])}
+
+                return cp(kq), cp(vq)
+
+            self._fork_fns["ring"] = jax.jit(fork_ring, donate_argnums=(0, 1))
+        return self._fork_fns["ring"]
+
     def _device_fork(self, src: int, dst: int, covered: int) -> None:
-        """Alias the donor's full blocks and copy the boundary block.
+        """Alias the donor's full blocks and copy the boundary block; in a
+        mixed stack also copy the donor's ring pages (the caller has checked
+        that the ring still holds `covered`'s window: `_ring_holds`).
         Raises PoolDry when the boundary block cannot be allocated."""
         cp = self._alloc.fork(src, dst, covered)
         if cp is not None:
@@ -1781,6 +1958,25 @@ class JaxDecodeEngine(InferenceEngine):
                     jnp.asarray(src_b, jnp.int32),
                     jnp.asarray(dst_b, jnp.int32),
                 ))
+        if self._ring is not None and src != dst:
+            fn = self._get_ring_copy_fn()
+            with self._weight_lock:
+                kq, vq = self._kv_operands()
+                self._set_kv_operands(*fn(
+                    kq,
+                    vq,
+                    jnp.asarray(self._ring.blocks(src)),
+                    jnp.asarray(self._ring.blocks(dst)),
+                ))
+            # what the donor's ring was written up to is what the copy holds
+            self._ring.reset(dst, int(self._ring.hi[src]))
+
+    def _ring_holds(self, slot: int, covered: int) -> bool:
+        """Whether `slot`'s KV can seed a request that continues at
+        `covered`: always for a uniform stack (its rows stay where they
+        were written); for a mixed one only while the slot's ring has not
+        been written past that window."""
+        return self._ring is None or self._ring.holds(slot, covered)
 
     # -- host KV tier (kv_host_pool_mb) --------------------------------
     def _get_host_gather_fn(self):
@@ -2422,11 +2618,16 @@ class JaxDecodeEngine(InferenceEngine):
             return None
         slot, covered, _ = entry
         cached = self._parked_tokens.get(item.rid, [])
-        if covered == len(item.prompt) - 1 and cached == item.prompt[:-1]:
+        if (
+            covered == len(item.prompt) - 1
+            and cached == item.prompt[:-1]
+            and self._ring_holds(slot, covered)
+        ):
             self._parked.pop(item.rid)
             self._parked_tokens.pop(item.rid, None)
             return slot
-        # prompt diverged (edited/truncated): drop the stale cache
+        # prompt diverged (edited/truncated), or run-ahead chunks wrote a
+        # mixed stack's ring past the parked window: drop the stale cache
         self._parked.pop(item.rid)
         self._parked_tokens.pop(item.rid, None)
         self._release_slot_blocks(slot)
@@ -2510,6 +2711,10 @@ class JaxDecodeEngine(InferenceEngine):
                 donor = self._prefix_lookup.get(covered_t)
                 if donor is None:
                     donor = self._find_covering_donor(covered_t)
+                if donor is not None and not self._ring_holds(donor, P - 1):
+                    # a mixed stack: the donor has decoded past this
+                    # prefix's window, its ring no longer holds it
+                    donor = None
             # Partial prefix sharing: no exact donor, but a registered
             # prefix covers the head of this prompt (multi-turn requests
             # re-submit shared history + a short new suffix). Fork the
@@ -2545,7 +2750,12 @@ class JaxDecodeEngine(InferenceEngine):
                 and not is_wave_dup
                 and not host_hit
             ):
-                found = self._find_shared_prefix(covered_t)
+                # (a mixed stack shares whole prefixes only: a suffix
+                # prefill would read the donor's window rows at `plen`)
+                found = (
+                    None if self.model_config.mixed
+                    else self._find_shared_prefix(covered_t)
+                )
                 if found is None and req_chain:
                     # fabric dedup rung: longest common block-aligned run
                     # with ANY resident registration, even one whose tail
@@ -2615,6 +2825,8 @@ class JaxDecodeEngine(InferenceEngine):
                 # no prefill: the decode loop writes KV from row 0, which
                 # invalidates whatever prefix this slot may have donated
                 self._release_slot_blocks(slot_idx)
+                if self._ring is not None:
+                    self._ring.reset(slot_idx)
             promoted = False
             if resumed is None and host_hit:
                 # Host-tier swap-in: fresh device blocks + async upload
@@ -2900,6 +3112,9 @@ class JaxDecodeEngine(InferenceEngine):
             while i < len(entries):
                 rest = len(entries) - i
                 B = 8 if rest >= 8 else 4 if rest >= 4 else 2 if rest >= 2 else 1
+                if bucket > PREFILL_DENSE_MAX:
+                    # the chunked prefill: one prompt fills the MXU alone
+                    B = 1
                 group = entries[i : i + B]
                 i += B
                 with self._prefill_dispatch(bucket, B):
@@ -2914,7 +3129,7 @@ class JaxDecodeEngine(InferenceEngine):
                                 vq,
                                 jnp.asarray(ids),
                                 jnp.asarray(positions),
-                                self._alloc.row(slot_idx, nb_w),
+                                self._prefill_tables(slot_idx, nb_w),
                                 pre,
                             ))
                     else:
@@ -2929,16 +3144,18 @@ class JaxDecodeEngine(InferenceEngine):
                                     np.stack([g[1] for g in group])
                                 ),
                                 jnp.asarray(positions),
-                                jnp.asarray(
-                                    np.stack(
-                                        [self._alloc.row(g[0], nb_w) for g in group]
-                                    )
+                                jax.tree.map(
+                                    lambda *rows: jnp.asarray(np.stack(rows)),
+                                    *[self._prefill_tables(g[0], nb_w)
+                                      for g in group],
                                 ),
                                 jnp.asarray(
                                     np.array([g[2] for g in group], np.int32)
                                 ),
                             ))
-                for slot_idx, _, _, _, covered_t in group:
+                for slot_idx, _, pre, _, covered_t in group:
+                    if self._ring is not None:
+                        self._ring.reset(slot_idx, pre)
                     self._register_prefix(slot_idx, list(covered_t))
         for dst, src, covered_t, bucket in forks:
             covered = len(covered_t)
@@ -2970,9 +3187,11 @@ class JaxDecodeEngine(InferenceEngine):
                                 jnp.asarray(
                                     np.arange(bucket, dtype=np.int32)
                                 ),
-                                self._alloc.row(dst, nb_w),
+                                self._prefill_tables(dst, nb_w),
                                 covered,
                             ))
+                        if self._ring is not None:
+                            self._ring.reset(dst, covered)
                     else:
                         self._preempt_slot(dst)
                         continue
@@ -3409,6 +3628,8 @@ class JaxDecodeEngine(InferenceEngine):
             # (spec_w - accepted - 1) back out, and retire rewinds set the
             # absolute end as for normal chunks
             self._slot_lengths[active] += spec_w
+            if self._ring is not None:
+                self._ring.note_written(active, self._slot_lengths[active])
             with self._metrics_lock:
                 self._chunks_dispatched += 1
             return _Inflight(
@@ -3469,6 +3690,8 @@ class JaxDecodeEngine(InferenceEngine):
         # ensure / bucket choice covers this (unconsumed) chunk's growth;
         # retire rewinds overwrite this with the absolute true end
         self._slot_lengths[active] += n_chunk
+        if self._ring is not None:
+            self._ring.note_written(active, self._slot_lengths[active])
         with self._metrics_lock:
             self._chunks_dispatched += 1
         return _Inflight(
@@ -3492,10 +3715,18 @@ class JaxDecodeEngine(InferenceEngine):
                 logps = np.asarray(rec.logps)
                 acc = np.asarray(rec.accepted) if rec.spec_w > 0 else None
                 if rec.moe_load is not None:
-                    pairs, hot = np.asarray(rec.moe_load).tolist()
+                    # [pairs, hot] and, by what the model is, [absent]
+                    # and [full rows, window rows] (models/qwen2.py)
+                    pairs, hot, *more = np.asarray(rec.moe_load).tolist()
+                    cfg = self.model_config
                     with self._metrics_lock:
                         self._moe_pairs += pairs
                         self._moe_hot_pairs += hot
+                        if cfg.num_experts_published_ != cfg.num_experts:
+                            self._moe_absent_pairs += more.pop(0)
+                        if more:
+                            self._kv_full_rows_read += more[0]
+                            self._kv_window_rows_read += more[1]
             self._apply_chunk(rec, toks, logps, acc)
 
     def _apply_chunk(self, rec: "_Inflight", toks: np.ndarray,
@@ -3858,7 +4089,9 @@ class JaxDecodeEngine(InferenceEngine):
         budget = max(int(self.config.max_prefill_tokens), _PREFILL_BUCKET)
         R = self.config.max_running_requests
         waves = [
-            w for w in (8, 4, 2, 1) if w <= R and w * bucket <= budget
+            w for w in (8, 4, 2, 1)
+            if w <= R and w * bucket <= budget
+            and (w == 1 or bucket <= PREFILL_DENSE_MAX)
         ] or [1]
         if include_fork and R >= 2:
             waves.append(-2)  # 2-wave of identical prompts: dup-fork path
@@ -4243,6 +4476,7 @@ class JaxDecodeEngine(InferenceEngine):
         blocks are never written by in-flight chunks, and the gather
         serialises under _sched_lock -> _weight_lock like every other
         pool read."""
+        self._no_migration_for_mixed("export_session")
         from areal_tpu.ops.kv_quant import split_pool
 
         try:
@@ -4395,6 +4629,7 @@ class JaxDecodeEngine(InferenceEngine):
         output; empty when nothing matches. Safe from the HTTP thread:
         the whole resolution + gather runs under _sched_lock (and the
         mesh scope), so a racing weight install cannot tear a chain."""
+        self._no_migration_for_mixed("export_fabric_blocks")
         from areal_tpu.ops.kv_quant import split_pool
 
         if not self._fabric_on or self._alloc is None:
@@ -4560,6 +4795,7 @@ class JaxDecodeEngine(InferenceEngine):
         carry their scale blocks in `ks`/`vs` and land verbatim — no
         requantization on this hop either.
         """
+        self._no_migration_for_mixed("import_session")
         if self._alloc is None or self._k_cache is None:
             return "rejected"
         try:
@@ -5032,6 +5268,8 @@ class JaxDecodeEngine(InferenceEngine):
             chunks_dispatched = self._chunks_dispatched
             runahead_discarded = self._runahead_discarded
             moe_pairs, moe_hot_pairs = self._moe_pairs, self._moe_hot_pairs
+            moe_absent_pairs = self._moe_absent_pairs
+            kv_rows_read = self._kv_full_rows_read, self._kv_window_rows_read
             table_uploads = self._table_uploads
             spec_hist = self._spec_hist.copy()
             spec_chunk_slots = self._spec_chunk_slots
@@ -5124,6 +5362,11 @@ class JaxDecodeEngine(InferenceEngine):
             # expert's share of them per layer and token step (0 for dense)
             "moe_pairs_total": moe_pairs,
             "moe_hot_expert_pairs_total": moe_hot_pairs,
+            # experts across chips: pairs whose expert another chip holds;
+            # a mixed stack: cached rows the chunks read, by kind of layer
+            "moe_absent_pairs_total": moe_absent_pairs,
+            "kv_full_rows_read_total": kv_rows_read[0],
+            "kv_window_rows_read_total": kv_rows_read[1],
             "device_busy_s": round(dev_busy_s, 6),
             "device_idle_s": round(dev_idle_s, 6),
             "device_idle_frac": (

@@ -33,6 +33,16 @@ boundary block is always the copied one.
 Block 0 is a reserved null block: unallocated table entries point at it,
 so uniform-width gathers of short slots read (masked) garbage instead of
 stealing a live block's rows.
+
+A mixed stack (window and full layers, models/qwen2.py) keeps TWO kinds of
+cache side by side. The full layers have the pool above, over the full
+layers alone. A window layer never reads past its window, so its rows live
+in a fixed RING of pages a slot (`WindowRing`): a second pool
+`[window layers, 1 + slots * pages, block_size, nKV*hd]` indexed by slot,
+outside the allocator, read through a `[slots, pages]` table with the window
+in the mask. A fork aliases the full layers' blocks as above and COPIES the
+donor's ring pages; what a ring has been written past, it no longer holds,
+so a donor serves a prefix only while `WindowRing.holds` says so.
 """
 
 from __future__ import annotations
@@ -176,6 +186,49 @@ class KVBlockAllocator:
             self.nblocks[dst] = full + 1
             return int(self.tables[src, full]), got[0]
         return None
+
+
+class WindowRing:
+    """Host-side account of the window layers' ring pool of one engine.
+
+    Slot r owns pool blocks `1 + r*pages .. 1 + r*pages + pages - 1`
+    (block 0 is the null block inactive slots write to); the row of
+    position p sits in page `(p // block_size) % pages`. Nothing is
+    allocated or freed: what has to be tracked is how far each slot's ring
+    has been WRITTEN (`hi`, one past the highest position), because a
+    write at p overwrites position p - pages * block_size. A slot's ring
+    holds the window of a request that continues at `covered` (the rows
+    `covered - window + 1 .. covered - 1`) only while nothing past
+    `covered - window + pages * block_size` has been written."""
+
+    def __init__(self, n_slots: int, window: int, block_size: int):
+        from areal_tpu.models.qwen2 import ring_pages
+
+        self.window = int(window)
+        self.block_size = int(block_size)
+        self.pages = ring_pages(window, block_size)
+        self.n_blocks = 1 + int(n_slots) * self.pages
+        self.hi = np.zeros(n_slots, dtype=np.int64)
+
+    def blocks(self, slot: int) -> np.ndarray:
+        """The slot's ring blocks, by page: int32 [pages]."""
+        return (1 + slot * self.pages + np.arange(self.pages)).astype(np.int32)
+
+    def note_written(self, slots, lengths) -> None:
+        """Rows up to `lengths` (exclusive) have been (or are about to be:
+        dispatched chunks) written into `slots`' rings."""
+        self.hi[slots] = np.maximum(self.hi[slots], lengths)
+
+    def reset(self, slot: int, length: int = 0) -> None:
+        """The slot's ring was rewritten from scratch (a prefill, a fork)
+        and now holds the rows below `length`."""
+        self.hi[slot] = length
+
+    def holds(self, slot: int, covered: int) -> bool:
+        """Whether the slot's ring still has every row a request continuing
+        at `covered` reads first: its window's rows below `covered`."""
+        oldest = max(covered - self.window + 1, 0)
+        return covered <= self.hi[slot] <= oldest + self.pages * self.block_size
 
 
 @dataclass

@@ -8,8 +8,9 @@ HF stores linear weights as [out, in] (torch convention); our kernels are
 transpose + reshape per tensor.
 
 Supports Qwen2/2.5 (qkv bias), Qwen3 (qk norm), Llama/Mistral, Gemma,
-Qwen3-MoE / Qwen2-MoE (shared expert) and Mixtral (block_sparse_moe.*)
-layouts. Files: model.safetensors or sharded model-*-of-*.safetensors with
+Qwen3-MoE / Qwen2-MoE (shared expert), Mixtral (block_sparse_moe.*), OLMoE
+and K-EXAONE (a leading dense layer, router bias, shared_experts; a chip's
+share of the experts by their published numbers) layouts. Files: model.safetensors or sharded model-*-of-*.safetensors with
 index.
 """
 
@@ -86,6 +87,13 @@ def hf_name_to_ours(name: str) -> tuple[str, ...] | None:
             "mlp.shared_expert.up_proj.weight": ("mlp", "shared_up_kernel"),
             "mlp.shared_expert.down_proj.weight": ("mlp", "shared_down_kernel"),
             "mlp.shared_expert_gate.weight": ("mlp", "shared_router_kernel"),
+            # K-EXAONE (DeepSeek-V3's names: no checkpoint here to read them
+            # from, benchmark/configs/k-exaone-236b-a23b.json `assumed`): the
+            # router's selection bias and the ungated shared expert
+            "mlp.gate.e_score_correction_bias": ("mlp", "router_bias"),
+            "mlp.shared_experts.gate_proj.weight": ("mlp", "shared_gate_kernel"),
+            "mlp.shared_experts.up_proj.weight": ("mlp", "shared_up_kernel"),
+            "mlp.shared_experts.down_proj.weight": ("mlp", "shared_down_kernel"),
             # Mixtral router
             "block_sparse_moe.gate.weight": ("mlp", "router_kernel"),
             "input_layernorm.weight": ("input_norm",),
@@ -260,10 +268,11 @@ def assemble_params(
             m = int(p[k].split("_")[1])
             tgt = p[:k] + p[k + 1 :]
             grouped.setdefault(tgt, {})[m] = flat.pop(p)
+        # (a chip that holds a share of the experts takes its own, by
+        # their published numbers)
+        held = range(cfg.expert_first, cfg.expert_first + cfg.num_experts)
         for tgt, by_idx in grouped.items():
-            flat[tgt] = np.stack(
-                [by_idx[m] for m in range(cfg.num_experts)], axis=0
-            )
+            flat[tgt] = np.stack([by_idx[m] for m in held], axis=0)
     if cfg.tie_word_embeddings or cfg.is_critic:
         flat = {p: w for p, w in flat.items() if p[0] != "lm_head"}
     if cfg.is_critic and ("value_head", "kernel") not in flat:
@@ -349,9 +358,12 @@ def flatten_params(params: dict, cfg: ModelConfig) -> dict[tuple[str, ...], np.n
                 len(p) >= 2
                 and p[-2] == "mlp"
                 and p[-1] in ("gate_kernel", "up_kernel", "down_kernel")
+                and w.ndim == 3  # not a leading dense layer's 2-D kernels
             ):
                 for m in range(cfg.num_experts):
-                    out2[p[:-1] + (f"expert_{m}", p[-1])] = w[m]
+                    out2[
+                        p[:-1] + (f"expert_{cfg.expert_first + m}", p[-1])
+                    ] = w[m]
             else:
                 out2[p] = w
         flat = out2
@@ -385,6 +397,12 @@ def ours_name_to_hf(path: tuple[str, ...], model_type: str = "qwen2") -> str:
     }
     if model_type == "mixtral":
         leaf_table[("mlp", "router_kernel")] = "block_sparse_moe.gate.weight"
+    if model_type == "exaone_moe":
+        leaf_table[("mlp", "router_bias")] = "mlp.gate.e_score_correction_bias"
+        for proj in ("gate", "up", "down"):
+            leaf_table[("mlp", f"shared_{proj}_kernel")] = (
+                f"mlp.shared_experts.{proj}_proj.weight"
+            )
     if path == ("embed", "embedding"):
         return "model.embed_tokens.weight"
     if path == ("final_norm",):

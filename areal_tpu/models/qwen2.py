@@ -31,6 +31,7 @@ Qwen2-MoE shared expert and OLMoE's full-width q/k norm.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
@@ -59,8 +60,11 @@ def _cstr(x: jax.Array, *logical_axes: str | None) -> jax.Array:
 # dense qwen2-shaped model with whatever keys happen to match.
 MODEL_TYPES = (
     "qwen2", "qwen3", "llama", "mistral", "gemma", "gemma2", "gpt2",
-    "mixtral", "qwen2_moe", "qwen3_moe", "olmoe",
+    "mixtral", "qwen2_moe", "qwen3_moe", "olmoe", "exaone_moe",
 )
+
+# `layer_types` entries of a mixed stack (HF's names)
+_WINDOW_LAYER, _FULL_LAYER = "sliding_attention", "full_attention"
 
 
 @dataclass(frozen=True)
@@ -155,6 +159,35 @@ class ModelConfig:
     shared_expert_intermediate_size: int = 0
     norm_topk_prob: bool = True
     router_aux_loss_coef: float = 0.0
+    # Qwen2-MoE mixes its shared expert in through a sigmoid gate of its
+    # own; K-EXAONE (DeepSeek-V3's form) adds it as it is.
+    shared_expert_gated: bool = True
+    # Router score: "softmax" over all experts, or "sigmoid" of each logit
+    # (DeepSeek-V3, K-EXAONE). `moe_router_bias`: a per-expert buffer added
+    # to the score for the CHOICE of the top k only, never to the weight.
+    # `routed_scaling_factor` multiplies the (normalised) weights.
+    moe_scoring: str = "softmax"
+    moe_router_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    # Experts across chips: the router is `num_experts_published` wide and
+    # every token picks its k among all of them; this chip HOLDS the
+    # `num_experts` experts [expert_first, expert_first + num_experts) and
+    # computes their part of the layer. Pairs whose expert lives elsewhere
+    # are computed by no one here (None = every expert is held).
+    num_experts_published: int | None = None
+    expert_first: int = 0
+    # -- mixed stacks (K-EXAONE-class). `layer_types[i]` is
+    # "sliding_attention" (window `sliding_window`) or "full_attention";
+    # None = one kind for the whole stack. `nope_full_layers`: the full
+    # layers of a mixed stack take no rotary embedding. `first_k_dense`:
+    # leading layers whose MLP is dense (`intermediate_size`) before the
+    # sparse ones. Hashable tuples/ints, so the frozen config stays a jit
+    # static. A mixed stack has no uniform per-layer pytree: its layers
+    # live unstacked (`layers_{i}`, scan_layers=False) and the layer loops
+    # read each layer's kind from here, statically.
+    layer_types: tuple | None = None
+    nope_full_layers: bool = False
+    first_k_dense: int = 0
     # vocab chunk for the fused LM-head loss (ops/fused_xent.py): peak
     # logits transient is [tokens, loss_vocab_chunk]
     loss_vocab_chunk: int = 16384
@@ -172,6 +205,45 @@ class ModelConfig:
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def num_experts_published_(self) -> int:
+        return self.num_experts_published or self.num_experts
+
+    @property
+    def mixed(self) -> bool:
+        """Layers of more than one kind: no stacked scan, two caches."""
+        return self.layer_types is not None or self.first_k_dense > 0
+
+    def layer_window(self, i) -> int | None:
+        """Layer i's attention window (None = full causal). `i` is a
+        python int in a mixed stack; a uniform stack ignores it (it may be
+        a scan's traced index)."""
+        if self.layer_types is None:
+            return self.sliding_window
+        return self.sliding_window if self.layer_types[i] == _WINDOW_LAYER else None
+
+    def layer_rope(self, i) -> bool:
+        """Whether layer i rotates q and k."""
+        if self.pos_embed != "rope":
+            return False
+        return not (self.nope_full_layers and self.layer_window(i) is None)
+
+    def layer_sparse(self, i) -> bool:
+        """Whether layer i's MLP is the routed one."""
+        return bool(self.num_experts) and (
+            self.first_k_dense == 0 or i >= self.first_k_dense
+        )
+
+    @property
+    def cache_layers(self) -> dict:
+        """{"full": layer indices with a paged cache, "window": those with
+        a ring} of a mixed stack, in layer order."""
+        L = range(self.num_hidden_layers)
+        return {
+            "full": tuple(i for i in L if self.layer_window(i) is None),
+            "window": tuple(i for i in L if self.layer_window(i) is not None),
+        }
 
     @classmethod
     def from_hf_config(cls, path_or_dict, **overrides) -> "ModelConfig":
@@ -257,6 +329,15 @@ class ModelConfig:
                 f"rope_scaling type {rs_type!r} not implemented "
                 "(supported: llama3, linear)"
             )
+        if model_type == "exaone_moe":
+            # `rope_parameters` is this family's nesting of theta and type
+            rp = hf.get("rope_parameters") or {}
+            hf = {**hf, "rope_theta": rp.get("rope_theta", hf.get("rope_theta", 1e6))}
+            if rp.get("rope_type", "default") != "default":
+                raise NotImplementedError(
+                    f"exaone_moe rope_type {rp.get('rope_type')!r} is not "
+                    "implemented (served: default)"
+                )
         kw = dict(
             vocab_size=hf["vocab_size"],
             hidden_size=hf["hidden_size"],
@@ -340,6 +421,8 @@ class ModelConfig:
                 router_aux_loss_coef=hf.get("router_aux_loss_coef", 0.0),
                 rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
             )
+        elif model_type == "exaone_moe":
+            kw.update(_exaone_moe_kw(hf))
         elif model_type == "gemma":
             # Gemma-1 (reference: realhf/api/from_hf/gemma.py — GeGLU MLP,
             # zero-centered RMSNorm, sqrt(H)-scaled embeddings, tied head).
@@ -404,13 +487,94 @@ class ModelConfig:
         return None
 
 
+def _exaone_moe_kw(hf: dict) -> dict:
+    """K-EXAONE (`exaone_moe`): window and full layers in one stack with
+    rotary embedding on the window layers only, per-head q/k norm, leading
+    dense layers, then sigmoid-scored experts with a selection bias,
+    normalised top-k times `routed_scaling_factor`, and an ungated shared
+    expert. Raises on what is not served."""
+    L = hf["num_hidden_layers"]
+    if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+        raise NotImplementedError(
+            "exaone_moe with n_group/topk_group != 1 (group-limited "
+            "routing) is not implemented"
+        )
+    if hf.get("num_nextn_predict_layers", 0):
+        raise NotImplementedError(
+            "exaone_moe with num_nextn_predict_layers > 0: the multi-token "
+            "prediction layer is not implemented (it takes no part in the "
+            "main model's logits; set it to 0 to serve the model without it)"
+        )
+    if hf.get("scoring_func", "sigmoid") not in ("sigmoid", "softmax"):
+        raise NotImplementedError(
+            f"exaone_moe scoring_func {hf['scoring_func']!r} is not implemented"
+        )
+    types = hf.get("layer_types")
+    if types is None:
+        raise NotImplementedError("exaone_moe without layer_types")
+    # a config cut in depth keeps the published list whole: the first L count
+    types = tuple(types[:L])
+    if len(types) != L or any(t not in (_WINDOW_LAYER, _FULL_LAYER) for t in types):
+        raise NotImplementedError(
+            f"exaone_moe layer_types {types!r}: need {L} entries of "
+            f"{_WINDOW_LAYER!r} / {_FULL_LAYER!r}"
+        )
+    window = hf.get("sliding_window")
+    if _WINDOW_LAYER in types and not window:
+        raise NotImplementedError("exaone_moe window layers without sliding_window")
+    k_dense = int(hf.get("first_k_dense_replace", 0))
+    mlp_types = hf.get("mlp_layer_types")
+    if mlp_types is not None:
+        mlp_types = tuple(mlp_types[:L])
+        want = ("dense",) * min(k_dense, L) + ("sparse",) * max(L - k_dense, 0)
+        if mlp_types != want:
+            raise NotImplementedError(
+                f"exaone_moe mlp_layer_types {mlp_types!r} is not "
+                f"first_k_dense_replace={k_dense} dense layers then sparse ones"
+            )
+    n_shared = int(hf.get("num_shared_experts", 0))
+    held = int(hf["num_experts"])
+    published = int(hf.get("num_experts_published", held))
+    first = int(hf.get("expert_first", 0))
+    if not 0 <= first <= published - held:
+        raise ValueError(
+            f"exaone_moe holds experts [{first}, {first + held}) of {published}"
+        )
+    return dict(
+        qk_norm=True,
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        sliding_window=int(window) if window else None,
+        layer_types=types,
+        nope_full_layers=True,
+        first_k_dense=k_dense,
+        num_experts=held,
+        num_experts_published=published,
+        expert_first=first,
+        num_experts_per_tok=hf.get("num_experts_per_tok", 8),
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        shared_expert_intermediate_size=n_shared * hf["moe_intermediate_size"],
+        shared_expert_gated=False,
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        moe_scoring=hf.get("scoring_func", "sigmoid"),
+        moe_router_bias=True,
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        router_aux_loss_coef=hf.get("router_aux_loss_coef", 0.0),
+        # layers of two kinds do not stack
+        scan_layers=False,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Parameter tree + logical sharding axes
 # ---------------------------------------------------------------------------
 
 
-def _layer_shapes(cfg: ModelConfig) -> dict:
+def _layer_shapes(cfg: ModelConfig, i: int | None = None) -> dict:
+    """One layer's leaves. `i` names the layer of an unstacked tree; None is
+    the stacked layer of a uniform stack."""
     H, M = cfg.hidden_size, cfg.intermediate_size
+    sparse = cfg.layer_sparse(0 if i is None else i)
+    S = cfg.shared_expert_intermediate_size
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     shapes = {
         "attn": {
@@ -434,20 +598,23 @@ def _layer_shapes(cfg: ModelConfig) -> dict:
                     "down_kernel": (M, H),
                 }
             )
-            if cfg.num_experts == 0
+            if not sparse
             else {
-                "router_kernel": (H, cfg.num_experts),
+                "router_kernel": (H, cfg.num_experts_published_),
+                **({"router_bias": (cfg.num_experts_published_,)}
+                   if cfg.moe_router_bias else {}),
                 "gate_kernel": (cfg.num_experts, H, cfg.moe_intermediate_size_),
                 "up_kernel": (cfg.num_experts, H, cfg.moe_intermediate_size_),
                 "down_kernel": (cfg.num_experts, cfg.moe_intermediate_size_, H),
                 **(
                     {
-                        "shared_gate_kernel": (H, cfg.shared_expert_intermediate_size),
-                        "shared_up_kernel": (H, cfg.shared_expert_intermediate_size),
-                        "shared_down_kernel": (cfg.shared_expert_intermediate_size, H),
-                        "shared_router_kernel": (H, 1),
+                        "shared_gate_kernel": (H, S),
+                        "shared_up_kernel": (H, S),
+                        "shared_down_kernel": (S, H),
+                        **({"shared_router_kernel": (H, 1)}
+                           if cfg.shared_expert_gated else {}),
                     }
-                    if cfg.shared_expert_intermediate_size
+                    if S
                     else {}
                 ),
             }
@@ -701,6 +868,7 @@ def _lora_delta(layer_p: dict, leaf: str, x: jax.Array, cfg: ModelConfig):
 
 _MOE_MLP_AXES = {
     "router_kernel": ("embed", None),
+    "router_bias": (None,),
     "gate_kernel": ("experts", "embed", "mlp"),
     "up_kernel": ("experts", "embed", "mlp"),
     "down_kernel": ("experts", "mlp", "embed"),
@@ -712,27 +880,27 @@ _MOE_MLP_AXES = {
 }
 
 
-def _mlp_axes(cfg: ModelConfig) -> dict:
-    if not cfg.num_experts:
-        keys = _layer_shapes(cfg)["mlp"].keys()
-        return {k: _LAYER_AXES["mlp"][k] for k in keys}
-    axes = dict(_MOE_MLP_AXES)
-    if not cfg.shared_expert_intermediate_size:
-        for k in list(axes):
-            if k.startswith("shared_"):
-                del axes[k]
-    return axes
+def _mlp_axes(cfg: ModelConfig, i: int | None = None) -> dict:
+    keys = _layer_shapes(cfg, i)["mlp"].keys()
+    table = _MOE_MLP_AXES if cfg.layer_sparse(0 if i is None else i) else _LAYER_AXES["mlp"]
+    return {k: table[k] for k in keys}
 
 
 def param_shapes(cfg: ModelConfig) -> dict:
     layer = _layer_shapes(cfg)
     if cfg.scan_layers:
+        if cfg.mixed:
+            raise ValueError(
+                "a mixed stack (layer_types / first_k_dense) has no uniform "
+                "per-layer pytree to stack: set scan_layers=False"
+            )
         L = cfg.num_hidden_layers
         layers = jax.tree.map(lambda s: (L, *s), layer, is_leaf=lambda x: isinstance(x, tuple))
         layers_tree = {"layers": layers}
     else:
         layers_tree = {
-            f"layers_{i}": layer for i in range(cfg.num_hidden_layers)
+            f"layers_{i}": _layer_shapes(cfg, i)
+            for i in range(cfg.num_hidden_layers)
         }
     out = {
         "embed": {"embedding": (cfg.vocab_size, cfg.hidden_size)},
@@ -762,25 +930,26 @@ def param_logical_axes(cfg: ModelConfig) -> dict:
             )
         return axes_tree
 
-    layer_axes = {
-        k: v for k, v in _LAYER_AXES.items()
-    }
-    # prune entries not present for this config
+    # only the entries present for this config (and, unstacked, this layer)
     shapes = _layer_shapes(cfg)
-    layer_axes = {
-        "attn": {k: _LAYER_AXES["attn"][k] for k in shapes["attn"]},
-        "mlp": _mlp_axes(cfg),
-        "input_norm": _LAYER_AXES["input_norm"],
-        "post_attn_norm": _LAYER_AXES["post_attn_norm"],
-    }
-    if cfg.norm_type == "layernorm":
-        layer_axes["input_norm_bias"] = _LAYER_AXES["input_norm_bias"]
-        layer_axes["post_attn_norm_bias"] = _LAYER_AXES["post_attn_norm_bias"]
+
+    def layer_axes(i=None):
+        out = {
+            "attn": {k: _LAYER_AXES["attn"][k] for k in shapes["attn"]},
+            "mlp": _mlp_axes(cfg, i),
+            "input_norm": _LAYER_AXES["input_norm"],
+            "post_attn_norm": _LAYER_AXES["post_attn_norm"],
+        }
+        if cfg.norm_type == "layernorm":
+            out["input_norm_bias"] = _LAYER_AXES["input_norm_bias"]
+            out["post_attn_norm_bias"] = _LAYER_AXES["post_attn_norm_bias"]
+        return out
+
     if cfg.scan_layers:
-        layers_tree = {"layers": prefix_layers(layer_axes)}
+        layers_tree = {"layers": prefix_layers(layer_axes())}
     else:
         layers_tree = {
-            f"layers_{i}": layer_axes for i in range(cfg.num_hidden_layers)
+            f"layers_{i}": layer_axes(i) for i in range(cfg.num_hidden_layers)
         }
     out = {
         "embed": {"embedding": ("vocab", "embed")},
@@ -1186,13 +1355,25 @@ def segment_causal_mask(
 _ATTN_IMPLS = ("auto", "flash", "dense", "ring", "chunked")
 
 
-def resolve_attn_impl(cfg: ModelConfig) -> str:
+_UNIFORM = object()
+
+
+def resolve_attn_impl(cfg: ModelConfig, window=_UNIFORM) -> str:
+    """The trainer's attention implementation. `window` is the layer's
+    window in a mixed stack, whose `attn_impl` names the implementation of
+    its FULL layers: a window layer goes through "chunked" (the flash and
+    ring kernels take no window yet) unless the config says "dense"."""
     if cfg.attn_impl not in _ATTN_IMPLS:
         raise ValueError(
             f"attn_impl={cfg.attn_impl!r} not in {_ATTN_IMPLS} "
             "(engine configs may also say 'pallas'/'xla' for flash/dense)"
         )
-    if cfg.sliding_window is not None:
+    mixed = cfg.layer_types is not None
+    if window is _UNIFORM:
+        window = None if mixed else cfg.sliding_window
+    if mixed and window is not None:
+        return "dense" if cfg.attn_impl == "dense" else "chunked"
+    if window is not None:
         # the Pallas flash/ring kernels have no window support yet —
         # attending globally would be silently wrong. The XLA chunked
         # online-softmax path applies the window at O(T·chunk) memory
@@ -1223,6 +1404,14 @@ def resolve_attn_impl(cfg: ModelConfig) -> str:
     return "flash"
 
 
+def _attention_scope(cfg: ModelConfig, window) -> str:
+    """Scope of the attention read itself: a mixed stack names its two
+    kinds apart, so a trace tells window layers from full ones."""
+    if cfg.layer_types is None:
+        return "attention"
+    return "attention_full" if window is None else "attention_window"
+
+
 def _qk_norm(q, k, layer_p: dict, cfg: ModelConfig):
     """RMSNorm of q [..., nH, hd] and k [..., nKV, hd]: per head (Qwen3,
     weight [hd]) or over the whole projection before the split into heads
@@ -1246,8 +1435,12 @@ def attention(
     segment_ids: jax.Array,
     mask: jax.Array | None,
     cfg: ModelConfig,
+    li: int | None = None,
 ) -> jax.Array:
-    """Packed multi-head GQA attention over one 1-D token stream [T, H]."""
+    """Packed multi-head GQA attention over one 1-D token stream [T, H].
+    `li`: the layer's index in a mixed stack (its window, and whether it
+    rotates q and k); `mask` is then the mask of that layer's kind."""
+    window = cfg.layer_window(li)
     with jax.named_scope("qkv"):
         q = _w_einsum("th,hnd->tnd", x, layer_p["q_kernel"], 1)
         k = _w_einsum("th,hnd->tnd", x, layer_p["k_kernel"], 1)
@@ -1262,7 +1455,7 @@ def attention(
             v = v + layer_p["v_bias"]
         if cfg.qk_norm:
             q, k = _qk_norm(q, k, layer_p, cfg)
-    if cfg.pos_embed == "rope":
+    if cfg.layer_rope(li):
         with jax.named_scope("rope"):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
@@ -1271,8 +1464,8 @@ def attention(
     v = _cstr(v, "tokens", "act_kv_heads", None)
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     T = x.shape[0]
-    impl = resolve_attn_impl(cfg)
-    with jax.named_scope("attention"):
+    impl = resolve_attn_impl(cfg, window)
+    with jax.named_scope(_attention_scope(cfg, window)):
         if impl == "flash":
             from areal_tpu.ops.flash_attention import flash_attention
 
@@ -1293,13 +1486,13 @@ def attention(
             from areal_tpu.ops.chunked_attention import chunked_attention
 
             out = chunked_attention(
-                q, k, v, segment_ids, sliding_window=cfg.sliding_window
+                q, k, v, segment_ids, sliding_window=window
             )
         else:
             # GQA: broadcast kv heads to query heads via grouped einsum.
             group = nH // nKV
             if mask is None:
-                mask = segment_causal_mask(segment_ids, cfg.sliding_window)
+                mask = segment_causal_mask(segment_ids, window)
             qg = q.reshape(T, nKV, group, hd)
             scores = jnp.einsum("tkgd,skd->kgts", qg, k).astype(jnp.float32)
             scores = scores / np.sqrt(hd)
@@ -1449,11 +1642,24 @@ def moe_mlp(
     so they are routed to no expert, cost no expert arithmetic and add
     nothing.
 
+    The score is a softmax over the router's width or a sigmoid of each
+    logit, the k are chosen on score (+ bias), normalised over the chosen
+    and scaled (`moe_scoring`, `moe_router_bias`, `norm_topk_prob`,
+    `routed_scaling_factor`). Where the chip holds `num_experts` of
+    `num_experts_published` experts, routing is over all of them and only
+    the pairs whose expert is held reach the grouped matmul; the shared
+    expert is added once. With every expert held the same code is the
+    whole layer.
+
     Returns (y [T, H], aux_loss scalar), and with `with_load` also the
-    int32 pair `[pairs computed, pairs of the busiest expert]` over the
-    valid tokens (the decode engine's expert-load counters).
+    int32 vector `[pairs computed, pairs of the busiest expert]` over the
+    valid tokens (the decode engine's expert-load counters), with a third
+    entry, the pairs whose expert another chip holds, where not all are held.
     """
     E, K = cfg.num_experts, cfg.num_experts_per_tok
+    # the router's width; E of them, from `first` on, are held here
+    E_pub, first = cfg.num_experts_published_, cfg.expert_first
+    partial = E_pub != E
 
     with jax.named_scope("router"):
         # float32 all the way: HIGHEST keeps a TPU from rounding float32
@@ -1464,47 +1670,75 @@ def moe_mlp(
             layer_p["router_kernel"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )
-        probs = jax.nn.softmax(router_logits, axis=-1)  # [T, E] float32
-        gate_vals, topk_idx = jax.lax.top_k(probs, K)  # [T, K]
+        if cfg.moe_scoring == "sigmoid":
+            probs = jax.nn.sigmoid(router_logits)  # [T, E_pub] float32
+        else:
+            probs = jax.nn.softmax(router_logits, axis=-1)
+        if cfg.moe_router_bias:
+            # the bias enters the choice of the k experts, not their weights
+            _, topk_idx = jax.lax.top_k(
+                probs + layer_p["router_bias"].astype(jnp.float32), K
+            )
+            gate_vals = jnp.take_along_axis(probs, topk_idx, axis=-1)
+        else:
+            gate_vals, topk_idx = jax.lax.top_k(probs, K)  # [T, K]
         if cfg.norm_topk_prob:
-            gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+            denom = jnp.sum(gate_vals, axis=-1, keepdims=True)
+            if cfg.moe_scoring == "sigmoid":
+                denom = denom + 1e-20
+            gate_vals = gate_vals / denom
+        if cfg.routed_scaling_factor != 1.0:
+            gate_vals = gate_vals * cfg.routed_scaling_factor
         if valid is not None:
-            # expert id E sorts last and belongs to no group
-            topk_idx = jnp.where(valid[:, None], topk_idx, E)
+            # expert id E_pub sorts last and belongs to no group
+            topk_idx = jnp.where(valid[:, None], topk_idx, E_pub)
             gate_vals = jnp.where(valid[:, None], gate_vals, 0)
+        if partial:
+            # this chip's experts: a pair whose expert lives elsewhere goes
+            # to no group here (local id E), and nothing stands in for it
+            local = topk_idx - first
+            held = (local >= 0) & (local < E)
+            held_idx = jnp.where(held, local, E)
+            gate_vals = jnp.where(held, gate_vals, 0)
+        else:
+            held_idx = topk_idx
 
     # a forward-only scan over stacked layers hands over every layer's
     # kernels and where this layer's groups start (`_scan_stacked`)
     y = _expert_mixture(cfg.hidden_act, E)(
-        x, topk_idx, gate_vals,
+        x, held_idx, gate_vals,
         layer_p["gate_kernel"], layer_p["up_kernel"], layer_p["down_kernel"],
         layer_p.get("first_group"),
     )
 
     if cfg.shared_expert_intermediate_size:
-        # Qwen2-MoE shared expert: dense SwiGLU mixed in via a per-token
-        # sigmoid gate (HF Qwen2MoeSparseMoeBlock semantics).
-        act = act_fn(cfg)
-        s_gate = jnp.einsum("th,hm->tm", x, layer_p["shared_gate_kernel"])
-        s_up = jnp.einsum("th,hm->tm", x, layer_p["shared_up_kernel"])
-        sh = _cstr(act(s_gate) * s_up, "tokens", "act_mlp")
-        ys = _cstr(
-            jnp.einsum("tm,mh->th", sh, layer_p["shared_down_kernel"]),
-            "tokens",
-            "act_embed",
-        )
-        g = jax.nn.sigmoid(
-            jnp.einsum(
-                "th,hk->tk",
-                x.astype(jnp.float32),
-                layer_p["shared_router_kernel"].astype(jnp.float32),
+        # the shared expert: a dense SwiGLU every token runs. Qwen2-MoE
+        # mixes it in via a per-token sigmoid gate (HF
+        # Qwen2MoeSparseMoeBlock semantics); K-EXAONE adds it as it is.
+        with jax.named_scope("shared_expert"):
+            act = act_fn(cfg)
+            s_gate = jnp.einsum("th,hm->tm", x, layer_p["shared_gate_kernel"])
+            s_up = jnp.einsum("th,hm->tm", x, layer_p["shared_up_kernel"])
+            sh = _cstr(act(s_gate) * s_up, "tokens", "act_mlp")
+            ys = _cstr(
+                jnp.einsum("tm,mh->th", sh, layer_p["shared_down_kernel"]),
+                "tokens",
+                "act_embed",
             )
-        ).astype(x.dtype)
-        y = y + g * ys
+            if cfg.shared_expert_gated:
+                g = jax.nn.sigmoid(
+                    jnp.einsum(
+                        "th,hk->tk",
+                        x.astype(jnp.float32),
+                        layer_p["shared_router_kernel"].astype(jnp.float32),
+                    )
+                ).astype(x.dtype)
+                ys = g * ys
+            y = y + ys
 
     # Switch/GShard load-balancing aux over REAL tokens only:
-    # E * sum_e fraction_assigned_e * mean_prob_e
-    assign = jax.nn.one_hot(topk_idx, E, dtype=jnp.float32)  # [T, K, E]
+    # E * sum_e fraction_assigned_e * mean_prob_e (over the router's width)
+    assign = jax.nn.one_hot(topk_idx, E_pub, dtype=jnp.float32)  # [T, K, E]
     if valid is not None:
         w = valid.astype(jnp.float32)
         denom = jnp.maximum(w.sum(), 1.0)
@@ -1513,9 +1747,14 @@ def moe_mlp(
     else:
         frac = assign.mean(axis=(0, 1))
         mean_prob = probs.mean(axis=0)
-    aux = E * jnp.sum(frac * mean_prob)
+    aux = E_pub * jnp.sum(frac * mean_prob)
     if with_load:
         per_expert = assign.sum(axis=(0, 1)).astype(jnp.int32)  # valid pairs
+        if partial:
+            here = jax.lax.dynamic_slice(per_expert, (first,), (E,))
+            return y, aux, jnp.stack(
+                [here.sum(), here.max(), per_expert.sum() - here.sum()]
+            )
         return y, aux, jnp.stack([per_expert.sum(), per_expert.max()])
     return y, aux
 
@@ -1539,7 +1778,7 @@ def _maybe_remat(layer_fn, cfg: ModelConfig):
     policy = (
         getattr(jax.checkpoint_policies, policy_name) if policy_name else None
     )
-    return jax.checkpoint(layer_fn, static_argnums=(6,), policy=policy)
+    return jax.checkpoint(layer_fn, static_argnums=(6, 7), policy=policy)
 
 
 @jax.named_scope("layer")
@@ -1551,18 +1790,27 @@ def decoder_layer(
     segment_ids: jax.Array,
     mask: jax.Array | None,
     cfg: ModelConfig,
+    li: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (hidden [T, H], router aux loss scalar — 0 for dense)."""
+    """Returns (hidden [T, H], router aux loss scalar — 0 for dense).
+    `li` (static) is the layer's index in an unstacked tree."""
     h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-    x = x + attention(layer_p["attn"], h, cos, sin, segment_ids, mask, cfg)
+    x = x + attention(layer_p["attn"], h, cos, sin, segment_ids, mask, cfg, li)
     h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
-    if cfg.num_experts:
+    if cfg.layer_sparse(li):
         y, aux = moe_mlp(
             layer_p["mlp"], h, cfg, valid=segment_ids != PADDING_SEGMENT
         )
     else:
         y, aux = mlp(layer_p["mlp"], h, cfg), jnp.float32(0.0)
     return x + y, aux
+
+
+def _layer_scope(cfg: ModelConfig, i: int):
+    """A leading dense layer of a sparse model under a scope of its own."""
+    if cfg.num_experts and not cfg.layer_sparse(i):
+        return jax.named_scope("dense_layer")
+    return contextlib.nullcontext()
 
 
 def forward(
@@ -1635,18 +1883,20 @@ def forward(
     cos, sin = rope_table(position_ids, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
     # Dense path: build the [T,T] mask ONCE here (outside the per-layer remat
     # region); flash/ring never materialise it.
-    mask = (
-        segment_causal_mask(segment_ids, cfg.sliding_window)
-        if resolve_attn_impl(cfg) == "dense"
-        else None
-    )
+    # (a mixed stack: one mask for each kind of layer that runs dense)
+    masks = {
+        w: segment_causal_mask(segment_ids, w)
+        for w in {cfg.layer_window(i) for i in range(cfg.num_hidden_layers)}
+        if resolve_attn_impl(cfg, w) == "dense"
+    }
+    mask = masks.get(cfg.sliding_window)
 
     layer_fn = _maybe_remat(decoder_layer, cfg)
 
     if cfg.scan_layers:
         def body(carry, layer_p):
             h, aux_sum = carry
-            h, aux = layer_fn(layer_p, h, cos, sin, segment_ids, mask, cfg)
+            h, aux = layer_fn(layer_p, h, cos, sin, segment_ids, mask, cfg, None)
             return (h, aux_sum + aux), None
 
         (x, aux_total), _ = jax.lax.scan(
@@ -1655,9 +1905,11 @@ def forward(
     else:
         aux_total = jnp.float32(0.0)
         for i in range(cfg.num_hidden_layers):
-            x, aux = layer_fn(
-                params[f"layers_{i}"], x, cos, sin, segment_ids, mask, cfg
-            )
+            with _layer_scope(cfg, i):
+                x, aux = layer_fn(
+                    params[f"layers_{i}"], x, cos, sin, segment_ids,
+                    masks.get(cfg.layer_window(i)), cfg, i,
+                )
             aux_total = aux_total + aux
 
     with jax.named_scope("final_norm"):
@@ -1717,7 +1969,7 @@ def _pp_stage_fn(cfg: ModelConfig):
 
         def body(carry, layer_p):
             h, aux_sum = carry
-            h, aux = layer_fn(layer_p, h, cos, sin, seg, None, cfg)
+            h, aux = layer_fn(layer_p, h, cos, sin, seg, None, cfg, None)
             return (h, aux_sum + aux), None
 
         (h, aux_sum), _ = jax.lax.scan(
@@ -1989,30 +2241,53 @@ def _embed_tokens(params: dict, tokens, positions, cfg: ModelConfig) -> jax.Arra
 
 
 def _attn_out_mlp(layer_p: dict, x, attn_out, cfg: ModelConfig, valid,
-                  moe_load: bool = False):
+                  moe_load: bool = False, li=None):
     """What every decode-side layer body ends with: the output projection of
     `attn_out` [N, nH, hd] and the MLP, each with its residual. With
     `moe_load` (MoE models only) returns (x, this layer's expert load, see
-    `moe_mlp`)."""
+    `moe_mlp`; zeros from a leading dense layer). `li`: the layer's index,
+    static in a mixed stack."""
+    sparse = cfg.layer_sparse(li)
     with jax.named_scope("attn"), jax.named_scope("o_proj"):
         proj = _w_einsum("tnd,ndh->th", attn_out, layer_p["attn"]["o_kernel"], 2)
         if cfg.attn_out_bias:
             proj = proj + layer_p["attn"]["o_bias"]
     x = x + proj
     h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
-    if moe_load:
+    if moe_load and sparse:
         y, _, load = moe_mlp(layer_p["mlp"], h, cfg, valid=valid, with_load=True)
         return x + y, load
-    if cfg.num_experts:
+    if sparse:
         y, _ = moe_mlp(layer_p["mlp"], h, cfg, valid=valid)
     else:
         y = mlp(layer_p["mlp"], h, cfg)
+    if moe_load:
+        return x + y, jnp.zeros(_moe_load_len(cfg), jnp.int32)
     return x + y
 
 
-def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig):
+# kernel names of the two kinds of cache read, so a device trace tells them
+# apart (the full layers keep the uniform stacks' name)
+_PAGED_KERNELS = {"full": "paged_attention", "window": "paged_attention_window"}
+
+
+def _moe_load_len(cfg: ModelConfig) -> int:
+    """Entries of `moe_mlp`'s load vector."""
+    return 3 if cfg.num_experts_published_ != cfg.num_experts else 2
+
+
+def decode_load_len(cfg: ModelConfig) -> int:
+    """Entries of the vector `decode_step_paged` returns under `moe_load`:
+    `moe_mlp`'s, and for a mixed stack the cached rows read by the full and
+    by the window layers."""
+    return _moe_load_len(cfg) + (2 if cfg.mixed else 0)
+
+
+def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig,
+                 rope: bool = True):
     """Shared QKV projection + norm + rope. x: [..., H] with leading dims
-    matching cos/sin's leading dims."""
+    matching cos/sin's leading dims. `rope` False: a layer that takes no
+    rotary embedding (the full layers of a mixed stack)."""
     with jax.named_scope("qkv"):
         q = _w_einsum("...h,hnd->...nd", x, layer_p["q_kernel"], 1)
         k = _w_einsum("...h,hnd->...nd", x, layer_p["k_kernel"], 1)
@@ -2034,12 +2309,17 @@ def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig):
             [t1 * cos_b - t2 * sin_b, t2 * cos_b + t1 * sin_b], axis=-1
         )
 
-    if cfg.pos_embed != "rope":
+    if cfg.pos_embed != "rope" or not rope:
         return q, k, v
     return rot(q), rot(k), v
 
 
 _EXPERT_KERNELS = ("gate_kernel", "up_kernel", "down_kernel")
+
+# The longest prefill bucket whose attention builds its [nKV, group, T, T]
+# float32 scores whole. Above it (prompts of thousands of tokens: 64 heads at
+# T = 6,144 would be 9.7 GB) the keys go a block at a time.
+PREFILL_DENSE_MAX = 1024
 
 
 def _scan_stacked(step, carry, params, cfg: ModelConfig, *xs):
@@ -2126,50 +2406,72 @@ def prefill(
     else:
         cos, sin = rope_table(position_ids, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
     T = input_ids.shape[0]
-    causal = jnp.tril(jnp.ones((T, T), dtype=bool))
-    band = _window_band(T, cfg.sliding_window)
-    if band is not None:
-        causal = causal & band
     with_prefix = prefix_k is not None
-    if with_prefix:
+    # above `PREFILL_DENSE_MAX` tokens no [T, T] score tensor is built: the
+    # attention goes a block of keys at a time (ops/chunked_attention.py)
+    chunked = T > PREFILL_DENSE_MAX and not with_prefix
+
+    def dense_mask(window):
+        causal = jnp.tril(jnp.ones((T, T), dtype=bool))
+        band = _window_band(T, window)
+        if band is not None:
+            causal = causal & band
+        if not with_prefix:
+            return causal
         Tp = prefix_k.shape[1]
         key_pos_prefix = jnp.arange(Tp, dtype=jnp.int32)
         prefix_mask = jnp.broadcast_to(
             key_pos_prefix[None, :] < prefix_len, (T, Tp)
         )
-        if cfg.sliding_window is not None:
+        if window is not None:
             prefix_mask = prefix_mask & (
-                key_pos_prefix[None, :]
-                > position_ids[:, None] - cfg.sliding_window
+                key_pos_prefix[None, :] > position_ids[:, None] - window
             )
-        mask = jnp.concatenate([prefix_mask, causal], axis=1)  # [T, Tp+T]
-    else:
-        mask = causal
+        return jnp.concatenate([prefix_mask, causal], axis=1)  # [T, Tp+T]
+
+    # one mask for each kind of layer (a uniform stack has one kind)
+    masks = {} if chunked else {
+        w: dense_mask(w)
+        for w in {cfg.layer_window(i) for i in range(cfg.num_hidden_layers)}
+    }
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     group = nH // nKV
 
     @jax.named_scope("layer")
-    def layer(x, inputs):
+    def layer(x, inputs, li=None):
         layer_p, *prefix = inputs
+        window = cfg.layer_window(li)
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         with jax.named_scope("attn"):
-            q, k, v = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-            with jax.named_scope("attention"):
-                if with_prefix:
-                    pk, pv = prefix
-                    kk = jnp.concatenate([pk.astype(k.dtype), k], axis=0)
-                    vv = jnp.concatenate([pv.astype(v.dtype), v], axis=0)
-                else:
-                    kk, vv = k, v
-                qg = q.reshape(T, nKV, group, hd)
-                scores = jnp.einsum("tkgd,skd->kgts", qg, kk).astype(jnp.float32)
-                scores = scores / np.sqrt(hd)
-                scores = jnp.where(mask[None, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-                attn_out = jnp.einsum(
-                    "kgts,skd->tkgd", probs, vv
-                ).reshape(T, nH, hd)
-        x = _attn_out_mlp(layer_p, x, attn_out, cfg, valid)
+            q, k, v = _project_qkv(
+                layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
+            )
+            if chunked:
+                from areal_tpu.ops.chunked_attention import chunked_attention
+
+                with jax.named_scope("attention_chunked"):
+                    # one sequence: bucket padding lies after every real
+                    # token, so causality alone hides it
+                    attn_out = chunked_attention(
+                        q, k, v, jnp.zeros(T, jnp.int32), sliding_window=window
+                    )
+            else:
+                with jax.named_scope(_attention_scope(cfg, window)):
+                    if with_prefix:
+                        pk, pv = prefix
+                        kk = jnp.concatenate([pk.astype(k.dtype), k], axis=0)
+                        vv = jnp.concatenate([pv.astype(v.dtype), v], axis=0)
+                    else:
+                        kk, vv = k, v
+                    qg = q.reshape(T, nKV, group, hd)
+                    scores = jnp.einsum("tkgd,skd->kgts", qg, kk).astype(jnp.float32)
+                    scores = scores / np.sqrt(hd)
+                    scores = jnp.where(masks[window][None, None], scores, -1e30)
+                    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+                    attn_out = jnp.einsum(
+                        "kgts,skd->tkgd", probs, vv
+                    ).reshape(T, nH, hd)
+        x = _attn_out_mlp(layer_p, x, attn_out, cfg, valid, li=li)
         return x, (k, v)
 
     prefix = (prefix_k, prefix_v) if with_prefix else ()
@@ -2178,7 +2480,10 @@ def prefill(
     else:
         ks_list, vs_list = [], []
         for i in range(cfg.num_hidden_layers):
-            x, (k, v) = layer(x, (params[f"layers_{i}"], *(p[i] for p in prefix)))
+            with _layer_scope(cfg, i):
+                x, (k, v) = layer(
+                    x, (params[f"layers_{i}"], *(p[i] for p in prefix)), i
+                )
             ks_list.append(k)
             vs_list.append(v)
         ks, vs = jnp.stack(ks_list), jnp.stack(vs_list)
@@ -2266,8 +2571,101 @@ def _scan_layers_carrying(layer, carry, params, cfg: ModelConfig):
             jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32),
         )[0]
     for i in range(cfg.num_hidden_layers):
-        carry = layer(carry, params[f"layers_{i}"], i)
+        with _layer_scope(cfg, i):
+            carry = layer(carry, params[f"layers_{i}"], i)
     return carry
+
+
+# -- the window layers' cache of a mixed stack -------------------------------
+# A window layer never reads past its window, so its rows live in a RING:
+# `ring_pages` pages a slot, fixed, outside the allocator. The ring pool is
+# `[window layers, 1 + slots * pages, bsz, nKV*hd]`: block 0 is the null
+# block (inactive slots write there), slot r owns blocks
+# 1 + r*pages .. 1 + r*pages + pages - 1, and the row of position p sits in
+# the slot's page (p // bsz) % pages at offset p % bsz. The read is the same
+# `paged_attention` over the slot's `pages` columns, with the window in
+# `valid`. The full layers keep the paged pool and the block tables, over
+# the full layers alone. A mixed model's pools travel as
+# {"full": pool, "window": ring pool}.
+
+
+def ring_pages(window: int, block_size: int) -> int:
+    """Pages a slot's ring needs: the window's rows span at most this many
+    pages, with one to spare so that a row of the lap before, still lying
+    in the page being written, is always older than the window."""
+    return -(-(int(window) - 1) // int(block_size)) + 1
+
+
+def ring_slack(window: int, block_size: int) -> int:
+    """Rows a slot's ring holds beyond the window: how far ahead of its
+    oldest query a verify chunk may write."""
+    return ring_pages(window, block_size) * int(block_size) - int(window)
+
+
+def _ring_coords(positions, slots, bsz: int, pages: int):
+    """(block, offset) in the ring pool of the row at `positions` [N] of
+    slots `slots` [N]."""
+    return 1 + slots * pages + (positions // bsz) % pages, positions % bsz
+
+
+def _ring_valid(positions, window: int, bsz: int, pages: int):
+    """[N, pages*bsz] bool: which cells of a slot's ring the query at
+    `positions` [N] attends. Cell (c, o) holds the latest position q <= p
+    with (q // bsz) % pages == c and q % bsz == o; it is read iff that row
+    exists and lies inside the window."""
+    cell = jnp.arange(pages * bsz, dtype=positions.dtype)
+    c, o = cell // bsz, cell % bsz
+    p = positions[:, None]
+    page = p // bsz
+    q = (page - (page - c[None, :]) % pages) * bsz + o[None, :]
+    q = jnp.where(q > p, q - pages * bsz, q)
+    return (q >= 0) & (p - q < window)
+
+
+def _mixed_cache(cfg: ModelConfig, k_pool, positions, slots, active, full):
+    """Where each layer of a mixed stack writes and reads: (`index`: layer ->
+    (kind, index in that kind's pool), `where`: kind -> (table, dest_block,
+    dest_off, valid)). `full` is the paged pool's tuple as a uniform stack
+    computes it; the ring's is built here from the flat [N] `positions` /
+    `slots` / `active` (N = R, or R*W in a verify, whose `valid` is
+    [R, W, cells] like `full`'s)."""
+    index = {
+        li: (kind, j)
+        for kind, layers in cfg.cache_layers.items()
+        for j, li in enumerate(layers)
+    }
+    where = {"full": full}
+    if "window" in k_pool:
+        bsz = k_pool["window"].shape[2]
+        window = cfg.sliding_window
+        pages = ring_pages(window, bsz)
+        R = (k_pool["window"].shape[1] - 1) // pages
+        table = 1 + jnp.arange(R * pages, dtype=jnp.int32).reshape(R, pages)
+        blk, off = _ring_coords(positions, slots, bsz, pages)
+        if active is not None:
+            blk = jnp.where(active, blk, 0)
+            off = jnp.where(active, off, 0)
+        seen = _ring_valid(positions, window, bsz, pages)
+        where["window"] = (
+            table, blk, off, seen.reshape(*full[3].shape[:-1], pages * bsz)
+        )
+    return index, where
+
+
+def _mixed_attention(read, q, kp, vp, k_new, v_new, place, attn_impl):
+    """One layer of a mixed stack: its new rows into its kind of pool, then
+    its read of that pool. `place` = (kind, index among that kind's layers,
+    (table, dest_block, dest_off, valid)); `read` is `paged_attention` or
+    `paged_attention_qlen`. Returns (attn_out, kp, vp)."""
+    kind, ci, (table, blk, off, seen) = place
+    kp = {**kp, kind: _write_pool_rows(kp[kind], k_new, ci, blk, off)}
+    vp = {**vp, kind: _write_pool_rows(vp[kind], v_new, ci, blk, off)}
+    with jax.named_scope(f"attention_{kind}"):
+        out = read(
+            q, kp[kind], vp[kind], table, seen, ci, impl=attn_impl,
+            kernel_name=_PAGED_KERNELS[kind],
+        )
+    return out, kp, vp
 
 
 def decode_step_paged(
@@ -2337,7 +2735,8 @@ def decode_step_paged(
     rope_pos = positions if rope_offset is None else positions + rope_offset
     cos, sin = rope_table(rope_pos, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
     valid = jnp.arange(span)[None, :] <= positions[:, None]  # [R, span]
-    if cfg.sliding_window is not None:
+    mixed = isinstance(k_pool, dict)
+    if cfg.sliding_window is not None and not mixed:
         valid = valid & (
             jnp.arange(span)[None, :] > positions[:, None] - cfg.sliding_window
         )
@@ -2353,31 +2752,56 @@ def decode_step_paged(
         dest_block = jnp.where(active, dest_block, 0)
         dest_off = jnp.where(active, dest_off, 0)
 
+    if mixed:
+        index, where = _mixed_cache(
+            cfg, k_pool, positions, jnp.arange(R, dtype=positions.dtype),
+            active, (block_tables, dest_block, dest_off, valid),
+        )
+
     @jax.named_scope("layer")
     def layer(carry, layer_p, li):
         x, kp, vp, load = carry
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         with jax.named_scope("attn"):
-            q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-            kp = _write_pool_rows(kp, k_new, li, dest_block, dest_off)
-            vp = _write_pool_rows(vp, v_new, li, dest_block, dest_off)
-            with jax.named_scope("attention"):
-                attn_out = paged_attention(
-                    q.reshape(R, nH, hd), kp, vp, block_tables, valid, li,
-                    impl=attn_impl,
+            q, k_new, v_new = _project_qkv(
+                layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
+            )
+            if mixed:
+                kind, ci = index[li]
+                attn_out, kp, vp = _mixed_attention(
+                    paged_attention, q.reshape(R, nH, hd), kp, vp, k_new,
+                    v_new, (kind, ci, where[kind]), attn_impl,
                 )
+            else:
+                kp = _write_pool_rows(kp, k_new, li, dest_block, dest_off)
+                vp = _write_pool_rows(vp, v_new, li, dest_block, dest_off)
+                with jax.named_scope("attention"):
+                    attn_out = paged_attention(
+                        q.reshape(R, nH, hd), kp, vp, block_tables, valid, li,
+                        impl=attn_impl,
+                    )
         if moe_load:
-            x, layer_load = _attn_out_mlp(layer_p, x, attn_out, cfg, active, True)
+            x, layer_load = _attn_out_mlp(layer_p, x, attn_out, cfg, active, True, li)
             return x, kp, vp, load + layer_load
-        return _attn_out_mlp(layer_p, x, attn_out, cfg, active), kp, vp, None
+        return _attn_out_mlp(layer_p, x, attn_out, cfg, active, li=li), kp, vp, None
 
     # the load rides in the carry as None (no leaf) unless asked for
-    load0 = jnp.zeros(2, jnp.int32) if moe_load else None
+    load0 = jnp.zeros(_moe_load_len(cfg), jnp.int32) if moe_load else None
     x, k_pool, v_pool, load = _scan_layers_carrying(
         layer, (x, k_pool, v_pool, load0), params, cfg
     )
     logits = _final_logits(params, x, cfg)
     if moe_load:
+        if mixed:
+            # cached rows this step's attention read, live slots only, counted
+            # from the masks the kernels were given, a kind of layer at a time
+            live = jnp.ones((R, 1), bool) if active is None else active[:, None]
+            rows = jnp.stack([
+                (where[kind][3] & live).sum() * len(cfg.cache_layers[kind])
+                if kind in where else jnp.zeros((), jnp.int32)
+                for kind in ("full", "window")
+            ]).astype(load.dtype)
+            load = jnp.concatenate([load, rows])
         return logits, k_pool, v_pool, load
     return logits, k_pool, v_pool
 
@@ -2432,7 +2856,8 @@ def verify_step_paged(
     valid = (
         jnp.arange(span)[None, None, :] <= positions[:, :, None]
     )  # [R, W, span]
-    if cfg.sliding_window is not None:
+    mixed = isinstance(k_pool, dict)
+    if cfg.sliding_window is not None and not mixed:
         valid = valid & (
             jnp.arange(span)[None, None, :]
             > positions[:, :, None] - cfg.sliding_window
@@ -2452,20 +2877,45 @@ def verify_step_paged(
         None if active is None else jnp.repeat(active, W, axis=0)
     )
 
+    if mixed:
+        # the W rows of a slot are all written before any is read, so the
+        # ring must keep the oldest query's window clear of the newest row
+        if "window" in k_pool and W - 1 > ring_slack(cfg.sliding_window, bsz):
+            raise ValueError(
+                f"a verify chunk of {W} positions does not fit the ring's "
+                f"{ring_slack(cfg.sliding_window, bsz)} rows of slack past a "
+                f"window of {cfg.sliding_window} at pages of {bsz}"
+            )
+        index, where = _mixed_cache(
+            cfg, k_pool, flat_pos,
+            jnp.repeat(jnp.arange(R, dtype=flat_pos.dtype), W), active_flat,
+            (block_tables, dest_block_f, dest_off_f, valid),
+        )
+
     @jax.named_scope("layer")
     def layer(carry, layer_p, li):
         x, kp, vp = carry
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         with jax.named_scope("attn"):
-            q, k_new, v_new = _project_qkv(layer_p["attn"], h, cos, sin, cfg)
-            kp = _write_pool_rows(kp, k_new, li, dest_block_f, dest_off_f)
-            vp = _write_pool_rows(vp, v_new, li, dest_block_f, dest_off_f)
-            with jax.named_scope("attention"):
-                attn_out = paged_attention_qlen(
-                    q.reshape(R, W, nH, hd), kp, vp, block_tables, valid, li,
-                    impl=attn_impl,
-                ).reshape(R * W, nH, hd)
-        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat)
+            q, k_new, v_new = _project_qkv(
+                layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
+            )
+            if mixed:
+                kind, ci = index[li]
+                attn_out, kp, vp = _mixed_attention(
+                    paged_attention_qlen, q.reshape(R, W, nH, hd), kp, vp,
+                    k_new, v_new, (kind, ci, where[kind]), attn_impl,
+                )
+                attn_out = attn_out.reshape(R * W, nH, hd)
+            else:
+                kp = _write_pool_rows(kp, k_new, li, dest_block_f, dest_off_f)
+                vp = _write_pool_rows(vp, v_new, li, dest_block_f, dest_off_f)
+                with jax.named_scope("attention"):
+                    attn_out = paged_attention_qlen(
+                        q.reshape(R, W, nH, hd), kp, vp, block_tables, valid, li,
+                        impl=attn_impl,
+                    ).reshape(R * W, nH, hd)
+        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat, li=li)
         return x, kp, vp
 
     x, k_pool, v_pool = _scan_layers_carrying(

@@ -195,7 +195,7 @@ def _paged_kernel(
 
 
 def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
-                  interpret):
+                  interpret, kernel_name="paged_attention"):
     """q [R, W, nH, hd], valid [R, W, nb*bsz] -> [R, W, nH, hd]. Decoding is
     the W == 1 case of the speculative verify."""
     (k_pool, k_scales), (v_pool, v_scales) = split_pool(k_pool), split_pool(v_pool)
@@ -260,7 +260,7 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, sm_scale=sm_scale, quant=quant),
-        name="paged_attention",
+        name=kernel_name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, rows, D), q.dtype),
         interpret=interpret,
@@ -285,6 +285,7 @@ def paged_attention_qlen(
     impl: str = "auto",
     sm_scale: float | None = None,
     interpret: bool | None = None,
+    kernel_name: str = "paged_attention",
 ) -> jax.Array:
     """q_len>1 decode attention against the block table (speculative
     verify chunks): slot r's W queries (positions base..base+W-1) attend
@@ -311,7 +312,8 @@ def paged_attention_qlen(
         vc = _gather_dequant(v_pool, block_table, layer, hd, q.dtype)
         return verify_attention(q, kc, vc, valid, sm_scale=sm_scale)
     return _paged_pallas(
-        q, k_pool, v_pool, block_table, valid, layer, sm_scale, interpret
+        q, k_pool, v_pool, block_table, valid, layer, sm_scale, interpret,
+        kernel_name,
     )
 
 
@@ -331,6 +333,7 @@ def paged_attention(
     impl: str = "auto",
     sm_scale: float | None = None,
     interpret: bool | None = None,
+    kernel_name: str = "paged_attention",
 ) -> jax.Array:
     """Decode attention of R single-token queries over paged KV.
 
@@ -338,7 +341,9 @@ def paged_attention(
     `(layer, block_table[r, s // bsz], s % bsz)`; `valid` carries the causal
     (and sliding-window) mask over those logical rows, so unallocated
     table tail entries (null block 0) are read but never scored. Returns
-    `[R, nH, hd]` in q's dtype.
+    `[R, nH, hd]` in q's dtype. `kernel_name` names the Pallas call in a
+    device trace (a mixed stack reads its window layers' ring under a name
+    of its own).
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
@@ -351,5 +356,5 @@ def paged_attention(
         )
     return _paged_pallas(
         q[:, None], k_pool, v_pool, block_table, valid[:, None], layer,
-        sm_scale, interpret,
+        sm_scale, interpret, kernel_name,
     )[:, 0]

@@ -321,6 +321,31 @@ def test_paged_kernel_is_named_at_olmoes_head_shape(one_chip):
     assert "tpu_custom_call" in hlo and "%paged_attention" in hlo
 
 
+@pytest.mark.parametrize("kind,layers,blocks,nb", [
+    ("window", 4, 1 + 64 * 2, 2), ("full", 1, 64 * 64 + 1, 64)])
+def test_paged_kernels_are_named_apart_at_kexaones_head_shape(one_chip, kind, layers, blocks, nb):
+    """64 query and 8 KV heads of 128 (K-EXAONE): the window layers read their
+    ring (two pages a slot, a two-column table) under a kernel name of their
+    own, the full layer its pool through 64 block columns under the uniform
+    stacks' name: a device trace tells the two apart."""
+    from areal_tpu.models.qwen2 import _PAGED_KERNELS
+    from areal_tpu.ops.paged_attention import paged_attention
+
+    R, nH, nKV, hd, bsz = 64, 64, 8, 128, 128
+    pool = ((layers, blocks, bsz, nKV * hd), jnp.bfloat16)
+
+    def step(q, kp, vp, bt, valid):
+        return paged_attention(q, kp, vp, bt, valid, layers - 1, impl="pallas",
+                               interpret=False, kernel_name=_PAGED_KERNELS[kind])
+
+    hlo = _compile(
+        step, one_chip, ((R, nH, hd), jnp.bfloat16), pool, pool,
+        ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_))
+    assert "tpu_custom_call" in hlo
+    assert ("%paged_attention_window" in hlo) == (kind == "window")
+    assert ("%paged_attention." in hlo or "%paged_attention " in hlo) == (kind == "full")
+
+
 @pytest.mark.parametrize("tokens", [64, 2048], ids=["decode_step", "batched_prefill"])
 def test_grouped_expert_matmuls_are_named_at_olmoes_widths(one_chip, tokens):
     """XLA:TPU lowers `jax.lax.ragged_dot` to a Mosaic grouped matmul of its
