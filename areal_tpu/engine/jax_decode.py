@@ -602,6 +602,10 @@ class JaxDecodeEngine(InferenceEngine):
         self._moe_absent_pairs = 0
         self._kv_full_rows_read = 0
         self._kv_window_rows_read = 0
+        # of the R x nb block columns the paged kernel's grid walks a chunk,
+        # those inside a slot's live range (_count_block_columns)
+        self._paged_cols_live = 0
+        self._paged_cols_visited = 0
         self._chunk_fns: dict[bool, Callable] = {}
         # speculative verify-chunk variants, keyed (use_topp, nb, W)
         self._verify_fns: dict[tuple, Callable] = {}
@@ -882,6 +886,8 @@ class JaxDecodeEngine(InferenceEngine):
             self._moe_absent_pairs = 0
             self._kv_full_rows_read = 0
             self._kv_window_rows_read = 0
+            self._paged_cols_live = 0
+            self._paged_cols_visited = 0
             self._spec_hist = np.zeros(
                 max(int(self.config.spec_k), 1) + 1, dtype=np.int64
             )
@@ -966,6 +972,14 @@ class JaxDecodeEngine(InferenceEngine):
         if self._thread is not None:
             self._thread.join(timeout=5)
             self._thread = None
+        with self._metrics_lock:
+            live, visited = self._paged_cols_live, self._paged_cols_visited
+            chunks = self._chunks_dispatched
+        if visited:
+            logger.info(
+                f"paged kernel: {live} of {visited} block columns live "
+                f"({100.0 * live / visited:.1f}%) over {chunks} chunks"
+            )
         if self._executor is not None:
             self._executor.destroy()
         self.params = None
@@ -3440,6 +3454,24 @@ class JaxDecodeEngine(InferenceEngine):
         while self._inflight:
             self._consume_chunk(self._inflight.popleft())
 
+    def _count_block_columns(self, active: np.ndarray, nb: int) -> None:
+        """`paged_block_columns_{live,visited}_total`: of the R x nb block
+        columns the paged kernel's grid walks for the chunk just dispatched
+        (the paged pool's table; a mixed stack's ring is two columns a
+        slot, both live), those inside a slot's live range at the chunk's
+        last step. `ops/paged_attention.live_block_range` on the host, from
+        the projected lengths: an active slot has the columns up to its
+        last query's, less those wholly before a uniform stack's window."""
+        bsz = self._alloc.block_size
+        last = self._slot_lengths[active].astype(np.int64) - 1
+        live = np.minimum(last // bsz + 1, nb)
+        window = self.model_config.sliding_window
+        if window is not None and self._ring is None:
+            live -= np.maximum(last - window + 1, 0) // bsz
+        with self._metrics_lock:
+            self._paged_cols_live += int(live.sum())
+            self._paged_cols_visited += active.size * nb
+
     def _dispatch_chunk(self, active: np.ndarray) -> "_Inflight | None":
         R = self.config.max_running_requests
         n_chunk = self.config.new_tokens_per_chunk
@@ -3630,6 +3662,7 @@ class JaxDecodeEngine(InferenceEngine):
             self._slot_lengths[active] += spec_w
             if self._ring is not None:
                 self._ring.note_written(active, self._slot_lengths[active])
+            self._count_block_columns(active, nb)
             with self._metrics_lock:
                 self._chunks_dispatched += 1
             return _Inflight(
@@ -3692,6 +3725,7 @@ class JaxDecodeEngine(InferenceEngine):
         self._slot_lengths[active] += n_chunk
         if self._ring is not None:
             self._ring.note_written(active, self._slot_lengths[active])
+        self._count_block_columns(active, nb)
         with self._metrics_lock:
             self._chunks_dispatched += 1
         return _Inflight(
@@ -5270,6 +5304,7 @@ class JaxDecodeEngine(InferenceEngine):
             moe_pairs, moe_hot_pairs = self._moe_pairs, self._moe_hot_pairs
             moe_absent_pairs = self._moe_absent_pairs
             kv_rows_read = self._kv_full_rows_read, self._kv_window_rows_read
+            paged_cols = self._paged_cols_live, self._paged_cols_visited
             table_uploads = self._table_uploads
             spec_hist = self._spec_hist.copy()
             spec_chunk_slots = self._spec_chunk_slots
@@ -5367,6 +5402,10 @@ class JaxDecodeEngine(InferenceEngine):
             "moe_absent_pairs_total": moe_absent_pairs,
             "kv_full_rows_read_total": kv_rows_read[0],
             "kv_window_rows_read_total": kv_rows_read[1],
+            # block columns inside a slot's live range, of those the paged
+            # kernel's (slots x nb) grid walks: how often it skips a step
+            "paged_block_columns_live_total": paged_cols[0],
+            "paged_block_columns_visited_total": paged_cols[1],
             "device_busy_s": round(dev_busy_s, 6),
             "device_idle_s": round(dev_idle_s, 6),
             "device_idle_frac": (

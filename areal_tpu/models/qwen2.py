@@ -2622,13 +2622,15 @@ def _ring_valid(positions, window: int, bsz: int, pages: int):
     return (q >= 0) & (p - q < window)
 
 
-def _mixed_cache(cfg: ModelConfig, k_pool, positions, slots, active, full):
+def _mixed_cache(cfg: ModelConfig, k_pool, positions, slots, active, full,
+                 live_of):
     """Where each layer of a mixed stack writes and reads: (`index`: layer ->
     (kind, index in that kind's pool), `where`: kind -> (table, dest_block,
-    dest_off, valid)). `full` is the paged pool's tuple as a uniform stack
-    computes it; the ring's is built here from the flat [N] `positions` /
-    `slots` / `active` (N = R, or R*W in a verify, whose `valid` is
-    [R, W, cells] like `full`'s)."""
+    dest_off, valid, live)). `full` is the paged pool's first four as a
+    uniform stack computes them; the ring's are built here from the flat [N]
+    `positions` / `slots` / `active` (N = R, or R*W in a verify, whose
+    `valid` is [R, W, cells] like `full`'s); `live_of(valid)` gives each
+    kind's range of live block columns (`_live_columns`)."""
     index = {
         li: (kind, j)
         for kind, layers in cfg.cache_layers.items()
@@ -2649,21 +2651,33 @@ def _mixed_cache(cfg: ModelConfig, k_pool, positions, slots, active, full):
         where["window"] = (
             table, blk, off, seen.reshape(*full[3].shape[:-1], pages * bsz)
         )
-    return index, where
+    return index, {kind: (*w, live_of(w[3])) for kind, w in where.items()}
+
+
+def _live_columns(valid, active, bsz: int, attn_impl: str):
+    """Each slot's range of block columns that hold a row `valid` lets it
+    attend (`ops/paged_attention.live_block_range`): what the kernel read
+    works on. Taken once a token step, outside the layer loop. None for the
+    XLA read, which gathers every column and keeps its program."""
+    from areal_tpu.ops.paged_attention import live_block_range, resolve_impl
+
+    if resolve_impl(attn_impl) != "pallas":
+        return None
+    return live_block_range(valid, bsz, active)
 
 
 def _mixed_attention(read, q, kp, vp, k_new, v_new, place, attn_impl):
     """One layer of a mixed stack: its new rows into its kind of pool, then
     its read of that pool. `place` = (kind, index among that kind's layers,
-    (table, dest_block, dest_off, valid)); `read` is `paged_attention` or
-    `paged_attention_qlen`. Returns (attn_out, kp, vp)."""
-    kind, ci, (table, blk, off, seen) = place
+    (table, dest_block, dest_off, valid, live)); `read` is `paged_attention`
+    or `paged_attention_qlen`. Returns (attn_out, kp, vp)."""
+    kind, ci, (table, blk, off, seen, live) = place
     kp = {**kp, kind: _write_pool_rows(kp[kind], k_new, ci, blk, off)}
     vp = {**vp, kind: _write_pool_rows(vp[kind], v_new, ci, blk, off)}
     with jax.named_scope(f"attention_{kind}"):
         out = read(
             q, kp[kind], vp[kind], table, seen, ci, impl=attn_impl,
-            kernel_name=_PAGED_KERNELS[kind],
+            kernel_name=_PAGED_KERNELS[kind], live=live,
         )
     return out, kp, vp
 
@@ -2752,11 +2766,16 @@ def decode_step_paged(
         dest_block = jnp.where(active, dest_block, 0)
         dest_off = jnp.where(active, dest_off, 0)
 
+    live_of = functools.partial(
+        _live_columns, active=active, bsz=bsz, attn_impl=attn_impl
+    )
     if mixed:
         index, where = _mixed_cache(
             cfg, k_pool, positions, jnp.arange(R, dtype=positions.dtype),
-            active, (block_tables, dest_block, dest_off, valid),
+            active, (block_tables, dest_block, dest_off, valid), live_of,
         )
+    else:
+        live = live_of(valid)
 
     @jax.named_scope("layer")
     def layer(carry, layer_p, li):
@@ -2778,7 +2797,7 @@ def decode_step_paged(
                 with jax.named_scope("attention"):
                     attn_out = paged_attention(
                         q.reshape(R, nH, hd), kp, vp, block_tables, valid, li,
-                        impl=attn_impl,
+                        impl=attn_impl, live=live,
                     )
         if moe_load:
             x, layer_load = _attn_out_mlp(layer_p, x, attn_out, cfg, active, True, li)
@@ -2877,6 +2896,9 @@ def verify_step_paged(
         None if active is None else jnp.repeat(active, W, axis=0)
     )
 
+    live_of = functools.partial(
+        _live_columns, active=active, bsz=bsz, attn_impl=attn_impl
+    )
     if mixed:
         # the W rows of a slot are all written before any is read, so the
         # ring must keep the oldest query's window clear of the newest row
@@ -2889,8 +2911,10 @@ def verify_step_paged(
         index, where = _mixed_cache(
             cfg, k_pool, flat_pos,
             jnp.repeat(jnp.arange(R, dtype=flat_pos.dtype), W), active_flat,
-            (block_tables, dest_block_f, dest_off_f, valid),
+            (block_tables, dest_block_f, dest_off_f, valid), live_of,
         )
+    else:
+        live = live_of(valid)
 
     @jax.named_scope("layer")
     def layer(carry, layer_p, li):
@@ -2913,7 +2937,7 @@ def verify_step_paged(
                 with jax.named_scope("attention"):
                     attn_out = paged_attention_qlen(
                         q.reshape(R, W, nH, hd), kp, vp, block_tables, valid, li,
-                        impl=attn_impl,
+                        impl=attn_impl, live=live,
                     ).reshape(R * W, nH, hd)
         x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat, li=li)
         return x, kp, vp

@@ -21,7 +21,16 @@ Two implementations behind one signature, selected like `attn_impl`:
   contiguous `(bsz, nKV*hd)` slab (every kv head) and scores all query
   heads against it with block-diagonal queries. Online-softmax partial
   (max, sum, acc) scratch carries across the `nb` block steps of each
-  slot.
+  slot. The grid is `(R, nb)`, `nb` set by the deepest slot, but a slot
+  works only on the block columns it HAS: its live range `[lo, hi)`
+  (`live_block_range`: the first and one past the last column whose mask
+  holds a valid row; `lo == hi == 0` for a slot that is not active) rides
+  as two more scalar-prefetch vectors. A grid step outside the range runs
+  no cast, matmul or softmax update, and its index maps name the block the
+  nearest live step names (the null block 0 for an empty slot), so Pallas
+  issues no copy for it either. A column with no valid row adds exactly
+  nothing to the online softmax, so skipping it changes no bit of a live
+  slot's output; an empty slot writes zeros.
 - `"xla"` (CPU / tests): gathers the slot's `nb` blocks per step and
   runs plain einsums over them (scores and softmax in float32). The
   committed stream goldens (tests/fixtures/) were recorded through this
@@ -118,20 +127,45 @@ def _paged_attention_xla(q, k_pool, v_pool, block_table, valid, layer, sm_scale)
 # ---------------------------------------------------------------------------
 
 
+def live_block_range(valid, block_size: int, active=None):
+    """(lo, hi), two [R] int32 vectors: the block columns of each slot that
+    hold a row some query of the slot attends. `valid` is the mask the
+    kernel is given, [R, nb*bsz] or [R, W, nb*bsz] (the union over a
+    verify's W queries); `lo` is the first such column, `hi` one past the
+    last, and a slot with none, or one that is not `active`, has
+    `lo == hi == 0`. A causal mask gives `[0, position // bsz + 1)`, a
+    sliding window a leading `lo > 0`. Compute it once a token step, outside
+    the layer loop: every layer of a kind reads under the same mask."""
+    R = valid.shape[0]
+    cols = valid.reshape(R, -1, valid.shape[-1] // block_size, block_size)
+    cols = cols.any(axis=(1, 3))  # [R, nb]
+    if active is not None:
+        cols = cols & active[:, None]
+    nb = cols.shape[1]
+    col = jnp.arange(nb, dtype=jnp.int32)
+    hi = jnp.max(jnp.where(cols, col + 1, 0), axis=1)
+    lo = jnp.minimum(jnp.min(jnp.where(cols, col, nb), axis=1), hi)
+    return lo, hi
+
+
 def _paged_kernel(
     bt_ref,  # [R, nb] scalar-prefetch block table
     layer_ref,  # [1] scalar-prefetch layer index (read by the index maps)
+    lo_ref,  # [R] scalar-prefetch: first live block column of each slot
+    hi_ref,  # [R] scalar-prefetch: one past the last live column
     mask_ref,  # (1, 1, W, bsz) int32 validity rows for this block, per query
     q_ref,  # (1, W*nHp, D) block-diagonal queries, D = nKV*hd
     *refs,  # [sel], k, [k scales], v, [v scales], out, acc, m, l
     sm_scale: float,
     quant: bool,
 ):
-    """One grid step = one pool block of one slot, ALL kv heads: the block
-    is the contiguous (bsz, nKV*hd) slab the pool stores, so every tile is
-    lane-dense whatever the head count. Query row i carries head i's query
-    in its kv head's hd lanes and zeros elsewhere, so `q @ k.T` is exactly
-    the per-head score; `p @ v` is exact on the head's own lanes (the
+    """One grid step = one pool block of one slot, ALL kv heads, and only
+    where `lo[r] <= b < hi[r]`: outside its slot's live range a step does
+    nothing (the index maps hold its operands still, so nothing is copied
+    for it either). The block is the contiguous (bsz, nKV*hd) slab the pool
+    stores, so every tile is lane-dense whatever the head count. Query row
+    i carries head i's query in its kv head's hd lanes and zeros elsewhere,
+    so `q @ k.T` is exactly the per-head score; `p @ v` is exact on the head's own lanes (the
     caller reads only those). Int8 pools: the per-(row, kv head) scale
     factors out of the hd contraction, so it multiplies the SCORES (K) and
     the PROBS (V) — the int8 tile feeds the MXU straight after the DMA."""
@@ -140,7 +174,7 @@ def _paged_kernel(
         sel_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
         k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(1)
+    r, b = pl.program_id(0), pl.program_id(1)
     nb = pl.num_programs(1)
     rows = q_ref.shape[1]
     W, bsz = mask_ref.shape[2], mask_ref.shape[3]
@@ -158,34 +192,36 @@ def _paged_kernel(
             sel_ref[h] * sc[h : h + 1, :] for h in range(sc.shape[0])
         )
 
-    q = q_ref[0].astype(jnp.float32)  # [rows, D]
-    k = k_ref[...].astype(jnp.float32)  # [bsz, D]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = s * sm_scale
-    if quant:
-        s = s * head_rows(ks_ref)
-    # per-query causal horizon: mask row w applies to that query's heads
-    m2 = jnp.broadcast_to(
-        mask_ref[0, 0][:, None, :], (W, rows // W, bsz)
-    ).reshape(rows, bsz)
-    s = jnp.where(m2 != 0, s, _NEG_INF)
+    @pl.when((lo_ref[r] <= b) & (b < hi_ref[r]))
+    def _live_column():
+        q = q_ref[0].astype(jnp.float32)  # [rows, D]
+        k = k_ref[...].astype(jnp.float32)  # [bsz, D]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        s = s * sm_scale
+        if quant:
+            s = s * head_rows(ks_ref)
+        # per-query causal horizon: mask row w applies to that query's heads
+        m2 = jnp.broadcast_to(
+            mask_ref[0, 0][:, None, :], (W, rows // W, bsz)
+        ).reshape(rows, bsz)
+        s = jnp.where(m2 != 0, s, _NEG_INF)
 
-    m_prev = m_ref[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    # rows with no valid key yet: every p entry is exp(-inf - -inf) = 1
-    p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_ref[:] = m_new
-    if quant:
-        p = p * head_rows(vs_ref)
-    v = v_ref[...].astype(jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        # rows with no valid key yet: every p entry is exp(-inf - -inf) = 1
+        p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[:] = m_new
+        if quant:
+            p = p * head_rows(vs_ref)
+        v = v_ref[...].astype(jnp.float32)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
 
     @pl.when(b == nb - 1)
     def _finalize():
@@ -195,9 +231,10 @@ def _paged_kernel(
 
 
 def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
-                  interpret, kernel_name="paged_attention"):
+                  interpret, kernel_name="paged_attention", live=None):
     """q [R, W, nH, hd], valid [R, W, nb*bsz] -> [R, W, nH, hd]. Decoding is
-    the W == 1 case of the speculative verify."""
+    the W == 1 case of the speculative verify. `live` = (lo, hi) as
+    `live_block_range` gives them; read from `valid` here when not given."""
     (k_pool, k_scales), (v_pool, v_scales) = split_pool(k_pool), split_pool(v_pool)
     R, W, nH, hd = q.shape
     _, _, bsz, D = k_pool.shape
@@ -222,23 +259,34 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
         qp[:, :, :, None, :] * jnp.asarray(onehot, q.dtype)[:, :, None]
     ).reshape(R, rows, D)
     mask = valid.astype(jnp.int32).reshape(R, W, nb, bsz).swapaxes(1, 2)
+    lo, hi = live_block_range(valid, bsz) if live is None else live
+
+    # a dead step reads the column the nearest live step reads: an index
+    # map that holds still issues no copy
+    def column(r, b, lo, hi):
+        return jnp.clip(b, lo[r], jnp.maximum(hi[r] - 1, lo[r]))
 
     # the index map IS the page walk: block b of slot r comes straight
     # from the pool page (layer, table entry) names, as the pool stores it
-    # (scale strips walk the same map)
-    def page(r, b, bt, layer):
-        return layer[0], bt[r, b], 0, 0
+    # (scale strips walk the same map); an empty slot names the null block
+    # whatever its table still holds
+    def page(r, b, bt, layer, lo, hi):
+        blk = jnp.where(hi[r] > lo[r], bt[r, column(r, b, lo, hi)], 0)
+        return layer[0], blk, 0, 0
 
     kv_spec = pl.BlockSpec((None, None, bsz, D), page)
     sc_spec = pl.BlockSpec((None, None, nKV, bsz), page)
     in_specs = [
-        pl.BlockSpec((1, 1, W, bsz), lambda r, b, bt, layer: (r, b, 0, 0)),
-        pl.BlockSpec((1, rows, D), lambda r, b, bt, layer: (r, 0, 0)),
+        pl.BlockSpec(
+            (1, 1, W, bsz),
+            lambda r, b, bt, layer, lo, hi: (r, column(r, b, lo, hi), 0, 0),
+        ),
+        pl.BlockSpec((1, rows, D), lambda r, b, *_: (r, 0, 0)),
     ]
     if quant:
         sel = jnp.asarray(np.tile(onehot, (W, 1)).T[:, :, None])
         in_specs += [
-            pl.BlockSpec((nKV, rows, 1), lambda r, b, bt, layer: (0, 0, 0)),
+            pl.BlockSpec((nKV, rows, 1), lambda r, b, *_: (0, 0, 0)),
             kv_spec, sc_spec, kv_spec, sc_spec,
         ]
         operands = (sel, k_pool, k_scales, v_pool, v_scales)
@@ -246,12 +294,10 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
         in_specs += [kv_spec, kv_spec]
         operands = (k_pool, v_pool)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(R, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, rows, D), lambda r, b, bt, layer: (r, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, rows, D), lambda r, b, *_: (r, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, D), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
@@ -267,8 +313,8 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-    )(block_table, jnp.asarray(layer, jnp.int32).reshape(1), mask, q_exp,
-      *operands)
+    )(block_table, jnp.asarray(layer, jnp.int32).reshape(1),
+      lo.astype(jnp.int32), hi.astype(jnp.int32), mask, q_exp, *operands)
     # each head keeps the hd lanes of its own kv head
     out = out.reshape(R, W, nHp, nKV, hd)
     return out[:, :, np.arange(nH), kv_of_head[:nH]]
@@ -286,6 +332,7 @@ def paged_attention_qlen(
     sm_scale: float | None = None,
     interpret: bool | None = None,
     kernel_name: str = "paged_attention",
+    live=None,  # (lo, hi) of `live_block_range`; read from `valid` if None
 ) -> jax.Array:
     """q_len>1 decode attention against the block table (speculative
     verify chunks): slot r's W queries (positions base..base+W-1) attend
@@ -313,7 +360,7 @@ def paged_attention_qlen(
         return verify_attention(q, kc, vc, valid, sm_scale=sm_scale)
     return _paged_pallas(
         q, k_pool, v_pool, block_table, valid, layer, sm_scale, interpret,
-        kernel_name,
+        kernel_name, live,
     )
 
 
@@ -334,16 +381,19 @@ def paged_attention(
     sm_scale: float | None = None,
     interpret: bool | None = None,
     kernel_name: str = "paged_attention",
+    live=None,  # (lo, hi) of `live_block_range`; read from `valid` if None
 ) -> jax.Array:
     """Decode attention of R single-token queries over paged KV.
 
     Logical row s of slot r lives at pool position
     `(layer, block_table[r, s // bsz], s % bsz)`; `valid` carries the causal
-    (and sliding-window) mask over those logical rows, so unallocated
-    table tail entries (null block 0) are read but never scored. Returns
+    (and sliding-window) mask over those logical rows. Returns
     `[R, nH, hd]` in q's dtype. `kernel_name` names the Pallas call in a
     device trace (a mixed stack reads its window layers' ring under a name
-    of its own).
+    of its own). `live` is the slots' range of block columns that hold a
+    valid row, which the Pallas kernel neither fetches nor scores beyond
+    (the XLA impl gathers every column and takes no notice of it); a slot
+    outside `active` there reads as zeros.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
@@ -356,5 +406,5 @@ def paged_attention(
         )
     return _paged_pallas(
         q[:, None], k_pool, v_pool, block_table, valid[:, None], layer,
-        sm_scale, interpret, kernel_name,
+        sm_scale, interpret, kernel_name, live,
     )[:, 0]

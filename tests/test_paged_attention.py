@@ -6,6 +6,9 @@ its one row per slot and attends through the block table
 
 - the op against a plain reference, and the Pallas kernel (interpret mode
   here) against the XLA gather;
+- the kernel's live range of block columns: the helper against a brute-force
+  `any()`, the kernel under it equal to the bit to the kernel that walks
+  every column, and a dead column's pages never read;
 - the decode step against the trainer's `forward` over prompt + token (what
   a decode log-probability has to equal for PPO), and its write contract:
   the row at `(layer, bt[r, p // bsz], p % bsz)` holds the new K/V, an
@@ -43,7 +46,13 @@ from areal_tpu.models.qwen2 import (
     prefill,
     verify_step_paged,
 )
-from areal_tpu.ops.paged_attention import paged_attention, resolve_impl
+from areal_tpu.ops.kv_quant import quantize_kv
+from areal_tpu.ops.paged_attention import (
+    live_block_range,
+    paged_attention,
+    paged_attention_qlen,
+    resolve_impl,
+)
 
 TINY = ModelConfig(
     vocab_size=64,
@@ -171,6 +180,259 @@ def test_paged_attention_pallas_at_published_heads(cpu_devices, nH, nKV, hd):
     np.testing.assert_allclose(
         np.asarray(f32(out)), np.asarray(ref), atol=2e-2, rtol=2e-2
     )
+
+
+# -- the live range of block columns ----------------------------------------
+# A grid step outside its slot's `[lo, hi)` neither fetches nor scores. What
+# a live slot reads back is equal to the bit to a kernel told every column
+# is live (what the kernel did before it had a range): a column with no
+# valid row adds exactly nothing to the online softmax.
+
+_RB, _RNB = 16, 10  # page rows, block columns a slot
+# (position of the slot's first query, active, table): an empty slot, one
+# live column of ten, all ten live, a retired slot whose table still names
+# blocks deep in the pool, a slot that ends mid-page
+_RAGGED = [(0, False, "null"), (5, True, "own"), (_RNB * _RB - 4, True, "own"),
+           (100, False, "own"), (3 * _RB + 7, True, "own")]
+
+
+def _ragged_slots(rng, W=1, int8=False, window=None, dtype=jnp.bfloat16):
+    """(q [R, W, nH, hd], k pool, v pool, table, valid [R, W, span], active)
+    over `_RAGGED`'s slots; `window` adds a sliding window to the mask."""
+    R, nH, nKV, hd = len(_RAGGED), 8, 2, 16
+    n_blocks = 1 + R * _RNB
+    shape = (_LAYERS, n_blocks, _RB, nKV, hd)
+    kp = jnp.asarray(rng.standard_normal(shape), dtype)
+    vp = jnp.asarray(rng.standard_normal(shape), dtype)
+    rows = lambda a: a.reshape(_LAYERS, n_blocks, _RB, nKV * hd)  # noqa: E731
+    if int8:
+        (kq, ks), (vq, vs) = quantize_kv(kp), quantize_kv(vp)
+        kp = (rows(kq), jnp.swapaxes(ks, -1, -2))
+        vp = (rows(vq), jnp.swapaxes(vs, -1, -2))
+    else:
+        kp, vp = rows(kp), rows(vp)
+    q = jnp.asarray(rng.standard_normal((R, W, nH, hd)), dtype)
+    bt = rng.permutation(np.arange(1, n_blocks)).astype(np.int32).reshape(R, _RNB)
+    for r, (_, _, table) in enumerate(_RAGGED):
+        if table == "null":
+            bt[r] = 0
+    pos = np.array([p for p, _, _ in _RAGGED])[:, None] + np.arange(W)[None, :]
+    pos = np.minimum(pos, _RNB * _RB - 1)
+    s = np.arange(_RNB * _RB)[None, None, :]
+    valid = s <= pos[:, :, None]
+    if window is not None:
+        valid &= s > pos[:, :, None] - window
+    active = np.array([a for _, a, _ in _RAGGED])
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(valid), jnp.asarray(active)
+
+
+def _read(q, kp, vp, bt, valid, live, **kw):
+    """The Pallas kernel (interpret mode) under the range `live`, through
+    the entry point its width calls for."""
+    if q.shape[1] == 1:
+        return paged_attention(
+            q[:, 0], kp, vp, bt, valid[:, 0], jnp.int32(_LAYER), impl="pallas",
+            interpret=True, live=live, **kw)[:, None]
+    return paged_attention_qlen(
+        q, kp, vp, bt, valid, jnp.int32(_LAYER), impl="pallas", interpret=True,
+        live=live, **kw)
+
+
+def _all_live(R, nb):
+    return jnp.zeros(R, jnp.int32), jnp.full(R, nb, jnp.int32)
+
+
+def _bits(a):
+    return np.asarray(a.astype(jnp.float32)).view(np.uint32)
+
+
+@pytest.mark.parametrize("W", [1, 3], ids=["decode", "verify3"])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_live_range_is_exact_over_ragged_slots(cpu_devices, int8, W):
+    q, kp, vp, bt, valid, active = _ragged_slots(
+        np.random.default_rng(11), W=W, int8=int8)
+    live = live_block_range(valid, _RB, active)
+    lo, hi = (np.asarray(x) for x in live)
+    first = [p for p, _, _ in _RAGGED]
+    assert lo.tolist() == [0] * len(_RAGGED)
+    assert hi.tolist() == [
+        min((p + W - 1) // _RB + 1, _RNB) if a else 0
+        for p, (_, a, _) in zip(first, _RAGGED)]
+    assert 1 in hi and _RNB in hi  # one live column of ten, and all ten
+    out = _read(q, kp, vp, bt, valid, live)
+    ref = _read(q, kp, vp, bt, valid, _all_live(*bt.shape))
+    on = np.asarray(active)
+    np.testing.assert_array_equal(_bits(out)[on], _bits(ref)[on])
+    # a slot that is not active is not read at all: zeros, whatever its table
+    assert not np.asarray(out.astype(jnp.float32))[~on].any()
+    # and left to itself the op reads the range off the mask it is given
+    own = _read(q, kp, vp, bt, valid, None)
+    np.testing.assert_array_equal(_bits(own)[on], _bits(ref)[on])
+
+
+@pytest.mark.parametrize("W", [1, 3], ids=["decode", "verify3"])
+def test_live_range_skips_the_columns_before_a_window(cpu_devices, W):
+    """A uniform sliding-window stack: the columns wholly behind the window
+    are dead too, `lo > 0`."""
+    window = 2 * _RB + 3
+    q, kp, vp, bt, valid, active = _ragged_slots(
+        np.random.default_rng(12), W=W, window=window)
+    live = live_block_range(valid, _RB, active)
+    lo, hi = (np.asarray(x) for x in live)
+    deep = _RAGGED[2][0]
+    assert lo[2] == max(deep - window + 1, 0) // _RB > 0 and hi[2] == _RNB
+    assert lo[1] == 0 and hi[1] == 1
+    out = _read(q, kp, vp, bt, valid, live)
+    ref = _read(q, kp, vp, bt, valid, _all_live(*bt.shape))
+    on = np.asarray(active)
+    np.testing.assert_array_equal(_bits(out)[on], _bits(ref)[on])
+
+
+@pytest.mark.parametrize("W", [1, 2], ids=["decode", "verify2"])
+def test_live_range_keeps_both_columns_of_a_ring(cpu_devices, W):
+    """A mixed stack's window layers: a two-column table over the slot's
+    ring pages (`models/qwen2._ring_valid`). Past the first page both
+    columns hold rows inside the window; only slots that are not active
+    are skipped."""
+    from areal_tpu.models.qwen2 import _PAGED_KERNELS, _ring_valid, ring_pages
+
+    rng = np.random.default_rng(13)
+    bsz = window = 16
+    pages = ring_pages(window, bsz)
+    assert pages == 2
+    R, nH, nKV, hd = 4, 8, 2, 16
+    kp, vp = _random_pool(rng, 1 + R * pages, bsz, nKV, hd, jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((R, W, nH, hd)), jnp.bfloat16)
+    bt = 1 + jnp.arange(R * pages, dtype=jnp.int32).reshape(R, pages)
+    pos = np.array([3, 40, 77, 21])[:, None] + np.arange(W)[None, :]
+    valid = _ring_valid(jnp.asarray(pos.reshape(-1)), window, bsz, pages)
+    valid = valid.reshape(R, W, pages * bsz)
+    active = jnp.asarray([True, True, False, True])
+    live = live_block_range(valid, bsz, active)
+    assert np.asarray(live[0]).tolist() == [0, 0, 0, 0]
+    assert np.asarray(live[1]).tolist() == [1, 2, 0, 2]
+    kw = dict(kernel_name=_PAGED_KERNELS["window"])
+    out = _read(q, kp, vp, bt, valid, live, **kw)
+    ref = _read(q, kp, vp, bt, valid, _all_live(R, pages), **kw)
+    on = np.asarray(active)
+    np.testing.assert_array_equal(_bits(out)[on], _bits(ref)[on])
+
+
+@pytest.mark.parametrize("W", [None, 1, 4], ids=["2d", "W1", "W4"])
+@pytest.mark.parametrize("with_active", [False, True], ids=["all", "active"])
+def test_live_block_range_against_brute_force(cpu_devices, with_active, W):
+    """Masks with holes, empty rows and single cells: `lo` is the first
+    column holding a valid row of any query, `hi` one past the last."""
+    rng = np.random.default_rng(14)
+    R, nb, bsz = 12, 7, 8
+    shape = (R, nb * bsz) if W is None else (R, W, nb * bsz)
+    valid = rng.random(shape) < 0.02
+    valid[0] = False  # nothing to attend
+    valid[1] = True  # everything
+    valid[2] = False
+    valid[2][..., 3 * bsz] = True  # one cell, the first row of column 3
+    active = rng.random(R) < 0.7 if with_active else None
+    lo, hi = live_block_range(
+        jnp.asarray(valid), bsz, None if active is None else jnp.asarray(active))
+    assert lo.dtype == hi.dtype == jnp.int32 and lo.shape == hi.shape == (R,)
+    for r in range(R):
+        cols = valid[r].reshape(-1, nb, bsz).any(axis=(0, 2))
+        if active is not None and not active[r]:
+            cols[:] = False
+        want = (0, 0)
+        if cols.any():
+            want = (int(np.argmax(cols)), nb - int(np.argmax(cols[::-1])))
+        assert (int(lo[r]), int(hi[r])) == want, r
+    if active is None:
+        assert (int(lo[2]), int(hi[2])) == (3, 4)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_dead_columns_are_never_read(cpu_devices, int8):
+    """Every page no live column names, the null block among them, holds
+    NaN (an int8 pool: NaN scales). A kernel that scored such a page would
+    return NaN (0 x NaN); the live slots read back finite and equal to the
+    bit to what they read from the clean pool."""
+    W = 2
+    q, kp, vp, bt, valid, active = _ragged_slots(
+        np.random.default_rng(15), W=W, int8=int8,
+        window=None if int8 else 3 * _RB)
+    live = live_block_range(valid, _RB, active)
+    lo, hi = (np.asarray(x) for x in live)
+    named = {int(b) for r in range(bt.shape[0])
+             for b in np.asarray(bt)[r, lo[r]:hi[r]]}
+    dead = np.array(sorted(set(range(kp[0].shape[1] if int8 else kp.shape[1]))
+                           - named))
+    assert 0 in dead and len(dead) > len(named)
+
+    def poison(pool):
+        if int8:
+            data, scales = pool
+            return data, scales.at[:, dead].set(jnp.nan)
+        return pool.at[:, dead].set(jnp.nan)
+
+    clean = _read(q, kp, vp, bt, valid, live)
+    out = _read(q, poison(kp), poison(vp), bt, valid, live)
+    on = np.asarray(active)
+    assert np.isfinite(np.asarray(out.astype(jnp.float32))).all()
+    np.testing.assert_array_equal(_bits(out)[on], _bits(clean)[on])
+    # the kernel that walks every column does read them
+    walked = _read(q, poison(kp), poison(vp), bt, valid, _all_live(*bt.shape))
+    assert np.isnan(np.asarray(walked.astype(jnp.float32))[on]).any()
+
+
+@pytest.mark.parametrize("W", [1, 3], ids=["decode", "verify3"])
+def test_mixed_stack_steps_read_each_kind_under_its_range(cpu_devices, W):
+    """Window and full layers in one stack (ring and paged pool side by
+    side): the decode and the verify step through the kernel, each kind of
+    layer under the live range of its own mask, against the XLA read; a
+    slot that is not active in the batch."""
+    from areal_tpu.models.qwen2 import ring_pages
+
+    cfg = ModelConfig.from_hf_config(dict(
+        model_type="exaone_moe", vocab_size=64, hidden_size=32, intermediate_size=48,
+        num_hidden_layers=5, num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        rope_parameters={"rope_theta": 10000.0, "rope_type": "default"}, rms_norm_eps=1e-5,
+        sliding_window=8,
+        layer_types=["sliding_attention"] * 3 + ["full_attention", "sliding_attention"],
+        first_k_dense_replace=1, mlp_layer_types=["dense"] + ["sparse"] * 4, num_experts=4,
+        num_experts_per_tok=2, moe_intermediate_size=16, num_shared_experts=1,
+        scoring_func="sigmoid", norm_topk_prob=True, routed_scaling_factor=2.5, n_group=1,
+        topk_group=1, num_nextn_predict_layers=0, tie_word_embeddings=False,
+        max_position_embeddings=512), dtype="float32", param_dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    bsz, R, nb = 4, 3, 8
+    pages = ring_pages(cfg.sliding_window, bsz)
+    L = cfg.cache_layers
+    rng = np.random.default_rng(16)
+
+    def pools():
+        z = lambda n, blocks: jnp.asarray(  # noqa: E731
+            rng.standard_normal((n, blocks, bsz, 2 * 8)), jnp.float32)
+        return {"full": z(len(L["full"]), 1 + R * nb),
+                "window": z(len(L["window"]), 1 + R * pages)}
+
+    kp, vp = pools(), pools()
+    bt = jnp.asarray(1 + np.arange(R * nb).reshape(R, nb), jnp.int32)
+    pos = jnp.asarray([2, 21, 13], jnp.int32)
+    active = jnp.asarray([True, True, False])
+    toks = jnp.asarray(rng.integers(1, 64, (R, W)), jnp.int32)
+
+    def step(impl):
+        if W == 1:
+            return decode_step_paged(params, toks[:, 0], pos, kp, vp, bt, cfg,
+                                     active=active, attn_impl=impl)
+        return verify_step_paged(params, toks, pos, kp, vp, bt, cfg, active=active,
+                                 attn_impl=impl)
+
+    (la, ka, va), (lb, kb, vb) = step("xla"), step("pallas")
+    on = np.asarray(active)
+    np.testing.assert_allclose(np.asarray(la)[on], np.asarray(lb)[on], atol=1e-4, rtol=1e-4)
+    # the null block 0 takes the row of the slot that is not active: not read
+    for kind in ("full", "window"):
+        for a, b in ((ka, kb), (va, vb)):
+            np.testing.assert_allclose(
+                np.asarray(a[kind])[:, 1:], np.asarray(b[kind])[:, 1:], atol=1e-5)
 
 
 def test_resolve_impl(cpu_devices):

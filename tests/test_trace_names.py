@@ -239,6 +239,55 @@ def test_moe_engine_counts_expert_load(moe_programs):
     assert m["moe_hot_expert_pairs_total"] * 2 == m["moe_pairs_total"]
 
 
+def test_engine_counts_live_block_columns(cpu_devices):
+    """`paged_block_columns_live_total` / `_visited_total` against a NumPy
+    count over the chunks the engine dispatched: two requests, one of them
+    past its first page, in four slots under a two-column table."""
+    from areal_tpu.engine.jax_decode import JaxDecodeEngine
+
+    R, n_chunk, bsz = 4, 4, 128
+    eng = JaxDecodeEngine(
+        JaxDecodeConfig(context_length=256, max_running_requests=R,
+                        new_tokens_per_chunk=n_chunk, page_size=bsz, dtype="float32",
+                        kv_cache_dtype="float32"),
+        InferenceEngineConfig())
+    eng.set_model(init_params(TINY, jax.random.PRNGKey(0)), TINY)
+    eng.initialize()
+    chunks = []
+    bucket = eng._chunk_bucket
+
+    def noting(active, grow=None):
+        s = bucket(active, grow)
+        chunks.append((active.copy(), eng._slot_lengths.copy(), -(-s // bsz)))
+        return s
+
+    eng._chunk_bucket = noting
+    try:
+        import asyncio
+
+        async def both():
+            return await asyncio.gather(*(
+                eng.agenerate(ModelRequest(
+                    input_ids=list(range(1, n + 1)),
+                    gconfig=GenerationHyperparameters(greedy=True, max_new_tokens=new)))
+                for n, new in ((5, 6), (124, 11))))
+
+        asyncio.run(both())
+        m = eng.get_metrics()
+    finally:
+        eng.destroy()
+    assert len(chunks) == m["chunks_dispatched_total"] >= 3
+    live = visited = 0
+    for active, lengths, nb in chunks:
+        last = lengths[active] + n_chunk - 1  # the chunk's last query
+        live += int(np.minimum(last // bsz + 1, nb).sum())
+        visited += R * nb
+    assert m["paged_block_columns_visited_total"] == visited
+    assert m["paged_block_columns_live_total"] == live
+    # one and two live columns were both seen, and empty slots none
+    assert len(chunks) < live < visited
+
+
 def test_dense_engine_reports_no_expert_load(programs):
     spy, _ = programs
     assert "ragged_dot" not in spy.text["chunk"] and "ragged_dot" not in spy.text["grad_step"]
@@ -284,6 +333,20 @@ def _compile(fn, one_chip, *shapes):
     return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).compile().as_text()
 
 
+def _mosaic_kernels(hlo: str) -> list[str]:
+    return [line.split(" = ")[0].split("%")[-1] for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _one_paged_kernel(hlo: str) -> str:
+    """The program's only Mosaic call, and no loop around it: the slots'
+    live ranges ride as scalar-prefetch vectors of ONE kernel over the
+    `(slots, nb)` grid (a range that varied a grid bound would loop)."""
+    kernels = _mosaic_kernels(hlo)
+    assert len(kernels) == 1 and " while(" not in hlo, kernels
+    return kernels[0]
+
+
 def test_paged_kernel_is_named_at_the_1p5b_head_shape(one_chip):
     from areal_tpu.ops.paged_attention import paged_attention
 
@@ -302,6 +365,7 @@ def test_paged_kernel_is_named_at_the_1p5b_head_shape(one_chip):
         ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_), ((), jnp.int32))
     assert "tpu_custom_call" in hlo
     assert "%paged_attention" in hlo and "%layer" not in hlo
+    assert _one_paged_kernel(hlo).startswith("paged_attention")
 
 
 def test_paged_kernel_is_named_at_olmoes_head_shape(one_chip):
@@ -312,13 +376,17 @@ def test_paged_kernel_is_named_at_olmoes_head_shape(one_chip):
     R, nH, nKV, hd, bsz, nb, L = 64, 16, 16, 128, 128, 10, 8
     pool = ((L, R * nb + 1, bsz, nKV * hd), jnp.bfloat16)
 
-    def step(q, kp, vp, bt, valid, li):
-        return paged_attention(q, kp, vp, bt, valid, li, impl="pallas", interpret=False)
+    # the range as the model step hands it over, taken outside the layer loop
+    def step(q, kp, vp, bt, valid, li, lo, hi):
+        return paged_attention(q, kp, vp, bt, valid, li, impl="pallas", interpret=False,
+                               live=(lo, hi))
 
     hlo = _compile(
         step, one_chip, ((R, nH, hd), jnp.bfloat16), pool, pool,
-        ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_), ((), jnp.int32))
+        ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_), ((), jnp.int32),
+        ((R,), jnp.int32), ((R,), jnp.int32))
     assert "tpu_custom_call" in hlo and "%paged_attention" in hlo
+    assert _one_paged_kernel(hlo).startswith("paged_attention")
 
 
 @pytest.mark.parametrize("kind,layers,blocks,nb", [
@@ -344,6 +412,7 @@ def test_paged_kernels_are_named_apart_at_kexaones_head_shape(one_chip, kind, la
     assert "tpu_custom_call" in hlo
     assert ("%paged_attention_window" in hlo) == (kind == "window")
     assert ("%paged_attention." in hlo or "%paged_attention " in hlo) == (kind == "full")
+    assert _one_paged_kernel(hlo).startswith(_PAGED_KERNELS[kind])
 
 
 @pytest.mark.parametrize("tokens", [64, 2048], ids=["decode_step", "batched_prefill"])
@@ -445,8 +514,7 @@ def test_flash_kernel_batches_under_vmap_at_the_rollout_head_shapes(one_chip, nH
         jax.vmap(lambda q, k, v, seg: flash_attention(q, k, v, seg, interpret=False)),
         one_chip, ((B, T, nH, hd), bf), ((B, T, nKV, hd), bf), ((B, T, nKV, hd), bf),
         ((B, T), jnp.int32))
-    kernels = [line.split(" = ")[0].split("%")[-1] for line in hlo.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = _mosaic_kernels(hlo)
     assert len(kernels) == 1 and "flash_fwd" in kernels[0], kernels
     assert " while(" not in hlo
 

@@ -29,7 +29,11 @@ from areal_tpu.ops.flash_attention import (
     flash_attention_chunk,
 )
 from areal_tpu.ops.kv_quant import quantize_kv
-from areal_tpu.ops.paged_attention import paged_attention, paged_attention_qlen
+from areal_tpu.ops.paged_attention import (
+    live_block_range,
+    paged_attention,
+    paged_attention_qlen,
+)
 from areal_tpu.ops.quant import quantize_absmax
 from areal_tpu.ops.quant_matmul import quant_einsum
 
@@ -213,6 +217,92 @@ def flash_chunk_case(later=False, T=1024, nH=N_HEADS, nKV=N_KV, hd=HEAD_DIM):
     )
 
 
+def _paged_pool(keys, L, n_blocks, nKV, hd, int8):
+    """The engine's pool, every layer stacked: [L, n_blocks, page, nKV*hd]
+    in bf16, or (int8 rows, f32 scales [L, n_blocks, nKV, page])."""
+    shape = (L, n_blocks, PAGE, nKV, hd)
+    kp = jax.random.normal(keys[0], shape, jnp.bfloat16)
+    vp = jax.random.normal(keys[1], shape, jnp.bfloat16)
+    rows = lambda a: a.reshape(L, n_blocks, PAGE, nKV * hd)  # noqa: E731
+    if not int8:
+        return rows(kp), rows(vp)
+    (kq, ks), (vq, vs) = quantize_kv(kp), quantize_kv(vp)
+    return (rows(kq), jnp.swapaxes(ks, -1, -2)), (rows(vq), jnp.swapaxes(vs, -1, -2))
+
+
+def _maxerr(a, b, rows=None):
+    d = jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))
+    return float(jnp.max(d if rows is None else d[rows]))
+
+
+def paged_ragged_case(mix, W, int8, nH, nKV, hd, R=16, nb=10, L=2, layer=1):
+    """The kernel under its slots' live ranges of block columns: slots of
+    ragged depth under one `nb`, some not active with tables that still
+    name blocks. Equal to the bit, on the active slots, to the kernel told
+    every column is live (what it computed before it had a range), within
+    the usual error of the XLA read, zeros on the rest. `mix`: "empty"
+    (two slots of sixteen active), "30%" (about 30% of the block columns
+    live: the dense rollout cell's share), "all" (every slot at full depth:
+    nothing to skip, the range's own price)."""
+    n_blocks = R * nb + 1
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    kp, vp = _paged_pool(keys, L, n_blocks, nKV, hd, int8)
+    q = jax.random.normal(keys[2], (R, W, nH, hd), jnp.bfloat16)
+    bt = jnp.arange(1, n_blocks, dtype=jnp.int32).reshape(R, nb)
+    span = nb * PAGE
+    r = np.arange(R)
+    if mix == "all":
+        base, active = np.full(R, span - W), np.ones(R, bool)
+    elif mix == "empty":
+        base, active = 40 + 97 * r, (r == 3) | (r == 12)
+    else:  # active slots hold 1-10 columns, a deep one sets nb
+        base = np.where(r == 5, span - W, 17 + 61 * r)
+        active = r % 3 != 1
+    pos = jnp.asarray(base)[:, None] + jnp.arange(W)[None, :]
+    valid = jnp.arange(span)[None, None, :] <= pos[:, :, None]
+    active = jnp.asarray(active)
+    live = live_block_range(valid, PAGE, active)
+    every = (jnp.zeros(R, jnp.int32), jnp.full(R, nb, jnp.int32))
+    if W == 1:
+        args, fn = (q[:, 0], kp, vp, bt, valid[:, 0], jnp.int32(layer)), paged_attention
+    else:
+        args, fn = (q, kp, vp, bt, valid, jnp.int32(layer)), paged_attention_qlen
+    kernel = jax.jit(lambda rng, *a: fn(*a, impl="pallas", interpret=False, live=rng))
+    out, walked = kernel(live, *args), kernel(every, *args)
+    ref = jax.jit(lambda *a: fn(*a, impl="xla"))(*args)
+    exact = bool(jnp.all(jnp.where(active.reshape(R, *[1] * (out.ndim - 1)),
+                                   out == walked, out == 0)))
+    err = _maxerr(out, ref, active)
+    share = float((live[1] - live[0]).sum()) / (R * nb)
+    ok = exact and err < 0.03 and bool(jnp.all(jnp.isfinite(out)))
+    return ok, f"live={share:.0%} exact={exact} maxerr={err:.4f}"
+
+
+def paged_ring_case(nH=64, nKV=8, hd=128, R=16, window=128, L=4, layer=3):
+    """A mixed stack's window layers (K-EXAONE): the ring's two pages a
+    slot read through a two-column table under `%paged_attention_window`,
+    the window in the mask, slots before and past their first lap, one of
+    them not active."""
+    from areal_tpu.models.qwen2 import _PAGED_KERNELS, _ring_valid, ring_pages
+
+    pages = ring_pages(window, PAGE)
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    kp, vp = _paged_pool(keys, L, 1 + R * pages, nKV, hd, False)
+    q = jax.random.normal(keys[2], (R, nH, hd), jnp.bfloat16)
+    bt = 1 + jnp.arange(R * pages, dtype=jnp.int32).reshape(R, pages)
+    pos = 5 + 83 * jnp.arange(R)
+    valid = _ring_valid(pos, window, PAGE, pages)
+    active = jnp.arange(R) != 7
+    args = (q, kp, vp, bt, valid, jnp.int32(layer))
+    out = jax.jit(lambda *a: paged_attention(
+        *a, impl="pallas", interpret=False, kernel_name=_PAGED_KERNELS["window"],
+        live=live_block_range(a[4], PAGE, active)))(*args)
+    ref = jax.jit(lambda *a: paged_attention(*a, impl="xla"))(*args)
+    err = _maxerr(out, ref, active)
+    ok = err < 0.03 and bool(jnp.all(jnp.isfinite(out))) and not bool(jnp.any(out[7]))
+    return ok, f"pages={pages} maxerr={err:.4f}"
+
+
 def paged_case(W, int8, nH, nKV, hd, R=16, nb=4, L=3, layer=2):
     """Paged decode (W == 1) / speculative verify (W > 1) kernel against
     the op's XLA implementation, slots at different lengths. The pool is
@@ -220,17 +310,7 @@ def paged_case(W, int8, nH, nKV, hd, R=16, nb=4, L=3, layer=2):
     at a layer index that is traced, as the layer scan's is."""
     n_blocks = R * nb + 1
     keys = jax.random.split(jax.random.PRNGKey(2), 3)
-    shape = (L, n_blocks, PAGE, nKV, hd)
-    kp = jax.random.normal(keys[0], shape, jnp.bfloat16)
-    vp = jax.random.normal(keys[1], shape, jnp.bfloat16)
-    rows = lambda a: a.reshape(L, n_blocks, PAGE, nKV * hd)  # noqa: E731
-    if int8:
-        (kq, ks), (vq, vs) = quantize_kv(kp), quantize_kv(vp)
-        # scale pools are [L, n_blocks, nKV, page]
-        kp = (rows(kq), jnp.swapaxes(ks, -1, -2))
-        vp = (rows(vq), jnp.swapaxes(vs, -1, -2))
-    else:
-        kp, vp = rows(kp), rows(vp)
+    kp, vp = _paged_pool(keys, L, n_blocks, nKV, hd, int8)
     q = jax.random.normal(keys[2], (R, W, nH, hd), jnp.bfloat16)
     bt = jnp.arange(1, n_blocks, dtype=jnp.int32).reshape(R, nb)
     # slot r's first query sits at position 3 + 31 r (crosses page edges)
@@ -245,9 +325,7 @@ def paged_case(W, int8, nH, nKV, hd, R=16, nb=4, L=3, layer=2):
         fn = paged_attention_qlen
     out = jax.jit(lambda *a: fn(*a, impl="pallas", interpret=False))(*args)
     ref = jax.jit(lambda *a: fn(*a, impl="xla"))(*args)
-    err = float(
-        jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32)))
-    )
+    err = _maxerr(out, ref)
     # the XLA impl rounds probabilities (and dequantized rows) to bf16
     return err < 0.03 and bool(jnp.all(jnp.isfinite(out))), f"maxerr={err:.4f}"
 
@@ -422,6 +500,30 @@ def cases():
                 f"{op} {'int8' if int8 else 'bf16'} {heads} page={PAGE}",
                 path,
                 lambda a=(W, int8, nH, nKV, hd): paged_case(*a),
+            ))
+    # K-EXAONE's 64/8/128: the full layer's pool, and the window layers'
+    # ring through its two-column table under a kernel name of its own
+    out.append((
+        f"paged_attention bf16 64/8/128 page={PAGE}", "default",
+        lambda: paged_case(1, False, 64, 8, 128),
+    ))
+    out.append((
+        f"paged_attention_window bf16 64/8/128 ring of 2 pages page={PAGE}",
+        "default for a mixed stack", paged_ring_case,
+    ))
+    # the slots' live ranges of block columns, at the three rollout cells'
+    # widths: mostly empty slots, the dense cell's 30% of columns live,
+    # nothing to skip
+    for (nH, nKV, hd), other in (
+        ((12, 2, 128), (1, True)), ((16, 16, 128), (5, False)), ((64, 8, 128), (5, True)),
+    ):
+        for mix, W, int8 in (("empty", 1, False), ("30%", 1, False), ("all", 1, False),
+                             ("30%", *other)):
+            out.append((
+                f"paged_attention live range, {mix} W={W} "
+                f"{'int8' if int8 else 'bf16'} {nH}/{nKV}/{hd} nb=10",
+                "default",
+                lambda a=(mix, W, int8, nH, nKV, hd): paged_ragged_case(*a),
             ))
     # OLMoE's experts: a decode step's rows and a batched prefill's
     for T in (64, 2048):
