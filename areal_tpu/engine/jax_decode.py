@@ -87,6 +87,7 @@ from areal_tpu.api.io_struct import (
     WeightUpdateMeta,
 )
 from areal_tpu.engine.kv_pool import (
+    StateSlots,
     WindowRing,
     HostKVEntry,
     HostKVStore,
@@ -439,6 +440,10 @@ class JaxDecodeEngine(InferenceEngine):
         # the window layers} and `_ring` accounts for the rings; None for a
         # uniform stack, whose pools are bare arrays
         self._ring: WindowRing | None = None
+        # a model with linear layers: the K-side pool dict also carries
+        # {"state": {"S", "conv"}}, a recurrent state a slot and linear
+        # layer, and `_state` accounts for how many tokens each holds
+        self._state: StateSlots | None = None
         # int8 weight serving (ISSUE 16): dense matmul kernels live as
         # {"q","scale"} pytree leaves; False serves the fp oracle path
         self._w_quant = False
@@ -602,6 +607,8 @@ class JaxDecodeEngine(InferenceEngine):
         self._moe_absent_pairs = 0
         self._kv_full_rows_read = 0
         self._kv_window_rows_read = 0
+        # linear layers: state updates of live slots (slots x layers x steps)
+        self._gdn_state_updates = 0
         # of the R x nb block columns the paged kernel's grid walks a chunk,
         # those inside a slot's live range (_count_block_columns)
         self._paged_cols_live = 0
@@ -767,11 +774,13 @@ class JaxDecodeEngine(InferenceEngine):
             )
         else:
             n_blocks = R * max_bps + 1
-        self._ring = None
+        self._ring = self._state = None
         if cfg.mixed:
             self._refuse_unserved_for_mixed(bs)
             if cfg.cache_layers["window"]:
                 self._ring = WindowRing(R, cfg.sliding_window, bs)
+            if cfg.cache_layers["state"]:
+                self._state = StateSlots(R)
             # a prefix shared in part, or found by content, would need the
             # donor's ring at a length it no longer holds
             self._fabric_on = False
@@ -834,11 +843,27 @@ class JaxDecodeEngine(InferenceEngine):
                     self._cache_sharding,
                 )
                 for kind, layers in cfg.cache_layers.items()
-                if layers
+                if layers and kind in blocks
             }
 
         self._k_cache = new_pool()
         self._v_cache = new_pool()
+        if self._state is not None:
+            # a state a slot and linear layer, row 0 the null slot. It rides
+            # in the K-side dict, so every pool program carries it donated
+            n_lin = len(cfg.cache_layers["state"])
+            self._k_cache["state"] = {
+                "S": jnp.zeros(
+                    (n_lin, 1 + R, cfg.linear_num_value_heads,
+                     cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+                    jnp.float32,
+                ),
+                "conv": jnp.zeros(
+                    (n_lin, 1 + R, cfg.linear_conv_kernel_dim - 1,
+                     cfg.linear_conv_channels),
+                    kv_dtype,
+                ),
+            }
         self._k_scale = self._v_scale = None
         if self._kv_quant:
             # per-(row, head) f32 scales, paged like the data pool; the
@@ -886,6 +911,7 @@ class JaxDecodeEngine(InferenceEngine):
             self._moe_absent_pairs = 0
             self._kv_full_rows_read = 0
             self._kv_window_rows_read = 0
+            self._gdn_state_updates = 0
             self._paged_cols_live = 0
             self._paged_cols_visited = 0
             self._spec_hist = np.zeros(
@@ -953,18 +979,52 @@ class JaxDecodeEngine(InferenceEngine):
                     f"chunk of {int(c.spec_k) + 1} rows does not fit the "
                     f"ring's {slack} rows of slack past the window"
                 )
+        if cfg.cache_layers["state"] and c.spec_decode != "off":
+            refused.append(
+                f"spec_decode={c.spec_decode!r}: a rejected draft would have "
+                "to roll each slot's recurrent state back"
+            )
         if refused:
             raise NotImplementedError(
-                f"{cfg.model_type} (window and full layers in one stack) is "
-                "not served with: " + "; ".join(refused)
+                f"{cfg.model_type} (layers of more than one kind in one "
+                "stack) is not served with: " + "; ".join(refused)
             )
+
+    def _kv_row_nbytes(self) -> int:
+        """Bytes of one cached row of one layer, K and V (fp pools)."""
+        cfg = self.model_config
+        if cfg is None:
+            return 0
+        return (2 * cfg.num_key_value_heads * cfg.head_dim_
+                * jnp.dtype(self.config.kv_cache_dtype).itemsize)
+
+    def state_pool(self) -> dict | None:
+        """The recurrent-state pool `{"S", "conv"}` of a model with linear
+        layers, as the last pool program left it; None for any other model.
+        For a caller that has flushed the engine: a chunk in flight holds
+        the pool donated."""
+        if self._k_cache is None or self._state is None:
+            return None
+        return self._k_cache["state"]
+
+    def _state_update_nbytes(self) -> int:
+        """Bytes one linear layer's state update moves for one slot: its
+        state and convolution rows, once in and once out."""
+        if self._k_cache is None or self._state is None:
+            return 0
+        # from shapes alone: a metrics call may land while the pool is
+        # donated to a chunk in flight
+        return 2 * sum(
+            int(np.prod(a.shape[2:])) * a.dtype.itemsize
+            for a in self._k_cache["state"].values()
+        )
 
     def _no_migration_for_mixed(self, what: str) -> None:
         if self.model_config is not None and self.model_config.mixed:
             raise NotImplementedError(
                 f"{what}: a mixed stack's parked KV is a paged pool and a "
-                "ring; the migration wire and the host tier carry the paged "
-                "pool alone"
+                "ring or a recurrent state; the migration wire and the host "
+                "tier carry the paged pool alone"
             )
 
     def destroy(self):
@@ -1808,6 +1868,9 @@ class JaxDecodeEngine(InferenceEngine):
                 [B, pages])."""
                 bts_b, ring_b = tables if isinstance(tables, tuple) else (tables, None)
                 layers = cfg.cache_layers
+                # `ks` / `vs` stack the attention layers alone, in layer order
+                at = {li: j for j, li in enumerate(
+                    sorted(layers["full"] + layers["window"]))}
                 out = []
                 for pool, rows in ((kq, ks), (vq, vs)):
                     pool = dict(pool)
@@ -1815,7 +1878,7 @@ class JaxDecodeEngine(InferenceEngine):
                         fp = pool["full"]
                         Lf, _, bsz, D = fp.shape
                         nb_w = bts_b.shape[1]
-                        r = rows[:, np.asarray(layers["full"])]
+                        r = rows[:, np.asarray([at[li] for li in layers["full"]])]
                         r = jnp.pad(
                             r, ((0, 0), (0, 0), (0, nb_w * bsz - bucket),
                                 (0, 0), (0, 0))
@@ -1828,7 +1891,7 @@ class JaxDecodeEngine(InferenceEngine):
                         Lw, _, bsz, D = wp.shape
                         pages = ring_b.shape[1]
                         n_pg = -(-bucket // bsz)
-                        r = rows[:, np.asarray(layers["window"])]
+                        r = rows[:, np.asarray([at[li] for li in layers["window"]])]
                         r = jnp.pad(
                             r, ((0, 0), (0, 0), (0, n_pg * bsz - bucket),
                                 (0, 0), (0, 0))
@@ -1857,13 +1920,24 @@ class JaxDecodeEngine(InferenceEngine):
                 # bts_b: [B, nb_w] block-table rows to scatter into
                 def core(ids, true_len):
                     valid = jnp.arange(bucket) < true_len
-                    _, k, v = prefill(
+                    _, k, v, *state = prefill(
                         params, ids, positions, cfg, valid=valid,
                         with_logits=False,
                     )
-                    return k, v
+                    return k, v, *state
 
-                ks, vs = jax.vmap(core)(ids_b, lens_b)  # [B, L, bucket, ...]
+                ks, vs, *state = jax.vmap(core)(ids_b, lens_b)  # [B, L, bucket, ...]
+                if state:
+                    # the linear layers' state at each prompt's last real
+                    # token, into the prompts' slots' rows
+                    *bts_b, rows_b = bts_b
+                    bts_b = bts_b[0] if len(bts_b) == 1 else tuple(bts_b)
+                    kq = {**kq, "state": {
+                        name: kq["state"][name].at[:, rows_b].set(
+                            jnp.moveaxis(new, 0, 1).astype(kq["state"][name].dtype)
+                        )
+                        for name, new in state[0].items()
+                    }}
                 if cfg.mixed:
                     return write_mixed(kq, vq, ks, vs, bts_b, lens_b)
                 kp, ksc = split_pool(kq)
@@ -1907,9 +1981,12 @@ class JaxDecodeEngine(InferenceEngine):
         """What a prefill program scatters a slot's rows through: its
         block-table row, and for a mixed stack with it its ring blocks."""
         row = self._alloc.row(slot, nb_w)
-        if self._ring is None:
-            return row
-        return row, self._ring.blocks(slot)
+        tables = (row,)
+        if self._ring is not None:
+            tables += (self._ring.blocks(slot),)
+        if self._state is not None:
+            tables += (np.int32(self._state.row(slot)),)
+        return tables if len(tables) > 1 else row
 
     def _get_block_copy_fn(self):
         """Copy ONE pool block (the fork boundary's partial block).
@@ -1959,6 +2036,8 @@ class JaxDecodeEngine(InferenceEngine):
         """Alias the donor's full blocks and copy the boundary block; in a
         mixed stack also copy the donor's ring pages (the caller has checked
         that the ring still holds `covered`'s window: `_ring_holds`).
+        A model with linear layers: the donor's state rows are copied
+        (`_get_state_copy_fn`; the caller has checked `_ring_holds`).
         Raises PoolDry when the boundary block cannot be allocated."""
         cp = self._alloc.fork(src, dst, covered)
         if cp is not None:
@@ -1984,13 +2063,77 @@ class JaxDecodeEngine(InferenceEngine):
                 ))
             # what the donor's ring was written up to is what the copy holds
             self._ring.reset(dst, int(self._ring.hi[src]))
+        if self._state is not None and src != dst:
+            fn = self._get_state_copy_fn()
+            with self._weight_lock:
+                kq, vq = self._kv_operands()
+                self._set_kv_operands(*fn(
+                    kq,
+                    vq,
+                    # NumPy scalars: no eager device op ahead of the copy
+                    np.int32(self._state.row(src)),
+                    np.int32(self._state.row(dst)),
+                ))
+            self._state.reset(dst, int(self._state.count[src]))
+
+    def _get_state_copy_fn(self):
+        """Copy one slot's recurrent state onto another's (a fork of a
+        model with linear layers: a state cannot be aliased, each slot folds
+        its own tokens into its own)."""
+        if "state" not in self._fork_fns:
+
+            def fork_state(kq, vq, src_row, dst_row):
+                state = {
+                    name: a.at[:, dst_row].set(a[:, src_row])
+                    for name, a in kq["state"].items()
+                }
+                return {**kq, "state": state}, vq
+
+            self._fork_fns["state"] = jax.jit(fork_state, donate_argnums=(0, 1))
+        return self._fork_fns["state"]
+
+    def _zero_state(self, slot: int) -> None:
+        """A request that starts with no prefill decodes from an empty
+        state: the slot's rows back to zero."""
+        if "zero" not in self._fork_fns:
+
+            def zero(kq, vq, row):
+                state = {
+                    name: a.at[:, row].set(0) for name, a in kq["state"].items()
+                }
+                return {**kq, "state": state}, vq
+
+            self._fork_fns["zero"] = jax.jit(zero, donate_argnums=(0, 1))
+        with self._weight_lock:
+            kq, vq = self._kv_operands()
+            self._set_kv_operands(*self._fork_fns["zero"](
+                kq, vq, np.int32(self._state.row(slot))
+            ))
+
+    def _cache_rewritten(self, slot: int, length: int) -> None:
+        """A prefill (or nothing at all, `length` 0) has just written the
+        slot's ring and state from scratch: they hold `length` tokens."""
+        if self._ring is not None:
+            self._ring.reset(slot, length)
+        if self._state is not None:
+            self._state.reset(slot, length)
+
+    def _cache_written(self, active: np.ndarray) -> None:
+        """A dispatched chunk writes the active slots' rings and states up
+        to their (projected) lengths."""
+        for account in (self._ring, self._state):
+            if account is not None:
+                account.note_written(active, self._slot_lengths[active])
 
     def _ring_holds(self, slot: int, covered: int) -> bool:
         """Whether `slot`'s KV can seed a request that continues at
         `covered`: always for a uniform stack (its rows stay where they
         were written); for a mixed one only while the slot's ring has not
-        been written past that window."""
-        return self._ring is None or self._ring.holds(slot, covered)
+        been written past that window, and, with linear layers, only when
+        the slot's state holds exactly `covered` tokens."""
+        return (self._ring is None or self._ring.holds(slot, covered)) and (
+            self._state is None or self._state.holds(slot, covered)
+        )
 
     # -- host KV tier (kv_host_pool_mb) --------------------------------
     def _get_host_gather_fn(self):
@@ -2839,8 +2982,9 @@ class JaxDecodeEngine(InferenceEngine):
                 # no prefill: the decode loop writes KV from row 0, which
                 # invalidates whatever prefix this slot may have donated
                 self._release_slot_blocks(slot_idx)
-                if self._ring is not None:
-                    self._ring.reset(slot_idx)
+                if self._state is not None:
+                    self._zero_state(slot_idx)
+                self._cache_rewritten(slot_idx, 0)
             promoted = False
             if resumed is None and host_hit:
                 # Host-tier swap-in: fresh device blocks + async upload
@@ -3168,8 +3312,7 @@ class JaxDecodeEngine(InferenceEngine):
                                 ),
                             ))
                 for slot_idx, _, pre, _, covered_t in group:
-                    if self._ring is not None:
-                        self._ring.reset(slot_idx, pre)
+                    self._cache_rewritten(slot_idx, pre)
                     self._register_prefix(slot_idx, list(covered_t))
         for dst, src, covered_t, bucket in forks:
             covered = len(covered_t)
@@ -3204,8 +3347,7 @@ class JaxDecodeEngine(InferenceEngine):
                                 self._prefill_tables(dst, nb_w),
                                 covered,
                             ))
-                        if self._ring is not None:
-                            self._ring.reset(dst, covered)
+                        self._cache_rewritten(dst, covered)
                     else:
                         self._preempt_slot(dst)
                         continue
@@ -3660,8 +3802,7 @@ class JaxDecodeEngine(InferenceEngine):
             # (spec_w - accepted - 1) back out, and retire rewinds set the
             # absolute end as for normal chunks
             self._slot_lengths[active] += spec_w
-            if self._ring is not None:
-                self._ring.note_written(active, self._slot_lengths[active])
+            self._cache_written(active)
             self._count_block_columns(active, nb)
             with self._metrics_lock:
                 self._chunks_dispatched += 1
@@ -3723,8 +3864,7 @@ class JaxDecodeEngine(InferenceEngine):
         # ensure / bucket choice covers this (unconsumed) chunk's growth;
         # retire rewinds overwrite this with the absolute true end
         self._slot_lengths[active] += n_chunk
-        if self._ring is not None:
-            self._ring.note_written(active, self._slot_lengths[active])
+        self._cache_written(active)
         self._count_block_columns(active, nb)
         with self._metrics_lock:
             self._chunks_dispatched += 1
@@ -3749,8 +3889,8 @@ class JaxDecodeEngine(InferenceEngine):
                 logps = np.asarray(rec.logps)
                 acc = np.asarray(rec.accepted) if rec.spec_w > 0 else None
                 if rec.moe_load is not None:
-                    # [pairs, hot] and, by what the model is, [absent]
-                    # and [full rows, window rows] (models/qwen2.py)
+                    # [pairs, hot] and, by what the model is, [absent],
+                    # [full rows, window rows], [state updates] (models/qwen2.py)
                     pairs, hot, *more = np.asarray(rec.moe_load).tolist()
                     cfg = self.model_config
                     with self._metrics_lock:
@@ -3761,6 +3901,8 @@ class JaxDecodeEngine(InferenceEngine):
                         if more:
                             self._kv_full_rows_read += more[0]
                             self._kv_window_rows_read += more[1]
+                            # with linear layers, their state updates
+                            self._gdn_state_updates += sum(more[2:])
             self._apply_chunk(rec, toks, logps, acc)
 
     def _apply_chunk(self, rec: "_Inflight", toks: np.ndarray,
@@ -5304,6 +5446,7 @@ class JaxDecodeEngine(InferenceEngine):
             moe_pairs, moe_hot_pairs = self._moe_pairs, self._moe_hot_pairs
             moe_absent_pairs = self._moe_absent_pairs
             kv_rows_read = self._kv_full_rows_read, self._kv_window_rows_read
+            gdn_updates = self._gdn_state_updates
             paged_cols = self._paged_cols_live, self._paged_cols_visited
             table_uploads = self._table_uploads
             spec_hist = self._spec_hist.copy()
@@ -5402,6 +5545,12 @@ class JaxDecodeEngine(InferenceEngine):
             "moe_absent_pairs_total": moe_absent_pairs,
             "kv_full_rows_read_total": kv_rows_read[0],
             "kv_window_rows_read_total": kv_rows_read[1],
+            # bytes behind those rows (K and V of a full layer's row), and
+            # for linear layers the live slots' state updates and the bytes
+            # each moves: its state and convolution rows, read and written
+            "kv_full_bytes_read_total": kv_rows_read[0] * self._kv_row_nbytes(),
+            "gdn_state_updates_total": gdn_updates,
+            "gdn_state_bytes_total": gdn_updates * self._state_update_nbytes(),
             # block columns inside a slot's live range, of those the paged
             # kernel's (slots x nb) grid walks: how often it skips a step
             "paged_block_columns_live_total": paged_cols[0],

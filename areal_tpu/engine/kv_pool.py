@@ -231,6 +231,44 @@ class WindowRing:
         return covered <= self.hi[slot] <= oldest + self.pages * self.block_size
 
 
+class StateSlots:
+    """Host-side account of the linear layers' state pool of one engine.
+
+    A linear layer keeps no rows of keys and values but one recurrent state
+    a slot (`[linear layers, 1 + slots, ...]`, row 0 the null slot inactive
+    slots name): every token a slot's chunk computes is folded into it and
+    cannot be taken out again. So where a paged row stays valid for good and
+    a ring page for a window, a state is good for exactly one length: the
+    number of tokens it has absorbed (`count`; dispatched chunks included,
+    whether or not their tokens are kept). A slot's state can seed a request
+    that continues at `covered` only when it has absorbed exactly that
+    many: a donor that has decoded one token further, or a slot whose
+    run-ahead chunk ran past where the request stopped, cannot."""
+
+    def __init__(self, n_slots: int):
+        self.count = np.zeros(n_slots, dtype=np.int64)
+
+    @staticmethod
+    def row(slot: int) -> int:
+        """The slot's row in the state pool."""
+        return 1 + int(slot)
+
+    def note_written(self, slots, lengths) -> None:
+        """Tokens up to `lengths` (exclusive) have been (or are about to be:
+        dispatched chunks) folded into `slots`' states."""
+        self.count[slots] = np.maximum(self.count[slots], lengths)
+
+    def reset(self, slot: int, length: int = 0) -> None:
+        """The slot's state was written from scratch (a prefill, a fork, a
+        reset to zero) and now holds `length` tokens."""
+        self.count[slot] = length
+
+    def holds(self, slot: int, covered: int) -> bool:
+        """Whether the slot's state is the state after exactly `covered`
+        tokens."""
+        return int(self.count[slot]) == int(covered)
+
+
 @dataclass
 class HostKVEntry:
     """One offloaded slot's KV: the slot's first `nb` pool blocks gathered

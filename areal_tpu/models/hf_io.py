@@ -9,8 +9,10 @@ transpose + reshape per tensor.
 
 Supports Qwen2/2.5 (qkv bias), Qwen3 (qk norm), Llama/Mistral, Gemma,
 Qwen3-MoE / Qwen2-MoE (shared expert), Mixtral (block_sparse_moe.*), OLMoE
-and K-EXAONE (a leading dense layer, router bias, shared_experts; a chip's
-share of the experts by their published numbers) layouts. Files: model.safetensors or sharded model-*-of-*.safetensors with
+K-EXAONE (a leading dense layer, router bias, shared_experts; a chip's
+share of the experts by their published numbers) and Qwen3-Next (Gated
+DeltaNet layers under `linear_attn.*`, their fused projections grouped by
+key head; a doubled `q_proj`; zero-centred norm weights) layouts. Files: model.safetensors or sharded model-*-of-*.safetensors with
 index.
 """
 
@@ -94,6 +96,16 @@ def hf_name_to_ours(name: str) -> tuple[str, ...] | None:
             "mlp.shared_experts.gate_proj.weight": ("mlp", "shared_gate_kernel"),
             "mlp.shared_experts.up_proj.weight": ("mlp", "shared_up_kernel"),
             "mlp.shared_experts.down_proj.weight": ("mlp", "shared_down_kernel"),
+            # Qwen3-Next's Gated DeltaNet mixer (transformers' Qwen3NextGatedDeltaNet
+            # names: no checkpoint here to read them from,
+            # benchmark/configs/qwen3-next-80b-a3b.json `assumed`)
+            "linear_attn.in_proj_qkvz.weight": ("attn", "qkvz_kernel"),
+            "linear_attn.in_proj_ba.weight": ("attn", "ba_kernel"),
+            "linear_attn.conv1d.weight": ("attn", "conv_kernel"),
+            "linear_attn.dt_bias": ("attn", "dt_bias"),
+            "linear_attn.A_log": ("attn", "A_log"),
+            "linear_attn.norm.weight": ("attn", "norm"),
+            "linear_attn.out_proj.weight": ("attn", "out_kernel"),
             # Mixtral router
             "block_sparse_moe.gate.weight": ("mlp", "router_kernel"),
             "input_layernorm.weight": ("input_norm",),
@@ -123,14 +135,56 @@ def hf_name_to_ours(name: str) -> tuple[str, ...] | None:
     return None
 
 
+# Qwen3-Next's norms store a zero-centred weight w and scale by 1 + w; the
+# tree holds the effective scale (the Gated DeltaNet's own gated norm,
+# ("attn", "norm"), is a plain scale and is left as it is)
+_ZERO_CENTRED_NORMS = ("input_norm", "post_attn_norm", "final_norm", "q_norm", "k_norm")
+
+
+def _gdn_groups(cfg: ModelConfig, leaf: str) -> list[int]:
+    """Columns a key head owns of each part of a fused Gated DeltaNet
+    projection, in the checkpoint's order: [q, k, v, z] or [b, a]."""
+    r = cfg.linear_num_value_heads // cfg.linear_num_key_heads
+    if leaf == "ba_kernel":
+        return [r, r]
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    return [dk, dk, r * dv, r * dv]
+
+
+def _gdn_ungroup(w: np.ndarray, cfg: ModelConfig, leaf: str) -> np.ndarray:
+    """[H, key heads x (parts of one key head)] (a checkpoint's grouping) →
+    [H, part by part, heads in order] (the tree's)."""
+    H, nk = w.shape[0], cfg.linear_num_key_heads
+    parts = np.split(w.reshape(H, nk, -1), np.cumsum(_gdn_groups(cfg, leaf))[:-1], axis=-1)
+    return np.concatenate([part.reshape(H, -1) for part in parts], axis=-1)
+
+
+def _gdn_group(w: np.ndarray, cfg: ModelConfig, leaf: str) -> np.ndarray:
+    """Inverse of `_gdn_ungroup`."""
+    H, nk = w.shape[0], cfg.linear_num_key_heads
+    sizes = [nk * g for g in _gdn_groups(cfg, leaf)]
+    parts = np.split(w, np.cumsum(sizes)[:-1], axis=-1)
+    return np.concatenate([part.reshape(H, nk, -1) for part in parts], axis=-1).reshape(H, -1)
+
+
 def _convert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) -> np.ndarray:
     """Torch [out, in] → our einsum layout."""
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     H = cfg.hidden_size
     leaf = path[-1]
+    if cfg.model_type == "qwen3_next":
+        if leaf in _ZERO_CENTRED_NORMS:
+            return w + 1
+        if leaf in ("qkvz_kernel", "ba_kernel"):
+            return _gdn_ungroup(np.ascontiguousarray(w.T), cfg, leaf)
+        if leaf == "conv_kernel":  # torch Conv1d [channels, 1, width]
+            return w.reshape(w.shape[0], w.shape[-1])
+        if leaf == "out_kernel":
+            return np.ascontiguousarray(w.T)
     if leaf in ("q_kernel", "k_kernel", "v_kernel"):
         n = nH if leaf == "q_kernel" else nKV
-        return np.ascontiguousarray(w.T).reshape(H, n, hd)
+        # (under `attn_output_gate` a query head is 2 hd wide: q, then its gate)
+        return np.ascontiguousarray(w.T).reshape(H, n, -1)
     if leaf == "o_kernel":
         return np.ascontiguousarray(w.T).reshape(nH, hd, H)
     if leaf in ("q_bias",):
@@ -148,6 +202,15 @@ def _unconvert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) ->
     """Our layout → torch [out, in]."""
     H = cfg.hidden_size
     leaf = path[-1]
+    if cfg.model_type == "qwen3_next":
+        if leaf in _ZERO_CENTRED_NORMS:
+            return w - 1
+        if leaf in ("qkvz_kernel", "ba_kernel"):
+            return np.ascontiguousarray(_gdn_group(w, cfg, leaf).T)
+        if leaf == "conv_kernel":
+            return w.reshape(w.shape[0], 1, w.shape[1])
+        if leaf == "out_kernel":
+            return np.ascontiguousarray(w.T)
     if leaf in ("q_kernel", "k_kernel", "v_kernel"):
         return np.ascontiguousarray(w.reshape(H, -1).T)
     if leaf == "o_kernel":
@@ -394,6 +457,13 @@ def ours_name_to_hf(path: tuple[str, ...], model_type: str = "qwen2") -> str:
         ("mlp", "shared_router_kernel"): "mlp.shared_expert_gate.weight",
         ("input_norm",): "input_layernorm.weight",
         ("post_attn_norm",): "post_attention_layernorm.weight",
+        ("attn", "qkvz_kernel"): "linear_attn.in_proj_qkvz.weight",
+        ("attn", "ba_kernel"): "linear_attn.in_proj_ba.weight",
+        ("attn", "conv_kernel"): "linear_attn.conv1d.weight",
+        ("attn", "dt_bias"): "linear_attn.dt_bias",
+        ("attn", "A_log"): "linear_attn.A_log",
+        ("attn", "norm"): "linear_attn.norm.weight",
+        ("attn", "out_kernel"): "linear_attn.out_proj.weight",
     }
     if model_type == "mixtral":
         leaf_table[("mlp", "router_kernel")] = "block_sparse_moe.gate.weight"

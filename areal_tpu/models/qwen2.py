@@ -60,11 +60,12 @@ def _cstr(x: jax.Array, *logical_axes: str | None) -> jax.Array:
 # dense qwen2-shaped model with whatever keys happen to match.
 MODEL_TYPES = (
     "qwen2", "qwen3", "llama", "mistral", "gemma", "gemma2", "gpt2",
-    "mixtral", "qwen2_moe", "qwen3_moe", "olmoe", "exaone_moe",
+    "mixtral", "qwen2_moe", "qwen3_moe", "olmoe", "exaone_moe", "qwen3_next",
 )
 
 # `layer_types` entries of a mixed stack (HF's names)
 _WINDOW_LAYER, _FULL_LAYER = "sliding_attention", "full_attention"
+_LINEAR_LAYER = "linear_attention"
 
 
 @dataclass(frozen=True)
@@ -188,6 +189,21 @@ class ModelConfig:
     layer_types: tuple | None = None
     nope_full_layers: bool = False
     first_k_dense: int = 0
+    # -- Qwen3-Next-class. A third layer kind, "linear_attention": a Gated
+    # DeltaNet mixer (`gated_delta_net`) of `linear_num_key_heads` key and
+    # `linear_num_value_heads` value heads, behind a depthwise causal
+    # convolution of width `linear_conv_kernel_dim`. Its cache is no rows of
+    # keys and values but a recurrent state a sequence. The attention layers
+    # of such a model gate their output by a sigmoid of a second half of
+    # `q_proj` (`attn_output_gate`) and rotate only the first
+    # `partial_rotary_factor` of each head's lanes.
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    attn_output_gate: bool = False
+    partial_rotary_factor: float = 1.0
     # vocab chunk for the fused LM-head loss (ops/fused_xent.py): peak
     # logits transient is [tokens, loss_vocab_chunk]
     loss_vocab_chunk: int = 16384
@@ -215,6 +231,11 @@ class ModelConfig:
         """Layers of more than one kind: no stacked scan, two caches."""
         return self.layer_types is not None or self.first_k_dense > 0
 
+    @property
+    def rotary_dim(self) -> int:
+        """Lanes of a head that the rotary embedding turns (the first ones)."""
+        return int(self.head_dim_ * self.partial_rotary_factor)
+
     def layer_window(self, i) -> int | None:
         """Layer i's attention window (None = full causal). `i` is a
         python int in a mixed stack; a uniform stack ignores it (it may be
@@ -222,6 +243,16 @@ class ModelConfig:
         if self.layer_types is None:
             return self.sliding_window
         return self.sliding_window if self.layer_types[i] == _WINDOW_LAYER else None
+
+    def layer_linear(self, i) -> bool:
+        """Whether layer i's mixer is the Gated DeltaNet and not attention."""
+        return self.layer_types is not None and self.layer_types[i] == _LINEAR_LAYER
+
+    @property
+    def linear_conv_channels(self) -> int:
+        """Channels the convolution runs over: q, k and v side by side."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
 
     def layer_rope(self, i) -> bool:
         """Whether layer i rotates q and k."""
@@ -238,11 +269,16 @@ class ModelConfig:
     @property
     def cache_layers(self) -> dict:
         """{"full": layer indices with a paged cache, "window": those with
-        a ring} of a mixed stack, in layer order."""
+        a ring, "state": those with a recurrent state a slot} of a mixed
+        stack, in layer order."""
         L = range(self.num_hidden_layers)
+        state = tuple(i for i in L if self.layer_linear(i))
         return {
-            "full": tuple(i for i in L if self.layer_window(i) is None),
+            "full": tuple(
+                i for i in L if self.layer_window(i) is None and i not in state
+            ),
             "window": tuple(i for i in L if self.layer_window(i) is not None),
+            "state": state,
         }
 
     @classmethod
@@ -423,6 +459,8 @@ class ModelConfig:
             )
         elif model_type == "exaone_moe":
             kw.update(_exaone_moe_kw(hf))
+        elif model_type == "qwen3_next":
+            kw.update(_qwen3_next_kw(hf))
         elif model_type == "gemma":
             # Gemma-1 (reference: realhf/api/from_hf/gemma.py — GeGLU MLP,
             # zero-centered RMSNorm, sqrt(H)-scaled embeddings, tied head).
@@ -487,6 +525,20 @@ class ModelConfig:
         return None
 
 
+def _held_experts_kw(hf: dict) -> dict:
+    """The experts this chip holds of the published ones: `num_experts` of
+    `num_experts_published` (all of them when not given) from `expert_first`."""
+    held = int(hf["num_experts"])
+    published = int(hf.get("num_experts_published", held))
+    first = int(hf.get("expert_first", 0))
+    if not 0 <= first <= published - held:
+        raise ValueError(
+            f"{hf.get('model_type')} holds experts [{first}, {first + held}) "
+            f"of {published}"
+        )
+    return dict(num_experts=held, num_experts_published=published, expert_first=first)
+
+
 def _exaone_moe_kw(hf: dict) -> dict:
     """K-EXAONE (`exaone_moe`): window and full layers in one stack with
     rotary embedding on the window layers only, per-head q/k norm, leading
@@ -533,13 +585,6 @@ def _exaone_moe_kw(hf: dict) -> dict:
                 f"first_k_dense_replace={k_dense} dense layers then sparse ones"
             )
     n_shared = int(hf.get("num_shared_experts", 0))
-    held = int(hf["num_experts"])
-    published = int(hf.get("num_experts_published", held))
-    first = int(hf.get("expert_first", 0))
-    if not 0 <= first <= published - held:
-        raise ValueError(
-            f"exaone_moe holds experts [{first}, {first + held}) of {published}"
-        )
     return dict(
         qk_norm=True,
         rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
@@ -547,9 +592,7 @@ def _exaone_moe_kw(hf: dict) -> dict:
         layer_types=types,
         nope_full_layers=True,
         first_k_dense=k_dense,
-        num_experts=held,
-        num_experts_published=published,
-        expert_first=first,
+        **_held_experts_kw(hf),
         num_experts_per_tok=hf.get("num_experts_per_tok", 8),
         moe_intermediate_size=hf["moe_intermediate_size"],
         shared_expert_intermediate_size=n_shared * hf["moe_intermediate_size"],
@@ -564,9 +607,79 @@ def _exaone_moe_kw(hf: dict) -> dict:
     )
 
 
+def _qwen3_next_kw(hf: dict) -> dict:
+    """Qwen3-Next (`qwen3_next`): Gated DeltaNet layers with one gated
+    full-attention layer every `full_attention_interval`, per-head q/k norm,
+    rotary embedding on a prefix of the head's lanes, every layer sparse
+    (softmax routing normalised over the chosen, a sigmoid-gated shared
+    expert). Norm weights are held as effective scales (`hf_io` adds the 1 a
+    checkpoint leaves out). Raises on what is not served."""
+    L = hf["num_hidden_layers"]
+    if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1:
+        raise NotImplementedError(
+            "qwen3_next with mlp_only_layers / decoder_sparse_step != 1 "
+            "(dense layers among the sparse ones) is not implemented"
+        )
+    if hf.get("rope_scaling"):
+        raise NotImplementedError(
+            f"qwen3_next with rope_scaling {hf['rope_scaling']!r} is not "
+            "implemented (served: none)"
+        )
+    types = hf.get("layer_types")
+    if types is None:
+        interval = int(hf.get("full_attention_interval", 4))
+        types = [
+            _FULL_LAYER if (i + 1) % interval == 0 else _LINEAR_LAYER
+            for i in range(L)
+        ]
+    # a config cut in depth keeps the published list whole: the first L count
+    types = tuple(types[:L])
+    if len(types) != L or any(t not in (_LINEAR_LAYER, _FULL_LAYER) for t in types):
+        raise NotImplementedError(
+            f"qwen3_next layer_types {types!r}: need {L} entries of "
+            f"{_LINEAR_LAYER!r} / {_FULL_LAYER!r}"
+        )
+    return dict(
+        qk_norm=True,
+        layer_types=types,
+        partial_rotary_factor=float(hf.get("partial_rotary_factor", 0.25)),
+        attn_output_gate=True,
+        linear_num_key_heads=int(hf["linear_num_key_heads"]),
+        linear_num_value_heads=int(hf["linear_num_value_heads"]),
+        linear_key_head_dim=int(hf["linear_key_head_dim"]),
+        linear_value_head_dim=int(hf["linear_value_head_dim"]),
+        linear_conv_kernel_dim=int(hf.get("linear_conv_kernel_dim", 4)),
+        **_held_experts_kw(hf),
+        num_experts_per_tok=hf.get("num_experts_per_tok", 10),
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        shared_expert_intermediate_size=hf.get("shared_expert_intermediate_size", 0),
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        router_aux_loss_coef=hf.get("router_aux_loss_coef", 0.0),
+        # layers of two kinds do not stack
+        scan_layers=False,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Parameter tree + logical sharding axes
 # ---------------------------------------------------------------------------
+
+
+def _gdn_shapes(cfg: ModelConfig) -> dict:
+    """The Gated DeltaNet mixer's leaves: q, k, v and the output gate z from
+    one projection (columns [q | k | v | z], heads in order), the write
+    strength b and the decay input a from another ([b | a])."""
+    H, Hv = cfg.hidden_size, cfg.linear_num_value_heads
+    value_dim = Hv * cfg.linear_value_head_dim
+    return {
+        "qkvz_kernel": (H, cfg.linear_conv_channels + value_dim),
+        "ba_kernel": (H, 2 * Hv),
+        "conv_kernel": (cfg.linear_conv_channels, cfg.linear_conv_kernel_dim),
+        "dt_bias": (Hv,),
+        "A_log": (Hv,),
+        "norm": (cfg.linear_value_head_dim,),
+        "out_kernel": (value_dim, H),
+    }
 
 
 def _layer_shapes(cfg: ModelConfig, i: int | None = None) -> dict:
@@ -576,9 +689,11 @@ def _layer_shapes(cfg: ModelConfig, i: int | None = None) -> dict:
     sparse = cfg.layer_sparse(0 if i is None else i)
     S = cfg.shared_expert_intermediate_size
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
+    linear = i is not None and cfg.layer_linear(i)
     shapes = {
-        "attn": {
-            "q_kernel": (H, nH, hd),
+        "attn": _gdn_shapes(cfg) if linear else {
+            # `attn_output_gate`: each head's query lanes, then its gate's
+            "q_kernel": (H, nH, 2 * hd if cfg.attn_output_gate else hd),
             "k_kernel": (H, nKV, hd),
             "v_kernel": (H, nKV, hd),
             "o_kernel": (nH, hd, H),
@@ -622,6 +737,8 @@ def _layer_shapes(cfg: ModelConfig, i: int | None = None) -> dict:
         "input_norm": (H,),
         "post_attn_norm": (H,),
     }
+    if linear:
+        return shapes
     if cfg.qkv_bias:
         shapes["attn"]["q_bias"] = (nH, hd)
         shapes["attn"]["k_bias"] = (nKV, hd)
@@ -650,6 +767,15 @@ _LAYER_AXES = {
         "q_norm": ("norm",),
         "k_norm": ("norm",),
         "o_bias": ("norm",),
+        # Gated DeltaNet (its projections mix heads of two sizes in one
+        # output axis, so only the embed axis is named)
+        "qkvz_kernel": ("embed", None),
+        "ba_kernel": ("embed", None),
+        "conv_kernel": (None, None),
+        "dt_bias": ("norm",),
+        "A_log": ("norm",),
+        "norm": ("norm",),
+        "out_kernel": (None, "embed"),
     },
     "mlp": {
         "gate_kernel": ("embed", "mlp"),
@@ -934,8 +1060,9 @@ def param_logical_axes(cfg: ModelConfig) -> dict:
     shapes = _layer_shapes(cfg)
 
     def layer_axes(i=None):
+        attn = shapes["attn"] if i is None else _layer_shapes(cfg, i)["attn"]
         out = {
-            "attn": {k: _LAYER_AXES["attn"][k] for k in shapes["attn"]},
+            "attn": {k: _LAYER_AXES["attn"][k] for k in attn},
             "mlp": _mlp_axes(cfg, i),
             "input_norm": _LAYER_AXES["input_norm"],
             "post_attn_norm": _LAYER_AXES["post_attn_norm"],
@@ -995,6 +1122,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     # effective scale is 1 + weight
     def zero_special(path, x):
         name = path[-1].key if hasattr(path[-1], "key") else ""
+        if name == "dt_bias":  # the Gated DeltaNet's, as its module starts it
+            return x
         if name.endswith("_bias") or name == "bias":
             return jnp.zeros_like(x)
         if cfg.norm_zero_centered and name.endswith("norm"):
@@ -1317,15 +1446,31 @@ def rope_table(
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def _split_rotary(t: jax.Array, rot: int) -> tuple:
+    """(t1, t2, rest): the two halves of the first `rot` lanes of `t`'s last
+    axis (HF's 'rotate_half' pairs lane i with lane i + rot/2) and, as a list
+    of none or one, the lanes past them, which no rotation touches
+    (`partial_rotary_factor`)."""
+    d2 = rot // 2
+    rest = [t[..., rot:]] if rot < t.shape[-1] else []
+    return t[..., :d2], t[..., d2:rot], rest
+
+
+def _rotated(t1, t2, cos, sin, rest: list) -> jax.Array:
+    """The halves turned by tables that broadcast against them, the
+    untouched lanes behind them."""
+    return jnp.concatenate(
+        [t1 * cos - t2 * sin, t2 * cos + t1 * sin, *rest], axis=-1
+    )
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate pairs (HF 'rotate_half' convention). x: [T, n, hd]."""
-    d2 = x.shape[-1] // 2
-    x1, x2 = x[..., :d2], x[..., d2:]
+    """Rotate pairs (HF 'rotate_half' convention) of the lanes the tables
+    cover. x: [T, n, hd]; tables [T, rotary_dim/2]."""
+    x1, x2, rest = _split_rotary(x, 2 * cos.shape[-1])
     cos = cos[:, None, :].astype(x1.dtype)
     sin = sin[:, None, :].astype(x1.dtype)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    )
+    return _rotated(x1, x2, cos, sin, rest)
 
 
 def _window_band(T: int, sliding_window: int | None) -> jax.Array | None:
@@ -1453,6 +1598,7 @@ def attention(
             q = q + layer_p["q_bias"]
             k = k + layer_p["k_bias"]
             v = v + layer_p["v_bias"]
+        q, *gate = _split_output_gate(q, cfg)
         if cfg.qk_norm:
             q, k = _qk_norm(q, k, layer_p, cfg)
     if cfg.layer_rope(li):
@@ -1500,7 +1646,7 @@ def attention(
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             out = jnp.einsum("kgts,skd->tkgd", probs, v)
             out = out.reshape(T, nH, hd)
-    out = _cstr(out, "tokens", "act_heads", None)
+    out = _cstr(_gate_attn_out(out, gate), "tokens", "act_heads", None)
     with jax.named_scope("o_proj"):
         proj = _w_einsum("tnd,ndh->th", out, layer_p["o_kernel"], 2)
         if cfg.lora_rank:
@@ -1759,6 +1905,222 @@ def moe_mlp(
     return y, aux
 
 
+
+# -- Gated DeltaNet (Qwen3-Next's linear-attention mixer) --------------------
+# Per value head a state S [d_k, d_v] (float32, zero at a sequence's start):
+#   S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;  o_t = S^T q_t
+# behind a depthwise causal convolution (width K, no bias, silu) over the
+# channels [q | k | v]. A sequence's cache is S and the last K-1
+# pre-convolution rows. Two forms of one recurrence: `_gdn_chunk_scan` (a
+# scan over chunks of `GDN_CHUNK` tokens: forward, prefill) and one step
+# (`ops/gdn_step.py`: decode).
+
+GDN_CHUNK = 64
+
+
+def _gdn_project(layer_p: dict, x: jax.Array, cfg: ModelConfig):
+    """x [..., H] -> (u [..., C] pre-convolution channels [q | k | v],
+    z [..., Hv, dv], beta [..., Hv] float32, g [..., Hv] float32 <= 0)."""
+    Hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+    C = cfg.linear_conv_channels
+    with jax.named_scope("qkvz"):
+        qkvz = jnp.einsum("...h,hc->...c", x, layer_p["qkvz_kernel"])
+        ba = jnp.einsum("...h,hc->...c", x, layer_p["ba_kernel"]).astype(jnp.float32)
+    u, z = qkvz[..., :C], qkvz[..., C:].reshape(*x.shape[:-1], Hv, dv)
+    beta = jax.nn.sigmoid(ba[..., :Hv])
+    g = -jnp.exp(layer_p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+        ba[..., Hv:] + layer_p["dt_bias"].astype(jnp.float32)
+    )
+    return u, z, beta, g
+
+
+def _gdn_heads(u: jax.Array, cfg: ModelConfig):
+    """Post-convolution channels [..., C] float32 -> (q, k [..., Hv, dk],
+    v [..., Hv, dv]): q and k L2-normalised over their lanes, q scaled by
+    dk^-0.5, both repeated to the value heads."""
+    Hk, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
+    Hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+    lead = u.shape[:-1]
+    q = u[..., : Hk * dk].reshape(*lead, Hk, dk)
+    k = u[..., Hk * dk : 2 * Hk * dk].reshape(*lead, Hk, dk)
+    v = u[..., 2 * Hk * dk :].reshape(*lead, Hv, dv)
+
+    def l2(t):
+        return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+    q = jnp.repeat(l2(q) * dk ** -0.5, Hv // Hk, axis=-2)
+    k = jnp.repeat(l2(k), Hv // Hk, axis=-2)
+    return q, k, v
+
+
+def _gdn_output(layer_p: dict, o: jax.Array, z: jax.Array, cfg: ModelConfig):
+    """o [..., Hv, dv] float32 -> [..., H]: per head `w * rmsnorm(o) *
+    silu(z)` in float32, then the output projection."""
+    var = jnp.mean(o * o, axis=-1, keepdims=True)
+    o = o * jax.lax.rsqrt(var + cfg.rms_norm_eps) * layer_p["norm"].astype(jnp.float32)
+    o = o * jax.nn.silu(z.astype(jnp.float32))
+    dtype = jnp.dtype(cfg.dtype)
+    with jax.named_scope("out_proj"):
+        return jnp.einsum(
+            "...c,ch->...h", o.reshape(*o.shape[:-2], -1).astype(dtype),
+            layer_p["out_kernel"],
+        )
+
+
+def _gdn_conv(u: jax.Array, kernel: jax.Array, segment_ids: jax.Array):
+    """Depthwise causal convolution over one packed stream: u [T, C] ->
+    silu(sum_j kernel[:, j] * u[t - (K-1) + j]) in float32, rows of another
+    segment (and those before the stream's start) counting as zeros."""
+    K = kernel.shape[1]
+    T = u.shape[0]
+    u32 = u.astype(jnp.float32)
+    w = kernel.astype(jnp.float32)
+    acc = u32 * w[:, K - 1]
+    for back in range(1, K):
+        shifted = jnp.pad(u32, ((back, 0), (0, 0)))[:T]
+        seg = jnp.pad(segment_ids, (back, 0), constant_values=PADDING_SEGMENT)[:T]
+        same = (seg == segment_ids)[:, None]
+        acc = acc + jnp.where(same, shifted, 0.0) * w[:, K - 1 - back]
+    return jax.nn.silu(acc)
+
+
+@jax.named_scope("gdn_chunk_scan")
+def _gdn_chunk_scan(q, k, v, g, beta, segment_ids, chunk: int = GDN_CHUNK):
+    """The delta rule over one packed stream, a chunk of tokens at a time.
+
+    q, k [T, Hv, dk], v [T, Hv, dv], g, beta [T, Hv], all float32;
+    `segment_ids` [T]: the state starts from zero at each segment's first
+    token; a token of `PADDING_SEGMENT` must come with beta = g = 0 and then
+    leaves the state as it is. Returns (o [T, Hv, dv], S [Hv, dk, dv] after
+    the last token). Within a chunk the rule's triangular system
+    `(I + tril(beta k k^T * decay, -1)) D = beta v - beta k decay S_prev` is
+    solved in float32 for both right-hand sides; the scan over chunks carries
+    S alone."""
+    T, Hv, dk = q.shape
+    dv = v.shape[-1]
+    # padding belongs to the segment before it: with g = 0 and beta = 0 it
+    # then carries that segment's state across unchanged
+    t_idx = jnp.arange(T)
+    last_real = jax.lax.cummax(
+        jnp.where(segment_ids == PADDING_SEGMENT, -1, t_idx), axis=0
+    )
+    seg = jnp.where(last_real >= 0, segment_ids[jnp.maximum(last_real, 0)], segment_ids)
+    pad = (-T) % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+            for a in (q, k, v, g, beta)
+        )
+        seg = jnp.pad(seg, (0, pad), mode="edge")
+    n = (T + pad) // chunk
+    hp = jax.lax.Precision.HIGHEST
+    # [n, Hv, chunk, ...]: heads ahead of the chunk's tokens
+    q, k, v = (a.reshape(n, chunk, Hv, -1).transpose(0, 2, 1, 3) for a in (q, k, v))
+    g, beta = (a.reshape(n, chunk, Hv).transpose(0, 2, 1) for a in (g, beta))
+    seg = seg.reshape(n, chunk)
+    # tokens whose segment began before their chunk see the carried state
+    seg_prev = jnp.concatenate(
+        [jnp.full((1,), PADDING_SEGMENT - 1, seg.dtype), seg[:-1, -1]]
+    )
+    cont = (seg == seg_prev[:, None]).astype(jnp.float32)[:, None]  # [n, 1, chunk]
+    G = jnp.cumsum(g, axis=-1)  # [n, Hv, chunk] log decay from the chunk's start
+    same = seg[:, :, None] == seg[:, None, :]  # [n, chunk, chunk]
+    idx = jnp.arange(chunk)
+    lower = idx[:, None] >= idx[None, :]
+    # decay from token j (after its write) to token i, 0 across a boundary
+    decay = jnp.exp(jnp.where(
+        (same & lower)[:, None], G[..., :, None] - G[..., None, :], -jnp.inf
+    ))  # [n, Hv, chunk, chunk]
+    from_prev = jnp.exp(G) * cont  # what of the carried state token i sees
+    kb = k * beta[..., None]
+    strict = (idx[:, None] > idx[None, :])[None, None]
+    A = jnp.where(strict, jnp.einsum("nhik,nhjk->nhij", kb, k, precision=hp) * decay, 0.0)
+    W = jax.scipy.linalg.solve_triangular(
+        A + jnp.eye(chunk, dtype=A.dtype),
+        jnp.concatenate([v * beta[..., None], kb * from_prev[..., None]], axis=-1),
+        lower=True, unit_diagonal=True,
+    )
+    attn = jnp.einsum("nhik,nhjk->nhij", q, k, precision=hp) * decay
+    # what of the carried state, and of each token's write, is left at the
+    # chunk's end
+    keep = from_prev[..., -1]  # [n, Hv]
+    tail = jnp.where(same[:, None, -1, :], jnp.exp(G[..., -1:] - G), 0.0)
+
+    def step(S, xs):  # S [Hv, dk, dv]
+        q_c, k_c, v_c, k_cum, attn_c, keep_c = xs
+        d = v_c - jnp.einsum("hik,hkv->hiv", k_cum, S, precision=hp)
+        o = jnp.einsum("hik,hkv->hiv", q_c, S, precision=hp) + jnp.einsum(
+            "hij,hjv->hiv", attn_c, d, precision=hp
+        )
+        S = S * keep_c[:, None, None] + jnp.einsum(
+            "hjk,hjv->hkv", k_c, d, precision=hp
+        )
+        return S, o
+
+    S, o = jax.lax.scan(
+        step, jnp.zeros((Hv, dk, dv), jnp.float32),
+        (q * from_prev[..., None], k * tail[..., None], W[..., :dv], W[..., dv:],
+         attn, keep),
+    )
+    o = o.transpose(0, 2, 1, 3).reshape(n * chunk, Hv, dv)[:T]
+    return o, S
+
+
+def gated_delta_net(layer_p: dict, x: jax.Array, segment_ids: jax.Array,
+                    cfg: ModelConfig, true_len: jax.Array | None = None):
+    """The Gated DeltaNet mixer over one packed stream x [T, H] (`forward`:
+    segments reset the state and the convolution; `prefill`: one segment).
+    Padding (`PADDING_SEGMENT`) does not enter the state. Returns the
+    mixer's output [T, H] and, given `true_len` (a prefill: the stream is
+    one sequence of that many real tokens, padding after them), also the
+    cache to hand over: (S [Hv, dk, dv] float32 at the last real token, the
+    last K-1 real pre-convolution rows [K-1, C])."""
+    u, z, beta, g = _gdn_project(layer_p, x, cfg)
+    real = (segment_ids != PADDING_SEGMENT)[:, None]
+    beta = jnp.where(real, beta, 0.0)
+    g = jnp.where(real, g, 0.0)
+    with jax.named_scope("conv"):
+        q, k, v = _gdn_heads(_gdn_conv(u, layer_p["conv_kernel"], segment_ids), cfg)
+    o, S = _gdn_chunk_scan(q, k, v, g, beta, segment_ids)
+    out = _gdn_output(layer_p, o, z, cfg)
+    if true_len is None:
+        return out
+    K = cfg.linear_conv_kernel_dim
+    with jax.named_scope("conv_state"):
+        rows = jax.lax.dynamic_slice_in_dim(
+            jnp.pad(u, ((K - 1, 0), (0, 0))), true_len, K - 1, axis=0
+        )
+    return out, (S, rows)
+
+
+def gated_delta_step(layer_p: dict, x: jax.Array, state: dict, ci: int,
+                     active: jax.Array | None, cfg: ModelConfig,
+                     impl: str = "auto"):
+    """One decode step of linear layer number `ci` (among the linear
+    layers) for R slots: x [R, H]; `state` = {"S": [n_lin, 1 + R, Hv, dk,
+    dv] float32, "conv": [n_lin, 1 + R, K-1, C]}, row 0 the null slot. A
+    slot that is not `active` keeps its state. Returns (out [R, H], state)."""
+    from areal_tpu.ops.gdn_step import gdn_step
+
+    u, z, beta, g = _gdn_project(layer_p, x, cfg)
+    with jax.named_scope("conv_state"):
+        conv = state["conv"]
+        rows = conv[ci, 1:]  # [R, K-1, C]
+        window = jnp.concatenate([rows, u[:, None].astype(rows.dtype)], axis=1)
+        w = layer_p["conv_kernel"].astype(jnp.float32)  # [C, K]
+        mixed = jax.nn.silu(
+            jnp.einsum("rkc,ck->rc", window.astype(jnp.float32), w)
+        )
+        new_rows = window[:, 1:]
+        if active is not None:
+            new_rows = jnp.where(active[:, None, None], new_rows, rows)
+        conv = conv.at[ci, 1:].set(new_rows)
+    q, k, v = _gdn_heads(mixed, cfg)
+    with jax.named_scope("gdn_step"):
+        o, S = gdn_step(state["S"], q, k, v, g, beta, ci, active, impl=impl)
+    return _gdn_output(layer_p, o, z, cfg), {"S": S, "conv": conv}
+
+
 _REMAT_POLICIES = {
     "full": None,
     "dots_saveable": "dots_saveable",
@@ -1795,7 +2157,11 @@ def decoder_layer(
     """Returns (hidden [T, H], router aux loss scalar — 0 for dense).
     `li` (static) is the layer's index in an unstacked tree."""
     h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-    x = x + attention(layer_p["attn"], h, cos, sin, segment_ids, mask, cfg, li)
+    if cfg.layer_linear(li):
+        with jax.named_scope("attn"):
+            x = x + gated_delta_net(layer_p["attn"], h, segment_ids, cfg)
+    else:
+        x = x + attention(layer_p["attn"], h, cos, sin, segment_ids, mask, cfg, li)
     h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
     if cfg.layer_sparse(li):
         y, aux = moe_mlp(
@@ -1880,7 +2246,7 @@ def forward(
                 "tokens",
                 "act_embed",
             )
-    cos, sin = rope_table(position_ids, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
+    cos, sin = rope_table(position_ids, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
     # Dense path: build the [T,T] mask ONCE here (outside the per-layer remat
     # region); flash/ring never materialise it.
     # (a mixed stack: one mask for each kind of layer that runs dense)
@@ -1965,7 +2331,7 @@ def _pp_stage_fn(cfg: ModelConfig):
 
     def stage_fn(layers_local, h, aux_t):
         pos, seg = aux_t
-        cos, sin = rope_table(pos, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
+        cos, sin = rope_table(pos, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
 
         def body(carry, layer_p):
             h, aux_sum = carry
@@ -2241,17 +2607,21 @@ def _embed_tokens(params: dict, tokens, positions, cfg: ModelConfig) -> jax.Arra
 
 
 def _attn_out_mlp(layer_p: dict, x, attn_out, cfg: ModelConfig, valid,
-                  moe_load: bool = False, li=None):
+                  moe_load: bool = False, li=None, projected: bool = False):
     """What every decode-side layer body ends with: the output projection of
     `attn_out` [N, nH, hd] and the MLP, each with its residual. With
     `moe_load` (MoE models only) returns (x, this layer's expert load, see
     `moe_mlp`; zeros from a leading dense layer). `li`: the layer's index,
-    static in a mixed stack."""
+    static in a mixed stack. `projected`: `attn_out` is a mixer's output
+    [N, H], its own projection behind it (a Gated DeltaNet layer)."""
     sparse = cfg.layer_sparse(li)
-    with jax.named_scope("attn"), jax.named_scope("o_proj"):
-        proj = _w_einsum("tnd,ndh->th", attn_out, layer_p["attn"]["o_kernel"], 2)
-        if cfg.attn_out_bias:
-            proj = proj + layer_p["attn"]["o_bias"]
+    if projected:
+        proj = attn_out
+    else:
+        with jax.named_scope("attn"), jax.named_scope("o_proj"):
+            proj = _w_einsum("tnd,ndh->th", attn_out, layer_p["attn"]["o_kernel"], 2)
+            if cfg.attn_out_bias:
+                proj = proj + layer_p["attn"]["o_bias"]
     x = x + proj
     h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
     if moe_load and sparse:
@@ -2278,16 +2648,36 @@ def _moe_load_len(cfg: ModelConfig) -> int:
 
 def decode_load_len(cfg: ModelConfig) -> int:
     """Entries of the vector `decode_step_paged` returns under `moe_load`:
-    `moe_mlp`'s, and for a mixed stack the cached rows read by the full and
-    by the window layers."""
-    return _moe_load_len(cfg) + (2 if cfg.mixed else 0)
+    `moe_mlp`'s, for a mixed stack the cached rows read by the full and by
+    the window layers, and where it has linear layers their state updates."""
+    return (_moe_load_len(cfg) + (2 if cfg.mixed else 0)
+            + (1 if cfg.cache_layers["state"] else 0))
+
+
+def _split_output_gate(q: jax.Array, cfg: ModelConfig) -> tuple:
+    """(q,) or, under `attn_output_gate`, (q, gate): each head's projection
+    is its query lanes followed by as many lanes of output gate."""
+    if not cfg.attn_output_gate:
+        return (q,)
+    hd = cfg.head_dim_
+    return q[..., :hd], q[..., hd:]
+
+
+def _gate_attn_out(attn_out: jax.Array, gate: list) -> jax.Array:
+    """`attn_out * sigmoid(gate)` where the layer has an output gate
+    (`gate` is what `_project_qkv` returned past q, k and v)."""
+    if not gate:
+        return attn_out
+    g = jax.nn.sigmoid(gate[0].astype(jnp.float32)).astype(attn_out.dtype)
+    return attn_out * g.reshape(attn_out.shape)
 
 
 def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig,
                  rope: bool = True):
     """Shared QKV projection + norm + rope. x: [..., H] with leading dims
     matching cos/sin's leading dims. `rope` False: a layer that takes no
-    rotary embedding (the full layers of a mixed stack)."""
+    rotary embedding (the full layers of a mixed stack). Returns (q, k, v),
+    and the output gate as a fourth under `attn_output_gate`."""
     with jax.named_scope("qkv"):
         q = _w_einsum("...h,hnd->...nd", x, layer_p["q_kernel"], 1)
         k = _w_einsum("...h,hnd->...nd", x, layer_p["k_kernel"], 1)
@@ -2296,6 +2686,7 @@ def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig,
             q = q + layer_p["q_bias"]
             k = k + layer_p["k_bias"]
             v = v + layer_p["v_bias"]
+        q, *gate = _split_output_gate(q, cfg)
         if cfg.qk_norm:
             q, k = _qk_norm(q, k, layer_p, cfg)
     cos_b = cos[..., None, :].astype(q.dtype)
@@ -2303,15 +2694,12 @@ def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig,
 
     @jax.named_scope("rope")
     def rot(t):
-        d2 = t.shape[-1] // 2
-        t1, t2 = t[..., :d2], t[..., d2:]
-        return jnp.concatenate(
-            [t1 * cos_b - t2 * sin_b, t2 * cos_b + t1 * sin_b], axis=-1
-        )
+        t1, t2, rest = _split_rotary(t, 2 * cos.shape[-1])
+        return _rotated(t1, t2, cos_b, sin_b, rest)
 
     if cfg.pos_embed != "rope" or not rope:
-        return q, k, v
-    return rot(q), rot(k), v
+        return q, k, v, *gate
+    return rot(q), rot(k), v, *gate
 
 
 _EXPERT_KERNELS = ("gate_kernel", "up_kernel", "down_kernel")
@@ -2368,7 +2756,10 @@ def prefill(
     prefix_len: jax.Array | None = None,
 ) -> tuple[jax.Array | None, jax.Array, jax.Array]:
     """Causal forward over ONE sequence [T], returning (logits [T, V],
-    k_cache [L, T, nKV, hd], v_cache [L, T, nKV, hd]).
+    k_cache [L, T, nKV, hd], v_cache [L, T, nKV, hd]). A model with linear
+    layers returns the rows of its attention layers alone (in layer order)
+    and a fourth: its linear layers' state at the last real token, {"S":
+    [n_lin, Hv, dk, dv] float32, "conv": [n_lin, K-1, C]}.
 
     `valid` [T] bool marks real (non-bucket-pad) tokens; MoE routing must
     see it so pad rows don't claim expert capacity. (Attention needs no
@@ -2404,7 +2795,7 @@ def prefill(
     if rope_cos is not None:
         cos, sin = rope_cos, rope_sin
     else:
-        cos, sin = rope_table(position_ids, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
+        cos, sin = rope_table(position_ids, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
     T = input_ids.shape[0]
     with_prefix = prefix_k is not None
     # above `PREFILL_DENSE_MAX` tokens no [T, T] score tensor is built: the
@@ -2437,13 +2828,35 @@ def prefill(
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     group = nH // nKV
 
+    has_state = bool(cfg.cache_layers["state"])
+    if has_state:
+        if with_prefix:
+            raise NotImplementedError(
+                "a suffix prefill over cached rows: a linear layer's state at "
+                "the prefix's end is not among them"
+            )
+        # one sequence, padding after its real tokens
+        seq_segments = (
+            jnp.zeros(T, jnp.int32) if valid is None
+            else jnp.where(valid, 0, PADDING_SEGMENT)
+        )
+        true_len = T if valid is None else valid.sum(dtype=jnp.int32)
+
     @jax.named_scope("layer")
     def layer(x, inputs, li=None):
         layer_p, *prefix = inputs
         window = cfg.layer_window(li)
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
+        if cfg.layer_linear(li):
+            with jax.named_scope("attn"):
+                out, cache = gated_delta_net(
+                    layer_p["attn"], h, seq_segments, cfg, true_len
+                )
+            return _attn_out_mlp(
+                layer_p, x, out, cfg, valid, li=li, projected=True
+            ), cache
         with jax.named_scope("attn"):
-            q, k, v = _project_qkv(
+            q, k, v, *gate = _project_qkv(
                 layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
             )
             if chunked:
@@ -2471,6 +2884,7 @@ def prefill(
                     attn_out = jnp.einsum(
                         "kgts,skd->tkgd", probs, vv
                     ).reshape(T, nH, hd)
+            attn_out = _gate_attn_out(attn_out, gate)
         x = _attn_out_mlp(layer_p, x, attn_out, cfg, valid, li=li)
         return x, (k, v)
 
@@ -2478,19 +2892,21 @@ def prefill(
     if cfg.scan_layers:
         x, (ks, vs) = _scan_stacked(layer, x, params, cfg, *prefix)
     else:
-        ks_list, vs_list = [], []
+        # attention layers hand over rows of keys and values (stacked in
+        # layer order), linear layers their state
+        rows, states = [], []
         for i in range(cfg.num_hidden_layers):
             with _layer_scope(cfg, i):
-                x, (k, v) = layer(
+                x, cache = layer(
                     x, (params[f"layers_{i}"], *(p[i] for p in prefix)), i
                 )
-            ks_list.append(k)
-            vs_list.append(v)
-        ks, vs = jnp.stack(ks_list), jnp.stack(vs_list)
+            (states if cfg.layer_linear(i) else rows).append(cache)
+        ks, vs = (jnp.stack(t) for t in zip(*rows))
 
-    if not with_logits:
-        return None, ks, vs
-    logits = _final_logits(params, x, cfg)
+    logits = _final_logits(params, x, cfg) if with_logits else None
+    if has_state:
+        S, conv = (jnp.stack(t) for t in zip(*states))
+        return logits, ks, vs, {"S": S, "conv": conv}
     return logits, ks, vs
 
 
@@ -2747,7 +3163,7 @@ def decode_step_paged(
     nH, hd = cfg.num_attention_heads, cfg.head_dim_
     x = _embed_tokens(params, tokens, positions, cfg)  # [R, H]
     rope_pos = positions if rope_offset is None else positions + rope_offset
-    cos, sin = rope_table(rope_pos, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
+    cos, sin = rope_table(rope_pos, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
     valid = jnp.arange(span)[None, :] <= positions[:, None]  # [R, span]
     mixed = isinstance(k_pool, dict)
     if cfg.sliding_window is not None and not mixed:
@@ -2781,28 +3197,42 @@ def decode_step_paged(
     def layer(carry, layer_p, li):
         x, kp, vp, load = carry
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
+        linear = cfg.layer_linear(li)
         with jax.named_scope("attn"):
-            q, k_new, v_new = _project_qkv(
-                layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
-            )
-            if mixed:
-                kind, ci = index[li]
-                attn_out, kp, vp = _mixed_attention(
-                    paged_attention, q.reshape(R, nH, hd), kp, vp, k_new,
-                    v_new, (kind, ci, where[kind]), attn_impl,
+            if linear:
+                # no rows to write or read: the slot's state, updated in place
+                attn_out, state = gated_delta_step(
+                    layer_p["attn"], h, kp["state"], index[li][1], active, cfg,
+                    attn_impl,
                 )
+                kp = {**kp, "state": state}
             else:
-                kp = _write_pool_rows(kp, k_new, li, dest_block, dest_off)
-                vp = _write_pool_rows(vp, v_new, li, dest_block, dest_off)
-                with jax.named_scope("attention"):
-                    attn_out = paged_attention(
-                        q.reshape(R, nH, hd), kp, vp, block_tables, valid, li,
-                        impl=attn_impl, live=live,
+                q, k_new, v_new, *gate = _project_qkv(
+                    layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
+                )
+                if mixed:
+                    kind, ci = index[li]
+                    attn_out, kp, vp = _mixed_attention(
+                        paged_attention, q.reshape(R, nH, hd), kp, vp, k_new,
+                        v_new, (kind, ci, where[kind]), attn_impl,
                     )
+                else:
+                    kp = _write_pool_rows(kp, k_new, li, dest_block, dest_off)
+                    vp = _write_pool_rows(vp, v_new, li, dest_block, dest_off)
+                    with jax.named_scope("attention"):
+                        attn_out = paged_attention(
+                            q.reshape(R, nH, hd), kp, vp, block_tables, valid,
+                            li, impl=attn_impl, live=live,
+                        )
+                attn_out = _gate_attn_out(attn_out, gate)
         if moe_load:
-            x, layer_load = _attn_out_mlp(layer_p, x, attn_out, cfg, active, True, li)
+            x, layer_load = _attn_out_mlp(
+                layer_p, x, attn_out, cfg, active, True, li, projected=linear
+            )
             return x, kp, vp, load + layer_load
-        return _attn_out_mlp(layer_p, x, attn_out, cfg, active, li=li), kp, vp, None
+        return _attn_out_mlp(
+            layer_p, x, attn_out, cfg, active, li=li, projected=linear
+        ), kp, vp, None
 
     # the load rides in the carry as None (no leaf) unless asked for
     load0 = jnp.zeros(_moe_load_len(cfg), jnp.int32) if moe_load else None
@@ -2821,6 +3251,10 @@ def decode_step_paged(
                 for kind in ("full", "window")
             ]).astype(load.dtype)
             load = jnp.concatenate([load, rows])
+            if cfg.cache_layers["state"]:
+                # live slots' state updates, a linear layer each
+                updates = live.sum() * len(cfg.cache_layers["state"])
+                load = jnp.concatenate([load, updates[None].astype(load.dtype)])
         return logits, k_pool, v_pool, load
     return logits, k_pool, v_pool
 
@@ -2860,6 +3294,11 @@ def verify_step_paged(
     `decode_step_paged` does for its single row."""
     from areal_tpu.ops.paged_attention import paged_attention_qlen
 
+    if cfg.cache_layers["state"]:
+        raise NotImplementedError(
+            "a verify step over linear layers: a rejected draft would have "
+            "to roll each slot's state back"
+        )
     R, W = tokens.shape
     bsz = jax.tree.leaves(k_pool)[0].shape[2]
     nb = block_tables.shape[1]
@@ -2871,7 +3310,7 @@ def verify_step_paged(
     rope_pos = (
         positions if rope_offset is None else positions + rope_offset[:, None]
     ).reshape(-1)
-    cos, sin = rope_table(rope_pos, cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling_)
+    cos, sin = rope_table(rope_pos, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
     valid = (
         jnp.arange(span)[None, None, :] <= positions[:, :, None]
     )  # [R, W, span]
@@ -2921,7 +3360,7 @@ def verify_step_paged(
         x, kp, vp = carry
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         with jax.named_scope("attn"):
-            q, k_new, v_new = _project_qkv(
+            q, k_new, v_new, *gate = _project_qkv(
                 layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
             )
             if mixed:
@@ -2939,6 +3378,7 @@ def verify_step_paged(
                         q.reshape(R, W, nH, hd), kp, vp, block_tables, valid, li,
                         impl=attn_impl, live=live,
                     ).reshape(R * W, nH, hd)
+            attn_out = _gate_attn_out(attn_out, gate)
         x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat, li=li)
         return x, kp, vp
 

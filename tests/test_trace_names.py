@@ -415,6 +415,53 @@ def test_paged_kernels_are_named_apart_at_kexaones_head_shape(one_chip, kind, la
     assert _one_paged_kernel(hlo).startswith(_PAGED_KERNELS[kind])
 
 
+def test_gdn_step_kernel_is_named_at_qwen3_nexts_state_shape(one_chip):
+    """32 value heads of 128 x 128 float32 a slot, six linear layers in one
+    pool (Qwen3-Next): ONE Mosaic call named `%gdn_step`, the whole pool its
+    operand and (aliased) its result, no copy of it beside the call."""
+    from areal_tpu.ops.gdn_step import gdn_step
+
+    n, R, Hv, dk, dv = 6, 64, 32, 128, 128
+    f32 = jnp.float32
+
+    def step(S, q, k, v, g, beta, active):
+        with jax.named_scope("layer"):
+            return gdn_step(S, q, k, v, g, beta, 4, active, impl="pallas", interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((n, 1 + R, Hv, dk, dv), f32), ((R, Hv, dk), f32), ((R, Hv, dk), f32),
+        ((R, Hv, dv), f32), ((R, Hv), f32), ((R, Hv), f32), ((R,), jnp.bool_))]
+    hlo = jax.jit(step, donate_argnums=0).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    kernels = _mosaic_kernels(hlo)
+    assert len(kernels) == 1 and kernels[0].startswith("gdn_step") and " while(" not in hlo
+    assert "%gdn_step" in hlo and "%layer" not in hlo
+    state = f"f32[{n},{1 + R},{Hv},{dk},{dv}]"
+    copies = [ln for ln in hlo.splitlines() if " copy(" in ln and state in ln]
+    assert not copies, copies
+
+
+def test_paged_kernel_is_named_at_qwen3_nexts_head_shape(one_chip):
+    """16 query and 2 KV heads of 256 (Qwen3-Next's gated full attention): a
+    pool row of 512 lanes for K and for V, two full layers in the pool, 64
+    block columns of a context of 8,192."""
+    from areal_tpu.ops.paged_attention import paged_attention
+
+    R, nH, nKV, hd, bsz, nb, L = 64, 16, 2, 256, 128, 64, 2
+    pool = ((L, R * nb + 1, bsz, nKV * hd), jnp.bfloat16)
+
+    def step(q, kp, vp, bt, valid, lo, hi):
+        return paged_attention(q, kp, vp, bt, valid, 1, impl="pallas", interpret=False,
+                               live=(lo, hi))
+
+    hlo = _compile(
+        step, one_chip, ((R, nH, hd), jnp.bfloat16), pool, pool,
+        ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_),
+        ((R,), jnp.int32), ((R,), jnp.int32))
+    assert "tpu_custom_call" in hlo and "%paged_attention" in hlo
+    assert _one_paged_kernel(hlo).startswith("paged_attention")
+
+
 @pytest.mark.parametrize("tokens", [64, 2048], ids=["decode_step", "batched_prefill"])
 def test_grouped_expert_matmuls_are_named_at_olmoes_widths(one_chip, tokens):
     """XLA:TPU lowers `jax.lax.ragged_dot` to a Mosaic grouped matmul of its

@@ -330,6 +330,33 @@ def paged_case(W, int8, nH, nKV, hd, R=16, nb=4, L=3, layer=2):
     return err < 0.03 and bool(jnp.all(jnp.isfinite(out))), f"maxerr={err:.4f}"
 
 
+def gdn_step_case(n=6, layer=4, R=64, Hv=32, dk=128, dv=128, dead_every=7):
+    """The Gated DeltaNet decode step (`ops/gdn_step.py`) at Qwen3-Next's
+    state shape: six linear layers' float32 states in one pool, the kernel
+    against the op's `jax.numpy` arithmetic; some slots not active, whose
+    rows, the other layers' and the null row must come back to the bit."""
+    from areal_tpu.ops.gdn_step import gdn_step
+
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    S = jax.random.normal(ks[0], (n, 1 + R, Hv, dk, dv), jnp.float32).at[:, 0].set(0)
+    q = jax.random.normal(ks[1], (R, Hv, dk)) * dk ** -0.5
+    k = jax.random.normal(ks[2], (R, Hv, dk))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[3], (R, Hv, dv))
+    g = -4.0 * jax.random.uniform(ks[4], (R, Hv))
+    beta = jax.random.uniform(ks[5], (R, Hv))
+    active = jnp.arange(R) % dead_every != 3
+    o, S1 = jax.jit(lambda *a: gdn_step(*a, layer, active, impl="pallas", interpret=False))(
+        S, q, k, v, g, beta)
+    o_ref, S_ref = jax.jit(lambda *a: gdn_step(*a, layer, active, impl="xla"))(
+        S, q, k, v, g, beta)
+    err = max(_maxerr(S1[layer], S_ref[layer]), _maxerr(o, o_ref, active))
+    kept = (bool(jnp.array_equal(S1[:, 0], S[:, 0]))
+            and bool(jnp.array_equal(jnp.delete(S1, layer, axis=0), jnp.delete(S, layer, axis=0)))
+            and bool(jnp.array_equal(S1[layer, 1:][~active], S[layer, 1:][~active])))
+    return err < 1e-4 and kept and bool(jnp.all(jnp.isfinite(o))), f"maxerr={err:.2e} kept={kept}"
+
+
 def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5):
     """The exact MoE mixture (`models/qwen2.py:moe_mlp`: pairs sorted by
     expert, XLA's grouped matmul for `jax.lax.ragged_dot`) at OLMoE's
@@ -525,6 +552,14 @@ def cases():
                 "default",
                 lambda a=(mix, W, int8, nH, nKV, hd): paged_ragged_case(*a),
             ))
+    # Qwen3-Next: the state update of a linear layer, and its gated full
+    # attention's head shape through the paged kernel (decode and ragged)
+    out.append(("gdn_step 6 layers x 64 slots x 32 heads of 128x128 float32, in place",
+                "default for models with linear layers", gdn_step_case))
+    out.append(("paged_attention W=1 bf16 16/2/256", "default for qwen3_next",
+                lambda: paged_case(1, False, 16, 2, 256)))
+    out.append(("paged_attention live range, 30% W=1 bf16 16/2/256 nb=10",
+                "default for qwen3_next", lambda: paged_ragged_case("30%", 1, False, 16, 2, 256)))
     # OLMoE's experts: a decode step's rows and a batched prefill's
     for T in (64, 2048):
         out.append((

@@ -11,7 +11,10 @@ an `.xplane.pb`, or the Chrome JSON that `AREAL_TPU_PERF_TRACE=1` leaves in
   kernels    device time by operation name (`%flash_fwd`, `%paged_attention`,
              `%fusion`, ...): inclusive, and what is not spent in nested operations
   scopes     device self time by the program's `jax.named_scope` path, from
-             the `op_name` the trace keeps in each operation's metadata
+             the `op_name` the trace keeps in each operation's metadata (a
+             linear layer's mixer shows as `layer/attn/gdn_chunk_scan` in a
+             prefill, `layer/attn/gdn_step` and `layer/attn/conv_state` in a
+             decode step, beside `attention_full` of the gated layers)
   spans      the program's `areal/` spans (and the benchmark's `bench/`): count,
              total and self time (total minus the spans nested in it)
   idle gaps  every gap of chip 0 over `--gap-ms`, summed by the innermost
