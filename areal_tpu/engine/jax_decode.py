@@ -609,8 +609,8 @@ class JaxDecodeEngine(InferenceEngine):
         self._kv_window_rows_read = 0
         # linear layers: state updates of live slots (slots x layers x steps)
         self._gdn_state_updates = 0
-        # of the R x nb block columns the paged kernel's grid walks a chunk,
-        # those inside a slot's live range (_count_block_columns)
+        # of the steps the paged kernel takes a chunk (a live block column,
+        # or a slot that has none), the live columns (_count_block_columns)
         self._paged_cols_live = 0
         self._paged_cols_visited = 0
         self._chunk_fns: dict[bool, Callable] = {}
@@ -1037,7 +1037,7 @@ class JaxDecodeEngine(InferenceEngine):
             chunks = self._chunks_dispatched
         if visited:
             logger.info(
-                f"paged kernel: {live} of {visited} block columns live "
+                f"paged kernel: {live} of {visited} steps a live block column "
                 f"({100.0 * live / visited:.1f}%) over {chunks} chunks"
             )
         if self._executor is not None:
@@ -1494,11 +1494,12 @@ class JaxDecodeEngine(InferenceEngine):
         so the compiled chunk updates the pool in place and moves no KV
         besides (tests/test_pool_in_place.py holds the traced program to
         it). `paged_attn_impl` selects only the attention read inside the
-        step: the Pallas kernel (each grid step DMAs one pool block
-        HBM->VMEM; through Mosaic on a TPU) or XLA's gather of the slot's
-        blocks a step. Every read goes through the pool as stored, so
-        streams do not depend on where a chunk ends, for fp and int8 pools
-        alike.
+        step: the Pallas kernel (a slot a grid step, which copies the pool
+        blocks of the slot's live columns HBM->VMEM one by one and no
+        others, so a chunk program is keyed by its depth `nb` alone; through
+        Mosaic on a TPU) or XLA's gather of the slot's blocks a step. Every
+        read goes through the pool as stored, so streams do not depend on
+        where a chunk ends, for fp and int8 pools alike.
 
         `use_topp=False` (the common RL rollout setting, top_p == 1):
         plain categorical over temperature-scaled logits. `use_topp=True`:
@@ -3597,22 +3598,26 @@ class JaxDecodeEngine(InferenceEngine):
             self._consume_chunk(self._inflight.popleft())
 
     def _count_block_columns(self, active: np.ndarray, nb: int) -> None:
-        """`paged_block_columns_{live,visited}_total`: of the R x nb block
-        columns the paged kernel's grid walks for the chunk just dispatched
-        (the paged pool's table; a mixed stack's ring is two columns a
-        slot, both live), those inside a slot's live range at the chunk's
-        last step. `ops/paged_attention.live_block_range` on the host, from
-        the projected lengths: an active slot has the columns up to its
-        last query's, less those wholly before a uniform stack's window."""
+        """`paged_block_columns_{live,visited}_total`: what the paged kernel
+        walks for the chunk just dispatched (the paged pool's table; a mixed
+        stack's ring is two columns a slot, both live), at the chunk's last
+        step. The kernel takes one step a live block column and one a slot
+        that has none (it writes that slot's zeros), so `visited` is the live
+        columns plus the slots not active; it was slots x `nb` while the
+        kernel's grid was. `ops/paged_attention.live_block_range` on the
+        host, from the projected lengths: an active slot has the columns up
+        to its last query's, less those wholly before a uniform stack's
+        window."""
         bsz = self._alloc.block_size
         last = self._slot_lengths[active].astype(np.int64) - 1
         live = np.minimum(last // bsz + 1, nb)
         window = self.model_config.sliding_window
         if window is not None and self._ring is None:
             live -= np.maximum(last - window + 1, 0) // bsz
+        columns = int(live.sum())
         with self._metrics_lock:
-            self._paged_cols_live += int(live.sum())
-            self._paged_cols_visited += active.size * nb
+            self._paged_cols_live += columns
+            self._paged_cols_visited += columns + active.size - live.size
 
     def _dispatch_chunk(self, active: np.ndarray) -> "_Inflight | None":
         R = self.config.max_running_requests
@@ -5551,8 +5556,8 @@ class JaxDecodeEngine(InferenceEngine):
             "kv_full_bytes_read_total": kv_rows_read[0] * self._kv_row_nbytes(),
             "gdn_state_updates_total": gdn_updates,
             "gdn_state_bytes_total": gdn_updates * self._state_update_nbytes(),
-            # block columns inside a slot's live range, of those the paged
-            # kernel's (slots x nb) grid walks: how often it skips a step
+            # block columns inside a slot's live range, of the steps the
+            # paged kernel takes (those, and one a slot with none)
             "paged_block_columns_live_total": paged_cols[0],
             "paged_block_columns_visited_total": paged_cols[1],
             "device_busy_s": round(dev_busy_s, 6),

@@ -3071,15 +3071,21 @@ def _mixed_cache(cfg: ModelConfig, k_pool, positions, slots, active, full,
 
 
 def _live_columns(valid, active, bsz: int, attn_impl: str):
-    """Each slot's range of block columns that hold a row `valid` lets it
-    attend (`ops/paged_attention.live_block_range`): what the kernel read
-    works on. Taken once a token step, outside the layer loop. None for the
-    XLA read, which gathers every column and keeps its program."""
-    from areal_tpu.ops.paged_attention import live_block_range, resolve_impl
+    """The kernel read's work list: each slot's range of block columns that
+    hold a row `valid` lets it attend (`ops/paged_attention.live_block_range`)
+    and the chain that walks the ranges slot after slot (`slot_schedule`).
+    Taken once a token step, outside the layer loop. None for the XLA read,
+    which gathers every column and keeps its program."""
+    from areal_tpu.ops.paged_attention import (
+        live_block_range,
+        resolve_impl,
+        slot_schedule,
+    )
 
     if resolve_impl(attn_impl) != "pallas":
         return None
-    return live_block_range(valid, bsz, active)
+    live = live_block_range(valid, bsz, active)
+    return (*live, *slot_schedule(*live))
 
 
 def _mixed_attention(read, q, kp, vp, k_new, v_new, place, attn_impl):

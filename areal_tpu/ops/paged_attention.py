@@ -12,25 +12,32 @@ Decode is HBM-bandwidth-bound on TPU: like SGLang's paged radix cache
 
 Two implementations behind one signature, selected like `attn_impl`:
 
-- `"pallas"` (TPU): a split-KV flash-decode kernel. The block table is a
-  scalar-prefetch operand and the layer index a second, so each grid
-  step's BlockSpec index map reads `(layer, bt[r, b])` and DMAs exactly
-  that pool block HBM→VMEM — attention reads KV *through the table*, in
-  the layout the pool is stored in (tests/test_pool_in_place.py holds
-  the traced programs to that). One grid step takes the block's whole
-  contiguous `(bsz, nKV*hd)` slab (every kv head) and scores all query
-  heads against it with block-diagonal queries. Online-softmax partial
-  (max, sum, acc) scratch carries across the `nb` block steps of each
-  slot. The grid is `(R, nb)`, `nb` set by the deepest slot, but a slot
-  works only on the block columns it HAS: its live range `[lo, hi)`
-  (`live_block_range`: the first and one past the last column whose mask
-  holds a valid row; `lo == hi == 0` for a slot that is not active) rides
-  as two more scalar-prefetch vectors. A grid step outside the range runs
-  no cast, matmul or softmax update, and its index maps name the block the
-  nearest live step names (the null block 0 for an empty slot), so Pallas
-  issues no copy for it either. A column with no valid row adds exactly
-  nothing to the online softmax, so skipping it changes no bit of a live
-  slot's output; an empty slot writes zeros.
+- `"pallas"` (TPU): a split-KV flash-decode kernel whose work is the live
+  (slot, block column) pairs and nothing else. The grid is the R slots; a
+  grid step loops over the block columns its slot HAS, the live range
+  `[lo, hi)` (`live_block_range`: the first and one past the last column
+  whose mask holds a valid row; `lo == hi == 0` for a slot that is not
+  active). The pools stay in HBM as they are stored and the kernel copies
+  the pages it scores itself: the block table, the layer index, `lo`, `hi`
+  and the walk's chain (`slot_schedule`: how many live columns lie before
+  each slot, and which slot is the next to have one) are scalar-prefetch
+  operands, so an iteration reads `(layer, bt[r, c])` and DMAs exactly that
+  pool block HBM->VMEM into one of two page buffers while the page before
+  is scored; a slot's last column starts the next live slot's first page,
+  so the copies stay ahead across slots too. Attention reads KV *through
+  the table*, in the layout the pool is stored in
+  (tests/test_pool_in_place.py holds the traced programs to that). One
+  iteration takes the block's whole contiguous `(bsz, nKV*hd)` slab (every
+  kv head) and scores all query heads against it with block-diagonal
+  queries; online-softmax partials (max, sum, acc) carry across a slot's
+  columns in scratch. A column outside the range is never named: not
+  fetched, not scored, no step taken for it (the `(R, nb)` grid this
+  replaced still paid a fifth to a third of a live step for each). A
+  column with no valid row adds exactly nothing to the online softmax, so
+  a live slot's output is equal to the bit to a walk over every column; a
+  slot with no live column writes zeros. The loop's length is read on the
+  device from the slot's own range, so no program is keyed by how ragged
+  a batch is.
 - `"xla"` (CPU / tests): gathers the slot's `nb` blocks per step and
   runs plain einsums over them (scores and softmax in float32). The
   committed stream goldens (tests/fixtures/) were recorded through this
@@ -123,7 +130,8 @@ def _paged_attention_xla(q, k_pool, v_pool, block_table, valid, layer, sm_scale)
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernel: split-KV grid, online-softmax partial reduction
+# Pallas TPU kernel: a walk over the live (slot, block column) pairs,
+# online-softmax partial reduction
 # ---------------------------------------------------------------------------
 
 
@@ -148,63 +156,118 @@ def live_block_range(valid, block_size: int, active=None):
     return lo, hi
 
 
+def slot_schedule(lo, hi):
+    """(start, nxt), two [R] int32 vectors that chain the slots' live ranges
+    into one walk: `start[r]` is the number of live columns of the slots
+    before `r` (so column `j` of slot `r` is the walk's `start[r] + j`-th, and
+    its parity picks the page buffer), `nxt[r]` the next slot after `r` that
+    has a live column, `R` if none. With `live_block_range`'s (lo, hi) this
+    is the kernel's whole work list; taken with it, once a token step."""
+    R = lo.shape[0]
+    count = (hi - lo).astype(jnp.int32)
+    start = jnp.cumsum(count) - count
+    slot = jnp.where(count > 0, jnp.arange(R, dtype=jnp.int32), R)
+    after = jax.lax.cummin(slot, reverse=True)
+    nxt = jnp.concatenate([after[1:], jnp.full((1,), R, jnp.int32)])
+    return start.astype(jnp.int32), nxt
+
+
 def _paged_kernel(
     bt_ref,  # [R, nb] scalar-prefetch block table
-    layer_ref,  # [1] scalar-prefetch layer index (read by the index maps)
+    layer_ref,  # [1] scalar-prefetch layer index
     lo_ref,  # [R] scalar-prefetch: first live block column of each slot
     hi_ref,  # [R] scalar-prefetch: one past the last live column
-    mask_ref,  # (1, 1, W, bsz) int32 validity rows for this block, per query
+    start_ref,  # [R] scalar-prefetch: live columns of the slots before
+    nxt_ref,  # [R] scalar-prefetch: the next slot with a live column
+    mask_ref,  # (1, nb, W, bsz) int32 validity rows of the slot, per query
     q_ref,  # (1, W*nHp, D) block-diagonal queries, D = nKV*hd
-    *refs,  # [sel], k, [k scales], v, [v scales], out, acc, m, l
+    *refs,  # [sel], k, [k scales], v, [v scales] (HBM), out, scratch
     sm_scale: float,
     quant: bool,
 ):
-    """One grid step = one pool block of one slot, ALL kv heads, and only
-    where `lo[r] <= b < hi[r]`: outside its slot's live range a step does
-    nothing (the index maps hold its operands still, so nothing is copied
-    for it either). The block is the contiguous (bsz, nKV*hd) slab the pool
+    """One grid step = one slot: a loop over the slot's live block columns
+    `lo[r] .. hi[r] - 1`, one pool block (ALL kv heads) an iteration, copied
+    HBM->VMEM by the kernel itself into one of two page buffers while the
+    page before it is scored. The walk runs on across slots: the last column
+    of a slot starts the copy of the next live slot's first page, so a page's
+    latency hides behind a page's arithmetic everywhere but at the walk's
+    first column. A slot with no live column copies and scores nothing and
+    writes zeros. The block is the contiguous (bsz, nKV*hd) slab the pool
     stores, so every tile is lane-dense whatever the head count. Query row
     i carries head i's query in its kv head's hd lanes and zeros elsewhere,
     so `q @ k.T` is exactly the per-head score; `p @ v` is exact on the head's own lanes (the
     caller reads only those). Int8 pools: the per-(row, kv head) scale
     factors out of the hd contraction, so it multiplies the SCORES (K) and
-    the PROBS (V) — the int8 tile feeds the MXU straight after the DMA."""
+    the PROBS (V) — the int8 tile feeds the MXU straight after the copy."""
     if quant:
         # sel: (nKV, W*nHp, 1) f32 one-hot, query row -> its kv head
-        sel_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        (sel_ref, k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref, acc_ref, m_ref, l_ref,
+         k_buf, v_buf, ks_buf, vs_buf, sems) = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf), (vs_hbm, vs_buf))
     else:
-        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    r, b = pl.program_id(0), pl.program_id(1)
-    nb = pl.num_programs(1)
+        k_hbm, v_hbm, o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sems = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    r = pl.program_id(0)
+    R = pl.num_programs(0)
     rows = q_ref.shape[1]
     W, bsz = mask_ref.shape[2], mask_ref.shape[3]
+    layer = layer_ref[0]
+    lo = lo_ref[r]
+    n = hi_ref[r] - lo
+    start = start_ref[r]
+    nxt = nxt_ref[r]
 
-    @pl.when(b == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def copies(slot, col, buf):
+        # the page walk: block `col` of `slot` comes straight from the pool
+        # page (layer, table entry) names, as the pool stores it (scale
+        # strips walk the same entry)
+        blk = bt_ref[slot, col]
+        return [
+            pltpu.make_async_copy(hbm.at[layer, blk], vmem.at[buf], sems.at[i, buf])
+            for i, (hbm, vmem) in enumerate(pools)
+        ]
 
-    def head_rows(sc_ref):
+    # the walk's first page: no slot before this one started it
+    @pl.when((n > 0) & (start == 0))
+    def _first_page():
+        for c in copies(r, lo, 0):
+            c.start()
+
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def head_rows(sc):
         # (nKV, bsz) block scales -> [rows, bsz]: row i gets its kv head's
-        sc = sc_ref[...]
         return sum(
             sel_ref[h] * sc[h : h + 1, :] for h in range(sc.shape[0])
         )
 
-    @pl.when((lo_ref[r] <= b) & (b < hi_ref[r]))
-    def _live_column():
+    def live_column(j, carry):
+        buf = (start + j) % 2
+        more = j + 1 < n
+
+        # the page after this one, the next live slot's first after the last
+        @pl.when(more | (nxt < R))
+        def _next_page():
+            slot = jnp.where(more, r, jnp.minimum(nxt, R - 1))
+            col = jnp.where(more, lo + j + 1, lo_ref[slot])
+            for c in copies(slot, col, 1 - buf):
+                c.start()
+
+        for c in copies(r, lo + j, buf):
+            c.wait()
         q = q_ref[0].astype(jnp.float32)  # [rows, D]
-        k = k_ref[...].astype(jnp.float32)  # [bsz, D]
+        k = k_buf[buf].astype(jnp.float32)  # [bsz, D]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         s = s * sm_scale
         if quant:
-            s = s * head_rows(ks_ref)
+            s = s * head_rows(ks_buf[buf])
         # per-query causal horizon: mask row w applies to that query's heads
         m2 = jnp.broadcast_to(
-            mask_ref[0, 0][:, None, :], (W, rows // W, bsz)
+            mask_ref[0, lo + j][:, None, :], (W, rows // W, bsz)
         ).reshape(rows, bsz)
         s = jnp.where(m2 != 0, s, _NEG_INF)
 
@@ -217,24 +280,26 @@ def _paged_kernel(
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_ref[:] = m_new
         if quant:
-            p = p * head_rows(vs_ref)
-        v = v_ref[...].astype(jnp.float32)
+            p = p * head_rows(vs_buf[buf])
+        v = v_buf[buf].astype(jnp.float32)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
+        return carry
 
-    @pl.when(b == nb - 1)
-    def _finalize():
-        l = l_ref[:]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n, live_column, None)
+
+    l = l_ref[:]
+    safe_l = jnp.where(l > 0.0, l, 1.0)
+    o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
 
 
 def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
                   interpret, kernel_name="paged_attention", live=None):
     """q [R, W, nH, hd], valid [R, W, nb*bsz] -> [R, W, nH, hd]. Decoding is
     the W == 1 case of the speculative verify. `live` = (lo, hi) as
-    `live_block_range` gives them; read from `valid` here when not given."""
+    `live_block_range` gives them, with or without `slot_schedule`'s
+    (start, nxt) after them; read from `valid` here when not given."""
     (k_pool, k_scales), (v_pool, v_scales) = split_pool(k_pool), split_pool(v_pool)
     R, W, nH, hd = q.shape
     _, _, bsz, D = k_pool.shape
@@ -259,50 +324,45 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
         qp[:, :, :, None, :] * jnp.asarray(onehot, q.dtype)[:, :, None]
     ).reshape(R, rows, D)
     mask = valid.astype(jnp.int32).reshape(R, W, nb, bsz).swapaxes(1, 2)
-    lo, hi = live_block_range(valid, bsz) if live is None else live
+    if live is None:
+        live = live_block_range(valid, bsz)
+    if len(live) == 2:
+        live = (*live, *slot_schedule(*live))
 
-    # a dead step reads the column the nearest live step reads: an index
-    # map that holds still issues no copy
-    def column(r, b, lo, hi):
-        return jnp.clip(b, lo[r], jnp.maximum(hi[r] - 1, lo[r]))
-
-    # the index map IS the page walk: block b of slot r comes straight
-    # from the pool page (layer, table entry) names, as the pool stores it
-    # (scale strips walk the same map); an empty slot names the null block
-    # whatever its table still holds
-    def page(r, b, bt, layer, lo, hi):
-        blk = jnp.where(hi[r] > lo[r], bt[r, column(r, b, lo, hi)], 0)
-        return layer[0], blk, 0, 0
-
-    kv_spec = pl.BlockSpec((None, None, bsz, D), page)
-    sc_spec = pl.BlockSpec((None, None, nKV, bsz), page)
+    # the pools stay where they are: the kernel copies the pages it scores
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [
-        pl.BlockSpec(
-            (1, 1, W, bsz),
-            lambda r, b, bt, layer, lo, hi: (r, column(r, b, lo, hi), 0, 0),
-        ),
-        pl.BlockSpec((1, rows, D), lambda r, b, *_: (r, 0, 0)),
+        pl.BlockSpec((1, nb, W, bsz), lambda r, *_: (r, 0, 0, 0)),
+        pl.BlockSpec((1, rows, D), lambda r, *_: (r, 0, 0)),
+    ]
+    scratch = [
+        pltpu.VMEM((rows, D), jnp.float32),
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((2, bsz, D), k_pool.dtype),
+        pltpu.VMEM((2, bsz, D), v_pool.dtype),
     ]
     if quant:
         sel = jnp.asarray(np.tile(onehot, (W, 1)).T[:, :, None])
         in_specs += [
-            pl.BlockSpec((nKV, rows, 1), lambda r, b, *_: (0, 0, 0)),
-            kv_spec, sc_spec, kv_spec, sc_spec,
+            pl.BlockSpec((nKV, rows, 1), lambda r, *_: (0, 0, 0)),
+            pool_spec, pool_spec, pool_spec, pool_spec,
         ]
         operands = (sel, k_pool, k_scales, v_pool, v_scales)
+        scratch += [
+            pltpu.VMEM((2, nKV, bsz), k_scales.dtype),
+            pltpu.VMEM((2, nKV, bsz), v_scales.dtype),
+        ]
     else:
-        in_specs += [kv_spec, kv_spec]
+        in_specs += [pool_spec, pool_spec]
         operands = (k_pool, v_pool)
+    scratch.append(pltpu.SemaphoreType.DMA((4 if quant else 2, 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(R, nb),
+        num_scalar_prefetch=6,
+        grid=(R,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, D), lambda r, b, *_: (r, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, D), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, rows, D), lambda r, *_: (r, 0, 0)),
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, sm_scale=sm_scale, quant=quant),
@@ -311,10 +371,11 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
         out_shape=jax.ShapeDtypeStruct((R, rows, D), q.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # the page copies chain from one slot into the next
+            dimension_semantics=("arbitrary",),
         ),
     )(block_table, jnp.asarray(layer, jnp.int32).reshape(1),
-      lo.astype(jnp.int32), hi.astype(jnp.int32), mask, q_exp, *operands)
+      *(x.astype(jnp.int32) for x in live), mask, q_exp, *operands)
     # each head keeps the hd lanes of its own kv head
     out = out.reshape(R, W, nHp, nKV, hd)
     return out[:, :, np.arange(nH), kv_of_head[:nH]]
@@ -332,7 +393,7 @@ def paged_attention_qlen(
     sm_scale: float | None = None,
     interpret: bool | None = None,
     kernel_name: str = "paged_attention",
-    live=None,  # (lo, hi) of `live_block_range`; read from `valid` if None
+    live=None,  # (lo, hi[, start, nxt]): the work list; read from `valid` if None
 ) -> jax.Array:
     """q_len>1 decode attention against the block table (speculative
     verify chunks): slot r's W queries (positions base..base+W-1) attend
@@ -381,7 +442,7 @@ def paged_attention(
     sm_scale: float | None = None,
     interpret: bool | None = None,
     kernel_name: str = "paged_attention",
-    live=None,  # (lo, hi) of `live_block_range`; read from `valid` if None
+    live=None,  # (lo, hi[, start, nxt]): the work list; read from `valid` if None
 ) -> jax.Array:
     """Decode attention of R single-token queries over paged KV.
 
@@ -391,9 +452,10 @@ def paged_attention(
     `[R, nH, hd]` in q's dtype. `kernel_name` names the Pallas call in a
     device trace (a mixed stack reads its window layers' ring under a name
     of its own). `live` is the slots' range of block columns that hold a
-    valid row, which the Pallas kernel neither fetches nor scores beyond
-    (the XLA impl gathers every column and takes no notice of it); a slot
-    outside `active` there reads as zeros.
+    valid row (`live_block_range`, and `slot_schedule`'s chain over it when
+    the caller has taken that too): the Pallas kernel walks those columns
+    and no others (the XLA impl gathers every column and takes no notice of
+    it); a slot outside `active` there reads as zeros.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])

@@ -9,6 +9,11 @@ its one row per slot and attends through the block table
 - the kernel's live range of block columns: the helper against a brute-force
   `any()`, the kernel under it equal to the bit to the kernel that walks
   every column, and a dead column's pages never read;
+- the kernel's walk over the live (slot, column) pairs alone: the chain
+  (`slot_schedule`) against a brute-force list, and the kernel equal to the
+  bit to the `(R, nb)` grid it replaced (kept below as the reference) over
+  empty slots at every place in the chain, one deep slot, and random
+  batches;
 - the decode step against the trainer's `forward` over prompt + token (what
   a decode log-probability has to equal for PPO), and its write contract:
   the row at `(layer, bt[r, p // bsz], p % bsz)` holds the new K/V, an
@@ -46,12 +51,13 @@ from areal_tpu.models.qwen2 import (
     prefill,
     verify_step_paged,
 )
-from areal_tpu.ops.kv_quant import quantize_kv
+from areal_tpu.ops.kv_quant import quantize_kv, split_pool
 from areal_tpu.ops.paged_attention import (
     live_block_range,
     paged_attention,
     paged_attention_qlen,
     resolve_impl,
+    slot_schedule,
 )
 
 TINY = ModelConfig(
@@ -196,21 +202,26 @@ _RAGGED = [(0, False, "null"), (5, True, "own"), (_RNB * _RB - 4, True, "own"),
            (100, False, "own"), (3 * _RB + 7, True, "own")]
 
 
+def _stored_pools(rng, n_blocks, bsz, nKV, hd, int8=False, dtype=jnp.bfloat16):
+    """(k pool, v pool) as the engine stores them, every layer stacked:
+    `[L, n_blocks, bsz, nKV*hd]`, or (int8 rows, f32 scales `[L, n_blocks,
+    nKV, bsz]`)."""
+    shape = (_LAYERS, n_blocks, bsz, nKV, hd)
+    kp = jnp.asarray(rng.standard_normal(shape), dtype)
+    vp = jnp.asarray(rng.standard_normal(shape), dtype)
+    rows = lambda a: a.reshape(_LAYERS, n_blocks, bsz, nKV * hd)  # noqa: E731
+    if not int8:
+        return rows(kp), rows(vp)
+    (kq, ks), (vq, vs) = quantize_kv(kp), quantize_kv(vp)
+    return (rows(kq), jnp.swapaxes(ks, -1, -2)), (rows(vq), jnp.swapaxes(vs, -1, -2))
+
+
 def _ragged_slots(rng, W=1, int8=False, window=None, dtype=jnp.bfloat16):
     """(q [R, W, nH, hd], k pool, v pool, table, valid [R, W, span], active)
     over `_RAGGED`'s slots; `window` adds a sliding window to the mask."""
     R, nH, nKV, hd = len(_RAGGED), 8, 2, 16
     n_blocks = 1 + R * _RNB
-    shape = (_LAYERS, n_blocks, _RB, nKV, hd)
-    kp = jnp.asarray(rng.standard_normal(shape), dtype)
-    vp = jnp.asarray(rng.standard_normal(shape), dtype)
-    rows = lambda a: a.reshape(_LAYERS, n_blocks, _RB, nKV * hd)  # noqa: E731
-    if int8:
-        (kq, ks), (vq, vs) = quantize_kv(kp), quantize_kv(vp)
-        kp = (rows(kq), jnp.swapaxes(ks, -1, -2))
-        vp = (rows(vq), jnp.swapaxes(vs, -1, -2))
-    else:
-        kp, vp = rows(kp), rows(vp)
+    kp, vp = _stored_pools(rng, n_blocks, _RB, nKV, hd, int8, dtype)
     q = jnp.asarray(rng.standard_normal((R, W, nH, hd)), dtype)
     bt = rng.permutation(np.arange(1, n_blocks)).astype(np.int32).reshape(R, _RNB)
     for r, (_, _, table) in enumerate(_RAGGED):
@@ -379,6 +390,246 @@ def test_dead_columns_are_never_read(cpu_devices, int8):
     # the kernel that walks every column does read them
     walked = _read(q, poison(kp), poison(vp), bt, valid, _all_live(*bt.shape))
     assert np.isnan(np.asarray(walked.astype(jnp.float32))[on]).any()
+
+
+# -- the walk over the live (slot, column) pairs ------------------------------
+# The kernel's grid is the slots; a grid step loops over its slot's live
+# columns and the page copies chain from one slot into the next. What it
+# replaced, kept here as the reference: a grid over every (slot, column),
+# a step outside its slot's range doing nothing. Same body, same order over
+# a slot's columns: equal to the bit.
+
+
+def _grid_walk(q, kp, vp, bt, valid, live):
+    """The `(R, nb)`-grid range kernel (PR 31's), interpret mode: q
+    [R, W, nH, hd] with nH a multiple of 8, valid [R, W, nb*bsz]."""
+    import functools
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (kd, ks), (vd, vs) = split_pool(kp), split_pool(vp)
+    quant = ks is not None
+    R, W, nH, hd = q.shape
+    bsz, D = kd.shape[2:]
+    nb, nKV, rows = bt.shape[1], D // hd, W * nH
+    kv_of_head = np.arange(nH) // (nH // nKV)
+    onehot = np.zeros((nH, nKV), np.float32)
+    onehot[np.arange(nH), kv_of_head] = 1.0
+    q_exp = (q[:, :, :, None, :] * jnp.asarray(onehot, q.dtype)[:, :, None]).reshape(R, rows, D)
+    mask = valid.astype(jnp.int32).reshape(R, W, nb, bsz).swapaxes(1, 2)
+    sm_scale = 1.0 / np.sqrt(hd)
+
+    def kernel(bt_ref, lo_ref, hi_ref, mask_ref, q_ref, *refs):
+        if quant:
+            sel_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        else:
+            k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        r, b = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(b == 0)
+        def _init():
+            m_ref[:] = jnp.full_like(m_ref, -1e30)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        def head_rows(sc_ref):
+            sc = sc_ref[...]
+            return sum(sel_ref[h] * sc[h : h + 1, :] for h in range(sc.shape[0]))
+
+        @pl.when((lo_ref[r] <= b) & (b < hi_ref[r]))
+        def _live_column():
+            s = jax.lax.dot_general(
+                q_ref[0].astype(jnp.float32), k_ref[...].astype(jnp.float32),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            s = s * sm_scale
+            if quant:
+                s = s * head_rows(ks_ref)
+            m2 = jnp.broadcast_to(
+                mask_ref[0, 0][:, None, :], (W, rows // W, bsz)).reshape(rows, bsz)
+            s = jnp.where(m2 != 0, s, -1e30)
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            p = jnp.where(m_new > -1e30 / 2, p, 0.0)
+            l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[:] = m_new
+            if quant:
+                p = p * head_rows(vs_ref)
+            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+                p, v_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        @pl.when(b == nb - 1)
+        def _finalize():
+            l = l_ref[:]
+            o_ref[0] = (acc_ref[:] / jnp.where(l > 0.0, l, 1.0)).astype(o_ref.dtype)
+
+    def column(r, b, lo, hi):
+        return jnp.clip(b, lo[r], jnp.maximum(hi[r] - 1, lo[r]))
+
+    def page(r, b, bt, lo, hi):
+        return _LAYER, jnp.where(hi[r] > lo[r], bt[r, column(r, b, lo, hi)], 0), 0, 0
+
+    kv_spec = pl.BlockSpec((None, None, bsz, D), page)
+    sc_spec = pl.BlockSpec((None, None, nKV, bsz), page)
+    in_specs = [
+        pl.BlockSpec((1, 1, W, bsz), lambda r, b, bt, lo, hi: (r, column(r, b, lo, hi), 0, 0)),
+        pl.BlockSpec((1, rows, D), lambda r, b, *_: (r, 0, 0)),
+    ]
+    if quant:
+        sel = jnp.asarray(np.tile(onehot, (W, 1)).T[:, :, None])
+        in_specs += [pl.BlockSpec((nKV, rows, 1), lambda r, b, *_: (0, 0, 0)),
+                     kv_spec, sc_spec, kv_spec, sc_spec]
+        operands = (sel, kd, ks, vd, vs)
+    else:
+        in_specs += [kv_spec, kv_spec]
+        operands = (kd, vd)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(R, nb), in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, rows, D), lambda r, b, *_: (r, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((R, rows, D), q.dtype), interpret=True,
+    )(bt, *(x.astype(jnp.int32) for x in live[:2]), mask, q_exp, *operands)
+    out = out.reshape(R, W, nH, nKV, hd)
+    return out[:, :, np.arange(nH), kv_of_head]
+
+
+def _columns_batch(rng, cols, lo_cols=None, W=1, int8=False, bsz=_RB):
+    """A batch whose slot r holds the live columns `[lo_cols[r], cols[r])`
+    (none, and not active, where `cols[r] == 0`), every other slot ending
+    mid-page, over tables that name blocks everywhere."""
+    cols = np.asarray(cols)
+    R, nb = len(cols), max(int(cols.max()), 2)
+    nH, nKV, hd = 8, 2, 16
+    n_blocks = 1 + R * nb
+    kp, vp = _stored_pools(rng, n_blocks, bsz, nKV, hd, int8)
+    q = jnp.asarray(rng.standard_normal((R, W, nH, hd)), jnp.bfloat16)
+    bt = rng.permutation(np.arange(1, n_blocks)).astype(np.int32).reshape(R, nb)
+    last = np.maximum(cols * bsz - 1 - rng.integers(0, bsz - W + 1, R), W - 1)
+    pos = (last - (W - 1))[:, None] + np.arange(W)[None, :]
+    s = np.arange(nb * bsz)[None, None, :]
+    valid = s <= pos[:, :, None]
+    if lo_cols is not None:
+        valid &= s >= (np.asarray(lo_cols) * bsz)[:, None, None]
+    active = cols > 0
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(valid), jnp.asarray(active)
+
+
+# where the empty slots sit in the chain, and how deep the others are
+_CHAINS = {
+    "empty_first": [0, 0, 3, 1, 2],
+    "empty_last": [2, 1, 4, 0, 0],
+    "empty_between": [1, 0, 0, 0, 5, 0, 2],
+    "one_live_slot": [0, 0, 6, 0],
+    "all_empty": [0, 0, 0],
+    "one_deep_slot": [1, 1, 10, 1, 1, 1],
+    "all_live": [4, 4, 4, 4],
+    "single_columns": [1, 1, 1, 1, 1],
+}
+
+
+@pytest.mark.parametrize("chain", list(_CHAINS))
+def test_pair_walk_equals_the_grid_walk(cpu_devices, chain):
+    cols = np.array(_CHAINS[chain])
+    q, kp, vp, bt, valid, active = _columns_batch(np.random.default_rng(21), cols)
+    live = live_block_range(valid, _RB, active)
+    assert (np.asarray(live[1]) - np.asarray(live[0])).tolist() == cols.tolist()
+    out = _read(q, kp, vp, bt, valid, (*live, *slot_schedule(*live)))
+    ref = _grid_walk(q, kp, vp, bt, valid, live)
+    np.testing.assert_array_equal(_bits(out), _bits(ref))
+    on = np.asarray(active)
+    assert not np.asarray(out.astype(jnp.float32))[~on].any()
+    if on.any():
+        every = _read(q, kp, vp, bt, valid, _all_live(*bt.shape))
+        np.testing.assert_array_equal(_bits(out)[on], _bits(every)[on])
+        # and within the usual error of the XLA read
+        xla = paged_attention_qlen(q, kp, vp, bt, valid, jnp.int32(_LAYER), impl="xla")
+        np.testing.assert_allclose(
+            np.asarray(out.astype(jnp.float32))[on], np.asarray(xla.astype(jnp.float32))[on],
+            atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_pair_walk_equals_the_grid_walk_on_random_batches(cpu_devices, seed):
+    """Random depths, windows (`lo > 0`), empty slots, both pools and both
+    widths: the chain never names a wrong page or a stale buffer."""
+    rng = np.random.default_rng(100 + seed)
+    R, nb = int(rng.integers(2, 9)), int(rng.integers(2, 8))
+    cols = rng.integers(0, nb + 1, R) * (rng.random(R) < 0.75)
+    cols[rng.integers(R)] = nb
+    lo_cols = np.where(rng.random(R) < 0.5, rng.integers(0, nb, R), 0)
+    lo_cols = np.minimum(lo_cols, np.maximum(cols - 1, 0))
+    W, int8 = (1, 3)[seed % 2], seed % 3 == 0
+    q, kp, vp, bt, valid, active = _columns_batch(rng, cols, lo_cols, W=W, int8=int8)
+    live = live_block_range(valid, _RB, active)
+    lo, hi = (np.asarray(x) for x in live)
+    assert lo.tolist() == np.where(cols > 0, lo_cols, 0).tolist() and hi.tolist() == cols.tolist()
+    out = _read(q, kp, vp, bt, valid, live)  # the op chains the range it is given
+    ref = _grid_walk(q, kp, vp, bt, valid, live)
+    np.testing.assert_array_equal(_bits(out), _bits(ref))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_pair_walk_never_reads_a_page_outside_the_pairs(cpu_devices, int8):
+    """Empty slots inside the chain with tables that name blocks, every page
+    no live pair names NaN: the copy a slot's last column starts is the next
+    LIVE slot's first page, never an empty slot's."""
+    cols = np.array([0, 2, 0, 0, 1, 3, 0])
+    lo_cols = np.array([0, 1, 0, 0, 0, 2, 0])
+    q, kp, vp, bt, valid, active = _columns_batch(
+        np.random.default_rng(22), cols, lo_cols, W=2, int8=int8)
+    live = live_block_range(valid, _RB, active)
+    named = {int(np.asarray(bt)[r, c]) for r in range(len(cols))
+             for c in range(lo_cols[r], cols[r])}
+    n_blocks = (kp[0] if int8 else kp).shape[1]
+    dead = np.array(sorted(set(range(n_blocks)) - named))
+
+    def poison(pool):
+        if int8:
+            return pool[0], pool[1].at[:, dead].set(jnp.nan)
+        return pool.at[:, dead].set(jnp.nan)
+
+    clean = _read(q, kp, vp, bt, valid, live)
+    out = _read(q, poison(kp), poison(vp), bt, valid, live)
+    assert np.isfinite(np.asarray(out.astype(jnp.float32))).all()
+    np.testing.assert_array_equal(_bits(out), _bits(clean))
+
+
+@pytest.mark.parametrize("pattern", ["random", "all_empty", "all_live", "ends_empty"])
+def test_slot_schedule_against_brute_force(cpu_devices, pattern):
+    """`start[r]`: the live columns before slot r, so the walk's i-th pair is
+    column `i - start[r]` of the slot it falls in; `nxt[r]`: the next slot
+    with a live column, R if none. Against the list of pairs, in order."""
+    rng = np.random.default_rng(23)
+    R, nb = 11, 6
+    lo = rng.integers(0, nb, R)
+    hi = np.minimum(lo + rng.integers(0, 4, R), nb)
+    if pattern == "all_empty":
+        hi = lo.copy()
+    elif pattern == "all_live":
+        lo, hi = np.zeros(R, int), np.full(R, nb)
+    elif pattern == "ends_empty":
+        hi[[0, 1, R - 1]] = lo[[0, 1, R - 1]]
+    start, nxt = slot_schedule(jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32))
+    assert start.dtype == nxt.dtype == jnp.int32 and start.shape == nxt.shape == (R,)
+    pairs = [(r, c) for r in range(R) for c in range(lo[r], hi[r])]
+    for r in range(R):
+        assert int(start[r]) == sum(1 for s, _ in pairs if s < r), r
+        later = [s for s, _ in pairs if s > r]
+        assert int(nxt[r]) == (min(later) if later else R), r
+    # the chain, followed from the first live slot, lists every pair once
+    walked, r = [], min((s for s, _ in pairs), default=R)
+    while r < R:
+        walked += [(r, int(lo[r]) + j) for j in range(int(hi[r] - lo[r]))]
+        assert len(walked) - int(hi[r] - lo[r]) == int(start[r])
+        r = int(nxt[r])
+    assert walked == pairs
 
 
 @pytest.mark.parametrize("W", [1, 3], ids=["decode", "verify3"])
@@ -793,22 +1044,29 @@ def test_block_table_upload_dirty_tracking(cpu_devices):
     assert m["block_table_uploads_total"] >= 1
 
 
-def test_prewarm_covers_paged_variants(cpu_devices):
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_prewarm_covers_paged_variants(cpu_devices, impl):
     """Prewarm on a paged engine must ghost-compile the paged chunk
     variants (and the patch fn) so the first overlapped dispatch never
-    traces: after prewarm, serving a request compiles nothing new."""
-    eng = _engine("xla")
+    traces: after prewarm, serving requests of ragged lengths compiles
+    nothing new. A chunk program is keyed by its depth `nb` and by nothing
+    that depends on how ragged the batch is (the kernel reads each slot's
+    columns off the device), so one program a depth serves them all."""
+    eng = _engine(impl)
     try:
-        eng.prewarm(prompt_len=8, new_tokens=4, sampler_top_ps=(1.0,))
+        eng.prewarm(prompt_len=8, new_tokens=40, sampler_top_ps=(1.0,))
         compiled = set(eng._chunk_fns)
         assert compiled, "prewarm compiled no chunk variants"
         assert eng._patch_fn is not None
+        assert len({k[:2] for k in compiled}) == 1 and len({k[2] for k in compiled}) == len(compiled)
 
         async def main():
-            g = GenerationHyperparameters(greedy=True, max_new_tokens=4)
-            return await eng.agenerate(
-                ModelRequest(input_ids=[3, 1, 4, 1, 5, 9, 2, 6], gconfig=g)
-            )
+            # one request a page deep beside three of a few tokens
+            return await asyncio.gather(*(
+                eng.agenerate(ModelRequest(
+                    input_ids=[3, 1, 4, 1, 5, 9, 2, 6][:n],
+                    gconfig=GenerationHyperparameters(greedy=True, max_new_tokens=new)))
+                for n, new in ((8, 40), (3, 4), (5, 9), (2, 1))))
 
         asyncio.run(main())
         assert set(eng._chunk_fns) == compiled, (

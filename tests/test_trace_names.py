@@ -240,8 +240,9 @@ def test_moe_engine_counts_expert_load(moe_programs):
 
 
 def test_engine_counts_live_block_columns(cpu_devices):
-    """`paged_block_columns_live_total` / `_visited_total` against a NumPy
-    count over the chunks the engine dispatched: two requests, one of them
+    """`paged_block_columns_live_total` / `_visited_total` (what the paged
+    kernel walks: its live columns, and a step for each slot with none)
+    against a NumPy count over the chunks the engine dispatched: two requests, one of them
     past its first page, in four slots under a two-column table."""
     from areal_tpu.engine.jax_decode import JaxDecodeEngine
 
@@ -281,7 +282,8 @@ def test_engine_counts_live_block_columns(cpu_devices):
     for active, lengths, nb in chunks:
         last = lengths[active] + n_chunk - 1  # the chunk's last query
         live += int(np.minimum(last // bsz + 1, nb).sum())
-        visited += R * nb
+        # a step a live column, and one a slot that has none
+        visited += int(np.minimum(last // bsz + 1, nb).sum()) + R - int(active.sum())
     assert m["paged_block_columns_visited_total"] == visited
     assert m["paged_block_columns_live_total"] == live
     # one and two live columns were both seen, and empty slots none
@@ -338,12 +340,19 @@ def _mosaic_kernels(hlo: str) -> list[str]:
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
-def _one_paged_kernel(hlo: str) -> str:
-    """The program's only Mosaic call, and no loop around it: the slots'
-    live ranges ride as scalar-prefetch vectors of ONE kernel over the
-    `(slots, nb)` grid (a range that varied a grid bound would loop)."""
+def _one_paged_kernel(hlo: str, slots: int) -> str:
+    """The program's only Mosaic call, and no loop around it: ONE kernel over
+    a grid of the slots, whose work list (each slot's live range of block
+    columns and the chain from slot to slot: four `[slots]` vectors after
+    the table and the layer index) rides as scalar-prefetch operands, the
+    walk over a slot's columns inside the kernel (a list that varied a grid
+    bound would loop in the program, or key it by its length)."""
+    calls = [ln for ln in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
     kernels = _mosaic_kernels(hlo)
     assert len(kernels) == 1 and " while(" not in hlo, kernels
+    operands = calls[0].split("operand_layout_constraints={")[1].split("}}")[0]
+    vec = f"s32[{slots}]{{0}}"
+    assert operands.split(", ")[1:6] == ["s32[1]{0}"] + [vec] * 4, operands
     return kernels[0]
 
 
@@ -365,7 +374,72 @@ def test_paged_kernel_is_named_at_the_1p5b_head_shape(one_chip):
         ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_), ((), jnp.int32))
     assert "tpu_custom_call" in hlo
     assert "%paged_attention" in hlo and "%layer" not in hlo
-    assert _one_paged_kernel(hlo).startswith("paged_attention")
+    assert _one_paged_kernel(hlo, R).startswith("paged_attention")
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_verify_kernel_is_one_call_at_the_1p5b_head_shape(one_chip, int8):
+    """The speculative verify's five query positions a slot through the same
+    kernel, over the bf16 pool and over the int8 pool with its scale strips
+    (copied by the same chain): still one Mosaic call, no loop around it."""
+    from areal_tpu.ops.paged_attention import paged_attention_qlen
+
+    R, W, nH, nKV, hd, bsz, nb, L = 128, 5, 12, 2, 128, 128, 10, 28
+    n_blocks = R * nb + 1
+    pool = [((L, n_blocks, bsz, nKV * hd), jnp.int8 if int8 else jnp.bfloat16)]
+    if int8:
+        pool.append(((L, n_blocks, nKV, bsz), jnp.float32))
+
+    def step(q, bt, valid, li, lo, hi, *pools):
+        kp, vp = pools[: len(pool)], pools[len(pool):]
+        kp, vp = (kp, vp) if int8 else (kp[0], vp[0])
+        return paged_attention_qlen(q, kp, vp, bt, valid, li, impl="pallas",
+                                    interpret=False, live=(lo, hi))
+
+    hlo = _compile(
+        step, one_chip, ((R, W, nH, hd), jnp.bfloat16), ((R, nb), jnp.int32),
+        ((R, W, nb * bsz), jnp.bool_), ((), jnp.int32), ((R,), jnp.int32),
+        ((R,), jnp.int32), *pool, *pool)
+    assert _one_paged_kernel(hlo, R).startswith("paged_attention")
+
+
+@pytest.mark.parametrize("W", [1, 5], ids=["decode", "verify5"])
+def test_model_step_holds_one_paged_kernel_at_the_1p5b_head_shape(one_chip, monkeypatch, W):
+    """The decode step and the verify step, two stacked layers at the 1.5B's
+    widths, lowered with the kernel through Mosaic: the layer loop holds ONE
+    Mosaic call, `%paged_attention`, with the step's work list (taken once,
+    outside the layer loop) among its operands."""
+    from areal_tpu.models.qwen2 import decode_step_paged, param_shapes, verify_step_paged
+    from areal_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_default_interpret", lambda: False)
+    L, R, nb, bsz = 2, 128, 10, 128
+    cfg = ModelConfig(
+        vocab_size=151936, hidden_size=1536, intermediate_size=8960, num_hidden_layers=L,
+        num_attention_heads=12, num_key_value_heads=2, dtype="bfloat16",
+        param_dtype="bfloat16")
+    bf = jnp.bfloat16
+    arg = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(lambda s: arg(s, bf), param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    pool = arg((L, R * nb + 1, bsz, 2 * 128), bf)
+
+    def step(params, kp, vp, bt, tokens, positions, active):
+        fn = decode_step_paged if W == 1 else verify_step_paged
+        return fn(params, tokens, positions, kp, vp, bt, cfg, active=active,
+                  attn_impl="pallas")
+
+    hlo = jax.jit(step, donate_argnums=(1, 2)).trace(
+        params, pool, pool, arg((R, nb), jnp.int32),
+        arg((R,) if W == 1 else (R, W), jnp.int32), arg((R,), jnp.int32),
+        arg((R,), jnp.bool_),
+    ).lower(lowering_platforms=("tpu",)).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "%paged_attention" in calls[0], _mosaic_kernels(hlo)
+    operands = calls[0].split("operand_layout_constraints={")[1].split("}}")[0]
+    assert operands.split(", ")[2:6] == [f"s32[{R}]{{0}}"] * 4, operands
+    # one loop, the layer scan: the walk over a slot's columns is the kernel's
+    assert hlo.count(" while(") == 1
 
 
 def test_paged_kernel_is_named_at_olmoes_head_shape(one_chip):
@@ -386,7 +460,7 @@ def test_paged_kernel_is_named_at_olmoes_head_shape(one_chip):
         ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_), ((), jnp.int32),
         ((R,), jnp.int32), ((R,), jnp.int32))
     assert "tpu_custom_call" in hlo and "%paged_attention" in hlo
-    assert _one_paged_kernel(hlo).startswith("paged_attention")
+    assert _one_paged_kernel(hlo, R).startswith("paged_attention")
 
 
 @pytest.mark.parametrize("kind,layers,blocks,nb", [
@@ -412,7 +486,7 @@ def test_paged_kernels_are_named_apart_at_kexaones_head_shape(one_chip, kind, la
     assert "tpu_custom_call" in hlo
     assert ("%paged_attention_window" in hlo) == (kind == "window")
     assert ("%paged_attention." in hlo or "%paged_attention " in hlo) == (kind == "full")
-    assert _one_paged_kernel(hlo).startswith(_PAGED_KERNELS[kind])
+    assert _one_paged_kernel(hlo, R).startswith(_PAGED_KERNELS[kind])
 
 
 def test_gdn_step_kernel_is_named_at_qwen3_nexts_state_shape(one_chip):
@@ -459,7 +533,7 @@ def test_paged_kernel_is_named_at_qwen3_nexts_head_shape(one_chip):
         ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_),
         ((R,), jnp.int32), ((R,), jnp.int32))
     assert "tpu_custom_call" in hlo and "%paged_attention" in hlo
-    assert _one_paged_kernel(hlo).startswith("paged_attention")
+    assert _one_paged_kernel(hlo, R).startswith("paged_attention")
 
 
 @pytest.mark.parametrize("tokens", [64, 2048], ids=["decode_step", "batched_prefill"])

@@ -236,14 +236,15 @@ def _maxerr(a, b, rows=None):
 
 
 def paged_ragged_case(mix, W, int8, nH, nKV, hd, R=16, nb=10, L=2, layer=1):
-    """The kernel under its slots' live ranges of block columns: slots of
+    """The kernel's walk over the live (slot, block column) pairs: slots of
     ragged depth under one `nb`, some not active with tables that still
     name blocks. Equal to the bit, on the active slots, to the kernel told
     every column is live (what it computed before it had a range), within
     the usual error of the XLA read, zeros on the rest. `mix`: "empty"
     (two slots of sixteen active), "30%" (about 30% of the block columns
     live: the dense rollout cell's share), "all" (every slot at full depth:
-    nothing to skip, the range's own price)."""
+    nothing to skip, the walk's own price), "one-deep" (one slot at `nb`,
+    the rest one column: the batch a grid of slots x `nb` served worst)."""
     n_blocks = R * nb + 1
     keys = jax.random.split(jax.random.PRNGKey(5), 3)
     kp, vp = _paged_pool(keys, L, n_blocks, nKV, hd, int8)
@@ -255,8 +256,10 @@ def paged_ragged_case(mix, W, int8, nH, nKV, hd, R=16, nb=10, L=2, layer=1):
         base, active = np.full(R, span - W), np.ones(R, bool)
     elif mix == "empty":
         base, active = 40 + 97 * r, (r == 3) | (r == 12)
-    else:  # active slots hold 1-10 columns, a deep one sets nb
-        base = np.where(r == 5, span - W, 17 + 61 * r)
+    elif mix == "one-deep":
+        base, active = np.where(r == 5, span - W, 9 + 7 * r), np.ones(R, bool)
+    else:  # active slots hold 1 to nb columns, a deep one sets nb
+        base = np.where(r == 5, span - W, (17 + 61 * r) * nb // 10)
         active = r % 3 != 1
     pos = jnp.asarray(base)[:, None] + jnp.arange(W)[None, :]
     valid = jnp.arange(span)[None, None, :] <= pos[:, :, None]
@@ -538,19 +541,20 @@ def cases():
         f"paged_attention_window bf16 64/8/128 ring of 2 pages page={PAGE}",
         "default for a mixed stack", paged_ring_case,
     ))
-    # the slots' live ranges of block columns, at the three rollout cells'
-    # widths: mostly empty slots, the dense cell's 30% of columns live,
-    # nothing to skip
-    for (nH, nKV, hd), other in (
-        ((12, 2, 128), (1, True)), ((16, 16, 128), (5, False)), ((64, 8, 128), (5, True)),
+    # the walk over the live (slot, column) pairs, at the four rollout
+    # cells' widths and depths: mostly empty slots, the dense cell's 30% of
+    # columns live, nothing to skip, one deep slot among shallow ones
+    for (nH, nKV, hd), other, nb in (
+        ((12, 2, 128), (1, True), 10), ((16, 16, 128), (5, False), 10),
+        ((64, 8, 128), (5, True), 10), ((16, 2, 256), (5, False), 64),
     ):
-        for mix, W, int8 in (("empty", 1, False), ("30%", 1, False), ("all", 1, False),
-                             ("30%", *other)):
+        mixes = [("30%", 1, False), ("all", 1, False), ("one-deep", 1, False), ("30%", *other)]
+        for mix, W, int8 in ([("empty", 1, False)] if nb == 10 else []) + mixes:
             out.append((
                 f"paged_attention live range, {mix} W={W} "
-                f"{'int8' if int8 else 'bf16'} {nH}/{nKV}/{hd} nb=10",
+                f"{'int8' if int8 else 'bf16'} {nH}/{nKV}/{hd} nb={nb}",
                 "default",
-                lambda a=(mix, W, int8, nH, nKV, hd): paged_ragged_case(*a),
+                lambda a=(mix, W, int8, nH, nKV, hd), nb=nb: paged_ragged_case(*a, nb=nb),
             ))
     # Qwen3-Next: the state update of a linear layer, and its gated full
     # attention's head shape through the paged kernel (decode and ragged)
