@@ -681,7 +681,6 @@ class InferenceEngineConfig:
     queue_size: None | int = None
     consumer_batch_size: int = 1
     max_head_offpolicyness: int = 0
-    enable_rollout_tracing: bool = False
     check_trajectory_format: bool = False
     schedule_policy: str = "round_robin"
     setup_timeout: float = 120.0

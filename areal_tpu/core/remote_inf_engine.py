@@ -1009,6 +1009,10 @@ class RemoteInfEngine(InferenceEngine):
             dataloader, workflow, workflow_builder, should_accept
         )
 
+    def get_loop_metrics(self) -> dict:
+        """The loop's counters (`WorkflowExecutor.get_metrics`)."""
+        return self._executor.get_metrics()
+
     def pause(self):
         self._executor.pause()
 

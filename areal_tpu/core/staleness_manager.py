@@ -35,6 +35,11 @@ class StalenessManager:
 
     def get_capacity(self, current_version: int) -> int:
         """Available rollout slots (may be negative when over capacity)."""
+        return self.gate(current_version)[0]
+
+    def gate(self, current_version: int) -> tuple[int, str]:
+        """(capacity, the term that binds it: "staleness" or "concurrency").
+        With capacity <= 0 the gate is closed, and that term closed it."""
         with self.lock:
             concurrency_capacity = (
                 max(1, self.max_concurrent_rollouts) - self.rollout_stat.running
@@ -45,7 +50,9 @@ class StalenessManager:
                 * max(1, self.consumer_batch_size)
                 - sample_cnt
             )
-            return min(concurrency_capacity, staleness_capacity)
+        if staleness_capacity <= concurrency_capacity:
+            return staleness_capacity, "staleness"
+        return concurrency_capacity, "concurrency"
 
     def on_rollout_submitted(self) -> None:
         with self.lock:

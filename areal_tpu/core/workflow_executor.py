@@ -36,6 +36,36 @@ logger = logging.getLogger("workflow_executor")
 ROLLOUT_POLL_WAIT_TIME = 0.4
 
 
+class _Periods:
+    """Time in periods that begin in one call and end in a later one (the
+    gate closed, admission paused): seconds by kind, and one detached span a
+    period. Set on the trainer's thread; read from any."""
+
+    def __init__(self, span_name: str, clock: Callable[[], float]):
+        self.span_name = span_name
+        self.count = 0
+        self._clock = perf_tracer.StateClock(clock=clock)
+        self._kind: str | None = None
+        self._span: perf_tracer.span | None = None
+
+    def set(self, kind: str | None, **ids) -> None:
+        """The period of `kind` runs from now (None: no period runs)."""
+        if kind == self._kind:
+            return
+        if self._clock.switch(kind) is None:
+            self.count += 1
+        self._kind = kind
+        if self._span is not None:
+            self._span.close()
+        self._span = (
+            perf_tracer.span(self.span_name, **ids).open() if kind is not None else None
+        )
+
+    def secs(self, kind: str) -> float:
+        """Seconds of `kind` so far, the running period's included."""
+        return self._clock.read().get(kind, 0.0)
+
+
 def check_trajectory_format(traj: dict[str, Any]) -> None:
     """Validate a workflow result batch (parity: workflow_executor.py:27).
 
@@ -66,6 +96,8 @@ def check_trajectory_format(traj: dict[str, Any]) -> None:
 
 
 class WorkflowExecutor:
+    _clock = staticmethod(time.monotonic)  # a test's fake clock goes here
+
     def __init__(
         self,
         config: InferenceEngineConfig,
@@ -88,6 +120,15 @@ class WorkflowExecutor:
         self._version = 0
         self._paused = False
         self._consecutive_failures = 0
+        # the loop's time and counts (get_metrics); trainer thread only
+        self._gate_closed = _Periods("rollout/gate_closed", self._clock)
+        self._pause_periods = _Periods("rollout/paused", self._clock)
+        self._metrics = dict.fromkeys(
+            ("prepare_batch_secs_total", "episode_secs_total", "pending_secs_total"), 0.0
+        ) | dict.fromkeys(
+            ("batches_prepared_total", "episodes_finished_total", "consumed_samples_total",
+             "consumed_staleness_versions_total", "consumed_staleness_max"), 0
+        )
         # exactly-once sample accounting: rollout-id issuance, consumed-id
         # dedup, and the consumed-batch WAL (core/sample_ledger.py)
         self.ledger = SampleLedger()
@@ -110,10 +151,12 @@ class WorkflowExecutor:
     def pause(self) -> None:
         """Stop admitting new rollouts (weight-update window)."""
         self._paused = True
+        self._pause_periods.set("paused", version=self._version)
         self.runner.pause()
 
     def resume(self) -> None:
         self._paused = False
+        self._pause_periods.set(None)
         self.runner.resume()
 
     @property
@@ -139,49 +182,49 @@ class WorkflowExecutor:
         rid = self.ledger.new_rid() if rollout_id is None else int(rollout_id)
         try:
             self._pending_inputs.put_nowait(
-                (rid, data, workflow, workflow_builder, should_accept)
+                (rid, data, workflow, workflow_builder, should_accept, self._clock())
             )
         except queue.Full:
             raise RuntimeError("workflow executor input queue full") from None
 
     def _launch_one(self, item) -> None:
-        rid, data, workflow, workflow_builder, should_accept = item
+        rid, data, workflow, workflow_builder, should_accept, t_submit = item
         if workflow is None:
             workflow = workflow_builder()
-        sm = self.staleness_manager
         engine = self.engine
-        tracing = self.config.enable_rollout_tracing
         check_format = self.config.check_trajectory_format
+        clock = self._clock
+        t_launch = clock()
+        # after the fact and not a `with`: the wait began in another call,
+        # and episodes interleave on the runner's one thread
+        perf_tracer.record("rollout/pending", t_submit, t_launch, rid=rid)
 
         async def episode():
-            # after the fact and not a `with`: episodes interleave on the
-            # runner's one thread
-            t0 = time.monotonic()
+            t0 = clock()
             traj = await workflow.arun_episode(engine, data)
-            perf_tracer.record("rollout/episode", t0, time.monotonic(), rid=rid)
+            t1 = clock()
+            perf_tracer.record("rollout/episode", t0, t1, rid=rid)
             if traj is not None and check_format:
                 check_trajectory_format(traj)
             if traj is not None and should_accept is not None and not should_accept(traj):
                 traj = None
-            return rid, traj
+            return rid, traj, (t_launch - t_submit, t1 - t0)
 
-        task_id = self.runner.submit(episode)
-        sm.on_rollout_submitted()
-        if tracing:
-            logger.info(f"submitted rollout task {task_id} (rid {rid})")
+        self.runner.submit(episode)
+        self.staleness_manager.on_rollout_submitted()
 
     def _admit_pending(self) -> None:
         """Move pending submissions into the runner within capacity."""
         if self._paused:
             return
-        capacity = self.staleness_manager.get_capacity(self._version)
-        while capacity > 0:
-            try:
-                item = self._pending_inputs.get_nowait()
-            except queue.Empty:
-                return
-            self._launch_one(item)
-            capacity -= 1
+        capacity, by = self.staleness_manager.gate(self._version)
+        # (this thread alone takes from the queue)
+        while capacity > 0 and not self._pending_inputs.empty():
+            self._launch_one(self._pending_inputs.get_nowait())
+            capacity -= 1  # of both terms: the one that binds stays
+        # closed: work is pending and none may start, and `by` is why
+        held = not self._pending_inputs.empty()
+        self._gate_closed.set(by if held else None, by=by)
 
     def _collect(self) -> None:
         results = self.runner.poll_results()
@@ -226,11 +269,13 @@ class WorkflowExecutor:
             return
         # any completed episode (accepted or rejected) breaks the streak
         self._consecutive_failures = 0
-        rid, traj = tr.result
+        rid, traj, (pending_s, episode_s) = tr.result
+        m = self._metrics
+        m["episodes_finished_total"] += 1
+        m["pending_secs_total"] += pending_s
+        m["episode_secs_total"] += episode_s
         if traj is None:
             sm.on_rollout_rejected()
-            if self.config.enable_rollout_tracing:
-                logger.info(f"rollout {tr.task_id} (rid {rid}) rejected")
             return
         if not self.ledger.on_accepted(rid, self._version):
             # already consumed (or already pending) — a duplicate from a
@@ -277,10 +322,24 @@ class WorkflowExecutor:
                 if "rollout_id" in r]
         if rids:
             self.ledger.on_consumed(rids, self._version)
+        self._count_consumed(results)
         # Shuffle so GRPO groups from the same prompt don't correlate with
         # batch position (parity: workflow_executor wait shuffles).
         random.shuffle(results)
         return concat_padded_tensors(results)
+
+    def _count_consumed(self, results: list[dict[str, Any]]) -> None:
+        """How stale what the trainer takes is: its version now less the
+        newest version that generated a token of the sample."""
+        m = self._metrics
+        for traj in results:
+            if "versions" not in traj:
+                continue
+            newest = np.asarray(traj["versions"]).reshape(len(traj["versions"]), -1).max(axis=1)
+            stale = np.maximum(self._version - newest, 0)
+            m["consumed_samples_total"] += int(stale.size)
+            m["consumed_staleness_versions_total"] += int(stale.sum())
+            m["consumed_staleness_max"] = max(m["consumed_staleness_max"], int(stale.max()))
 
     def rollout_batch(
         self,
@@ -304,6 +363,7 @@ class WorkflowExecutor:
         """Async pipeline heart: keep ≥2 batches of episodes in flight and
         return one training batch when ready (workflow_executor.py:561-598)."""
         # the wait for the staleness gate and the episodes, as the loop sees it
+        t0 = self._clock()
         with perf_tracer.span("rollout/prepare_batch", version=self._version):
             if self._data_generator is None:
                 self._data_generator = cycle_dataloader(dataloader)
@@ -328,11 +388,30 @@ class WorkflowExecutor:
                 self._collect()
                 if len(self._result_cache) >= batch_size:
                     with stats_tracker.record_timing("prepare_batch/concat"):
-                        return self.wait(batch_size, timeout=1)
+                        batch = self.wait(batch_size, timeout=1)
+                    self._metrics["batches_prepared_total"] += 1
+                    self._metrics["prepare_batch_secs_total"] += self._clock() - t0
+                    return batch
                 time.sleep(ROLLOUT_POLL_WAIT_TIME / 10)
 
     def get_stats(self):
         return self.staleness_manager.get_stats()
+
+    def get_metrics(self) -> dict:
+        """The loop's counters, flat and numeric as the decode engine's:
+        every `*_secs_total` a sum of clock differences (a period that is
+        running counts up to now), every other a count. What the gate held
+        back and which rule did, what the pauses and the wait for a batch
+        cost, an episode's time and its wait before it could start, and how
+        stale the consumed samples were, in versions."""
+        gate, paused = self._gate_closed, self._pause_periods
+        return {
+            "gate_closed_staleness_secs_total": gate.secs("staleness"),
+            "gate_closed_concurrency_secs_total": gate.secs("concurrency"),
+            "paused_secs_total": paused.secs("paused"),
+            "pauses_total": paused.count,
+            **self._metrics,
+        }
 
     # -- checkpointing ---------------------------------------------------
     def attach_ledger_wal(self, path: str) -> None:

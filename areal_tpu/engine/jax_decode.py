@@ -172,12 +172,10 @@ _GUARDED_BY = {
     # cross-replica KV migration + TTFT-split accounting: written by the
     # scheduler (admission timing) AND the HTTP thread (export_session /
     # import_session), snapshotted by get_metrics — all under _metrics_lock
-    "JaxDecodeEngine._ttft_queue_ms": "_metrics_lock",
     "JaxDecodeEngine._ttft_prefill_ms": "_metrics_lock",
     "JaxDecodeEngine._ttft_transfer_ms": "_metrics_lock",
     "JaxDecodeEngine._queue_secs_total": "_metrics_lock",
     "JaxDecodeEngine._prefill_secs_total": "_metrics_lock",
-    "JaxDecodeEngine._transfer_secs_total": "_metrics_lock",
     "JaxDecodeEngine._n_migrated_in": "_metrics_lock",
     "JaxDecodeEngine._n_migrated_out": "_metrics_lock",
     "JaxDecodeEngine._migrated_in_bytes": "_metrics_lock",
@@ -345,6 +343,12 @@ class _Slot:
     admit_t: float = 0.0
 
 
+# the scheduler thread's states (`sched_<state>_secs_total`), each exclusive
+# of what is nested in it
+SCHED_STATES = ("admit", "prefill", "dispatch", "consume", "wait_device",
+                "paused", "idle", "other")
+
+
 @dataclass
 class _Inflight:
     """One dispatched-but-unconsumed decode chunk.
@@ -510,12 +514,16 @@ class JaxDecodeEngine(InferenceEngine):
         # prefill dispatch wall attributed per admitted slot, and
         # host-tier/migration transfer wall (promotion upload). Recent
         # windows for percentiles + monotonic totals.
-        self._ttft_queue_ms: deque = deque(maxlen=512)
         self._ttft_prefill_ms: deque = deque(maxlen=512)
         self._ttft_transfer_ms: deque = deque(maxlen=512)
         self._queue_secs_total = 0.0
         self._prefill_secs_total = 0.0
-        self._transfer_secs_total = 0.0
+        # what a weight push costs the engine (_weight_swap; _metrics_lock)
+        self._n_weight_updates = 0
+        self._weight_swap_s = 0.0
+        self._weight_drain_s = 0.0
+        # the scheduler thread's time by state (its own lock)
+        self._sched_clock = perf_tracer.StateClock(SCHED_STATES)
         # KV sessions migrated across replicas (disaggregated fleets /
         # drain): import = sessions landed in this engine's host tier,
         # export = sessions streamed out; version rejects = imports
@@ -921,12 +929,13 @@ class JaxDecodeEngine(InferenceEngine):
             self._spec_drafted = 0
             self._spec_accepted = 0
             self._spec_rejected = 0
-            self._ttft_queue_ms = deque(maxlen=512)
             self._ttft_prefill_ms = deque(maxlen=512)
             self._ttft_transfer_ms = deque(maxlen=512)
             self._queue_secs_total = 0.0
             self._prefill_secs_total = 0.0
-            self._transfer_secs_total = 0.0
+            self._n_weight_updates = 0
+            self._weight_swap_s = 0.0
+            self._weight_drain_s = 0.0
             self._n_migrated_in = 0
             self._n_migrated_out = 0
             self._migrated_in_bytes = 0
@@ -2328,7 +2337,6 @@ class JaxDecodeEngine(InferenceEngine):
         dt = time.monotonic() - t_promote
         with self._metrics_lock:
             self._ttft_transfer_ms.append(dt * 1000.0)
-            self._transfer_secs_total += dt
         return True
 
     def _get_suffix_prefill_fn(self, suffix_bucket: int, prefix_bucket: int,
@@ -2594,7 +2602,6 @@ class JaxDecodeEngine(InferenceEngine):
         dt = time.monotonic() - t0
         with self._metrics_lock:
             self._ttft_transfer_ms.append(dt * 1000.0)
-            self._transfer_secs_total += dt
         return plen
 
     # -- prefix-KV registry --------------------------------------------
@@ -3188,9 +3195,7 @@ class JaxDecodeEngine(InferenceEngine):
             # prefill/transfer shares are recorded at their dispatch sites
             item.admit_t = time.monotonic()
             with self._metrics_lock:
-                q_s = max(item.admit_t - item.start_time, 0.0)
-                self._ttft_queue_ms.append(q_s * 1000.0)
-                self._queue_secs_total += q_s
+                self._queue_secs_total += max(item.admit_t - item.start_time, 0.0)
             perf_tracer.record("request/queue", item.start_time, item.admit_t,
                                rid=item.rid, slot=slot_idx)
             if item.prefill_only:
@@ -3245,7 +3250,8 @@ class JaxDecodeEngine(InferenceEngine):
         on TPU it is the dispatch cost — the honest host-side share of TTFT
         either way."""
         t0 = time.monotonic()
-        with perf_tracer.span("decode/prefill", bucket=bucket, batch=n):
+        with perf_tracer.span("decode/prefill", bucket=bucket, batch=n), \
+                self._sched_state("prefill"):
             yield
         dt = time.monotonic() - t0
         with self._metrics_lock:
@@ -3480,11 +3486,27 @@ class JaxDecodeEngine(InferenceEngine):
         if item.future is not None and not item.future.done():
             item.loop.call_soon_threadsafe(item.future.set_result, resp)
 
+    @contextmanager
+    def _sched_state(self, state: str):
+        """The scheduler thread is in `state` inside the block, and back in
+        the state around it after (`sched_<state>_secs_total`). Other threads
+        run some of these paths too (`pause_generation` drains on its
+        caller's); their time is not the scheduler's."""
+        if threading.current_thread() is not self._thread:
+            yield
+            return
+        around = self._sched_clock.switch(state)
+        try:
+            yield
+        finally:
+            self._sched_clock.switch(around)
+
     def _scheduler_loop(self):
         debug = bool(os.environ.get("AREAL_DECODE_DEBUG"))
         last_dbg = time.monotonic()
         R = self.config.max_running_requests
         runahead = max(int(self.config.decode_runahead_chunks), 0)
+        self._sched_clock.switch("other")
         try:
             while not self._shutdown.is_set():
                 if debug and time.monotonic() - last_dbg > 5.0:
@@ -3501,7 +3523,11 @@ class JaxDecodeEngine(InferenceEngine):
                 # trace constraining onto that topology is a compile error.
                 # Re-bound per pass because set_model can install a sharded
                 # mesh after the thread starts.
-                with mesh_lib.mesh_scope(self.mesh), self._sched_lock:
+                # (`decode/pass` is around the wait for the lock and the
+                # bookkeeping between the spans below: with `decode/paused`
+                # and `decode/idle` no instant of this thread is unmarked)
+                with perf_tracer.span("decode/pass"), \
+                        mesh_lib.mesh_scope(self.mesh), self._sched_lock:
                     if self._gen_paused.is_set():
                         # fence: never leave a chunk dispatched while a
                         # pause holder swaps weights/aborts under us
@@ -3512,7 +3538,8 @@ class JaxDecodeEngine(InferenceEngine):
                         admitted = False
                         if self._overflow or not self._request_q.empty():
                             # (with nothing queued _admit does nothing)
-                            with perf_tracer.span("decode/admit"):
+                            with perf_tracer.span("decode/admit"), \
+                                    self._sched_state("admit"):
                                 admitted = self._admit()
                         active = self._active_mask()
                         dispatched = False
@@ -3522,7 +3549,7 @@ class JaxDecodeEngine(InferenceEngine):
                                 chunk=self._chunks_dispatched + 1,
                                 active=int(active.sum()),
                                 version=self._version,
-                            ):
+                            ), self._sched_state("dispatch"):
                                 rec = self._dispatch_chunk(active)
                             if rec is not None:
                                 self._inflight.append(rec)
@@ -3550,12 +3577,22 @@ class JaxDecodeEngine(InferenceEngine):
                     # one span for the whole pause (a weight commit, an
                     # abort), not one per poll; nothing can be dispatched
                     # meanwhile, so there is nothing to drain again
-                    with perf_tracer.span("decode/paused", version=self._version):
+                    with perf_tracer.span("decode/paused", version=self._version), \
+                            self._sched_state("paused"):
                         while (self._gen_paused.is_set()
                                and not self._shutdown.is_set()):
                             time.sleep(0.005)
+                elif not worked and (self._overflow or self._active_mask().any()):
+                    time.sleep(0.002)  # held back (pool pressure), not idle
                 elif not worked:
-                    time.sleep(0.002)
+                    # nothing queued, nothing active: one span for the whole
+                    # wait for traffic, as for a pause. Only `_admit` fills a
+                    # slot, and it has nothing to do until a request is queued
+                    with perf_tracer.span("decode/idle"), self._sched_state("idle"):
+                        while (self._request_q.empty()
+                               and not self._gen_paused.is_set()
+                               and not self._shutdown.is_set()):
+                            time.sleep(0.002)
         except BaseException as e:  # noqa: BLE001
             self._thread_exc = e
             logger.error(
@@ -3579,6 +3616,8 @@ class JaxDecodeEngine(InferenceEngine):
                     break
                 if item.future is not None and not item.future.done():
                     item.loop.call_soon_threadsafe(item.future.set_exception, e)
+        finally:
+            self._sched_clock.switch(None)
 
     def _run_chunk(self, active: np.ndarray):
         """Synchronous step: dispatch one chunk and consume it immediately
@@ -3754,7 +3793,7 @@ class JaxDecodeEngine(InferenceEngine):
                 jnp.asarray(np.array(self._slot_lengths)),  # no-alias copy
             )
             self._patch_slots.clear()
-        with perf_tracer.span("decode/refresh_ctl"):
+        with perf_tracer.span("decode/refresh_ctl"), self._sched_state("other"):
             ctl = self._refresh_ctl()
         # the effective (saturation-refined) active mask gets its own
         # cached device buffer: it changes only when a slot joins, leaves,
@@ -3888,8 +3927,9 @@ class JaxDecodeEngine(InferenceEngine):
 
     def _consume_chunk(self, rec: "_Inflight") -> None:
         with perf_tracer.span("decode/consume_chunk", chunk=rec.chunk,
-                              version=rec.version):
-            with perf_tracer.span("decode/wait_device", chunk=rec.chunk):
+                              version=rec.version), self._sched_state("consume"):
+            with perf_tracer.span("decode/wait_device", chunk=rec.chunk), \
+                    self._sched_state("wait_device"):
                 toks = np.asarray(rec.toks)  # [n_chunk, R]
                 logps = np.asarray(rec.logps)
                 acc = np.asarray(rec.accepted) if rec.spec_w > 0 else None
@@ -4145,6 +4185,11 @@ class JaxDecodeEngine(InferenceEngine):
             dataloader, workflow, workflow_builder, should_accept
         )
 
+    def get_loop_metrics(self) -> dict:
+        """The loop's counters (`WorkflowExecutor.get_metrics`): the gate,
+        the pauses, the wait for a batch, episodes, consumed staleness."""
+        return self._executor.get_metrics()
+
     # -- flow control ---------------------------------------------------
     def pause(self):
         self._executor.pause()
@@ -4174,8 +4219,10 @@ class JaxDecodeEngine(InferenceEngine):
         under the weight lock, resume. An external pause is preserved (an
         external /pause_generation is not cancelled by the swap's own)."""
         was_paused = self._gen_paused.is_set()
+        t0 = time.monotonic()
         with perf_tracer.span("weights/pause", version=self._version):
             self.pause_generation()
+        t_drained = time.monotonic()
         try:
             with perf_tracer.span("weights/commit", version=self._version), \
                     self._weight_lock:
@@ -4184,6 +4231,11 @@ class JaxDecodeEngine(InferenceEngine):
             if not was_paused:
                 with perf_tracer.span("weights/resume", version=self._version):
                     self.continue_generation()
+            t1 = time.monotonic()
+            with self._metrics_lock:
+                self._n_weight_updates += 1
+                self._weight_swap_s += t1 - t0
+                self._weight_drain_s += t_drained - t0
 
     def prewarm(
         self,
@@ -5439,6 +5491,7 @@ class JaxDecodeEngine(InferenceEngine):
         # Snapshot under _metrics_lock: this runs on the HTTP/main thread
         # while the scheduler mutates the counters per chunk; the lock
         # prevents torn busy/idle pairs and mid-append deque iteration.
+        sched = self._sched_clock.read()
         with self._metrics_lock:
             itl = np.asarray(self._chunk_itl_ms, dtype=np.float64)
             itl_wall = np.asarray(self._chunk_wall_itl_ms, dtype=np.float64)
@@ -5459,14 +5512,14 @@ class JaxDecodeEngine(InferenceEngine):
             spec_drafted = self._spec_drafted
             spec_accepted = self._spec_accepted
             spec_rejected = self._spec_rejected
-            ttft_queue = np.asarray(self._ttft_queue_ms, dtype=np.float64)
             ttft_prefill = np.asarray(self._ttft_prefill_ms, dtype=np.float64)
             ttft_transfer = np.asarray(
                 self._ttft_transfer_ms, dtype=np.float64
             )
             queue_secs_total = self._queue_secs_total
+            weight_updates = self._n_weight_updates
+            weight_swap_s, weight_drain_s = self._weight_swap_s, self._weight_drain_s
             prefill_secs_total = self._prefill_secs_total
-            transfer_secs_total = self._transfer_secs_total
             migrated_in = self._n_migrated_in
             migrated_out = self._n_migrated_out
             migrated_in_bytes = self._migrated_in_bytes
@@ -5579,30 +5632,14 @@ class JaxDecodeEngine(InferenceEngine):
                 float(np.percentile(itl_wall, 99)) if itl_wall.size else 0.0
             ),
             # TTFT decomposition (disaggregation observability): queue =
-            # enqueue→admission wait, prefill = prompt prefill dispatch
-            # wall, transfer = host-tier/migration swap-in wall — a
+            # enqueue→admission wait (a monotonic total), prefill = prompt
+            # prefill dispatch wall (total, and the recent window's p99),
+            # transfer = host-tier/migration swap-in wall (p99) — a
             # migrated session's TTFT trades its prefill share for a
-            # (much smaller) transfer share. Percentiles over the recent
-            # window + monotonic totals.
-            "ttft_queue_p50_ms": (
-                float(np.percentile(ttft_queue, 50)) if ttft_queue.size else 0.0
-            ),
-            "ttft_queue_p99_ms": (
-                float(np.percentile(ttft_queue, 99)) if ttft_queue.size else 0.0
-            ),
-            "ttft_prefill_p50_ms": (
-                float(np.percentile(ttft_prefill, 50))
-                if ttft_prefill.size
-                else 0.0
-            ),
+            # (much smaller) transfer share. The router reads the two p99s.
             "ttft_prefill_p99_ms": (
                 float(np.percentile(ttft_prefill, 99))
                 if ttft_prefill.size
-                else 0.0
-            ),
-            "ttft_transfer_p50_ms": (
-                float(np.percentile(ttft_transfer, 50))
-                if ttft_transfer.size
                 else 0.0
             ),
             "ttft_transfer_p99_ms": (
@@ -5612,7 +5649,14 @@ class JaxDecodeEngine(InferenceEngine):
             ),
             "queue_secs_total": round(queue_secs_total, 6),
             "prefill_secs_total": round(prefill_secs_total, 6),
-            "transfer_secs_total": round(transfer_secs_total, 6),
+            # the scheduler thread's time by state, each exclusive of what
+            # is nested in it, summing to the thread's life; and what a
+            # weight push costs: pause requested to generation resumed, and
+            # the part of it spent waiting for the chunk boundary
+            **{f"sched_{k}_secs_total": v for k, v in sched.items()},
+            "weight_updates_total": weight_updates,
+            "weight_swap_secs_total": weight_swap_s,
+            "weight_drain_secs_total": weight_drain_s,
             # cross-replica KV migration (role fleets / drain): sessions
             # + bytes in/out, and imports refused on a weight-version
             # mismatch (the racing-commit case — honest misses)

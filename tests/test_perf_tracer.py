@@ -6,6 +6,7 @@ device trace of a real run."""
 import glob
 import json
 import os
+import sys
 import threading
 import time
 
@@ -209,6 +210,31 @@ def test_maybe_xprof_step_window(tmp_path, monkeypatch):
     assert glob.glob(str(tmp_path) + "/**/*.xplane.pb", recursive=True)
 
 
+def test_a_real_runs_trace_and_record_lie_on_one_clock(tmp_path, monkeypatch, not_recording):
+    """The operator's pair: a device trace (`AREAL_TPU_XPROF_DIR`) and the span
+    record of the same run. Both hold `xprof_window`; `trace_report --spans`
+    places the record by it, with a span still open at the profiler's stop."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from tools import trace_report
+
+    monkeypatch.setenv("AREAL_TPU_XPROF_DIR", str(tmp_path / "xprof"))
+    monkeypatch.setenv("AREAL_TPU_XPROF_STEPS", "1-2")
+    monkeypatch.setitem(perf_tracer._xprof_state, "active", False)
+    monkeypatch.setitem(perf_tracer._xprof_state, "done", False)
+    with perf_tracer.recording() as rec:
+        with perf_tracer.span("decode/paused"):  # open before the start and after the stop
+            for step in range(4):
+                perf_tracer.maybe_xprof_step(step)
+                with perf_tracer.step_span("train/train_batch", step):
+                    time.sleep(0.01)
+            path = rec.save(str(tmp_path / "record.json"))
+    r = trace_report.report(str(tmp_path / "xprof"), spans=path)
+    assert abs(r["record_clock"]["skew_ns"]) < 5e6  # the two ends agree to milliseconds
+    rows = {row[0]: row for row in r["spans"]}
+    assert rows["areal/train/train_batch"][1] == 4  # the record's, the steps before the window too
+    assert "areal/decode/paused" in rows and "areal/xprof_window" not in rows
+
+
 def test_trace_report_reads_a_span_record(recording, tmp_path):
     """tools/trace_report.py on the Chrome JSON: count, total and self time."""
     import sys
@@ -224,3 +250,111 @@ def test_trace_report_reads_a_span_record(recording, tmp_path):
     assert rows["areal/train/minibatch"][1:3] == (2, pytest.approx(0.75, abs=1e-5))
     assert rows["areal/train/ppo_update"][1] == 1
     assert rows["areal/train/ppo_update"][3] == pytest.approx(1.25, abs=1e-5)  # self
+
+
+# -- one complete record, placed on the trace's clock (ISSUE 34) -------------
+
+
+def test_recording_on_and_off_in_one_process(not_recording):
+    with perf_tracer.span("before"):
+        pass
+    with perf_tracer.recording() as rec:
+        assert perf_tracer.recorder() is rec
+        with perf_tracer.span("inside", step=1):
+            pass
+        perf_tracer.record("request/queue", 1.0, 2.0, rid="r")
+        late = perf_tracer.span("closes_after")
+        late.__enter__()
+    assert perf_tracer.recorder() is None
+    late.__exit__(None, None, None)  # began inside: still kept, where it began
+    with perf_tracer.span("after"):
+        pass
+    perf_tracer.record("request/queue", 3.0, 4.0, rid="s")
+    assert [s["name"] for s in rec.snapshot()] == ["inside", "request/queue", "closes_after"]
+    # a second stretch is a second record
+    with perf_tracer.recording() as again:
+        with perf_tracer.span("second"):
+            pass
+    assert again is not rec and [s["name"] for s in again.snapshot()] == ["second"]
+
+
+def test_recording_inside_the_environments_record_is_that_record(recording):
+    with perf_tracer.recording() as rec:
+        assert rec is recording
+        with perf_tracer.span("inside"):
+            pass
+    assert perf_tracer.recorder() is recording  # and it stays on after
+    assert [row[1] for row in recording.spans] == ["inside"]
+
+
+def test_snapshot_returns_a_span_another_thread_holds_open(not_recording):
+    started, release = threading.Event(), threading.Event()
+
+    def worker():
+        with perf_tracer.span("decode/paused", version=3):
+            started.set()
+            assert release.wait(10)
+
+    with perf_tracer.recording() as rec:
+        t = threading.Thread(target=worker)
+        t.start()
+        assert started.wait(10)
+        with perf_tracer.span("step/update_weights"):
+            now = time.monotonic_ns()
+            snap = {s["name"]: s for s in rec.snapshot(now)}
+        # both are open at `now`, on two threads, each cut there and marked
+        assert set(snap) == {"decode/paused", "step/update_weights"}
+        assert all(s["open"] and s["end_ns"] == now for s in snap.values())
+        assert snap["decode/paused"]["thread"] == t.ident != snap["step/update_weights"]["thread"]
+        assert snap["decode/paused"]["ids"] == {"version": 3}
+        assert snap["decode/paused"]["start_ns"] <= now
+        release.set()
+        t.join(10)
+        assert not t.is_alive()
+        done = {s["name"]: s for s in rec.snapshot()}
+        assert not done["decode/paused"]["open"] and not done["step/update_weights"]["open"]
+        assert done["decode/paused"]["end_ns"] >= now
+
+
+def test_a_detached_span_outlives_the_span_it_began_in(not_recording):
+    with perf_tracer.recording() as rec:
+        with perf_tracer.span("rollout/prepare_batch"):
+            gate = perf_tracer.span("rollout/gate_closed", by="staleness").open()
+            with perf_tracer.span("inner"):
+                pass
+        with perf_tracer.span("step/train_step"):
+            assert {s["name"] for s in rec.snapshot() if s["open"]} == {
+                "rollout/gate_closed", "step/train_step"}
+        gate.close()
+    rows = {s["name"]: s for s in rec.snapshot()}
+    assert rows["rollout/gate_closed"]["parent"] is None
+    # nobody's parent either: what opens meanwhile nests as if it were not there
+    assert rows["inner"]["parent"] == rows["rollout/prepare_batch"]["id"]
+    assert rows["step/train_step"]["parent"] is None
+    assert rows["rollout/gate_closed"]["end_ns"] >= rows["step/train_step"]["end_ns"]
+
+
+def test_save_marks_the_spans_still_open(not_recording, tmp_path):
+    with perf_tracer.recording() as rec:
+        with perf_tracer.span("decode/idle"):
+            path = rec.save(str(tmp_path / "r.json"))
+    events = {e["name"]: e for e in json.load(open(path))["traceEvents"]}
+    assert events["decode/idle"]["args"]["open"] is True
+
+
+@pytest.mark.parametrize("first, last", [(5_000_000_000, 5_000_000_000),  # one clock, shifted
+                                         (-7_000_000, -6_400_000)])       # and 0.6 ms of drift
+def test_the_record_is_placed_on_the_traces_clock_by_two_instants(first, last):
+    """The traced window's start and stop, on the host's clock and on the
+    trace's: the record moves by the mean of the two differences, and how
+    far they are apart is reported."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from benchmark.lib import program_spans
+
+    host = (1_000_000_000.0, 21_000_000_000.0)
+    clock = program_spans.clock_offset(host, (host[0] + first, host[1] + last))
+    assert clock == {"offset_ns": (first + last) / 2, "skew_ns": last - first}
+    (moved,) = program_spans.shifted(
+        [dict(name="decode/paused", start_ns=2e9, end_ns=3e9, open=True)], clock["offset_ns"])
+    assert moved["start_ns"] == 2e9 + (first + last) / 2 and moved["end_ns"] - moved["start_ns"] == 1e9
+    assert moved["open"] and moved["name"] == "decode/paused"

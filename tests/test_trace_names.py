@@ -74,22 +74,30 @@ def programs(cpu_devices):
     from areal_tpu.engine.jax_decode import JaxDecodeEngine
     from areal_tpu.engine.ppo.actor import JaxPPOActor
 
+    from areal_tpu.utils import perf_tracer
+
     spy = _JitSpy()
     mp = pytest.MonkeyPatch()
     mp.setattr(jax, "jit", spy)
+    recording = perf_tracer.recording()
+    rec = recording.__enter__()
     try:
         eng = JaxDecodeEngine(
             JaxDecodeConfig(context_length=256, max_running_requests=4,
                             new_tokens_per_chunk=4, page_size=128, dtype="float32",
                             kv_cache_dtype="float32", paged_attn_impl="pallas"),
-            InferenceEngineConfig())
-        eng.set_model(init_params(TINY, jax.random.PRNGKey(0)), TINY)
+            # one episode at a time: the second of two waits at the gate
+            InferenceEngineConfig(max_concurrent_rollouts=1, consumer_batch_size=1,
+                                  max_head_offpolicyness=4))
+        params = init_params(TINY, jax.random.PRNGKey(0))
+        eng.set_model(params, TINY)
         eng.initialize()
         try:
             eng.generate(ModelRequest(
                 input_ids=[1, 5, 9, 13, 2],
                 gconfig=GenerationHyperparameters(greedy=True, max_new_tokens=6)),
                 timeout=300)
+            _drive_the_loop(eng, params)
             # the programs this request did not need are made, not run
             eng._get_verify_fn(False, 1, 3)
             eng._get_suffix_prefill_fn(64, 64, 1)
@@ -124,7 +132,70 @@ def programs(cpu_devices):
             actor.destroy()
     finally:
         mp.undo()
+        recording.__exit__(None, None, None)
+    spy.span_names = {s["name"] for s in rec.snapshot()}
     return spy, stats
+
+
+class _OneRequest:
+    """An episode that is one request of the engine."""
+
+    async def arun_episode(self, engine, data):
+        resp = await engine.agenerate(ModelRequest(
+            input_ids=data["prompt"],
+            gconfig=GenerationHyperparameters(greedy=True, max_new_tokens=4)))
+        n = resp.input_len + resp.output_len
+        return dict(input_ids=np.asarray([resp.input_tokens + resp.output_tokens], np.int32),
+                    attention_mask=np.ones((1, n), bool))
+
+
+class _Loader:
+    batch_size = 2
+
+    def __iter__(self):
+        while True:
+            yield [{"prompt": [3, 7, 11, 4]}, {"prompt": [2, 6, 10, 8]}]
+
+
+def _drive_the_loop(eng, params):
+    """What the loop's spans need: a batch through the gate (one episode at a
+    time, so one waits), a pause held through an in-memory push, and a wait
+    for traffic."""
+    import time
+
+    from areal_tpu.api.io_struct import WeightUpdateMeta
+
+    eng.prepare_batch(_Loader(), workflow=_OneRequest())
+    eng.pause()
+    eng.update_weights_from_distributed(WeightUpdateMeta(type="memory"), params, TINY)
+    eng.set_version(1)
+    eng.resume()
+    time.sleep(0.05)
+
+
+# every span the program opens on the paths above, `areal/<name>` in a device
+# trace (PERF.md section 3 lists who reads each). The last five of the loop's,
+# `decode/pass` and `decode/idle` are ISSUE 34's.
+SPANS = {
+    "scheduler thread": {
+        "decode/pass", "decode/admit", "decode/prefill", "decode/refresh_ctl", "decode/dispatch_chunk",
+        "decode/consume_chunk", "decode/wait_device", "decode/paused", "decode/idle",
+        "request/queue", "request/prefill", "request/decode"},
+    "loop": {"rollout/prepare_batch", "rollout/episode", "rollout/pending",
+             "rollout/gate_closed", "rollout/paused", "step/prepare_batch/concat"},
+    "push": {"weights/pause", "weights/commit", "weights/resume"},
+    "trainer": {
+        "train/compute_logp", "train/ppo_update", "train/minibatch", "train/train_batch",
+        "train/split_mbs", "train/upload_mb", "train/grad_step", "train/fwd_step",
+        "train/read_stats", "train/apply_update", "train/wait_device", "train/step_stats",
+        "train/compile"},
+}
+
+
+@pytest.mark.parametrize("where", sorted(SPANS))
+def test_every_span_has_its_pinned_name(programs, where):
+    spy, _ = programs
+    assert SPANS[where] <= spy.span_names, sorted(SPANS[where] - spy.span_names)
 
 
 def test_every_program_has_its_pinned_name(programs):

@@ -1,6 +1,6 @@
 """What a device trace, or a span record, says about one run.
 
-    python tools/trace_report.py <path> [--gap-ms 1.0] [--top 20]
+    python tools/trace_report.py <path> [--spans <record.json>] [--gap-ms 1.0] [--top 20]
 
 `<path>` is a directory the JAX profiler wrote into (`AREAL_TPU_XPROF_DIR` of
 a real run, `.bench_work/<cell>/trace` of a benchmark run with `--trace 1`),
@@ -20,10 +20,21 @@ an `.xplane.pb`, or the Chrome JSON that `AREAL_TPU_PERF_TRACE=1` leaves in
   idle gaps  every gap of chip 0 over `--gap-ms`, summed by the innermost
              `areal/` span open at its middle on any host thread
 
+  by thread  the same gaps for each host thread by that thread's own innermost
+             span: what the trainer and what the scheduler was in; with
+             `--spans` also the five longest, each named by both
+
 and from a span record the spans table alone. Device and host events of one
-trace share a clock. The arithmetic on intervals is the benchmark's
-(`benchmark/lib/xplane.py`: `load`, `union`, `busy`); what this file adds is
-what a later benchmark PR lifts into readers.
+trace share a clock. `--spans` lays `perf_tracer`'s record (the Chrome JSON)
+over the trace's own `areal/` events: it also holds the spans known after the
+fact and those open when the profiler started or stopped. The record is on
+the host's monotonic clock and is placed by measurement, from a span both hold:
+`xprof_window` of a run under `AREAL_TPU_XPROF_DIR` with `AREAL_TPU_PERF_TRACE=1`,
+or a benchmark kind's `traced_window` beside `bench/traced_window`.
+The arithmetic on intervals is the benchmark's (`benchmark/lib/xplane.py`:
+`load`, `union`, `busy`; `benchmark/lib/program_spans.py`: the clock, the gaps,
+a thread's innermost span); what this file adds is what a later benchmark PR
+lifts into readers.
 """
 
 from __future__ import annotations
@@ -40,7 +51,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.lib import xplane  # noqa: E402
+from benchmark.lib import program_spans, xplane  # noqa: E402
 
 PROGRAM_PREFIX = "areal/"
 PREFIXES = (PROGRAM_PREFIX, xplane.SPAN_PREFIX)
@@ -69,12 +80,43 @@ def load(path: str) -> tuple[dict, str | None]:
 
 def spans_record(chrome: dict) -> dict:
     """`perf_tracer`'s Chrome JSON as one host plane, a line per thread."""
-    lines: dict[int, list] = defaultdict(list)
-    for e in chrome.get("traceEvents", []):
-        if e.get("ph") == "X":
-            lines[e["tid"]].append([PROGRAM_PREFIX + e["name"], e["ts"] * 1e3, e["dur"] * 1e3])
-    return {"planes": [{"name": "/host:CPU", "lines": [
-        {"name": f"thread {tid}", "events": evs} for tid, evs in sorted(lines.items())]}]}
+    return {"planes": [program_spans.as_host_plane(
+        program_spans.from_chrome(chrome), PROGRAM_PREFIX)]}
+
+
+def with_record(trace: dict, record_path: str) -> tuple[dict, dict]:
+    """(the trace with the record's spans in place of its own `areal/`
+    events, {"offset_ns", "skew_ns"}). Both hold the traced window: the
+    trace as `bench/traced_window`, the record as `traced_window` on the
+    host's clock."""
+    opener = gzip.open if record_path.endswith(".gz") else open
+    with opener(record_path, "rt") as f:
+        spans = program_spans.from_chrome(json.load(f))
+    # the span both hold: the benchmark's `traced_window` (`bench/` in the
+    # trace) or `maybe_xprof_step`'s `xprof_window` (`areal/` in the trace)
+    in_trace = {e[0]: (e[1], e[1] + e[2]) for _, events in host_lines(trace) for e in events}
+    pairs = ((program_spans.WINDOW_SPAN, xplane.WINDOW_SPAN),
+             ("xprof_window", PROGRAM_PREFIX + "xprof_window"))
+    anchor, on_trace = next(
+        ((s, in_trace[there]) for here, there in pairs for s in spans
+         if s["name"] == here and there in in_trace), (None, None))
+    if anchor is None:
+        raise ValueError(f"{record_path} and the trace share no `traced_window` or "
+                         "`xprof_window` span: nothing says where the record lies on the "
+                         "trace's clock")
+    clock = program_spans.clock_offset((anchor["start_ns"], anchor["end_ns"]), on_trace)
+    spans = program_spans.shifted([s for s in spans if s is not anchor], clock["offset_ns"])
+    planes = []
+    for p in trace["planes"]:
+        if xplane.DEVICE_PLANE.match(p["name"]):
+            planes.append(p)
+            continue
+        lines = [{"name": line["name"],
+                  "events": [e for e in line["events"] if not e[0].startswith(PROGRAM_PREFIX)]}
+                 for line in p["lines"]]
+        planes.append({"name": p["name"], "lines": [line for line in lines if line["events"]]})
+    planes.append(program_spans.as_host_plane(spans, PROGRAM_PREFIX))
+    return {"planes": planes, "record": spans}, clock
 
 
 def scope_of(op_name: str) -> str:
@@ -271,20 +313,9 @@ def idle_gaps(trace: dict, lo: float, hi: float, min_ns: float) -> list[dict]:
     span open at the gap's middle on any host thread (the shortest, if
     several threads have one), else the innermost `bench/` span in brackets,
     else None."""
-    planes = xplane.device_planes(trace)
-    if not planes:
-        return []
-    busy = xplane.union(xplane._clip(xplane._line(planes[0], xplane.OPS_LINE), lo, hi))
-    gaps, t = [], lo
-    for a, b in busy:
-        if a - t >= min_ns:
-            gaps.append((t, a))
-        t = max(t, b)
-    if hi - t >= min_ns:
-        gaps.append((t, hi))
     threads = host_lines(trace)
     out = []
-    for a, b in gaps:
+    for a, b in program_spans.device_gaps(trace, lo, hi, min_ns):
         mid = (a + b) / 2
         best = {}
         for thread, events in threads:
@@ -319,10 +350,36 @@ def gaps_by_span(gaps: list[dict], lo: float) -> list[tuple]:
                   key=lambda r: -r[3])
 
 
-def report(path: str, gap_ms: float = 1.0, top: int = 20, depth: int = 4) -> dict:
+def gaps_by_thread(trace: dict, gaps: list[dict]) -> dict[str, list[tuple]]:
+    """{host thread that holds `areal/` spans: [(its innermost `areal/` span
+    at the gap's middle or None, gaps, total s, longest s)]}: every gap once
+    for every such thread, so each thread's rows sum to the idle time."""
+    out = {}
+    for thread, events in host_lines(trace):
+        spans = [{"name": e[0], "thread": thread, "start_ns": e[1], "end_ns": e[1] + e[2]}
+                 for e in events if e[0].startswith(PROGRAM_PREFIX)
+                 and not e[0][len(PROGRAM_PREFIX):].startswith(program_spans.AFTER_THE_FACT)]
+        if not spans:
+            continue
+        acc: dict = defaultdict(lambda: [0, 0.0, 0.0])
+        for g in gaps:
+            a = acc[program_spans.innermost(spans, thread, g["start"] + g["seconds"] * 1e9 / 2)]
+            a[0] += 1
+            a[1] += g["seconds"]
+            a[2] = max(a[2], g["seconds"])
+        out[thread] = sorted(((k, int(v[0]), v[1], v[2]) for k, v in acc.items()),
+                             key=lambda r: -r[2])
+    return out
+
+
+def report(path: str, gap_ms: float = 1.0, top: int = 20, depth: int = 4,
+           spans: str | None = None) -> dict:
     """Everything `main` prints, as data."""
     trace, pb = load(path)
-    out: dict = {"spans": span_table(trace)}
+    out: dict = {}
+    if spans:
+        trace, out["record_clock"] = with_record(trace, spans)
+    out["spans"] = span_table(trace)
     if not xplane.device_planes(trace):
         return out
     lo, hi = xplane.window(trace)
@@ -333,21 +390,35 @@ def report(path: str, gap_ms: float = 1.0, top: int = 20, depth: int = 4) -> dic
     gaps = idle_gaps(trace, lo, hi, gap_ms * 1e6)
     out["gaps"] = gaps
     out["gaps_by_span"] = gaps_by_span(gaps, lo)
+    out["gaps_by_thread"] = gaps_by_thread(trace, gaps)
+    if "record" in trace:
+        # the five longest, each by what the loop's two threads were in: the
+        # trainer's is where `step/` spans are, the scheduler's `decode/`
+        threads = {label: program_spans.thread_of(trace["record"], prefix)
+                   for label, prefix in (("trainer", "step/"), ("decode", "decode/"))}
+        out["longest_gaps"] = program_spans.name_gaps(
+            [(g["start"], g["start"] + g["seconds"] * 1e9) for g in gaps], trace["record"],
+            {k: v for k, v in threads.items() if v is not None})
     return out
 
 
 def main(argv) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("path")
+    ap.add_argument("--spans", help="perf_tracer's Chrome JSON of the same run, laid over the trace")
     ap.add_argument("--gap-ms", type=float, default=1.0)
     ap.add_argument("--top", type=int, default=20)
     ap.add_argument("--depth", type=int, default=4, help="components of a scope path kept")
     ap.add_argument("--json", action="store_true", help="print the report as one JSON object")
     args = ap.parse_args(argv)
-    r = report(args.path, args.gap_ms, args.top, args.depth)
+    r = report(args.path, args.gap_ms, args.top, args.depth, args.spans)
     if args.json:
         print(json.dumps(r))
         return 0
+    if "record_clock" in r:
+        c = r["record_clock"]
+        print(f"record: placed on the trace's clock at {c['offset_ns'] / 1e9:+.6f} s; the window's "
+              f"two ends differ by {c['skew_ns'] / 1e6:.3f} ms")
     if "busy" in r:
         b = r["busy"]
         print(f"device: window {r['window_s']:.3f} s, busy {b['busy_s']:.3f} s, idle "
@@ -375,6 +446,13 @@ def main(argv) -> int:
         for span, thread, n, total, longest, first, last in r["gaps_by_span"]:
             print(f"  {n:6d} {1e3 * total:10.2f} {1e3 * longest:11.2f} {first:8.3f} {last:8.3f}  "
                   f"{span} ({thread})")
+        for name, seconds in r.get("longest_gaps", []):
+            print(f"  longest: {1e3 * seconds:10.2f} ms  {name}")
+        for thread, rows in r["gaps_by_thread"].items():
+            print(f"\nthe same gaps by what thread `{thread}` was in")
+            print(f"  {'count':>6} {'total ms':>10} {'longest ms':>11}  its innermost areal/ span")
+            for span, n, total, longest in rows:
+                print(f"  {n:6d} {1e3 * total:10.2f} {1e3 * longest:11.2f}  {span or 'no span'}")
     return 0
 
 
