@@ -100,6 +100,8 @@ from areal_tpu.models.qwen2 import (
     decode_load_len,
     ModelConfig,
     decode_step_paged,
+    GROUPED_MATMUL_ROW_TILE,
+    grouped_matmul_rows,
     prefill,
     verify_step_paged,
 )
@@ -621,6 +623,11 @@ class JaxDecodeEngine(InferenceEngine):
         # or a slot that has none), the live columns (_count_block_columns)
         self._paged_cols_live = 0
         self._paged_cols_visited = 0
+        # sparse layers' grouped matmuls dispatched (token steps x sparse
+        # layers), and those whose pair rows `grouped_matmul_rows` laid out
+        # for a finer row tile (_count_grouped_matmuls)
+        self._gmm_steps = 0
+        self._gmm_small_tile_steps = 0
         self._chunk_fns: dict[bool, Callable] = {}
         # speculative verify-chunk variants, keyed (use_topp, nb, W)
         self._verify_fns: dict[tuple, Callable] = {}
@@ -922,6 +929,8 @@ class JaxDecodeEngine(InferenceEngine):
             self._gdn_state_updates = 0
             self._paged_cols_live = 0
             self._paged_cols_visited = 0
+            self._gmm_steps = 0
+            self._gmm_small_tile_steps = 0
             self._spec_hist = np.zeros(
                 max(int(self.config.spec_k), 1) + 1, dtype=np.int64
             )
@@ -1043,11 +1052,18 @@ class JaxDecodeEngine(InferenceEngine):
             self._thread = None
         with self._metrics_lock:
             live, visited = self._paged_cols_live, self._paged_cols_visited
+            gmm, gmm_small = self._gmm_steps, self._gmm_small_tile_steps
             chunks = self._chunks_dispatched
         if visited:
             logger.info(
                 f"paged kernel: {live} of {visited} steps a live block column "
                 f"({100.0 * live / visited:.1f}%) over {chunks} chunks"
+            )
+        if gmm:
+            logger.info(
+                f"grouped matmuls: {gmm_small} of {gmm} sparse-layer steps laid "
+                f"out for a {GROUPED_MATMUL_ROW_TILE}-row tile "
+                f"({100.0 * gmm_small / gmm:.1f}%) over {chunks} chunks"
             )
         if self._executor is not None:
             self._executor.destroy()
@@ -3658,6 +3674,23 @@ class JaxDecodeEngine(InferenceEngine):
             self._paged_cols_live += columns
             self._paged_cols_visited += columns + active.size - live.size
 
+    def _count_grouped_matmuls(self, steps: int, tokens: int) -> None:
+        """`moe_grouped_matmul_{,small_tile_}steps_total` for the chunk just
+        dispatched, from its program's static shapes: `steps` token steps,
+        each of which hands every sparse layer's grouped matmuls `tokens`
+        rows x top-k pairs; the second counts those whose row count
+        `models/qwen2.grouped_matmul_rows` changed. Nothing for a dense
+        model."""
+        cfg = self.model_config
+        if not cfg.num_experts:
+            return
+        layers = sum(map(cfg.layer_sparse, range(cfg.num_hidden_layers)))
+        rows = tokens * cfg.num_experts_per_tok
+        small = grouped_matmul_rows(rows, cfg.num_experts) != rows
+        with self._metrics_lock:
+            self._gmm_steps += steps * layers
+            self._gmm_small_tile_steps += steps * layers * small
+
     def _dispatch_chunk(self, active: np.ndarray) -> "_Inflight | None":
         R = self.config.max_running_requests
         n_chunk = self.config.new_tokens_per_chunk
@@ -3848,6 +3881,7 @@ class JaxDecodeEngine(InferenceEngine):
             self._slot_lengths[active] += spec_w
             self._cache_written(active)
             self._count_block_columns(active, nb)
+            self._count_grouped_matmuls(1, R * spec_w)
             with self._metrics_lock:
                 self._chunks_dispatched += 1
             return _Inflight(
@@ -3910,6 +3944,7 @@ class JaxDecodeEngine(InferenceEngine):
         self._slot_lengths[active] += n_chunk
         self._cache_written(active)
         self._count_block_columns(active, nb)
+        self._count_grouped_matmuls(n_chunk, R)
         with self._metrics_lock:
             self._chunks_dispatched += 1
         return _Inflight(
@@ -5506,6 +5541,7 @@ class JaxDecodeEngine(InferenceEngine):
             kv_rows_read = self._kv_full_rows_read, self._kv_window_rows_read
             gdn_updates = self._gdn_state_updates
             paged_cols = self._paged_cols_live, self._paged_cols_visited
+            gmm_steps = self._gmm_steps, self._gmm_small_tile_steps
             table_uploads = self._table_uploads
             spec_hist = self._spec_hist.copy()
             spec_chunk_slots = self._spec_chunk_slots
@@ -5613,6 +5649,10 @@ class JaxDecodeEngine(InferenceEngine):
             # paged kernel takes (those, and one a slot with none)
             "paged_block_columns_live_total": paged_cols[0],
             "paged_block_columns_visited_total": paged_cols[1],
+            # sparse-layer token steps dispatched, and those whose pair rows
+            # were laid out for the grouped matmul's finer row tile
+            "moe_grouped_matmul_steps_total": gmm_steps[0],
+            "moe_grouped_matmul_small_tile_steps_total": gmm_steps[1],
             "device_busy_s": round(dev_busy_s, 6),
             "device_idle_s": round(dev_idle_s, 6),
             "device_idle_frac": (

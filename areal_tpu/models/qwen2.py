@@ -1687,6 +1687,27 @@ def mlp(layer_p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     return _cstr(out, "tokens", "act_embed")
 
 
+# XLA:TPU's grouped matmul (`jax.lax.ragged_dot`) takes as its row tile the
+# largest power of two, at most 512, that divides the row count, and visits
+# each group's rows a whole tile at a time: 64 slots x top-8 = 512 pair rows
+# run a 512-row tile for the 3-8 rows a group holds. This is the tile such
+# few rows are laid out for (`tests/test_trace_names.py` pins XLA's rule).
+GROUPED_MATMUL_ROW_TILE = 128
+
+
+def grouped_matmul_rows(rows: int, groups: int) -> int:
+    """The row count `_expert_mixture_plain` lays `rows` sorted pair rows out
+    at for grouped matmuls over `groups` groups: `rows` itself where a group
+    holds a tile's rows or more on average (a prefill bucket, a trainer's
+    batch: the 512 tile is right for them) or where XLA's tile for `rows` is
+    already `GROUPED_MATMUL_ROW_TILE` or finer; else one tile of zero rows
+    more, which leaves that tile the largest power of two dividing it."""
+    tile = GROUPED_MATMUL_ROW_TILE
+    if rows >= groups * tile or rows % (2 * tile):
+        return rows
+    return rows + tile
+
+
 def _expert_mixture_plain(
     act, E, x, expert, gates, gate_k, up_k, down_k, first_group=None
 ):
@@ -1721,11 +1742,17 @@ def _expert_mixture_plain(
         # zero them going in and coming out, so nothing (and no gradient)
         # of a pad row is ever read
         xs = jnp.where(live[:, None], x[order // K], 0)  # [T*K, H]
+        # few rows a group: more zero rows past the last group, which set
+        # the grouped matmuls' row tile (`grouped_matmul_rows`)
+        pad = grouped_matmul_rows(T * K, E) - T * K
+        if pad:
+            xs = jnp.concatenate([xs, jnp.zeros((pad, H), xs.dtype)])
     with jax.named_scope("experts"):
         h_gate = jax.lax.ragged_dot(xs, gate_k, group_sizes)
         h_up = jax.lax.ragged_dot(xs, up_k, group_sizes)
         ys = jax.lax.ragged_dot(act(h_gate) * h_up, down_k, group_sizes)
     with jax.named_scope("combine"):
+        ys = ys[: T * K]
         ys = jnp.where(live[:, None], ys, 0)
         # back to pair order [T, K, H] by the inverse permutation (a gather,
         # not a scatter-add), then the gate-weighted sum in float32

@@ -631,12 +631,10 @@ def test_grouped_expert_matmuls_are_named_at_olmoes_widths(one_chip, tokens):
     assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
 
 
-def test_stacked_decode_step_reads_olmoes_experts_in_place(one_chip):
-    """A decode step over two stacked layers at OLMoE's widths: the layer
-    loop holds three `%ragged-dot-none` calls whose weight operand is the
-    whole stacked leaf, and nothing (the parent had a
-    `%dynamic-slice_bitcast_fusion` per kernel, 0.68 ms each on the chip)
-    has one layer's kernels as its result."""
+@pytest.fixture(scope="module")
+def stacked_olmoe_step(one_chip):
+    """The compiled decode step over two stacked layers at OLMoE's widths,
+    64 slots."""
     from areal_tpu.models.qwen2 import decode_step_paged, param_shapes
 
     L, R, nb, bsz = 2, 64, 2, 128
@@ -656,10 +654,19 @@ def test_stacked_decode_step_reads_olmoes_experts_in_place(one_chip):
         return decode_step_paged(params, tokens, positions, kp, vp, bt, cfg,
                                  active=active, attn_impl="pallas", moe_load=True)
 
-    hlo = jax.jit(step, donate_argnums=(1, 2)).trace(
+    return jax.jit(step, donate_argnums=(1, 2)).trace(
         params, pool, pool, arg((R, nb), jnp.int32), arg((R,), jnp.int32),
         arg((R,), jnp.int32), arg((R,), jnp.bool_),
     ).lower(lowering_platforms=("tpu",)).compile().as_text()
+
+
+def test_stacked_decode_step_reads_olmoes_experts_in_place(stacked_olmoe_step):
+    """A decode step over two stacked layers at OLMoE's widths: the layer
+    loop holds three `%ragged-dot-none` calls whose weight operand is the
+    whole stacked leaf, and nothing (the parent had a
+    `%dynamic-slice_bitcast_fusion` per kernel, 0.68 ms each on the chip)
+    has one layer's kernels as its result."""
+    hlo = stacked_olmoe_step
     calls = re.findall(r"%ragged-dot-none(?:\.\d+)? = \S+ custom-call\(([^)]*)\)", hlo)
     assert len(calls) == 3, calls
     # each reads the stacked leaf itself: `[L*E, H, M]`, a bitcast of the argument
@@ -668,6 +675,60 @@ def test_stacked_decode_step_reads_olmoes_experts_in_place(one_chip):
     assert all(set(c.replace(" ", "").split(",")) & stacked for c in calls), (calls, stacked)
     copied = re.findall(r"%(\S+) = bf16\[64,(?:2048,1024|1024,2048)\]", hlo)
     assert not copied, copied
+
+
+# (tokens, hidden, expert width, experts held, published, top-k, sigmoid scores): one
+# sparse layer's `moe_mlp` at a cell's widths
+_OLMOE = (2048, 1024, 64, 64, 8, False)
+_GROUPED_LAYERS = {
+    "olmoe_64_slots": ((64, *_OLMOE), 640, "128,512,512"),
+    "kexaone_64_slots_16_of_128_held": ((64, 6144, 2048, 16, 128, 8, True), 640, "128,512,512"),
+    "qwen3next_64_slots_64_of_512_held": ((64, 2048, 512, 64, 512, 10, False), 640, "128,512,512"),
+    "olmoe_prefill_bucket_2048": ((2048, *_OLMOE), 16384, "512,512,512"),
+}
+
+
+def _grouped_matmul_calls(hlo: str) -> tuple[set, set, set]:
+    """(names, row counts, `ragged_dot_tiling`s) of a compiled program's
+    `%ragged-dot-none` custom calls."""
+    calls = re.findall(
+        r"%(ragged-dot-none(?:\.\d+)?) = \w+\[(\d+),\d+\]\S* custom-call\(.*"
+        r"ragged_dot_tiling\W+([\d,]+)", hlo)
+    return tuple(set(c[i] for c in calls) for i in range(3))
+
+
+@pytest.mark.parametrize("shape", [*_GROUPED_LAYERS, "olmoe_64_slots_two_stacked_layers"])
+def test_grouped_matmuls_row_tile_is_pinned(one_chip, shape, request):
+    """XLA:TPU tiles `jax.lax.ragged_dot`'s rows by the largest power of
+    two, at most 512, that divides their count: that is the rule
+    `grouped_matmul_rows` lays a decode step's few rows a group out for (512
+    pair rows as 640: a 128-row tile where 512 rows would run a 512-row tile
+    for 3-8 rows a group), and leaves a prefill bucket's rows at. Three
+    `%ragged-dot-none` calls a layer in each. An XLA whose rule is another
+    fails here, not in a ledger line."""
+    from areal_tpu.models.qwen2 import grouped_matmul_rows, moe_mlp
+
+    if shape == "olmoe_64_slots_two_stacked_layers":
+        hlo, rows, tiling = request.getfixturevalue("stacked_olmoe_step"), 640, "128,512,512"
+    else:
+        (tokens, H, M, E, E_pub, K, sigmoid), rows, tiling = _GROUPED_LAYERS[shape]
+        assert grouped_matmul_rows(tokens * K, E) == rows
+        cfg = ModelConfig(
+            hidden_size=H, num_experts=E, num_experts_published=E_pub, num_experts_per_tok=K,
+            moe_intermediate_size=M, norm_topk_prob=sigmoid,
+            moe_scoring="sigmoid" if sigmoid else "softmax")
+
+        def layer_mlp(x, valid, router, gate, up, down):
+            p = dict(router_kernel=router, gate_kernel=gate, up_kernel=up, down_kernel=down)
+            return moe_mlp(p, x, cfg, valid=valid, with_load=True)
+
+        bf = jnp.bfloat16
+        hlo = _compile(
+            layer_mlp, one_chip, ((tokens, H), bf), ((tokens,), jnp.bool_), ((H, E_pub), bf),
+            ((E, H, M), bf), ((E, H, M), bf), ((E, M, H), bf))
+    names, row_counts, tilings = _grouped_matmul_calls(hlo)
+    assert len(names) == 3, names
+    assert row_counts == {str(rows)} and tilings == {tiling}, (row_counts, tilings)
 
 
 def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):

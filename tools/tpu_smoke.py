@@ -360,7 +360,7 @@ def gdn_step_case(n=6, layer=4, R=64, Hv=32, dk=128, dv=128, dead_every=7):
     return err < 1e-4 and kept and bool(jnp.all(jnp.isfinite(o))), f"maxerr={err:.2e} kept={kept}"
 
 
-def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5):
+def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5, published=None):
     """The exact MoE mixture (`models/qwen2.py:moe_mlp`: pairs sorted by
     expert, XLA's grouped matmul for `jax.lax.ragged_dot`) at OLMoE's
     published widths against every expert run densely on every token in
@@ -373,16 +373,25 @@ def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5):
     all the layers `[layers*E, H, M]`, layer `li`'s experts are the groups
     from `li*E` on and the others are empty; also timed against the same
     call on that layer's own `[E, H, M]` kernels, since the empty groups
-    must cost nothing."""
+    must cost nothing.
+
+    With `published`, a chip's share of the experts (K-EXAONE: 16 held of
+    128): the router is `published` wide, every token picks its K among all
+    of them, and the E held here compute the pairs that land on them.
+
+    At 64 tokens the 512 pair rows are few a group, so `grouped_matmul_rows`
+    lays them out at 640 (the grouped matmul's 128-row tile); a 2,048-token
+    bucket's 16,384 keep the 512-row tile: `rows=` in the detail."""
     import time
 
-    from areal_tpu.models.qwen2 import ModelConfig, moe_mlp
+    from areal_tpu.models.qwen2 import ModelConfig, grouped_matmul_rows, moe_mlp
 
-    cfg = ModelConfig(hidden_size=H, num_experts=E, num_experts_per_tok=K,
-                      moe_intermediate_size=M, norm_topk_prob=False)
+    E_pub = published or E
+    cfg = ModelConfig(hidden_size=H, num_experts=E, num_experts_published=published,
+                      num_experts_per_tok=K, moe_intermediate_size=M, norm_topk_prob=False)
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
     G = layers * E
-    router = jax.random.normal(ks[0], (H, E), jnp.bfloat16) / H**0.5
+    router = jax.random.normal(ks[0], (H, E_pub), jnp.bfloat16) / H**0.5
     stack = {
         "gate_kernel": jax.random.normal(ks[1], (G, H, M), jnp.bfloat16) / H**0.5,
         "up_kernel": jax.random.normal(ks[2], (G, H, M), jnp.bfloat16) / H**0.5,
@@ -400,7 +409,8 @@ def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5):
             x32 = x.astype(jnp.float32)
             probs = jax.nn.softmax(x32 @ p["router_kernel"].astype(jnp.float32), -1)
             w, idx = jax.lax.top_k(probs, K)
-            dw = (jax.nn.one_hot(idx, E) * w[..., None]).sum(1) * valid[:, None]
+            # the weights of the experts held here (the first E of the router's width)
+            dw = (jax.nn.one_hot(idx, E_pub) * w[..., None]).sum(1)[:, :E] * valid[:, None]
 
             def one(acc, xs):
                 g, u, d, w_e = (a.astype(jnp.float32) for a in xs)
@@ -414,8 +424,12 @@ def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5):
     ref = jax.jit(dense)(p, x)
     err = _rel(y.astype(jnp.float32), ref)
     dead = float(jnp.abs(y[~valid].astype(jnp.float32)).max())
-    ok = err < 0.03 and dead == 0.0 and int(load[0]) == int(valid.sum()) * K
-    detail = f"relerr={err:.4f} dead_rows_max={dead} pairs={int(load[0])} hot={int(load[1])}"
+    # every valid pair is computed here or, with a share held, counted absent
+    absent = int(load[2]) if published else 0
+    ok = (err < 0.03 and dead == 0.0 and int(load[0]) > 0
+          and int(load[0]) + absent == int(valid.sum()) * K)
+    detail = (f"relerr={err:.4f} dead_rows_max={dead} pairs={int(load[0])} hot={int(load[1])} "
+              f"rows={T * K}->{grouped_matmul_rows(T * K, E)}")
     if layers == 1:
         return ok, detail
 
@@ -575,6 +589,12 @@ def cases():
         "moe_mlp 64 live of 512 groups (8 layers' experts in place) T=64 2048x1024",
         "default for MoE models with stacked layers",
         lambda: moe_case(64, layers=8, li=5),
+    ))
+    # K-EXAONE's share of a layer: 16 held of 128 experts, 3-4 pair rows each
+    out.append((
+        "moe_mlp top-8 of 128, 16 held (ragged_dot) T=64 6144x2048",
+        "default for MoE models with a share of the experts held",
+        lambda: moe_case(64, H=6144, M=2048, E=16, published=128),
     ))
     for k_dims, out_dims in (
         ((HIDDEN,), (N_HEADS, HEAD_DIM)),  # q
