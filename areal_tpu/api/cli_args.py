@@ -437,6 +437,16 @@ class JaxDecodeConfig:
     # longest trailing n-gram matched against the slot's earlier context
     # (matching tries spec_ngram_max down to 1, longest match wins)
     spec_ngram_max: int = 3
+    # Block-diffusion models (a ModelConfig with block_length > 1; others
+    # ignore these): denoise forwards a block of block_length positions
+    # before its commit forward; which masked positions a forward reveals,
+    # "low_confidence_static" (the block_length // steps most confident, one
+    # more in the first block_length % steps steps) or
+    # "low_confidence_dynamic" (every one above diffusion_threshold, and at
+    # least the static quota). Statics of the chunk program.
+    diffusion_steps: int = 4
+    diffusion_strategy: str = "low_confidence_static"
+    diffusion_threshold: float = 0.9
     skip_tokenizer_init: bool = False
     log_level: str = "info"
     # Server-side idempotency table (launcher/decode_server.py): /generate

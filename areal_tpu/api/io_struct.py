@@ -51,6 +51,12 @@ class ModelResponse:
     # Weight version that produced each output token — the heart of the
     # async/staleness bookkeeping (reference io_struct.py:48).
     output_versions: list[int] = field(default_factory=list)
+    # A block-diffusion model only (empty otherwise): the denoise step of its
+    # block at which each output token was revealed, 0-based. With the tokens
+    # it rebuilds the state every token was sampled from: at step s of a
+    # block, the positions revealed at steps < s (and the prompt's own) held
+    # their tokens and every other held the mask token.
+    output_reveal_steps: list[int] = field(default_factory=list)
     # "prefill": a prefill-only request (disaggregated fleets) — the KV is
     # parked/exported, no tokens were generated by design.
     stop_reason: Literal["length", "stop", "interrupt", "prefill"] = "stop"

@@ -110,6 +110,9 @@ class RemoteBackend:
             "output_tokens": [int(t) for t in data["output_tokens"]],
             "output_logprobs": [float(x) for x in data["output_logprobs"]],
             "output_versions": [int(v) for v in data.get("output_versions", [])],
+            "output_reveal_steps": [
+                int(v) for v in data.get("output_reveal_steps", [])
+            ],
             "stop_reason": data["stop_reason"],
         }
 
@@ -546,6 +549,7 @@ class RemoteInfEngine(InferenceEngine):
         acc_tokens: list[int] = []
         acc_logprobs: list[float] = []
         acc_versions: list[int] = []
+        acc_reveal_steps: list[int] = []  # a block-diffusion server only
         stop_reason = "interrupt"
         ttft = float("inf")
         try:
@@ -587,6 +591,7 @@ class RemoteInfEngine(InferenceEngine):
                     out["output_tokens"]
                 )
                 acc_versions.extend(versions)
+                acc_reveal_steps.extend(out.get("output_reveal_steps", []))
                 if ttft == float("inf") and out["output_tokens"]:
                     ttft = time.monotonic() - start
                 stop_reason = out["stop_reason"]
@@ -614,6 +619,7 @@ class RemoteInfEngine(InferenceEngine):
             output_tokens=acc_tokens,
             output_logprobs=acc_logprobs,
             output_versions=acc_versions,
+            output_reveal_steps=acc_reveal_steps,
             stop_reason=stop_reason,  # type: ignore[arg-type]
             latency=time.monotonic() - start,
             ttft=ttft,

@@ -100,6 +100,7 @@ from areal_tpu.models.qwen2 import (
     decode_load_len,
     ModelConfig,
     decode_step_paged,
+    diffusion_step_paged,
     GROUPED_MATMUL_ROW_TILE,
     grouped_matmul_rows,
     prefill,
@@ -152,6 +153,7 @@ _GUARDED_BY = {
     "JaxDecodeEngine._dev_table_key": "_sched_lock",
     "JaxDecodeEngine._dev_last": "_sched_lock",
     "JaxDecodeEngine._dev_lengths": "_sched_lock",
+    "JaxDecodeEngine._dev_block": "_sched_lock",
     # compiled-fn caches: populated lazily by the scheduler, cleared by
     # destroy() (thread already joined) and warmed by prewarm (pause-fenced)
     "JaxDecodeEngine._patch_fn": "_sched_lock",
@@ -322,6 +324,8 @@ class _Slot:
     tokens: list[int] = field(default_factory=list)
     logprobs: list[float] = field(default_factory=list)
     versions: list[int] = field(default_factory=list)
+    # a block-diffusion model: the denoise step each token was revealed at
+    reveal_steps: list[int] = field(default_factory=list)
     # per-token inter-token latency; chunked decode can only observe the
     # chunk wall clock, so each token in a chunk gets chunk_dt / n_chunk
     itl: list[float] = field(default_factory=list)
@@ -347,6 +351,8 @@ class _Slot:
 
 # the scheduler thread's states (`sched_<state>_secs_total`), each exclusive
 # of what is nested in it
+DIFFUSION_STRATEGIES = ("low_confidence_static", "low_confidence_dynamic")
+
 SCHED_STATES = ("admit", "prefill", "dispatch", "consume", "wait_device",
                 "paused", "idle", "other")
 
@@ -384,6 +390,13 @@ class _Inflight:
     # MoE models: jax int32 [2], the chunk's expert load (see _get_chunk_fn)
     moe_load: Any = None
     draft_lens: np.ndarray | None = None  # [R] host draft lengths dispatched
+    # -- block-diffusion chunks (a model with block_length > 1) ---------
+    # `toks` / `logps` / `steps` are [n_chunk, R]: the blocks each slot
+    # committed, in order, `blocks[r]` of them (block_length rows each);
+    # `steps` is the denoise step a position was revealed at, -1 where the
+    # prompt gave it
+    steps: Any = None
+    blocks: Any = None  # jax [R] (NumPy once consumed)
 
 
 class JaxDecodeEngine(InferenceEngine):
@@ -629,6 +642,21 @@ class JaxDecodeEngine(InferenceEngine):
         self._gmm_steps = 0
         self._gmm_small_tile_steps = 0
         self._chunk_fns: dict[bool, Callable] = {}
+        # -- block diffusion (a model with block_length > 1; see
+        # _get_diffusion_chunk_fn) ------------------------------------
+        self._diffusion = False  # resolved in initialize()
+        # the in-flight block of every slot, device state chained chunk to
+        # chunk like last/lengths: (tokens [R, B], revealed [R, B], logprobs
+        # [R, B], reveal steps [R, B], denoise forwards done [R])
+        self._dev_block = None
+        # live slots x forwards, those that were a slot's commit pass, blocks
+        # committed, tokens of committed blocks dropped at a stop, and cached
+        # rows the blocks' attention read (all under _metrics_lock)
+        self._dfn_slot_forwards = 0
+        self._dfn_commit_forwards = 0
+        self._dfn_blocks = 0
+        self._dfn_tokens_discarded = 0
+        self._kv_block_rows_read = 0
         # speculative verify-chunk variants, keyed (use_topp, nb, W)
         self._verify_fns: dict[tuple, Callable] = {}
         # -- speculative decoding (spec_decode="ngram") accounting -----
@@ -790,6 +818,12 @@ class JaxDecodeEngine(InferenceEngine):
         else:
             n_blocks = R * max_bps + 1
         self._ring = self._state = None
+        self._diffusion = cfg.block_length_ > 1
+        if self._diffusion:
+            self._refuse_unserved_for_diffusion()
+            # a prefix found by content ends where its blocks of the POOL
+            # end, not on a block boundary of the mask
+            self._fabric_on = False
         if cfg.mixed:
             self._refuse_unserved_for_mixed(bs)
             if cfg.cache_layers["window"]:
@@ -911,8 +945,14 @@ class JaxDecodeEngine(InferenceEngine):
         self._dev_table_key = None
         self._dev_last = None
         self._dev_lengths = None
+        self._dev_block = None
         self._patch_slots = set()
         with self._metrics_lock:
+            self._dfn_slot_forwards = 0
+            self._dfn_commit_forwards = 0
+            self._dfn_blocks = 0
+            self._dfn_tokens_discarded = 0
+            self._kv_block_rows_read = 0
             self._table_uploads = 0
             self._dev_busy_s = 0.0
             self._dev_idle_s = 0.0
@@ -1008,6 +1048,76 @@ class JaxDecodeEngine(InferenceEngine):
                 "stack) is not served with: " + "; ".join(refused)
             )
 
+    def _refuse_unserved_for_diffusion(self) -> None:
+        """A block-diffusion model's cache is valid a whole block at a time
+        (a block's rows are written for good by its commit forward), and a
+        chunk returns blocks, not one token a forward. What assumes
+        otherwise is not served for it yet: say so here, with the reason,
+        and not at the first request that needs it."""
+        cfg, c = self.model_config, self.config
+        B = cfg.block_length_
+        refused = []
+        if cfg.mask_token_id is None or not 0 <= cfg.mask_token_id < cfg.vocab_size:
+            refused.append(
+                f"mask_token_id={cfg.mask_token_id!r}: the mask token has to "
+                f"be an embedding row (vocab_size={cfg.vocab_size})"
+            )
+        if cfg.mixed or cfg.sliding_window is not None:
+            refused.append(
+                "window, linear or leading dense layers: the block step "
+                "reads one paged pool under the block-causal mask alone"
+            )
+        if c.spec_decode != "off":
+            refused.append(
+                f"spec_decode={c.spec_decode!r}: a verify chunk drafts the "
+                "NEXT token of a causal model; a block is denoised in place"
+            )
+        if float(c.kv_host_pool_mb) > 0:
+            refused.append(
+                "kv_host_pool_mb > 0: the host tier restores a prefix cut at "
+                "any token; this cache is valid at block boundaries only"
+            )
+        if getattr(c, "role", "unified") != "unified":
+            refused.append(
+                f"role={c.role!r}: a prefill-only park and the migration "
+                "wire cover prompt[:-1], which ends inside a block"
+            )
+        if c.kv_dtype != "fp":
+            refused.append(
+                f"kv_dtype={c.kv_dtype!r}: the block step through an int8 "
+                "pool has not been held to the reference"
+            )
+        if int(c.context_length) % B or int(c.new_tokens_per_chunk) % B:
+            refused.append(
+                f"context_length={c.context_length} and new_tokens_per_chunk="
+                f"{c.new_tokens_per_chunk} have to be whole numbers of blocks "
+                f"of {B}"
+            )
+        if int(c.diffusion_steps) < 1:
+            refused.append(f"diffusion_steps={c.diffusion_steps} < 1")
+        if c.diffusion_strategy not in DIFFUSION_STRATEGIES:
+            refused.append(
+                f"diffusion_strategy={c.diffusion_strategy!r} not in "
+                f"{DIFFUSION_STRATEGIES}"
+            )
+        if self._vision_params is not None:
+            refused.append("a vision tower: image rows have no block mask")
+        if refused:
+            raise NotImplementedError(
+                f"{cfg.model_type} (generation by diffusion over blocks of "
+                f"{B}) is not served with: " + "; ".join(refused)
+            )
+
+    def _cover(self, n: int) -> int:
+        """Of a sequence of `n` tokens, how many have their rows cached when
+        it is admitted: all but the last, which the chunk's first step takes
+        as its input; for a block-diffusion model the whole blocks, the rest
+        being the revealed head of the first block it denoises."""
+        if self._diffusion:
+            B = self.model_config.block_length_
+            return (n // B) * B
+        return n - 1
+
     def _kv_row_nbytes(self) -> int:
         """Bytes of one cached row of one layer, K and V (fp pools)."""
         cfg = self.model_config
@@ -1038,6 +1148,11 @@ class JaxDecodeEngine(InferenceEngine):
         )
 
     def _no_migration_for_mixed(self, what: str) -> None:
+        if self._diffusion:
+            raise NotImplementedError(
+                f"{what}: a block-diffusion model's parked KV ends on a block "
+                "boundary of its mask; the migration wire carries prompt[:-1]"
+            )
         if self.model_config is not None and self.model_config.mixed:
             raise NotImplementedError(
                 f"{what}: a mixed stack's parked KV is a paged pool and a "
@@ -1054,6 +1169,14 @@ class JaxDecodeEngine(InferenceEngine):
             live, visited = self._paged_cols_live, self._paged_cols_visited
             gmm, gmm_small = self._gmm_steps, self._gmm_small_tile_steps
             chunks = self._chunks_dispatched
+            dfn_forwards, dfn_tokens = self._dfn_slot_forwards, self._gen_token_count
+        if dfn_forwards and dfn_tokens:
+            logger.info(
+                f"block diffusion: {dfn_forwards} slot forwards for "
+                f"{dfn_tokens} tokens returned "
+                f"({dfn_forwards / dfn_tokens:.3f} forwards a token) over "
+                f"{chunks} chunks"
+            )
         if visited:
             logger.info(
                 f"paged kernel: {live} of {visited} steps a live block column "
@@ -1088,6 +1211,7 @@ class JaxDecodeEngine(InferenceEngine):
         self._dev_table_key = None
         self._dev_last = None
         self._dev_lengths = None
+        self._dev_block = None
         self._patch_fn = None
         self._vision_fns.clear()
         self._embed_prefill_fns.clear()
@@ -1628,6 +1752,228 @@ class JaxDecodeEngine(InferenceEngine):
         )
         self._chunk_fns[key_] = fn
         return fn
+
+    def _diffusion_forwards(self) -> int:
+        """Forwards of one block-diffusion chunk: what its
+        `new_tokens_per_chunk // block_length` blocks take when every one
+        is denoised from all masks, `diffusion_steps` forwards and a commit."""
+        B = self.model_config.block_length_
+        return (int(self.config.new_tokens_per_chunk) // B) * (
+            min(int(self.config.diffusion_steps), B) + 1
+        )
+
+    def _get_diffusion_chunk_fn(self, use_topp: bool, nb: int):
+        """The chunk program of a block-diffusion model (`block_length` B > 1):
+        a `lax.scan` over forwards with the pool as carry. A forward runs
+        every live slot's in-flight block of B positions through
+        `models/qwen2.diffusion_step_paged`, and each slot is in its own
+        state:
+
+        - denoising (some position still holds the mask token): every masked
+          position samples `x0` through the engine's sampler (so top-p and
+          greedy keep their meaning) with `conf = p(x0)`, the probability the
+          sampler reports; the static strategy reveals the `n_s` most
+          confident masked positions (`n_s = B // S` plus one in the first
+          `B % S` steps, ties to the lower position), the dynamic one every
+          position with `conf > threshold` and at least those; a revealed
+          token keeps the log-probability and the step it was revealed at.
+          The rows this forward wrote are dead: the commit writes them again.
+        - clean (no mask left): this forward was its commit, over the clean
+          block, and its rows are written for good. The block is emitted
+          (tokens, log-probabilities, reveal steps), `lengths` advances by B
+          and a fresh all-mask block is loaded. A slot commits at most
+          `new_tokens_per_chunk // B` blocks a chunk, which is what its
+          pages were provisioned for; past that a clean block waits.
+
+        Slots are not synchronised, so the dynamic strategy, a first block
+        whose head the prompt's last `P % B` tokens give, and a slot admitted
+        between chunks cost nothing extra. The in-flight block is device
+        state chained chunk to chunk (`_dev_block`), patched like
+        `last`/`lengths` (`_get_diffusion_patch_fn`).
+
+        PRNG: `fold_in(fold_in(base_key, position), denoise_step)`, so a
+        stream is invariant to chunk boundaries, to which slots share the
+        batch and to run-ahead, as a causal model's is.
+
+        Returns (kp, vp, block state, lengths, toks [N, R], logps [N, R],
+        steps [N, R], blocks [R], load) with N = `new_tokens_per_chunk`: a
+        slot's committed blocks in order, and the int32 load vector
+        [pairs, busiest expert's pairs, cached rows read, live slots x
+        forwards, of those commit forwards] summed over the chunk."""
+        key_ = (use_topp, False, nb)
+        if key_ in self._chunk_fns:
+            return self._chunk_fns[key_]
+        cfg = self.model_config
+        B = cfg.block_length_
+        mask_id = int(cfg.mask_token_id)
+        n_chunk = int(self.config.new_tokens_per_chunk)
+        max_blocks = n_chunk // B
+        n_forwards = self._diffusion_forwards()
+        S = min(int(self.config.diffusion_steps), B)
+        dynamic = self.config.diffusion_strategy == "low_confidence_dynamic"
+        threshold = float(self.config.diffusion_threshold)
+        paged_impl = self._paged_impl
+        sample = _make_sample_fn(use_topp)
+        fold = jax.vmap(jax.random.fold_in)
+
+        def chunk_diffusion(params, kp, vp, bt, block, lengths, active,
+                            base_keys, temps, top_ps, greedy, rope_delta):
+            R = lengths.shape[0]
+            col = jnp.arange(B, dtype=jnp.int32)
+            rep = lambda a: jnp.repeat(a, B, axis=0)  # noqa: E731
+
+            @jax.named_scope("unmask")
+            def unmask(x0, x0_logp, known, n_step):
+                """Which masked positions this forward reveals: [R, B]."""
+                conf = jnp.where(known, -1.0, jnp.exp(x0_logp))
+                ahead = (conf[:, :, None] < conf[:, None, :]) | (
+                    (conf[:, :, None] == conf[:, None, :])
+                    & (col[None, :, None] > col[None, None, :])
+                )
+                rank = ahead.sum(axis=-1)  # more confident positions before j
+                n_s = B // S + (n_step < B % S).astype(jnp.int32)
+                rev = rank < n_s[:, None]
+                if dynamic:
+                    rev = rev | (conf > threshold)
+                return rev & ~known
+
+            @jax.named_scope("decode_step")
+            def forward(carry, _):
+                (tok, known, logp, step, n_step), lengths, kpc, vpc, out, done, load = carry
+                clean = known.all(axis=1)
+                commit = active & clean & (done < max_blocks)
+                denoise = active & ~clean
+                with jax.named_scope("denoise"):
+                    logits, kpc, vpc, fwd_load = diffusion_step_paged(
+                        params, jnp.where(known, tok, mask_id), lengths, kpc,
+                        vpc, bt, cfg, active=active, rope_offset=rope_delta,
+                        attn_impl=paged_impl, moe_load=True,
+                    )
+                    pos = lengths[:, None] + col[None, :]
+                    subkeys = fold(
+                        fold(rep(base_keys), pos.reshape(-1)), rep(n_step)
+                    )
+                    x0, x0_logp = sample(
+                        logits.reshape(R * B, -1), subkeys, rep(temps),
+                        rep(top_ps), rep(greedy),
+                    )
+                    x0, x0_logp = x0.reshape(R, B), x0_logp.reshape(R, B)
+                    rev = unmask(x0, x0_logp, known, n_step) & denoise[:, None]
+                    tok = jnp.where(rev, x0.astype(tok.dtype), tok)
+                    logp = jnp.where(rev, x0_logp, logp)
+                    step = jnp.where(rev, n_step[:, None], step)
+                    known = known | rev
+                    n_step = n_step + denoise.astype(n_step.dtype)
+                with jax.named_scope("commit"):
+                    # this forward wrote a clean block's rows for good: emit
+                    # it, advance, and load a fresh block of masks
+                    at = jnp.where(commit, done * B, n_chunk)  # past the end: dropped
+                    rows = jnp.arange(R)[:, None]
+                    cols = at[:, None] + col[None, :]
+                    out = tuple(
+                        o.at[rows, cols].set(v, mode="drop")
+                        for o, v in zip(out, (tok, logp, step))
+                    )
+                    c = commit[:, None]
+                    tok = jnp.where(c, mask_id, tok)
+                    known = known & ~c
+                    logp = jnp.where(c, 0.0, logp)
+                    step = jnp.where(c, -1, step)
+                    n_step = jnp.where(commit, 0, n_step)
+                    lengths = lengths + B * commit.astype(lengths.dtype)
+                    done = done + commit.astype(done.dtype)
+                load = load + jnp.concatenate([
+                    fwd_load,
+                    jnp.stack([active.sum(), commit.sum()]).astype(
+                        fwd_load.dtype),
+                ])
+                return ((tok, known, logp, step, n_step), lengths, kpc, vpc,
+                        out, done, load), None
+
+            out0 = (
+                jnp.zeros((R, n_chunk), jnp.int32),
+                jnp.zeros((R, n_chunk), jnp.float32),
+                jnp.full((R, n_chunk), -1, jnp.int32),
+            )
+            init = (block, lengths, kp, vp, out0, jnp.zeros(R, jnp.int32),
+                    jnp.zeros(decode_load_len(cfg) + 3, jnp.int32))
+            (block, lengths, kp, vp, out, done, load), _ = jax.lax.scan(
+                forward, init, None, length=n_forwards
+            )
+            toks, logps, steps = (o.T for o in out)
+            return kp, vp, block, lengths, toks, logps, steps, done, load
+
+        # (its name starts with `jit_chunk`, which is how a device trace
+        # finds a chunk program of any model)
+        fn = jax.jit(chunk_diffusion, donate_argnums=(1, 2))
+        self._chunk_fns[key_] = fn
+        return fn
+
+    def _fresh_blocks(self, slots) -> tuple[np.ndarray, np.ndarray]:
+        """The block each of `slots` starts denoising, on the host: (tokens
+        [R, B], revealed [R, B]), rows of other slots zero. All masks but
+        for a request none of whose blocks is committed yet: the tail of its
+        prompt past the cached whole blocks is the block's revealed head."""
+        cfg = self.model_config
+        R, B = self.config.max_running_requests, cfg.block_length_
+        tok = np.full((R, B), int(cfg.mask_token_id), dtype=np.int32)
+        known = np.zeros((R, B), dtype=bool)
+        for i in slots:
+            s = self._slots[i]
+            if s is None:
+                continue
+            head = (list(s.prompt) + list(s.tokens))[int(self._slot_lengths[i]):]
+            assert len(head) < B, (len(head), B)
+            tok[i, : len(head)] = head
+            known[i, : len(head)] = True
+        return tok, known
+
+    def _get_diffusion_patch_fn(self):
+        """`_get_patch_fn` for a block-diffusion model: selected slots take
+        the host's length and a fresh block (`_fresh_blocks`); whatever block
+        they were denoising is dropped."""
+        if self._patch_fn is None:
+
+            def patch(block, lengths, mask, ptok, pknown, plen):
+                tok, known, logp, step, n_step = block
+                m = mask[:, None]
+                return (
+                    jnp.where(m, ptok, tok),
+                    jnp.where(m, pknown, known),
+                    jnp.where(m, 0.0, logp),
+                    jnp.where(m, -1, step),
+                    jnp.where(mask, 0, n_step),
+                ), jnp.where(mask, plen, lengths)
+
+            self._patch_fn = jax.jit(patch)
+        return self._patch_fn
+
+    def _patch_diffusion_state(self) -> None:
+        """The device-chained (block, lengths) brought to the host's truth
+        for `_patch_slots` (all slots when there is no state yet): called
+        where a causal model's dispatch patches (last, lengths)."""
+        R, B = self.config.max_running_requests, self.model_config.block_length_
+        everything = self._dev_block is None or self._dev_lengths is None
+        if everything:
+            self._dev_block = (
+                jnp.zeros((R, B), jnp.int32), jnp.ones((R, B), bool),
+                jnp.zeros((R, B), jnp.float32), jnp.full((R, B), -1, jnp.int32),
+                jnp.zeros(R, jnp.int32),
+            )
+            self._dev_lengths = jnp.asarray(np.array(self._slot_lengths))
+        slots = range(R) if everything else sorted(self._patch_slots)
+        mask = np.zeros(R, dtype=bool)
+        mask[list(slots)] = True
+        ptok, pknown = self._fresh_blocks(slots)
+        self._dev_block, self._dev_lengths = self._get_diffusion_patch_fn()(
+            self._dev_block,
+            self._dev_lengths,
+            jnp.asarray(mask),
+            jnp.asarray(ptok),
+            jnp.asarray(pknown),
+            jnp.asarray(np.array(self._slot_lengths)),  # no-alias copy
+        )
+        self._patch_slots.clear()
 
     def _spec_draft_buckets(self) -> list[int]:
         """Draft-width buckets a verify dispatch can pick (powers of two up
@@ -2772,7 +3118,7 @@ class JaxDecodeEngine(InferenceEngine):
             # never-consumed last one (_slot_lengths may be projected
             # ahead by dispatched-but-unconsumed chunks whose tokens the
             # reconcile will discard)
-            covered = len(item.prompt) - 1 + len(item.tokens)
+            covered = self._cover(len(item.prompt) + len(item.tokens))
             if covered > 0:
                 self._offload_slot_kv(
                     item.rid,
@@ -2800,8 +3146,8 @@ class JaxDecodeEngine(InferenceEngine):
         slot, covered, _ = entry
         cached = self._parked_tokens.get(item.rid, [])
         if (
-            covered == len(item.prompt) - 1
-            and cached == item.prompt[:-1]
+            covered == self._cover(len(item.prompt))
+            and cached == item.prompt[:covered]
             and self._ring_holds(slot, covered)
         ):
             self._parked.pop(item.rid)
@@ -2856,6 +3202,9 @@ class JaxDecodeEngine(InferenceEngine):
             # decoding continues exactly where it stopped.
             prompt = list(item.prompt) + list(item.tokens)
             P = len(prompt)
+            # C: the head of it whose rows are cached at admission (all but the last; a
+            # block-diffusion model: its whole blocks)
+            C = self._cover(P)
             if (
                 len(item.prompt) + item.gconfig.max_new_tokens
                 > self.config.context_length
@@ -2865,34 +3214,34 @@ class JaxDecodeEngine(InferenceEngine):
             # bucket may not exceed the KV cache's sequence capacity —
             # writing a [bucket]-row update into a shorter cache is malformed
             needs_prefill_bucket = (
-                min(_next_bucket(P - 1), self.config.context_length)
-                if P > 1
+                min(_next_bucket(C), self.config.context_length)
+                if C > 0
                 else 0
             )
             # Meta-only drained sessions (cheap drain over the KV fabric)
             # surrender their sampling identity here, then fall through
             # the ladder like a fresh request — fabric blocks or an
             # honest prefill rebuild the KV.
-            if P > 1:
+            if C > 0:
                 self._claim_meta_identity(item)
             # Host-tier peek FIRST: an exact offloaded match means this
             # resume needs neither prefill work nor a donor fork — the
             # original KV bytes come back from host RAM (bit-identical,
             # where a donor's rows are merely same-tokens-same-weights).
-            host_hit = P > 1 and self._host_match(
-                item.rid, P - 1, prompt[:-1]
+            host_hit = C > 0 and self._host_match(
+                item.rid, C, prompt[:C]
             )
             # Prefix-KV lookup (decided once, here, so the budget gate can
             # wave forks through: a fork is a memcpy, not prefill work).
             # Image requests are excluded — their KV depends on pixel data
             # the token-tuple key cannot see.
             donor = None
-            if P > 1 and not item.image_data and not host_hit:
-                covered_t = tuple(prompt[:-1])
+            if C > 0 and not item.image_data and not host_hit:
+                covered_t = tuple(prompt[:C])
                 donor = self._prefix_lookup.get(covered_t)
                 if donor is None:
                     donor = self._find_covering_donor(covered_t)
-                if donor is not None and not self._ring_holds(donor, P - 1):
+                if donor is not None and not self._ring_holds(donor, C):
                     # a mixed stack: the donor has decoded past this
                     # prefix's window, its ring no longer holds it
                     donor = None
@@ -2902,9 +3251,9 @@ class JaxDecodeEngine(InferenceEngine):
             # shared rows, prefill only the suffix.
             partial = None
             partial_fabric = False
-            covered_t = tuple(prompt[:-1]) if P > 1 else ()
+            covered_t = tuple(prompt[:C]) if C > 0 else ()
             is_wave_dup = (
-                P > 1 and not item.image_data and covered_t in wave_primaries
+                C > 0 and not item.image_data and covered_t in wave_primaries
             )
             # content chain of the covered prefix (fleet KV fabric):
             # consulted by the device dedup rung below and the host-tier
@@ -2913,40 +3262,42 @@ class JaxDecodeEngine(InferenceEngine):
             if (
                 self._fabric_on
                 and donor is None
-                and P > 1
+                and C > 0
                 and not item.image_data
                 and not is_wave_dup
                 and not host_hit
             ):
                 req_chain = kv_fabric.chain_keys(
-                    prompt[:-1],
+                    prompt[:C],
                     self._alloc.block_size,
                     int(self._version),
                     str(self.config.kv_dtype),
                 )
             if (
                 donor is None
-                and P > 1
+                and C > 0
                 and not item.image_data
                 and not is_wave_dup
                 and not host_hit
             ):
                 # (a mixed stack shares whole prefixes only: a suffix
                 # prefill would read the donor's window rows at `plen`)
+                # (nor a block-diffusion model: a suffix prefill would have
+                # to start on a block boundary of the mask)
                 found = (
-                    None if self.model_config.mixed
+                    None if self.model_config.mixed or self._diffusion
                     else self._find_shared_prefix(covered_t)
                 )
                 if found is None and req_chain:
                     # fabric dedup rung: longest common block-aligned run
                     # with ANY resident registration, even one whose tail
                     # diverges from this prompt
-                    found = self._fabric_dev_match(req_chain, P - 1)
+                    found = self._fabric_dev_match(req_chain, C)
                     partial_fabric = found is not None
                 if found is not None:
                     donor_slot, plen = found
                     suffix_bucket = min(
-                        _pow2_bucket(P - 1 - plen), self.config.context_length
+                        _pow2_bucket(C - plen), self.config.context_length
                     )
                     if plen + suffix_bucket <= self.config.context_length:
                         partial = (donor_slot, plen, suffix_bucket)
@@ -3002,7 +3353,7 @@ class JaxDecodeEngine(InferenceEngine):
                     # full-buffer copy on device)
                     self._freq_counts = self._freq_counts.at[slot_idx].set(0.0)
                     self._slot_used_freq[slot_idx] = False
-            if resumed is None and P <= 1:
+            if resumed is None and C == 0:
                 # no prefill: the decode loop writes KV from row 0, which
                 # invalidates whatever prefix this slot may have donated
                 self._release_slot_blocks(slot_idx)
@@ -3017,7 +3368,7 @@ class JaxDecodeEngine(InferenceEngine):
                 # away. Falls back to the normal (re-prefill) paths only
                 # if the entry vanished between peek and take.
                 try:
-                    promoted = self._host_promote(item, slot_idx, P - 1)
+                    promoted = self._host_promote(item, slot_idx, C)
                 except PoolDry:
                     # device pool cannot back the blocks even after
                     # reclaim: the entry went back to the host store;
@@ -3050,20 +3401,20 @@ class JaxDecodeEngine(InferenceEngine):
                 # donor == self)
                 try:
                     fplen = self._promote_fabric_blocks(
-                        item, slot_idx, req_chain, P - 1
+                        item, slot_idx, req_chain, C
                     )
                 except PoolDry:
                     self._overflow.insert(0, item)
                     break
                 if fplen > 0:
                     sb = min(
-                        _pow2_bucket(P - 1 - fplen),
+                        _pow2_bucket(C - fplen),
                         self.config.context_length,
                     )
                     if fplen + sb <= self.config.context_length:
                         partial = (slot_idx, fplen, sb)
                         partial_fabric = False  # already attributed
-            if resumed is None and P > 1 and not promoted and donor is not None:
+            if resumed is None and C > 0 and not promoted and donor is not None:
                 # Prefix-KV hit (the GRPO group case: group_size requests
                 # share one prompt). The donor slot's blocks [0, P-1)
                 # already hold this prefix — alias them in the block table
@@ -3074,7 +3425,7 @@ class JaxDecodeEngine(InferenceEngine):
                 if donor != slot_idx:
                     self._unregister_prefix(slot_idx)
                     try:
-                        self._device_fork(donor, slot_idx, P - 1)
+                        self._device_fork(donor, slot_idx, C)
                     except PoolDry:
                         # never reclaim the donor mid-fork: its table is
                         # the source of the alias we are creating
@@ -3084,11 +3435,11 @@ class JaxDecodeEngine(InferenceEngine):
                             self._overflow.insert(0, item)
                             break
                         try:
-                            self._device_fork(donor, slot_idx, P - 1)
+                            self._device_fork(donor, slot_idx, C)
                         except PoolDry:
                             self._overflow.insert(0, item)
                             break
-                    self._register_prefix(slot_idx, list(prompt[:-1]))
+                    self._register_prefix(slot_idx, list(prompt[:C]))
                     self._n_prefix_forks += 1
                 else:
                     self._n_prefix_inplace += 1
@@ -3096,8 +3447,8 @@ class JaxDecodeEngine(InferenceEngine):
                     # request's prefix (covering-donor reuse); decode will
                     # overwrite rows past P-1, so trim the claim to what
                     # stays valid
-                    self._register_prefix(slot_idx, list(prompt[:-1]))
-            elif resumed is None and P > 1 and partial is not None:
+                    self._register_prefix(slot_idx, list(prompt[:C]))
+            elif resumed is None and C > 0 and partial is not None:
                 donor_slot, plen, sb = partial
                 prefill_budget -= sb
                 did_prefill = True
@@ -3128,7 +3479,7 @@ class JaxDecodeEngine(InferenceEngine):
                     self._release_slot_blocks(slot_idx)
                     self._overflow.insert(0, item)
                     break
-                suffix = prompt[plen : P - 1]
+                suffix = prompt[plen:C]
                 ids = np.zeros(sb, dtype=np.int32)
                 ids[: len(suffix)] = suffix
                 bsz = self._alloc.block_size
@@ -3145,9 +3496,9 @@ class JaxDecodeEngine(InferenceEngine):
                         len(suffix),
                         plen,
                     ))
-                self._register_prefix(slot_idx, list(prompt[:-1]))
-            elif resumed is None and P > 1 and not promoted:
-                pre = P - 1
+                self._register_prefix(slot_idx, list(prompt[:C]))
+            elif resumed is None and C > 0 and not promoted:
+                pre = C
                 bucket = min(_next_bucket(pre), self.config.context_length)
                 self._unregister_prefix(slot_idx)
                 if not is_wave_dup:
@@ -3162,7 +3513,7 @@ class JaxDecodeEngine(InferenceEngine):
                     did_prefill = True
                     self._n_prefills += 1
                     ids = np.zeros(bucket, dtype=np.int32)
-                    ids[:pre] = prompt[:-1]
+                    ids[:pre] = prompt[:C]
                     positions = np.arange(bucket, dtype=np.int32)
                     img_embeds = self._encode_images(item.image_data)
                     cos, sin, delta = self._image_rope_tables(
@@ -3198,13 +3549,13 @@ class JaxDecodeEngine(InferenceEngine):
                     did_prefill = True
                     self._n_prefills += 1
                     ids = np.zeros(bucket, dtype=np.int32)
-                    ids[:pre] = prompt[:-1]
+                    ids[:pre] = prompt[:C]
                     wave_primaries[covered_t] = slot_idx
                     wave_pending.append(
                         (slot_idx, ids, pre, bucket, covered_t)
                     )
             self._slots[slot_idx] = item
-            self._slot_lengths[slot_idx] = P - 1
+            self._slot_lengths[slot_idx] = C
             self._slot_epoch[slot_idx] += 1
             # TTFT split: everything between enqueue and this point is
             # queue wait (scheduler backlog + pool-pressure holds); the
@@ -3433,6 +3784,7 @@ class JaxDecodeEngine(InferenceEngine):
             del item.tokens[cut:]
             del item.logprobs[cut:]
             del item.versions[cut:]
+            del item.reveal_steps[cut:]
             del item.itl[cut:]
             item.stop_reason = "stop"
             return
@@ -3440,6 +3792,7 @@ class JaxDecodeEngine(InferenceEngine):
             del item.tokens[g.max_new_tokens :]
             del item.logprobs[g.max_new_tokens :]
             del item.versions[g.max_new_tokens :]
+            del item.reveal_steps[g.max_new_tokens :]
             del item.itl[g.max_new_tokens :]
             item.stop_reason = "length"
 
@@ -3493,6 +3846,7 @@ class JaxDecodeEngine(InferenceEngine):
             output_tokens=list(item.tokens),
             output_logprobs=list(item.logprobs),
             output_versions=list(item.versions),
+            output_reveal_steps=list(item.reveal_steps),
             stop_reason=stop_reason,  # type: ignore[arg-type]
             latency=t_done - item.start_time,
             ttft=item.ttft,
@@ -3723,7 +4077,11 @@ class JaxDecodeEngine(InferenceEngine):
             if s is None:
                 active[i] = False
                 continue
-            projected_gen = int(self._slot_lengths[i]) - (len(s.prompt) - 1)
+            # (rows cached past the prompt's: a causal model's lag its
+            # tokens by one, a block-diffusion model's are its tokens)
+            projected_gen = int(self._slot_lengths[i]) - (
+                len(s.prompt) - (0 if self._diffusion else 1)
+            )
             if projected_gen >= s.gconfig.max_new_tokens:
                 active[i] = False
         if not active.any():
@@ -3760,6 +4118,10 @@ class JaxDecodeEngine(InferenceEngine):
                 spec_w = b + 1
                 drafts_np = drafts_np[:, :b]
         grow = spec_w if spec_w else n_chunk
+        if self._diffusion:
+            # the blocks a chunk can commit, and the rows of the block a
+            # slot is denoising when the chunk ends
+            grow = n_chunk + self.model_config.block_length_
         # Every active slot needs blocks through this chunk's growth
         # (self._slot_lengths already projects all dispatched chunks).
         # Shortest-first so pool pressure preempts as few slots as
@@ -3796,7 +4158,10 @@ class JaxDecodeEngine(InferenceEngine):
                 return None
         # device-chained (last, lengths): init on first dispatch, then
         # patch only the slots whose host truth diverged since
-        if self._dev_last is None or self._dev_lengths is None:
+        if self._diffusion:
+            if self._dev_block is None or self._patch_slots:
+                self._patch_diffusion_state()
+        elif self._dev_last is None or self._dev_lengths is None:
             last = np.zeros(R, dtype=np.int32)
             for i, s in enumerate(self._slots):
                 if s is not None:
@@ -3898,6 +4263,8 @@ class JaxDecodeEngine(InferenceEngine):
                 accepted=accepted,
                 draft_lens=dlens_np,
             )
+        if self._diffusion:
+            return self._dispatch_diffusion_chunk(active, use_topp, nb, ctl)
         chunk_fn = self._get_chunk_fn(use_topp, use_freq, nb)
         t_dispatch = time.monotonic()
         with self._weight_lock:
@@ -3960,6 +4327,70 @@ class JaxDecodeEngine(InferenceEngine):
             moe_load=moe_load,
         )
 
+    def _call_diffusion_chunk(self, use_topp: bool, nb: int, active, ctl: dict):
+        """The diffusion chunk program over the pool and the device-chained
+        (block, lengths), which it leaves advanced; returns what the host
+        reads: (toks, logps, steps, blocks, load)."""
+        chunk_fn = self._get_diffusion_chunk_fn(use_topp, nb)
+        with self._weight_lock:
+            kq, vq = self._kv_operands()
+            kq, vq, self._dev_block, self._dev_lengths, *out = chunk_fn(
+                self.params,
+                kq,
+                vq,
+                self._table_device(nb),
+                self._dev_block,
+                self._dev_lengths,
+                active,
+                ctl["base_keys"],
+                ctl["temps"],
+                ctl["top_ps"],
+                ctl["greedy"],
+                ctl["rope_delta"],
+            )
+            self._set_kv_operands(kq, vq)
+        return out
+
+    def _dispatch_diffusion_chunk(self, active: np.ndarray, use_topp: bool,
+                                  nb: int, ctl: dict) -> "_Inflight":
+        """`_dispatch_chunk`'s last part for a block-diffusion model: the
+        chunk program's call and the projection of the host lengths."""
+        R = self.config.max_running_requests
+        n_chunk = self.config.new_tokens_per_chunk
+        version_at_chunk = self._version
+        t_dispatch = time.monotonic()
+        toks, logps, steps, blocks, load = self._call_diffusion_chunk(
+            use_topp, nb, self._dev_active, ctl
+        )
+        for arr in (toks, logps, steps, blocks, load):
+            copy_async = getattr(arr, "copy_to_host_async", None)
+            if copy_async is not None:
+                copy_async()
+        # worst-case projection, as a verify chunk's: a slot commits at most
+        # n_chunk rows; _apply_chunk takes back what it did not
+        self._slot_lengths[active] += n_chunk
+        self._cache_written(active)
+        self._count_block_columns(active, nb)
+        self._count_grouped_matmuls(
+            self._diffusion_forwards(), R * self.model_config.block_length_
+        )
+        with self._metrics_lock:
+            self._chunks_dispatched += 1
+        return _Inflight(
+            toks=toks,
+            logps=logps,
+            items=list(self._slots),
+            active=active.copy(),
+            epochs=self._slot_epoch.copy(),
+            version=version_at_chunk,
+            t_dispatch=t_dispatch,
+            n_chunk=n_chunk,
+            chunk=self._chunks_dispatched,
+            moe_load=load,
+            steps=steps,
+            blocks=blocks,
+        )
+
     def _consume_chunk(self, rec: "_Inflight") -> None:
         with perf_tracer.span("decode/consume_chunk", chunk=rec.chunk,
                               version=rec.version), self._sched_state("consume"):
@@ -3968,6 +4399,10 @@ class JaxDecodeEngine(InferenceEngine):
                 toks = np.asarray(rec.toks)  # [n_chunk, R]
                 logps = np.asarray(rec.logps)
                 acc = np.asarray(rec.accepted) if rec.spec_w > 0 else None
+                if rec.blocks is not None:
+                    # a block-diffusion chunk: blocks committed a slot, and
+                    # the reveal step of each row of toks / logps
+                    rec.blocks, rec.steps = np.asarray(rec.blocks), np.asarray(rec.steps)
                 if rec.moe_load is not None:
                     # [pairs, hot] and, by what the model is, [absent],
                     # [full rows, window rows], [state updates] (models/qwen2.py)
@@ -3978,6 +4413,12 @@ class JaxDecodeEngine(InferenceEngine):
                         self._moe_hot_pairs += hot
                         if cfg.num_experts_published_ != cfg.num_experts:
                             self._moe_absent_pairs += more.pop(0)
+                        if rec.blocks is not None:
+                            # cached rows read, live slots x forwards, and
+                            # those that were a slot's commit pass
+                            self._kv_block_rows_read += more.pop(0)
+                            self._dfn_slot_forwards += more.pop(0)
+                            self._dfn_commit_forwards += more.pop(0)
                         if more:
                             self._kv_full_rows_read += more[0]
                             self._kv_window_rows_read += more[1]
@@ -3991,6 +4432,11 @@ class JaxDecodeEngine(InferenceEngine):
         with slots retired since the dispatch, extend the requests, scan for
         stops, retire and complete."""
         spec = rec.spec_w > 0
+        blocks, steps = rec.blocks, rec.steps  # a block-diffusion chunk's, on the host
+        if blocks is not None:
+            B = self.model_config.block_length_
+            with self._metrics_lock:
+                self._dfn_blocks += int(blocks[rec.active].sum())
         t_ready = time.monotonic()
         n_chunk = rec.n_chunk
         # dispatch→ready is the device window; anything between the
@@ -4023,12 +4469,24 @@ class JaxDecodeEngine(InferenceEngine):
                 # item that re-admitted into the same slot.
                 with self._metrics_lock:
                     self._runahead_discarded += (
-                        int(acc[i]) + 1 if spec else n_chunk
+                        B * int(blocks[i]) if blocks is not None
+                        else int(acc[i]) + 1 if spec else n_chunk
                     )
                 continue
             # a verify chunk emits only the accepted draft prefix plus the
             # bonus token; a normal chunk emits its full depth
             e = int(acc[i]) + 1 if spec else n_chunk
+            new_toks, new_logps = toks[:e, i], logps[:e, i]
+            if blocks is not None:
+                # a block-diffusion chunk emits the blocks it committed, less
+                # the positions the prompt gave (a first block's head); the
+                # dispatch projected n_chunk rows, so take back the rest
+                rows = B * int(blocks[i])
+                self._slot_lengths[i] -= n_chunk - rows
+                ours = steps[:rows, i] >= 0
+                new_toks, new_logps = toks[:rows, i][ours], logps[:rows, i][ours]
+                e = int(ours.sum())
+                s.reveal_steps.extend(steps[:rows, i][ours].tolist())
             emitted_counts.append(e)
             if spec:
                 # reconcile the dispatch's worst-case length projection
@@ -4041,11 +4499,11 @@ class JaxDecodeEngine(InferenceEngine):
                     self._spec_drafted += d
                     self._spec_accepted += int(acc[i])
                     self._spec_rejected += d - int(acc[i])
-            if s.ttft == float("inf"):
+            if s.ttft == float("inf") and e:
                 s.ttft = time.monotonic() - s.start_time
             n_before = len(s.tokens)
-            s.tokens.extend(toks[:e, i].tolist())
-            s.logprobs.extend(logps[:e, i].tolist())
+            s.tokens.extend(new_toks.tolist())
+            s.logprobs.extend(new_logps.tolist())
             s.versions.extend([rec.version] * e)
             # honest per-token ITL: the device window divided by tokens
             # actually emitted for THIS slot (accepted + bonus), not the
@@ -4057,11 +4515,16 @@ class JaxDecodeEngine(InferenceEngine):
             # never reach the client and must not inflate throughput
             with self._metrics_lock:
                 self._gen_token_count += len(s.tokens) - n_before
+                if blocks is not None:
+                    # what the committed blocks held beyond a stop
+                    self._dfn_tokens_discarded += e - (len(s.tokens) - n_before)
             if s.stop_reason is not None:
                 # rewind the slot length to the true end: KV rows cover
                 # prompt[:-1] plus every *consumed* token (cache positions
-                # past it are never attended again before overwrite)
-                self._slot_lengths[i] = len(s.prompt) - 1 + len(s.tokens)
+                # past it are never attended again before overwrite); a
+                # block-diffusion model's the whole blocks of them (a block
+                # cut by the stop was committed with what was cut in sight)
+                self._slot_lengths[i] = self._cover(len(s.prompt) + len(s.tokens))
                 self._retire(i)
         # chunk-level ITL sample: device window over the MEAN tokens a
         # surviving slot emitted (== n_chunk for normal chunks; accepted+1
@@ -4110,6 +4573,14 @@ class JaxDecodeEngine(InferenceEngine):
                 "JaxDecodeEngine has no vision tower installed; call "
                 "set_vision_model() (models/qwen2_vl.py) to serve image "
                 "inputs"
+            )
+        if self._diffusion and (
+            req.image_data or req.gconfig.frequency_penalty != 0.0
+        ):
+            raise NotImplementedError(
+                "a block-diffusion model is not served image inputs or a "
+                "frequency penalty (its counts would follow the order of "
+                "reveal, not of position)"
             )
         loop = asyncio.get_running_loop()
         future: asyncio.Future = loop.create_future()
@@ -4262,6 +4733,15 @@ class JaxDecodeEngine(InferenceEngine):
             with perf_tracer.span("weights/commit", version=self._version), \
                     self._weight_lock:
                 yield
+            if self._diffusion:
+                # a block never mixes weight versions: what each slot was
+                # denoising is dropped, and denoised again from a fresh block
+                # under the new weights (committed blocks stay, as a causal
+                # model's tokens do)
+                with self._sched_lock:
+                    self._patch_slots.update(
+                        i for i, s in enumerate(self._slots) if s is not None
+                    )
         finally:
             if not was_paused:
                 with perf_tracer.span("weights/resume", version=self._version):
@@ -4443,9 +4923,11 @@ class JaxDecodeEngine(InferenceEngine):
         n_chunk = self.config.new_tokens_per_chunk
         if grow is None:
             grow = n_chunk
+            if self._diffusion:
+                grow += self.model_config.block_length_
         out: set[int] = set()
-        length = max(prompt_len - 1, 0)
-        end = min(prompt_len - 1 + new_tokens, S)
+        length = max(self._cover(prompt_len), 0)
+        end = min(length + new_tokens, S)
         while True:
             b = 256
             while b < length + grow + 1:
@@ -4482,13 +4964,17 @@ class JaxDecodeEngine(InferenceEngine):
                         np.array(self._slot_lengths)
                     )
                 # the run-ahead reconcile's patch fn compiles here too
-                self._dev_last, self._dev_lengths = self._get_patch_fn()(
-                    self._dev_last,
-                    self._dev_lengths,
-                    jnp.zeros(R, dtype=bool),
-                    jnp.zeros(R, dtype=jnp.int32),
-                    jnp.asarray(np.array(self._slot_lengths)),
-                )
+                if self._diffusion:
+                    # (the slots a dispatch would patch, or none)
+                    self._patch_diffusion_state()
+                else:
+                    self._dev_last, self._dev_lengths = self._get_patch_fn()(
+                        self._dev_last,
+                        self._dev_lengths,
+                        jnp.zeros(R, dtype=bool),
+                        jnp.zeros(R, dtype=jnp.int32),
+                        jnp.asarray(np.array(self._slot_lengths)),
+                    )
                 # the ghost compiles below warm whichever kv_dtype
                 # variants the live config selects — an int8
                 # engine ghost-compiles the QUANTIZED chunk/verify fns, so
@@ -4578,6 +5064,11 @@ class JaxDecodeEngine(InferenceEngine):
         the run-ahead scheduler's first overlapped dispatch must never
         trace."""
         R = self.config.max_running_requests
+        if self._diffusion:
+            self._call_diffusion_chunk(
+                use_topp, nb, jnp.zeros(R, dtype=bool), self._refresh_ctl()
+            )
+            return
         chunk_fn = self._get_chunk_fn(use_topp, False, nb)
         ctl = self._refresh_ctl()
         with self._weight_lock:
@@ -5540,6 +6031,9 @@ class JaxDecodeEngine(InferenceEngine):
             moe_absent_pairs = self._moe_absent_pairs
             kv_rows_read = self._kv_full_rows_read, self._kv_window_rows_read
             gdn_updates = self._gdn_state_updates
+            dfn = (self._dfn_slot_forwards, self._dfn_commit_forwards,
+                   self._dfn_blocks, self._dfn_tokens_discarded,
+                   self._kv_block_rows_read)
             paged_cols = self._paged_cols_live, self._paged_cols_visited
             gmm_steps = self._gmm_steps, self._gmm_small_tile_steps
             table_uploads = self._table_uploads
@@ -5645,6 +6139,15 @@ class JaxDecodeEngine(InferenceEngine):
             "kv_full_bytes_read_total": kv_rows_read[0] * self._kv_row_nbytes(),
             "gdn_state_updates_total": gdn_updates,
             "gdn_state_bytes_total": gdn_updates * self._state_update_nbytes(),
+            # a block-diffusion model: live slots x forwards of its chunks,
+            # those that were a slot's commit pass, the blocks they committed,
+            # tokens of committed blocks dropped at a stop, and the cached
+            # rows the blocks' attention read (0 for every other model)
+            "diffusion_slot_forwards_total": dfn[0],
+            "diffusion_commit_forwards_total": dfn[1],
+            "diffusion_blocks_committed_total": dfn[2],
+            "diffusion_block_tokens_discarded_total": dfn[3],
+            "kv_block_rows_read_total": dfn[4],
             # block columns inside a slot's live range, of the steps the
             # paged kernel takes (those, and one a slot with none)
             "paged_block_columns_live_total": paged_cols[0],

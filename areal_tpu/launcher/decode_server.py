@@ -320,6 +320,7 @@ class DecodeServer:
             "output_tokens": resp.output_tokens,
             "output_logprobs": resp.output_logprobs,
             "output_versions": resp.output_versions,
+            "output_reveal_steps": resp.output_reveal_steps,
             "stop_reason": resp.stop_reason,
             "latency": resp.latency,
             "ttft": resp.ttft,

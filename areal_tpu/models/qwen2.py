@@ -61,6 +61,7 @@ def _cstr(x: jax.Array, *logical_axes: str | None) -> jax.Array:
 MODEL_TYPES = (
     "qwen2", "qwen3", "llama", "mistral", "gemma", "gemma2", "gpt2",
     "mixtral", "qwen2_moe", "qwen3_moe", "olmoe", "exaone_moe", "qwen3_next",
+    "sdar_moe",
 )
 
 # `layer_types` entries of a mixed stack (HF's names)
@@ -204,6 +205,15 @@ class ModelConfig:
     linear_conv_kernel_dim: int = 4
     attn_output_gate: bool = False
     partial_rotary_factor: float = 1.0
+    # -- SDAR-class (generation by diffusion over blocks). `block_length` B > 1:
+    # attention is block-causal, position j visible to position i iff
+    # j // B <= i // B (causal across blocks, both directions inside one;
+    # blocks are aligned to absolute position 0), and the decode engine
+    # generates a block of B positions at a time by denoising
+    # `mask_token_id` placeholders (engine/jax_decode.py). None or 1: causal,
+    # every other model.
+    block_length: int | None = None
+    mask_token_id: int | None = None
     # vocab chunk for the fused LM-head loss (ops/fused_xent.py): peak
     # logits transient is [tokens, loss_vocab_chunk]
     loss_vocab_chunk: int = 16384
@@ -225,6 +235,11 @@ class ModelConfig:
     @property
     def num_experts_published_(self) -> int:
         return self.num_experts_published or self.num_experts
+
+    @property
+    def block_length_(self) -> int:
+        """Positions of one attention block: 1 is the causal mask."""
+        return int(self.block_length or 1)
 
     @property
     def mixed(self) -> bool:
@@ -316,7 +331,7 @@ class ModelConfig:
         if model_type in ("mistral", "mixtral") and hf.get("sliding_window"):
             sw_kw = dict(sliding_window=int(hf["sliding_window"]))
         elif model_type in (
-            "qwen2", "qwen2_moe", "qwen3", "qwen3_moe"
+            "qwen2", "qwen2_moe", "qwen3", "qwen3_moe", "sdar_moe"
         ) and hf.get("use_sliding_window"):
             # HF windows only layers with layer_idx >= max_window_layers:
             # mwl >= L means NO layer is windowed (the shape Qwen2.5 ships,
@@ -390,7 +405,7 @@ class ModelConfig:
             max_position_embeddings=hf.get("max_position_embeddings", 32768),
             model_type=model_type,
             qkv_bias=model_type in ("qwen2", "qwen2_moe"),
-            qk_norm=model_type in ("qwen3", "qwen3_moe", "olmoe"),
+            qk_norm=model_type in ("qwen3", "qwen3_moe", "olmoe", "sdar_moe"),
             qk_norm_full=model_type == "olmoe",
             # act_fn raises on anything unsupported, so an exotic
             # hidden_act fails loudly at trace time instead of silently
@@ -399,7 +414,14 @@ class ModelConfig:
             **rope_kw,
             **sw_kw,
         )
-        if model_type == "qwen3_moe":
+        if model_type in ("qwen3_moe", "sdar_moe"):
+            if model_type == "sdar_moe" and (
+                hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1
+            ):
+                raise NotImplementedError(
+                    "sdar_moe with mlp_only_layers/decoder_sparse_step != 1 "
+                    "(heterogeneous dense/sparse layers) is not supported"
+                )
             kw.update(
                 num_experts=hf.get("num_experts", 0),
                 num_experts_per_tok=hf.get("num_experts_per_tok", 2),
@@ -407,6 +429,16 @@ class ModelConfig:
                 norm_topk_prob=hf.get("norm_topk_prob", True),
                 router_aux_loss_coef=hf.get("router_aux_loss_coef", 0.0),
             )
+            if model_type == "sdar_moe":
+                # SDAR: the Qwen3-MoE decoder layer under a block-causal
+                # mask. config.json gives neither key (the block length is
+                # an argument of the family's sampler, the mask token an
+                # added token of its tokenizer): the family's published
+                # defaults, which a caller's config may override.
+                kw.update(
+                    block_length=int(hf.get("block_length", 4)),
+                    mask_token_id=int(hf.get("mask_token_id", 151669)),
+                )
         elif model_type == "qwen2_moe":
             # Qwen1.5/2-MoE: routed experts + a sigmoid-gated shared expert.
             # Only the homogeneous all-sparse stack is supported — a
@@ -1482,16 +1514,35 @@ def _window_band(T: int, sliding_window: int | None) -> jax.Array | None:
     return idx[:, None] - idx[None, :] < sliding_window
 
 
+def block_horizon(position_ids: jax.Array, block_length: int) -> jax.Array | None:
+    """[T] the last index of the stream each query may attend under a
+    block-causal mask: the index of its block's last position (a sequence's
+    tokens are contiguous, so index distance equals position distance; the
+    same-segment test cuts a horizon that reaches into the next sequence).
+    None for the causal mask (`block_length` 1), whose programs stay as
+    they are."""
+    if block_length <= 1:
+        return None
+    idx = jnp.arange(position_ids.shape[0], dtype=position_ids.dtype)
+    return idx + (block_length - 1 - position_ids % block_length)
+
+
 def segment_causal_mask(
-    segment_ids: jax.Array, sliding_window: int | None = None
+    segment_ids: jax.Array, sliding_window: int | None = None,
+    horizon: jax.Array | None = None,
 ) -> jax.Array:
     """[T, T] bool mask: attend iff same segment AND causal AND not padding
     (AND within `sliding_window` positions — same-segment tokens are
-    contiguous in the pack, so index distance equals position distance)."""
+    contiguous in the pack, so index distance equals position distance).
+    `horizon` [T] (`block_horizon`): each query's last visible index in
+    place of its own."""
     T = segment_ids.shape[0]
     seg_q = segment_ids[:, None]
     seg_k = segment_ids[None, :]
-    causal = jnp.tril(jnp.ones((T, T), dtype=bool))
+    if horizon is None:
+        causal = jnp.tril(jnp.ones((T, T), dtype=bool))
+    else:
+        causal = jnp.arange(T)[None, :] <= horizon[:, None]
     m = (seg_q == seg_k) & causal & (seg_q != PADDING_SEGMENT)
     band = _window_band(T, sliding_window)
     return m if band is None else m & band
@@ -1516,6 +1567,18 @@ def resolve_attn_impl(cfg: ModelConfig, window=_UNIFORM) -> str:
     mixed = cfg.layer_types is not None
     if window is _UNIFORM:
         window = None if mixed else cfg.sliding_window
+    if cfg.block_length_ > 1:
+        # a block-causal mask: like a window, the Pallas flash/ring kernels
+        # have none, and attending causally would be silently wrong
+        if cfg.attn_impl in ("flash", "ring"):
+            raise NotImplementedError(
+                f"attn_impl={cfg.attn_impl!r} does not support a block-causal "
+                f"mask (block_length={cfg.block_length}); use 'chunked' "
+                "(O(T) memory) or 'dense'"
+            )
+        if cfg.attn_impl != "auto":
+            return cfg.attn_impl
+        return "chunked" if jax.default_backend() == "tpu" else "dense"
     if mixed and window is not None:
         return "dense" if cfg.attn_impl == "dense" else "chunked"
     if window is not None:
@@ -1581,10 +1644,12 @@ def attention(
     mask: jax.Array | None,
     cfg: ModelConfig,
     li: int | None = None,
+    horizon: jax.Array | None = None,
 ) -> jax.Array:
     """Packed multi-head GQA attention over one 1-D token stream [T, H].
     `li`: the layer's index in a mixed stack (its window, and whether it
-    rotates q and k); `mask` is then the mask of that layer's kind."""
+    rotates q and k); `mask` is then the mask of that layer's kind.
+    `horizon`: `block_horizon` of a block-causal model."""
     window = cfg.layer_window(li)
     with jax.named_scope("qkv"):
         q = _w_einsum("th,hnd->tnd", x, layer_p["q_kernel"], 1)
@@ -1632,13 +1697,15 @@ def attention(
             from areal_tpu.ops.chunked_attention import chunked_attention
 
             out = chunked_attention(
-                q, k, v, segment_ids, sliding_window=window
+                q, k, v, segment_ids, sliding_window=window, **(
+                    {} if horizon is None else {"q_horizon": horizon}
+                )
             )
         else:
             # GQA: broadcast kv heads to query heads via grouped einsum.
             group = nH // nKV
             if mask is None:
-                mask = segment_causal_mask(segment_ids, window)
+                mask = segment_causal_mask(segment_ids, window, horizon)
             qg = q.reshape(T, nKV, group, hd)
             scores = jnp.einsum("tkgd,skd->kgts", qg, k).astype(jnp.float32)
             scores = scores / np.sqrt(hd)
@@ -2180,15 +2247,19 @@ def decoder_layer(
     mask: jax.Array | None,
     cfg: ModelConfig,
     li: int | None = None,
+    horizon: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (hidden [T, H], router aux loss scalar — 0 for dense).
-    `li` (static) is the layer's index in an unstacked tree."""
+    `li` (static) is the layer's index in an unstacked tree; `horizon` is
+    `block_horizon` of a block-causal model."""
     h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
     if cfg.layer_linear(li):
         with jax.named_scope("attn"):
             x = x + gated_delta_net(layer_p["attn"], h, segment_ids, cfg)
     else:
-        x = x + attention(layer_p["attn"], h, cos, sin, segment_ids, mask, cfg, li)
+        x = x + attention(
+            layer_p["attn"], h, cos, sin, segment_ids, mask, cfg, li, horizon
+        )
     h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
     if cfg.layer_sparse(li):
         y, aux = moe_mlp(
@@ -2277,19 +2348,25 @@ def forward(
     # Dense path: build the [T,T] mask ONCE here (outside the per-layer remat
     # region); flash/ring never materialise it.
     # (a mixed stack: one mask for each kind of layer that runs dense)
+    # (a block-causal model: each query's horizon is its block's last index)
+    horizon = block_horizon(position_ids, cfg.block_length_)
     masks = {
-        w: segment_causal_mask(segment_ids, w)
+        w: segment_causal_mask(segment_ids, w, horizon)
         for w in {cfg.layer_window(i) for i in range(cfg.num_hidden_layers)}
         if resolve_attn_impl(cfg, w) == "dense"
     }
     mask = masks.get(cfg.sliding_window)
 
     layer_fn = _maybe_remat(decoder_layer, cfg)
+    # the causal models' call is what it was: no ninth argument
+    more = () if horizon is None else (horizon,)
 
     if cfg.scan_layers:
         def body(carry, layer_p):
             h, aux_sum = carry
-            h, aux = layer_fn(layer_p, h, cos, sin, segment_ids, mask, cfg, None)
+            h, aux = layer_fn(
+                layer_p, h, cos, sin, segment_ids, mask, cfg, None, *more
+            )
             return (h, aux_sum + aux), None
 
         (x, aux_total), _ = jax.lax.scan(
@@ -2301,7 +2378,7 @@ def forward(
             with _layer_scope(cfg, i):
                 x, aux = layer_fn(
                     params[f"layers_{i}"], x, cos, sin, segment_ids,
-                    masks.get(cfg.layer_window(i)), cfg, i,
+                    masks.get(cfg.layer_window(i)), cfg, i, *more,
                 )
             aux_total = aux_total + aux
 
@@ -2359,10 +2436,12 @@ def _pp_stage_fn(cfg: ModelConfig):
     def stage_fn(layers_local, h, aux_t):
         pos, seg = aux_t
         cos, sin = rope_table(pos, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
+        horizon = block_horizon(pos, cfg.block_length_)
+        more = () if horizon is None else (horizon,)
 
         def body(carry, layer_p):
             h, aux_sum = carry
-            h, aux = layer_fn(layer_p, h, cos, sin, seg, None, cfg, None)
+            h, aux = layer_fn(layer_p, h, cos, sin, seg, None, cfg, None, *more)
             return (h, aux_sum + aux), None
 
         (h, aux_sum), _ = jax.lax.scan(
@@ -2829,8 +2908,21 @@ def prefill(
     # attention goes a block of keys at a time (ops/chunked_attention.py)
     chunked = T > PREFILL_DENSE_MAX and not with_prefix
 
+    # a block-causal model: a query sees to the end of its block (the rows of
+    # a bucket's padding tail lie after every real token, and the engine ends
+    # a prompt's cached part on a block boundary, so no real query sees one)
+    horizon = block_horizon(position_ids, cfg.block_length_)
+    if horizon is not None and with_prefix:
+        raise NotImplementedError(
+            "a suffix prefill under a block-causal mask: the cached prefix "
+            "would have to end on a block boundary"
+        )
+
     def dense_mask(window):
-        causal = jnp.tril(jnp.ones((T, T), dtype=bool))
+        if horizon is None:
+            causal = jnp.tril(jnp.ones((T, T), dtype=bool))
+        else:
+            causal = jnp.arange(T)[None, :] <= horizon[:, None]
         band = _window_band(T, window)
         if band is not None:
             causal = causal & band
@@ -2893,7 +2985,8 @@ def prefill(
                     # one sequence: bucket padding lies after every real
                     # token, so causality alone hides it
                     attn_out = chunked_attention(
-                        q, k, v, jnp.zeros(T, jnp.int32), sliding_window=window
+                        q, k, v, jnp.zeros(T, jnp.int32), sliding_window=window,
+                        **({} if horizon is None else {"q_horizon": horizon}),
                     )
             else:
                 with jax.named_scope(_attention_scope(cfg, window)):
@@ -3292,6 +3385,157 @@ def decode_step_paged(
     return logits, k_pool, v_pool
 
 
+def _qlen_step_paged(
+    params: dict,
+    tokens: jax.Array,  # [R, W]
+    positions0: jax.Array,  # [R] base index column 0 occupies
+    k_pool,
+    v_pool,
+    block_tables: jax.Array,  # [R, nb]
+    cfg: ModelConfig,
+    active: jax.Array | None,
+    rope_offset: jax.Array | None,
+    attn_impl: str,
+    block: bool = False,
+    moe_load: bool = False,
+    with_logits: bool = True,
+) -> tuple:
+    """W positions a slot in one forward over the paged pool: the body of
+    `verify_step_paged` (each query's horizon its own position) and of
+    `diffusion_step_paged` (`block`: each query's horizon its block's last
+    position, the read named apart). Returns (logits [R, W, V] or None,
+    k_pool, v_pool) and, with `moe_load`, the forward's load vector."""
+    from areal_tpu.ops.paged_attention import paged_attention_qlen
+
+    if cfg.cache_layers["state"]:
+        raise NotImplementedError(
+            "a verify step over linear layers: a rejected draft would have "
+            "to roll each slot's state back"
+        )
+    R, W = tokens.shape
+    bsz = jax.tree.leaves(k_pool)[0].shape[2]
+    nb = block_tables.shape[1]
+    span = nb * bsz
+    nH, hd = cfg.num_attention_heads, cfg.head_dim_
+    positions = positions0[:, None] + jnp.arange(W, dtype=positions0.dtype)
+    flat_pos = positions.reshape(-1)
+    x = _embed_tokens(params, tokens.reshape(-1), flat_pos, cfg)  # [R*W, H]
+    rope_pos = (
+        positions if rope_offset is None else positions + rope_offset[:, None]
+    ).reshape(-1)
+    cos, sin = rope_table(rope_pos, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
+    if block:
+        # block-causal: every query of a block sees to the block's last row
+        B = cfg.block_length_
+        sees = positions - positions % B + (B - 1)
+        scope, kernel = "attention_block", {"kernel_name": "paged_attention_block"}
+    else:
+        sees, scope, kernel = positions, "attention", {}
+    valid = (
+        jnp.arange(span)[None, None, :] <= sees[:, :, None]
+    )  # [R, W, span]
+    mixed = isinstance(k_pool, dict)
+    if block and (mixed or cfg.sliding_window is not None):
+        raise NotImplementedError(
+            "a block step over a window's ring: the block-causal models "
+            "served have full attention alone"
+        )
+    if cfg.sliding_window is not None and not mixed:
+        valid = valid & (
+            jnp.arange(span)[None, None, :]
+            > positions[:, :, None] - cfg.sliding_window
+        )
+
+    # pool coordinates of each (slot, position) row; inactive slots land in
+    # the null block 0 so donors/parked KV stay untouched
+    blk_col = jnp.clip(positions // bsz, 0, nb - 1)  # [R, W]
+    dest_block = jnp.take_along_axis(block_tables, blk_col, axis=1)
+    dest_off = positions % bsz
+    if active is not None:
+        dest_block = jnp.where(active[:, None], dest_block, 0)
+        dest_off = jnp.where(active[:, None], dest_off, 0)
+    if block:
+        # a slot whose request has ended denoises on until its chunk does: a
+        # block past the table's last column goes to the null block, not
+        # (clipped) over the finished request's rows, which a later one forks
+        dest_block = jnp.where(positions // bsz < nb, dest_block, 0)
+    dest_block_f = dest_block.reshape(-1)
+    dest_off_f = dest_off.reshape(-1)
+    active_flat = (
+        None if active is None else jnp.repeat(active, W, axis=0)
+    )
+
+    live_of = functools.partial(
+        _live_columns, active=active, bsz=bsz, attn_impl=attn_impl
+    )
+    if mixed:
+        # the W rows of a slot are all written before any is read, so the
+        # ring must keep the oldest query's window clear of the newest row
+        if "window" in k_pool and W - 1 > ring_slack(cfg.sliding_window, bsz):
+            raise ValueError(
+                f"a verify chunk of {W} positions does not fit the ring's "
+                f"{ring_slack(cfg.sliding_window, bsz)} rows of slack past a "
+                f"window of {cfg.sliding_window} at pages of {bsz}"
+            )
+        index, where = _mixed_cache(
+            cfg, k_pool, flat_pos,
+            jnp.repeat(jnp.arange(R, dtype=flat_pos.dtype), W), active_flat,
+            (block_tables, dest_block_f, dest_off_f, valid), live_of,
+        )
+    else:
+        live = live_of(valid)
+
+    @jax.named_scope("layer")
+    def layer(carry, layer_p, li):
+        x, kp, vp, load = carry
+        h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
+        with jax.named_scope("attn"):
+            q, k_new, v_new, *gate = _project_qkv(
+                layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
+            )
+            if mixed:
+                kind, ci = index[li]
+                attn_out, kp, vp = _mixed_attention(
+                    paged_attention_qlen, q.reshape(R, W, nH, hd), kp, vp,
+                    k_new, v_new, (kind, ci, where[kind]), attn_impl,
+                )
+                attn_out = attn_out.reshape(R * W, nH, hd)
+            else:
+                kp = _write_pool_rows(kp, k_new, li, dest_block_f, dest_off_f)
+                vp = _write_pool_rows(vp, v_new, li, dest_block_f, dest_off_f)
+                with jax.named_scope(scope):
+                    attn_out = paged_attention_qlen(
+                        q.reshape(R, W, nH, hd), kp, vp, block_tables, valid, li,
+                        impl=attn_impl, live=live, **kernel,
+                    ).reshape(R * W, nH, hd)
+            attn_out = _gate_attn_out(attn_out, gate)
+        if moe_load:
+            x, layer_load = _attn_out_mlp(
+                layer_p, x, attn_out, cfg, active_flat, True, li
+            )
+            return x, kp, vp, load + layer_load
+        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat, li=li)
+        return x, kp, vp, None
+
+    # the load rides in the carry as None (no leaf) unless asked for
+    load0 = jnp.zeros(_moe_load_len(cfg), jnp.int32) if moe_load else None
+    x, k_pool, v_pool, load = _scan_layers_carrying(
+        layer, (x, k_pool, v_pool, load0), params, cfg
+    )
+    logits = (
+        _final_logits(params, x, cfg).reshape(R, W, -1) if with_logits else None
+    )
+    if moe_load:
+        # cached rows this forward's attention read, live slots only, counted
+        # from the mask the kernel was given (one query's: a block shares it)
+        live_rows = valid[:, 0] if active is None else valid[:, 0] & active[:, None]
+        rows = live_rows.sum() * cfg.num_hidden_layers
+        return logits, k_pool, v_pool, jnp.concatenate(
+            [load, rows[None].astype(load.dtype)]
+        )
+    return logits, k_pool, v_pool
+
+
 def verify_step_paged(
     params: dict,
     tokens: jax.Array,  # [R, W]: draft inputs, column 0 = the last token
@@ -3325,102 +3569,46 @@ def verify_step_paged(
     pool block once for all W queries). Int8 pools quantize the W rows at
     this scatter and return (data, scales) tuples, exactly as
     `decode_step_paged` does for its single row."""
-    from areal_tpu.ops.paged_attention import paged_attention_qlen
-
-    if cfg.cache_layers["state"]:
-        raise NotImplementedError(
-            "a verify step over linear layers: a rejected draft would have "
-            "to roll each slot's state back"
-        )
-    R, W = tokens.shape
-    bsz = jax.tree.leaves(k_pool)[0].shape[2]
-    nb = block_tables.shape[1]
-    span = nb * bsz
-    nH, hd = cfg.num_attention_heads, cfg.head_dim_
-    positions = positions0[:, None] + jnp.arange(W, dtype=positions0.dtype)
-    flat_pos = positions.reshape(-1)
-    x = _embed_tokens(params, tokens.reshape(-1), flat_pos, cfg)  # [R*W, H]
-    rope_pos = (
-        positions if rope_offset is None else positions + rope_offset[:, None]
-    ).reshape(-1)
-    cos, sin = rope_table(rope_pos, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
-    valid = (
-        jnp.arange(span)[None, None, :] <= positions[:, :, None]
-    )  # [R, W, span]
-    mixed = isinstance(k_pool, dict)
-    if cfg.sliding_window is not None and not mixed:
-        valid = valid & (
-            jnp.arange(span)[None, None, :]
-            > positions[:, :, None] - cfg.sliding_window
-        )
-
-    # pool coordinates of each (slot, position) row; inactive slots land in
-    # the null block 0 so donors/parked KV stay untouched
-    blk_col = jnp.clip(positions // bsz, 0, nb - 1)  # [R, W]
-    dest_block = jnp.take_along_axis(block_tables, blk_col, axis=1)
-    dest_off = positions % bsz
-    if active is not None:
-        dest_block = jnp.where(active[:, None], dest_block, 0)
-        dest_off = jnp.where(active[:, None], dest_off, 0)
-    dest_block_f = dest_block.reshape(-1)
-    dest_off_f = dest_off.reshape(-1)
-    active_flat = (
-        None if active is None else jnp.repeat(active, W, axis=0)
+    return _qlen_step_paged(
+        params, tokens, positions0, k_pool, v_pool, block_tables, cfg,
+        active, rope_offset, attn_impl,
     )
 
-    live_of = functools.partial(
-        _live_columns, active=active, bsz=bsz, attn_impl=attn_impl
-    )
-    if mixed:
-        # the W rows of a slot are all written before any is read, so the
-        # ring must keep the oldest query's window clear of the newest row
-        if "window" in k_pool and W - 1 > ring_slack(cfg.sliding_window, bsz):
-            raise ValueError(
-                f"a verify chunk of {W} positions does not fit the ring's "
-                f"{ring_slack(cfg.sliding_window, bsz)} rows of slack past a "
-                f"window of {cfg.sliding_window} at pages of {bsz}"
-            )
-        index, where = _mixed_cache(
-            cfg, k_pool, flat_pos,
-            jnp.repeat(jnp.arange(R, dtype=flat_pos.dtype), W), active_flat,
-            (block_tables, dest_block_f, dest_off_f, valid), live_of,
-        )
-    else:
-        live = live_of(valid)
 
-    @jax.named_scope("layer")
-    def layer(carry, layer_p, li):
-        x, kp, vp = carry
-        h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-        with jax.named_scope("attn"):
-            q, k_new, v_new, *gate = _project_qkv(
-                layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
-            )
-            if mixed:
-                kind, ci = index[li]
-                attn_out, kp, vp = _mixed_attention(
-                    paged_attention_qlen, q.reshape(R, W, nH, hd), kp, vp,
-                    k_new, v_new, (kind, ci, where[kind]), attn_impl,
-                )
-                attn_out = attn_out.reshape(R * W, nH, hd)
-            else:
-                kp = _write_pool_rows(kp, k_new, li, dest_block_f, dest_off_f)
-                vp = _write_pool_rows(vp, v_new, li, dest_block_f, dest_off_f)
-                with jax.named_scope("attention"):
-                    attn_out = paged_attention_qlen(
-                        q.reshape(R, W, nH, hd), kp, vp, block_tables, valid, li,
-                        impl=attn_impl, live=live,
-                    ).reshape(R * W, nH, hd)
-            attn_out = _gate_attn_out(attn_out, gate)
-        x = _attn_out_mlp(layer_p, x, attn_out, cfg, active_flat, li=li)
-        return x, kp, vp
+def diffusion_step_paged(
+    params: dict,
+    tokens: jax.Array,  # [R, B]: the block as it stands, masks included
+    positions0: jax.Array,  # [R] index the block's first position occupies
+    k_pool,
+    v_pool,
+    block_tables: jax.Array,  # [R, nb]
+    cfg: ModelConfig,
+    active: jax.Array | None = None,
+    rope_offset: jax.Array | None = None,
+    attn_impl: str = "auto",
+    moe_load: bool = False,
+    with_logits: bool = True,
+) -> tuple:
+    """One forward of a block-diffusion decoder over the paged pool: the B
+    positions of each slot's block at `positions0 + j`, written to the pool
+    and attended under the block-causal mask (every query sees the pool rows
+    of all earlier blocks and the block's own B rows, both directions:
+    `valid = arange(span) <= block_end`). Logits [R, B, V] at a position are
+    of that position's own token (no shift). `verify_step_paged`'s body,
+    with the block's horizon in place of each query's own; the Pallas read
+    is named `paged_attention_block`.
 
-    x, k_pool, v_pool = _scan_layers_carrying(
-        layer, (x, k_pool, v_pool), params, cfg
-    )
-    logits = _final_logits(params, x, cfg)
-    return (
-        logits.reshape(R, W, -1),
-        k_pool,
-        v_pool,
+    A denoise forward's rows (some inputs still `mask_token_id`) are dead:
+    the commit forward over the clean block writes the same B rows again,
+    for good, before any later block reads them. `with_logits=False` skips
+    the head (a commit pass needs no logits). `moe_load`: also the forward's
+    int32 load vector, `moe_mlp`'s [pairs, busiest expert's pairs] over
+    layers and after it the cached rows the live slots' blocks read over
+    layers."""
+    if cfg.block_length_ <= 1:
+        raise ValueError("diffusion_step_paged: the model has no block_length")
+    return _qlen_step_paged(
+        params, tokens, positions0, k_pool, v_pool, block_tables, cfg,
+        active, rope_offset, attn_impl, block=True, moe_load=moe_load,
+        with_logits=with_logits,
     )

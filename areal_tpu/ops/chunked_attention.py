@@ -78,8 +78,11 @@ def chunked_attention(
     sm_scale: float | None = None,
     sliding_window: int | None = None,
     kv_chunk: int = 512,
+    q_horizon: jax.Array | None = None,  # [T] last visible index a query
 ) -> jax.Array:
-    """Packed causal-within-segment attention, O(T·kv_chunk) memory."""
+    """Packed causal-within-segment attention, O(T·kv_chunk) memory.
+    `q_horizon`: each query's last visible index of the stream in place of
+    its own (a block-causal mask: models/qwen2.block_horizon)."""
     T, nH, hd = q.shape
     nKV = k.shape[1]
     group = nH // nKV
@@ -99,6 +102,7 @@ def chunked_attention(
 
     qg = (q * scale).reshape(T, nKV, group, hd)
     q_idx = jnp.arange(T)
+    q_last = q_idx if q_horizon is None else q_horizon
 
     k_chunks = k.reshape(n_chunks, cs, nKV, hd)
     v_chunks = v.reshape(n_chunks, cs, nKV, hd)
@@ -115,7 +119,7 @@ def chunked_attention(
         k_idx = off + jnp.arange(cs)
         mask = (
             (segment_ids[:, None] == seg_c[None, :])
-            & (q_idx[:, None] >= k_idx[None, :])
+            & (q_last[:, None] >= k_idx[None, :])
             & (segment_ids[:, None] != PADDING_SEGMENT)
         )
         if sliding_window is not None:

@@ -513,6 +513,49 @@ def test_model_step_holds_one_paged_kernel_at_the_1p5b_head_shape(one_chip, monk
     assert hlo.count(" while(") == 1
 
 
+def test_block_step_holds_one_block_kernel_at_sdars_head_shape(one_chip, monkeypatch):
+    """A block-diffusion forward, two stacked layers at SDAR-30B-A3B's widths
+    (32 query and 4 KV heads of 128, blocks of 4 positions, 128 slots at a
+    depth of 10 pages), lowered with the kernel through Mosaic: the layer
+    loop holds ONE Mosaic call, named `%paged_attention_block`, with the
+    forward's work list among its operands, and three grouped matmuls over
+    4,224 rows."""
+    from areal_tpu.models.qwen2 import diffusion_step_paged, param_shapes
+    from areal_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_default_interpret", lambda: False)
+    L, R, B, nb, bsz = 2, 128, 4, 10, 128
+    cfg = ModelConfig(
+        vocab_size=151936, hidden_size=2048, intermediate_size=6144, num_hidden_layers=L,
+        num_attention_heads=32, num_key_value_heads=4, head_dim=128, model_type="sdar_moe",
+        qkv_bias=False, qk_norm=True, num_experts=128, num_experts_per_tok=8,
+        moe_intermediate_size=768, norm_topk_prob=True, rope_theta=1e6, block_length=B,
+        mask_token_id=151669, dtype="bfloat16", param_dtype="bfloat16")
+    bf = jnp.bfloat16
+    arg = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(lambda s: arg(s, bf), param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    pool = arg((L, R * nb + 1, bsz, 4 * 128), bf)
+
+    def step(params, kp, vp, bt, tokens, positions, active):
+        return diffusion_step_paged(params, tokens, positions, kp, vp, bt, cfg, active=active,
+                                    attn_impl="pallas", moe_load=True)
+
+    hlo = jax.jit(step, donate_argnums=(1, 2)).trace(
+        params, pool, pool, arg((R, nb), jnp.int32), arg((R, B), jnp.int32),
+        arg((R,), jnp.int32), arg((R,), jnp.bool_),
+    ).lower(lowering_platforms=("tpu",)).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in ln
+             and "ragged-dot" not in ln.split(" = ")[0]]
+    assert len(calls) == 1 and "%paged_attention_block" in calls[0], _mosaic_kernels(hlo)
+    operands = calls[0].split("operand_layout_constraints={")[1].split("}}")[0]
+    assert operands.split(", ")[2:6] == [f"s32[{R}]{{0}}"] * 4, operands
+    assert hlo.count(" while(") == 1
+    names, row_counts, tilings = _grouped_matmul_calls(hlo)
+    assert len(names) == 3 and row_counts == {"4224"}, (names, row_counts)
+    assert tilings == {"128,256,512", "128,512,256"}, tilings  # a 128-row tile
+
+
 def test_paged_kernel_is_named_at_olmoes_head_shape(one_chip):
     """16 query and 16 KV heads of 128: a pool row of 4,096 lanes (K and V
     2,048 each), the query block `[slots, 16, 2048]`, the cell's pool."""
@@ -685,6 +728,11 @@ _GROUPED_LAYERS = {
     "kexaone_64_slots_16_of_128_held": ((64, 6144, 2048, 16, 128, 8, True), 640, "128,512,512"),
     "qwen3next_64_slots_64_of_512_held": ((64, 2048, 512, 64, 512, 10, False), 640, "128,512,512"),
     "olmoe_prefill_bucket_2048": ((2048, *_OLMOE), 16384, "512,512,512"),
+    # a block-diffusion forward: 128 slots x 4 positions x top-8 = 4,096 pair
+    # rows over 128 groups, 32 a group, laid out at 4,224
+    # (experts 768 wide: 512 does not divide it, so that side tiles 256)
+    "sdar_128_slots_blocks_of_4": ((512, 2048, 768, 128, 128, 8, False), 4224,
+                                   "128,256,512|128,512,256"),
 }
 
 
@@ -728,7 +776,7 @@ def test_grouped_matmuls_row_tile_is_pinned(one_chip, shape, request):
             ((E, H, M), bf), ((E, H, M), bf), ((E, M, H), bf))
     names, row_counts, tilings = _grouped_matmul_calls(hlo)
     assert len(names) == 3, names
-    assert row_counts == {str(rows)} and tilings == {tiling}, (row_counts, tilings)
+    assert row_counts == {str(rows)} and tilings == set(tiling.split("|")), (row_counts, tilings)
 
 
 def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):

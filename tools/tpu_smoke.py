@@ -333,6 +333,28 @@ def paged_case(W, int8, nH, nKV, hd, R=16, nb=4, L=3, layer=2):
     return err < 0.03 and bool(jnp.all(jnp.isfinite(out))), f"maxerr={err:.4f}"
 
 
+def paged_block_case(nH=32, nKV=4, hd=128, B=4, R=16, nb=4, L=3, layer=2):
+    """The block-diffusion forward's read (`paged_attention_qlen` under the
+    kernel name `paged_attention_block`): B queries a slot that ALL see to
+    their block's last row, blocks aligned to position 0, slots at different
+    depths (a block that straddles a page edge among them), against the op's
+    XLA implementation."""
+    n_blocks = R * nb + 1
+    keys = jax.random.split(jax.random.PRNGKey(8), 3)
+    kp, vp = _paged_pool(keys, L, n_blocks, nKV, hd, False)
+    q = jax.random.normal(keys[2], (R, B, nH, hd), jnp.bfloat16)
+    bt = jnp.arange(1, n_blocks, dtype=jnp.int32).reshape(R, nb)
+    base = B * (31 * jnp.arange(R))  # slot 1's block is rows 124..127, slot 2's 248..251
+    end = (base + B - 1)[:, None, None]
+    valid = jnp.broadcast_to(jnp.arange(nb * PAGE)[None, None, :] <= end, (R, B, nb * PAGE))
+    args = (q, kp, vp, bt, valid, jnp.int32(layer))
+    out = jax.jit(lambda *a: paged_attention_qlen(
+        *a, impl="pallas", interpret=False, kernel_name="paged_attention_block"))(*args)
+    ref = jax.jit(lambda *a: paged_attention_qlen(*a, impl="xla"))(*args)
+    err = _maxerr(out, ref)
+    return err < 0.03 and bool(jnp.all(jnp.isfinite(out))), f"maxerr={err:.4f}"
+
+
 def gdn_step_case(n=6, layer=4, R=64, Hv=32, dk=128, dv=128, dead_every=7):
     """The Gated DeltaNet decode step (`ops/gdn_step.py`) at Qwen3-Next's
     state shape: six linear layers' float32 states in one pool, the kernel
@@ -595,6 +617,16 @@ def cases():
         "moe_mlp top-8 of 128, 16 held (ragged_dot) T=64 6144x2048",
         "default for MoE models with a share of the experts held",
         lambda: moe_case(64, H=6144, M=2048, E=16, published=128),
+    ))
+    # SDAR-30B-A3B: a block-diffusion forward's read (4 queries a slot under
+    # the block's horizon) and its experts (128 slots x 4 positions x top-8 =
+    # 4,096 pair rows over 128 experts of 768, laid out at 4,224)
+    out.append((f"paged_attention_block W=4 bf16 32/4/128 page={PAGE}",
+                "default for block-diffusion models", paged_block_case))
+    out.append((
+        "moe_mlp exact top-8 of 128 (ragged_dot) T=512 2048x768",
+        "default for block-diffusion MoE models",
+        lambda: moe_case(512, M=768, E=128),
     ))
     for k_dims, out_dims in (
         ((HIDDEN,), (N_HEADS, HEAD_DIM)),  # q
