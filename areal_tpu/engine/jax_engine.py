@@ -1181,15 +1181,17 @@ class JaxTrainEngine(TrainEngine):
         out["position_ids"] = pos
         return out
 
-    def _attn_live_block_pct(self, mbs: list[dict[str, Any]]) -> float:
-        """Live block pairs over all block pairs of these micro-batches, by
-        the flash kernels' own rule on the host's segment ids (NumPy; no
-        device read), in the layout the model gives the kernels: the packed
-        row whole, or in ring shards, zig-zag permuted where the model
-        permutes."""
+    def _attn_block_pcts(self, mbs: list[dict[str, Any]]) -> tuple[float, float]:
+        """(live, walked) block pairs as percentages of all block pairs of
+        these micro-batches, by the flash kernels' own rule and work list on
+        the host's segment ids (NumPy; no device read), in the layout the
+        model gives the kernels: the packed row whole, or in ring shards,
+        zig-zag permuted where the model permutes. Walked: the inner steps
+        `%flash_fwd`, `%flash_dq` and `%flash_dkv` take over their three
+        grids; above live where a run of the work list has a hole."""
         cfg = self.model_config
         ring = cfg is not None and resolve_attn_impl(cfg) == "ring"
-        live = visits = 0
+        live = walked = visits = 0
         for mb in mbs:
             cu = np.asarray(mb["cu_seqlens"])
             total = int(cu[-1])
@@ -1199,9 +1201,10 @@ class JaxTrainEngine(TrainEngine):
             if n and cfg.cp_zigzag and zigzag_eligible(total, self.mesh):
                 perm = zigzag_indices(total, n)
                 seg, pos = seg[perm], pos[perm]
-            a, b = live_block_counts(seg, pos, total // max(n, 1))
-            live, visits = live + a, visits + b
-        return 100.0 * live / max(visits, 1)
+            a, (walk_q, walk_k), b = live_block_counts(seg, pos, total // max(n, 1))
+            live, walked, visits = live + a, walked + 2 * walk_q + walk_k, visits + b
+        visits = max(visits, 1)
+        return 100.0 * live / visits, 100.0 * walked / (3 * visits)
 
     def _device_mb(self, mb: dict[str, Any]) -> dict[str, jax.Array]:
         """One packed micro-batch on device with the token sharding."""
@@ -1706,8 +1709,8 @@ class JaxTrainEngine(TrainEngine):
                 self.params = self._merge_trainable(self.params, new_trainable)
             # host work while the device runs the update: the share of the
             # flash kernels' block pairs that can hold a valid (query, key)
-            # pair (the rest they skip)
-            live_pct = self._attn_live_block_pct(mb_list.mbs)
+            # pair, and the share their walks take (the rest cost no step)
+            live_pct, walked_pct = self._attn_block_pcts(mb_list.mbs)
             with span("train/wait_device"):
                 gnorm_f = float(gnorm)  # blocks until the step is done on device
             step_time = time.perf_counter() - t_start
@@ -1728,6 +1731,7 @@ class JaxTrainEngine(TrainEngine):
                     # with n_tokens, the useful share of what the device was given
                     padded_tokens=float(sum(mb_tokens)),
                     attn_live_block_pct=live_pct,
+                    attn_walked_block_pct=walked_pct,
                     # programs this step ran for the first time (a steady-state
                     # step that compiles names itself here)
                     compiles=len(self._programs_seen) - seen_before,

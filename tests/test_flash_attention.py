@@ -14,7 +14,8 @@ from areal_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_chunk,
     live_block_counts,
-    live_table,
+    live_runs,
+    walk_runs,
 )
 
 
@@ -60,6 +61,9 @@ PACKINGS = {
     "many_short": lambda T: dict(n_seqs=T // 24, pad=T // 5),
     "single_segment": lambda T: dict(n_seqs=1, pad=0),
     "all_pad": lambda T: dict(n_seqs=1, pad=T),
+    # the last outer block (of 128 tokens, and the one before it at T = 512)
+    # is pad alone: a grid step that walks nothing
+    "pad_block": lambda T: dict(n_seqs=3, pad=T // 2),
 }
 
 
@@ -100,7 +104,9 @@ def test_liveness_is_exact_on_contiguous_packings(T, block, mean_len, pad):
         live = block_liveness(seg, seg, pos, pos, block, block)
         brute = brute_force_liveness(seg, seg, pos, pos, block, block)
         np.testing.assert_array_equal(live, brute)
-        assert live_block_counts(seg, pos, T, block) == (int(brute.sum()), brute.size)
+        # contiguous segments in order: no run has a hole, a walk is its live pairs
+        n_live = int(brute.sum())
+        assert live_block_counts(seg, pos, T, block) == (n_live, (n_live, n_live), brute.size)
     if pad == T:
         assert not live.any()
     if mean_len > T:
@@ -112,15 +118,8 @@ def test_liveness_never_kills_a_valid_pair_when_ids_are_scattered(seed):
     """No assumption that ids are monotone or contiguous: random ids with
     pads sprinkled in, random positions. The rule may keep a block that
     holds no pair, never drop one that does."""
-    rng = np.random.RandomState(seed)
-    Tq, Tk, bq, bk = 1024, 768, 128, 256
-    seg_q = rng.randint(-1, 6, Tq).astype(np.int32)
-    seg_k = rng.randint(-1, 6, Tk).astype(np.int32)
-    # ids in bands, so that some intervals do not overlap
-    seg_q[seg_q >= 0] += 7 * (np.arange(Tq) // 256)[seg_q >= 0]
-    seg_k[seg_k >= 0] += 7 * (np.arange(Tk) // 256)[seg_k >= 0]
-    qpos = rng.randint(0, 2000, Tq).astype(np.int32)
-    kpos = rng.randint(0, 2000, Tk).astype(np.int32)
+    bq, bk = 128, 256
+    seg_q, seg_k, qpos, kpos = scattered_ids(seed)
     live = block_liveness(seg_q, seg_k, qpos, kpos, bq, bk)
     brute = brute_force_liveness(seg_q, seg_k, qpos, kpos, bq, bk)
     assert not (brute & ~live).any()
@@ -128,26 +127,94 @@ def test_liveness_never_kills_a_valid_pair_when_ids_are_scattered(seed):
 
 
 
-@pytest.mark.parametrize("nk", [1, 5, 32, 40, 70])
-def test_live_table_packs_32_key_blocks_a_word(nk):
-    block, nq = 8, 6
-    rng = np.random.RandomState(nk)
-    seg_q = (rng.randint(0, 3, nq * block) + np.arange(nq * block) // 16).astype(np.int32)
-    seg_k = (rng.randint(0, 3, nk * block) + np.arange(nk * block) // 200).astype(np.int32)
-    qpos = rng.randint(0, 300, nq * block).astype(np.int32)
-    kpos = rng.randint(200, 900, nk * block).astype(np.int32)
-    live = block_liveness(seg_q, seg_k, qpos, kpos, block, block)
-    table = np.asarray(live_table(*map(jnp.asarray, (seg_q, seg_k, qpos, kpos)), block, block))
-    words = -(-nk // 32)
-    assert table.shape == (1, nq * words) and table.dtype == np.int32
-    i, j = np.meshgrid(np.arange(nq), np.arange(nk), indexing="ij")
-    bits = (table[0, i * words + j // 32] >> (j % 32)) & 1
-    np.testing.assert_array_equal(bits.astype(bool), live)
-    assert 0 < live.sum() and (nk == 1 or live.sum() < live.size)
+def scattered_ids(seed, Tq=1024, Tk=768):
+    """Ids that are neither monotone nor contiguous, in bands so that some
+    intervals do not overlap, pads sprinkled in, random positions."""
+    rng = np.random.RandomState(seed)
+    seg_q = rng.randint(-1, 6, Tq).astype(np.int32)
+    seg_k = rng.randint(-1, 6, Tk).astype(np.int32)
+    seg_q[seg_q >= 0] += 7 * (np.arange(Tq) // 256)[seg_q >= 0]
+    seg_k[seg_k >= 0] += 7 * (np.arange(Tk) // 256)[seg_k >= 0]
+    qpos = rng.randint(0, 2000, Tq).astype(np.int32)
+    kpos = rng.randint(0, 2000, Tk).astype(np.int32)
+    return seg_q, seg_k, qpos, kpos
+
+
+def check_work_list(seg_q, seg_k, qpos, kpos, bq, bk):
+    """`walk_runs` against brute force over `_mask_for`: every pair that
+    holds a valid (query, key) is in its query block's walk and in its key
+    block's; a walk starts and ends on a pair the liveness rule keeps (no
+    walk is longer than its run), and a block with none walks nothing. The
+    same on JAX arrays as on NumPy. Returns (brute, runs)."""
+    brute = brute_force_liveness(seg_q, seg_k, qpos, kpos, bq, bk)
+    live = np.asarray(block_liveness(seg_q, seg_k, qpos, kpos, bq, bk))
+    runs = walk_runs(seg_q, seg_k, qpos, kpos, bq, bk)
+    on_device = walk_runs(*map(jnp.asarray, (seg_q, seg_k, qpos, kpos)), bq, bk)
+    for a, b in zip(runs, on_device):
+        assert a.dtype == np.int32 and b.dtype == jnp.int32
+        np.testing.assert_array_equal(a, np.asarray(b))
+    for (lo, hi), table, brute_t in (
+        (runs[:2], live, brute), (runs[2:], live.T, brute.T),
+    ):
+        assert lo.shape == hi.shape == table.shape[:1]
+        inner = np.arange(table.shape[1])
+        walked = (lo[:, None] <= inner) & (inner < hi[:, None])
+        assert not (brute_t & ~walked).any()
+        for i in range(len(lo)):
+            if table[i].any():
+                assert table[i, lo[i]] and table[i, hi[i] - 1]
+            else:
+                assert lo[i] == hi[i] == 0
+    return brute, runs
+
+
+@pytest.mark.parametrize(
+    "T,block,mean_len,pad",
+    [
+        (2048, 128, 40, 300),  # many short segments, a pad tail
+        (4096, 512, 473, 700),  # the trainer's cell
+        (2048, 128, 5000, 0),  # one long segment: every run starts at 0
+        (1024, 128, 100, 1024),  # all pad: every walk empty
+        (1024, 128, 60, 520),  # outer blocks that are pad alone
+    ],
+    ids=["many_short", "trainer_cell", "one_long", "all_pad", "pad_blocks"],
+)
+def test_work_list_on_contiguous_packings(T, block, mean_len, pad):
+    """In-order contiguous segments: a run has no hole, so the three walks
+    are the live pairs and nothing else."""
+    for seed in range(3):
+        seg = random_packing(T, seed, mean_len, pad)
+        if mean_len > T:
+            seg[:] = 0
+        pos = np.arange(T, dtype=np.int32)
+        brute, (lo_q, hi_q, lo_k, hi_k) = check_work_list(seg, seg, pos, pos, block, block)
+        assert (hi_q - lo_q).sum() == (hi_k - lo_k).sum() == brute.sum()
+        if mean_len > T:
+            np.testing.assert_array_equal(lo_q, 0)
+            np.testing.assert_array_equal(hi_q, np.arange(T // block) + 1)
+            np.testing.assert_array_equal(hi_k, T // block)
+        if pad >= block:
+            assert (hi_q[-(pad // block):] == 0).all()
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_work_list_walks_the_holes_of_non_monotone_ids(seed):
+    """Scattered ids: a run may hold dead pairs (they are walked, and add
+    nothing); it never misses a live one, and `live_runs` of any table is its
+    rows' first and last set entry."""
+    brute, (lo_q, hi_q, lo_k, hi_k) = check_work_list(*scattered_ids(seed), 128, 256)
+    assert (hi_q - lo_q).sum() >= brute.sum() and (hi_k - lo_k).sum() >= brute.sum()
+    table = np.random.RandomState(seed).rand(3, 7, 9) < 0.3
+    table[1, 2] = False
+    lo, hi = live_runs(table)
+    for idx in np.ndindex(3, 7):
+        on = np.flatnonzero(table[idx])
+        want = (on[0], on[-1] + 1) if len(on) else (0, 0)
+        assert (lo[idx], hi[idx]) == want
 
 
 def test_forward_past_32_key_blocks():
-    """40 key blocks: a query block's row of the table spans two words."""
+    """40 key blocks a query block: the walk's `[nq]` vectors, whatever nk."""
     T, nH, nKV, hd = 5120, 1, 1, 32
     q, k, v, seg = make_inputs(T, nH, nKV, hd, seed=3, n_seqs=9, pad=200)
     out = flash_attention(q, k, v, seg, block_q=128, block_k=128, interpret=True)
@@ -156,34 +223,34 @@ def test_forward_past_32_key_blocks():
 
 
 def _flash_both_ways(q, k, v, seg_q, seg_k, qpos, kpos, block):
-    """(out, lse, dq, dk, dv) of the skipping kernels and of the same kernels
-    with every block pair forced live."""
-    q3, k3, v3 = (jnp.swapaxes(x, 0, 1) for x in (q, k, v))
+    """(out, lse, dq, dk, dv) of the kernels on their work list and of the
+    same kernels walking every block pair."""
+    q4, k4, v4 = (jnp.swapaxes(x, 0, 1)[None] for x in (q, k, v))
+    ids = tuple(jnp.asarray(x)[None] for x in (seg_q, seg_k, qpos, kpos))
     rng = np.random.RandomState(11)
-    do = jnp.asarray(rng.randn(*q3.shape), jnp.float32)
-    dlse = jnp.asarray(rng.randn(*q3.shape[:2]), jnp.float32)
-    scale = q.shape[-1] ** -0.5
+    do = jnp.asarray(rng.randn(*q4.shape), jnp.float32)
+    dlse = jnp.asarray(rng.randn(*q4.shape[:3]), jnp.float32)
+    flash = _flash(q.shape[-1] ** -0.5, block, block, True)
 
-    def run(live):
-        def f(q3, k3, v3):
-            return _flash(q3, k3, v3, seg_q, seg_k, qpos, kpos, live,
-                          scale, block, block, True)
-
-        (o, lse), vjp = jax.vjp(f, q3, k3, v3)
+    def run(runs):
+        runs = tuple(jnp.asarray(r, jnp.int32)[None] for r in runs)
+        (o, lse), vjp = jax.vjp(lambda q4, k4, v4: flash(q4, k4, v4, *ids, runs), q4, k4, v4)
         return (o, lse) + vjp((do, dlse))
 
-    live = live_table(seg_q, seg_k, qpos, kpos, block, block)
-    return run(live), run(jnp.full_like(live, -1))  # -1: every bit set
+    nq, nk = len(seg_q) // block, len(seg_k) // block
+    every = (np.zeros(nq), np.full(nq, nk), np.zeros(nk), np.full(nk, nq))
+    return run(walk_runs(seg_q, seg_k, qpos, kpos, block, block)), run(every)
 
 
 @pytest.mark.parametrize("packing", list(PACKINGS))
-def test_skipping_equals_all_live_to_the_bit(packing):
+def test_walk_equals_all_live_to_the_bit(packing):
     T, nH, nKV, hd, block = 512, 4, 2, 32, 128
     q, k, v, seg = make_inputs(T, nH, nKV, hd, seed=4, **PACKINGS[packing](T))
     pos = jnp.arange(T, dtype=jnp.int32)
     live = np.asarray(block_liveness(seg, seg, pos, pos, block, block))
     assert {"many_short": 0 < live.sum() < 10, "single_segment": live.sum() == 10,
-            "all_pad": live.sum() == 0}[packing], live
+            "all_pad": live.sum() == 0, "pad_block": 0 < live[:2].sum() and not live[2:].any(),
+            }[packing], live
     skipping, forced = _flash_both_ways(q, k, v, seg, seg, pos, pos, block)
     for a, b, name in zip(skipping, forced, ("out", "lse", "dq", "dk", "dv")):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
@@ -279,6 +346,27 @@ def test_vmap_with_a_segment_row_each():
         np.testing.assert_allclose(
             np.asarray(out[b]), np.asarray(dense_reference(q[b], k[b], v[b], seg[b])),
             atol=2e-5, rtol=2e-5)
+
+
+def test_vmap_of_the_gradients_equals_row_by_row():
+    """The pipelined trainer reaches the backward kernels under `jax.vmap`
+    (a vmap over stages around each stage's vjp): rows fold into the
+    kernels' batch axis there too, each with its own work list."""
+    B, T, nH, nKV, hd = 2, 256, 2, 1, 32
+    rows = [make_inputs(T, nH, nKV, hd, seed=40 + b, n_seqs=2 + 3 * b, pad=130 * b)
+            for b in range(B)]
+    q, k, v, seg = (jnp.stack(x) for x in zip(*rows))
+
+    def grads(q, k, v, seg):
+        loss = lambda q, k, v: jnp.sum(jnp.sin(flash_attention(  # noqa: E731
+            q, k, v, seg, block_q=128, block_k=128, interpret=True)))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    batched = jax.jit(jax.vmap(grads))(q, k, v, seg)
+    one_row = jax.jit(grads)  # compiled as well: `delta` is XLA's sum on both sides
+    for b in range(B):
+        for got, want in zip(batched, one_row(q[b], k[b], v[b], seg[b])):
+            np.testing.assert_array_equal(np.asarray(got[b]), np.asarray(want))
 
 
 def test_nonaligned_length_padding():

@@ -24,6 +24,7 @@ from areal_tpu.engine.sft.lm_engine import (
 )
 from areal_tpu.models.qwen2 import ModelConfig
 from areal_tpu.parallel import mesh as mesh_lib
+from areal_tpu.ops.flash_attention import block_liveness
 from areal_tpu.utils.data import pad_sequences_to_tensors, zigzag_indices
 from tests.test_flash_attention import brute_force_liveness, random_packing
 
@@ -132,16 +133,25 @@ def test_save_load_roundtrip(engine, tmp_path):
     [("flash", 1, False), ("ring", 4, False), ("ring", 4, True), ("dense", 1, False)],
     ids=["one_chip", "ring4", "ring4_zigzag", "dense_reads_as_flash"],
 )
-def test_attn_live_block_pct_is_the_numpy_count(cpu_devices, attn_impl, dp, zigzag):
-    """`train_batch`'s counter: the flash kernels' liveness rule on the host's
-    segment ids, in the layout the model hands the kernels, against a brute
-    force count over `_mask_for` (contiguous layouts: exact)."""
+def test_attn_block_pcts_are_the_numpy_counts(cpu_devices, attn_impl, dp, zigzag):
+    """`train_batch`'s two counters: the flash kernels' liveness rule and
+    their work list on the host's segment ids, in the layout the model hands
+    the kernels. Live against a brute force count over `_mask_for`
+    (contiguous layouts: exact); walked against the span from each outer
+    block's first to its last live partner, call by call (a ring step is a
+    call), twice by query block (`%flash_fwd`, `%flash_dq`) and once by key
+    block (`%flash_dkv`): the live count where no run has a hole."""
     mesh = mesh_lib.build_mesh(
         ParallelStrategy(data_parallel_size=dp), devices=cpu_devices[:dp]
     )
     cfg = dataclasses.replace(TINY_MODEL, attn_impl=attn_impl, cp_zigzag=zigzag)
     fake = types.SimpleNamespace(model_config=cfg, mesh=mesh)
-    mbs, live, visits = [], 0, 0
+    mbs, live, visits, walked = [], 0, 0, 0
+
+    def spans(table):
+        on = [np.flatnonzero(row) for row in table]
+        return sum(int(x[-1] - x[0] + 1) for x in on if len(x))
+
     for seed, total in enumerate((8192, 4096, 2560)):
         seg = random_packing(total, seed, mean_len=400, pad=300)
         seg[seg < 0] = seg.max() + 1  # the packer's pad tail: a segment of its own
@@ -162,11 +172,16 @@ def test_attn_live_block_pct_is_the_numpy_count(cpu_devices, attn_impl, dp, zigz
                     shard(seg, qi, -1), shard(seg, ki, -1), shard(pos, qi, 0),
                     shard(pos, ki, 0), block, block)
                 live, visits = live + int(brute.sum()), visits + brute.size
-    pct = JaxTrainEngine._attn_live_block_pct(fake, mbs)
+                kept = block_liveness(
+                    shard(seg, qi, -1), shard(seg, ki, -1), shard(pos, qi, 0),
+                    shard(pos, ki, 0), block, block)
+                walked += 2 * spans(kept) + spans(kept.T)
+    pct, walked_pct = JaxTrainEngine._attn_block_pcts(fake, mbs)
+    assert walked_pct == pytest.approx(100.0 * walked / (3 * visits))
     if zigzag:
-        assert 100.0 * live / visits <= pct < 40.0
+        assert 100.0 * live / visits <= pct <= walked_pct < 40.0
     else:
-        assert pct == pytest.approx(100.0 * live / visits)
+        assert pct == pytest.approx(100.0 * live / visits) == walked_pct
         assert 5.0 < pct < 40.0
 
 

@@ -12,7 +12,9 @@ from areal_tpu.parallel import mesh as mesh_lib
 from areal_tpu.utils.data import zigzag_indices
 from tests.test_flash_attention import (
     PACKINGS,
+    _flash_both_ways,
     brute_force_liveness,
+    check_work_list,
     dense_reference,
     make_inputs,
     random_packing,
@@ -77,22 +79,53 @@ def test_liveness_of_every_ring_step(n, mean_len, zigzag):
     shard = lambda x, i: x[i * Tl:(i + 1) * Tl]  # noqa: E731
     positions = [np.asarray(_shard_positions(jnp.int32(i), Tl, n, zigzag))
                  for i in range(n)]
-    dead_steps = 0
+    dead_steps = walked = n_live = 0
     for qi in range(n):
         for ki in range(n):
             args = (shard(seg_row, qi), shard(seg_row, ki), positions[qi],
                     positions[ki], block, block)
-            live, brute = block_liveness(*args), brute_force_liveness(*args)
+            live = block_liveness(*args)
+            # the step's work list: every live pair in a walk, an earlier,
+            # the same and a later shard alike
+            brute, (lo_q, hi_q, lo_k, hi_k) = check_work_list(*args)
             assert not (brute & ~live).any(), (qi, ki)
             if not zigzag:
                 np.testing.assert_array_equal(live, brute, err_msg=f"{qi},{ki}")
+                assert (hi_q - lo_q).sum() == (hi_k - lo_k).sum() == brute.sum()
                 if ki > qi:
-                    assert not live.any()
+                    assert not live.any() and not hi_q.any() and not hi_k.any()
             dead_steps += not live.any()
+            walked += int((hi_q - lo_q).sum())
+            n_live += int(live.sum())
+    # zig-zag: a shard's two chunks can leave a hole in a run, which is walked
+    assert walked >= n_live and (zigzag or walked == n_live)
+    assert walked < 0.5 * n * n * (Tl // block) ** 2
     # the whole ring visits far fewer blocks than it holds
     assert dead_steps >= (n * (n - 1) // 2 if not zigzag else 0)
     if mean_len < Tl and n > 2 and not zigzag:
         assert dead_steps > n * (n - 1) // 2  # also steps two shards back
+
+
+@pytest.mark.parametrize("zigzag", [False, True], ids=["contiguous", "zigzag"])
+@pytest.mark.parametrize("src", [0, 1, 2], ids=["earlier", "same", "later"])
+def test_ring_step_walk_equals_all_live_to_the_bit(src, zigzag):
+    """One ring step of query shard 1 of 3 (256 tokens, two blocks) against
+    the shard before it, itself and the one after, with the ring body's own
+    position maps: outputs, lse and the three gradients of the kernels on
+    their work list equal those of a walk over every block pair."""
+    n, Tl, nH, nKV, hd, block = 3, 256, 4, 2, 32, 128
+    T = n * Tl
+    q, k, v, _ = make_inputs(Tl, nH, nKV, hd, seed=31 + src)
+    seg_row = random_packing(T, seed=5, mean_len=150, pad=60)
+    if zigzag:
+        seg_row = seg_row[zigzag_indices(T, n)]
+    shard = lambda i: jnp.asarray(seg_row[i * Tl:(i + 1) * Tl])  # noqa: E731
+    qpos, kpos = (_shard_positions(jnp.int32(i), Tl, n, zigzag) for i in (1, src))
+    walk, forced = _flash_both_ways(q, k, v, shard(1), shard(src), qpos, kpos, block)
+    for a, b, name in zip(walk, forced, ("out", "lse", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    if src == 2 and not zigzag:
+        assert not np.asarray(walk[0]).any()
 
 
 @pytest.mark.parametrize("packing", list(PACKINGS))

@@ -373,7 +373,7 @@ def test_train_batch_counts_padding_and_first_calls(programs):
     assert s["n_tokens"] == 32.0 and s["padded_tokens"] >= s["n_tokens"]
     assert s["padded_tokens"] % 128 == 0
     # one micro-batch of one 128-token block: the only block pair is live
-    assert s["attn_live_block_pct"] == 100.0
+    assert s["attn_live_block_pct"] == s["attn_walked_block_pct"] == 100.0
     # zero_grads, grad_step and apply_update ran for the first time
     assert s["compiles"] == 3
 
@@ -779,7 +779,26 @@ def test_grouped_matmuls_row_tile_is_pinned(one_chip, shape, request):
     assert row_counts == {str(rows)} and tilings == set(tiling.split("|")), (row_counts, tilings)
 
 
+def _flash_work_lists(hlo: str, rows: int, outer: dict[str, int]) -> None:
+    """Each flash kernel of the program takes its work list as its first
+    four operands, scalar-prefetch vectors whose shapes follow the row count
+    and the number of outer blocks alone: `_schedule`'s `[rows * outer
+    blocks]` (a block's run, its place in the walk and the next block that
+    has a partner); the fifth is the ids of the outer side."""
+    calls = [ln for ln in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert {c.split(" = ")[0].split("%")[-1].split(".")[0] for c in calls} == set(outer), calls
+    for call in calls:
+        n = outer[call.split(" = ")[0].split("%")[-1].split(".")[0]]
+        operands = call.split("operand_layout_constraints={")[1].split("}}")[0].split(", ")
+        assert operands[:4] == [f"s32[{rows * n}]{{0}}"] * 4, operands[:5]
+        assert operands[4].startswith(f"s32[{rows},1,"), operands[:5]
+
+
 def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):
+    """`flash_attention` at `train-0.5b-gsm8k`'s call, forward and both
+    backward kernels under remat: the names the benchmark's readers match,
+    and a grid of (1 row, 14 heads, 16 outer blocks) each, the inner axis a
+    loop over the work list inside the kernel."""
     from areal_tpu.ops.flash_attention import flash_attention
 
     T, nH, nKV, hd = 8192, 14, 2, 64
@@ -798,15 +817,18 @@ def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):
     # what the transformations around a kernel used to name it
     for wrapper in ("%checkpoint", "%rematted_computation", "%closed_call"):
         assert wrapper not in hlo, wrapper
+    _flash_work_lists(hlo, 1, {"flash_fwd": 16, "flash_dq": 16, "flash_dkv": 16})
 
 
 @pytest.mark.parametrize("nH,nKV", [(12, 2), (16, 16)], ids=["1p5b", "olmoe"])
 def test_flash_kernel_batches_under_vmap_at_the_rollout_head_shapes(one_chip, nH, nKV):
     """The decode engine's batched prefill `vmap`s the model forward, each
-    row with its own segment ids, so each with its own liveness table: the
-    table is a blocked SMEM operand, which the batching rule folds into one
-    kernel over a longer grid (a scalar-prefetch operand would become a
-    sequential loop over the rows)."""
+    row with its own segment ids, so each with its own work list. The work
+    list is scalar-prefetch operands, which Pallas's batching rule would turn
+    into a sequential loop over the rows: the kernels carry a row axis of
+    their own and a batching rule (`_rows_under_vmap`) that folds the
+    vmapped rows into it, so the batch is still ONE kernel over a longer
+    grid and no loop."""
     from areal_tpu.ops.flash_attention import flash_attention
 
     B, T, hd = 16, 256, 128
@@ -818,6 +840,7 @@ def test_flash_kernel_batches_under_vmap_at_the_rollout_head_shapes(one_chip, nH
     kernels = _mosaic_kernels(hlo)
     assert len(kernels) == 1 and "flash_fwd" in kernels[0], kernels
     assert " while(" not in hlo
+    _flash_work_lists(hlo, B, {"flash_fwd": 1})
 
 
 def test_ring_step_kernels_are_named_at_the_fsdp4_shard_shape(one_chip):
@@ -837,3 +860,4 @@ def test_ring_step_kernels_are_named_at_the_fsdp4_shard_shape(one_chip):
         ((T, nKV, hd), bf), ((T, nKV, hd), bf), *[((T,), jnp.int32)] * 4)
     for kernel in ("%flash_fwd", "%flash_dq", "%flash_dkv"):
         assert kernel in hlo, kernel
+    _flash_work_lists(hlo, 1, {"flash_fwd": 8, "flash_dq": 8, "flash_dkv": 8})

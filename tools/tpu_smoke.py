@@ -15,6 +15,7 @@ from __future__ import annotations
 import functools
 import os
 import sys
+import time
 import traceback
 
 import jax
@@ -93,8 +94,26 @@ def _rel(a, b):
     return float(jnp.max(jnp.abs(a - b)) / (1e-3 + jnp.max(jnp.abs(b))))
 
 
+def _ms_per_call(fn, *args, n=20):
+    """Host clock over `n` queued calls and one wait (`fn` already compiled)."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / n * 1e3
+
+
+def _one_segment(T):
+    """One sequence over the whole row: every block pair under the diagonal
+    is live, so a walk is as long as the grid it replaced."""
+    return jnp.zeros((T,), jnp.int32), T
+
+
 def flash_case(T, nH, nKV, hd, segments=_packed_segments):
-    """flash_attention forward + backward against the dense reference."""
+    """flash_attention forward + backward against the dense reference; the
+    detail ends with the time of a forward and of forward + backward a call
+    (the kernels and the wrapper's transposes around them)."""
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(kq, (T, nH, hd), jnp.bfloat16)
     k = jax.random.normal(kk, (T, nKV, hd), jnp.bfloat16)
@@ -107,15 +126,18 @@ def flash_case(T, nH, nKV, hd, segments=_packed_segments):
         o = flash_attention(q, k, v, seg, sm_scale=sm_scale, interpret=False)
         return jnp.sum((o.astype(jnp.float32) * w) ** 2)
 
-    o_flash = jax.jit(
+    run_fwd = jax.jit(
         lambda q, k, v: flash_attention(
             q, k, v, seg, sm_scale=sm_scale, interpret=False
         )
-    )(q, k, v)
-    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    )
+    run_grad = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))
+    o_flash, g_flash = run_fwd(q, k, v), run_grad(q, k, v)
+    times = (f" ms_per_call: fwd {_ms_per_call(run_fwd, q, k, v):.3f}"
+             f" fwd+bwd {_ms_per_call(run_grad, q, k, v):.3f}")
     mask_rows = functools.partial(_segment_mask, seg)
 
-    if T > 8192:
+    if T >= 8192:
         # A dense [T, T] reference is infeasible here, which is the point of
         # the case: check a 128-query slice that attends the whole prefix,
         # and that the backward ran and is finite.
@@ -123,7 +145,7 @@ def flash_case(T, nH, nKV, hd, segments=_packed_segments):
         o_ref, _ = _masked_attention(q[rows], k, v, mask_rows(rows), sm_scale)
         fwd = float(jnp.max(jnp.abs(o_flash[rows].astype(jnp.float32) - o_ref)))
         finite = all(bool(jnp.all(jnp.isfinite(g))) for g in g_flash)
-        return fwd < 0.06 and finite, f"fwd_maxerr={fwd:.4f} bwd_finite={finite}"
+        return fwd < 0.06 and finite, f"fwd_maxerr={fwd:.4f} bwd_finite={finite}" + times
 
     mask = mask_rows(jnp.arange(T))
 
@@ -138,7 +160,7 @@ def flash_case(T, nH, nKV, hd, segments=_packed_segments):
     )
     g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     bwd = max(_rel(a, b) for a, b in zip(g_flash, g_ref))
-    return fwd < 0.06 and bwd < 0.06, f"fwd_maxerr={fwd:.4f} bwd_relerr={bwd:.4f}"
+    return fwd < 0.06 and bwd < 0.06, f"fwd_maxerr={fwd:.4f} bwd_relerr={bwd:.4f}" + times
 
 
 def flash_vmap_case(B=4, T=256, nH=12, nKV=2, hd=128):
@@ -154,11 +176,12 @@ def flash_vmap_case(B=4, T=256, nH=12, nKV=2, hd=128):
         jnp.arange(T)[None, :] < lens[:, None], 0, PADDING_SEGMENT
     ).astype(jnp.int32)
     sm_scale = hd**-0.5
-    out = jax.jit(jax.vmap(
+    batched = jax.jit(jax.vmap(
         lambda q, k, v, seg: flash_attention(
             q, k, v, seg, sm_scale=sm_scale, interpret=False
         )
-    ))(q, k, v, seg)
+    ))
+    out = batched(q, k, v, seg)
     err = 0.0
     for b in range(B):
         mask = _segment_mask(seg[b], jnp.arange(T))
@@ -167,7 +190,8 @@ def flash_vmap_case(B=4, T=256, nH=12, nKV=2, hd=128):
         err = max(err, float(
             jnp.max(jnp.abs((out[b].astype(jnp.float32) - o_ref)[valid]))
         ))
-    return err < 0.06, f"fwd_maxerr={err:.4f} rows={B}"
+    return err < 0.06, (f"fwd_maxerr={err:.4f} rows={B} "
+                        f"ms_per_call: fwd {_ms_per_call(batched, q, k, v, seg):.3f}")
 
 
 def flash_chunk_case(later=False, T=1024, nH=N_HEADS, nKV=N_KV, hd=HEAD_DIM):
@@ -204,16 +228,20 @@ def flash_chunk_case(later=False, T=1024, nH=N_HEADS, nKV=N_KV, hd=HEAD_DIM):
 
         return f
 
-    o, lse = jax.jit(flash)(q, k, v)
+    run_fwd = jax.jit(flash)
+    run_grad = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))
+    o, lse = run_fwd(q, k, v)
     o_ref, lse_ref = ref(q, k, v)
-    g = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    g = run_grad(q, k, v)
     g_ref = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(q, k, v)
     fwd = float(jnp.max(jnp.abs(o.astype(jnp.float32) - o_ref.astype(jnp.float32))))
     lse_err = float(jnp.max(jnp.abs(lse - lse_ref)))
     bwd = max(_rel(a, b) for a, b in zip(g, g_ref))
     return (
         fwd < 0.06 and lse_err < 0.05 and bwd < 0.06,
-        f"fwd_maxerr={fwd:.4f} lse_maxerr={lse_err:.4f} bwd_relerr={bwd:.4f}",
+        f"fwd_maxerr={fwd:.4f} lse_maxerr={lse_err:.4f} bwd_relerr={bwd:.4f} "
+        f"ms_per_call: fwd {_ms_per_call(run_fwd, q, k, v):.3f} "
+        f"fwd+bwd {_ms_per_call(run_grad, q, k, v):.3f}",
     )
 
 
@@ -404,7 +432,6 @@ def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5, publish
     At 64 tokens the 512 pair rows are few a group, so `grouped_matmul_rows`
     lays them out at 640 (the grouped matmul's 128-row tile); a 2,048-token
     bucket's 16,384 keep the 512-row tile: `rows=` in the detail."""
-    import time
 
     from areal_tpu.models.qwen2 import ModelConfig, grouped_matmul_rows, moe_mlp
 
@@ -455,15 +482,9 @@ def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5, publish
     if layers == 1:
         return ok, detail
 
-    def ms(p, n=30):
-        mlp(p, x)[0].block_until_ready()
-        t0 = time.perf_counter()
-        for _ in range(n):
-            out = mlp(p, x)[0]
-        out.block_until_ready()
-        return (time.perf_counter() - t0) / n * 1e3
-
-    t_own, t_stack = ms(p), ms(called)
+    t_own, t_stack = (
+        _ms_per_call(lambda p: mlp(p, x)[0], p, n=30) for p in (p, called)
+    )
     return ok and t_stack < 1.25 * t_own, (
         f"{detail} ms_per_call: {G} groups {t_stack:.3f}, {E} groups {t_own:.3f}")
 
@@ -533,13 +554,20 @@ def cases():
             "default",
             lambda a=(T, nH, nKV, hd): flash_case(*a),
         ))
-    # the trainer's packing: the block-liveness skip at work
-    for T, nH, nKV, hd in ((4096, 14, 2, 64), (4096, 12, 2, 128)):
+    # the trainer's packing: the walk over live block pairs at work (the
+    # 8,192 row is `train-0.5b-gsm8k`'s call, 4,096 at 128 a ring shard's)
+    for T, nH, nKV, hd in ((4096, 14, 2, 64), (4096, 12, 2, 128), (8192, 14, 2, 64)):
         out.append((
             f"flash_attention fwd+bwd many segments + pad tail T={T} {nH}/{nKV}/{hd}",
             "default",
             lambda a=(T, nH, nKV, hd): flash_case(*a, segments=_many_segments),
         ))
+    # one sequence a row: nothing to leave out, the walk as long as a grid
+    out.append((
+        "flash_attention fwd+bwd one segment T=8192 14/2/64",
+        "default",
+        lambda: flash_case(8192, 14, 2, 64, segments=_one_segment),
+    ))
     out.append((
         "flash_attention under vmap, a segment row each, B=4 T=256 12/2/128",
         "default (batched prefill)",
