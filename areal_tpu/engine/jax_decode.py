@@ -630,6 +630,13 @@ class JaxDecodeEngine(InferenceEngine):
         self._moe_absent_pairs = 0
         self._kv_full_rows_read = 0
         self._kv_window_rows_read = 0
+        # a latent model: cached latent rows read, and tokens x sparse layers
+        # whose kept routing groups include one held here; bytes of a cached
+        # row of one layer as the pool stores it (set by initialize())
+        self._kv_latent_rows_read = 0
+        self._moe_group_tokens_here = 0
+        self._moe_group_experts_touched = 0
+        self._row_nbytes = 0
         # linear layers: state updates of live slots (slots x layers x steps)
         self._gdn_state_updates = 0
         # of the steps the paged kernel takes a chunk (a live block column,
@@ -826,6 +833,8 @@ class JaxDecodeEngine(InferenceEngine):
             self._fabric_on = False
         if cfg.mixed:
             self._refuse_unserved_for_mixed(bs)
+            if cfg.latent:
+                self._refuse_unserved_for_latent()
             if cfg.cache_layers["window"]:
                 self._ring = WindowRing(R, cfg.sliding_window, bs)
             if cfg.cache_layers["state"]:
@@ -846,14 +855,21 @@ class JaxDecodeEngine(InferenceEngine):
             else jnp.dtype(self.config.kv_cache_dtype).itemsize
         )
         kv_scale_bytes = 4 if self._kv_quant else 0
-        block_nbytes = (
-            2  # K and V
-            * (len(cfg.cache_layers["full"]) if cfg.mixed
-               else cfg.num_hidden_layers)
-            * bs
-            * cfg.num_key_value_heads
-            * (cfg.head_dim_ * kv_elem + kv_scale_bytes)
-        )
+        # the row the block-table-addressed pool has, a layer: K and V of every
+        # kv head (and an int8 pool's scale a row and head), or a latent
+        # model's one row at the lanes the pool stores it in
+        if cfg.latent:
+            paged_layers = len(cfg.cache_layers["latent"])
+            self._row_nbytes = cfg.latent_row_lanes * kv_elem
+        else:
+            paged_layers = (len(cfg.cache_layers["full"]) if cfg.mixed
+                            else cfg.num_hidden_layers)
+            self._row_nbytes = (
+                2  # K and V
+                * cfg.num_key_value_heads
+                * (cfg.head_dim_ * kv_elem + kv_scale_bytes)
+            )
+        block_nbytes = paged_layers * bs * self._row_nbytes
         self._block_nbytes = int(block_nbytes)
         with self._host_lock:
             if float(self.config.kv_host_pool_mb) > 0:
@@ -895,8 +911,19 @@ class JaxDecodeEngine(InferenceEngine):
                 if layers and kind in blocks
             }
 
-        self._k_cache = new_pool()
-        self._v_cache = new_pool()
+        if cfg.latent:
+            # a fourth kind: ONE row a token and layer, `[c_kv | k_pe]` at
+            # whole vregs of lanes, through the same block table and
+            # allocator as a paged pool (a fork aliases its blocks); no V side
+            self._k_cache = {"latent": jax.device_put(
+                jnp.zeros((cfg.num_hidden_layers, n_blocks, bs,
+                           cfg.latent_row_lanes), pool_dtype),
+                self._cache_sharding,
+            )}
+            self._v_cache = {}
+        else:
+            self._k_cache = new_pool()
+            self._v_cache = new_pool()
         if self._state is not None:
             # a state a slot and linear layer, row 0 the null slot. It rides
             # in the K-side dict, so every pool program carries it donated
@@ -966,6 +993,9 @@ class JaxDecodeEngine(InferenceEngine):
             self._moe_absent_pairs = 0
             self._kv_full_rows_read = 0
             self._kv_window_rows_read = 0
+            self._kv_latent_rows_read = 0
+            self._moe_group_tokens_here = 0
+            self._moe_group_experts_touched = 0
             self._gdn_state_updates = 0
             self._paged_cols_live = 0
             self._paged_cols_visited = 0
@@ -1048,6 +1078,38 @@ class JaxDecodeEngine(InferenceEngine):
                 "stack) is not served with: " + "; ".join(refused)
             )
 
+    def _refuse_unserved_for_latent(self) -> None:
+        """A latent model's cache is one row a token and layer with no V
+        side, read by a kernel of its own that scores one query a slot. What
+        assumes K and V rows by kv head is not served for it yet (beyond
+        what `_refuse_unserved_for_mixed` refuses for every stack that lives
+        in a dict of pools: an int8 pool, the host tier, migration): say so
+        here, with the reason, and not at the first request that needs it."""
+        cfg, c = self.model_config, self.config
+        refused = []
+        if c.spec_decode != "off":
+            refused.append(
+                f"spec_decode={c.spec_decode!r}: the absorbed attention scores "
+                "one query a slot; a verify chunk has several"
+            )
+        if max(int(c.tensor_parallel_size), 1) > 1:
+            refused.append(
+                f"tensor_parallel_size={c.tensor_parallel_size}: the latent "
+                "pool has no kv-head axis to shard"
+            )
+        if self._w_quant:
+            refused.append(
+                f"weight_dtype={c.weight_dtype!r}: the low-rank projections "
+                "have no int8 form"
+            )
+        if self._vision_params is not None:
+            refused.append("a vision tower: no latent model with one is known")
+        if refused:
+            raise NotImplementedError(
+                f"{cfg.model_type} (latent attention: one cached row a token) "
+                "is not served with: " + "; ".join(refused)
+            )
+
     def _refuse_unserved_for_diffusion(self) -> None:
         """A block-diffusion model's cache is valid a whole block at a time
         (a block's rows are written for good by its commit forward), and a
@@ -1119,12 +1181,9 @@ class JaxDecodeEngine(InferenceEngine):
         return n - 1
 
     def _kv_row_nbytes(self) -> int:
-        """Bytes of one cached row of one layer, K and V (fp pools)."""
-        cfg = self.model_config
-        if cfg is None:
-            return 0
-        return (2 * cfg.num_key_value_heads * cfg.head_dim_
-                * jnp.dtype(self.config.kv_cache_dtype).itemsize)
+        """Bytes of one cached row of one layer as the pool stores it: K
+        and V of every kv head, or a latent model's one row."""
+        return int(self._row_nbytes)
 
     def state_pool(self) -> dict | None:
         """The recurrent-state pool `{"S", "conv"}` of a model with linear
@@ -2240,6 +2299,19 @@ class JaxDecodeEngine(InferenceEngine):
                 [B, pages])."""
                 bts_b, ring_b = tables if isinstance(tables, tuple) else (tables, None)
                 layers = cfg.cache_layers
+                if cfg.latent:
+                    # one pool: the prompts' latent rows, padded to the lanes
+                    # the pool stores, through the block table
+                    lp = kq["latent"]
+                    Ll, _, bsz, D = lp.shape
+                    nb_w = bts_b.shape[1]
+                    r = jnp.pad(
+                        ks[:, :, :, 0], ((0, 0), (0, 0), (0, nb_w * bsz - bucket),
+                                         (0, D - ks.shape[-1]))
+                    ).reshape(B, Ll, nb_w, bsz, D).astype(lp.dtype)
+                    for b in range(B):
+                        lp = lp.at[:, bts_b[b]].set(r[b])
+                    return {**kq, "latent": lp}, vq
                 # `ks` / `vs` stack the attention layers alone, in layer order
                 at = {li: j for j, li in enumerate(
                     sorted(layers["full"] + layers["window"]))}
@@ -2368,6 +2440,7 @@ class JaxDecodeEngine(InferenceEngine):
         dense engine's O(prefix-length) row memcpy, and versus the
         transformer prefill both replace."""
         if True not in self._fork_fns:
+            cfg_latent = self.model_config.latent
 
             def fork_block(kq, vq, src_b, dst_b):
                 # tree-mapped so int8 operands copy the scale block through
@@ -2379,8 +2452,11 @@ class JaxDecodeEngine(InferenceEngine):
                 def one(pool):
                     if isinstance(pool, dict):
                         # a mixed stack: the boundary block is the paged
-                        # pool's; the ring is copied whole (_get_ring_copy_fn)
-                        return {**pool, "full": cp(pool["full"])}
+                        # pool's (a latent model's: its one pool's; its V
+                        # side holds nothing); the ring is copied whole
+                        # (_get_ring_copy_fn)
+                        kind = "latent" if cfg_latent else "full"
+                        return {**pool, kind: cp(pool[kind])} if pool else pool
                     return jax.tree.map(cp, pool)
 
                 return one(kq), one(vq)
@@ -4419,11 +4495,17 @@ class JaxDecodeEngine(InferenceEngine):
                             self._kv_block_rows_read += more.pop(0)
                             self._dfn_slot_forwards += more.pop(0)
                             self._dfn_commit_forwards += more.pop(0)
+                        if cfg.moe_n_group > 1:
+                            self._moe_group_tokens_here += more.pop(0)
+                            self._moe_group_experts_touched += more.pop(0)
                         if more:
                             self._kv_full_rows_read += more[0]
                             self._kv_window_rows_read += more[1]
-                            # with linear layers, their state updates
-                            self._gdn_state_updates += sum(more[2:])
+                            if cfg.latent:
+                                self._kv_latent_rows_read += more[2]
+                            else:
+                                # with linear layers, their state updates
+                                self._gdn_state_updates += sum(more[2:])
             self._apply_chunk(rec, toks, logps, acc)
 
     def _apply_chunk(self, rec: "_Inflight", toks: np.ndarray,
@@ -6030,6 +6112,9 @@ class JaxDecodeEngine(InferenceEngine):
             moe_pairs, moe_hot_pairs = self._moe_pairs, self._moe_hot_pairs
             moe_absent_pairs = self._moe_absent_pairs
             kv_rows_read = self._kv_full_rows_read, self._kv_window_rows_read
+            kv_latent_rows = self._kv_latent_rows_read
+            moe_group_here = self._moe_group_tokens_here
+            moe_group_touched = self._moe_group_experts_touched
             gdn_updates = self._gdn_state_updates
             dfn = (self._dfn_slot_forwards, self._dfn_commit_forwards,
                    self._dfn_blocks, self._dfn_tokens_discarded,
@@ -6137,6 +6222,16 @@ class JaxDecodeEngine(InferenceEngine):
             # for linear layers the live slots' state updates and the bytes
             # each moves: its state and convolution rows, read and written
             "kv_full_bytes_read_total": kv_rows_read[0] * self._kv_row_nbytes(),
+            # a latent model: cached latent rows the chunks' attention read
+            # (live slots' rows x latent layers of every token step), their
+            # bytes as the pool stores a row, and the tokens x sparse layers
+            # whose kept routing groups include one held here
+            "kv_latent_rows_read_total": kv_latent_rows,
+            "kv_latent_bytes_read_total": kv_latent_rows * self._kv_row_nbytes(),
+            "moe_group_tokens_here_total": moe_group_here,
+            # held experts with at least one pair, summed over sparse layers
+            # and token steps: the expert weights the grouped matmuls read
+            "moe_group_experts_touched_total": moe_group_touched,
             "gdn_state_updates_total": gdn_updates,
             "gdn_state_bytes_total": gdn_updates * self._state_update_nbytes(),
             # a block-diffusion model: live slots x forwards of its chunks,

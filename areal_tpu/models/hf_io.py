@@ -106,6 +106,15 @@ def hf_name_to_ours(name: str) -> tuple[str, ...] | None:
             "linear_attn.A_log": ("attn", "A_log"),
             "linear_attn.norm.weight": ("attn", "norm"),
             "linear_attn.out_proj.weight": ("attn", "out_kernel"),
+            # DeepSeek-V2's latent attention (its modelling code's names as
+            # ISSUE 38's author knew them: no checkpoint here to read them
+            # from, benchmark/configs/deepseek-v2.json `assumed`)
+            "self_attn.q_a_proj.weight": ("attn", "q_a_kernel"),
+            "self_attn.q_a_layernorm.weight": ("attn", "q_a_norm"),
+            "self_attn.q_b_proj.weight": ("attn", "q_b_kernel"),
+            "self_attn.kv_a_proj_with_mqa.weight": ("attn", "kv_a_kernel"),
+            "self_attn.kv_a_layernorm.weight": ("attn", "kv_a_norm"),
+            "self_attn.kv_b_proj.weight": ("attn", "kv_b_kernel"),
             # Mixtral router
             "block_sparse_moe.gate.weight": ("mlp", "router_kernel"),
             "input_layernorm.weight": ("input_norm",),
@@ -167,6 +176,30 @@ def _gdn_group(w: np.ndarray, cfg: ModelConfig, leaf: str) -> np.ndarray:
     return np.concatenate([part.reshape(H, nk, -1) for part in parts], axis=-1).reshape(H, -1)
 
 
+def _latent_rotary_lanes(w: np.ndarray, cfg: ModelConfig, leaf: str, load: bool) -> np.ndarray:
+    """A latent model's `q_b_kernel` / `kv_a_kernel` [in, out] with the
+    rotary lanes reordered: a DeepSeek-V2 checkpoint holds them as
+    interleaved pairs (lanes 2i and 2i + 1 turn together; its modelling code
+    de-interleaves q_pe and k_pe at every call), the tree as `rotate_half`
+    pairs them (lane i with lane i + rope/2). `load`: checkpoint -> tree,
+    once; else the way back."""
+    rope = cfg.qk_rope_head_dim
+    perm = np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+    if not load:
+        perm = np.argsort(perm)
+    if leaf == "kv_a_kernel":
+        C = cfg.kv_lora_rank
+        return np.concatenate([w[:, :C], w[:, C:][:, perm]], axis=1)
+    nH, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    heads = w.reshape(w.shape[0], nH, nope + rope)
+    return np.concatenate(
+        [heads[..., :nope], heads[..., nope:][..., perm]], axis=-1
+    ).reshape(w.shape)
+
+
+_LATENT_KERNELS = ("q_a_kernel", "q_b_kernel", "kv_a_kernel", "kv_b_kernel")
+
+
 def _convert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) -> np.ndarray:
     """Torch [out, in] → our einsum layout."""
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
@@ -181,12 +214,18 @@ def _convert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) -> n
             return w.reshape(w.shape[0], w.shape[-1])
         if leaf == "out_kernel":
             return np.ascontiguousarray(w.T)
+    if leaf in _LATENT_KERNELS:
+        w = np.ascontiguousarray(w.T)
+        if leaf in ("q_b_kernel", "kv_a_kernel"):
+            w = _latent_rotary_lanes(w, cfg, leaf, load=True)
+        return w
     if leaf in ("q_kernel", "k_kernel", "v_kernel"):
         n = nH if leaf == "q_kernel" else nKV
         # (under `attn_output_gate` a query head is 2 hd wide: q, then its gate)
         return np.ascontiguousarray(w.T).reshape(H, n, -1)
     if leaf == "o_kernel":
-        return np.ascontiguousarray(w.T).reshape(nH, hd, H)
+        # (a latent model's heads come out `v_head_dim` wide, not `head_dim`)
+        return np.ascontiguousarray(w.T).reshape(nH, -1, H)
     if leaf in ("q_bias",):
         return w.reshape(nH, hd)
     if leaf in ("k_bias", "v_bias"):
@@ -211,6 +250,10 @@ def _unconvert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) ->
             return w.reshape(w.shape[0], 1, w.shape[1])
         if leaf == "out_kernel":
             return np.ascontiguousarray(w.T)
+    if leaf in _LATENT_KERNELS:
+        if leaf in ("q_b_kernel", "kv_a_kernel"):
+            w = _latent_rotary_lanes(w, cfg, leaf, load=False)
+        return np.ascontiguousarray(w.T)
     if leaf in ("q_kernel", "k_kernel", "v_kernel"):
         return np.ascontiguousarray(w.reshape(H, -1).T)
     if leaf == "o_kernel":
@@ -464,11 +507,18 @@ def ours_name_to_hf(path: tuple[str, ...], model_type: str = "qwen2") -> str:
         ("attn", "A_log"): "linear_attn.A_log",
         ("attn", "norm"): "linear_attn.norm.weight",
         ("attn", "out_kernel"): "linear_attn.out_proj.weight",
+        ("attn", "q_a_kernel"): "self_attn.q_a_proj.weight",
+        ("attn", "q_a_norm"): "self_attn.q_a_layernorm.weight",
+        ("attn", "q_b_kernel"): "self_attn.q_b_proj.weight",
+        ("attn", "kv_a_kernel"): "self_attn.kv_a_proj_with_mqa.weight",
+        ("attn", "kv_a_norm"): "self_attn.kv_a_layernorm.weight",
+        ("attn", "kv_b_kernel"): "self_attn.kv_b_proj.weight",
     }
     if model_type == "mixtral":
         leaf_table[("mlp", "router_kernel")] = "block_sparse_moe.gate.weight"
     if model_type == "exaone_moe":
         leaf_table[("mlp", "router_bias")] = "mlp.gate.e_score_correction_bias"
+    if model_type in ("exaone_moe", "deepseek_v2"):
         for proj in ("gate", "up", "down"):
             leaf_table[("mlp", f"shared_{proj}_kernel")] = (
                 f"mlp.shared_experts.{proj}_proj.weight"

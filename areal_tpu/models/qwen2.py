@@ -35,6 +35,7 @@ import contextlib
 import dataclasses
 import functools
 import json
+import math
 import os
 from dataclasses import dataclass
 
@@ -61,7 +62,7 @@ def _cstr(x: jax.Array, *logical_axes: str | None) -> jax.Array:
 MODEL_TYPES = (
     "qwen2", "qwen3", "llama", "mistral", "gemma", "gemma2", "gpt2",
     "mixtral", "qwen2_moe", "qwen3_moe", "olmoe", "exaone_moe", "qwen3_next",
-    "sdar_moe",
+    "sdar_moe", "deepseek_v2",
 )
 
 # `layer_types` entries of a mixed stack (HF's names)
@@ -87,6 +88,9 @@ class ModelConfig:
     rope_low_freq_factor: float = 1.0
     rope_high_freq_factor: float = 4.0
     rope_original_max_position: int = 8192
+    # "yarn" (NTK-by-parts with a ramp over the rotary frequencies): the
+    # rest of its numbers, (beta_fast, beta_slow, mscale, mscale_all_dim)
+    rope_yarn: tuple | None = None
     rms_norm_eps: float = 1e-6
     tie_word_embeddings: bool = False
     max_position_embeddings: int = 32768
@@ -214,6 +218,25 @@ class ModelConfig:
     # every other model.
     block_length: int | None = None
     mask_token_id: int | None = None
+    # -- DeepSeek-V2-class latent attention (MLA). `kv_lora_rank` C > 0: a
+    # token's cache is ONE row a layer, `[c_kv (C) | k_pe (qk_rope_head_dim)]`,
+    # the normed latent and one rotary head shared by every query head; keys
+    # and values are `kv_b_kernel`'s expansion of c_kv (`qk_nope_head_dim`
+    # and `v_head_dim` lanes a head), the query comes through a low-rank
+    # `q_lora_rank` bottleneck with a norm of its own. `forward` and `prefill`
+    # expand to heads; the decode step absorbs `kv_b_kernel` into the query
+    # and the output and attends over the cached rows themselves
+    # (`_latent_decode_attention`). `moe_n_group` > 1: group-limited routing,
+    # a token keeps the `moe_topk_group` best of `moe_n_group` groups of
+    # consecutive experts (a group's score its best expert's) and picks its
+    # k among those groups' experts.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # vocab chunk for the fused LM-head loss (ops/fused_xent.py): peak
     # logits transient is [tokens, loss_vocab_chunk]
     loss_vocab_chunk: int = 16384
@@ -244,11 +267,33 @@ class ModelConfig:
     @property
     def mixed(self) -> bool:
         """Layers of more than one kind: no stacked scan, two caches."""
-        return self.layer_types is not None or self.first_k_dense > 0
+        return (self.layer_types is not None or self.first_k_dense > 0
+                or self.latent)
+
+    @property
+    def latent(self) -> bool:
+        """Latent attention: one cached row a token and layer, no V side."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row(self) -> int:
+        """Lanes of a cached latent row that carry something: `[c_kv | k_pe]`."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row_lanes(self) -> int:
+        """Lanes a latent pool's row HAS: `latent_row` up to whole vregs of
+        128. A TPU stores an array's minor dimension in tiles of 128 lanes
+        whatever its logical size, and Mosaic copies whole tiles, so the pad
+        is written out (zeros) and every byte count sees it."""
+        return -(-self.latent_row // 128) * 128
 
     @property
     def rotary_dim(self) -> int:
-        """Lanes of a head that the rotary embedding turns (the first ones)."""
+        """Lanes of a head that the rotary embedding turns (the first ones;
+        a latent model's rotary part is a head of its own, `qk_rope_head_dim`)."""
+        if self.latent:
+            return self.qk_rope_head_dim
         return int(self.head_dim_ * self.partial_rotary_factor)
 
     def layer_window(self, i) -> int | None:
@@ -284,9 +329,11 @@ class ModelConfig:
     @property
     def cache_layers(self) -> dict:
         """{"full": layer indices with a paged cache, "window": those with
-        a ring, "state": those with a recurrent state a slot} of a mixed
-        stack, in layer order."""
+        a ring, "state": those with a recurrent state a slot, "latent": those
+        with one latent row a token} of a mixed stack, in layer order."""
         L = range(self.num_hidden_layers)
+        if self.latent:
+            return {"full": (), "window": (), "state": (), "latent": tuple(L)}
         state = tuple(i for i in L if self.layer_linear(i))
         return {
             "full": tuple(
@@ -374,11 +421,24 @@ class ModelConfig:
                 rope_scaling_type="linear",
                 rope_scaling_factor=rs.get("factor", 1.0),
             )
+        elif rs_type == "yarn":
+            rope_kw = dict(
+                rope_scaling_type="yarn",
+                rope_scaling_factor=float(rs.get("factor", 1.0)),
+                rope_original_max_position=int(rs.get(
+                    "original_max_position_embeddings",
+                    hf.get("max_position_embeddings", 8192),
+                )),
+                rope_yarn=(
+                    float(rs.get("beta_fast", 32)), float(rs.get("beta_slow", 1)),
+                    float(rs.get("mscale", 1.0)), float(rs.get("mscale_all_dim", 0.0)),
+                ),
+            )
         elif rs_type not in (None, "default", "mrope"):
-            # yarn/dynamic etc.: loading would silently misplace positions
+            # dynamic etc.: loading would silently misplace positions
             raise NotImplementedError(
                 f"rope_scaling type {rs_type!r} not implemented "
-                "(supported: llama3, linear)"
+                "(supported: llama3, linear, yarn)"
             )
         if model_type == "exaone_moe":
             # `rope_parameters` is this family's nesting of theta and type
@@ -493,6 +553,8 @@ class ModelConfig:
             kw.update(_exaone_moe_kw(hf))
         elif model_type == "qwen3_next":
             kw.update(_qwen3_next_kw(hf))
+        elif model_type == "deepseek_v2":
+            kw.update(_deepseek_v2_kw(hf))
         elif model_type == "gemma":
             # Gemma-1 (reference: realhf/api/from_hf/gemma.py — GeGLU MLP,
             # zero-centered RMSNorm, sqrt(H)-scaled embeddings, tied head).
@@ -554,7 +616,33 @@ class ModelConfig:
             )
         if self.rope_scaling_type == "linear":
             return ("linear", self.rope_scaling_factor)
+        if self.rope_scaling_type == "yarn":
+            return (
+                "yarn",
+                self.rope_scaling_factor,
+                self.rope_original_max_position,
+                *(self.rope_yarn or (32.0, 1.0, 1.0, 0.0)),
+            )
         return None
+
+    @property
+    def latent_softmax_scale(self) -> float:
+        """The latent attention's softmax scale: a head's q/k width to the
+        -1/2, times YaRN's `mscale(factor, mscale_all_dim)` squared where the
+        model declares that (DeepSeek-V2 folds the long-context temperature
+        into the scale, its tables' own factor being 1)."""
+        scale = float(self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.rope_scaling_type == "yarn" and self.rope_yarn and self.rope_yarn[3]:
+            m = yarn_mscale(self.rope_scaling_factor, self.rope_yarn[3])
+            scale *= m * m
+        return scale
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
 
 
 def _held_experts_kw(hf: dict) -> dict:
@@ -692,6 +780,86 @@ def _qwen3_next_kw(hf: dict) -> dict:
     )
 
 
+def _deepseek_v2_kw(hf: dict) -> dict:
+    """DeepSeek-V2 (`deepseek_v2`): latent attention (a low-rank query, one
+    cached row `[c_kv | k_pe]` a token, `kv_b_proj` expanding it to heads),
+    leading dense layers, then softmax-scored experts chosen inside the
+    `topk_group` best of `n_group` expert groups, unnormalised weights times
+    `routed_scaling_factor`, and ungated shared experts. Raises on what is
+    not served."""
+    if hf.get("moe_layer_freq", 1) != 1:
+        raise NotImplementedError(
+            f"deepseek_v2 with moe_layer_freq={hf['moe_layer_freq']} (dense "
+            "layers among the sparse ones) is not implemented"
+        )
+    if hf.get("scoring_func", "softmax") != "softmax":
+        raise NotImplementedError(
+            f"deepseek_v2 scoring_func {hf['scoring_func']!r} is not "
+            "implemented (served: softmax)"
+        )
+    method = hf.get("topk_method", "greedy")
+    if method not in ("group_limited_greedy", "greedy"):
+        raise NotImplementedError(
+            f"deepseek_v2 topk_method {method!r} is not implemented (served: "
+            "group_limited_greedy, greedy)"
+        )
+    if not hf.get("q_lora_rank") or not hf.get("kv_lora_rank"):
+        raise NotImplementedError(
+            "deepseek_v2 without q_lora_rank / kv_lora_rank (a full-rank "
+            "query projection) is not implemented"
+        )
+    if hf.get("attention_bias"):
+        raise NotImplementedError("deepseek_v2 with attention_bias is not implemented")
+    held = _held_experts_kw({**hf, "num_experts": hf["n_routed_experts"]})
+    n_group, topk_group = 1, 1
+    if method == "group_limited_greedy":
+        n_group, topk_group = int(hf.get("n_group", 1)), int(hf.get("topk_group", 1))
+    published = held["num_experts_published"]
+    if n_group < 1 or published % n_group or not 1 <= topk_group <= n_group:
+        raise NotImplementedError(
+            f"deepseek_v2 n_routed_experts={published} does not divide into "
+            f"n_group={n_group} groups (topk_group={topk_group})"
+        )
+    size = published // n_group
+    if n_group > 1 and (held["num_experts"] % size or held["expert_first"] % size):
+        raise NotImplementedError(
+            f"deepseek_v2 holds experts [{held['expert_first']}, "
+            f"{held['expert_first'] + held['num_experts']}): not whole routing "
+            f"groups of {size} (a group is what a chip of the deployment holds)"
+        )
+    if hf["num_experts_per_tok"] > topk_group * size:
+        raise NotImplementedError(
+            f"deepseek_v2 num_experts_per_tok={hf['num_experts_per_tok']} "
+            f"exceeds the {topk_group * size} experts of the kept groups"
+        )
+    nope, rope = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
+    return dict(
+        kv_lora_rank=int(hf["kv_lora_rank"]),
+        q_lora_rank=int(hf["q_lora_rank"]),
+        qk_nope_head_dim=nope,
+        qk_rope_head_dim=rope,
+        v_head_dim=int(hf["v_head_dim"]),
+        # a head's q/k width; keys and values are not cached by head
+        head_dim=nope + rope,
+        first_k_dense=int(hf.get("first_k_dense_replace", 0)),
+        **held,
+        num_experts_per_tok=int(hf["num_experts_per_tok"]),
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        shared_expert_intermediate_size=(
+            int(hf.get("n_shared_experts") or 0) * hf["moe_intermediate_size"]
+        ),
+        shared_expert_gated=False,
+        norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+        moe_scoring="softmax",
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        router_aux_loss_coef=float(hf.get("aux_loss_alpha", 0.0)),
+        moe_n_group=n_group,
+        moe_topk_group=topk_group,
+        # no uniform stack: a leading dense layer, and one pool of rows
+        scan_layers=False,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Parameter tree + logical sharding axes
 # ---------------------------------------------------------------------------
@@ -714,6 +882,25 @@ def _gdn_shapes(cfg: ModelConfig) -> dict:
     }
 
 
+def _latent_shapes(cfg: ModelConfig) -> dict:
+    """The latent attention's leaves. `q_b_kernel`'s columns are the heads in
+    order, each `[nope | rope]`; `kv_a_kernel`'s `[c_kv | k_pe]`;
+    `kv_b_kernel`'s the heads in order, each `[k_nope | v]`. The rotary lanes
+    are held as `rotate_half` pairs them, lane i with lane i + rope/2
+    (`hf_io` permutes a checkpoint's interleaved pairs at load)."""
+    H, nH = cfg.hidden_size, cfg.num_attention_heads
+    C, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    return {
+        "q_a_kernel": (H, cfg.q_lora_rank),
+        "q_a_norm": (cfg.q_lora_rank,),
+        "q_b_kernel": (cfg.q_lora_rank, nH * (cfg.qk_nope_head_dim + rope)),
+        "kv_a_kernel": (H, C + rope),
+        "kv_a_norm": (C,),
+        "kv_b_kernel": (C, nH * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "o_kernel": (nH, cfg.v_head_dim, H),
+    }
+
+
 def _layer_shapes(cfg: ModelConfig, i: int | None = None) -> dict:
     """One layer's leaves. `i` names the layer of an unstacked tree; None is
     the stacked layer of a uniform stack."""
@@ -723,7 +910,7 @@ def _layer_shapes(cfg: ModelConfig, i: int | None = None) -> dict:
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     linear = i is not None and cfg.layer_linear(i)
     shapes = {
-        "attn": _gdn_shapes(cfg) if linear else {
+        "attn": _gdn_shapes(cfg) if linear else _latent_shapes(cfg) if cfg.latent else {
             # `attn_output_gate`: each head's query lanes, then its gate's
             "q_kernel": (H, nH, 2 * hd if cfg.attn_output_gate else hd),
             "k_kernel": (H, nKV, hd),
@@ -769,7 +956,7 @@ def _layer_shapes(cfg: ModelConfig, i: int | None = None) -> dict:
         "input_norm": (H,),
         "post_attn_norm": (H,),
     }
-    if linear:
+    if linear or cfg.latent:
         return shapes
     if cfg.qkv_bias:
         shapes["attn"]["q_bias"] = (nH, hd)
@@ -808,6 +995,13 @@ _LAYER_AXES = {
         "A_log": ("norm",),
         "norm": ("norm",),
         "out_kernel": (None, "embed"),
+        # latent attention: the low-rank axes stay whole, heads split
+        "q_a_kernel": ("embed", None),
+        "q_a_norm": ("norm",),
+        "q_b_kernel": (None, "heads"),
+        "kv_a_kernel": ("embed", None),
+        "kv_a_norm": ("norm",),
+        "kv_b_kernel": (None, "heads"),
     },
     "mlp": {
         "gate_kernel": ("embed", "mlp"),
@@ -1454,6 +1648,7 @@ def rope_table(
     inv_freq = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
+    table_scale = 1.0  # (YaRN's tables may carry a factor)
     if scaling is not None and scaling[0] == "linear":
         inv_freq = inv_freq / scaling[1]
     elif scaling is not None and scaling[0] == "llama3":
@@ -1474,7 +1669,29 @@ def rope_table(
             ),
         )
         inv_freq = scaled
+    elif scaling is not None and scaling[0] == "yarn":
+        # YaRN (HF `_compute_yarn_parameters`): frequencies that turn more
+        # than `beta_fast` times over the original context are kept, those
+        # that turn fewer than `beta_slow` times are divided by `factor`, a
+        # linear ramp over the frequency index between; cos and sin carry
+        # mscale(factor, mscale) / mscale(factor, mscale_all_dim)
+        _, factor, orig_max, beta_fast, beta_slow, mscale, mscale_all = scaling
+
+        def turns_at(n_rot):  # the (fractional) frequency index turning n_rot times
+            return (head_dim * math.log(orig_max / (n_rot * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(turns_at(beta_fast)), 0)
+        high = min(math.ceil(turns_at(beta_slow)), head_dim - 1)
+        ramp = jnp.clip(
+            (jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+            / max(high - low, 1e-3), 0.0, 1.0,
+        )
+        inv_freq = inv_freq * (1.0 - ramp) + inv_freq / factor * ramp
+        table_scale = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all)
     angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    if table_scale != 1.0:
+        return jnp.cos(angles) * table_scale, jnp.sin(angles) * table_scale
     return jnp.cos(angles), jnp.sin(angles)
 
 
@@ -1567,6 +1784,19 @@ def resolve_attn_impl(cfg: ModelConfig, window=_UNIFORM) -> str:
     mixed = cfg.layer_types is not None
     if window is _UNIFORM:
         window = None if mixed else cfg.sliding_window
+    if cfg.latent:
+        # q and k are qk_nope + qk_rope lanes a head, v is v_head_dim: the
+        # Pallas flash/ring kernels take one width for all three
+        if cfg.attn_impl in ("flash", "ring"):
+            raise NotImplementedError(
+                f"attn_impl={cfg.attn_impl!r} does not support latent "
+                f"attention (q/k {cfg.qk_nope_head_dim + cfg.qk_rope_head_dim} "
+                f"wide, v {cfg.v_head_dim}); use 'chunked' (O(T) memory) or "
+                "'dense'"
+            )
+        if cfg.attn_impl != "auto":
+            return cfg.attn_impl
+        return "chunked" if jax.default_backend() == "tpu" else "dense"
     if cfg.block_length_ > 1:
         # a block-causal mask: like a window, the Pallas flash/ring kernels
         # have none, and attending causally would be silently wrong
@@ -1651,6 +1881,11 @@ def attention(
     rotates q and k); `mask` is then the mask of that layer's kind.
     `horizon`: `block_horizon` of a block-causal model."""
     window = cfg.layer_window(li)
+    if cfg.latent:
+        return _cstr(
+            latent_attention(layer_p, x, cos, sin, segment_ids, mask, cfg),
+            "tokens", "act_embed",
+        )
     with jax.named_scope("qkv"):
         q = _w_einsum("th,hnd->tnd", x, layer_p["q_kernel"], 1)
         k = _w_einsum("th,hnd->tnd", x, layer_p["k_kernel"], 1)
@@ -1725,6 +1960,156 @@ def attention(
         if cfg.attn_out_bias:
             proj = proj + layer_p["o_bias"]
     return _cstr(proj, "tokens", "act_embed")
+
+
+# -- latent attention (DeepSeek-V2's MLA) -------------------------------------
+# With x a token's normed hidden state and h a head:
+#   c_q = RMSNorm(x W_qa);  [q_nope_h | q_pe_h] = c_q W_qb;  q_pe_h = RoPE(q_pe_h)
+#   [c_kv | k_pe] = x W_kva;  c_kv = RMSNorm(c_kv);  k_pe = RoPE(k_pe)   (one head)
+#   the cached row is [c_kv | k_pe], after the norm and the rotation
+# expanded (`forward`, `prefill`):  [k_nope_h | v_h] = c_kv W_kvb,h;
+#   k_h = [k_nope_h | k_pe];  o_h = softmax_s(scale q_h . k_h(s)) v_h(s)
+# absorbed (`decode_step_paged`), equal in exact arithmetic, W_uk,h and W_uv,h
+# the two halves of W_kvb,h:  q~_h = q_nope_h W_uk,h^T;
+#   score = scale (q~_h . c_kv(s) + q_pe_h . k_pe(s));  u_h = sum_s p c_kv(s);
+#   o_h = u_h W_uv,h
+# so the decode step reads the cached rows themselves and never expands them.
+
+
+def _latent_project(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig):
+    """x [..., H] -> (q_nope [..., nH, nope], q_pe [..., nH, rope] turned,
+    row [..., C + rope]: the row to cache, `[c_kv | k_pe]` normed and turned).
+    cos/sin: [..., rope/2], leading dims as x's."""
+    nH, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    C = cfg.kv_lora_rank
+    with jax.named_scope("q_lora"):
+        c_q = _norm(
+            jnp.einsum("...h,hr->...r", x, layer_p["q_a_kernel"]),
+            layer_p["q_a_norm"], cfg,
+        )
+        q = jnp.einsum("...r,rm->...m", c_q, layer_p["q_b_kernel"])
+        q = q.reshape(*q.shape[:-1], nH, nope + cfg.qk_rope_head_dim)
+    with jax.named_scope("kv_latent"):
+        kv = jnp.einsum("...h,hr->...r", x, layer_p["kv_a_kernel"])
+        c_kv = _norm(kv[..., :C], layer_p["kv_a_norm"], cfg)
+    with jax.named_scope("rope"):
+        cos_b, sin_b = cos.astype(q.dtype), sin.astype(q.dtype)
+        q_pe = _rotated(
+            *_split_rotary(q[..., nope:], cfg.qk_rope_head_dim)[:2],
+            cos_b[..., None, :], sin_b[..., None, :], [],
+        )
+        k_pe = _rotated(
+            *_split_rotary(kv[..., C:], cfg.qk_rope_head_dim)[:2], cos_b, sin_b, []
+        )
+    return q[..., :nope], q_pe, jnp.concatenate([c_kv, k_pe], axis=-1)
+
+
+def _latent_expand(layer_p: dict, row: jax.Array, cfg: ModelConfig):
+    """Cached rows [..., C + rope] -> (k [..., nH, nope + rope], v [..., nH,
+    dv]): `kv_b_kernel`'s expansion of c_kv, the one rotary head under all."""
+    nH, nope, C = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    kvb = jnp.einsum("...c,cm->...m", row[..., :C], layer_p["kv_b_kernel"])
+    kvb = kvb.reshape(*kvb.shape[:-1], nH, nope + cfg.v_head_dim)
+    k_pe = jnp.broadcast_to(
+        row[..., None, C:], (*row.shape[:-1], nH, cfg.qk_rope_head_dim)
+    )
+    return jnp.concatenate([kvb[..., :nope], k_pe], axis=-1), kvb[..., nope:]
+
+
+def _latent_out(layer_p: dict, o: jax.Array) -> jax.Array:
+    """Heads' outputs [..., nH, dv] -> [..., H]."""
+    with jax.named_scope("out_proj"):
+        return jnp.einsum("...nd,ndh->...h", o, layer_p["o_kernel"])
+
+
+def latent_attention(layer_p: dict, x: jax.Array, cos, sin, segment_ids,
+                     mask, cfg: ModelConfig) -> jax.Array:
+    """The EXPANDED form over one packed stream [T, H]: 128 heads of keys
+    and values built from the latent rows (`forward` and its gradient)."""
+    q_nope, q_pe, row = _latent_project(layer_p, x, cos, sin, cfg)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k, v = _latent_expand(layer_p, row, cfg)
+    q = _cstr(q, "tokens", "act_heads", None)
+    k = _cstr(k, "tokens", "act_heads", None)
+    v = _cstr(v, "tokens", "act_heads", None)
+    scale = cfg.latent_softmax_scale
+    with jax.named_scope("latent_attention"):
+        if resolve_attn_impl(cfg) == "chunked":
+            from areal_tpu.ops.chunked_attention import chunked_attention
+
+            out = chunked_attention(q, k, v, segment_ids, sm_scale=scale)
+        else:
+            if mask is None:
+                mask = segment_causal_mask(segment_ids)
+            out = _latent_dense_attention(q, k, v, mask, scale)
+    return _latent_out(layer_p, _cstr(out, "tokens", "act_heads", None))
+
+
+def _latent_dense_attention(q, k, v, mask, scale: float) -> jax.Array:
+    """Expanded heads under a [T, S] mask, scores and softmax in float32."""
+    scores = jnp.einsum("tnd,snd->nts", q, k).astype(jnp.float32) * scale
+    scores = jnp.where(mask[None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("nts,snd->tnd", probs, v)
+
+
+def _latent_prefill_attention(layer_p: dict, x: jax.Array, cos, sin, mask,
+                              cfg: ModelConfig):
+    """The expanded form over ONE sequence [T, H] (bucket padding after the
+    real tokens, hidden by causality). `mask` [T, T], or None above
+    `PREFILL_DENSE_MAX` tokens, where queries and keys both go a block at a
+    time and a block of queries stops at its own keys. Returns (out [T, H],
+    row [T, C + rope]: the rows to cache)."""
+    q_nope, q_pe, row = _latent_project(layer_p, x, cos, sin, cfg)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k, v = _latent_expand(layer_p, row, cfg)
+    scale = cfg.latent_softmax_scale
+    with jax.named_scope("latent_attention"):
+        if mask is None:
+            from areal_tpu.ops.chunked_attention import causal_blocked_attention
+
+            out = causal_blocked_attention(q, k, v, sm_scale=scale)
+        else:
+            out = _latent_dense_attention(q, k, v, mask, scale)
+    return _latent_out(layer_p, out), row
+
+
+def _latent_pool_row(row: jax.Array, lanes: int) -> jax.Array:
+    """A row to cache at the width the pool stores it (`latent_row_lanes`)."""
+    pad = lanes - row.shape[-1]
+    if not pad:
+        return row
+    return jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, pad)])
+
+
+def _latent_decode_attention(layer_p: dict, x: jax.Array, cos, sin, pool,
+                             ci, place, cfg: ModelConfig, attn_impl: str):
+    """The ABSORBED form for R single-token queries: each slot's new row into
+    the latent pool at `(ci, dest_block, dest_off)`, every head's query
+    carried into the row's space, attention over the cached rows in place
+    (`ops/paged_attention_latent.py`), the result carried out through the
+    value half of `kv_b_kernel`. `place` = (table, dest_block, dest_off,
+    valid, live). Returns (out [R, H], pool)."""
+    from areal_tpu.ops.paged_attention_latent import paged_attention_latent
+
+    table, blk, off, seen, live = place
+    nH, nope, C = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q_nope, q_pe, row = _latent_project(layer_p, x, cos, sin, cfg)
+    D = pool.shape[-1]
+    pool = _write_pool_rows(pool, _latent_pool_row(row, D), ci, blk, off)
+    w_kvb = layer_p["kv_b_kernel"].reshape(C, nH, nope + cfg.v_head_dim)
+    with jax.named_scope("absorb_q"):
+        q_lat = jnp.einsum("rnd,cnd->rnc", q_nope, w_kvb[..., :nope])
+        q_row = jnp.concatenate([q_lat, q_pe], axis=-1)
+        q_row = jnp.pad(q_row, ((0, 0), (0, 0), (0, D - q_row.shape[-1])))
+    with jax.named_scope("latent_attention"):
+        u = paged_attention_latent(
+            q_row, pool, table, seen, ci, dv=C,
+            sm_scale=cfg.latent_softmax_scale, impl=attn_impl, live=live,
+        )
+    with jax.named_scope("absorb_out"):
+        o = jnp.einsum("rnc,cnd->rnd", u, w_kvb[..., nope:])
+    return _latent_out(layer_p, o), pool
 
 
 def _with_lora(layer_p, leaf, y, x, cfg):
@@ -1914,14 +2299,29 @@ def moe_mlp(
             probs = jax.nn.sigmoid(router_logits)  # [T, E_pub] float32
         else:
             probs = jax.nn.softmax(router_logits, axis=-1)
+        choice = probs
+        if cfg.moe_n_group > 1:
+            # group-limited choice: a group of consecutive experts scores as
+            # its best expert does, the `moe_topk_group` best groups are
+            # kept, and the k are picked among their experts alone (the
+            # others' scores read 0, below any softmax score)
+            with jax.named_scope("group_route"):
+                G = cfg.moe_n_group
+                _, kept = jax.lax.top_k(
+                    probs.reshape(-1, G, E_pub // G).max(axis=-1), cfg.moe_topk_group
+                )
+                kept_groups = jax.nn.one_hot(kept, G, dtype=bool).any(axis=1)  # [T, G]
+                choice = jnp.where(
+                    jnp.repeat(kept_groups, E_pub // G, axis=1), probs, 0.0
+                )
         if cfg.moe_router_bias:
             # the bias enters the choice of the k experts, not their weights
             _, topk_idx = jax.lax.top_k(
-                probs + layer_p["router_bias"].astype(jnp.float32), K
+                choice + layer_p["router_bias"].astype(jnp.float32), K
             )
             gate_vals = jnp.take_along_axis(probs, topk_idx, axis=-1)
         else:
-            gate_vals, topk_idx = jax.lax.top_k(probs, K)  # [T, K]
+            gate_vals, topk_idx = jax.lax.top_k(choice, K)  # [T, K]
         if cfg.norm_topk_prob:
             denom = jnp.sum(gate_vals, axis=-1, keepdims=True)
             if cfg.moe_scoring == "sigmoid":
@@ -1990,12 +2390,23 @@ def moe_mlp(
     aux = E_pub * jnp.sum(frac * mean_prob)
     if with_load:
         per_expert = assign.sum(axis=(0, 1)).astype(jnp.int32)  # valid pairs
+        grouped = []
+        if cfg.moe_n_group > 1:
+            # valid tokens whose kept groups include one held here, and the
+            # held experts with at least one pair (a grouped matmul reads
+            # nothing of an empty group: the weights this call had to read)
+            size = E_pub // cfg.moe_n_group
+            here_tok = kept_groups[:, first // size:(first + E) // size].any(axis=1)
+            if valid is not None:
+                here_tok = here_tok & valid
+            held = jax.lax.dynamic_slice(per_expert, (first,), (E,))
+            grouped = [here_tok.sum(dtype=jnp.int32), (held > 0).sum(dtype=jnp.int32)]
         if partial:
             here = jax.lax.dynamic_slice(per_expert, (first,), (E,))
             return y, aux, jnp.stack(
-                [here.sum(), here.max(), per_expert.sum() - here.sum()]
+                [here.sum(), here.max(), per_expert.sum() - here.sum(), *grouped]
             )
-        return y, aux, jnp.stack([per_expert.sum(), per_expert.max()])
+        return y, aux, jnp.stack([per_expert.sum(), per_expert.max(), *grouped])
     return y, aux
 
 
@@ -2749,7 +3160,8 @@ _PAGED_KERNELS = {"full": "paged_attention", "window": "paged_attention_window"}
 
 def _moe_load_len(cfg: ModelConfig) -> int:
     """Entries of `moe_mlp`'s load vector."""
-    return 3 if cfg.num_experts_published_ != cfg.num_experts else 2
+    return ((3 if cfg.num_experts_published_ != cfg.num_experts else 2)
+            + (2 if cfg.moe_n_group > 1 else 0))
 
 
 def decode_load_len(cfg: ModelConfig) -> int:
@@ -2757,7 +3169,8 @@ def decode_load_len(cfg: ModelConfig) -> int:
     `moe_mlp`'s, for a mixed stack the cached rows read by the full and by
     the window layers, and where it has linear layers their state updates."""
     return (_moe_load_len(cfg) + (2 if cfg.mixed else 0)
-            + (1 if cfg.cache_layers["state"] else 0))
+            + (1 if cfg.cache_layers["state"] else 0)
+            + (1 if cfg.latent else 0))
 
 
 def _split_output_gate(q: jax.Array, cfg: ModelConfig) -> tuple:
@@ -2862,7 +3275,10 @@ def prefill(
     prefix_len: jax.Array | None = None,
 ) -> tuple[jax.Array | None, jax.Array, jax.Array]:
     """Causal forward over ONE sequence [T], returning (logits [T, V],
-    k_cache [L, T, nKV, hd], v_cache [L, T, nKV, hd]). A model with linear
+    k_cache [L, T, nKV, hd], v_cache [L, T, nKV, hd]). A latent model's
+    k_cache is its rows to cache at the pool's lanes, [L, T, 1,
+    `latent_row_lanes`], its v_cache empty
+    ([L, T, 1, 0]). A model with linear
     layers returns the rows of its attention layers alone (in layer order)
     and a fourth: its linear layers' state at the last real token, {"S":
     [n_lin, Hv, dk, dv] float32, "conv": [n_lin, K-1, C]}.
@@ -2947,6 +3363,11 @@ def prefill(
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     group = nH // nKV
 
+    if cfg.latent and with_prefix:
+        raise NotImplementedError(
+            "a suffix prefill over cached latent rows: the expanded form "
+            "would have to expand the prefix's rows again"
+        )
     has_state = bool(cfg.cache_layers["state"])
     if has_state:
         if with_prefix:
@@ -2974,6 +3395,18 @@ def prefill(
             return _attn_out_mlp(
                 layer_p, x, out, cfg, valid, li=li, projected=True
             ), cache
+        if cfg.latent:
+            with jax.named_scope("attn"):
+                out, row = _latent_prefill_attention(
+                    layer_p["attn"], h, cos, sin,
+                    None if chunked else masks[window], cfg,
+                )
+            # the rows to cache as the pool stores them, as one kv head;
+            # nothing on the V side
+            row = _latent_pool_row(row, cfg.latent_row_lanes)
+            return _attn_out_mlp(
+                layer_p, x, out, cfg, valid, li=li, projected=True
+            ), (row[:, None, :], row[:, None, :0])
         with jax.named_scope("attn"):
             q, k, v, *gate = _project_qkv(
                 layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
@@ -3172,7 +3605,8 @@ def _mixed_cache(cfg: ModelConfig, k_pool, positions, slots, active, full,
         for kind, layers in cfg.cache_layers.items()
         for j, li in enumerate(layers)
     }
-    where = {"full": full}
+    # (a latent model's one pool is addressed as the paged pool is)
+    where = {"latent" if "latent" in k_pool else "full": full}
     if "window" in k_pool:
         bsz = k_pool["window"].shape[2]
         window = cfg.sliding_window
@@ -3323,15 +3757,23 @@ def decode_step_paged(
     def layer(carry, layer_p, li):
         x, kp, vp, load = carry
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
-        linear = cfg.layer_linear(li)
+        # (a mixer whose output is projected already: linear, latent)
+        projected = cfg.layer_linear(li) or cfg.latent
         with jax.named_scope("attn"):
-            if linear:
+            if cfg.layer_linear(li):
                 # no rows to write or read: the slot's state, updated in place
                 attn_out, state = gated_delta_step(
                     layer_p["attn"], h, kp["state"], index[li][1], active, cfg,
                     attn_impl,
                 )
                 kp = {**kp, "state": state}
+            elif cfg.latent:
+                # the absorbed form: one row written, the rows read in place
+                attn_out, pool = _latent_decode_attention(
+                    layer_p["attn"], h, cos, sin, kp["latent"], index[li][1],
+                    where["latent"], cfg, attn_impl,
+                )
+                kp = {**kp, "latent": pool}
             else:
                 q, k_new, v_new, *gate = _project_qkv(
                     layer_p["attn"], h, cos, sin, cfg, cfg.layer_rope(li)
@@ -3353,11 +3795,11 @@ def decode_step_paged(
                 attn_out = _gate_attn_out(attn_out, gate)
         if moe_load:
             x, layer_load = _attn_out_mlp(
-                layer_p, x, attn_out, cfg, active, True, li, projected=linear
+                layer_p, x, attn_out, cfg, active, True, li, projected=projected
             )
             return x, kp, vp, load + layer_load
         return _attn_out_mlp(
-            layer_p, x, attn_out, cfg, active, li=li, projected=linear
+            layer_p, x, attn_out, cfg, active, li=li, projected=projected
         ), kp, vp, None
 
     # the load rides in the carry as None (no leaf) unless asked for
@@ -3381,6 +3823,10 @@ def decode_step_paged(
                 # live slots' state updates, a linear layer each
                 updates = live.sum() * len(cfg.cache_layers["state"])
                 load = jnp.concatenate([load, updates[None].astype(load.dtype)])
+            if cfg.latent:
+                # cached latent rows read: live slots' rows x latent layers
+                rows = (where["latent"][3] & live).sum() * cfg.num_hidden_layers
+                load = jnp.concatenate([load, rows[None].astype(load.dtype)])
         return logits, k_pool, v_pool, load
     return logits, k_pool, v_pool
 
@@ -3411,6 +3857,11 @@ def _qlen_step_paged(
         raise NotImplementedError(
             "a verify step over linear layers: a rejected draft would have "
             "to roll each slot's state back"
+        )
+    if cfg.latent:
+        raise NotImplementedError(
+            "a verify step over a latent pool: the absorbed attention scores "
+            "one query a slot (ops/paged_attention_latent.py)"
         )
     R, W = tokens.shape
     bsz = jax.tree.leaves(k_pool)[0].shape[2]

@@ -105,7 +105,7 @@ def chunked_attention(
     q_last = q_idx if q_horizon is None else q_horizon
 
     k_chunks = k.reshape(n_chunks, cs, nKV, hd)
-    v_chunks = v.reshape(n_chunks, cs, nKV, hd)
+    v_chunks = v.reshape(n_chunks, cs, nKV, v.shape[-1])
     seg_chunks = seg_k_full.reshape(n_chunks, cs)
     off_chunks = jnp.arange(n_chunks, dtype=jnp.int32) * cs
 
@@ -142,7 +142,8 @@ def chunked_attention(
     init = (
         jnp.full((nKV, group, T), -jnp.inf, jnp.float32),
         jnp.zeros((nKV, group, T), jnp.float32),
-        jnp.zeros((nKV, group, T, hd), jnp.float32),
+        # (v may be narrower than q and k: a latent model's heads)
+        jnp.zeros((nKV, group, T, v.shape[-1]), jnp.float32),
     )
     (m, denom, acc), _ = jax.lax.scan(
         jax.checkpoint(body, prevent_cse=False),
@@ -151,4 +152,71 @@ def chunked_attention(
     )
     out = acc / jnp.maximum(denom, 1e-30)[..., None]
     # [nKV, group, T, hd] -> [T, nH, hd]
-    return out.transpose(2, 0, 1, 3).reshape(T, nH, hd).astype(q.dtype)
+    return out.transpose(2, 0, 1, 3).reshape(T, nH, v.shape[-1]).astype(q.dtype)
+
+
+def causal_blocked_attention(
+    q: jax.Array,  # [T, nH, dq]
+    k: jax.Array,  # [T, nH, dq]
+    v: jax.Array,  # [T, nH, dv]
+    sm_scale: float,
+    q_block: int = 512,
+    kv_chunk: int = 1024,
+) -> jax.Array:
+    """Causal attention over ONE sequence with as many key heads as query
+    heads, queries AND keys a block at a time: a forward-only long prefill
+    at many heads (128 heads x 16,384 queries against one 512-key chunk are
+    4.3 GB of float32 scores in `chunked_attention`; a block here is
+    `nH * q_block * kv_chunk`). A block of queries walks the key chunks up
+    to its own last row and no further (a `fori_loop` of that length), so
+    the triangle above the diagonal costs nothing. Rows past the real
+    tokens (bucket padding) lie after every real query. Returns
+    [T, nH, dv] in q's dtype."""
+    T, nH, _ = q.shape
+    dv = v.shape[-1]
+    qb = int(min(q_block, T))
+    kc = int(min(kv_chunk, T))
+    pad_q, pad_k = (-T) % qb, (-T) % kc
+    if pad_q:
+        q = jnp.pad(q, ((0, pad_q), (0, 0), (0, 0)))
+    if pad_k:
+        k = jnp.pad(k, ((0, pad_k), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, pad_k), (0, 0), (0, 0)))
+    n_q = (T + pad_q) // qb
+
+    def q_block_out(_, i):
+        q0 = i * qb
+        qi = jax.lax.dynamic_slice_in_dim(q, q0, qb, axis=0) * sm_scale
+        q_idx = q0 + jnp.arange(qb)
+
+        def key_chunk(j, carry):
+            m, denom, acc = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * kc, kc, axis=0)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * kc, kc, axis=0)
+            s = jnp.einsum("tnd,snd->nts", qi, kj, preferred_element_type=jnp.float32)
+            seen = q_idx[:, None] >= (j * kc + jnp.arange(kc))[None, :]
+            s = jnp.where(seen[None], s, -1e30)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.where(seen[None], jnp.exp(s - m_new[..., None]), 0.0)
+            rescale = jnp.exp(m - m_new)
+            denom = denom * rescale + p.sum(axis=-1)
+            acc = acc * rescale[..., None] + jnp.einsum(
+                "nts,snd->ntd", p.astype(v.dtype), vj,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, denom, acc
+
+        init = (
+            jnp.full((nH, qb), -1e30, jnp.float32),
+            jnp.zeros((nH, qb), jnp.float32),
+            jnp.zeros((nH, qb, dv), jnp.float32),
+        )
+        # key chunks 0 .. the one holding this block's last row
+        _, denom, acc = jax.lax.fori_loop(
+            0, (q0 + qb + kc - 1) // kc, key_chunk, init
+        )
+        out = acc / jnp.maximum(denom, 1e-30)[..., None]
+        return None, out.transpose(1, 0, 2).astype(q.dtype)  # [qb, nH, dv]
+
+    _, out = jax.lax.scan(q_block_out, None, jnp.arange(n_q))
+    return out.reshape(n_q * qb, nH, dv)[:T]

@@ -291,6 +291,15 @@ def test_decode_idle_is_open_while_nothing_is_queued(engine):
     eng = engine[0]
     with perf_tracer.recording() as rec:
         _generate(eng)
+        # the response returns before the scheduler's pass ends: wait for the
+        # thread to have gone idle (on busy cores that takes more than a
+        # moment), then let the idle period run
+        deadline = time.monotonic() + 30.0
+        while time.monotonic() < deadline:
+            open_now = [s for s in rec.snapshot() if s["open"]]
+            if [s["name"] for s in open_now] == ["decode/idle"]:
+                break
+            time.sleep(0.01)
         time.sleep(0.1)
         snap = rec.snapshot()
         open_now = [s for s in snap if s["open"]]

@@ -97,7 +97,7 @@ def programs(cpu_devices):
                 input_ids=[1, 5, 9, 13, 2],
                 gconfig=GenerationHyperparameters(greedy=True, max_new_tokens=6)),
                 timeout=300)
-            _drive_the_loop(eng, params)
+            _drive_the_loop(eng, params, rec)
             # the programs this request did not need are made, not run
             eng._get_verify_fn(False, 1, 3)
             eng._get_suffix_prefill_fn(64, 64, 1)
@@ -157,7 +157,7 @@ class _Loader:
             yield [{"prompt": [3, 7, 11, 4]}, {"prompt": [2, 6, 10, 8]}]
 
 
-def _drive_the_loop(eng, params):
+def _drive_the_loop(eng, params, rec):
     """What the loop's spans need: a batch through the gate (one episode at a
     time, so one waits), a pause held through an in-memory push, and a wait
     for traffic."""
@@ -167,6 +167,12 @@ def _drive_the_loop(eng, params):
 
     eng.prepare_batch(_Loader(), workflow=_OneRequest())
     eng.pause()
+    # the push of a tiny tree is over before a scheduler thread on busy cores
+    # has looked at the flag: hold the pause until the thread is in it
+    deadline = time.monotonic() + 30.0
+    while time.monotonic() < deadline and not any(
+            s["name"] == "decode/paused" for s in rec.snapshot()):
+        time.sleep(0.005)
     eng.update_weights_from_distributed(WeightUpdateMeta(type="memory"), params, TINY)
     eng.set_version(1)
     eng.resume()
@@ -601,6 +607,49 @@ def test_paged_kernels_are_named_apart_at_kexaones_head_shape(one_chip, kind, la
     assert ("%paged_attention_window" in hlo) == (kind == "window")
     assert ("%paged_attention." in hlo or "%paged_attention " in hlo) == (kind == "full")
     assert _one_paged_kernel(hlo, R).startswith(_PAGED_KERNELS[kind])
+
+
+def test_latent_kernel_is_named_at_deepseek_v2s_widths(one_chip):
+    """128 heads against ONE shared 576-wide row stored at 640 lanes, 512
+    summed (DeepSeek-V2): one Mosaic call named `%paged_attention_latent`
+    over a grid of the slots, PR 33's work list as its scalar-prefetch
+    operands, the cell's pool read in place (no copy beside it)."""
+    from areal_tpu.ops.paged_attention_latent import paged_attention_latent
+
+    R, nH, D, dv, bsz, nb, L = 64, 128, 640, 512, 128, 128, 5
+    pool = ((L, 786432 // bsz + 1, bsz, D), jnp.bfloat16)
+
+    def step(q, kp, bt, valid, li, lo, hi):
+        with jax.named_scope("layer"):
+            return paged_attention_latent(q, kp, bt, valid, li, dv=dv, sm_scale=0.11472,
+                                          impl="pallas", interpret=False, live=(lo, hi))
+
+    hlo = _compile(
+        step, one_chip, ((R, nH, D), jnp.bfloat16), pool, ((R, nb), jnp.int32),
+        ((R, nb * bsz), jnp.bool_), ((), jnp.int32), ((R,), jnp.int32), ((R,), jnp.int32))
+    assert "tpu_custom_call" in hlo and "%layer" not in hlo
+    assert _one_paged_kernel(hlo, R).startswith("paged_attention_latent")
+    # the pool is an operand of the call as it is stored: no copy of its 5 GB
+    big = [ln for ln in hlo.splitlines() if f"bf16[{L},{786432 // bsz + 1},{bsz},{D}]" in ln
+           and " copy(" in ln]
+    assert not big, big
+
+
+def test_a_latent_row_of_576_lanes_is_refused_by_mosaic(one_chip):
+    """Why the pool stores 640 lanes: a TPU tiles the minor dimension in 128
+    lanes whatever its logical size (the 576-lane pool IS 640 wide in HBM),
+    and Mosaic copies whole tiles."""
+    from areal_tpu.ops.paged_attention_latent import paged_attention_latent
+
+    R, nH, D, bsz, nb = 8, 128, 576, 128, 4
+
+    def step(q, kp, bt, valid):
+        return paged_attention_latent(q, kp, bt, valid, 0, dv=512, sm_scale=0.1,
+                                      impl="pallas", interpret=False)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(step, one_chip, ((R, nH, D), jnp.bfloat16), ((1, 33, bsz, D), jnp.bfloat16),
+                 ((R, nb), jnp.int32), ((R, nb * bsz), jnp.bool_))
 
 
 def test_gdn_step_kernel_is_named_at_qwen3_nexts_state_shape(one_chip):

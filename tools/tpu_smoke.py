@@ -383,6 +383,56 @@ def paged_block_case(nH=32, nKV=4, hd=128, B=4, R=16, nb=4, L=3, layer=2):
     return err < 0.03 and bool(jnp.all(jnp.isfinite(out))), f"maxerr={err:.4f}"
 
 
+def latent_case(mix, nH=128, C=512, rope=64, R=16, nb=10, L=5, layer=3):
+    """The latent decode kernel (`ops/paged_attention_latent.py`) at
+    DeepSeek-V2's widths: 128 heads against ONE shared row of 512 + 64 lanes
+    stored at 640, the first 512 summed, over a five-layer pool read at a
+    traced layer index; against the same arithmetic in `jax.numpy` at
+    float32 on the gathered rows. `mix`: "ragged" (slots of 1 to `nb`
+    columns, every third not active with a table that still names blocks:
+    zeros there), "deep" (every slot at full depth). `us_per_live_page` is
+    from the host's clock, wrapper and work list included."""
+    from areal_tpu.ops.paged_attention_latent import paged_attention_latent
+
+    D = -(-(C + rope) // 128) * 128
+    n_blocks = R * nb + 1
+    keys = jax.random.split(jax.random.PRNGKey(9), 2)
+    lanes = jnp.arange(D) < C + rope  # the pad lanes hold zeros, as the engine writes them
+    pool = jax.random.normal(keys[0], (L, n_blocks, PAGE, D), jnp.bfloat16) * lanes
+    q = (jax.random.normal(keys[1], (R, nH, D), jnp.bfloat16) * lanes).astype(jnp.bfloat16)
+    bt = jnp.arange(1, n_blocks, dtype=jnp.int32).reshape(R, nb)
+    span, r = nb * PAGE, np.arange(R)
+    if mix == "deep":
+        length, active = np.full(R, span), np.ones(R, bool)
+    else:
+        length = np.where(r == 5, span, (17 + 61 * r) * nb // 10 % span + 1)
+        active = r % 3 != 1
+    valid = jnp.arange(span)[None, :] < jnp.asarray(length)[:, None]
+    active = jnp.asarray(active)
+    scale = 0.11472
+    kernel = jax.jit(lambda q, pool, valid: paged_attention_latent(
+        q, pool, bt, valid, jnp.int32(layer), dv=C, sm_scale=scale, impl="pallas",
+        interpret=False, live=live_block_range(valid, PAGE, active)))
+    out = kernel(q, pool, valid)
+
+    def plain(q, pool, valid):
+        with jax.default_matmul_precision("highest"):
+            rows = pool[layer][bt].reshape(R, span, D).astype(jnp.float32)
+            s = jnp.einsum("rnd,rsd->rns", q.astype(jnp.float32), rows) * scale
+            p = jax.nn.softmax(jnp.where(valid[:, None, :], s, -jnp.inf), axis=-1)
+            return jnp.einsum("rns,rsd->rnd", p, rows[..., :C])
+
+    ref = jax.jit(plain)(q, pool, valid)
+    err = _maxerr(out, ref, active)
+    zeros = not bool(jnp.any(out[~active]))
+    ok = err < 0.03 and zeros and bool(jnp.all(jnp.isfinite(out)))
+    live_lo, live_hi = live_block_range(valid, PAGE, active)
+    pages = int((live_hi - live_lo).sum())
+    ms = _ms_per_call(kernel, q, pool, valid)
+    return ok, (f"maxerr={err:.4f} inactive_zero={zeros} ms_per_call={ms:.3f} "
+                f"us_per_live_page={1e3 * ms / pages:.3f} ({pages} pages)")
+
+
 def gdn_step_case(n=6, layer=4, R=64, Hv=32, dk=128, dv=128, dead_every=7):
     """The Gated DeltaNet decode step (`ops/gdn_step.py`) at Qwen3-Next's
     state shape: six linear layers' float32 states in one pool, the kernel
@@ -655,6 +705,17 @@ def cases():
         "moe_mlp exact top-8 of 128 (ragged_dot) T=512 2048x768",
         "default for block-diffusion MoE models",
         lambda: moe_case(512, M=768, E=128),
+    ))
+    # DeepSeek-V2: the absorbed attention's read of the latent pool (128 heads
+    # against one 576-wide row at 640 lanes), and a routing group's share of
+    # a layer: 20 held of 160 experts of 1,536
+    for mix in ("ragged", "deep"):
+        out.append((f"paged_attention_latent bf16 128 heads x (512 + 64), {mix} page={PAGE}",
+                    "default for latent-attention models", lambda mix=mix: latent_case(mix)))
+    out.append((
+        "moe_mlp top-6 of 160, 20 held (ragged_dot) T=64 5120x1536",
+        "default for MoE models with a share of the experts held",
+        lambda: moe_case(64, H=5120, M=1536, E=20, K=6, published=160),
     ))
     for k_dims, out_dims in (
         ((HIDDEN,), (N_HEADS, HEAD_DIM)),  # q
