@@ -643,6 +643,10 @@ class JaxDecodeEngine(InferenceEngine):
         # or a slot that has none), the live columns (_count_block_columns)
         self._paged_cols_live = 0
         self._paged_cols_visited = 0
+        # the groups of columns the kernel's loop takes for them, and the
+        # columns those score (groups x the call's group size)
+        self._paged_groups_walked = 0
+        self._paged_cols_scored = 0
         # sparse layers' grouped matmuls dispatched (token steps x sparse
         # layers), and those whose pair rows `grouped_matmul_rows` laid out
         # for a finer row tile (_count_grouped_matmuls)
@@ -999,6 +1003,8 @@ class JaxDecodeEngine(InferenceEngine):
             self._gdn_state_updates = 0
             self._paged_cols_live = 0
             self._paged_cols_visited = 0
+            self._paged_groups_walked = 0
+            self._paged_cols_scored = 0
             self._gmm_steps = 0
             self._gmm_small_tile_steps = 0
             self._spec_hist = np.zeros(
@@ -1226,6 +1232,7 @@ class JaxDecodeEngine(InferenceEngine):
             self._thread = None
         with self._metrics_lock:
             live, visited = self._paged_cols_live, self._paged_cols_visited
+            groups, scored = self._paged_groups_walked, self._paged_cols_scored
             gmm, gmm_small = self._gmm_steps, self._gmm_small_tile_steps
             chunks = self._chunks_dispatched
             dfn_forwards, dfn_tokens = self._dfn_slot_forwards, self._gen_token_count
@@ -1239,7 +1246,9 @@ class JaxDecodeEngine(InferenceEngine):
         if visited:
             logger.info(
                 f"paged kernel: {live} of {visited} steps a live block column "
-                f"({100.0 * live / visited:.1f}%) over {chunks} chunks"
+                f"({100.0 * live / visited:.1f}%) over {chunks} chunks, walked "
+                f"in {groups} groups ({groups / max(chunks, 1):.1f} a call) that "
+                f"score {scored} columns (fill {100.0 * live / max(scored, 1):.1f}%)"
             )
         if gmm:
             logger.info(
@@ -4082,7 +4091,7 @@ class JaxDecodeEngine(InferenceEngine):
         while self._inflight:
             self._consume_chunk(self._inflight.popleft())
 
-    def _count_block_columns(self, active: np.ndarray, nb: int) -> None:
+    def _count_block_columns(self, active: np.ndarray, nb: int, W: int = 1) -> None:
         """`paged_block_columns_{live,visited}_total`: what the paged kernel
         walks for the chunk just dispatched (the paged pool's table; a mixed
         stack's ring is two columns a slot, both live), at the chunk's last
@@ -4092,7 +4101,17 @@ class JaxDecodeEngine(InferenceEngine):
         kernel's grid was. `ops/paged_attention.live_block_range` on the
         host, from the projected lengths: an active slot has the columns up
         to its last query's, less those wholly before a uniform stack's
-        window."""
+        window. `paged_block_groups_walked_total` and
+        `paged_block_columns_scored_total`: the loop iterations the kernel
+        takes for those columns at the group its shapes give a chunk of `W`
+        queries a slot (`group_pages`; the latent kernel's constant), and
+        groups x group size, so live / scored is how full the score matmuls
+        are."""
+        # (imported here as the model imports them: a process that never
+        # dispatches a chunk never imports Pallas)
+        from areal_tpu.ops.paged_attention import pool_group_pages
+        from areal_tpu.ops.paged_attention_latent import PAGES_PER_GROUP
+
         bsz = self._alloc.block_size
         last = self._slot_lengths[active].astype(np.int64) - 1
         live = np.minimum(last // bsz + 1, nb)
@@ -4100,9 +4119,19 @@ class JaxDecodeEngine(InferenceEngine):
         if window is not None and self._ring is None:
             live -= np.maximum(last - window + 1, 0) // bsz
         columns = int(live.sum())
+        pool = self._k_cache
+        if isinstance(pool, dict) and "latent" in pool:
+            pages = PAGES_PER_GROUP
+        else:
+            pages = pool_group_pages(
+                pool["full"] if isinstance(pool, dict) else pool, W, nb
+            )
+        groups = int((-(-live // pages)).sum())
         with self._metrics_lock:
             self._paged_cols_live += columns
             self._paged_cols_visited += columns + active.size - live.size
+            self._paged_groups_walked += groups
+            self._paged_cols_scored += groups * pages
 
     def _count_grouped_matmuls(self, steps: int, tokens: int) -> None:
         """`moe_grouped_matmul_{,small_tile_}steps_total` for the chunk just
@@ -4321,7 +4350,7 @@ class JaxDecodeEngine(InferenceEngine):
             # absolute end as for normal chunks
             self._slot_lengths[active] += spec_w
             self._cache_written(active)
-            self._count_block_columns(active, nb)
+            self._count_block_columns(active, nb, spec_w)
             self._count_grouped_matmuls(1, R * spec_w)
             with self._metrics_lock:
                 self._chunks_dispatched += 1
@@ -4446,7 +4475,7 @@ class JaxDecodeEngine(InferenceEngine):
         # n_chunk rows; _apply_chunk takes back what it did not
         self._slot_lengths[active] += n_chunk
         self._cache_written(active)
-        self._count_block_columns(active, nb)
+        self._count_block_columns(active, nb, self.model_config.block_length_)
         self._count_grouped_matmuls(
             self._diffusion_forwards(), R * self.model_config.block_length_
         )
@@ -6119,7 +6148,8 @@ class JaxDecodeEngine(InferenceEngine):
             dfn = (self._dfn_slot_forwards, self._dfn_commit_forwards,
                    self._dfn_blocks, self._dfn_tokens_discarded,
                    self._kv_block_rows_read)
-            paged_cols = self._paged_cols_live, self._paged_cols_visited
+            paged_cols = (self._paged_cols_live, self._paged_cols_visited,
+                          self._paged_groups_walked, self._paged_cols_scored)
             gmm_steps = self._gmm_steps, self._gmm_small_tile_steps
             table_uploads = self._table_uploads
             spec_hist = self._spec_hist.copy()
@@ -6247,6 +6277,11 @@ class JaxDecodeEngine(InferenceEngine):
             # paged kernel takes (those, and one a slot with none)
             "paged_block_columns_live_total": paged_cols[0],
             "paged_block_columns_visited_total": paged_cols[1],
+            # the loop iterations the kernel takes for the live columns (a
+            # group of them an iteration), and groups x the group's size:
+            # live / scored is how full its score matmuls are
+            "paged_block_groups_walked_total": paged_cols[2],
+            "paged_block_columns_scored_total": paged_cols[3],
             # sparse-layer token steps dispatched, and those whose pair rows
             # were laid out for the grouped matmul's finer row tile
             "moe_grouped_matmul_steps_total": gmm_steps[0],

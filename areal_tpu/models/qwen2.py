@@ -3598,8 +3598,8 @@ def _mixed_cache(cfg: ModelConfig, k_pool, positions, slots, active, full,
     dest_off, valid, live)). `full` is the paged pool's first four as a
     uniform stack computes them; the ring's are built here from the flat [N]
     `positions` / `slots` / `active` (N = R, or R*W in a verify, whose
-    `valid` is [R, W, cells] like `full`'s); `live_of(valid)` gives each
-    kind's range of live block columns (`_live_columns`)."""
+    `valid` is [R, W, cells] like `full`'s); `live_of(valid, pool)` gives
+    each kind's range of live block columns (`_live_columns`)."""
     index = {
         li: (kind, j)
         for kind, layers in cfg.cache_layers.items()
@@ -3621,25 +3621,23 @@ def _mixed_cache(cfg: ModelConfig, k_pool, positions, slots, active, full,
         where["window"] = (
             table, blk, off, seen.reshape(*full[3].shape[:-1], pages * bsz)
         )
-    return index, {kind: (*w, live_of(w[3])) for kind, w in where.items()}
+    return index, {
+        kind: (*w, live_of(w[3], k_pool[kind])) for kind, w in where.items()
+    }
 
 
-def _live_columns(valid, active, bsz: int, attn_impl: str):
-    """The kernel read's work list: each slot's range of block columns that
-    hold a row `valid` lets it attend (`ops/paged_attention.live_block_range`)
-    and the chain that walks the ranges slot after slot (`slot_schedule`).
-    Taken once a token step, outside the layer loop. None for the XLA read,
-    which gathers every column and keeps its program."""
-    from areal_tpu.ops.paged_attention import (
-        live_block_range,
-        resolve_impl,
-        slot_schedule,
-    )
+def _live_columns(valid, pool, active, attn_impl: str):
+    """The kernel read's work list over `pool` (a kind's K pool): each slot's
+    range of block columns that hold a row `valid` lets it attend and the
+    chain that walks the ranges slot after slot, in the groups of columns the
+    kernel takes there (`ops/paged_attention.work_list`). Taken once a token
+    step, outside the layer loop. None for the XLA read, which gathers every
+    column and keeps its program."""
+    from areal_tpu.ops.paged_attention import resolve_impl, work_list
 
     if resolve_impl(attn_impl) != "pallas":
         return None
-    live = live_block_range(valid, bsz, active)
-    return (*live, *slot_schedule(*live))
+    return work_list(valid, pool, active)
 
 
 def _mixed_attention(read, q, kp, vp, k_new, v_new, place, attn_impl):
@@ -3743,7 +3741,7 @@ def decode_step_paged(
         dest_off = jnp.where(active, dest_off, 0)
 
     live_of = functools.partial(
-        _live_columns, active=active, bsz=bsz, attn_impl=attn_impl
+        _live_columns, active=active, attn_impl=attn_impl
     )
     if mixed:
         index, where = _mixed_cache(
@@ -3751,7 +3749,7 @@ def decode_step_paged(
             active, (block_tables, dest_block, dest_off, valid), live_of,
         )
     else:
-        live = live_of(valid)
+        live = live_of(valid, k_pool)
 
     @jax.named_scope("layer")
     def layer(carry, layer_p, li):
@@ -3917,7 +3915,7 @@ def _qlen_step_paged(
     )
 
     live_of = functools.partial(
-        _live_columns, active=active, bsz=bsz, attn_impl=attn_impl
+        _live_columns, active=active, attn_impl=attn_impl
     )
     if mixed:
         # the W rows of a slot are all written before any is read, so the
@@ -3934,7 +3932,7 @@ def _qlen_step_paged(
             (block_tables, dest_block_f, dest_off_f, valid), live_of,
         )
     else:
-        live = live_of(valid)
+        live = live_of(valid, k_pool)
 
     @jax.named_scope("layer")
     def layer(carry, layer_p, li):

@@ -17,27 +17,32 @@ Two implementations behind one signature, selected like `attn_impl`:
   grid step loops over the block columns its slot HAS, the live range
   `[lo, hi)` (`live_block_range`: the first and one past the last column
   whose mask holds a valid row; `lo == hi == 0` for a slot that is not
-  active). The pools stay in HBM as they are stored and the kernel copies
-  the pages it scores itself: the block table, the layer index, `lo`, `hi`
-  and the walk's chain (`slot_schedule`: how many live columns lie before
-  each slot, and which slot is the next to have one) are scalar-prefetch
-  operands, so an iteration reads `(layer, bt[r, c])` and DMAs exactly that
-  pool block HBM->VMEM into one of two page buffers while the page before
-  is scored; a slot's last column starts the next live slot's first page,
-  so the copies stay ahead across slots too. Attention reads KV *through
-  the table*, in the layout the pool is stored in
-  (tests/test_pool_in_place.py holds the traced programs to that). One
-  iteration takes the block's whole contiguous `(bsz, nKV*hd)` slab (every
-  kv head) and scores all query heads against it with block-diagonal
-  queries; online-softmax partials (max, sum, acc) carry across a slot's
-  columns in scratch. A column outside the range is never named: not
-  fetched, not scored, no step taken for it (the `(R, nb)` grid this
-  replaced still paid a fifth to a third of a live step for each). A
-  column with no valid row adds exactly nothing to the online softmax, so
-  a live slot's output is equal to the bit to a walk over every column; a
-  slot with no live column writes zeros. The loop's length is read on the
-  device from the slot's own range, so no program is keyed by how ragged
-  a batch is.
+  active), a GROUP of them an iteration (`group_pages`: as many pages as
+  keep an iteration's copies near a MiB, from the call's static shapes;
+  eight at a 256-lane row, one at 2,048 lanes and for a verify). The pools
+  stay in HBM as they are stored and the kernel copies the pages it scores
+  itself: the block table, the layer index, `lo`, `hi` and the walk's chain
+  (`slot_schedule`: how many groups lie before each slot, and which slot is
+  the next to have one; `work_list` gives all four) are scalar-prefetch
+  operands, so an iteration reads `(layer, bt[r, c])` for each column of
+  its group and DMAs exactly those pool blocks HBM->VMEM, each by its own
+  copy and semaphore, into one of two group buffers while the group before
+  is scored; a slot's last group starts the next live slot's first, so the
+  copies stay ahead across slots too. Attention reads KV *through the
+  table*, in the layout the pool is stored in (tests/test_pool_in_place.py
+  holds the traced programs to that). One iteration takes each block's
+  whole contiguous `(bsz, nKV*hd)` slab (every kv head) and scores all
+  query heads against the group's rows with block-diagonal queries in one
+  matmul; online-softmax partials (max, sum, acc) carry across a slot's
+  groups in scratch, updated once a group. A column outside the range is
+  never named: not fetched, no step taken for it (the `(R, nb)` grid this
+  replaced still paid a fifth to a third of a live step for each); a short
+  last group's missing pages are masked. A column with no valid row adds
+  exactly nothing to the online softmax; at one page a group a live slot's
+  output is equal to the bit to a walk over every column, at more it is the
+  same sum regrouped (a bf16 rounding apart); a slot with no live column
+  writes zeros. The loop's length is read on the device from the slot's own
+  range, so no program is keyed by how ragged a batch is.
 - `"xla"` (CPU / tests): gathers the slot's `nb` blocks per step and
   runs plain einsums over them (scores and softmax in float32). The
   committed stream goldens (tests/fixtures/) were recorded through this
@@ -156,20 +161,73 @@ def live_block_range(valid, block_size: int, active=None):
     return lo, hi
 
 
-def slot_schedule(lo, hi):
+def slot_schedule(lo, hi, pages: int = 1):
     """(start, nxt), two [R] int32 vectors that chain the slots' live ranges
-    into one walk: `start[r]` is the number of live columns of the slots
-    before `r` (so column `j` of slot `r` is the walk's `start[r] + j`-th, and
-    its parity picks the page buffer), `nxt[r]` the next slot after `r` that
+    into one walk of GROUPS of `pages` columns (`group_pages`; a slot's last
+    group may be short): `start[r]` is the number of groups of the slots
+    before `r` (so group `g` of slot `r` is the walk's `start[r] + g`-th, and
+    its parity picks the group buffer), `nxt[r]` the next slot after `r` that
     has a live column, `R` if none. With `live_block_range`'s (lo, hi) this
-    is the kernel's whole work list; taken with it, once a token step."""
+    is the kernel's whole work list; taken with it, once a token step
+    (`work_list`)."""
     R = lo.shape[0]
     count = (hi - lo).astype(jnp.int32)
+    if pages > 1:
+        count = (count + (pages - 1)) // pages
     start = jnp.cumsum(count) - count
     slot = jnp.where(count > 0, jnp.arange(R, dtype=jnp.int32), R)
     after = jax.lax.cummin(slot, reverse=True)
     nxt = jnp.concatenate([after[1:], jnp.full((1,), R, jnp.int32)])
     return start.astype(jnp.int32), nxt
+
+
+# Pool bytes (K and V pages together) a loop iteration of the kernel should
+# move before a larger group stops paying. An iteration costs about 0.35 us
+# beyond its pages' copies and matmuls on the v5e (the loop's own latencies,
+# the softmax's reductions, the accumulator's rescale); a page pair of 128 KiB
+# (D 256, bf16) is copied in 0.18 us, one of 1 MiB (D 2,048) in 1.5 us, so the
+# first gains 43% from a group of eight and the second nothing from any
+# (PR 41's kernel-alone table, PERF.md section 6; bench_artifacts/pr42/
+# kernel_groups.py measures it again).
+GROUP_BYTES = 1 << 20
+MAX_GROUP_PAGES = 8
+
+
+def group_pages(bsz: int, D: int, itemsize: int, W: int, nb: int) -> int:
+    """Live block columns a loop iteration of `_paged_kernel` scores together:
+    arithmetic on the call's static shapes, nothing else. The largest power
+    of two, at most `MAX_GROUP_PAGES`, whose pages in both pools stay within
+    `GROUP_BYTES` (so the two group buffers a pool, 2 MiB at most, and the
+    float32 copies of a scored group, `4 / itemsize` times the group's bytes,
+    fit VMEM with room), clipped to the table's `nb` columns (a ring of two
+    pages, a 256-token bucket: no empty page is scored). One for a verify or
+    block step (`W > 1`): its `W` queries share an iteration's overheads
+    already, and no cell runs one to measure more. At one page the kernel is
+    the walk a column an iteration, instruction for instruction."""
+    if W > 1:
+        return 1
+    pages = GROUP_BYTES // (2 * bsz * D * itemsize)
+    pages = 1 << max(pages, 1).bit_length() - 1  # a power of two, at least 1
+    return max(min(pages, MAX_GROUP_PAGES, nb), 1)
+
+
+def pool_group_pages(k_pool, W: int, nb: int) -> int:
+    """`group_pages` of a K pool as the kernel is handed it (an int8 pool's
+    data array: the scale strips are a sixty-fourth of its bytes)."""
+    data, _ = split_pool(k_pool)
+    return group_pages(data.shape[2], data.shape[3], data.dtype.itemsize, W, nb)
+
+
+def work_list(valid, k_pool, active=None):
+    """(lo, hi, start, nxt): the kernel's whole work list under `valid`
+    ([R, nb*bsz], or [R, W, nb*bsz] for a verify) over `k_pool`'s pages, the
+    chain counting the groups the kernel will take there. Once a token step,
+    outside the layer loop."""
+    bsz = split_pool(k_pool)[0].shape[2]
+    W = 1 if valid.ndim == 2 else valid.shape[1]
+    live = live_block_range(valid, bsz, active)
+    pages = pool_group_pages(k_pool, W, valid.shape[-1] // bsz)
+    return (*live, *slot_schedule(*live, pages))
 
 
 def _paged_kernel(
@@ -184,81 +242,129 @@ def _paged_kernel(
     *refs,  # [sel], k, [k scales], v, [v scales] (HBM), out, scratch
     sm_scale: float,
     quant: bool,
+    pages: int,
 ):
     """One grid step = one slot: a loop over the slot's live block columns
-    `lo[r] .. hi[r] - 1`, one pool block (ALL kv heads) an iteration, copied
-    HBM->VMEM by the kernel itself into one of two page buffers while the
-    page before it is scored. The walk runs on across slots: the last column
-    of a slot starts the copy of the next live slot's first page, so a page's
-    latency hides behind a page's arithmetic everywhere but at the walk's
-    first column. A slot with no live column copies and scores nothing and
-    writes zeros. The block is the contiguous (bsz, nKV*hd) slab the pool
-    stores, so every tile is lane-dense whatever the head count. Query row
-    i carries head i's query in its kv head's hd lanes and zeros elsewhere,
-    so `q @ k.T` is exactly the per-head score; `p @ v` is exact on the head's own lanes (the
-    caller reads only those). Int8 pools: the per-(row, kv head) scale
-    factors out of the hd contraction, so it multiplies the SCORES (K) and
-    the PROBS (V) — the int8 tile feeds the MXU straight after the copy."""
+    `lo[r] .. hi[r] - 1`, a GROUP of `pages` of them an iteration
+    (`group_pages`), each a whole pool block (ALL kv heads) copied HBM->VMEM
+    by the kernel itself, by its own copy and semaphore (a loop over the
+    pages the group has: the kernel's text is a page's whatever the group,
+    so a set-up traces and lowers no more for it), into one of two group
+    buffers while the group before it is scored: one mask, one score matmul
+    `[rows, pages*bsz]`, one online-softmax update and one weighted sum a
+    group, so the loop's own latencies, the reductions and the accumulator's
+    rescale are paid a group and not a page. The walk runs on across slots:
+    the last group of a slot starts the copies of the next live slot's first,
+    so a group's latency hides behind a group's arithmetic everywhere but at
+    the walk's first. A slot's last group may be short: its missing pages
+    are not copied, and their rows (whatever an earlier group left in the
+    buffer: finite, the walk's first group zeroes the buffers) are masked
+    out. A slot with no live column copies and scores nothing and writes
+    zeros. The block is the contiguous (bsz, nKV*hd) slab the pool stores, so
+    every tile is lane-dense whatever the head count. Query row i carries
+    head i's query in its kv head's hd lanes and zeros elsewhere, so
+    `q @ k.T` is exactly the per-head score; `p @ v` is exact on the head's
+    own lanes (the caller reads only those). Int8 pools: the per-(row, kv
+    head) scale factors out of the hd contraction, so it multiplies the
+    SCORES (K) and the PROBS (V) — the int8 tile feeds the MXU straight
+    after the copy. At `pages == 1` this is the walk a column an iteration,
+    operation for operation."""
     if quant:
         # sel: (nKV, W*nHp, 1) f32 one-hot, query row -> its kv head
         (sel_ref, k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref, acc_ref, m_ref, l_ref,
          k_buf, v_buf, ks_buf, vs_buf, sems) = refs
-        pools = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf), (vs_hbm, vs_buf))
+        # (pool, its group buffers, whether a page's rows are the lanes there)
+        pools = ((k_hbm, k_buf, False), (v_hbm, v_buf, False),
+                 (ks_hbm, ks_buf, True), (vs_hbm, vs_buf, True))
     else:
         k_hbm, v_hbm, o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sems = refs
-        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+        pools = ((k_hbm, k_buf, False), (v_hbm, v_buf, False))
     r = pl.program_id(0)
     R = pl.num_programs(0)
     rows = q_ref.shape[1]
-    W, bsz = mask_ref.shape[2], mask_ref.shape[3]
+    nb, W, bsz = mask_ref.shape[1:]
     layer = layer_ref[0]
     lo = lo_ref[r]
     n = hi_ref[r] - lo
+    if pages > 1:
+        n = (n + (pages - 1)) // pages  # the slot's groups
     start = start_ref[r]
     nxt = nxt_ref[r]
 
-    def copies(slot, col, buf):
+    def page_copies(slot, col, buf, i=None):
         # the page walk: block `col` of `slot` comes straight from the pool
         # page (layer, table entry) names, as the pool stores it (scale
-        # strips walk the same entry)
+        # strips walk the same entry), into page `i` of group buffer `buf`
         blk = bt_ref[slot, col]
-        return [
-            pltpu.make_async_copy(hbm.at[layer, blk], vmem.at[buf], sems.at[i, buf])
-            for i, (hbm, vmem) in enumerate(pools)
-        ]
+        out = []
+        for p, (hbm, vmem, lanes) in enumerate(pools):
+            if pages == 1:
+                dst, sem = vmem.at[buf], sems.at[p, buf]
+            else:
+                rows_i = pl.ds(pl.multiple_of(i * bsz, bsz), bsz)
+                dst = vmem.at[buf, :, rows_i] if lanes else vmem.at[buf, rows_i]
+                sem = sems.at[p, buf, i]
+            out.append(pltpu.make_async_copy(hbm.at[layer, blk], dst, sem))
+        return out
 
-    # the walk's first page: no slot before this one started it
+    def group_copies(slot, col, buf, go):
+        """Start (`go`) or await the copies of the group of `slot` whose
+        first column is `col`: the pages the slot has from there, `pages` at
+        most, in a loop of their own, so that the kernel's text (what a
+        set-up traces and lowers a chunk program) is a page's whatever the
+        group."""
+
+        def page(i, carry):
+            for c in page_copies(slot, col + i, buf, i):
+                c.start() if go else c.wait()
+            return carry
+
+        if pages == 1:
+            for c in page_copies(slot, col, buf):
+                c.start() if go else c.wait()
+        else:
+            jax.lax.fori_loop(
+                0, jnp.minimum(hi_ref[slot] - col, pages), page, None
+            )
+
+    # the walk's first group: no slot before this one started it
     @pl.when((n > 0) & (start == 0))
-    def _first_page():
-        for c in copies(r, lo, 0):
-            c.start()
+    def _first_group():
+        if pages > 1:
+            for _, vmem, _ in pools:
+                vmem[...] = jnp.zeros_like(vmem)
+        group_copies(r, lo, 0, True)
 
     m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[:] = jnp.zeros_like(l_ref)
     acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def head_rows(sc):
-        # (nKV, bsz) block scales -> [rows, bsz]: row i gets its kv head's
+        # (nKV, pages*bsz) group scales -> [rows, pages*bsz]: row i gets its
+        # kv head's
         return sum(
             sel_ref[h] * sc[h : h + 1, :] for h in range(sc.shape[0])
         )
 
-    def live_column(j, carry):
-        buf = (start + j) % 2
-        more = j + 1 < n
+    def live_group(g, carry):
+        buf = (start + g) % 2
+        more = g + 1 < n
 
-        # the page after this one, the next live slot's first after the last
+        def first():  # the group's first column
+            return lo + (g if pages == 1 else g * pages)
+
+        # the group after this one, the next live slot's first after the last
         @pl.when(more | (nxt < R))
-        def _next_page():
+        def _next_group():
             slot = jnp.where(more, r, jnp.minimum(nxt, R - 1))
-            col = jnp.where(more, lo + j + 1, lo_ref[slot])
-            for c in copies(slot, col, 1 - buf):
-                c.start()
+            group_copies(
+                slot, jnp.where(more, first() + pages, lo_ref[slot]), 1 - buf,
+                True,
+            )
 
-        for c in copies(r, lo + j, buf):
-            c.wait()
+        group_copies(r, first(), buf, False)
         q = q_ref[0].astype(jnp.float32)  # [rows, D]
-        k = k_buf[buf].astype(jnp.float32)  # [bsz, D]
+        k = k_buf[buf].astype(jnp.float32)  # [pages*bsz, D]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -266,10 +372,19 @@ def _paged_kernel(
         if quant:
             s = s * head_rows(ks_buf[buf])
         # per-query causal horizon: mask row w applies to that query's heads
-        m2 = jnp.broadcast_to(
-            mask_ref[0, lo + j][:, None, :], (W, rows // W, bsz)
-        ).reshape(rows, bsz)
-        s = jnp.where(m2 != 0, s, _NEG_INF)
+        col = first()
+        held = mask_ref[0, col] if pages == 1 else jnp.concatenate(
+            [mask_ref[0, jnp.minimum(col + i, nb - 1)] for i in range(pages)],
+            axis=1,
+        )  # [W, pages*bsz]
+        seen = jnp.broadcast_to(
+            held[:, None, :], (W, rows // W, pages * bsz)
+        ).reshape(rows, pages * bsz) != 0
+        if pages > 1:
+            # a short group's missing pages hold no row of this slot
+            lane = jax.lax.broadcasted_iota(jnp.int32, seen.shape, 1)
+            seen &= lane < (hi_ref[r] - col) * bsz
+        s = jnp.where(seen, s, _NEG_INF)
 
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -287,7 +402,7 @@ def _paged_kernel(
         )
         return carry
 
-    jax.lax.fori_loop(0, n, live_column, None)
+    jax.lax.fori_loop(0, n, live_group, None)
 
     l = l_ref[:]
     safe_l = jnp.where(l > 0.0, l, 1.0)
@@ -295,11 +410,15 @@ def _paged_kernel(
 
 
 def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
-                  interpret, kernel_name="paged_attention", live=None):
+                  interpret, kernel_name="paged_attention", live=None,
+                  pages=None):
     """q [R, W, nH, hd], valid [R, W, nb*bsz] -> [R, W, nH, hd]. Decoding is
     the W == 1 case of the speculative verify. `live` = (lo, hi) as
     `live_block_range` gives them, with or without `slot_schedule`'s
-    (start, nxt) after them; read from `valid` here when not given."""
+    (start, nxt) over this call's groups after them (`work_list` gives all
+    four); read from `valid` here when not given. `pages` is
+    `group_pages`'s of the call's shapes unless a test or a measurement
+    names another."""
     (k_pool, k_scales), (v_pool, v_scales) = split_pool(k_pool), split_pool(v_pool)
     R, W, nH, hd = q.shape
     _, _, bsz, D = k_pool.shape
@@ -324,10 +443,12 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
         qp[:, :, :, None, :] * jnp.asarray(onehot, q.dtype)[:, :, None]
     ).reshape(R, rows, D)
     mask = valid.astype(jnp.int32).reshape(R, W, nb, bsz).swapaxes(1, 2)
+    if pages is None:
+        pages = group_pages(bsz, D, k_pool.dtype.itemsize, W, nb)
     if live is None:
         live = live_block_range(valid, bsz)
     if len(live) == 2:
-        live = (*live, *slot_schedule(*live))
+        live = (*live, *slot_schedule(*live, pages))
 
     # the pools stay where they are: the kernel copies the pages it scores
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
@@ -339,8 +460,9 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
         pltpu.VMEM((rows, D), jnp.float32),
         pltpu.VMEM((rows, 1), jnp.float32),
         pltpu.VMEM((rows, 1), jnp.float32),
-        pltpu.VMEM((2, bsz, D), k_pool.dtype),
-        pltpu.VMEM((2, bsz, D), v_pool.dtype),
+        # two group buffers a pool: one is scored while the other fills
+        pltpu.VMEM((2, pages * bsz, D), k_pool.dtype),
+        pltpu.VMEM((2, pages * bsz, D), v_pool.dtype),
     ]
     if quant:
         sel = jnp.asarray(np.tile(onehot, (W, 1)).T[:, :, None])
@@ -350,13 +472,16 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
         ]
         operands = (sel, k_pool, k_scales, v_pool, v_scales)
         scratch += [
-            pltpu.VMEM((2, nKV, bsz), k_scales.dtype),
-            pltpu.VMEM((2, nKV, bsz), v_scales.dtype),
+            pltpu.VMEM((2, nKV, pages * bsz), k_scales.dtype),
+            pltpu.VMEM((2, nKV, pages * bsz), v_scales.dtype),
         ]
     else:
         in_specs += [pool_spec, pool_spec]
         operands = (k_pool, v_pool)
-    scratch.append(pltpu.SemaphoreType.DMA((4 if quant else 2, 2)))
+    # a semaphore a pool, buffer and page of the group
+    scratch.append(pltpu.SemaphoreType.DMA(
+        (4 if quant else 2, 2) + ((pages,) if pages > 1 else ())
+    ))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(R,),
@@ -365,7 +490,9 @@ def _paged_pallas(q, k_pool, v_pool, block_table, valid, layer, sm_scale,
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, sm_scale=sm_scale, quant=quant),
+        functools.partial(
+            _paged_kernel, sm_scale=sm_scale, quant=quant, pages=pages
+        ),
         name=kernel_name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, rows, D), q.dtype),
@@ -394,6 +521,7 @@ def paged_attention_qlen(
     interpret: bool | None = None,
     kernel_name: str = "paged_attention",
     live=None,  # (lo, hi[, start, nxt]): the work list; read from `valid` if None
+    pages: int | None = None,  # a test's or a measurement's group; else the rule's
 ) -> jax.Array:
     """q_len>1 decode attention against the block table (speculative
     verify chunks): slot r's W queries (positions base..base+W-1) attend
@@ -421,7 +549,7 @@ def paged_attention_qlen(
         return verify_attention(q, kc, vc, valid, sm_scale=sm_scale)
     return _paged_pallas(
         q, k_pool, v_pool, block_table, valid, layer, sm_scale, interpret,
-        kernel_name, live,
+        kernel_name, live, pages,
     )
 
 
@@ -443,6 +571,7 @@ def paged_attention(
     interpret: bool | None = None,
     kernel_name: str = "paged_attention",
     live=None,  # (lo, hi[, start, nxt]): the work list; read from `valid` if None
+    pages: int | None = None,  # a test's or a measurement's group; else the rule's
 ) -> jax.Array:
     """Decode attention of R single-token queries over paged KV.
 
@@ -468,5 +597,5 @@ def paged_attention(
         )
     return _paged_pallas(
         q[:, None], k_pool, v_pool, block_table, valid[:, None], layer,
-        sm_scale, interpret, kernel_name, live,
+        sm_scale, interpret, kernel_name, live, pages,
     )[:, 0]

@@ -53,11 +53,16 @@ from areal_tpu.models.qwen2 import (
 )
 from areal_tpu.ops.kv_quant import quantize_kv, split_pool
 from areal_tpu.ops.paged_attention import (
+    GROUP_BYTES,
+    MAX_GROUP_PAGES,
+    group_pages,
     live_block_range,
     paged_attention,
     paged_attention_qlen,
+    pool_group_pages,
     resolve_impl,
     slot_schedule,
+    work_list,
 )
 
 TINY = ModelConfig(
@@ -239,7 +244,10 @@ def _ragged_slots(rng, W=1, int8=False, window=None, dtype=jnp.bfloat16):
 
 def _read(q, kp, vp, bt, valid, live, **kw):
     """The Pallas kernel (interpret mode) under the range `live`, through
-    the entry point its width calls for."""
+    the entry point its width calls for: a column an iteration (the walk the
+    bit-for-bit cases below were written against) unless `pages` names a
+    group, None for the one the shapes give."""
+    kw.setdefault("pages", 1)
     if q.shape[1] == 1:
         return paged_attention(
             q[:, 0], kp, vp, bt, valid[:, 0], jnp.int32(_LAYER), impl="pallas",
@@ -358,8 +366,9 @@ def test_live_block_range_against_brute_force(cpu_devices, with_active, W):
         assert (int(lo[2]), int(hi[2])) == (3, 4)
 
 
+@pytest.mark.parametrize("pages", [1, 4])
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_dead_columns_are_never_read(cpu_devices, int8):
+def test_dead_columns_are_never_read(cpu_devices, int8, pages):
     """Every page no live column names, the null block among them, holds
     NaN (an int8 pool: NaN scales). A kernel that scored such a page would
     return NaN (0 x NaN); the live slots read back finite and equal to the
@@ -382,8 +391,8 @@ def test_dead_columns_are_never_read(cpu_devices, int8):
             return data, scales.at[:, dead].set(jnp.nan)
         return pool.at[:, dead].set(jnp.nan)
 
-    clean = _read(q, kp, vp, bt, valid, live)
-    out = _read(q, poison(kp), poison(vp), bt, valid, live)
+    clean = _read(q, kp, vp, bt, valid, live, pages=pages)
+    out = _read(q, poison(kp), poison(vp), bt, valid, live, pages=pages)
     on = np.asarray(active)
     assert np.isfinite(np.asarray(out.astype(jnp.float32))).all()
     np.testing.assert_array_equal(_bits(out)[on], _bits(clean)[on])
@@ -575,11 +584,14 @@ def test_pair_walk_equals_the_grid_walk_on_random_batches(cpu_devices, seed):
     np.testing.assert_array_equal(_bits(out), _bits(ref))
 
 
+@pytest.mark.parametrize("pages", [1, 2, 4, 8])
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_pair_walk_never_reads_a_page_outside_the_pairs(cpu_devices, int8):
+def test_pair_walk_never_reads_a_page_outside_the_pairs(cpu_devices, int8, pages):
     """Empty slots inside the chain with tables that name blocks, every page
     no live pair names NaN: the copy a slot's last column starts is the next
-    LIVE slot's first page, never an empty slot's."""
+    LIVE slot's first page, never an empty slot's; a short last group copies
+    the pages the slot has and no other, and what its buffer holds beyond
+    them is masked out."""
     cols = np.array([0, 2, 0, 0, 1, 3, 0])
     lo_cols = np.array([0, 1, 0, 0, 0, 2, 0])
     q, kp, vp, bt, valid, active = _columns_batch(
@@ -595,10 +607,207 @@ def test_pair_walk_never_reads_a_page_outside_the_pairs(cpu_devices, int8):
             return pool[0], pool[1].at[:, dead].set(jnp.nan)
         return pool.at[:, dead].set(jnp.nan)
 
-    clean = _read(q, kp, vp, bt, valid, live)
-    out = _read(q, poison(kp), poison(vp), bt, valid, live)
+    clean = _read(q, kp, vp, bt, valid, live, pages=pages)
+    out = _read(q, poison(kp), poison(vp), bt, valid, live, pages=pages)
     assert np.isfinite(np.asarray(out.astype(jnp.float32))).all()
     np.testing.assert_array_equal(_bits(out), _bits(clean))
+
+
+# ---------------------------------------------------------------------------
+# a GROUP of live columns a loop iteration (`group_pages`): one score matmul,
+# one softmax update and one weighted sum a group. The softmax is associative
+# but not bit-stable under regrouping, so a group is held to the walk a column
+# an iteration within a bf16 rounding or two, and to the XLA read as that is.
+# ---------------------------------------------------------------------------
+
+
+def _grouped_batch(case, rng, int8):
+    """(q, kp, vp, bt, valid, active) of a named batch."""
+    if case == "ragged":  # an empty slot, one column of ten, all ten, mid-page ends
+        return _ragged_slots(rng, int8=int8)
+    if case == "window":  # `lo > 0`: the groups start inside the table
+        return _ragged_slots(rng, int8=int8, window=2 * _RB + 3)
+    return _columns_batch(rng, np.array(_CHAINS[case]), int8=int8)
+
+
+@pytest.mark.parametrize("pages", [2, 4, 8])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize(
+    "case", ["ragged", "window", "one_deep_slot", "single_columns"])
+def test_grouped_walk_is_the_walk_a_column(cpu_devices, case, int8, pages):
+    """Ragged ranges, a slot with no live column, short last groups (10 = 8 + 2,
+    5 = 4 + 1), `lo > 0`, a table narrower than the group (`single_columns`:
+    two columns), both pools."""
+    q, kp, vp, bt, valid, active = _grouped_batch(case, np.random.default_rng(31), int8)
+    live = live_block_range(valid, _RB, active)
+    one = _read(q, kp, vp, bt, valid, live, pages=1)
+    out = _read(q, kp, vp, bt, valid, live, pages=pages)
+    on = np.asarray(active)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
+    assert np.isfinite(f32(out)).all() and not f32(out)[~on].any()
+    np.testing.assert_allclose(f32(out)[on], f32(one)[on], atol=2e-2, rtol=2e-2)
+    xla = paged_attention_qlen(q, kp, vp, bt, valid, jnp.int32(_LAYER), impl="xla")
+    np.testing.assert_allclose(f32(out)[on], f32(xla)[on], atol=3e-2, rtol=3e-2)
+    if pages == 4:
+        # told every column is live the groups are the same ones (`lo == 0`)
+        # or others (a window): the result is the mask's either way
+        every = _read(q, kp, vp, bt, valid, _all_live(*bt.shape), pages=pages)
+        np.testing.assert_allclose(f32(every)[on], f32(one)[on], atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("pages", [2, 8])
+def test_grouped_ring_reads_both_pages_in_one_group(cpu_devices, pages):
+    """A mixed stack's window ring: two columns a slot, one group (a group of
+    eight is clipped to the table's two by the rule, and scores six masked
+    pages when it is named anyway)."""
+    from areal_tpu.models.qwen2 import _PAGED_KERNELS, _ring_valid, ring_pages
+
+    rng = np.random.default_rng(13)
+    bsz = window = 16
+    ring = ring_pages(window, bsz)
+    R, nH, nKV, hd = 4, 8, 2, 16
+    kp, vp = _random_pool(rng, 1 + R * ring, bsz, nKV, hd, jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((R, 1, nH, hd)), jnp.bfloat16)
+    bt = 1 + jnp.arange(R * ring, dtype=jnp.int32).reshape(R, ring)
+    valid = _ring_valid(jnp.asarray([3, 40, 77, 21]), window, bsz, ring)[:, None]
+    active = jnp.asarray([True, True, False, True])
+    live = live_block_range(valid, bsz, active)
+    kw = dict(kernel_name=_PAGED_KERNELS["window"])
+    one = _read(q, kp, vp, bt, valid, live, pages=1, **kw)
+    out = _read(q, kp, vp, bt, valid, live, pages=pages, **kw)
+    on = np.asarray(active)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
+    np.testing.assert_allclose(f32(out)[on], f32(one)[on], atol=2e-2, rtol=2e-2)
+    assert not f32(out)[~on].any()
+    assert pool_group_pages(kp, 1, ring) == 2
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_rules_group_is_what_the_op_takes(cpu_devices, int8):
+    """Left to itself the op takes `group_pages` of its shapes (eight here,
+    over ten columns), with the chain it builds or `work_list`'s."""
+    q, kp, vp, bt, valid, active = _ragged_slots(np.random.default_rng(33), int8=int8)
+    assert pool_group_pages(kp, 1, _RNB) == 8
+    live = live_block_range(valid, _RB, active)
+    named = _read(q, kp, vp, bt, valid, live, pages=8)
+    own = _read(q, kp, vp, bt, valid, live, pages=None)
+    np.testing.assert_array_equal(_bits(own), _bits(named))
+    chained = _read(q, kp, vp, bt, valid, work_list(valid[:, 0], kp, active), pages=None)
+    np.testing.assert_array_equal(_bits(chained), _bits(named))
+    assert len(work_list(valid, kp, active)) == 4
+
+
+# bsz, D, itemsize, W, nb -> pages: the five rollout cells that run the kernel
+# (their deepest bucket), the buckets and tables narrower than a group, and
+# what takes one page by the rule
+_RULE = {
+    "rollout-1.5b-gsm8k": ((128, 256, 2, 1, 10), 8),
+    "rollout-1.5b-gsm8k 256-token bucket": ((128, 256, 2, 1, 2), 2),
+    "rollout-1.5b-gsm8k 512-token bucket": ((128, 256, 2, 1, 4), 4),
+    "rollout-1.5b-gsm8k int8 pool": ((128, 256, 1, 1, 10), 8),
+    "rollout-qwen3next-mixedlen": ((128, 512, 2, 1, 64), 4),
+    "rollout-kexaone-mixedlen full": ((128, 1024, 2, 1, 64), 2),
+    "rollout-kexaone-mixedlen ring": ((128, 1024, 2, 1, 2), 2),
+    "rollout-olmoe-gsm8k": ((128, 2048, 2, 1, 10), 1),
+    "rollout-sdar-gsm8k block of 4": ((128, 512, 2, 4, 10), 1),
+    "verify chunk of 5": ((128, 256, 2, 5, 10), 1),
+    "a float32 pool": ((128, 256, 4, 1, 10), 4),
+    "0.5B": ((128, 128, 2, 1, 10), 8),
+    "one column": ((128, 256, 2, 1, 1), 1),
+    "a row wider than the budget": ((128, 8192, 2, 1, 10), 1),
+}
+
+
+@pytest.mark.parametrize("cell", list(_RULE))
+def test_group_rule_is_arithmetic_on_the_shapes(cell):
+    """`group_pages` is a pure function of five ints: it takes and returns
+    Python ints, traces nothing, compiles nothing and reads no device (no jax
+    event fires while it runs), and its group's pages in both pools stay
+    within `GROUP_BYTES` and `MAX_GROUP_PAGES` and the table."""
+    import jax.monitoring
+
+    shape, pages = _RULE[cell]
+    events = []
+    listener = lambda name, *a, **k: events.append(name)  # noqa: E731
+    jax.monitoring.register_event_listener(listener)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        with jax.transfer_guard("disallow"):
+            got = group_pages(*shape)
+            bsz, D, itemsize, W, nb = shape
+            pool = jax.ShapeDtypeStruct((2, 9, bsz, D), {1: jnp.int8, 2: jnp.bfloat16,
+                                                       4: jnp.float32}[itemsize])
+            assert pool_group_pages(pool, W, nb) == got
+    finally:
+        from jax._src import monitoring
+
+        monitoring.unregister_event_listener(listener)
+        monitoring.unregister_event_duration_listener(listener)
+    assert type(got) is int and got == pages and not events, (got, events)
+    assert 1 <= got <= min(MAX_GROUP_PAGES, nb)
+    assert got == 1 or got * 2 * bsz * D * itemsize <= GROUP_BYTES
+
+
+def _kernel_primitives(q, kp, vp, bt, valid, pages):
+    """{primitive name: count} over the paged kernel's whole jaxpr (loops and
+    conditionals walked) at a group of `pages`."""
+    from collections import Counter
+
+    from jax._src import core
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    traced = jax.make_jaxpr(lambda *a: paged_attention(
+        *a, jnp.int32(_LAYER), impl="pallas", interpret=True, pages=pages)
+    )(q[:, 0], kp, vp, bt, valid[:, 0])
+    (call,) = [e for e in traced.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return Counter(walk(call.params["jaxpr"]))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_kernels_text_is_a_pages_whatever_the_group(cpu_devices, int8):
+    """What a set-up traces and lowers for each chunk program: a group's
+    copies are a loop over the pages it has, so the kernel at eight pages has
+    the copy starts and waits, the conditionals, the loops and the matmuls of
+    the kernel at two, and beyond them a mask row's load a page (unrolled a
+    page, with a conditional each, a chunk program's trace and lowering took
+    0.7 s more on the chip's host and a warm set-up 3 s: PERF.md section 5)."""
+    q, kp, vp, bt, valid, _ = _ragged_slots(np.random.default_rng(35), int8=int8)
+    two, eight = (_kernel_primitives(q, kp, vp, bt, valid, g) for g in (2, 8))
+    pools = 4 if int8 else 2
+    for name in ("dma_start", "dma_wait", "cond", "while", "dot_general"):
+        assert two[name] == eight[name] > 0, (name, two[name], eight[name])
+    assert two["dma_start"] == 2 * pools and two["dma_wait"] == pools
+    assert sum(eight.values()) - sum(two.values()) <= 6 * 4, (two, eight)
+
+
+@pytest.mark.parametrize("pages", [2, 3, 8])
+@pytest.mark.parametrize("pattern", ["random", "all_empty", "all_live", "ends_empty"])
+def test_slot_schedule_counts_groups(cpu_devices, pattern, pages):
+    """Over groups of `pages` columns `start[r]` is the number of groups of
+    the slots before `r` (a slot's last may be short) and `nxt` is what it
+    was: against the list of groups, in order."""
+    rng = np.random.default_rng(29)
+    R, nb = 11, 13
+    lo = rng.integers(0, nb, R)
+    hi = np.minimum(lo + rng.integers(0, 12, R), nb)
+    if pattern == "all_empty":
+        hi = lo.copy()
+    elif pattern == "all_live":
+        lo, hi = np.zeros(R, int), np.full(R, nb)
+    elif pattern == "ends_empty":
+        hi[[0, 1, R - 1]] = lo[[0, 1, R - 1]]
+    args = jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32)
+    start, nxt = slot_schedule(*args, pages)
+    groups = [(r, c) for r in range(R) for c in range(lo[r], hi[r], pages)]
+    for r in range(R):
+        assert int(start[r]) == sum(1 for s, _ in groups if s < r), r
+    np.testing.assert_array_equal(np.asarray(nxt), np.asarray(slot_schedule(*args)[1]))
+    assert start.dtype == nxt.dtype == jnp.int32
 
 
 @pytest.mark.parametrize("pattern", ["random", "all_empty", "all_live", "ends_empty"])

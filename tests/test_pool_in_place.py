@@ -161,6 +161,33 @@ def test_program_never_moves_the_pool(engine, program):
     assert not movers, f"{name} moves the pool: {movers}"
 
 
+@pytest.mark.parametrize("nb", [2, 4, 16])
+def test_chunk_scores_a_group_of_pages_from_two_buffers_a_pool(engine, nb):
+    """The decode chunk's kernel takes the group its shapes give (eight pages
+    of these 16-row pages, clipped to the table's columns) into two group
+    buffers a pool, whatever the bucket; the verify chunk's a page. Nothing
+    else of the program changes with the group: the pool is still read where
+    it is."""
+    from areal_tpu.ops.paged_attention import pool_group_pages
+
+    def buffers(fn, args):
+        calls = [e for e in _walk(jax.make_jaxpr(fn)(*args).jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert calls and not _pool_movers(jax.make_jaxpr(fn)(*args).jaxpr, int(
+            np.prod(engine._k_cache.shape[1:])))
+        D = engine._k_cache.shape[-1]
+        return {tuple(v.aval.shape) for e in calls for v in e.params["jaxpr"].invars
+                if len(v.aval.shape) == 3 and v.aval.shape[0] == 2 and v.aval.shape[2] == D
+                and v.aval.dtype == engine._k_cache.dtype}
+
+    progs = {name: (fn, args) for name, fn, args in _programs(engine, nb=nb)}
+    pages = pool_group_pages(engine._kv_operands()[0], 1, nb)
+    assert pages == min(8, nb)
+    D = engine._k_cache.shape[-1]
+    assert buffers(*progs["chunk"]) == {(2, pages * PAGE, D)}
+    assert buffers(*progs["verify_chunk"]) == {(2, PAGE, D)}
+
+
 @pytest.mark.parametrize("program", ["chunk", "verify_chunk", "prefill_batched"])
 def test_program_aliases_the_pool_to_its_results(engine, program):
     """Donated and carried through, the pool goes in and comes out in the

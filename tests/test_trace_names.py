@@ -355,14 +355,22 @@ def test_engine_counts_live_block_columns(cpu_devices):
     finally:
         eng.destroy()
     assert len(chunks) == m["chunks_dispatched_total"] >= 3
-    live = visited = 0
+    from areal_tpu.ops.paged_attention import group_pages
+
+    live = visited = groups = 0
     for active, lengths, nb in chunks:
         last = lengths[active] + n_chunk - 1  # the chunk's last query
         live += int(np.minimum(last // bsz + 1, nb).sum())
+        assert nb == 2 == group_pages(bsz, 2 * TINY.head_dim_, 4, 1, nb)
+        groups += int((-(-np.minimum(last // bsz + 1, nb) // 2)).sum())
         # a step a live column, and one a slot that has none
         visited += int(np.minimum(last // bsz + 1, nb).sum()) + R - int(active.sum())
     assert m["paged_block_columns_visited_total"] == visited
     assert m["paged_block_columns_live_total"] == live
+    # the groups the kernel's loop takes for them, at the group the pool's
+    # shapes give a chunk's table (two of its two columns), and what they score
+    assert m["paged_block_groups_walked_total"] == groups
+    assert m["paged_block_columns_scored_total"] == 2 * groups >= live
     # one and two live columns were both seen, and empty slots none
     assert len(chunks) < live < visited
 
@@ -910,3 +918,204 @@ def test_ring_step_kernels_are_named_at_the_fsdp4_shard_shape(one_chip):
     for kernel in ("%flash_fwd", "%flash_dq", "%flash_dkv"):
         assert kernel in hlo, kernel
     _flash_work_lists(hlo, 1, {"flash_fwd": 8, "flash_dq": 8, "flash_dkv": 8})
+
+
+# ---------------------------------------------------------------------------
+# The paged kernel's group of live columns (`ops/paged_attention.group_pages`)
+# at the rollout cells' shapes, compiled through Mosaic for the described v5e;
+# the cells whose shapes give a group of one keep the parent's programs; the
+# set-up makes the parent's programs and no other.
+# ---------------------------------------------------------------------------
+
+# cell: (slots, heads, kv heads, head size, table columns, queries a slot,
+# int8 pool, kernel name, the group its shapes give)
+_GROUP_CELLS = {
+    "rollout-1.5b-gsm8k": (128, 12, 2, 128, 10, 1, False, "paged_attention", 8),
+    "rollout-1.5b-gsm8k_256_token_bucket": (128, 12, 2, 128, 2, 1, False, "paged_attention", 2),
+    "rollout-1.5b-gsm8k_int8_pool": (128, 12, 2, 128, 10, 1, True, "paged_attention", 8),
+    "rollout-qwen3next-mixedlen": (64, 16, 2, 256, 64, 1, False, "paged_attention", 4),
+    "rollout-kexaone-mixedlen_full": (64, 64, 8, 128, 64, 1, False, "paged_attention", 2),
+    "rollout-kexaone-mixedlen_ring": (64, 64, 8, 128, 2, 1, False, "paged_attention_window", 2),
+    "rollout-olmoe-gsm8k": (64, 16, 16, 128, 10, 1, False, "paged_attention", 1),
+    "rollout-sdar-gsm8k": (128, 32, 4, 128, 10, 4, False, "paged_attention_block", 1),
+}
+
+
+@pytest.mark.parametrize("cell", list(_GROUP_CELLS))
+def test_grouped_kernel_compiles_at_each_cells_shape(one_chip, cell):
+    """The kernel with the group its shapes give: ONE Mosaic call over the
+    slots with the work list as scalar-prefetch operands, no loop around it
+    (the walk over a slot's groups is the kernel's), its two group buffers a
+    pool within VMEM (Mosaic refuses what does not fit), and the pools read
+    where they are: no operation of the program has a pool as its result."""
+    from areal_tpu.ops.paged_attention import paged_attention_qlen, pool_group_pages
+
+    R, nH, nKV, hd, nb, W, int8, name, pages = _GROUP_CELLS[cell]
+    L, bsz, n_blocks = 2, 128, R * nb + 1
+    pool = ((L, n_blocks, bsz, nKV * hd), jnp.int8 if int8 else jnp.bfloat16)
+    scales = ((L, n_blocks, nKV, bsz), jnp.float32)
+    assert pool_group_pages(jax.ShapeDtypeStruct(*pool), W, nb) == pages
+
+    def step(q, bt, valid, li, *pools):
+        kp, vp = (pools[:2], pools[2:]) if int8 else pools
+        return paged_attention_qlen(q, kp, vp, bt, valid, li, impl="pallas",
+                                    interpret=False, kernel_name=name)
+
+    hlo = _compile(
+        step, one_chip, ((R, W, nH, hd), jnp.bfloat16), ((R, nb), jnp.int32),
+        ((R, W, nb * bsz), jnp.bool_), ((), jnp.int32),
+        *([pool, scales] * 2 if int8 else [pool] * 2))
+    assert _one_paged_kernel(hlo, R) == name or _one_paged_kernel(hlo, R).startswith(name + ".")
+    dtype = "s8" if int8 else "bf16"
+    made = [ln for ln in hlo.splitlines()
+            if re.match(rf"\s*(ROOT )?%\S+ = {dtype}\[{L},{n_blocks},{bsz},{nKV * hd}\]", ln)
+            and " parameter(" not in ln]
+    assert not made, made
+
+
+def setup_programs(monkeypatch=None) -> list[str]:
+    """The names of the programs a decode engine with the kernel read makes
+    in `initialize()` and `_prewarm_chunk_variants` over a request that
+    grows through three chunk buckets, in the order they are made."""
+    from areal_tpu.engine.jax_decode import JaxDecodeEngine
+
+    spy = _JitSpy()
+    mp = monkeypatch or pytest.MonkeyPatch()
+    mp.setattr(jax, "jit", spy)
+    try:
+        eng = JaxDecodeEngine(
+            JaxDecodeConfig(context_length=1024, max_running_requests=4,
+                            new_tokens_per_chunk=64, page_size=128, dtype="float32",
+                            kv_cache_dtype="float32", paged_attn_impl="pallas"),
+            InferenceEngineConfig())
+        eng.set_model(init_params(TINY, jax.random.PRNGKey(0)), TINY)
+        eng.initialize()
+        try:
+            eng._prewarm_chunk_variants(100, 800, (1.0,))
+            return spy.made + [f"chunk nb={nb}" for _, _, nb in sorted(eng._chunk_fns)]
+        finally:
+            eng.destroy()
+    finally:
+        if monkeypatch is None:
+            mp.undo()
+
+
+# recorded on the parent commit with `python tests/test_trace_names.py`
+PARENT_SETUP_PROGRAMS = ["patch", "chunk", "chunk", "chunk",
+                         "chunk nb=2", "chunk nb=4", "chunk nb=8"]
+
+
+def test_setup_makes_the_parents_programs_and_no_other(cpu_devices, monkeypatch):
+    """The group of live columns is chosen where the kernel is traced, from
+    the call's shapes: no program is made to choose it, none is keyed by it,
+    and the chunk programs are the parent's, a bucket each."""
+    assert setup_programs(monkeypatch) == PARENT_SETUP_PROGRAMS
+
+
+def _location_free(lowered) -> str:
+    """A lowered program's text with each Mosaic kernel's serialised module
+    (which carries the source lines of the kernel's Python) replaced by the
+    module's own text without locations."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    def asm(match):
+        raw = base64.b64decode(match.group(1) + "=" * (-len(match.group(1)) % 4))
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            return "body: " + ir.Module.parse(raw).operation.get_asm(enable_debug_info=False)
+
+    text, n = re.subn(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', asm, lowered.as_text())
+    assert n, "no Mosaic kernel in the program"
+    return text
+
+
+def neighbour_steps(one_chip) -> dict:
+    """{name: location-free lowered text} of the model steps whose paged
+    kernel takes a group of one: OLMoE's decode step (a row of 2,048 lanes)
+    and SDAR's block step (4 queries a slot), two stacked layers each at the
+    cells' widths, the kernel lowered for the TPU."""
+    from areal_tpu.models.qwen2 import decode_step_paged, diffusion_step_paged, param_shapes
+
+    bf = jnp.bfloat16
+    arg = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    olmoe = ModelConfig(
+        vocab_size=50304, hidden_size=2048, intermediate_size=1024, num_hidden_layers=2,
+        num_attention_heads=16, num_key_value_heads=16, model_type="olmoe", qkv_bias=False,
+        qk_norm=True, qk_norm_full=True, num_experts=64, num_experts_per_tok=8,
+        moe_intermediate_size=1024, norm_topk_prob=False, tie_word_embeddings=False,
+        dtype="bfloat16", param_dtype="bfloat16")
+    sdar = ModelConfig(
+        vocab_size=151936, hidden_size=2048, intermediate_size=6144, num_hidden_layers=2,
+        num_attention_heads=32, num_key_value_heads=4, head_dim=128, model_type="sdar_moe",
+        qkv_bias=False, qk_norm=True, num_experts=128, num_experts_per_tok=8,
+        moe_intermediate_size=768, norm_topk_prob=True, rope_theta=1e6, block_length=4,
+        mask_token_id=151669, dtype="bfloat16", param_dtype="bfloat16")
+    out = {}
+    for name, cfg, fn, R, W in (("olmoe.decode_step", olmoe, decode_step_paged, 64, 1),
+                                ("sdar.diffusion_step", sdar, diffusion_step_paged, 128, 4)):
+        nb, bsz = 10, 128
+        params = jax.tree.map(lambda s: arg(s, bf), param_shapes(cfg),
+                              is_leaf=lambda x: isinstance(x, tuple))
+        pool = arg((2, R * nb + 1, bsz, cfg.num_key_value_heads * cfg.head_dim_), bf)
+
+        def step(params, kp, vp, bt, tokens, positions, active, cfg=cfg, fn=fn):
+            return fn(params, tokens, positions, kp, vp, bt, cfg, active=active,
+                      attn_impl="pallas", moe_load=True)
+
+        from areal_tpu.ops import paged_attention as pa
+
+        real, pa._default_interpret = pa._default_interpret, lambda: False
+        try:
+            out[name] = _location_free(jax.jit(step, donate_argnums=(1, 2)).trace(
+                params, pool, pool, arg((R, nb), jnp.int32),
+                arg((R,) if W == 1 else (R, W), jnp.int32), arg((R,), jnp.int32),
+                arg((R,), jnp.bool_)).lower(lowering_platforms=("tpu",)))
+        finally:
+            pa._default_interpret = real
+    return out
+
+
+def _sha(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+# recorded on the parent commit (PR 38's tree) with `python tests/test_trace_names.py`
+PARENT_SHA256 = {
+    "olmoe.decode_step": "31294f55415561e8",
+    "sdar.diffusion_step": "426539b5a586ca3c",
+}
+
+
+@pytest.fixture(scope="module")
+def neighbours(one_chip):
+    return neighbour_steps(one_chip)
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_SHA256))
+def test_a_group_of_one_keeps_the_parents_program(neighbours, name):
+    """At one page a group the kernel is the parent's, operation for
+    operation, and so is the work list around it: the lowered step, Mosaic
+    module included (without its source locations), hashes as the parent's."""
+    assert _sha(neighbours[name]) == PARENT_SHA256[name], (
+        f"{name}: the lowered step of a model whose paged kernel takes one page a group "
+        "changed; if the change is meant, record `python tests/test_trace_names.py` anew")
+
+
+if __name__ == "__main__":
+    import os
+
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    chip = SingleDeviceSharding(
+        topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
+    for k, v in sorted(neighbour_steps(chip).items()):
+        print(f'    "{k}": "{_sha(v)}",')
+    print("PARENT_SETUP_PROGRAMS =", setup_programs())
