@@ -14,9 +14,18 @@ designed for our packed layout:
   O(T^2) score matrix, which is what makes 32k-token generations trainable.
 - GQA is expressed in the BlockSpec index maps: query head h reads KV head
   h // (nH // nKV) — no KV replication in HBM.
-- every kernel casts its q/k/v/dO blocks to float32 before the matmuls, so
-  scores, softmax, the output accumulation and all gradients are float32
-  arithmetic (bf16 operands for the MXU are not used here).
+- the matmuls' operands are what the caller gave: q, k, v and dO blocks go
+  to the MXU in their own dtype, `p` and `ds` are rounded to it before the
+  products that consume them (as XLA's dense path rounds its softmax), and
+  every product accumulates in float32. Float32 whatever the inputs: the
+  scores, mask, running max and sum, `exp`, `lse`, `delta`, `dlse`, the
+  accumulators and the per-query-head `dk_h` / `dv_h`. On the chip bf16
+  operands change no bit and no time: Mosaic's default precision multiplies
+  float32 blocks in one bf16 pass, rounding them as `astype` does (PERF.md,
+  PR 43); it is interpret mode on the CPU that then computes the chip's
+  arithmetic. Float32 inputs keep float32 operands.
+- no product contracts dimension 0 of an operand (Mosaic would transpose
+  the block for it): `%flash_dkv` scores its block transposed, `[Bk, Bq]`.
 - backward is two more Pallas kernels (dq; dk/dv per query head reduced over
   the GQA group outside) wired through jax.custom_vjp, with the standard
   delta = rowsum(dO * O) trick so the backward never materialises probs.
@@ -85,13 +94,13 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _mask_for(seg_q, seg_k, qpos, kpos):
-    """[Bq, Bk] validity: same segment, causal by global position, not pad."""
-    return (
-        (seg_q[:, None] == seg_k[None, :])
-        & (qpos[:, None] >= kpos[None, :])
-        & (seg_q[:, None] != PADDING_SEGMENT)
-    )
+def _mask_for(seg_q, seg_k, qpos, kpos, key_major: bool = False):
+    """[Bq, Bk] validity: same segment, causal by global position, not pad;
+    `key_major`: the same table as [Bk, Bq]."""
+    q, k = (slice(None), None), (None, slice(None))  # x[:, None], x[None, :]
+    if key_major:
+        q, k = k, q
+    return (seg_q[q] == seg_k[k]) & (qpos[q] >= kpos[k]) & (seg_q[q] != PADDING_SEGMENT)
 
 
 # ---------------------------------------------------------------------------
@@ -276,6 +285,14 @@ def _key_side_copies(idk_hbm, k_hbm, v_hbm, bufs, sems, group: int):
     return copies
 
 
+def _scores(a, b, mask, sm_scale):
+    """Float32 masked scores `a b^T` of two blocks in their own dtype."""
+    s = jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return jnp.where(mask, s * sm_scale, _NEG_INF)
+
+
 _N_SCHED = 4  # `_schedule`'s vectors, the kernels' scalar-prefetch operands
 
 
@@ -301,17 +318,11 @@ def _fwd_kernel(*refs, sm_scale: float, group: int):
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def _compute(buf):
-        q = q_ref[0, 0].astype(jnp.float32)  # [Bq, hd]
-        k = k_buf[buf, :, :hd].astype(jnp.float32)  # [Bk, hd]
-        s = jax.lax.dot_general(
-            q,
-            k,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        s = s * sm_scale
+        # operands in the caller's dtype, every product into float32
+        q = q_ref[0, 0]  # [Bq, hd]
+        k = k_buf[buf, :, :hd]  # [Bk, hd]
         mask = _mask_for(seg_q_ref[0, 0], idk_buf[buf, 0], qpos_ref[0, 0], idk_buf[buf, 1])
-        s = jnp.where(mask, s, _NEG_INF)
+        s = _scores(q, k, mask, sm_scale)
 
         m_prev = m_ref[:]  # [Bq, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -323,9 +334,10 @@ def _fwd_kernel(*refs, sm_scale: float, group: int):
         p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_ref[:] = m_new
-        v = v_buf[buf, :, :hd].astype(jnp.float32)
+        v = v_buf[buf, :, :hd]
+        # l sums the float32 p; the product takes p rounded to v's dtype
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p,
+            p.astype(v.dtype),
             v,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -436,14 +448,6 @@ def _fwd_call(
 # ---------------------------------------------------------------------------
 
 
-def _scores(q, k, seg_q, seg_k, qpos, kpos, sm_scale):
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    s = s * sm_scale
-    return jnp.where(_mask_for(seg_q, seg_k, qpos, kpos), s, _NEG_INF)
-
-
 # rows of the per-query-row float32 operand `[B, nH, nq, 4, Bq]` (the fourth
 # is zeros: Mosaic tiles three sublanes by four, and a copy takes whole tiles)
 _LSE, _DELTA, _DLSE = range(3)
@@ -462,25 +466,25 @@ def _bwd_dq_kernel(*refs, sm_scale: float, group: int):
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
     def _compute(buf):
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_buf[buf, :, :hd].astype(jnp.float32)
-        v = v_buf[buf, :, :hd].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0, 0]
+        k = k_buf[buf, :, :hd]
+        v = v_buf[buf, :, :hd]
+        do = do_ref[0, 0]
         lse = rows_ref[0, 0, 0, _LSE]  # [Bq]
         delta = rows_ref[0, 0, 0, _DELTA]  # [Bq]
         dlse = rows_ref[0, 0, 0, _DLSE]  # [Bq]
-        s = _scores(
-            q, k, seg_q_ref[0, 0], idk_buf[buf, 0], qpos_ref[0, 0], idk_buf[buf, 1],
-            sm_scale,
-        )
+        mask = _mask_for(seg_q_ref[0, 0], idk_buf[buf, 0], qpos_ref[0, 0], idk_buf[buf, 1])
+        s = _scores(q, k, mask, sm_scale)
         p = jnp.exp(s - lse[:, None])
         p = jnp.where(lse[:, None] > _NEG_INF / 2, p, 0.0)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
+        # float32: s, p, dp, ds, the accumulator; ds rounded for its product
         ds = p * (dp - delta[:, None] + dlse[:, None])
         dq_acc_ref[:] += sm_scale * jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
     def _finalize():
@@ -512,30 +516,38 @@ def _bwd_dkv_kernel(*refs, sm_scale: float):
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
     def _compute(buf):
-        q = q_buf[buf, :, :hd].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_buf[buf, :, :hd].astype(jnp.float32)
-        lse = rows_buf[buf, _LSE]
-        delta = rows_buf[buf, _DELTA]
-        dlse = rows_buf[buf, _DLSE]
-        s = _scores(
-            q, k, idq_buf[buf, 0], seg_k_ref[0, 0], idq_buf[buf, 1], kpos_ref[0, 0],
-            sm_scale,
+        # The block is scored transposed, k q^T as [Bk, Bq]: p^T dO and
+        # ds^T q are then plain [Bk, Bq] x [Bq, hd] products (contracting
+        # dimension 0 of both operands made Mosaic transpose p and ds, a
+        # third of the kernel's time), and the query rows' lse, delta and
+        # dlse broadcast down the sublanes as they arrive, lane-major.
+        q = q_buf[buf, :, :hd]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_buf[buf, :, :hd]
+        lse = rows_buf[buf, _LSE][None, :]  # [1, Bq]
+        delta = rows_buf[buf, _DELTA][None, :]
+        dlse = rows_buf[buf, _DLSE][None, :]
+        mask = _mask_for(
+            idq_buf[buf, 0], seg_k_ref[0, 0], idq_buf[buf, 1], kpos_ref[0, 0],
+            key_major=True,
         )
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(lse[:, None] > _NEG_INF / 2, p, 0.0)
-        # dv += p^T @ do
+        s = _scores(k, q, mask, sm_scale)  # [Bk, Bq], float32 as p, dp, ds
+        p = jnp.exp(s - lse)
+        p = jnp.where(lse > _NEG_INF / 2, p, 0.0)
+        # dv += p^T @ do (p^T rounded to dO's dtype)
         dv_acc_ref[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ds = p * (dp - delta[:, None] + dlse[:, None])
-        # dk += ds^T @ q
+        ds = p * (dp - delta + dlse)
+        # dk += ds^T @ q (ds^T rounded to q's dtype)
         dk_acc_ref[:] += sm_scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
     def _finalize():

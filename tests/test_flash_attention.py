@@ -436,3 +436,150 @@ def test_model_forward_flash_vs_dense():
     np.testing.assert_allclose(
         np.asarray(out_d[:150]), np.asarray(out_f[:150]), atol=3e-4, rtol=3e-4
     )
+
+
+# ---------------------------------------------------------------------------
+# The matmuls' operand dtype is the caller's; the softmax stays float32
+# ---------------------------------------------------------------------------
+
+
+def kernel_equations(dtype):
+    """{kernel name: every equation of its traced body, loops and
+    conditionals walked} of the three kernels at `dtype` inputs."""
+    from jax._src import core
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    T, nH, nKV, hd = 256, 4, 2, 64
+    q, k = jnp.zeros((T, nH, hd), dtype), jnp.zeros((T, nKV, hd), dtype)
+    seg = jnp.zeros((T,), jnp.int32)
+    loss = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, seg, block_q=128, block_k=128, interpret=True).astype(jnp.float32).sum()
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, k)
+    return {
+        eqn.params["name"]: list(walk(eqn.params["jaxpr"]))
+        for eqn in walk(traced.jaxpr) if eqn.primitive.name == "pallas_call"
+    }
+
+
+# (products of the kernel, equations of its body at float32 inputs: PR 42's
+# for `%flash_fwd` and `%flash_dq`, whose casts of float32 blocks to float32
+# traced to nothing; `%flash_dkv` had 162 and scores the transposed block now)
+KERNEL_BODIES = {"flash_fwd": (2, 246), "flash_dq": (3, 221), "flash_dkv": (4, 161)}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("kernel", list(KERNEL_BODIES))
+def test_kernels_matmul_operands_follow_the_callers_dtype(kernel, dtype):
+    """What says the mechanism engaged (it is static a call, so no counter
+    can say more): in the kernel's traced body every `dot_general` takes
+    operands of the dtype the kernel was called with and gives float32; no
+    `[block, hd]` block is converted to float32 (the casts of q, k, v and dO
+    that made a v5e multiply in several bf16 passes); what is rounded to the
+    inputs' dtype besides the result blocks is `[Bq, Bk]`: `p` and `ds`, once
+    for each product that consumes them. At float32 inputs nothing is
+    converted at all and the body has the equations it had with the casts
+    (`%flash_dkv`: one fewer, its block transposed). No product contracts
+    dimension 0 of an operand: Mosaic would transpose the block for it."""
+    products, f32_equations = KERNEL_BODIES[kernel]
+    body = kernel_equations(jnp.dtype(dtype))[kernel]
+    dots = [e for e in body if e.primitive.name == "dot_general"]
+    assert len(dots) == products
+    for e in dots:
+        assert [str(v.aval.dtype) for v in e.invars] == [dtype, dtype], e
+        assert e.outvars[0].aval.dtype == jnp.float32, e
+        assert e.params["preferred_element_type"] == jnp.float32, e
+        (lhs, rhs), batch = e.params["dimension_numbers"]
+        assert lhs == (1,) and rhs in ((0,), (1,)) and batch == ((), ()), e
+    casts = [(e.invars[0].aval, e.outvars[0].aval) for e in body
+             if e.primitive.name == "convert_element_type" and e.invars[0].aval.shape]
+    if dtype == "float32":
+        assert not casts, casts
+        assert len(body) == f32_equations
+        return
+    assert not [c for c in casts if c[1].dtype == jnp.float32], casts
+    rounded = [src.shape for src, dst in casts if dst.dtype == jnp.bfloat16]
+    # `p` for `p v`; `ds` for `ds k`; `p` and `ds` for `p^T dO` and `ds^T q`
+    assert rounded.count((128, 128)) == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 2}[kernel]
+    # the output block (`o`, `dq`); `dk_h` and `dv_h` leave in float32
+    assert rounded.count((128, 64)) == (0 if kernel == "flash_dkv" else 1), rounded
+    assert len(rounded) == len(casts)
+
+
+def dense_branch(q, k, v, seg):
+    """The arithmetic of `models/qwen2.py:attention`'s dense branch, which
+    every cell's `correct` passes through in the decode engine's `prefill`:
+    scores from the operands as given into float32, a float32 softmax
+    rounded to the inputs' dtype, the weighted sum in that dtype."""
+    T, nH, hd = q.shape
+    nKV = k.shape[1]
+    qg = q.reshape(T, nKV, nH // nKV, hd)
+    scores = jnp.einsum("tkgd,skd->kgts", qg, k).astype(jnp.float32) / np.sqrt(hd)
+    scores = jnp.where(segment_causal_mask(seg)[None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    probs = jnp.where((seg != PADDING_SEGMENT)[None, None, :, None], probs, 0)
+    return jnp.einsum("kgts,skd->tkgd", probs, v).reshape(T, nH, hd)
+
+
+def bf16_case(T, nH, nKV, hd, seed, mean_len, pad):
+    """bf16 q, k, v (and a float32 weight for each output entry, so that a
+    loss has a gradient of unit scale) over a packed row with a pad tail."""
+    rng = np.random.RandomState(seed)
+    q, k, v = (jnp.asarray(rng.randn(T, n, hd), jnp.bfloat16) for n in (nH, nKV, nKV))
+    w = jnp.asarray(rng.randn(T, nH, hd), jnp.float32)
+    return q, k, v, w, jnp.asarray(random_packing(T, seed, mean_len, pad))
+
+
+def errors_against_float32(attend, q, k, v, w, seg):
+    """Largest |difference| of `attend`'s output and of its dq, dk, dv under
+    the loss sum(w * out) from `dense_reference`'s on the same values in
+    float32, and the four float32 arrays' largest |value|."""
+    real = np.asarray(seg) != PADDING_SEGMENT
+
+    def both(fn, *x):
+        loss = lambda q, k, v: jnp.sum(w * fn(q, k, v).astype(jnp.float32))  # noqa: E731
+        return [fn(*x), *jax.grad(loss, argnums=(0, 1, 2))(*x)]
+
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want = [np.asarray(x)[real] for x in both(lambda q, k, v: dense_reference(q, k, v, seg), *f32)]
+    got = [np.asarray(x, np.float32)[real] for x in both(attend, q, k, v)]
+    assert all(g.dtype == np.float32 and np.isfinite(g).all() for g in got)
+    return ([float(np.abs(g - x).max()) for g, x in zip(got, want)],
+            [float(np.abs(x).max()) for x in want])
+
+
+def test_bf16_inputs_are_as_close_to_float32_as_the_dense_path():
+    """The 0.5B head shape (14/2 heads of 64: a GQA group of 7) cut to 1,024
+    tokens, four packed sequences and a pad tail of 150, bf16 inputs, two
+    blocks of 512 a side: the kernels' output, dq, dk and dv against the
+    float32 reference on the same values, held to TWICE the error of
+    `attention()`'s dense branch on the same bf16 inputs, and `lse` to the
+    order of a float32 sum. Read on this CPU (interpret mode; largest
+    |difference| of out, dq, dk, dv; the float32 arrays' largest |value| are
+    3.25, 3.93, 7.22, 11.32): the kernels 0.0093, 0.0145, 0.0301, 0.0392, the
+    dense branch 0.0145, 0.0141, 0.0243, 0.0392: the same rounding of `p`,
+    and of `ds` where XLA's gradient rounds `dp` too. The kernels before this
+    change (float32 products of the same bf16 blocks) read 0.0078, 0.0145,
+    0.0213, 0.0392: the rounding of the results to bf16 is most of every
+    figure, and all of dv's. `lse` is within 2e-5 of float32's."""
+    T, nH, nKV, hd = 1024, 14, 2, 64
+    q, k, v, w, seg = bf16_case(T, nH, nKV, hd, seed=3, mean_len=200, pad=150)
+    assert len(np.unique(np.asarray(seg))) == 5 and int((np.asarray(seg) < 0).sum()) == 150
+    flash, scale = errors_against_float32(
+        lambda q, k, v: flash_attention(q, k, v, seg, interpret=True), q, k, v, w, seg)
+    dense, _ = errors_against_float32(lambda q, k, v: dense_branch(q, k, v, seg), q, k, v, w, seg)
+    for name, f, d, s in zip(("out", "dq", "dk", "dv"), flash, dense, scale):
+        assert 0 < f <= 2 * d and d < 0.02 * s, (name, f, d, s)
+    pos = jnp.arange(T, dtype=jnp.int32)
+    _, lse = flash_attention_chunk(q, k, v, seg, seg, pos, pos, interpret=True)
+    qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+    s = jnp.einsum("tkgd,skd->tkgs", qf.reshape(T, nKV, nH // nKV, hd), kf) / np.sqrt(hd)
+    s = jnp.where(segment_causal_mask(seg)[:, None, None, :], s, -jnp.inf)
+    real = np.asarray(seg) != PADDING_SEGMENT
+    want = np.asarray(jax.nn.logsumexp(s, axis=-1).reshape(T, nH))[real]
+    assert lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse)[real], want, atol=2e-5, rtol=0)

@@ -851,6 +851,28 @@ def _flash_work_lists(hlo: str, rows: int, outer: dict[str, int]) -> None:
         assert operands[4].startswith(f"s32[{rows},1,"), operands[:5]
 
 
+def _flash_operands_stay_as_given(hlo: str, T: int, hd: int) -> None:
+    """The kernels multiply what they are given: of a compiled bf16 step's
+    flash kernels every `[.., hd]` (or, blocked, 128-lane) operand is bf16,
+    the only float32 operand the per-row vectors `[.., 4, block]`; and the
+    program around them holds no float32 array of a `[T, heads, hd]`
+    operand's shape but `%flash_dkv`'s own float32 results, which leave the
+    kernel a query head each and are summed over the GQA group outside."""
+    calls = [ln for ln in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for call in calls:
+        operands = call.split("operand_layout_constraints={")[1].split("}}")[0].split(", ")
+        floats = [o for o in operands if not o.startswith("s32[")]
+        assert len(floats) >= 3, operands
+        for o in floats:
+            assert o.startswith("bf16[") or re.match(r"f32\[[\d,]+,4,\d+\]", o), (o, operands)
+    entry = hlo.split("\nENTRY ")[1]
+    wide = [ln for ln in entry.splitlines() if re.search(rf" = \(?f32\[[\d,]*{T},{hd}\]", ln)]
+    kinds = {re.search(r"\}\)? ([\w-]+)\(", ln).group(1) for ln in wide}
+    assert kinds <= {"custom-call", "get-tuple-element", "bitcast"}, wide
+    assert all("flash_dkv" in ln for ln in wide if "custom-call(" in ln), wide
+
+
 def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):
     """`flash_attention` at `train-0.5b-gsm8k`'s call, forward and both
     backward kernels under remat: the names the benchmark's readers match,
@@ -875,6 +897,7 @@ def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):
     for wrapper in ("%checkpoint", "%rematted_computation", "%closed_call"):
         assert wrapper not in hlo, wrapper
     _flash_work_lists(hlo, 1, {"flash_fwd": 16, "flash_dq": 16, "flash_dkv": 16})
+    _flash_operands_stay_as_given(hlo, T, hd)
 
 
 @pytest.mark.parametrize("nH,nKV", [(12, 2), (16, 16)], ids=["1p5b", "olmoe"])
@@ -898,6 +921,7 @@ def test_flash_kernel_batches_under_vmap_at_the_rollout_head_shapes(one_chip, nH
     assert len(kernels) == 1 and "flash_fwd" in kernels[0], kernels
     assert " while(" not in hlo
     _flash_work_lists(hlo, B, {"flash_fwd": 1})
+    _flash_operands_stay_as_given(hlo, T, hd)
 
 
 def test_ring_step_kernels_are_named_at_the_fsdp4_shard_shape(one_chip):
@@ -918,6 +942,7 @@ def test_ring_step_kernels_are_named_at_the_fsdp4_shard_shape(one_chip):
     for kernel in ("%flash_fwd", "%flash_dq", "%flash_dkv"):
         assert kernel in hlo, kernel
     _flash_work_lists(hlo, 1, {"flash_fwd": 8, "flash_dq": 8, "flash_dkv": 8})
+    _flash_operands_stay_as_given(hlo, T, hd)
 
 
 # ---------------------------------------------------------------------------

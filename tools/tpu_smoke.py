@@ -110,6 +110,17 @@ def _one_segment(T):
     return jnp.zeros((T,), jnp.int32), T
 
 
+# bf16 inputs against XLA's float32 dense reference, read on the chip once
+# (PR 43, call k2: the kernels' products take bf16 operands and `p`, `ds`
+# rounded to bf16, as the float32-cast operands before them did inside the
+# MXU): forward 0.0106-0.0129 on whole rows up to 4,096 tokens (0.0003-0.0036
+# on the 128-query slice of the 8,192- and 32,768-token rows; 0.0121 under
+# vmap, 0.0020 a ring chunk), gradients 0.0054-0.0091 of their largest entry
+# (0.0081 a ring chunk), `lse` under 5e-5.
+FLASH_TOL = 0.03
+FLASH_LSE_TOL = 1e-3
+
+
 def flash_case(T, nH, nKV, hd, segments=_packed_segments):
     """flash_attention forward + backward against the dense reference; the
     detail ends with the time of a forward and of forward + backward a call
@@ -145,7 +156,7 @@ def flash_case(T, nH, nKV, hd, segments=_packed_segments):
         o_ref, _ = _masked_attention(q[rows], k, v, mask_rows(rows), sm_scale)
         fwd = float(jnp.max(jnp.abs(o_flash[rows].astype(jnp.float32) - o_ref)))
         finite = all(bool(jnp.all(jnp.isfinite(g))) for g in g_flash)
-        return fwd < 0.06 and finite, f"fwd_maxerr={fwd:.4f} bwd_finite={finite}" + times
+        return fwd < FLASH_TOL and finite, f"fwd_maxerr={fwd:.4f} bwd_finite={finite}" + times
 
     mask = mask_rows(jnp.arange(T))
 
@@ -160,7 +171,8 @@ def flash_case(T, nH, nKV, hd, segments=_packed_segments):
     )
     g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     bwd = max(_rel(a, b) for a, b in zip(g_flash, g_ref))
-    return fwd < 0.06 and bwd < 0.06, f"fwd_maxerr={fwd:.4f} bwd_relerr={bwd:.4f}" + times
+    return (fwd < FLASH_TOL and bwd < FLASH_TOL,
+            f"fwd_maxerr={fwd:.4f} bwd_relerr={bwd:.4f}" + times)
 
 
 def flash_vmap_case(B=4, T=256, nH=12, nKV=2, hd=128):
@@ -190,8 +202,8 @@ def flash_vmap_case(B=4, T=256, nH=12, nKV=2, hd=128):
         err = max(err, float(
             jnp.max(jnp.abs((out[b].astype(jnp.float32) - o_ref)[valid]))
         ))
-    return err < 0.06, (f"fwd_maxerr={err:.4f} rows={B} "
-                        f"ms_per_call: fwd {_ms_per_call(batched, q, k, v, seg):.3f}")
+    return err < FLASH_TOL, (f"fwd_maxerr={err:.4f} rows={B} "
+                             f"ms_per_call: fwd {_ms_per_call(batched, q, k, v, seg):.3f}")
 
 
 def flash_chunk_case(later=False, T=1024, nH=N_HEADS, nKV=N_KV, hd=HEAD_DIM):
@@ -238,8 +250,8 @@ def flash_chunk_case(later=False, T=1024, nH=N_HEADS, nKV=N_KV, hd=HEAD_DIM):
     lse_err = float(jnp.max(jnp.abs(lse - lse_ref)))
     bwd = max(_rel(a, b) for a, b in zip(g, g_ref))
     return (
-        fwd < 0.06 and lse_err < 0.05 and bwd < 0.06,
-        f"fwd_maxerr={fwd:.4f} lse_maxerr={lse_err:.4f} bwd_relerr={bwd:.4f} "
+        fwd < FLASH_TOL and lse_err < FLASH_LSE_TOL and bwd < FLASH_TOL,
+        f"fwd_maxerr={fwd:.4f} lse_maxerr={lse_err:.6f} bwd_relerr={bwd:.4f} "
         f"ms_per_call: fwd {_ms_per_call(run_fwd, q, k, v):.3f} "
         f"fwd+bwd {_ms_per_call(run_grad, q, k, v):.3f}",
     )
