@@ -22,7 +22,7 @@ AR301 — route pairing. Server-side registrations
   `# wire: external` (an ops/bench surface consumed outside the tree —
   the annotation IS the declared contract). Both directions are skipped
   when the analyzed set harvested no registrations at all, so a
-  client-only sweep (`tools/lint.sh --all` over `bench.py`) stays quiet.
+  client-only sweep (`tools/lint.sh --all` over `tools/`) stays quiet.
 
 AR302 — fault-seam validity. Every `fire/afire/tear("<seam>", ...)`
   string constant is a real seam; every `FaultPoint(site=<pat>)` /

@@ -321,8 +321,8 @@ class JaxDecodeConfig:
     #     scales as-is, halving swap and wire bytes too. Mixed-dtype
     #     fleets reject migrated sessions as tombstoned honest misses
     #     (kv_migrate_dtype_rejects_total), like the weight-version rule.
-    #     Drift (logprob delta, spec accept-rate shift) is measured by
-    #     `bench.py --mode kvquant`, not assumed zero.
+    #     Drift (logprob delta) is bounded at a tiny preset on the CPU by
+    #     tests/test_kv_quant.py; at a real model's widths it is unmeasured.
     kv_dtype: str = "fp"  # "fp" | "int8"
     # Weight serving dtype for the dense transformer matmul kernels
     # (models/qwen2.py q/k/v/o + dense mlp; MoE, embed, lm_head, norms,
@@ -339,9 +339,10 @@ class JaxDecodeConfig:
     #     (ops/quant_matmul.py) right after each weight tile's HBM→VMEM
     #     DMA — decode chunks read half the weight bytes and the freed
     #     HBM goes to the KV pool (utils/hbm.py prices it). Drift vs the
-    #     fp oracle is measured by `bench.py --mode wquant`, not assumed
-    #     zero. The LoRA delta push stays fp and requantizes the folded
-    #     kernels at install.
+    #     fp oracle is bounded at a tiny preset on the CPU by
+    #     tests/test_weight_quant.py; at a real model's widths it is
+    #     unmeasured. The LoRA delta push stays fp and requantizes the
+    #     folded kernels at install.
     weight_dtype: str = "fp"  # "fp" | "int8"
     # Replica role in a disaggregated fleet (launcher/decode_server.py):
     #   "unified" (default): one replica does both prefill and decode.

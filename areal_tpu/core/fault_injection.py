@@ -5,9 +5,9 @@ push, host-KV tier, rollout executor) is only trustworthy if it DEGRADES
 instead of corrupting data when a component fails (Podracer's anti-fragile
 actor fleets; LlamaRL treats worker loss as routine). This module gives
 every cross-component boundary a named injection seam and a seed-driven
-plan that perturbs those seams reproducibly, so `bench.py --mode chaos`
-and `tests/test_chaos.py` can replay a whole fleet trace under a fault
-schedule and assert the exactly-once / bit-identical-stream invariants.
+plan that perturbs those seams reproducibly, so `tests/test_chaos.py`
+and its neighbours can replay a fleet trace under a fault schedule and
+assert the exactly-once / bit-identical-stream invariants.
 
 Seams (grep for `fault_injection.fire(` / `.afire(` / `.tear(`):
 

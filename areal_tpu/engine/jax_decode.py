@@ -87,12 +87,11 @@ from areal_tpu.api.io_struct import (
     WeightUpdateMeta,
 )
 from areal_tpu.engine.kv_pool import (
-    StateSlots,
-    WindowRing,
     HostKVEntry,
     HostKVStore,
     KVBlockAllocator,
     PoolDry,
+    SlotCache,
 )
 from areal_tpu.models import hf_io
 from areal_tpu.models.qwen2 import (
@@ -161,7 +160,6 @@ _GUARDED_BY = {
     "JaxDecodeEngine._verify_fns": "_sched_lock",
     "JaxDecodeEngine._prefill_fns": "_sched_lock",
     "JaxDecodeEngine._batched_prefill_fns": "_sched_lock",
-    "JaxDecodeEngine._fork_fns": "_sched_lock",
     "JaxDecodeEngine._suffix_prefill_fns": "_sched_lock",
     "JaxDecodeEngine._vision_fns": "_sched_lock",
     "JaxDecodeEngine._embed_prefill_fns": "_sched_lock",
@@ -454,15 +452,10 @@ class JaxDecodeEngine(InferenceEngine):
         self._k_scale = None
         self._v_scale = None
         self._kv_quant = False
-        # a mixed stack (window and full layers): the pools are
-        # {"full": paged pool over the full layers, "window": ring pool over
-        # the window layers} and `_ring` accounts for the rings; None for a
-        # uniform stack, whose pools are bare arrays
-        self._ring: WindowRing | None = None
-        # a model with linear layers: the K-side pool dict also carries
-        # {"state": {"S", "conv"}}, a recurrent state a slot and linear
-        # layer, and `_state` accounts for how many tokens each holds
-        self._state: StateSlots | None = None
+        # what a slot's cache is for the model served: the pools above are
+        # what its `new_pools()` returned, `_alloc` below is its allocator
+        # (set in initialize)
+        self._slot_cache: SlotCache | None = None
         # int8 weight serving (ISSUE 16): dense matmul kernels live as
         # {"q","scale"} pytree leaves; False serves the fp oracle path
         self._w_quant = False
@@ -552,9 +545,6 @@ class JaxDecodeEngine(InferenceEngine):
         # differs from this engine's pool — mixed-dtype fleets tombstone
         # the rid as an honest miss, like the weight-version rule
         self._n_migrate_dtype_rejects = 0
-        # K+V bytes of one pool block (set in initialize; import_session
-        # needs it to size a lazily created host tier)
-        self._block_nbytes = 0
         self._alloc: KVBlockAllocator | None = None  # set in initialize
         # host-RAM KV tier (kv_host_pool_mb > 0): eviction offloads
         # parked/preempted slots' blocks here instead of dropping them;
@@ -631,12 +621,10 @@ class JaxDecodeEngine(InferenceEngine):
         self._kv_full_rows_read = 0
         self._kv_window_rows_read = 0
         # a latent model: cached latent rows read, and tokens x sparse layers
-        # whose kept routing groups include one held here; bytes of a cached
-        # row of one layer as the pool stores it (set by initialize())
+        # whose kept routing groups include one held here
         self._kv_latent_rows_read = 0
         self._moe_group_tokens_here = 0
         self._moe_group_experts_touched = 0
-        self._row_nbytes = 0
         # linear layers: state updates of live slots (slots x layers x steps)
         self._gdn_state_updates = 0
         # of the steps the paged kernel takes a chunk (a live block column,
@@ -683,7 +671,6 @@ class JaxDecodeEngine(InferenceEngine):
         self._paged_impl = "auto"  # resolved in initialize()
         self._prefill_fns: dict[int, Callable] = {}
         self._batched_prefill_fns: dict[tuple[int, int], Callable] = {}
-        self._fork_fns: dict[int, Callable] = {}
         self._suffix_prefill_fns: dict[tuple[int, int], Callable] = {}
         self._write_fns: dict[int, Callable] = {}
         # GQA-under-tp: kv heads repeated _kv_repeat times at install
@@ -828,136 +815,41 @@ class JaxDecodeEngine(InferenceEngine):
             )
         else:
             n_blocks = R * max_bps + 1
-        self._ring = self._state = None
+        # what a slot's cache is for this model (engine/kv_pool.py): the
+        # accounts, the pools, and what it cannot serve, refused here with
+        # the reason and not at the first request that needs it
+        self._slot_cache = SlotCache(
+            cfg, slots=R, block_size=bs, n_blocks=n_blocks,
+            max_blocks_per_slot=max_bps, kv_dtype=kv_dtype, quant=self._kv_quant,
+            cache_sharding=self._cache_sharding,
+            scale_sharding=self._scale_sharding,
+        )
+        self._alloc = self._slot_cache.alloc
+        self._slot_cache.unserved(
+            self.config, vision=self._vision_params is not None,
+            weight_quant=self._w_quant,
+        )
         self._diffusion = cfg.block_length_ > 1
         if self._diffusion:
-            self._refuse_unserved_for_diffusion()
-            # a prefix found by content ends where its blocks of the POOL
-            # end, not on a block boundary of the mask
+            self._check_diffusion_config()
+        if not self._slot_cache.content_addressed:
             self._fabric_on = False
-        if cfg.mixed:
-            self._refuse_unserved_for_mixed(bs)
-            if cfg.latent:
-                self._refuse_unserved_for_latent()
-            if cfg.cache_layers["window"]:
-                self._ring = WindowRing(R, cfg.sliding_window, bs)
-            if cfg.cache_layers["state"]:
-                self._state = StateSlots(R)
-            # a prefix shared in part, or found by content, would need the
-            # donor's ring at a length it no longer holds
-            self._fabric_on = False
-        self._alloc = KVBlockAllocator(R, n_blocks, bs, max_bps)
         # host-RAM tier under the pool: budgeted by kv_host_pool_mb
         # (0 = disabled — eviction drops KV and resume re-prefills,
-        # exactly the pre-tier behavior). PHYSICAL bytes per block: int8
-        # pools store 1 byte/element plus one f32 scale per (row, head) —
-        # every byte counter downstream (host budget, swap totals,
-        # migration totals) derives from this, so
-        # none of them can silently assume the fp element size.
-        kv_elem = (
-            1 if self._kv_quant
-            else jnp.dtype(self.config.kv_cache_dtype).itemsize
-        )
-        kv_scale_bytes = 4 if self._kv_quant else 0
-        # the row the block-table-addressed pool has, a layer: K and V of every
-        # kv head (and an int8 pool's scale a row and head), or a latent
-        # model's one row at the lanes the pool stores it in
-        if cfg.latent:
-            paged_layers = len(cfg.cache_layers["latent"])
-            self._row_nbytes = cfg.latent_row_lanes * kv_elem
-        else:
-            paged_layers = (len(cfg.cache_layers["full"]) if cfg.mixed
-                            else cfg.num_hidden_layers)
-            self._row_nbytes = (
-                2  # K and V
-                * cfg.num_key_value_heads
-                * (cfg.head_dim_ * kv_elem + kv_scale_bytes)
-            )
-        block_nbytes = paged_layers * bs * self._row_nbytes
-        self._block_nbytes = int(block_nbytes)
+        # exactly the pre-tier behavior), in the pool's PHYSICAL bytes a block
         with self._host_lock:
             if float(self.config.kv_host_pool_mb) > 0:
                 self._host_store = HostKVStore(
                     budget_bytes=int(
                         float(self.config.kv_host_pool_mb) * 1024 * 1024
                     ),
-                    block_nbytes=block_nbytes,
+                    block_nbytes=self._slot_cache.block_nbytes,
                     block_size=bs,
                 )
             else:
                 self._host_store = None
-        shape = (
-            cfg.num_hidden_layers,
-            n_blocks,
-            bs,
-            cfg.num_key_value_heads * cfg.head_dim_,
-        )
-        pool_dtype = jnp.int8 if self._kv_quant else kv_dtype
-
-        def new_pool():
-            if not cfg.mixed:
-                return jax.device_put(
-                    jnp.zeros(shape, pool_dtype), self._cache_sharding
-                )
-            # two kinds of cache side by side: pages through the block
-            # table for the full layers, a fixed ring of pages a slot for
-            # the window layers (engine/kv_pool.py)
-            blocks = {
-                "full": n_blocks,
-                "window": self._ring.n_blocks if self._ring else 0,
-            }
-            return {
-                kind: jax.device_put(
-                    jnp.zeros((len(layers), blocks[kind], *shape[2:]), pool_dtype),
-                    self._cache_sharding,
-                )
-                for kind, layers in cfg.cache_layers.items()
-                if layers and kind in blocks
-            }
-
-        if cfg.latent:
-            # a fourth kind: ONE row a token and layer, `[c_kv | k_pe]` at
-            # whole vregs of lanes, through the same block table and
-            # allocator as a paged pool (a fork aliases its blocks); no V side
-            self._k_cache = {"latent": jax.device_put(
-                jnp.zeros((cfg.num_hidden_layers, n_blocks, bs,
-                           cfg.latent_row_lanes), pool_dtype),
-                self._cache_sharding,
-            )}
-            self._v_cache = {}
-        else:
-            self._k_cache = new_pool()
-            self._v_cache = new_pool()
-        if self._state is not None:
-            # a state a slot and linear layer, row 0 the null slot. It rides
-            # in the K-side dict, so every pool program carries it donated
-            n_lin = len(cfg.cache_layers["state"])
-            self._k_cache["state"] = {
-                "S": jnp.zeros(
-                    (n_lin, 1 + R, cfg.linear_num_value_heads,
-                     cfg.linear_key_head_dim, cfg.linear_value_head_dim),
-                    jnp.float32,
-                ),
-                "conv": jnp.zeros(
-                    (n_lin, 1 + R, cfg.linear_conv_kernel_dim - 1,
-                     cfg.linear_conv_channels),
-                    kv_dtype,
-                ),
-            }
-        self._k_scale = self._v_scale = None
-        if self._kv_quant:
-            # per-(row, head) f32 scales, paged like the data pool; the
-            # kv-head axis precedes block_size so a Pallas scale block is
-            # (1, 1, bs) with the 128-multiple page size on the lane dim
-            sshape = (
-                cfg.num_hidden_layers, n_blocks, cfg.num_key_value_heads, bs
-            )
-            self._k_scale = jax.device_put(
-                jnp.zeros(sshape, jnp.float32), self._scale_sharding
-            )
-            self._v_scale = jax.device_put(
-                jnp.zeros(sshape, jnp.float32), self._scale_sharding
-            )
+        (self._k_cache, self._v_cache, self._k_scale,
+         self._v_scale) = self._slot_cache.new_pools()
         self._slot_lengths = np.zeros(R, dtype=np.int32)
         self._slot_rope_delta = np.zeros(R, dtype=np.int32)
         self._slot_used_freq = np.zeros(R, dtype=bool)
@@ -1042,188 +934,14 @@ class JaxDecodeEngine(InferenceEngine):
         self._thread.start()
         return self
 
-    def _refuse_unserved_for_mixed(self, block_size: int) -> None:
-        """A mixed stack's cache is two pools, one of them a ring that is
-        written over as a request grows. What moves, stores or re-reads KV
-        as one block-table-addressed array is not served for it yet: say so
-        here, with the reason, and not at the first request that needs it."""
-        cfg, c = self.model_config, self.config
-        refused = []
-        if c.kv_dtype != "fp":
-            refused.append(
-                f"kv_dtype={c.kv_dtype!r}: the ring pool has no scale pool"
-            )
-        if float(c.kv_host_pool_mb) > 0:
-            refused.append(
-                "kv_host_pool_mb > 0: the host tier gathers one block-table-"
-                "addressed pool; a ring's rows are not in it"
-            )
-        if getattr(c, "role", "unified") != "unified":
-            refused.append(
-                f"role={c.role!r}: export and import of parked KV "
-                "(migration) move the paged pool alone"
-            )
-        if cfg.cache_layers["window"]:
-            from areal_tpu.models.qwen2 import ring_slack
-
-            slack = ring_slack(cfg.sliding_window, block_size)
-            if c.spec_decode == "ngram" and int(c.spec_k) > slack:
-                refused.append(
-                    f"spec_decode='ngram' with spec_k={c.spec_k}: a verify "
-                    f"chunk of {int(c.spec_k) + 1} rows does not fit the "
-                    f"ring's {slack} rows of slack past the window"
-                )
-        if cfg.cache_layers["state"] and c.spec_decode != "off":
-            refused.append(
-                f"spec_decode={c.spec_decode!r}: a rejected draft would have "
-                "to roll each slot's recurrent state back"
-            )
-        if refused:
-            raise NotImplementedError(
-                f"{cfg.model_type} (layers of more than one kind in one "
-                "stack) is not served with: " + "; ".join(refused)
-            )
-
-    def _refuse_unserved_for_latent(self) -> None:
-        """A latent model's cache is one row a token and layer with no V
-        side, read by a kernel of its own that scores one query a slot. What
-        assumes K and V rows by kv head is not served for it yet (beyond
-        what `_refuse_unserved_for_mixed` refuses for every stack that lives
-        in a dict of pools: an int8 pool, the host tier, migration): say so
-        here, with the reason, and not at the first request that needs it."""
-        cfg, c = self.model_config, self.config
-        refused = []
-        if c.spec_decode != "off":
-            refused.append(
-                f"spec_decode={c.spec_decode!r}: the absorbed attention scores "
-                "one query a slot; a verify chunk has several"
-            )
-        if max(int(c.tensor_parallel_size), 1) > 1:
-            refused.append(
-                f"tensor_parallel_size={c.tensor_parallel_size}: the latent "
-                "pool has no kv-head axis to shard"
-            )
-        if self._w_quant:
-            refused.append(
-                f"weight_dtype={c.weight_dtype!r}: the low-rank projections "
-                "have no int8 form"
-            )
-        if self._vision_params is not None:
-            refused.append("a vision tower: no latent model with one is known")
-        if refused:
-            raise NotImplementedError(
-                f"{cfg.model_type} (latent attention: one cached row a token) "
-                "is not served with: " + "; ".join(refused)
-            )
-
-    def _refuse_unserved_for_diffusion(self) -> None:
-        """A block-diffusion model's cache is valid a whole block at a time
-        (a block's rows are written for good by its commit forward), and a
-        chunk returns blocks, not one token a forward. What assumes
-        otherwise is not served for it yet: say so here, with the reason,
-        and not at the first request that needs it."""
-        cfg, c = self.model_config, self.config
-        B = cfg.block_length_
-        refused = []
-        if cfg.mask_token_id is None or not 0 <= cfg.mask_token_id < cfg.vocab_size:
-            refused.append(
-                f"mask_token_id={cfg.mask_token_id!r}: the mask token has to "
-                f"be an embedding row (vocab_size={cfg.vocab_size})"
-            )
-        if cfg.mixed or cfg.sliding_window is not None:
-            refused.append(
-                "window, linear or leading dense layers: the block step "
-                "reads one paged pool under the block-causal mask alone"
-            )
-        if c.spec_decode != "off":
-            refused.append(
-                f"spec_decode={c.spec_decode!r}: a verify chunk drafts the "
-                "NEXT token of a causal model; a block is denoised in place"
-            )
-        if float(c.kv_host_pool_mb) > 0:
-            refused.append(
-                "kv_host_pool_mb > 0: the host tier restores a prefix cut at "
-                "any token; this cache is valid at block boundaries only"
-            )
-        if getattr(c, "role", "unified") != "unified":
-            refused.append(
-                f"role={c.role!r}: a prefill-only park and the migration "
-                "wire cover prompt[:-1], which ends inside a block"
-            )
-        if c.kv_dtype != "fp":
-            refused.append(
-                f"kv_dtype={c.kv_dtype!r}: the block step through an int8 "
-                "pool has not been held to the reference"
-            )
-        if int(c.context_length) % B or int(c.new_tokens_per_chunk) % B:
-            refused.append(
-                f"context_length={c.context_length} and new_tokens_per_chunk="
-                f"{c.new_tokens_per_chunk} have to be whole numbers of blocks "
-                f"of {B}"
-            )
-        if int(c.diffusion_steps) < 1:
-            refused.append(f"diffusion_steps={c.diffusion_steps} < 1")
-        if c.diffusion_strategy not in DIFFUSION_STRATEGIES:
-            refused.append(
-                f"diffusion_strategy={c.diffusion_strategy!r} not in "
-                f"{DIFFUSION_STRATEGIES}"
-            )
-        if self._vision_params is not None:
-            refused.append("a vision tower: image rows have no block mask")
-        if refused:
-            raise NotImplementedError(
-                f"{cfg.model_type} (generation by diffusion over blocks of "
-                f"{B}) is not served with: " + "; ".join(refused)
-            )
-
-    def _cover(self, n: int) -> int:
-        """Of a sequence of `n` tokens, how many have their rows cached when
-        it is admitted: all but the last, which the chunk's first step takes
-        as its input; for a block-diffusion model the whole blocks, the rest
-        being the revealed head of the first block it denoises."""
-        if self._diffusion:
-            B = self.model_config.block_length_
-            return (n // B) * B
-        return n - 1
-
-    def _kv_row_nbytes(self) -> int:
-        """Bytes of one cached row of one layer as the pool stores it: K
-        and V of every kv head, or a latent model's one row."""
-        return int(self._row_nbytes)
-
     def state_pool(self) -> dict | None:
         """The recurrent-state pool `{"S", "conv"}` of a model with linear
         layers, as the last pool program left it; None for any other model.
         For a caller that has flushed the engine: a chunk in flight holds
         the pool donated."""
-        if self._k_cache is None or self._state is None:
+        if self._k_cache is None or self._slot_cache.state is None:
             return None
         return self._k_cache["state"]
-
-    def _state_update_nbytes(self) -> int:
-        """Bytes one linear layer's state update moves for one slot: its
-        state and convolution rows, once in and once out."""
-        if self._k_cache is None or self._state is None:
-            return 0
-        # from shapes alone: a metrics call may land while the pool is
-        # donated to a chunk in flight
-        return 2 * sum(
-            int(np.prod(a.shape[2:])) * a.dtype.itemsize
-            for a in self._k_cache["state"].values()
-        )
-
-    def _no_migration_for_mixed(self, what: str) -> None:
-        if self._diffusion:
-            raise NotImplementedError(
-                f"{what}: a block-diffusion model's parked KV ends on a block "
-                "boundary of its mask; the migration wire carries prompt[:-1]"
-            )
-        if self.model_config is not None and self.model_config.mixed:
-            raise NotImplementedError(
-                f"{what}: a mixed stack's parked KV is a paged pool and a "
-                "ring or a recurrent state; the migration wire and the host "
-                "tier carry the paged pool alone"
-            )
 
     def destroy(self):
         self._shutdown.set()
@@ -1261,7 +979,7 @@ class JaxDecodeEngine(InferenceEngine):
         self.params = None
         self._k_cache = self._v_cache = None
         self._k_scale = self._v_scale = None
-        self._alloc = None
+        self._alloc = self._slot_cache = None
         with self._host_lock:
             if self._host_store is not None:
                 self._host_store.clear()
@@ -1287,7 +1005,6 @@ class JaxDecodeEngine(InferenceEngine):
         self._verify_fns.clear()
         self._prefill_fns.clear()
         self._batched_prefill_fns.clear()
-        self._fork_fns.clear()
         self._suffix_prefill_fns.clear()
         self._prefix_lookup.clear()
 
@@ -1820,6 +1537,37 @@ class JaxDecodeEngine(InferenceEngine):
         )
         self._chunk_fns[key_] = fn
         return fn
+
+    def _check_diffusion_config(self) -> None:
+        """What the diffusion chunk needs of the config beyond what its cache
+        can serve (`SlotCache.unserved`): refused at `initialize()`, with
+        the reason, and not at the first chunk."""
+        cfg, c = self.model_config, self.config
+        B = cfg.block_length_
+        refused = []
+        if cfg.mask_token_id is None or not 0 <= cfg.mask_token_id < cfg.vocab_size:
+            refused.append(
+                f"mask_token_id={cfg.mask_token_id!r}: the mask token has to "
+                f"be an embedding row (vocab_size={cfg.vocab_size})"
+            )
+        if int(c.context_length) % B or int(c.new_tokens_per_chunk) % B:
+            refused.append(
+                f"context_length={c.context_length} and new_tokens_per_chunk="
+                f"{c.new_tokens_per_chunk} have to be whole numbers of blocks "
+                f"of {B}"
+            )
+        if int(c.diffusion_steps) < 1:
+            refused.append(f"diffusion_steps={c.diffusion_steps} < 1")
+        if c.diffusion_strategy not in DIFFUSION_STRATEGIES:
+            refused.append(
+                f"diffusion_strategy={c.diffusion_strategy!r} not in "
+                f"{DIFFUSION_STRATEGIES}"
+            )
+        if refused:
+            raise NotImplementedError(
+                f"{cfg.model_type} (generation by diffusion over blocks of "
+                f"{B}) is not served with: " + "; ".join(refused)
+            )
 
     def _diffusion_forwards(self) -> int:
         """Forwards of one block-diffusion chunk: what its
@@ -2430,167 +2178,20 @@ class JaxDecodeEngine(InferenceEngine):
             )
         return self._batched_prefill_fns[key]
 
-    def _prefill_tables(self, slot: int, nb_w: int):
-        """What a prefill program scatters a slot's rows through: its
-        block-table row, and for a mixed stack with it its ring blocks."""
-        row = self._alloc.row(slot, nb_w)
-        tables = (row,)
-        if self._ring is not None:
-            tables += (self._ring.blocks(slot),)
-        if self._state is not None:
-            tables += (np.int32(self._state.row(slot)),)
-        return tables if len(tables) > 1 else row
-
-    def _get_block_copy_fn(self):
-        """Copy ONE pool block (the fork boundary's partial block).
-
-        Prefix forks are block-table aliasing on the host (kv_pool.py) —
-        the only device work left is this single-block copy, versus the
-        dense engine's O(prefix-length) row memcpy, and versus the
-        transformer prefill both replace."""
-        if True not in self._fork_fns:
-            cfg_latent = self.model_config.latent
-
-            def fork_block(kq, vq, src_b, dst_b):
-                # tree-mapped so int8 operands copy the scale block through
-                # the same block ids as the data block (fp: bare arrays)
-                def cp(pool):
-                    blk = jnp.take(pool, src_b[None], axis=1)
-                    return pool.at[:, dst_b[None]].set(blk)
-
-                def one(pool):
-                    if isinstance(pool, dict):
-                        # a mixed stack: the boundary block is the paged
-                        # pool's (a latent model's: its one pool's; its V
-                        # side holds nothing); the ring is copied whole
-                        # (_get_ring_copy_fn)
-                        kind = "latent" if cfg_latent else "full"
-                        return {**pool, kind: cp(pool[kind])} if pool else pool
-                    return jax.tree.map(cp, pool)
-
-                return one(kq), one(vq)
-
-            self._fork_fns[True] = jax.jit(fork_block, donate_argnums=(0, 1))
-        return self._fork_fns[True]
-
-    def _get_ring_copy_fn(self):
-        """Copy one slot's ring pages onto another's (a fork of a mixed
-        stack: the window layers' rows cannot be aliased, each slot writes
-        over its own)."""
-        if "ring" not in self._fork_fns:
-
-            def fork_ring(kq, vq, src_b, dst_b):
-                def cp(pool):
-                    ring = pool["window"]
-                    return {**pool, "window": ring.at[:, dst_b].set(ring[:, src_b])}
-
-                return cp(kq), cp(vq)
-
-            self._fork_fns["ring"] = jax.jit(fork_ring, donate_argnums=(0, 1))
-        return self._fork_fns["ring"]
+    def _run_copies(self, copies: list[tuple]) -> None:
+        """Run the device copies a fork or a reset of a slot's cache takes
+        (`SlotCache.fork`, `.zero`), in order, on the pools."""
+        if not copies:
+            return
+        with self._weight_lock:
+            for fn, *operands in copies:
+                self._set_kv_operands(*fn(*self._kv_operands(), *operands))
 
     def _device_fork(self, src: int, dst: int, covered: int) -> None:
-        """Alias the donor's full blocks and copy the boundary block; in a
-        mixed stack also copy the donor's ring pages (the caller has checked
-        that the ring still holds `covered`'s window: `_ring_holds`).
-        A model with linear layers: the donor's state rows are copied
-        (`_get_state_copy_fn`; the caller has checked `_ring_holds`).
-        Raises PoolDry when the boundary block cannot be allocated."""
-        cp = self._alloc.fork(src, dst, covered)
-        if cp is not None:
-            src_b, dst_b = cp
-            fn = self._get_block_copy_fn()
-            with self._weight_lock:
-                kq, vq = self._kv_operands()
-                self._set_kv_operands(*fn(
-                    kq,
-                    vq,
-                    jnp.asarray(src_b, jnp.int32),
-                    jnp.asarray(dst_b, jnp.int32),
-                ))
-        if self._ring is not None and src != dst:
-            fn = self._get_ring_copy_fn()
-            with self._weight_lock:
-                kq, vq = self._kv_operands()
-                self._set_kv_operands(*fn(
-                    kq,
-                    vq,
-                    jnp.asarray(self._ring.blocks(src)),
-                    jnp.asarray(self._ring.blocks(dst)),
-                ))
-            # what the donor's ring was written up to is what the copy holds
-            self._ring.reset(dst, int(self._ring.hi[src]))
-        if self._state is not None and src != dst:
-            fn = self._get_state_copy_fn()
-            with self._weight_lock:
-                kq, vq = self._kv_operands()
-                self._set_kv_operands(*fn(
-                    kq,
-                    vq,
-                    # NumPy scalars: no eager device op ahead of the copy
-                    np.int32(self._state.row(src)),
-                    np.int32(self._state.row(dst)),
-                ))
-            self._state.reset(dst, int(self._state.count[src]))
-
-    def _get_state_copy_fn(self):
-        """Copy one slot's recurrent state onto another's (a fork of a
-        model with linear layers: a state cannot be aliased, each slot folds
-        its own tokens into its own)."""
-        if "state" not in self._fork_fns:
-
-            def fork_state(kq, vq, src_row, dst_row):
-                state = {
-                    name: a.at[:, dst_row].set(a[:, src_row])
-                    for name, a in kq["state"].items()
-                }
-                return {**kq, "state": state}, vq
-
-            self._fork_fns["state"] = jax.jit(fork_state, donate_argnums=(0, 1))
-        return self._fork_fns["state"]
-
-    def _zero_state(self, slot: int) -> None:
-        """A request that starts with no prefill decodes from an empty
-        state: the slot's rows back to zero."""
-        if "zero" not in self._fork_fns:
-
-            def zero(kq, vq, row):
-                state = {
-                    name: a.at[:, row].set(0) for name, a in kq["state"].items()
-                }
-                return {**kq, "state": state}, vq
-
-            self._fork_fns["zero"] = jax.jit(zero, donate_argnums=(0, 1))
-        with self._weight_lock:
-            kq, vq = self._kv_operands()
-            self._set_kv_operands(*self._fork_fns["zero"](
-                kq, vq, np.int32(self._state.row(slot))
-            ))
-
-    def _cache_rewritten(self, slot: int, length: int) -> None:
-        """A prefill (or nothing at all, `length` 0) has just written the
-        slot's ring and state from scratch: they hold `length` tokens."""
-        if self._ring is not None:
-            self._ring.reset(slot, length)
-        if self._state is not None:
-            self._state.reset(slot, length)
-
-    def _cache_written(self, active: np.ndarray) -> None:
-        """A dispatched chunk writes the active slots' rings and states up
-        to their (projected) lengths."""
-        for account in (self._ring, self._state):
-            if account is not None:
-                account.note_written(active, self._slot_lengths[active])
-
-    def _ring_holds(self, slot: int, covered: int) -> bool:
-        """Whether `slot`'s KV can seed a request that continues at
-        `covered`: always for a uniform stack (its rows stay where they
-        were written); for a mixed one only while the slot's ring has not
-        been written past that window, and, with linear layers, only when
-        the slot's state holds exactly `covered` tokens."""
-        return (self._ring is None or self._ring.holds(slot, covered)) and (
-            self._state is None or self._state.holds(slot, covered)
-        )
+        """Point `dst` at `src`'s first `covered` tokens of cache (the caller
+        has asked `SlotCache.holds`). Raises PoolDry when the boundary block
+        cannot be allocated."""
+        self._run_copies(self._slot_cache.fork(src, dst, covered))
 
     # -- host KV tier (kv_host_pool_mb) --------------------------------
     def _get_host_gather_fn(self):
@@ -3203,7 +2804,7 @@ class JaxDecodeEngine(InferenceEngine):
             # never-consumed last one (_slot_lengths may be projected
             # ahead by dispatched-but-unconsumed chunks whose tokens the
             # reconcile will discard)
-            covered = self._cover(len(item.prompt) + len(item.tokens))
+            covered = self._slot_cache.cover(len(item.prompt) + len(item.tokens))
             if covered > 0:
                 self._offload_slot_kv(
                     item.rid,
@@ -3231,9 +2832,9 @@ class JaxDecodeEngine(InferenceEngine):
         slot, covered, _ = entry
         cached = self._parked_tokens.get(item.rid, [])
         if (
-            covered == self._cover(len(item.prompt))
+            covered == self._slot_cache.cover(len(item.prompt))
             and cached == item.prompt[:covered]
-            and self._ring_holds(slot, covered)
+            and self._slot_cache.holds(slot, covered)
         ):
             self._parked.pop(item.rid)
             self._parked_tokens.pop(item.rid, None)
@@ -3289,7 +2890,7 @@ class JaxDecodeEngine(InferenceEngine):
             P = len(prompt)
             # C: the head of it whose rows are cached at admission (all but the last; a
             # block-diffusion model: its whole blocks)
-            C = self._cover(P)
+            C = self._slot_cache.cover(P)
             if (
                 len(item.prompt) + item.gconfig.max_new_tokens
                 > self.config.context_length
@@ -3326,7 +2927,7 @@ class JaxDecodeEngine(InferenceEngine):
                 donor = self._prefix_lookup.get(covered_t)
                 if donor is None:
                     donor = self._find_covering_donor(covered_t)
-                if donor is not None and not self._ring_holds(donor, C):
+                if donor is not None and not self._slot_cache.holds(donor, C):
                     # a mixed stack: the donor has decoded past this
                     # prefix's window, its ring no longer holds it
                     donor = None
@@ -3370,8 +2971,8 @@ class JaxDecodeEngine(InferenceEngine):
                 # (nor a block-diffusion model: a suffix prefill would have
                 # to start on a block boundary of the mask)
                 found = (
-                    None if self.model_config.mixed or self._diffusion
-                    else self._find_shared_prefix(covered_t)
+                    self._find_shared_prefix(covered_t)
+                    if self._slot_cache.shares_partial_prefix else None
                 )
                 if found is None and req_chain:
                     # fabric dedup rung: longest common block-aligned run
@@ -3442,9 +3043,7 @@ class JaxDecodeEngine(InferenceEngine):
                 # no prefill: the decode loop writes KV from row 0, which
                 # invalidates whatever prefix this slot may have donated
                 self._release_slot_blocks(slot_idx)
-                if self._state is not None:
-                    self._zero_state(slot_idx)
-                self._cache_rewritten(slot_idx, 0)
+                self._run_copies(self._slot_cache.zero(slot_idx))
             promoted = False
             if resumed is None and host_hit:
                 # Host-tier swap-in: fresh device blocks + async upload
@@ -3746,7 +3345,7 @@ class JaxDecodeEngine(InferenceEngine):
                                 vq,
                                 jnp.asarray(ids),
                                 jnp.asarray(positions),
-                                self._prefill_tables(slot_idx, nb_w),
+                                self._slot_cache.tables(slot_idx, nb_w),
                                 pre,
                             ))
                     else:
@@ -3763,7 +3362,7 @@ class JaxDecodeEngine(InferenceEngine):
                                 jnp.asarray(positions),
                                 jax.tree.map(
                                     lambda *rows: jnp.asarray(np.stack(rows)),
-                                    *[self._prefill_tables(g[0], nb_w)
+                                    *[self._slot_cache.tables(g[0], nb_w)
                                       for g in group],
                                 ),
                                 jnp.asarray(
@@ -3771,7 +3370,7 @@ class JaxDecodeEngine(InferenceEngine):
                                 ),
                             ))
                 for slot_idx, _, pre, _, covered_t in group:
-                    self._cache_rewritten(slot_idx, pre)
+                    self._slot_cache.rewritten(slot_idx, pre)
                     self._register_prefix(slot_idx, list(covered_t))
         for dst, src, covered_t, bucket in forks:
             covered = len(covered_t)
@@ -3803,10 +3402,10 @@ class JaxDecodeEngine(InferenceEngine):
                                 jnp.asarray(
                                     np.arange(bucket, dtype=np.int32)
                                 ),
-                                self._prefill_tables(dst, nb_w),
+                                self._slot_cache.tables(dst, nb_w),
                                 covered,
                             ))
-                        self._cache_rewritten(dst, covered)
+                        self._slot_cache.rewritten(dst, covered)
                     else:
                         self._preempt_slot(dst)
                         continue
@@ -4093,39 +3692,17 @@ class JaxDecodeEngine(InferenceEngine):
 
     def _count_block_columns(self, active: np.ndarray, nb: int, W: int = 1) -> None:
         """`paged_block_columns_{live,visited}_total`: what the paged kernel
-        walks for the chunk just dispatched (the paged pool's table; a mixed
-        stack's ring is two columns a slot, both live), at the chunk's last
-        step. The kernel takes one step a live block column and one a slot
-        that has none (it writes that slot's zeros), so `visited` is the live
-        columns plus the slots not active; it was slots x `nb` while the
-        kernel's grid was. `ops/paged_attention.live_block_range` on the
-        host, from the projected lengths: an active slot has the columns up
-        to its last query's, less those wholly before a uniform stack's
-        window. `paged_block_groups_walked_total` and
+        walks for the chunk just dispatched (`SlotCache.walk`, from the
+        projected lengths), at the chunk's last step. The kernel takes one
+        step a live block column and one a slot that has none (it writes
+        that slot's zeros), so `visited` is the live columns plus the slots
+        not active. `paged_block_groups_walked_total` and
         `paged_block_columns_scored_total`: the loop iterations the kernel
         takes for those columns at the group its shapes give a chunk of `W`
-        queries a slot (`group_pages`; the latent kernel's constant), and
-        groups x group size, so live / scored is how full the score matmuls
-        are."""
-        # (imported here as the model imports them: a process that never
-        # dispatches a chunk never imports Pallas)
-        from areal_tpu.ops.paged_attention import pool_group_pages
-        from areal_tpu.ops.paged_attention_latent import PAGES_PER_GROUP
-
-        bsz = self._alloc.block_size
-        last = self._slot_lengths[active].astype(np.int64) - 1
-        live = np.minimum(last // bsz + 1, nb)
-        window = self.model_config.sliding_window
-        if window is not None and self._ring is None:
-            live -= np.maximum(last - window + 1, 0) // bsz
+        queries a slot, and groups x group size, so live / scored is how
+        full the score matmuls are."""
+        live, pages = self._slot_cache.walk(self._slot_lengths[active], nb, W)
         columns = int(live.sum())
-        pool = self._k_cache
-        if isinstance(pool, dict) and "latent" in pool:
-            pages = PAGES_PER_GROUP
-        else:
-            pages = pool_group_pages(
-                pool["full"] if isinstance(pool, dict) else pool, W, nb
-            )
         groups = int((-(-live // pages)).sum())
         with self._metrics_lock:
             self._paged_cols_live += columns
@@ -4182,10 +3759,8 @@ class JaxDecodeEngine(InferenceEngine):
             if s is None:
                 active[i] = False
                 continue
-            # (rows cached past the prompt's: a causal model's lag its
-            # tokens by one, a block-diffusion model's are its tokens)
-            projected_gen = int(self._slot_lengths[i]) - (
-                len(s.prompt) - (0 if self._diffusion else 1)
+            projected_gen = self._slot_cache.generated(
+                int(self._slot_lengths[i]), len(s.prompt)
             )
             if projected_gen >= s.gconfig.max_new_tokens:
                 active[i] = False
@@ -4349,7 +3924,7 @@ class JaxDecodeEngine(InferenceEngine):
             # (spec_w - accepted - 1) back out, and retire rewinds set the
             # absolute end as for normal chunks
             self._slot_lengths[active] += spec_w
-            self._cache_written(active)
+            self._slot_cache.written(active, self._slot_lengths)
             self._count_block_columns(active, nb, spec_w)
             self._count_grouped_matmuls(1, R * spec_w)
             with self._metrics_lock:
@@ -4414,7 +3989,7 @@ class JaxDecodeEngine(InferenceEngine):
         # ensure / bucket choice covers this (unconsumed) chunk's growth;
         # retire rewinds overwrite this with the absolute true end
         self._slot_lengths[active] += n_chunk
-        self._cache_written(active)
+        self._slot_cache.written(active, self._slot_lengths)
         self._count_block_columns(active, nb)
         self._count_grouped_matmuls(n_chunk, R)
         with self._metrics_lock:
@@ -4474,7 +4049,7 @@ class JaxDecodeEngine(InferenceEngine):
         # worst-case projection, as a verify chunk's: a slot commits at most
         # n_chunk rows; _apply_chunk takes back what it did not
         self._slot_lengths[active] += n_chunk
-        self._cache_written(active)
+        self._slot_cache.written(active, self._slot_lengths)
         self._count_block_columns(active, nb, self.model_config.block_length_)
         self._count_grouped_matmuls(
             self._diffusion_forwards(), R * self.model_config.block_length_
@@ -4528,13 +4103,11 @@ class JaxDecodeEngine(InferenceEngine):
                             self._moe_group_tokens_here += more.pop(0)
                             self._moe_group_experts_touched += more.pop(0)
                         if more:
-                            self._kv_full_rows_read += more[0]
-                            self._kv_window_rows_read += more[1]
-                            if cfg.latent:
-                                self._kv_latent_rows_read += more[2]
-                            else:
-                                # with linear layers, their state updates
-                                self._gdn_state_updates += sum(more[2:])
+                            read = self._slot_cache.rows_read(more)
+                            self._kv_full_rows_read += read["full"]
+                            self._kv_window_rows_read += read["window"]
+                            self._kv_latent_rows_read += read["latent"]
+                            self._gdn_state_updates += read["state"]
             self._apply_chunk(rec, toks, logps, acc)
 
     def _apply_chunk(self, rec: "_Inflight", toks: np.ndarray,
@@ -4635,7 +4208,7 @@ class JaxDecodeEngine(InferenceEngine):
                 # past it are never attended again before overwrite); a
                 # block-diffusion model's the whole blocks of them (a block
                 # cut by the stop was committed with what was cut in sight)
-                self._slot_lengths[i] = self._cover(len(s.prompt) + len(s.tokens))
+                self._slot_lengths[i] = self._slot_cache.cover(len(s.prompt) + len(s.tokens))
                 self._retire(i)
         # chunk-level ITL sample: device window over the MEAN tokens a
         # surviving slot emitted (== n_chunk for normal chunks; accepted+1
@@ -4878,7 +4451,7 @@ class JaxDecodeEngine(InferenceEngine):
         gets compiled during a live load burst depends on request-arrival
         interleaving — a "warmed-by-traffic" engine can still hit a
         multi-second first-compile mid-serving (observed as an 80x
-        throughput flake in bench_decode's timed window). This uses only
+        throughput flake in a timed window). This uses only
         public APIs to force exact wave sizes: queue exactly W requests
         while generation is paused, then resume — the scheduler admits
         them as one wave of W (same-bucket waves dispatch as one vmapped
@@ -5037,7 +4610,7 @@ class JaxDecodeEngine(InferenceEngine):
             if self._diffusion:
                 grow += self.model_config.block_length_
         out: set[int] = set()
-        length = max(self._cover(prompt_len), 0)
+        length = max(self._slot_cache.cover(prompt_len), 0)
         end = min(length + new_tokens, S)
         while True:
             b = 256
@@ -5346,7 +4919,8 @@ class JaxDecodeEngine(InferenceEngine):
         blocks are never written by in-flight chunks, and the gather
         serialises under _sched_lock -> _weight_lock like every other
         pool read."""
-        self._no_migration_for_mixed("export_session")
+        if self._slot_cache is not None:
+            self._slot_cache.unserved_call("export_session", "migration")
         from areal_tpu.ops.kv_quant import split_pool
 
         try:
@@ -5499,7 +5073,8 @@ class JaxDecodeEngine(InferenceEngine):
         output; empty when nothing matches. Safe from the HTTP thread:
         the whole resolution + gather runs under _sched_lock (and the
         mesh scope), so a racing weight install cannot tear a chain."""
-        self._no_migration_for_mixed("export_fabric_blocks")
+        if self._slot_cache is not None:
+            self._slot_cache.unserved_call("export_fabric_blocks", "migration")
         from areal_tpu.ops.kv_quant import split_pool
 
         if not self._fabric_on or self._alloc is None:
@@ -5645,7 +5220,7 @@ class JaxDecodeEngine(InferenceEngine):
                     * 1024
                     * 1024
                 ),
-                block_nbytes=max(self._block_nbytes, 1),
+                block_nbytes=self._slot_cache.block_nbytes,
                 block_size=block_size,
             )
 
@@ -5665,7 +5240,8 @@ class JaxDecodeEngine(InferenceEngine):
         carry their scale blocks in `ks`/`vs` and land verbatim — no
         requantization on this hop either.
         """
-        self._no_migration_for_mixed("import_session")
+        if self._slot_cache is not None:
+            self._slot_cache.unserved_call("import_session", "migration")
         if self._alloc is None or self._k_cache is None:
             return "rejected"
         try:
@@ -6106,6 +5682,12 @@ class JaxDecodeEngine(InferenceEngine):
             if s is not None:
                 running += 1
                 active_tokens += int(self._slot_lengths[i]) + 1
+        # PHYSICAL bytes of a cached row, a pool block and a state update
+        # (0 before initialize() and after destroy())
+        cache = self._slot_cache
+        row_nbytes = cache.row_nbytes if cache else 0
+        block_nbytes = cache.block_nbytes if cache else 0
+        state_update_nbytes = cache.state_update_nbytes if cache else 0
         # queued work is load too: a router that only saw running slots
         # would dogpile a server whose queue is deep (its slot count
         # saturates at max_running_requests). The queue's deque must be
@@ -6251,19 +5833,19 @@ class JaxDecodeEngine(InferenceEngine):
             # bytes behind those rows (K and V of a full layer's row), and
             # for linear layers the live slots' state updates and the bytes
             # each moves: its state and convolution rows, read and written
-            "kv_full_bytes_read_total": kv_rows_read[0] * self._kv_row_nbytes(),
+            "kv_full_bytes_read_total": kv_rows_read[0] * row_nbytes,
             # a latent model: cached latent rows the chunks' attention read
             # (live slots' rows x latent layers of every token step), their
             # bytes as the pool stores a row, and the tokens x sparse layers
             # whose kept routing groups include one held here
             "kv_latent_rows_read_total": kv_latent_rows,
-            "kv_latent_bytes_read_total": kv_latent_rows * self._kv_row_nbytes(),
+            "kv_latent_bytes_read_total": kv_latent_rows * row_nbytes,
             "moe_group_tokens_here_total": moe_group_here,
             # held experts with at least one pair, summed over sparse layers
             # and token steps: the expert weights the grouped matmuls read
             "moe_group_experts_touched_total": moe_group_touched,
             "gdn_state_updates_total": gdn_updates,
-            "gdn_state_bytes_total": gdn_updates * self._state_update_nbytes(),
+            "gdn_state_bytes_total": gdn_updates * state_update_nbytes,
             # a block-diffusion model: live slots x forwards of its chunks,
             # those that were a slot's commit pass, the blocks they committed,
             # tokens of committed blocks dropped at a stop, and the cached
@@ -6359,11 +5941,9 @@ class JaxDecodeEngine(InferenceEngine):
             # param tree holds {"q","scale"} leaves (ISSUE 16) and wire
             # pushes must arrive producer-quantized
             "weight_dtype": self.config.weight_dtype,
-            "kv_block_nbytes": self._block_nbytes,
+            "kv_block_nbytes": block_nbytes,
             "kv_pool_device_bytes": (
-                self._alloc.n_blocks * self._block_nbytes
-                if self._alloc
-                else 0
+                self._alloc.n_blocks * block_nbytes if self._alloc else 0
             ),
             "kv_block_size": self._alloc.block_size if self._alloc else 0,
             "kv_blocks_total": self._alloc.usable_blocks if self._alloc else 0,

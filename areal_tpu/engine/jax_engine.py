@@ -1638,7 +1638,7 @@ class JaxTrainEngine(TrainEngine):
         from areal_tpu.core import fault_injection
 
         # chaos seam: a trainer dying inside an optimizer step (weights
-        # half-applied in HBM, nothing durable) — see bench chaostrain
+        # half-applied in HBM, nothing durable)
         fault_injection.fire("train.step", step=self._step_count)
 
         t_start = time.perf_counter()

@@ -34,15 +34,18 @@ Block 0 is a reserved null block: unallocated table entries point at it,
 so uniform-width gathers of short slots read (masked) garbage instead of
 stealing a live block's rows.
 
-A mixed stack (window and full layers, models/qwen2.py) keeps TWO kinds of
-cache side by side. The full layers have the pool above, over the full
-layers alone. A window layer never reads past its window, so its rows live
-in a fixed RING of pages a slot (`WindowRing`): a second pool
-`[window layers, 1 + slots * pages, block_size, nKV*hd]` indexed by slot,
-outside the allocator, read through a `[slots, pages]` table with the window
-in the mask. A fork aliases the full layers' blocks as above and COPIES the
-donor's ring pages; what a ring has been written past, it no longer holds,
-so a donor serves a prefix only while `WindowRing.holds` says so.
+What a slot's cache IS follows from the model, and `SlotCache` is the one
+place that says so: which pools exist, what a slot holds in each, and what
+follows (what is cached at admission, what a prefill scatters through,
+whether a slot can still seed a request, what a fork aliases and what it
+copies, a row's bytes, which mechanisms cannot be served). The engine asks
+it and tests no kind itself. Beside the paged pool above (all a uniform stack
+has, a bare array a side) there are: for window layers a fixed RING of pages
+a slot (`WindowRing`), a second pool outside the allocator that a fork
+copies; for linear layers one recurrent STATE a slot (`StateSlots`), good for
+exactly the length it has absorbed; for latent attention ONE row a token and
+layer through the same table and allocator, with no V side; and under a block
+mask (`block_length` > 1) the paged pool valid a whole block at a time.
 """
 
 from __future__ import annotations
@@ -51,6 +54,8 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -267,6 +272,413 @@ class StateSlots:
         """Whether the slot's state is the state after exactly `covered`
         tokens."""
         return int(self.count[slot]) == int(covered)
+
+
+# -- the device copies a fork or a reset takes (jitted a `SlotCache`) ---------
+
+
+def fork_block(kq, vq, src_b, dst_b):
+    """Copy ONE block of the block-table-addressed pool: a fork boundary's
+    partial block. Prefix forks are block-table aliasing on the host; the only
+    device work left is this copy, against the O(prefix) memcpy of a dense
+    cache and the prefill both replace."""
+
+    # tree-mapped so int8 operands copy the scale block through the same
+    # block ids as the data block (fp: bare arrays)
+    def cp(pool):
+        blk = jnp.take(pool, src_b[None], axis=1)
+        return pool.at[:, dst_b[None]].set(blk)
+
+    def one(pool):
+        if isinstance(pool, dict):
+            # a dict of pools: the boundary block is the paged pool's (a
+            # latent model's one pool; its V side holds nothing); a ring is
+            # copied whole (`fork_ring`)
+            kind = "latent" if "latent" in pool else "full"
+            return {**pool, kind: cp(pool[kind])} if pool else pool
+        return jax.tree.map(cp, pool)
+
+    return one(kq), one(vq)
+
+
+def fork_ring(kq, vq, src_b, dst_b):
+    """Copy one slot's ring pages onto another's: the window layers' rows
+    cannot be aliased, each slot writes over its own."""
+
+    def cp(pool):
+        ring = pool["window"]
+        return {**pool, "window": ring.at[:, dst_b].set(ring[:, src_b])}
+
+    return cp(kq), cp(vq)
+
+
+def fork_state(kq, vq, src_row, dst_row):
+    """Copy one slot's recurrent state onto another's: a state cannot be
+    aliased, each slot folds its own tokens into its own."""
+    state = {
+        name: a.at[:, dst_row].set(a[:, src_row])
+        for name, a in kq["state"].items()
+    }
+    return {**kq, "state": state}, vq
+
+
+def zero(kq, vq, row):
+    """A slot's recurrent state back to zero: a request that starts with no
+    prefill decodes from an empty state."""
+    state = {name: a.at[:, row].set(0) for name, a in kq["state"].items()}
+    return {**kq, "state": state}, vq
+
+
+# -- what each cache cannot serve ----------------------------------------------
+
+# What a mechanism may need of a slot's cache, in a refusal's words.
+_NEEDS = {
+    "scales": "rows by kv head with a scale pool beside them",
+    "one_pool": "all a slot has cached in one bare pool behind the block table",
+    "any_cut": "a prefix that is whole when cut at any token",
+    "next_token": "a forward that predicts the next token",
+    "queries": "a read that scores several queries a slot",
+    "take_back": "a step that a rejected draft can take back",
+    "draft_rows": "room for a verify chunk's rows past its oldest query's window",
+    "kv_heads": "a kv-head axis to shard",
+    "int8_projections": "projections with an int8 form",
+    "image_rows": "a place for image rows",
+}
+
+# mechanism -> (its setting in words when (config, vision tower, int8
+# weights) ask for it, what it needs of the cache)
+MECHANISMS = {
+    "kv_dtype": (lambda c, v, w: c.kv_dtype != "fp" and f"kv_dtype={c.kv_dtype!r}",
+                 ("scales",)),
+    "host tier": (lambda c, v, w: float(c.kv_host_pool_mb) > 0
+                  and "kv_host_pool_mb > 0 (the host tier)", ("one_pool", "any_cut")),
+    "migration": (lambda c, v, w: getattr(c, "role", "unified") != "unified"
+                  and f"role={c.role!r} (export and import of parked KV: migration)",
+                  ("one_pool", "any_cut")),
+    "spec_decode": (lambda c, v, w: c.spec_decode != "off"
+                    and f"spec_decode={c.spec_decode!r}",
+                    ("next_token", "queries", "take_back", "draft_rows")),
+    "tensor_parallel_size": (lambda c, v, w: int(c.tensor_parallel_size) > 1
+                             and f"tensor_parallel_size={c.tensor_parallel_size}",
+                             ("kv_heads",)),
+    "weight_dtype": (lambda c, v, w: w and f"weight_dtype={c.weight_dtype!r}",
+                     ("int8_projections",)),
+    "vision": (lambda c, v, w: v and "a vision tower", ("image_rows",)),
+}
+
+
+def _ring_draft_room(cache: "SlotCache", config) -> str | None:
+    from areal_tpu.models.qwen2 import ring_slack
+
+    slack = ring_slack(cache.ring.window, cache.ring.block_size)
+    if config is None or int(config.spec_k) <= slack:
+        return None
+    return (f"a verify chunk of {int(config.spec_k) + 1} rows (spec_k="
+            f"{config.spec_k}) does not fit the ring's {slack} rows of slack")
+
+
+# kind of cache -> what it is ("is") and, by need, why it lacks it (a function
+# of (cache, config) where that depends on a number)
+KINDS = {
+    "pools": {
+        "is": "a mixed stack, layers of more than one kind: a dict of pools",
+        "scales": "a pool of a dict has no scale pool",
+        "one_pool": "a mixed stack's parked KV is a paged pool and a ring, a "
+                    "recurrent state or a latent pool; the host tier and the "
+                    "migration wire carry one paged pool of K and V alone",
+    },
+    "window": {
+        "is": "a ring of pages a slot for the window layers",
+        "draft_rows": _ring_draft_room,
+    },
+    "state": {
+        "is": "a recurrent state a slot for the linear layers",
+        "take_back": "a rejected draft would have to roll each slot's "
+                     "recurrent state back",
+    },
+    "latent": {
+        "is": "latent attention: one cached row a token",
+        "queries": "the absorbed attention scores one query a slot; a verify "
+                   "chunk has several",
+        "kv_heads": "the latent pool has no kv-head axis to shard",
+        "int8_projections": "the low-rank projections have no int8 form",
+        "image_rows": "no latent model with a vision tower is known",
+    },
+    "block": {
+        "is": "generation by diffusion over blocks of positions",
+        "scales": "the block step through an int8 pool has not been held to "
+                  "the reference",
+        "any_cut": "this cache is valid at block boundaries of the mask only; "
+                   "the host tier, a prefill-only park and the migration wire "
+                   "cover prompt[:-1], which ends inside a block",
+        "next_token": "a verify chunk drafts the NEXT token of a causal model; "
+                      "a block is denoised in place",
+        "image_rows": "image rows have no block mask",
+    },
+}
+
+
+class SlotCache:
+    """What a slot's cache is, for one engine and one model: the pools it
+    consists of, the host-side accounts of what each slot holds in them
+    (`alloc` always; `ring` with window layers, `state` with linear layers),
+    and every decision that follows from the kinds present. One class: a
+    uniform stack is the case with the paged pool alone. Like the accounts,
+    mutated by the decode scheduler thread alone."""
+
+    def __init__(self, cfg, *, slots: int, block_size: int, n_blocks: int,
+                 max_blocks_per_slot: int, kv_dtype, quant: bool = False,
+                 cache_sharding=None, scale_sharding=None):
+        self.cfg = cfg
+        self.block_length = cfg.block_length_
+        layers = cfg.cache_layers if cfg.mixed else {}
+        self.alloc = KVBlockAllocator(slots, n_blocks, block_size, max_blocks_per_slot)
+        self.ring = (WindowRing(slots, cfg.sliding_window, block_size)
+                     if layers.get("window") else None)
+        self.state = StateSlots(slots) if layers.get("state") else None
+        self._accounts = [a for a in (self.ring, self.state) if a is not None]
+        self.kinds = tuple(kind for kind, there in (
+            ("pools", cfg.mixed), ("window", self.ring), ("state", self.state),
+            ("latent", cfg.latent), ("block", self.block_length > 1)) if there)
+        # PHYSICAL bytes: an int8 pool stores 1 byte an element and one f32
+        # scale a (row, head); every byte counter downstream (host budget,
+        # swap and migration totals) derives from these
+        self._pool_dtype = jnp.dtype(jnp.int8 if quant else kv_dtype)
+        elem = self._pool_dtype.itemsize
+        if cfg.latent:
+            self._row_lanes = cfg.latent_row_lanes
+            self.row_nbytes = self._row_lanes * elem
+        else:
+            self._row_lanes = cfg.num_key_value_heads * cfg.head_dim_
+            self.row_nbytes = 2 * cfg.num_key_value_heads * (
+                cfg.head_dim_ * elem + (4 if quant else 0))
+        # (layers, blocks) of each pool of a dict of pools, by kind; None: a
+        # bare array a side. `_paged` names the one the block table addresses
+        blocks = {"full": n_blocks, "latent": n_blocks,
+                  "window": self.ring.n_blocks if self.ring else 0}
+        self._pools = {kind: (len(ls), blocks[kind]) for kind, ls in layers.items()
+                       if ls and kind in blocks} if cfg.mixed else None
+        self._paged = "latent" if cfg.latent else "full" if cfg.mixed else None
+        paged_layers = len(layers[self._paged]) if cfg.mixed else cfg.num_hidden_layers
+        self.block_nbytes = paged_layers * self.alloc.block_size * self.row_nbytes
+        # the recurrent state a slot and linear layer, row 0 the null slot
+        self._state_shapes = {
+            "S": ((len(layers["state"]), 1 + slots, cfg.linear_num_value_heads,
+                   cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+                  jnp.dtype(jnp.float32)),
+            "conv": ((len(layers["state"]), 1 + slots, cfg.linear_conv_kernel_dim - 1,
+                      cfg.linear_conv_channels), jnp.dtype(kv_dtype)),
+        } if self.state else {}
+        # bytes one linear layer's state update moves for one slot: its state
+        # and convolution rows, once in and once out
+        self.state_update_nbytes = 2 * sum(
+            int(np.prod(shape[2:])) * dtype.itemsize
+            for shape, dtype in self._state_shapes.values())
+        self._scales = (cfg.num_hidden_layers, n_blocks, cfg.num_key_value_heads,
+                        self.alloc.block_size) if quant else None
+        self._shardings = cache_sharding, scale_sharding
+        self._copies: dict[str, Any] = {}  # jitted at first use
+        # whether a request may fork the head of a donor's prefix and prefill
+        # the rest (the suffix prefill reads the donor's rows at the cut
+        # through one bare pool), and whether a prefix may be found by CONTENT
+        # (the fleet KV fabric: it ends where its blocks of the pool end)
+        self.shares_partial_prefix = self.content_addressed = not self.lacks(
+            ("one_pool", "any_cut"))
+
+    def _copy(self, fn):
+        """The copy program `fn`, jitted, the pools donated."""
+        if fn.__name__ not in self._copies:
+            self._copies[fn.__name__] = jax.jit(fn, donate_argnums=(0, 1))
+        return self._copies[fn.__name__]
+
+    def new_pools(self):
+        """(K side, V side, K scales, V scales), zeroed on the device. A side
+        is a bare `[L, n_blocks, block_size, nKV*hd]` array for a uniform
+        stack; for a stack of several kinds a dict, `{"full", "window"}` by
+        the layers there are or `{"latent"}` with an EMPTY V side, the state
+        `{"S", "conv"}` riding in the K-side dict so that every pool program
+        carries it donated. Scales (an int8 pool's, else None) are f32
+        `[L, n_blocks, nKV, block_size]`: the kv-head axis precedes
+        block_size so a Pallas scale block is (1, 1, bs) with the page size
+        on the lane dim."""
+        cache_sharding, scale_sharding = self._shardings
+
+        def pool(layers, blocks):
+            shape = (layers, blocks, self.alloc.block_size, self._row_lanes)
+            return jax.device_put(jnp.zeros(shape, self._pool_dtype), cache_sharding)
+
+        def side():
+            if self._pools is None:
+                return pool(self.cfg.num_hidden_layers, self.alloc.n_blocks)
+            return {kind: pool(*shape) for kind, shape in self._pools.items()}
+
+        k, v = side(), {} if self._paged == "latent" else side()
+        if self.state is not None:
+            k["state"] = {name: jnp.zeros(shape, dtype)
+                          for name, (shape, dtype) in self._state_shapes.items()}
+        k_scale, v_scale = (
+            jax.device_put(jnp.zeros(self._scales, jnp.float32), scale_sharding)
+            if self._scales else None for _ in range(2))
+        return k, v, k_scale, v_scale
+
+    # -- what a slot holds -------------------------------------------------
+    def cover(self, n: int) -> int:
+        """Of a sequence of `n` tokens, how many have their rows cached when
+        it is admitted: all but the last, which the chunk's first step takes
+        as its input; under a block mask the whole blocks, the rest being
+        the revealed head of the first block it denoises."""
+        B = self.block_length
+        return (n // B) * B if B > 1 else n - 1
+
+    def generated(self, rows: int, prompt_len: int) -> int:
+        """Of a slot's `rows` cached rows, how many lie past its prompt's: a
+        causal model's rows lag its tokens by one, a block's are its tokens."""
+        return rows - prompt_len + (self.block_length == 1)
+
+    def tables(self, slot: int, nb: int):
+        """What a prefill program scatters a slot's rows through: its
+        block-table row, and with it the slot's ring blocks and state row
+        where there are such."""
+        row = self.alloc.row(slot, nb)
+        tables = (row,)
+        if self.ring is not None:
+            tables += (self.ring.blocks(slot),)
+        if self.state is not None:
+            tables += (np.int32(self.state.row(slot)),)
+        return tables if len(tables) > 1 else row
+
+    def holds(self, slot: int, covered: int) -> bool:
+        """Whether `slot`'s cache can seed a request that continues at
+        `covered`: always for paged rows (they stay where they were
+        written); a ring only while it has not been written past that
+        window; a state only when it holds exactly `covered` tokens."""
+        return all(a.holds(slot, covered) for a in self._accounts)
+
+    def rewritten(self, slot: int, length: int) -> None:
+        """A prefill (or nothing at all, `length` 0) has just written the
+        slot's ring and state from scratch: they hold `length` tokens."""
+        for account in self._accounts:
+            account.reset(slot, length)
+
+    def written(self, active: np.ndarray, lengths: np.ndarray) -> None:
+        """A dispatched chunk writes the `active` slots' rings and states up
+        to their (projected) `lengths`."""
+        for account in self._accounts:
+            account.note_written(active, lengths[active])
+
+    def fork(self, src: int, dst: int, covered: int) -> list[tuple]:
+        """Point `dst` at `src`'s first `covered` tokens (the caller has
+        asked `holds`): the donor's full blocks aliased in the table, and
+        the device copies that remain, as (program, *operands) for the
+        caller to run in order on the pools: the boundary's partial block,
+        the donor's ring pages, its state rows. `src == dst` copies nothing.
+        Operands are NumPy: no eager device op ahead of a copy. Raises
+        PoolDry when the boundary block cannot be allocated."""
+        copies = []
+        cp = self.alloc.fork(src, dst, covered)
+        if cp is not None:
+            copies.append((self._copy(fork_block), np.int32(cp[0]), np.int32(cp[1])))
+        if src == dst:
+            return copies
+        if self.ring is not None:
+            copies.append((self._copy(fork_ring),
+                           self.ring.blocks(src), self.ring.blocks(dst)))
+            # what the donor's ring was written up to is what the copy holds
+            self.ring.reset(dst, int(self.ring.hi[src]))
+        if self.state is not None:
+            copies.append((self._copy(fork_state), np.int32(self.state.row(src)),
+                           np.int32(self.state.row(dst))))
+            self.state.reset(dst, int(self.state.count[src]))
+        return copies
+
+    def zero(self, slot: int) -> list[tuple]:
+        """The slot starts a request with no prefill: its ring and state hold
+        nothing, and the state's rows go back to zero (the copy to run, as
+        `fork`'s)."""
+        self.rewritten(slot, 0)
+        if self.state is None:
+            return []
+        return [(self._copy(zero), np.int32(self.state.row(slot)))]
+
+    # -- what a chunk walks and reads -------------------------------------------
+    def walk(self, lengths: np.ndarray, nb: int, W: int = 1) -> tuple[np.ndarray, int]:
+        """(live block columns of each slot, columns a loop iteration scores
+        together) for the paged read of a chunk whose slots end at `lengths`
+        over a table of `nb` columns, `W` queries a slot:
+        `ops/paged_attention.live_block_range` on the host. A slot has the
+        columns up to its last query's, less those wholly before a uniform
+        stack's window (a ring is not in the table)."""
+        bsz = self.alloc.block_size
+        last = lengths.astype(np.int64) - 1
+        live = np.minimum(last // bsz + 1, nb)
+        window = self.cfg.sliding_window
+        if window is not None and self.ring is None:
+            live -= np.maximum(last - window + 1, 0) // bsz
+        # (imported here as the model imports them: a process that never
+        # dispatches a chunk never imports Pallas)
+        if self._paged == "latent":
+            from areal_tpu.ops.paged_attention_latent import PAGES_PER_GROUP
+
+            return live, PAGES_PER_GROUP
+        from areal_tpu.ops.paged_attention import group_pages
+
+        return live, group_pages(bsz, self._row_lanes, self._pool_dtype.itemsize, W, nb)
+
+    def rows_read(self, tail: list) -> dict[str, int]:
+        """A chunk's counters of cached rows read, by kind, from the tail of
+        what a mixed stack's program returns (models/qwen2.py): [full rows,
+        window rows] and then the latent rows or the linear layers' state
+        updates."""
+        latent = self._paged == "latent"
+        return {"full": tail[0], "window": tail[1], "latent": tail[2] if latent else 0,
+                "state": 0 if latent else sum(tail[2:])}
+
+    # -- what it cannot serve ----------------------------------------------------
+    def lacks(self, needs, config=None) -> list[str]:
+        """For each of `needs` that a kind present lacks: "needs ...: why"."""
+        out = []
+        for kind in self.kinds:
+            for need in needs:
+                why = KINDS[kind].get(need)
+                if callable(why):
+                    why = why(self, config)
+                if why:
+                    out.append(f"needs {_NEEDS[need]}: {why}")
+        return out
+
+    def _refuse(self, refused: list[str]) -> None:
+        if refused:
+            what = "; ".join(KINDS[kind]["is"] for kind in self.kinds)
+            raise NotImplementedError(
+                f"{self.cfg.model_type} ({what}) is not served with: "
+                + "; ".join(refused)
+            )
+
+    def unserved(self, config, *, vision: bool = False,
+                 weight_quant: bool = False) -> None:
+        """Raise NotImplementedError, every reason in words, if `config` (or
+        a vision tower, or int8 weights) asks for a mechanism that needs of
+        the cache what a kind present lacks: at `initialize()`, and not at
+        the first request that needs it."""
+        refused = []
+        if "block" in self.kinds and (
+            len(self.kinds) > 1 or self.cfg.sliding_window is not None
+        ):
+            refused.append(
+                "window, linear, latent or leading dense layers: the block step "
+                "reads one paged pool under the block-causal mask alone"
+            )
+        for setting, needs in MECHANISMS.values():
+            asked = setting(config, vision, weight_quant)
+            if asked:
+                refused += [f"{asked} {why}" for why in self.lacks(needs, config)]
+        self._refuse(refused)
+
+    def unserved_call(self, what: str, mechanism: str) -> None:
+        """The same for a call `what` that is `mechanism` whatever the config
+        says: a session's export or import on a unified engine."""
+        needs = MECHANISMS[mechanism][1]
+        self._refuse([f"{what} ({mechanism}) {why}" for why in self.lacks(needs)])
 
 
 @dataclass

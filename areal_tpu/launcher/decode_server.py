@@ -359,7 +359,7 @@ class DecodeServer:
         )
         out["weight_sync"] = ws
         # rid-dedup observability: table occupancy + duplicate deliveries
-        # prevented (the exactly-once evidence bench --mode fleet reads)
+        # prevented (the exactly-once evidence tests/test_chaos.py reads)
         out["idem_entries"] = len(self._idem)
         out["idem_hits_total"] = self._idem_hits
         # KV-migration observability (server side): sessions/bytes
@@ -1405,8 +1405,8 @@ def main(argv: list[str] | None = None) -> None:
              "~2x the resident "
              "sessions per MB, and swaps/migration ship the quantized "
              "bytes as-is (mixed-dtype fleets reject imports as honest "
-             "misses). Drift is measured (bench.py --mode kvquant), not "
-             "assumed zero",
+             "misses). Drift is bounded at a tiny preset "
+             "(tests/test_kv_quant.py), unmeasured at a real model's widths",
     )
     p.add_argument(
         "--weight-dtype",
@@ -1418,8 +1418,9 @@ def main(argv: list[str] | None = None) -> None:
              "push wire bytes ~halve, decode runs the fused dequant-matmul "
              "(Pallas on TPU). The trainer's WeightUpdateMeta.weight_dtype "
              "must match: quantized kernels travel as '.../q' + "
-             "'.../scale' wire leaves. Drift is measured (bench.py --mode "
-             "wquant), not assumed zero",
+             "'.../scale' wire leaves. Drift is bounded at a tiny preset "
+             "(tests/test_weight_quant.py), unmeasured at a real model's "
+             "widths",
     )
     p.add_argument(
         "--kv-host-pool-mb",

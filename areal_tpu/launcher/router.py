@@ -1160,8 +1160,8 @@ class DecodeRouter:
     async def _metrics(self, request: web.Request) -> web.Response:
         """Routing observability: queue/shedding state, affinity quality,
         failover activity, and the per-server pressure snapshots the
-        admission controller is acting on — what `bench.py --mode fleet`
-        and the ops layer read to judge routing quality."""
+        admission controller is acting on — what the ops layer reads to
+        judge routing quality."""
         async with self._lock:
             sched = self._counters["schedules_total"]
             hits = self._counters["affinity_hits_total"]

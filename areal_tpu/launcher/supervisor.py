@@ -15,7 +15,7 @@ exactly-once (ISSUE 8), deadlines and circuit breakers bound failure
      bands, per-action cooldowns, a min-capacity floor no plan may
      violate, at most one disruptive action in flight — and
   4. executes the plan through two seams: a `spawn_fn(role) -> handle`
-     launcher callback (in-process replicas in bench.py, decode-server
+     launcher callback (in-process replicas in tests, decode-server
      subprocesses via LocalLauncher.spawn_decode_server) and plain HTTP
      against the replicas (/drain, /set_role) + `handle.kill()`.
 
@@ -326,8 +326,8 @@ def plan_actions(
 # -- executor ------------------------------------------------------------
 class ReplicaHandle(Protocol):
     """What `spawn_fn` must return: a live replica's address plus a way
-    to destroy it. bench.py wraps its in-process replicas in this shape;
-    LocalLauncher.spawn_decode_server returns a subprocess-backed one."""
+    to destroy it. LocalLauncher.spawn_decode_server returns a
+    subprocess-backed one; tests/test_supervisor.py wraps stubs in it."""
 
     addr: str
 
@@ -1047,7 +1047,7 @@ class FleetSupervisor:
     def build_app(self) -> web.Application:
         app = web.Application()
         app.router.add_get("/health", self._health)
-        # wire: external — ops/bench surface (bench.py chaos report polls it)
+        # wire: external — ops surface (polled from outside the tree)
         app.router.add_get("/supervisor", self._supervisor_metrics)
         return app
 

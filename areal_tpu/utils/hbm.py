@@ -241,8 +241,7 @@ def estimate_train_hbm(
     microbatch's forward and its backward — 2*pp-1 entries for "1f1b",
     `virtual_pp`*(2*pp-1) *chunk* inputs for "1f1b_interleaved" (each 1/v
     the layers but a full [T_local, d] activation, so the stash bytes grow
-    ~v times while the bubble shrinks ~1/v: that trade is exactly what
-    bench --mode ppsched measures).
+    ~v times while the bubble shrinks ~1/v: unmeasured on the chip).
     """
     n = param_count(model_cfg)
     pbytes = _dtype_bytes(getattr(model_cfg, "param_dtype", "float32"))
@@ -310,7 +309,7 @@ def estimate_decode_hbm(
     matmul kernels at 1 byte/element plus one f32 scale per output channel
     instead of param_dtype; the per-chip bytes that frees vs fp serving
     surface as `wquant_freed_gib` in breakdown() — at a fixed HBM budget
-    that headroom goes to a larger resident KV pool (bench --mode wquant).
+    that headroom goes to a larger resident KV pool.
     """
     n = param_count(model_cfg)
     pbytes = _dtype_bytes(getattr(model_cfg, "param_dtype", "bfloat16"))

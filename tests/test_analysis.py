@@ -167,8 +167,8 @@ def test_ar301_route_pairing():
 
 
 def test_ar301_client_only_sweep_stays_quiet(tmp_path):
-    """No registrations harvested -> pairing cannot be judged; a bench.py
-    style client-only sweep must not drown in unregistered-path noise."""
+    """No registrations harvested -> pairing cannot be judged; a
+    client-only sweep must not drown in unregistered-path noise."""
     mod = tmp_path / "client.py"
     mod.write_text(
         "async def poll(arequest_with_retry, addr):\n"

@@ -622,7 +622,7 @@ def test_config_arms_global_injector():
     assert fault_injection.get() is not None
     with pytest.raises(InjectedFault):
         fault_injection.fire("cfg.site")
-    # disabled config does NOT clear an armed injector (the bench arms
+    # disabled config does NOT clear an armed injector (a chaos run arms
     # globally, then builds clients with default configs)
     RemoteInfEngine(InferenceEngineConfig())
     assert fault_injection.get() is not None

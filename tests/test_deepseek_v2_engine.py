@@ -138,22 +138,22 @@ def test_what_initialize_refuses_for_every_dict_of_pools(params, over, why):
 
 
 @pytest.mark.parametrize("over,why", [
-    (dict(spec_decode="ngram"), "spec_decode='ngram': the absorbed attention scores one query"),
-    (dict(weight_dtype="int8"), "weight_dtype='int8': the low-rank projections"),
+    (dict(spec_decode="ngram"), "spec_decode='ngram' needs .*: the absorbed attention scores one query"),
+    (dict(weight_dtype="int8"), "weight_dtype='int8' needs .*: the low-rank projections"),
 ])
 def test_what_initialize_refuses_for_a_latent_model(params, over, why):
     with pytest.raises(NotImplementedError, match=why):
         _engine(FULL, params, **over).initialize()
 
 
-def test_tensor_parallel_decode_is_refused(params):
-    from areal_tpu.engine.jax_decode import JaxDecodeEngine
+def test_tensor_parallel_decode_is_refused():
+    from areal_tpu.api.cli_args import JaxDecodeConfig
+    from areal_tpu.engine.kv_pool import SlotCache
 
-    engine = _engine(FULL, params, tensor_parallel_size=2)
-    engine._w_quant = False
-    engine.model_config = FULL
+    cache = SlotCache(FULL, slots=4, block_size=4, n_blocks=65, max_blocks_per_slot=64,
+                      kv_dtype="float32")
     with pytest.raises(NotImplementedError, match="no kv-head axis to shard"):
-        JaxDecodeEngine._refuse_unserved_for_latent(engine)
+        cache.unserved(JaxDecodeConfig(tensor_parallel_size=2))
 
 
 def test_migration_and_the_verify_step_refuse(params):

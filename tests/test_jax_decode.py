@@ -858,7 +858,7 @@ def test_prewarm_compiles_all_wave_variants(cpu_devices):
         assert set(eng._batched_prefill_fns) >= {(64, 4), (64, 2), (64, 1)}
         # both sampler variants (top_p == 1 and top_p < 1) compiled
         assert {k[0] for k in eng._chunk_fns} == {False, True}, eng._chunk_fns
-        assert True in eng._fork_fns, "dup-fork block copy not compiled"
+        assert "fork_block" in eng._slot_cache._copies, "dup-fork block copy not compiled"
         # misconfiguration must fail loudly, not silently warm nothing
         with pytest.raises(ValueError, match="length-rejected"):
             eng.prewarm(prompt_len=96, new_tokens=4)

@@ -438,11 +438,27 @@ async def _start_server(engine, dcfg):
     return srv, addr
 
 
-def test_kv_fetch_peer_to_peer_remote_hit_bit_identity():
+@pytest.mark.parametrize("fault", [None, "torn", "abort"])
+def test_kv_fetch_peer_to_peer_remote_hit_bit_identity(fault):
     """Replica A holds the prompt's blocks; replica B receives the
     /generate carrying the router's fetch hint, pulls the run from A over
     /kv_fetch, and serves the request with a suffix prefill instead of a
-    full one — bit-identically, with remote attribution."""
+    full one — bit-identically, with remote attribution. Under fire
+    (the `kv.migrate.*` seams, as a migration's): a TORN first frame is
+    re-covered by the frame retry and lands the run exactly once; an
+    ABORT of every send attempt, past the replay budget, makes A abandon
+    the stream and B degrade to a local full prefill, bit-identically,
+    with no fabric session imported."""
+    from areal_tpu.core import fault_injection
+    from areal_tpu.core.fault_injection import FaultPlan, FaultPoint
+
+    plan = {
+        None: None,
+        "torn": FaultPlan(seed=3, points=[
+            FaultPoint(site="kv.migrate.recv", mode="torn", at=(0,), times=1)]),
+        "abort": FaultPlan(seed=4, points=[
+            FaultPoint(site="kv.migrate.send", mode="abort", at=(0, 1, 2), times=3)]),
+    }[fault]
     prompt = _prompt(96, seed=41)
     oracle = _engine(fabric=False)
     try:
@@ -454,6 +470,7 @@ def test_kv_fetch_peer_to_peer_remote_hit_bit_identity():
         oracle.destroy()
     a = _engine()
     b = _engine()
+    fired: dict = {}
 
     async def scenario():
         sa, aa = await _start_server(a, a.config)
@@ -470,18 +487,24 @@ def test_kv_fetch_peer_to_peer_remote_hit_bit_identity():
             )
             chain = _chain_of(a, prompt[: len(prompt) - 1])
             assert len(chain) >= 8
-            out = await arequest_with_retry(
-                ba, "/generate",
-                payload=dict(
-                    rid="f2",
-                    input_ids=prompt,
-                    gconfig=dict(max_new_tokens=10, greedy=True),
-                    kv_fabric=dict(
-                        peer=aa, keys=kv_fabric.encode_digest(chain)
+            if plan is not None:
+                fault_injection.configure(plan)
+            try:
+                out = await arequest_with_retry(
+                    ba, "/generate",
+                    payload=dict(
+                        rid="f2",
+                        input_ids=prompt,
+                        gconfig=dict(max_new_tokens=10, greedy=True),
+                        kv_fabric=dict(
+                            peer=aa, keys=kv_fabric.encode_digest(chain)
+                        ),
                     ),
-                ),
-                max_retries=1, timeout=120,
-            )
+                    max_retries=1, timeout=120,
+                )
+            finally:
+                fired.update(fault_injection.snapshot() if plan else {})
+                fault_injection.deactivate()
             ma = await arequest_with_retry(
                 aa, "/metrics", method="GET", max_retries=1, timeout=30
             )
@@ -504,7 +527,20 @@ def test_kv_fetch_peer_to_peer_remote_hit_bit_identity():
     assert out["output_logprobs"] == pytest.approx(
         ro.output_logprobs, abs=1e-5
     )
+    if fault == "abort":
+        # every send attempt died: A abandoned the stream, B imported no
+        # session (no torn half-run ever serves) and paid one honest prefill
+        assert sum(v for k, v in fired.items() if k.startswith("kv.migrate.send")) >= 3
+        assert ma["kv_migrate"]["out_failures"] >= 1
+        assert mb["kv_fabric_sessions_in_total"] == 0
+        assert mb["kv_fabric_remote_hits_total"] == 0
+        assert mb["kv_fabric_local_hits_total"] == 0
+        assert mb["prefills_total"] == 1
+        return
+    if fault == "torn":
+        assert any(k.startswith("kv.migrate.recv") for k in fired), fired
     # server-side accounting: A served the run, B fetched + promoted it
+    # (exactly once, a torn frame replayed or not)
     assert ma["kv_fabric"]["serve_sessions"] == 1
     assert ma["kv_fabric"]["serve_bytes"] > 0
     assert mb["kv_fabric"]["fetch_sessions"] == 1

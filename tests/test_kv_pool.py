@@ -343,3 +343,266 @@ def test_reclaim_never_eats_inflight_donor(cpu_devices):
         assert c.tokens == greedy_reference(params, prompt_a, 4)
     finally:
         eng.destroy()
+
+
+# -- what a slot's cache is: `SlotCache`'s contract, a case a kind of cache ----
+# (no engine and no device program; `new_pools` alone touches the device)
+
+import json  # noqa: E402
+import os  # noqa: E402
+
+from test_deepseek_v2 import FULL as LATENT  # noqa: E402
+from test_kexaone import FULL as RING  # noqa: E402 — window 8: 3 pages of 4, slack 4
+from test_qwen3next import FULL as STATE  # noqa: E402
+from test_sdar import CFG as BLOCK  # noqa: E402 — blocks of 4
+
+from areal_tpu.engine import kv_pool  # noqa: E402
+from areal_tpu.engine.kv_pool import SlotCache  # noqa: E402
+
+CACHES = {"uniform": TINY, "ring": RING, "state": STATE, "latent": LATENT, "block": BLOCK}
+KINDS = sorted(CACHES)
+R, BS, NB = 4, 4, 65
+
+
+def _cache(kind, **over):
+    kw = dict(slots=R, block_size=BS, n_blocks=NB, max_blocks_per_slot=16, kv_dtype="float32")
+    return SlotCache(CACHES[kind], **{**kw, **over})
+
+
+def _published(name, **over):
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark/configs", name + ".json")) as f:
+        return SlotCache(ModelConfig.from_hf_config(json.load(f)), slots=2, block_size=128,
+                         n_blocks=11, max_blocks_per_slot=10, kv_dtype="bfloat16", **over)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_cache_kinds_and_what_is_cached_at_admission(kind):
+    cache = _cache(kind)
+    assert cache.kinds == {"uniform": (), "ring": ("pools", "window"), "state": ("pools", "state"),
+                           "latent": ("pools", "latent"), "block": ("block",)}[kind]
+    if kind == "block":
+        # whole blocks; a slot's rows are its tokens
+        assert [cache.cover(n) for n in (3, 4, 9, 16)] == [0, 4, 8, 16]
+        assert cache.generated(16, 9) == 7
+    else:
+        # all but the last token; rows lag tokens by one
+        assert [cache.cover(n) for n in (1, 9, 16)] == [0, 8, 15]
+        assert cache.generated(15, 9) == 7
+    assert cache.shares_partial_prefix == cache.content_addressed == (kind == "uniform")
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_what_a_prefill_scatters_through(kind):
+    cache = _cache(kind)
+    assert cache.alloc.ensure(2, 10)
+    tables = cache.tables(2, 3)
+    row = tables[0] if isinstance(tables, tuple) else tables
+    np.testing.assert_array_equal(row, cache.alloc.row(2, 3))
+    if kind == "ring":
+        assert len(tables) == 2
+        np.testing.assert_array_equal(tables[1], cache.ring.blocks(2))
+    elif kind == "state":
+        assert len(tables) == 2 and tables[1] == cache.state.row(2) == 3
+        assert tables[1].dtype == np.int32
+    else:
+        assert not isinstance(tables, tuple)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_whether_a_slot_still_holds_a_prefix(kind):
+    cache = _cache(kind)
+    cache.rewritten(1, 20)
+    assert cache.holds(1, 20)
+    # a chunk of 4 dispatched: the state has absorbed 24 tokens and is good for
+    # 24 alone; the ring (window 8, 12 rows) still has rows 13..19
+    lengths = np.array([0, 24, 0, 0])
+    active = np.arange(R) == 1
+    cache.written(active, lengths)
+    assert cache.holds(1, 20) == (kind != "state")
+    assert cache.holds(1, 24)
+    # written past the window's room: rows 13.. are gone from the ring
+    cache.written(active, np.array([0, 40, 0, 0]))
+    assert cache.holds(1, 20) == (kind not in ("state", "ring"))
+    # the slot starts over with no prefill
+    copies = cache.zero(1)
+    assert [fn.__name__ for fn, *_ in copies] == (["zero"] if kind == "state" else [])
+    assert cache.holds(1, 0) and cache.holds(1, 20) == (kind in ("uniform", "latent", "block"))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_what_a_fork_aliases_and_what_it_copies(kind, monkeypatch):
+    made = []
+    monkeypatch.setattr(jax, "jit", lambda fn, **kw: made.append((fn.__name__, kw)) or fn)
+    cache = _cache(kind)
+    assert cache.alloc.ensure(0, 12)
+    cache.rewritten(0, 10)
+    extra = {"ring": ["fork_ring"], "state": ["fork_state"]}.get(kind, [])
+    # 10 rows: two blocks aliased, the third (2 rows) copied
+    copies = cache.fork(0, 1, 10)
+    assert [fn.__name__ for fn, *_ in copies] == ["fork_block"] + extra
+    assert all(isinstance(x, (np.ndarray, np.generic)) for _, *ops in copies for x in ops)
+    np.testing.assert_array_equal(cache.alloc.tables[1, :2], cache.alloc.tables[0, :2])
+    assert copies[0][1] == cache.alloc.tables[0, 2] and copies[0][2] == cache.alloc.tables[1, 2]
+    # the copy holds what the donor held: a state its one length
+    assert cache.holds(1, 10) and cache.holds(1, 8) == (kind != "state")
+    if kind == "ring":
+        np.testing.assert_array_equal(copies[1][1], cache.ring.blocks(0))
+        np.testing.assert_array_equal(copies[1][2], cache.ring.blocks(1))
+    if kind == "state":
+        assert copies[1][1:] == (1, 2)
+    # a block-aligned boundary copies no block; a slot onto itself nothing at all
+    assert [fn.__name__ for fn, *_ in cache.fork(0, 2, 8)] == extra
+    assert cache.fork(0, 0, 10) == []
+    # each program jitted once, at its first use, the pools donated
+    assert made == [(name, {"donate_argnums": (0, 1)}) for name in ["fork_block"] + extra]
+    # no block for the boundary: PoolDry, and the accounts of dst untouched
+    small = _cache(kind, n_blocks=17)
+    assert small.alloc.ensure(0, 64)
+    small.rewritten(0, 10), small.rewritten(3, 5)
+    with pytest.raises(PoolDry):
+        small.fork(0, 3, 10)
+    assert small.holds(3, 5)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_pools_are_the_trees_the_programs_take(kind):
+    cfg = CACHES[kind]
+    cache = _cache(kind)
+    k, v, ks, vs = cache.new_pools()
+    assert ks is None and vs is None
+    shapes = lambda tree: jax.tree.map(lambda a: (a.shape, str(a.dtype)), tree)  # noqa: E731
+    row = (BS, cfg.num_key_value_heads * cfg.head_dim_)
+    L = cfg.num_hidden_layers
+    if kind in ("uniform", "block"):
+        assert shapes(k) == shapes(v) == ((L, NB, *row), "float32")
+    elif kind == "ring":
+        want = {"full": ((2, NB, *row), "float32"), "window": ((7, 1 + R * 3, *row), "float32")}
+        assert shapes(k) == shapes(v) == want
+    elif kind == "state":
+        assert shapes(v) == {"full": ((2, NB, *row), "float32")}
+        assert shapes(k) == {**shapes(v), "state": {
+            "S": ((6, 1 + R, 8, 16, 16), "float32"), "conv": ((6, 1 + R, 3, 256), "float32")}}
+    else:
+        assert shapes(k) == {"latent": ((L, NB, BS, cfg.latent_row_lanes), "float32")} and v == {}
+    assert all(not np.asarray(a).any() for a in jax.tree.leaves((k, v)))
+    # what the counter walks is what the kernel is handed: the group of pages
+    # from the paged pool's shapes, the live columns of each slot
+    from areal_tpu.ops import paged_attention, paged_attention_latent
+
+    live, pages = cache.walk(np.array([1, 9, 64]), 16)
+    np.testing.assert_array_equal(live, [1, 3, 16])
+    if kind == "latent":
+        assert pages == paged_attention_latent.PAGES_PER_GROUP
+    else:
+        pool = k["full"] if isinstance(k, dict) else k
+        assert pages == paged_attention.pool_group_pages(pool, 1, 16)
+        assert cache.walk(np.array([9]), 16, W=4)[1] == paged_attention.pool_group_pages(pool, 4, 16)
+
+
+def test_an_int8_pool_has_its_scale_pools_and_a_uniform_window_its_columns():
+    k, v, ks, vs = _cache("uniform", quant=True).new_pools()
+    assert k.dtype == v.dtype == np.int8 and k.shape == (2, NB, BS, 16)
+    assert ks.shape == vs.shape == (2, NB, 2, BS) and ks.dtype == np.float32
+    # a uniform stack under a window keeps no ring: the columns wholly before
+    # the window are not walked
+    import dataclasses
+
+    windowed = SlotCache(dataclasses.replace(TINY, sliding_window=8), slots=R, block_size=BS,
+                         n_blocks=NB, max_blocks_per_slot=16, kv_dtype="float32")
+    assert windowed.kinds == () and windowed.ring is None
+    np.testing.assert_array_equal(windowed.walk(np.array([1, 9, 64]), 16)[0], [1, 3, 2])
+
+
+@pytest.mark.parametrize("kind,tail,want", [
+    ("ring", [5, 7], dict(full=5, window=7, latent=0, state=0)),
+    ("state", [5, 0, 3, 4], dict(full=5, window=0, latent=0, state=7)),
+    ("latent", [0, 0, 9], dict(full=0, window=0, latent=9, state=0)),
+])
+def test_a_chunks_counters_of_rows_read_by_kind(kind, tail, want):
+    assert _cache(kind).rows_read(tail) == want
+
+
+@pytest.mark.parametrize("name,over,row,block,state_update", [
+    # K and V of 2 kv heads of 128 in bf16, 28 layers; int8 with an f32 scale a head
+    ("qwen2.5-1.5b", {}, 1024, 28 * 128 * 1024, 0),
+    ("qwen2.5-1.5b", dict(quant=True), 2 * 2 * (128 + 4), 28 * 128 * 528, 0),
+    # PERF.md section 3: 576 lanes stored as 640, one row and no V side
+    ("deepseek-v2", {}, 1280, None, 0),
+    # PERF.md section 3: a linear layer's state update a slot, in and out
+    ("qwen3-next-80b-a3b", {}, 2 * 2 * 256 * 2, None, 4_292_608),
+    ("k-exaone-236b-a23b", {}, 2 * 8 * 128 * 2, None, 0),
+    ("sdar-30b-a3b-chat", {}, 2 * 4 * 128 * 2, None, 0),
+])
+def test_bytes_at_published_widths(name, over, row, block, state_update):
+    cache = _published(name, **over)
+    cfg = cache.cfg
+    assert cache.row_nbytes == row and cache.state_update_nbytes == state_update
+    paged = (cfg.cache_layers["latent" if cfg.latent else "full"] if cfg.mixed
+             else range(cfg.num_hidden_layers))
+    assert cache.block_nbytes == (block or len(paged) * 128 * row)
+
+
+# What the parent's three hand-written refusal functions refused (R) and served
+# (.) at `initialize()`, by kind of cache; the ring's slack is 4 rows, so a
+# verify chunk of spec_k=2 fits it and one of spec_k=8 does not.
+MECHANISMS = {
+    "kv_dtype": dict(kv_dtype="int8"),
+    "host tier": dict(kv_host_pool_mb=16.0),
+    "migration": dict(role="prefill"),
+    "spec_k=2": dict(spec_decode="ngram", spec_k=2),
+    "spec_k=8": dict(spec_decode="ngram", spec_k=8),
+    "tensor_parallel": dict(tensor_parallel_size=2),
+    "weight_dtype": dict(weight_dtype="int8"),
+    "vision": {},
+}
+REFUSED = {
+    #           kv_dtype host migr spec2 spec8 tp   w8   vision
+    "uniform": ". . . . . . . .",
+    "ring":    "R R R . R . . .",
+    "state":   "R R R R R . . .",
+    "latent":  "R R R R R R R R",
+    "block":   "R R R R R . . R",
+}
+
+
+@pytest.mark.parametrize("mechanism", list(MECHANISMS))
+@pytest.mark.parametrize("kind", KINDS)
+def test_what_each_cache_cannot_serve(kind, mechanism):
+    config = JaxDecodeConfig(**MECHANISMS[mechanism])
+    asked = dict(vision=mechanism == "vision", weight_quant=mechanism == "weight_dtype")
+    refused = REFUSED[kind].split()[list(MECHANISMS).index(mechanism)] == "R"
+    cache = _cache(kind)
+    if not refused:
+        cache.unserved(config, **asked)
+        return
+    with pytest.raises(NotImplementedError, match="is not served with: .* needs .*: ") as e:
+        cache.unserved(config, **asked)
+    # the reason names the setting, and the default config is served
+    setting = "a vision tower" if mechanism == "vision" else next(iter(MECHANISMS[mechanism]))
+    assert setting in str(e.value) and CACHES[kind].model_type in str(e.value)
+    cache.unserved(JaxDecodeConfig())
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_migration_calls_are_refused_whatever_the_role(kind):
+    cache = _cache(kind)
+    if kind == "uniform":
+        cache.unserved_call("export_session", "migration")
+        return
+    with pytest.raises(NotImplementedError, match=r"export_session \(migration\) needs"):
+        cache.unserved_call("export_session", "migration")
+
+
+def test_a_block_mask_over_any_other_kind_is_refused_and_every_need_has_its_words():
+    import dataclasses
+
+    for cfg in (dataclasses.replace(BLOCK, sliding_window=8),
+                dataclasses.replace(RING, block_length=4, mask_token_id=3)):
+        cache = SlotCache(cfg, slots=R, block_size=BS, n_blocks=NB, max_blocks_per_slot=16,
+                          kv_dtype="float32")
+        with pytest.raises(NotImplementedError, match="one paged pool under the block-causal"):
+            cache.unserved(JaxDecodeConfig())
+    needs = {n for _, ns in kv_pool.MECHANISMS.values() for n in ns}
+    assert needs == set(kv_pool._NEEDS)
+    assert {n for lacks in kv_pool.KINDS.values() for n in lacks} - {"is"} <= needs

@@ -1,12 +1,10 @@
-"""Prewarm/startup hardening (ADVICE r05 #1-#4) — tier-1, CPU, fast.
+"""Prewarm/startup hardening (round 5's review) — tier-1, CPU, fast.
 
 1. decode_server binds its HTTP listener only AFTER prewarm finishes, so a
    request or /pause can never land mid-warmup.
 2. prewarm's load-bearing guards are RuntimeError, not assert — `python -O`
    must not silently cancel an externally held pause.
-3. bench's pause-latency probe records a -1 sentinel instead of timing an
-   idle-engine pause when the load window is missed.
-4. prewarm warns when a wave's promised batched-prefill variant never
+3. prewarm warns when a wave's promised batched-prefill variant never
    compiled (KV-pool pressure split the wave).
 """
 
@@ -132,18 +130,3 @@ def test_server_start_without_prewarm_unchanged():
 
     asyncio.run(run())
     assert [c[0] for c in stub.calls] == []  # not owned: no engine calls
-
-
-def test_bench_wait_for_running_sentinel():
-    import bench
-
-    class _Idle:
-        def get_metrics(self):
-            return {"running_requests": 0}
-
-    class _Busy:
-        def get_metrics(self):
-            return {"running_requests": 2}
-
-    assert bench._wait_for_running(_Busy(), timeout_s=1.0) is True
-    assert bench._wait_for_running(_Idle(), timeout_s=0.05) is False

@@ -145,12 +145,12 @@ def test_a_donor_that_has_not_decoded_is_forked_and_one_token_later_is_not(param
             return item
 
         admit("a")
-        assert engine._state.holds(0, 39)
+        assert engine._slot_cache.state.holds(0, 39)
         admit("b")
         assert engine._n_prefills == 1 and engine._n_prefix_forks == 1
         with engine._sched_lock:
             engine._run_chunk(engine._active_mask())  # one token for both
-        assert not engine._state.holds(0, 39) and engine._state.holds(0, 40)
+        assert not engine._slot_cache.state.holds(0, 39) and engine._slot_cache.state.holds(0, 40)
         admit("c")
         assert engine._n_prefills == 2 and engine._n_prefix_forks == 1
     finally:
@@ -182,14 +182,14 @@ def test_a_parked_slot_is_resumed_only_at_its_exact_length(params):
         item = run("r1", prompt, 1)
         assert engine.abort_all() == 1 and item.stop_reason == "interrupt"
         slot, covered, _ = engine._parked["r1"]
-        assert covered == 29 + 4 and engine._state.holds(slot, covered)
+        assert covered == 29 + 4 and engine._slot_cache.state.holds(slot, covered)
         before = engine._n_prefills
         run("r1", prompt + item.tokens, 1)
         assert engine._n_prefills == before  # resumed in place
         item2 = run("r2", _ids(16, 30).tolist(), 1)
         engine.abort_all()
         slot, covered, _ = engine._parked["r2"]
-        engine._state.note_written(np.arange(4) == slot, np.array([covered + 4]))  # ran ahead
+        engine._slot_cache.state.note_written(np.arange(4) == slot, np.array([covered + 4]))  # ran ahead
         before = engine._n_prefills
         run("r2", list(item2.prompt) + item2.tokens, 1)
         assert engine._n_prefills == before + 1

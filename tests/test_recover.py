@@ -315,9 +315,16 @@ def test_check_if_auto_recover_reports_half_deleted_dir(tmp_path):
     assert RecoverHandler(cfg, FT).load(_FakeStateEngine()) is None
 
 
-def test_dump_failure_degrades_not_raises(tmp_path):
-    """A failed dump (here: injected abort mid-save) logs + counts + leaves
-    the previous committed step intact; the loop keeps training."""
+@pytest.mark.parametrize("site", [
+    "recover.dump.save",    # mid engine.save: a torn tmp dir left behind
+    "recover.dump.info",    # saved, the recover info not yet written
+    "recover.dump.marker",  # the save-vs-marker gap: sealed but uncommitted
+])
+def test_dump_failure_degrades_not_raises(tmp_path, site):
+    """A failed dump (an injected abort at each of its seams: a trainer
+    killed there leaves the same files) logs + counts + leaves the
+    previous committed step intact; the loop keeps training, and a restart
+    resumes from that step with its engine version."""
     from areal_tpu.core.fault_injection import (
         FaultPlan, FaultPoint, configure, deactivate,
     )
@@ -328,7 +335,7 @@ def test_dump_failure_degrades_not_raises(tmp_path):
     eng = _FakeStateEngine(weight=7.0)
     h.dump(eng, _si(0), force=True)
     configure(FaultPlan(seed=1, points=[
-        FaultPoint(site="recover.dump.save", mode="abort", times=1)
+        FaultPoint(site=site, mode="abort", times=1)
     ]))
     try:
         assert h.dump(eng, _si(1), force=True) is None
@@ -336,8 +343,11 @@ def test_dump_failure_degrades_not_raises(tmp_path):
         deactivate()
     assert get_metrics().get("recover_dump_failures_total", 0) == 1
     # the crashed attempt is a .tmp dir, never a candidate; step-0 loads
-    info = RecoverHandler(cfg, FT).load(_FakeStateEngine())
+    eng2 = _FakeStateEngine()
+    info = RecoverHandler(cfg, FT).load(eng2)
     assert info is not None and info.last_step_info.global_step == 0
+    assert info.last_step_info.next().global_step == 1  # resume lands on the killed step
+    assert eng2.weight == 7.0 and eng2.get_version() == eng.get_version()
     # and the next gate retries successfully, replacing the torn tmp
     assert h.dump(eng, _si(1), force=True) is not None
 

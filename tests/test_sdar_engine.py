@@ -244,7 +244,7 @@ def test_an_interrupt_inside_a_block_returns_committed_blocks(params):
         assert engine.abort_all() == 1 and item.stop_reason == "interrupt"
         assert item.tokens == ref.tokens[:6] and len(item.reveal_steps) == 6
         slot, covered, _ = engine._parked["r1"]
-        assert covered == 16 == engine._cover(len(prompt) + len(item.tokens))
+        assert covered == 16 == engine._slot_cache.cover(len(prompt) + len(item.tokens))
         before = engine._n_prefills
         rest = run("r1", prompt + item.tokens, 3, n=8)
         assert engine._n_prefills == before  # resumed in place
@@ -373,7 +373,7 @@ def test_requests_and_calls_that_are_refused(params):
         for call in (lambda: engine.export_session("x"),
                      lambda: engine.import_session({}, None, None),
                      lambda: engine.export_fabric_blocks([])):
-            with pytest.raises(NotImplementedError, match="block boundary"):
+            with pytest.raises(NotImplementedError, match="block boundaries"):
                 call()
         assert engine._fabric_on is False
     finally:

@@ -62,7 +62,7 @@ def _echo_params():
     deterministic last-token map, which must enter a short cycle — a
     synthetic stand-in for the prompt-quoting repetition of trained
     math/code rollouts, with GUARANTEED n-gram acceptance once the cycle
-    repeats (bench.py bench_spec_compare uses the same construction)."""
+    repeats."""
     p = init_params(TINY, jax.random.PRNGKey(0))
     layers = dict(p["layers"])
     layers["attn"] = {

@@ -9,6 +9,7 @@ import threading
 import pytest
 
 from areal_tpu.api.cli_args import SupervisorConfig
+from areal_tpu.launcher import supervisor as supervisor_mod
 from areal_tpu.launcher.supervisor import (
     FleetSnapshot,
     FleetSupervisor,
@@ -412,7 +413,24 @@ def test_executor_crash_loop_gives_up_and_degrades():
     assert _run(_scenario_crash_loop())
 
 
-async def _scenario_replace_dead():
+# The control plane under fire: each of the supervisor's own seams armed
+# against the executor (what a whole supervised fleet was once run for).
+UNDER_FIRE = {
+    None: [],
+    # the replace's first two spawns die: backoff and retry, no crash loop
+    "spawn": [dict(site="supervisor.spawn", mode="abort", at=(0, 1), times=2)],
+    # the supervisor dies at the kill: the next tick plans the replace again
+    "kill": [dict(site="supervisor.kill", mode="abort", at=(0,), times=1)],
+    # one failed probe of the healthy replica: a flap, below the dead threshold
+    "health": [dict(site="supervisor.health", mode="abort", at=(0,), times=1,
+                    match={"target": "ok:1"})],
+}
+
+
+async def _scenario_replace_dead(seam=None):
+    from areal_tpu.core import fault_injection
+    from areal_tpu.core.fault_injection import FaultPlan, FaultPoint
+
     clock = {"t": 100.0}
     spawned = []
 
@@ -428,6 +446,7 @@ async def _scenario_replace_dead():
         max_replicas=4,
         spawn_max_attempts=3,
         spawn_backoff_s=0.01,
+        spawn_backoff_jitter=0.0,
         replace_cooldown_s=0.0,
         health_fail_threshold=2,
     )
@@ -441,34 +460,43 @@ async def _scenario_replace_dead():
     async def router():
         return {}
 
-    async def healths():
+    async def http(addr, path, **kw):
         # dead:1 fails every probe; everything else (incl. a respawned
         # handle) reports healthy
-        return [
-            (s.slot_id, s.addr != "dead:1")
-            for s in sup._slots.values()
-            if s.handle is not None
-        ]
+        if addr == "dead:1":
+            raise ConnectionError("dead")
+        return {"status": "ok"}
 
     sup._poll_router = router
-    sup._poll_healths = healths
-
-    for _ in range(30):
-        await sup._tick()
-        await _settle_spawns(sup)
-        if sup._disruptive_task is not None:
-            # the replace runs as a task: let it finish before advancing
-            await sup._disruptive_task
-        clock["t"] += 1.0
-        m = sup.get_metrics()
-        # gauges lag one tick (the disruptive task runs after the
-        # snapshot), so gate on the live slot table, not the gauges
-        if m["replacements_total"] >= 1 and all(
-            s.handle is not None for s in sup._slots.values()
-        ):
-            break
-    await sup._tick()  # refresh gauges with the respawned handle
+    real_http = supervisor_mod.arequest_with_retry
+    supervisor_mod.arequest_with_retry = http
+    fault_injection.configure(FaultPlan(
+        seed=13, points=[FaultPoint(**p) for p in UNDER_FIRE[seam]]))
+    try:
+        for _ in range(30):
+            await sup._tick()
+            await _settle_spawns(sup)
+            if sup._disruptive_task is not None:
+                # the replace runs as a task: let it finish before advancing
+                await sup._disruptive_task
+            clock["t"] += 1.0
+            m = sup.get_metrics()
+            # gauges lag one tick (the disruptive task runs after the
+            # snapshot), so gate on the live slot table, not the gauges
+            if m["replacements_total"] >= 1 and all(
+                s.handle is not None for s in sup._slots.values()
+            ):
+                break
+        await sup._tick()  # refresh gauges with the respawned handle
+        fired = {k.split("|")[0] for k in fault_injection.snapshot()}
+    finally:
+        fault_injection.deactivate()
+        supervisor_mod.arequest_with_retry = real_http
     m = sup.get_metrics()
+    assert fired == ({f"supervisor.{seam}"} if seam else set())
+    assert m["spawn_failures_total"] == (2 if seam == "spawn" else 0)
+    assert m["crash_loops_total"] == 0 and m["pending_spawns"] == 0
+    assert m["health_flaps_total"] == (1 if seam == "health" else 0)
     assert m["replacements_total"] == 1
     assert m["kills_total"] == 1
     assert dead.killed.is_set()
@@ -479,8 +507,69 @@ async def _scenario_replace_dead():
     return True
 
 
-def test_executor_replaces_dead_replica_and_respawns():
-    assert _run(_scenario_replace_dead())
+@pytest.mark.parametrize("seam", list(UNDER_FIRE), ids=str)
+def test_executor_replaces_dead_replica_and_respawns(seam):
+    assert _run(_scenario_replace_dead(seam))
+
+
+async def _scenario_hung_drain_rolls_back():
+    """Three idle replicas over a floor of two: the plan retires one. Its
+    first drain hangs past the deadline (the `supervisor.drain` seam): the
+    action is rolled back and the victim keeps serving; a later tick drains
+    it for good, and the fleet converges to the floor."""
+    from areal_tpu.core import fault_injection
+    from areal_tpu.core.fault_injection import FaultPlan, FaultPoint
+
+    clock = {"t": 100.0}
+    cfg = SupervisorConfig(min_replicas=2, max_replicas=4, scale_down_cooldown_s=0.0,
+                           drain_deadline_s=0.05, health_fail_threshold=2)
+    sup = FleetSupervisor("127.0.0.1:1", lambda role: None, config=cfg,
+                          time_fn=lambda: clock["t"])
+    handles = [_Handle(f"r{i}:1") for i in range(3)]
+    for h in handles:
+        sup.adopt(h)
+    drains = []
+
+    async def router():
+        return {}
+
+    async def http(addr, path, **kw):
+        if path == "/drain":
+            drains.append(addr)
+        return {"status": "ok"}
+
+    sup._poll_router = router
+    real_http = supervisor_mod.arequest_with_retry
+    supervisor_mod.arequest_with_retry = http
+    fault_injection.configure(FaultPlan(seed=14, points=[
+        FaultPoint(site="supervisor.drain", mode="delay", at=(0,), times=1, delay_s=0.5)]))
+    try:
+        rolled_back_alive = None
+        for _ in range(30):
+            await sup._tick()
+            if sup._disruptive_task is not None:
+                await sup._disruptive_task
+            clock["t"] += 1.0
+            m = sup.get_metrics()
+            if m["drain_rollbacks_total"] and rolled_back_alive is None:
+                rolled_back_alive = not any(h.killed.is_set() for h in handles)
+            if m["scale_downs_total"]:
+                break
+        await sup._tick()
+    finally:
+        fault_injection.deactivate()
+        supervisor_mod.arequest_with_retry = real_http
+    m = sup.get_metrics()
+    assert m["drain_rollbacks_total"] == 1 and rolled_back_alive
+    assert m["scale_downs_total"] == 1 and m["kills_total"] == 1
+    assert len(drains) == 1  # the hung drain never reached the replica
+    assert sum(h.killed.is_set() for h in handles) == 1
+    assert m["fleet_alive"] == 2 and m["pending_spawns"] == 0
+    return True
+
+
+def test_hung_drain_rolls_back_then_retires():
+    assert _run(_scenario_hung_drain_rolls_back())
 
 
 async def _scenario_endpoint():

@@ -22,6 +22,7 @@ from areal_tpu.api.cli_args import (
     PPOActorConfig,
 )
 from areal_tpu.api.io_struct import FinetuneSpec, ModelRequest
+from areal_tpu.engine.kv_pool import fork_block
 from areal_tpu.models.qwen2 import ModelConfig, init_params
 
 TINY = ModelConfig(
@@ -102,7 +103,7 @@ def programs(cpu_devices):
             eng._get_verify_fn(False, 1, 3)
             eng._get_suffix_prefill_fn(64, 64, 1)
             eng._get_embed_prefill_fn(64, 4)
-            eng._get_block_copy_fn()
+            eng._slot_cache._copy(fork_block)
             eng._get_patch_fn()
         finally:
             eng.destroy()
@@ -176,7 +177,12 @@ def _drive_the_loop(eng, params, rec):
     eng.update_weights_from_distributed(WeightUpdateMeta(type="memory"), params, TINY)
     eng.set_version(1)
     eng.resume()
-    time.sleep(0.05)
+    # with nothing left to serve the thread goes idle, and closes its spans
+    # when it gets a core: wait for the last of them as for the pause above
+    deadline = time.monotonic() + 30.0
+    while time.monotonic() < deadline and not SPANS["scheduler thread"] <= {
+            s["name"] for s in rec.snapshot()}:
+        time.sleep(0.005)
 
 
 # every span the program opens on the paths above, `areal/<name>` in a device

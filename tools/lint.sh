@@ -7,7 +7,7 @@
 # reference set is absent, so they stay quiet rather than wrong).
 #
 #   tools/lint.sh            # gate: what CI / the tier-1 suite enforces
-#   tools/lint.sh --all      # also sweep bench.py, tools/ and tests/
+#   tools/lint.sh --all      # also sweep tools/ and tests/
 #                            # (informational; tests/ has known AR201s in
 #                            # oracle loops where sync cost is irrelevant,
 #                            # and standalone AR301/AR302 noise from test
@@ -67,12 +67,12 @@ echo "== areal-lint (areal_tpu/ vs tools/lint_baseline.json) =="
 python -m areal_tpu.analysis areal_tpu/ --baseline tools/lint_baseline.json
 
 if [[ "${1:-}" == "--all" ]]; then
-    echo "== areal-lint sweep: bench.py tools/ (gating) =="
-    python -m areal_tpu.analysis bench.py tools/*.py --no-baseline
+    echo "== areal-lint sweep: tools/ (gating) =="
+    python -m areal_tpu.analysis tools/*.py --no-baseline
     echo "== areal-lint sweep: tests/ (informational) =="
     python -m areal_tpu.analysis tests/ --no-baseline || true
 fi
 
 echo "== compileall =="
-python -m compileall -q areal_tpu tests tools bench.py examples
+python -m compileall -q areal_tpu tests tools examples
 echo "lint: OK"
