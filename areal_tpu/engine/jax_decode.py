@@ -211,6 +211,58 @@ def _next_bucket(n: int, bucket: int = _PREFILL_BUCKET) -> int:
     return max(((n + bucket - 1) // bucket) * bucket, bucket)
 
 
+class _PrefillOrWider:
+    """A prefill program of one bucket, and what stands in for it where the
+    compiler refuses that shape. `program(tokens)` is the jitted pass over
+    `tokens` positions a prompt that gives the BUCKET's results: positions
+    past the bucket are masked going in and their rows cut coming out, as a
+    bucket's own padding is. The first call compiles. XLA:TPU has refused one
+    shape of a sparse model's prefill at compile time and taken its neighbours
+    (PR 45: a fusion of its own making out of scoped VMEM, which killed the
+    scheduler in the warm-up), so an error out of a program that has never
+    run tries the pass a bucket wider, then two, and keeps the first that
+    runs. A fault that is not the shape's comes back from those as well, and
+    the first error is the one raised."""
+
+    WIDER = 2
+
+    def __init__(self, program: Callable[[int], Callable], bucket: int):
+        self._program, self._bucket = program, bucket
+        self._own = program(bucket)
+        self._fn: Callable | None = None  # the program that has run,
+        self.tokens = bucket  # and the positions a prompt it passes over
+
+    def __getattr__(self, name: str):
+        # the jitted program's own (`lower`, `trace`), for a test or a tool
+        return getattr(self._fn or self._own, name)
+
+    def __call__(self, *args):
+        if self._fn is not None:
+            return self._fn(*args)
+        fn = self._own
+        try:
+            out = fn(*args)
+        except jax.errors.JaxRuntimeError as refusal:
+            for k in range(1, self.WIDER + 1):
+                tokens = self._bucket + k * _PREFILL_BUCKET
+                fn = self._program(tokens)
+                try:
+                    out = fn(*args)
+                except RuntimeError:  # refused too, or the pools went with
+                    continue  # the first call: `refusal` is what is raised
+                self.tokens = tokens
+                logger.warning(
+                    f"prefill bucket {self._bucket} runs as a pass over "
+                    f"{tokens} positions: the compiler refused it at its own "
+                    f"({str(refusal).splitlines()[0][:200]})"
+                )
+                break
+            else:
+                raise refusal
+        self._fn = fn
+        return out
+
+
 def _make_sample_fn(use_topp: bool):
     """Per-slot sampling used by BOTH the chunked decode loop and the
     speculative verify chunk (the verify path flattens [R, W] positions to
@@ -614,6 +666,10 @@ class JaxDecodeEngine(InferenceEngine):
         # expert of each layer and step: their ratio x E is max-over-mean load
         self._moe_pairs = 0
         self._moe_hot_pairs = 0
+        # token steps of the chunks CONSUMED: a chunk's load vector is added to
+        # the counters here then, a chunk or two after `chunks_dispatched_total`
+        # counted it, so this is what those sums are over
+        self._consumed_steps = 0
         # where the chip holds a share of the experts: pairs whose expert
         # lives on another chip; a mixed stack: cached rows the chunks'
         # attention read, by kind of layer
@@ -886,6 +942,7 @@ class JaxDecodeEngine(InferenceEngine):
             self._runahead_discarded = 0
             self._moe_pairs = 0
             self._moe_hot_pairs = 0
+            self._consumed_steps = 0
             self._moe_absent_pairs = 0
             self._kv_full_rows_read = 0
             self._kv_window_rows_read = 0
@@ -2112,15 +2169,24 @@ class JaxDecodeEngine(InferenceEngine):
                     out.append(pool)
                 return tuple(out)
 
-            def prefill_batched(params, kq, vq, ids_b, positions, bts_b,
-                                lens_b):
+            def prefill_rows(tokens, params, kq, vq, ids_b, positions, bts_b,
+                             lens_b):
                 from areal_tpu.ops.kv_quant import (
                     join_pool, quantize_kv, scales_blocked, split_pool,
                 )
 
+                if tokens > bucket:
+                    # (`_PrefillOrWider`) more masked positions than the bucket's
+                    more = tokens - bucket
+                    ids_b = jnp.pad(ids_b, ((0, 0), (0, more)))
+                    positions = jnp.concatenate([
+                        positions,
+                        positions[-1] + 1 + jnp.arange(more, dtype=positions.dtype),
+                    ])
+
                 # bts_b: [B, nb_w] block-table rows to scatter into
                 def core(ids, true_len):
-                    valid = jnp.arange(bucket) < true_len
+                    valid = jnp.arange(tokens) < true_len
                     _, k, v, *state = prefill(
                         params, ids, positions, cfg, valid=valid,
                         with_logits=False,
@@ -2128,6 +2194,8 @@ class JaxDecodeEngine(InferenceEngine):
                     return k, v, *state
 
                 ks, vs, *state = jax.vmap(core)(ids_b, lens_b)  # [B, L, bucket, ...]
+                if tokens > bucket:
+                    ks, vs = ks[:, :, :bucket], vs[:, :, :bucket]
                 if state:
                     # the linear layers' state at each prompt's last real
                     # token, into the prompts' slots' rows
@@ -2173,9 +2241,13 @@ class JaxDecodeEngine(InferenceEngine):
                     )
                 return join_pool(kp, ksc), join_pool(vp, vsc)
 
-            self._batched_prefill_fns[key] = jax.jit(
-                prefill_batched, donate_argnums=(1, 2)
-            )
+            def program(tokens: int):
+                def prefill_batched(*operands):
+                    return prefill_rows(tokens, *operands)
+
+                return jax.jit(prefill_batched, donate_argnums=(1, 2))
+
+            self._batched_prefill_fns[key] = _PrefillOrWider(program, bucket)
         return self._batched_prefill_fns[key]
 
     def _run_copies(self, copies: list[tuple]) -> None:
@@ -4099,7 +4171,7 @@ class JaxDecodeEngine(InferenceEngine):
                             self._kv_block_rows_read += more.pop(0)
                             self._dfn_slot_forwards += more.pop(0)
                             self._dfn_commit_forwards += more.pop(0)
-                        if cfg.moe_n_group > 1:
+                        if cfg.moe_grouped:
                             self._moe_group_tokens_here += more.pop(0)
                             self._moe_group_experts_touched += more.pop(0)
                         if more:
@@ -4108,6 +4180,8 @@ class JaxDecodeEngine(InferenceEngine):
                             self._kv_window_rows_read += read["window"]
                             self._kv_latent_rows_read += read["latent"]
                             self._gdn_state_updates += read["state"]
+            with self._metrics_lock:
+                self._consumed_steps += int(rec.n_chunk)
             self._apply_chunk(rec, toks, logps, acc)
 
     def _apply_chunk(self, rec: "_Inflight", toks: np.ndarray,
@@ -5721,6 +5795,7 @@ class JaxDecodeEngine(InferenceEngine):
             chunks_dispatched = self._chunks_dispatched
             runahead_discarded = self._runahead_discarded
             moe_pairs, moe_hot_pairs = self._moe_pairs, self._moe_hot_pairs
+            consumed_steps = self._consumed_steps
             moe_absent_pairs = self._moe_absent_pairs
             kv_rows_read = self._kv_full_rows_read, self._kv_window_rows_read
             kv_latent_rows = self._kv_latent_rows_read
@@ -5825,19 +5900,28 @@ class JaxDecodeEngine(InferenceEngine):
             # expert's share of them per layer and token step (0 for dense)
             "moe_pairs_total": moe_pairs,
             "moe_hot_expert_pairs_total": moe_hot_pairs,
+            # token steps of the chunks consumed, of any model: a chunk's load
+            # vector (these sums, and the rows, state updates and group counts
+            # below) is added when it is CONSUMED, so a per-step mean over a
+            # stretch of a run divides by this and not by
+            # `chunks_dispatched_total` (PR 45)
+            "chunks_consumed_token_steps_total": consumed_steps,
             # experts across chips: pairs whose expert another chip holds;
             # a mixed stack: cached rows the chunks read, by kind of layer
             "moe_absent_pairs_total": moe_absent_pairs,
             "kv_full_rows_read_total": kv_rows_read[0],
             "kv_window_rows_read_total": kv_rows_read[1],
             # bytes behind those rows (K and V of a full layer's row), and
-            # for linear layers the live slots' state updates and the bytes
-            # each moves: its state and convolution rows, read and written
+            # for linear layers (Gated DeltaNet or Kimi Delta Attention alike)
+            # the live slots' state updates and the bytes each moves, at
+            # `SlotCache.state_update_nbytes`: its state and convolution
+            # rows, read and written
             "kv_full_bytes_read_total": kv_rows_read[0] * row_nbytes,
             # a latent model: cached latent rows the chunks' attention read
             # (live slots' rows x latent layers of every token step), their
             # bytes as the pool stores a row, and the tokens x sparse layers
-            # whose kept routing groups include one held here
+            # whose kept routing groups include one held here (a router
+            # declared group-limited, at one group too: then every token)
             "kv_latent_rows_read_total": kv_latent_rows,
             "kv_latent_bytes_read_total": kv_latent_rows * row_nbytes,
             "moe_group_tokens_here_total": moe_group_here,

@@ -44,8 +44,11 @@ has, a bare array a side) there are: for window layers a fixed RING of pages
 a slot (`WindowRing`), a second pool outside the allocator that a fork
 copies; for linear layers one recurrent STATE a slot (`StateSlots`), good for
 exactly the length it has absorbed; for latent attention ONE row a token and
-layer through the same table and allocator, with no V side; and under a block
-mask (`block_length` > 1) the paged pool valid a whole block at a time.
+layer through the same table and allocator, with no V side (beside linear
+layers, Kimi-Linear, a slot holds BOTH: the latent rows of its attention
+layers and the state of its linear ones, and every answer below is the two
+kinds' together); and under a block mask (`block_length` > 1) the paged pool
+valid a whole block at a time.
 """
 
 from __future__ import annotations
@@ -496,8 +499,8 @@ class SlotCache:
         is a bare `[L, n_blocks, block_size, nKV*hd]` array for a uniform
         stack; for a stack of several kinds a dict, `{"full", "window"}` by
         the layers there are or `{"latent"}` with an EMPTY V side, the state
-        `{"S", "conv"}` riding in the K-side dict so that every pool program
-        carries it donated. Scales (an int8 pool's, else None) are f32
+        `{"S", "conv"}` riding in the K-side dict (beside `full` or beside
+        `latent`) so that every pool program carries it donated. Scales (an int8 pool's, else None) are f32
         `[L, n_blocks, nKV, block_size]`: the kv-head axis precedes
         block_size so a Pallas scale block is (1, 1, bs) with the page size
         on the lane dim."""
@@ -627,11 +630,12 @@ class SlotCache:
     def rows_read(self, tail: list) -> dict[str, int]:
         """A chunk's counters of cached rows read, by kind, from the tail of
         what a mixed stack's program returns (models/qwen2.py): [full rows,
-        window rows] and then the latent rows or the linear layers' state
-        updates."""
-        latent = self._paged == "latent"
-        return {"full": tail[0], "window": tail[1], "latent": tail[2] if latent else 0,
-                "state": 0 if latent else sum(tail[2:])}
+        window rows], then the linear layers' state updates where it has
+        such, then the latent rows where it has those."""
+        rest = list(tail[2:])
+        state = rest.pop(0) if self.state is not None else 0
+        return {"full": tail[0], "window": tail[1], "state": state,
+                "latent": rest.pop(0) if self._paged == "latent" else 0}
 
     # -- what it cannot serve ----------------------------------------------------
     def lacks(self, needs, config=None) -> list[str]:

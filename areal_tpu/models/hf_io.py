@@ -115,6 +115,26 @@ def hf_name_to_ours(name: str) -> tuple[str, ...] | None:
             "self_attn.kv_a_proj_with_mqa.weight": ("attn", "kv_a_kernel"),
             "self_attn.kv_a_layernorm.weight": ("attn", "kv_a_norm"),
             "self_attn.kv_b_proj.weight": ("attn", "kv_b_kernel"),
+            # Kimi-Linear's Kimi Delta Attention mixer, whose q, k, v and o
+            # projections carry the attention's names, and its experts under
+            # Mixtral's (its modelling code's names as ISSUE 45's author knew
+            # them: no checkpoint here to read them from,
+            # benchmark/configs/kimi-linear-48b-a3b.json `assumed`)
+            "self_attn.q_conv1d.weight": ("attn", "q_conv_kernel"),
+            "self_attn.k_conv1d.weight": ("attn", "k_conv_kernel"),
+            "self_attn.v_conv1d.weight": ("attn", "v_conv_kernel"),
+            "self_attn.A_log": ("attn", "A_log"),
+            "self_attn.dt_bias": ("attn", "dt_bias"),
+            "self_attn.f_a_proj.weight": ("attn", "f_a_kernel"),
+            "self_attn.f_b_proj.weight": ("attn", "f_b_kernel"),
+            "self_attn.b_proj.weight": ("attn", "b_kernel"),
+            "self_attn.g_a_proj.weight": ("attn", "g_a_kernel"),
+            "self_attn.g_b_proj.weight": ("attn", "g_b_kernel"),
+            "self_attn.o_norm.weight": ("attn", "o_norm"),
+            "block_sparse_moe.gate.e_score_correction_bias": ("mlp", "router_bias"),
+            "block_sparse_moe.shared_experts.gate_proj.weight": ("mlp", "shared_gate_kernel"),
+            "block_sparse_moe.shared_experts.up_proj.weight": ("mlp", "shared_up_kernel"),
+            "block_sparse_moe.shared_experts.down_proj.weight": ("mlp", "shared_down_kernel"),
             # Mixtral router
             "block_sparse_moe.gate.weight": ("mlp", "router_kernel"),
             "input_layernorm.weight": ("input_norm",),
@@ -198,6 +218,10 @@ def _latent_rotary_lanes(w: np.ndarray, cfg: ModelConfig, leaf: str, load: bool)
 
 
 _LATENT_KERNELS = ("q_a_kernel", "q_b_kernel", "kv_a_kernel", "kv_b_kernel")
+# Kimi Delta Attention's leaves that are plain [out, in] matrices, and its
+# depthwise convolutions (torch Conv1d [channels, 1, width])
+_KDA_MATRICES = ("f_a_kernel", "f_b_kernel", "b_kernel", "g_a_kernel", "g_b_kernel")
+_KDA_CONVS = ("q_conv_kernel", "k_conv_kernel", "v_conv_kernel")
 
 
 def _convert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) -> np.ndarray:
@@ -214,9 +238,14 @@ def _convert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) -> n
             return w.reshape(w.shape[0], w.shape[-1])
         if leaf == "out_kernel":
             return np.ascontiguousarray(w.T)
+    if leaf in _KDA_MATRICES:
+        return np.ascontiguousarray(w.T)
+    if leaf in _KDA_CONVS:
+        return w.reshape(w.shape[0], w.shape[-1])
     if leaf in _LATENT_KERNELS:
         w = np.ascontiguousarray(w.T)
-        if leaf in ("q_b_kernel", "kv_a_kernel"):
+        # (a model with no positional encoding turns no lanes: as they are)
+        if leaf in ("q_b_kernel", "kv_a_kernel") and cfg.pos_embed == "rope":
             w = _latent_rotary_lanes(w, cfg, leaf, load=True)
         return w
     if leaf in ("q_kernel", "k_kernel", "v_kernel"):
@@ -250,8 +279,12 @@ def _unconvert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) ->
             return w.reshape(w.shape[0], 1, w.shape[1])
         if leaf == "out_kernel":
             return np.ascontiguousarray(w.T)
+    if leaf in _KDA_MATRICES:
+        return np.ascontiguousarray(w.T)
+    if leaf in _KDA_CONVS:
+        return w.reshape(w.shape[0], 1, w.shape[1])
     if leaf in _LATENT_KERNELS:
-        if leaf in ("q_b_kernel", "kv_a_kernel"):
+        if leaf in ("q_b_kernel", "kv_a_kernel") and cfg.pos_embed == "rope":
             w = _latent_rotary_lanes(w, cfg, leaf, load=False)
         return np.ascontiguousarray(w.T)
     if leaf in ("q_kernel", "k_kernel", "v_kernel"):
@@ -523,6 +556,25 @@ def ours_name_to_hf(path: tuple[str, ...], model_type: str = "qwen2") -> str:
             leaf_table[("mlp", f"shared_{proj}_kernel")] = (
                 f"mlp.shared_experts.{proj}_proj.weight"
             )
+    if model_type == "kimi_linear":
+        leaf_table.update({
+            ("attn", "q_conv_kernel"): "self_attn.q_conv1d.weight",
+            ("attn", "k_conv_kernel"): "self_attn.k_conv1d.weight",
+            ("attn", "v_conv_kernel"): "self_attn.v_conv1d.weight",
+            ("attn", "A_log"): "self_attn.A_log",
+            ("attn", "dt_bias"): "self_attn.dt_bias",
+            ("attn", "f_a_kernel"): "self_attn.f_a_proj.weight",
+            ("attn", "f_b_kernel"): "self_attn.f_b_proj.weight",
+            ("attn", "b_kernel"): "self_attn.b_proj.weight",
+            ("attn", "g_a_kernel"): "self_attn.g_a_proj.weight",
+            ("attn", "g_b_kernel"): "self_attn.g_b_proj.weight",
+            ("attn", "o_norm"): "self_attn.o_norm.weight",
+            ("mlp", "router_kernel"): "block_sparse_moe.gate.weight",
+            ("mlp", "router_bias"): "block_sparse_moe.gate.e_score_correction_bias",
+            **{("mlp", f"shared_{proj}_kernel"):
+               f"block_sparse_moe.shared_experts.{proj}_proj.weight"
+               for proj in ("gate", "up", "down")},
+        })
     if path == ("embed", "embedding"):
         return "model.embed_tokens.weight"
     if path == ("final_norm",):
@@ -537,7 +589,7 @@ def ours_name_to_hf(path: tuple[str, ...], model_type: str = "qwen2") -> str:
         i = int(path[0].split("_")[1])
         if len(path) == 4 and path[2].startswith("expert_"):
             m = int(path[2].split("_")[1])
-            if model_type == "mixtral":
+            if model_type in ("mixtral", "kimi_linear"):
                 w = {
                     "gate_kernel": "w1",
                     "up_kernel": "w3",

@@ -62,7 +62,7 @@ def _cstr(x: jax.Array, *logical_axes: str | None) -> jax.Array:
 MODEL_TYPES = (
     "qwen2", "qwen3", "llama", "mistral", "gemma", "gemma2", "gpt2",
     "mixtral", "qwen2_moe", "qwen3_moe", "olmoe", "exaone_moe", "qwen3_next",
-    "sdar_moe", "deepseek_v2",
+    "sdar_moe", "deepseek_v2", "kimi_linear",
 )
 
 # `layer_types` entries of a mixed stack (HF's names)
@@ -121,7 +121,7 @@ class ModelConfig:
     # absolute position embeddings (wpe), ungated fc1/act/fc2 MLP, and a
     # bias on the attention output projection.
     norm_type: str = "rmsnorm"  # "rmsnorm" | "layernorm"
-    pos_embed: str = "rope"  # "rope" | "learned"
+    pos_embed: str = "rope"  # "rope" | "learned" | "none" (no positional encoding)
     mlp_style: str = "glu"  # "glu" (gate/up/down) | "fc" (fc1/fc2)
     attn_out_bias: bool = False
     # compute/storage dtypes
@@ -207,6 +207,11 @@ class ModelConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
+    # Kimi-Linear-class: the linear layers are Kimi Delta Attention, the same
+    # delta rule with the decay a VECTOR over a head's key lanes (`S <-
+    # Diag(exp(g)) S`), q, k and v from projections and convolutions of
+    # their own, two low-rank gates and a sigmoid on the output.
+    linear_decay_lanes: bool = False
     attn_output_gate: bool = False
     partial_rotary_factor: float = 1.0
     # -- SDAR-class (generation by diffusion over blocks). `block_length` B > 1:
@@ -229,7 +234,12 @@ class ModelConfig:
     # (`_latent_decode_attention`). `moe_n_group` > 1: group-limited routing,
     # a token keeps the `moe_topk_group` best of `moe_n_group` groups of
     # consecutive experts (a group's score its best expert's) and picks its
-    # k among those groups' experts.
+    # k among those groups' experts. `moe_grouped`: the router is declared
+    # group-limited at however many groups (at one the grouping is vacuous),
+    # and `moe_mlp`'s load vector also counts the tokens whose kept groups
+    # land here and the held experts with a pair. `q_lora_rank` 0: a
+    # full-rank query (`q_kernel`). Beside linear layers (Kimi-Linear) the
+    # attention layers alone are latent: a slot's cache is a state AND rows.
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -237,6 +247,7 @@ class ModelConfig:
     v_head_dim: int = 0
     moe_n_group: int = 1
     moe_topk_group: int = 1
+    moe_grouped: bool = False
     # vocab chunk for the fused LM-head loss (ops/fused_xent.py): peak
     # logits transient is [tokens, loss_vocab_chunk]
     loss_vocab_chunk: int = 16384
@@ -332,9 +343,10 @@ class ModelConfig:
         a ring, "state": those with a recurrent state a slot, "latent": those
         with one latent row a token} of a mixed stack, in layer order."""
         L = range(self.num_hidden_layers)
-        if self.latent:
-            return {"full": (), "window": (), "state": (), "latent": tuple(L)}
         state = tuple(i for i in L if self.layer_linear(i))
+        if self.latent:
+            return {"full": (), "window": (), "state": state,
+                    "latent": tuple(i for i in L if i not in state)}
         return {
             "full": tuple(
                 i for i in L if self.layer_window(i) is None and i not in state
@@ -555,6 +567,8 @@ class ModelConfig:
             kw.update(_qwen3_next_kw(hf))
         elif model_type == "deepseek_v2":
             kw.update(_deepseek_v2_kw(hf))
+        elif model_type == "kimi_linear":
+            kw.update(_kimi_linear_kw(hf))
         elif model_type == "gemma":
             # Gemma-1 (reference: realhf/api/from_hf/gemma.py — GeGLU MLP,
             # zero-centered RMSNorm, sqrt(H)-scaled embeddings, tied head).
@@ -855,7 +869,109 @@ def _deepseek_v2_kw(hf: dict) -> dict:
         router_aux_loss_coef=float(hf.get("aux_loss_alpha", 0.0)),
         moe_n_group=n_group,
         moe_topk_group=topk_group,
+        moe_grouped=n_group > 1,
         # no uniform stack: a leading dense layer, and one pool of rows
+        scan_layers=False,
+    )
+
+
+def _kimi_linear_kw(hf: dict) -> dict:
+    """Kimi-Linear (`kimi_linear`): Kimi Delta Attention layers (a delta rule
+    whose decay is a vector over a head's key lanes) with a latent-attention
+    layer among every few that takes NO positional encoding (`mla_use_nope`)
+    and a full-rank query, leading dense layers, then sigmoid-scored experts
+    with a selection bias, weights renormalised over the chosen times
+    `routed_scaling_factor`, and ungated shared experts. `linear_attn_config`
+    numbers its layers from 1. Raises on what is not served."""
+    L = hf["num_hidden_layers"]
+    if not hf.get("mla_use_nope", False):
+        raise NotImplementedError(
+            "kimi_linear with mla_use_nope false (rotary latent attention "
+            "beside the linear layers) is not implemented"
+        )
+    if hf.get("rope_scaling"):
+        raise NotImplementedError(
+            f"kimi_linear with rope_scaling {hf['rope_scaling']!r}: its latent "
+            "attention takes no positional encoding to scale"
+        )
+    if hf.get("num_nextn_predict_layers", 0):
+        raise NotImplementedError(
+            "kimi_linear with num_nextn_predict_layers > 0: the multi-token "
+            "prediction layer is not implemented (it takes no part in the "
+            "main model's logits; set it to 0 to serve the model without it)"
+        )
+    if hf.get("q_lora_rank"):
+        raise NotImplementedError(
+            f"kimi_linear with q_lora_rank={hf['q_lora_rank']} (a low-rank "
+            "query) is not implemented (served: null, a full-rank query)"
+        )
+    if hf.get("moe_layer_freq", 1) != 1:
+        raise NotImplementedError(
+            f"kimi_linear with moe_layer_freq={hf['moe_layer_freq']} (dense "
+            "layers among the sparse ones) is not implemented"
+        )
+    if hf.get("num_expert_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+        raise NotImplementedError(
+            "kimi_linear with num_expert_group / topk_group != 1 (sigmoid "
+            "scores under group-limited routing) is not implemented"
+        )
+    scoring = hf.get("moe_router_activation_func", "sigmoid")
+    if scoring not in ("sigmoid", "softmax"):
+        raise NotImplementedError(
+            f"kimi_linear moe_router_activation_func {scoring!r} is not implemented"
+        )
+    lin = hf.get("linear_attn_config") or {}
+    kda = {int(i) for i in lin.get("kda_layers", ())}
+    full = {int(i) for i in lin.get("full_attn_layers", ())}
+    # a config cut in depth keeps the published lists whole: the first L count
+    types = tuple(
+        _LINEAR_LAYER if i in kda else _FULL_LAYER if i in full else None
+        for i in range(1, L + 1)
+    )
+    if None in types or kda & full:
+        raise NotImplementedError(
+            f"kimi_linear linear_attn_config names layers {sorted(kda)} (kda) "
+            f"and {sorted(full)} (full): need each of 1..{L} in exactly one"
+        )
+    heads, dk = int(lin["num_heads"]), int(lin["head_dim"])
+    if heads != hf["num_attention_heads"]:
+        raise NotImplementedError(
+            f"kimi_linear linear_attn_config num_heads={heads} differs from "
+            f"num_attention_heads={hf['num_attention_heads']}: the two mixers' "
+            "projections carry one set of names by head"
+        )
+    nope, rope = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
+    n_shared = int(hf.get("num_shared_experts") or 0)
+    return dict(
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        pos_embed="none",
+        layer_types=types,
+        linear_decay_lanes=True,
+        linear_num_key_heads=heads,
+        linear_num_value_heads=heads,
+        linear_key_head_dim=dk,
+        linear_value_head_dim=dk,
+        linear_conv_kernel_dim=int(lin.get("short_conv_kernel_size", 4)),
+        kv_lora_rank=int(hf["kv_lora_rank"]),
+        q_lora_rank=0,
+        qk_nope_head_dim=nope,
+        qk_rope_head_dim=rope,
+        v_head_dim=int(hf["v_head_dim"]),
+        # a latent head's q/k width (the top-level `head_dim` is used by
+        # neither mixer)
+        head_dim=nope + rope,
+        first_k_dense=int(hf.get("first_k_dense_replace", 0)),
+        **_held_experts_kw(hf),
+        num_experts_per_tok=int(hf["num_experts_per_token"]),
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        shared_expert_intermediate_size=n_shared * hf["moe_intermediate_size"],
+        shared_expert_gated=False,
+        norm_topk_prob=bool(hf.get("moe_renormalize", True)),
+        moe_scoring=scoring,
+        moe_router_bias=True,
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_grouped=bool(hf.get("use_grouped_topk", False)),
+        # layers of two kinds do not stack
         scan_layers=False,
     )
 
@@ -882,18 +998,54 @@ def _gdn_shapes(cfg: ModelConfig) -> dict:
     }
 
 
+def _kda_shapes(cfg: ModelConfig) -> dict:
+    """The Kimi Delta Attention mixer's leaves: q, k and v each from a
+    projection and a depthwise convolution of its own, the decay's low-rank
+    gate `f` (to a head's key lanes: `A_log` a head, `dt_bias` a lane), the
+    write strength `b` a head, the output's low-rank gate `g`, the heads'
+    norm and the output projection."""
+    H, n = cfg.hidden_size, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    K = cfg.linear_conv_kernel_dim
+    return {
+        "q_kernel": (H, n, dk),
+        "k_kernel": (H, n, dk),
+        "v_kernel": (H, n, dv),
+        "q_conv_kernel": (n * dk, K),
+        "k_conv_kernel": (n * dk, K),
+        "v_conv_kernel": (n * dv, K),
+        "f_a_kernel": (H, dk),
+        "f_b_kernel": (dk, n * dk),
+        "A_log": (n,),
+        "dt_bias": (n * dk,),
+        "b_kernel": (H, n),
+        "g_a_kernel": (H, dv),
+        "g_b_kernel": (dv, n * dv),
+        "o_norm": (dv,),
+        "o_kernel": (n, dv, H),
+    }
+
+
+def _linear_shapes(cfg: ModelConfig) -> dict:
+    return _kda_shapes(cfg) if cfg.linear_decay_lanes else _gdn_shapes(cfg)
+
+
 def _latent_shapes(cfg: ModelConfig) -> dict:
     """The latent attention's leaves. `q_b_kernel`'s columns are the heads in
-    order, each `[nope | rope]`; `kv_a_kernel`'s `[c_kv | k_pe]`;
+    order, each `[nope | rope]` (a full-rank query, `q_lora_rank` 0: one
+    `q_kernel` by head in their place); `kv_a_kernel`'s `[c_kv | k_pe]`;
     `kv_b_kernel`'s the heads in order, each `[k_nope | v]`. The rotary lanes
     are held as `rotate_half` pairs them, lane i with lane i + rope/2
     (`hf_io` permutes a checkpoint's interleaved pairs at load)."""
     H, nH = cfg.hidden_size, cfg.num_attention_heads
     C, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    return {
+    query = {
         "q_a_kernel": (H, cfg.q_lora_rank),
         "q_a_norm": (cfg.q_lora_rank,),
         "q_b_kernel": (cfg.q_lora_rank, nH * (cfg.qk_nope_head_dim + rope)),
+    } if cfg.q_lora_rank else {"q_kernel": (H, nH, cfg.qk_nope_head_dim + rope)}
+    return {
+        **query,
         "kv_a_kernel": (H, C + rope),
         "kv_a_norm": (C,),
         "kv_b_kernel": (C, nH * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
@@ -910,7 +1062,7 @@ def _layer_shapes(cfg: ModelConfig, i: int | None = None) -> dict:
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     linear = i is not None and cfg.layer_linear(i)
     shapes = {
-        "attn": _gdn_shapes(cfg) if linear else _latent_shapes(cfg) if cfg.latent else {
+        "attn": _linear_shapes(cfg) if linear else _latent_shapes(cfg) if cfg.latent else {
             # `attn_output_gate`: each head's query lanes, then its gate's
             "q_kernel": (H, nH, 2 * hd if cfg.attn_output_gate else hd),
             "k_kernel": (H, nKV, hd),
@@ -995,6 +1147,16 @@ _LAYER_AXES = {
         "A_log": ("norm",),
         "norm": ("norm",),
         "out_kernel": (None, "embed"),
+        # Kimi Delta Attention (q, k, v and o as the attention's, by head)
+        "q_conv_kernel": (None, None),
+        "k_conv_kernel": (None, None),
+        "v_conv_kernel": (None, None),
+        "f_a_kernel": ("embed", None),
+        "f_b_kernel": (None, None),
+        "b_kernel": ("embed", None),
+        "g_a_kernel": ("embed", None),
+        "g_b_kernel": (None, None),
+        "o_norm": ("norm",),
         # latent attention: the low-rank axes stay whole, heads split
         "q_a_kernel": ("embed", None),
         "q_a_norm": ("norm",),
@@ -1695,6 +1857,14 @@ def rope_table(
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def _rope_tables(positions: jax.Array, cfg: "ModelConfig") -> tuple:
+    """`rope_table` of the model's rotary lanes; (None, None) for a model
+    that takes no positional encoding (no table in its programs)."""
+    if cfg.pos_embed == "none":
+        return None, None
+    return rope_table(positions, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
+
+
 def _split_rotary(t: jax.Array, rot: int) -> tuple:
     """(t1, t2, rest): the two halves of the first `rot` lanes of `t`'s last
     axis (HF's 'rotate_half' pairs lane i with lane i + rot/2) and, as a list
@@ -1979,19 +2149,27 @@ def attention(
 def _latent_project(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig):
     """x [..., H] -> (q_nope [..., nH, nope], q_pe [..., nH, rope] turned,
     row [..., C + rope]: the row to cache, `[c_kv | k_pe]` normed and turned).
-    cos/sin: [..., rope/2], leading dims as x's."""
+    cos/sin: [..., rope/2], leading dims as x's; None for a model with no
+    positional encoding, whose q_pe and k_pe go as they are."""
     nH, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
     C = cfg.kv_lora_rank
-    with jax.named_scope("q_lora"):
-        c_q = _norm(
-            jnp.einsum("...h,hr->...r", x, layer_p["q_a_kernel"]),
-            layer_p["q_a_norm"], cfg,
-        )
-        q = jnp.einsum("...r,rm->...m", c_q, layer_p["q_b_kernel"])
-        q = q.reshape(*q.shape[:-1], nH, nope + cfg.qk_rope_head_dim)
+    if cfg.q_lora_rank:
+        with jax.named_scope("q_lora"):
+            c_q = _norm(
+                jnp.einsum("...h,hr->...r", x, layer_p["q_a_kernel"]),
+                layer_p["q_a_norm"], cfg,
+            )
+            q = jnp.einsum("...r,rm->...m", c_q, layer_p["q_b_kernel"])
+            q = q.reshape(*q.shape[:-1], nH, nope + cfg.qk_rope_head_dim)
+    else:
+        with jax.named_scope("q_proj"):
+            q = jnp.einsum("...h,hnd->...nd", x, layer_p["q_kernel"])
     with jax.named_scope("kv_latent"):
         kv = jnp.einsum("...h,hr->...r", x, layer_p["kv_a_kernel"])
         c_kv = _norm(kv[..., :C], layer_p["kv_a_norm"], cfg)
+    if cos is None:
+        return q[..., :nope], q[..., nope:], jnp.concatenate(
+            [c_kv, kv[..., C:]], axis=-1)
     with jax.named_scope("rope"):
         cos_b, sin_b = cos.astype(q.dtype), sin.astype(q.dtype)
         q_pe = _rotated(
@@ -2391,12 +2569,16 @@ def moe_mlp(
     if with_load:
         per_expert = assign.sum(axis=(0, 1)).astype(jnp.int32)  # valid pairs
         grouped = []
-        if cfg.moe_n_group > 1:
-            # valid tokens whose kept groups include one held here, and the
-            # held experts with at least one pair (a grouped matmul reads
-            # nothing of an empty group: the weights this call had to read)
-            size = E_pub // cfg.moe_n_group
-            here_tok = kept_groups[:, first // size:(first + E) // size].any(axis=1)
+        if cfg.moe_grouped:
+            # valid tokens whose kept groups include one held here (under one
+            # group, every valid token), and the held experts with at least
+            # one pair (a grouped matmul reads nothing of an empty group: the
+            # weights this call had to read)
+            if cfg.moe_n_group > 1:
+                size = E_pub // cfg.moe_n_group
+                here_tok = kept_groups[:, first // size:(first + E) // size].any(axis=1)
+            else:
+                here_tok = jnp.ones(x.shape[:1], bool)
             if valid is not None:
                 here_tok = here_tok & valid
             held = jax.lax.dynamic_slice(per_expert, (first,), (E,))
@@ -2489,6 +2671,17 @@ def _gdn_conv(u: jax.Array, kernel: jax.Array, segment_ids: jax.Array):
     return jax.nn.silu(acc)
 
 
+def _padding_joins_the_segment_before(segment_ids: jax.Array) -> jax.Array:
+    """The chunk scans' segment ids: padding belongs to the segment before
+    it, so that with g = 0 and beta = 0 it carries that segment's state
+    across unchanged."""
+    t_idx = jnp.arange(segment_ids.shape[0])
+    last_real = jax.lax.cummax(
+        jnp.where(segment_ids == PADDING_SEGMENT, -1, t_idx), axis=0
+    )
+    return jnp.where(last_real >= 0, segment_ids[jnp.maximum(last_real, 0)], segment_ids)
+
+
 @jax.named_scope("gdn_chunk_scan")
 def _gdn_chunk_scan(q, k, v, g, beta, segment_ids, chunk: int = GDN_CHUNK):
     """The delta rule over one packed stream, a chunk of tokens at a time.
@@ -2503,13 +2696,7 @@ def _gdn_chunk_scan(q, k, v, g, beta, segment_ids, chunk: int = GDN_CHUNK):
     S alone."""
     T, Hv, dk = q.shape
     dv = v.shape[-1]
-    # padding belongs to the segment before it: with g = 0 and beta = 0 it
-    # then carries that segment's state across unchanged
-    t_idx = jnp.arange(T)
-    last_real = jax.lax.cummax(
-        jnp.where(segment_ids == PADDING_SEGMENT, -1, t_idx), axis=0
-    )
-    seg = jnp.where(last_real >= 0, segment_ids[jnp.maximum(last_real, 0)], segment_ids)
+    seg = _padding_joins_the_segment_before(segment_ids)
     pad = (-T) % chunk
     if pad:
         q, k, v, g, beta = (
@@ -2598,6 +2785,27 @@ def gated_delta_net(layer_p: dict, x: jax.Array, segment_ids: jax.Array,
     return out, (S, rows)
 
 
+def _conv_step(conv: jax.Array, ci: int, u: jax.Array, kernel: jax.Array,
+               active: jax.Array | None):
+    """One token of the depthwise convolution for R slots from their cached
+    rows: conv [n_lin, 1 + R, K-1, C], u [R, C] this token's pre-convolution
+    channels, kernel [C, K]. Returns (silu of the mixed channels [R, C]
+    float32, conv with the slots' rows moved on; a slot not `active` keeps
+    its rows)."""
+    with jax.named_scope("conv_state"):
+        rows = conv[ci, 1:]  # [R, K-1, C]
+        window = jnp.concatenate([rows, u[:, None].astype(rows.dtype)], axis=1)
+        w = kernel.astype(jnp.float32)  # [C, K]
+        mixed = jax.nn.silu(
+            jnp.einsum("rkc,ck->rc", window.astype(jnp.float32), w)
+        )
+        new_rows = window[:, 1:]
+        if active is not None:
+            new_rows = jnp.where(active[:, None, None], new_rows, rows)
+        conv = conv.at[ci, 1:].set(new_rows)
+    return mixed, conv
+
+
 def gated_delta_step(layer_p: dict, x: jax.Array, state: dict, ci: int,
                      active: jax.Array | None, cfg: ModelConfig,
                      impl: str = "auto"):
@@ -2608,22 +2816,229 @@ def gated_delta_step(layer_p: dict, x: jax.Array, state: dict, ci: int,
     from areal_tpu.ops.gdn_step import gdn_step
 
     u, z, beta, g = _gdn_project(layer_p, x, cfg)
-    with jax.named_scope("conv_state"):
-        conv = state["conv"]
-        rows = conv[ci, 1:]  # [R, K-1, C]
-        window = jnp.concatenate([rows, u[:, None].astype(rows.dtype)], axis=1)
-        w = layer_p["conv_kernel"].astype(jnp.float32)  # [C, K]
-        mixed = jax.nn.silu(
-            jnp.einsum("rkc,ck->rc", window.astype(jnp.float32), w)
-        )
-        new_rows = window[:, 1:]
-        if active is not None:
-            new_rows = jnp.where(active[:, None, None], new_rows, rows)
-        conv = conv.at[ci, 1:].set(new_rows)
+    mixed, conv = _conv_step(state["conv"], ci, u, layer_p["conv_kernel"], active)
     q, k, v = _gdn_heads(mixed, cfg)
     with jax.named_scope("gdn_step"):
         o, S = gdn_step(state["S"], q, k, v, g, beta, ci, active, impl=impl)
     return _gdn_output(layer_p, o, z, cfg), {"S": S, "conv": conv}
+
+
+# -- Kimi Delta Attention (Kimi-Linear's linear-attention mixer) --------------
+# The Gated DeltaNet's rule with the decay a VECTOR over a head's key lanes:
+#   S <- Diag(exp(g_t)) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;  o_t = S^T q_t
+# q, k and v each behind a projection and a depthwise convolution of its own
+# (one convolution over the channels [q | k | v] with the three kernels side
+# by side: a slot's cache has the Gated DeltaNet's shape), the decay through a
+# low-rank gate `f`, the output through a head norm and a SIGMOID of a
+# low-rank gate `g`. The same two forms: `_kda_chunk_scan` and one step
+# (`ops/gdn_step.py`, the decay a column over the state's sublanes).
+
+KDA_SUB = 16
+
+
+def _kda_project(layer_p: dict, x: jax.Array, cfg: ModelConfig):
+    """x [..., H] -> (u [..., C] pre-convolution channels [q | k | v],
+    beta [..., n] float32, g [..., n, dk] float32 <= 0)."""
+    n, dk = cfg.linear_num_value_heads, cfg.linear_key_head_dim
+    lead = x.shape[:-1]
+    with jax.named_scope("qkv"):
+        u = jnp.concatenate([
+            jnp.einsum("...h,hnd->...nd", x, layer_p[name]).reshape(*lead, -1)
+            for name in ("q_kernel", "k_kernel", "v_kernel")
+        ], axis=-1)
+    with jax.named_scope("kda_gate"):
+        f = jnp.einsum(
+            "...r,rc->...c", jnp.einsum("...h,hr->...r", x, layer_p["f_a_kernel"]),
+            layer_p["f_b_kernel"],
+        ).astype(jnp.float32)
+        g = -jnp.exp(layer_p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+            f + layer_p["dt_bias"].astype(jnp.float32)
+        ).reshape(*lead, n, dk)
+        beta = jax.nn.sigmoid(
+            jnp.einsum("...h,hn->...n", x, layer_p["b_kernel"]).astype(jnp.float32)
+        )
+    return u, beta, g
+
+
+def _kda_conv_kernel(layer_p: dict) -> jax.Array:
+    """The three convolutions' kernels over the channels [q | k | v]: [C, K]."""
+    return jnp.concatenate(
+        [layer_p[f"{name}_conv_kernel"] for name in ("q", "k", "v")], axis=0
+    )
+
+
+def _kda_output(layer_p: dict, o: jax.Array, x: jax.Array, cfg: ModelConfig):
+    """o [..., n, dv] float32, x [..., H] the mixer's input -> [..., H]: per
+    head `w * rmsnorm(o) * sigmoid(gate)` in float32, the gate low-rank from
+    x, then the output projection."""
+    with jax.named_scope("out_gate"):
+        gate = jnp.einsum(
+            "...r,rc->...c", jnp.einsum("...h,hr->...r", x, layer_p["g_a_kernel"]),
+            layer_p["g_b_kernel"],
+        ).astype(jnp.float32).reshape(o.shape)
+        var = jnp.mean(o * o, axis=-1, keepdims=True)
+        o = o * jax.lax.rsqrt(var + cfg.rms_norm_eps) * layer_p["o_norm"].astype(jnp.float32)
+        o = o * jax.nn.sigmoid(gate)
+    with jax.named_scope("out_proj"):
+        return jnp.einsum(
+            "...nd,ndh->...h", o.astype(jnp.dtype(cfg.dtype)), layer_p["o_kernel"]
+        )
+
+
+@jax.named_scope("kda_chunk_scan")
+def _kda_chunk_scan(q, k, v, g, beta, segment_ids, chunk: int = GDN_CHUNK,
+                    sub: int = KDA_SUB):
+    """The delta rule under a VECTOR decay over one packed stream, a chunk of
+    tokens at a time: `_gdn_chunk_scan` with g [T, Hv, dk], a log decay a key
+    lane. Returns (o [T, Hv, dv], S [Hv, dk, dv] after the last token).
+
+    What the scalar decay gives as `exp(G_i - G_j)` times a product of keys is
+    here `sum_l x_il k_jl exp(G_il - G_jl)`, and no `[chunk, chunk, dk]` tensor
+    of it is built for the stream, nor is `exp(-G)` ever taken (a step's log
+    decay reaches -20: it overflows float32 inside one chunk). Within a chunk,
+    sub-blocks of `sub` tokens: a pair (i, j) of different sub-blocks goes
+    through the cumulative log decay at the start b of i's sub-block,
+    `(x_i exp(G_i - G_b)) . (k_j exp(G_b - G_j))`, both exponents <= 0; the
+    pairs inside a sub-block take `exp(G_i - G_j)` lane by lane, a chunk's
+    `[sub, sub, dk]` blocks at a time (`lax.map` over the chunks)."""
+    T, Hv, dk = q.shape
+    dv = v.shape[-1]
+    seg = _padding_joins_the_segment_before(segment_ids)
+    pad = (-T) % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+            for a in (q, k, v, g, beta)
+        )
+        seg = jnp.pad(seg, (0, pad), mode="edge")
+    n, m = (T + pad) // chunk, chunk // sub
+    hp = jax.lax.Precision.HIGHEST
+    # [n, Hv, chunk, ...]: heads ahead of the chunk's tokens
+    q, k, v, g = (a.reshape(n, chunk, Hv, -1).transpose(0, 2, 1, 3) for a in (q, k, v, g))
+    beta = beta.reshape(n, chunk, Hv).transpose(0, 2, 1)
+    seg = seg.reshape(n, chunk)
+    seg_prev = jnp.concatenate(
+        [jnp.full((1,), PADDING_SEGMENT - 1, seg.dtype), seg[:-1, -1]]
+    )
+    cont = (seg == seg_prev[:, None]).astype(jnp.float32)[:, None, :, None]
+    G = jnp.cumsum(g, axis=2)  # [n, Hv, chunk, dk] log decay from the chunk's start
+    # the log decay up to each sub-block's start (its first token excluded)
+    Gb = jnp.concatenate(
+        [jnp.zeros_like(G[:, :, :1]), G[:, :, sub - 1:-1:sub]], axis=2
+    )  # [n, Hv, m, dk]
+    inner = jnp.exp(G - jnp.repeat(Gb, sub, axis=2))  # from its sub-block's start to i
+    # token j's key carried on to the start of sub-block a (j before it)
+    k_to = k[:, :, None] * jnp.exp(
+        jnp.minimum(Gb[:, :, :, None] - G[:, :, None], 0.0)
+    )  # [n, Hv, m, chunk, dk]
+    idx = jnp.arange(chunk)
+    same = (seg[:, :, None] == seg[:, None, :])[:, None]  # [n, 1, chunk, chunk]
+    lower = idx[:, None] >= idx[None, :]
+    strict = idx[:, None] > idx[None, :]
+    before = (idx[:, None] // sub) > (idx[None, :] // sub)  # j in an earlier sub-block
+
+    def within(xs):
+        """One chunk's pairs inside each sub-block, for the queries and for
+        the keys as queries: [Hv, m, sub, sub] each."""
+        q_c, k_c, G_c = (a.reshape(Hv, m, sub, dk) for a in xs)
+        sub_lower = jnp.arange(sub)[:, None] >= jnp.arange(sub)[None, :]
+        decay = jnp.exp(jnp.where(
+            sub_lower[:, :, None], G_c[:, :, :, None] - G_c[:, :, None, :], -jnp.inf
+        ))  # [Hv, m, sub, sub, dk]
+        kd = k_c[:, :, None, :] * decay
+        return (jnp.sum(q_c[:, :, :, None] * kd, axis=-1),
+                jnp.sum(k_c[:, :, :, None] * kd, axis=-1))
+
+    q_in, k_in = jax.lax.map(within, (q, k, G))
+    eye = jnp.eye(m, dtype=q.dtype)
+
+    def pairs(x, x_in):
+        """sum_l x_il k_jl exp(G_il - G_jl) for j <= i: [n, Hv, chunk, chunk]."""
+        across = jnp.einsum(
+            "nhaik,nhajk->nhaij", (x * inner).reshape(n, Hv, m, sub, dk), k_to,
+            precision=hp,
+        ).reshape(n, Hv, chunk, chunk)
+        diag = jnp.einsum("nhaij,ab->nhaibj", x_in, eye).reshape(n, Hv, chunk, chunk)
+        return jnp.where(before, across, diag)
+
+    from_prev = jnp.exp(G) * cont  # what of the carried state token i sees, a lane
+    kb = k * beta[..., None]
+    A = jnp.where(strict & same, pairs(k, k_in), 0.0) * beta[..., None]
+    W = jax.scipy.linalg.solve_triangular(
+        A + jnp.eye(chunk, dtype=A.dtype),
+        jnp.concatenate([v * beta[..., None], kb * from_prev], axis=-1),
+        lower=True, unit_diagonal=True,
+    )
+    attn = jnp.where(lower & same, pairs(q, q_in), 0.0)
+    # what of the carried state, and of each token's write, is left at the
+    # chunk's end, a lane
+    keep = from_prev[:, :, -1]  # [n, Hv, dk]
+    tail = jnp.where(same[:, :, -1, :, None], jnp.exp(G[:, :, -1:] - G), 0.0)
+
+    def step(S, xs):  # S [Hv, dk, dv]
+        q_c, k_c, v_c, k_cum, attn_c, keep_c = xs
+        d = v_c - jnp.einsum("hik,hkv->hiv", k_cum, S, precision=hp)
+        o = jnp.einsum("hik,hkv->hiv", q_c, S, precision=hp) + jnp.einsum(
+            "hij,hjv->hiv", attn_c, d, precision=hp
+        )
+        S = S * keep_c[:, :, None] + jnp.einsum(
+            "hjk,hjv->hkv", k_c, d, precision=hp
+        )
+        return S, o
+
+    S, o = jax.lax.scan(
+        step, jnp.zeros((Hv, dk, dv), jnp.float32),
+        (q * from_prev, k * tail, W[..., :dv], W[..., dv:], attn, keep),
+    )
+    o = o.transpose(0, 2, 1, 3).reshape(n * chunk, Hv, dv)[:T]
+    return o, S
+
+
+def kimi_delta_attention(layer_p: dict, x: jax.Array, segment_ids: jax.Array,
+                         cfg: ModelConfig, true_len: jax.Array | None = None):
+    """The Kimi Delta Attention mixer over one packed stream x [T, H]:
+    `gated_delta_net`'s contract (segments, padding, and with `true_len` the
+    cache to hand over: S at the last real token and the last K-1 real
+    pre-convolution rows)."""
+    u, beta, g = _kda_project(layer_p, x, cfg)
+    real = segment_ids != PADDING_SEGMENT
+    beta = jnp.where(real[:, None], beta, 0.0)
+    g = jnp.where(real[:, None, None], g, 0.0)
+    with jax.named_scope("conv"):
+        q, k, v = _gdn_heads(_gdn_conv(u, _kda_conv_kernel(layer_p), segment_ids), cfg)
+    o, S = _kda_chunk_scan(q, k, v, g, beta, segment_ids)
+    out = _kda_output(layer_p, o, x, cfg)
+    if true_len is None:
+        return out
+    K = cfg.linear_conv_kernel_dim
+    with jax.named_scope("conv_state"):
+        rows = jax.lax.dynamic_slice_in_dim(
+            jnp.pad(u, ((K - 1, 0), (0, 0))), true_len, K - 1, axis=0
+        )
+    return out, (S, rows)
+
+
+def kimi_delta_step(layer_p: dict, x: jax.Array, state: dict, ci: int,
+                    active: jax.Array | None, cfg: ModelConfig,
+                    impl: str = "auto"):
+    """One decode step of Kimi Delta Attention layer number `ci` (among the
+    linear layers) for R slots: `gated_delta_step`'s contract."""
+    from areal_tpu.ops.gdn_step import gdn_step
+
+    u, beta, g = _kda_project(layer_p, x, cfg)
+    mixed, conv = _conv_step(state["conv"], ci, u, _kda_conv_kernel(layer_p), active)
+    q, k, v = _gdn_heads(mixed, cfg)
+    with jax.named_scope("kda_step"):
+        o, S = gdn_step(state["S"], q, k, v, g, beta, ci, active, impl=impl)
+    return _kda_output(layer_p, o, x, cfg), {"S": S, "conv": conv}
+
+
+def _linear_mixers(cfg: ModelConfig) -> tuple:
+    """(the mixer over a packed stream, its decode step) of the model's
+    linear layers."""
+    if cfg.linear_decay_lanes:
+        return kimi_delta_attention, kimi_delta_step
+    return gated_delta_net, gated_delta_step
 
 
 _REMAT_POLICIES = {
@@ -2666,7 +3081,7 @@ def decoder_layer(
     h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
     if cfg.layer_linear(li):
         with jax.named_scope("attn"):
-            x = x + gated_delta_net(layer_p["attn"], h, segment_ids, cfg)
+            x = x + _linear_mixers(cfg)[0](layer_p["attn"], h, segment_ids, cfg)
     else:
         x = x + attention(
             layer_p["attn"], h, cos, sin, segment_ids, mask, cfg, li, horizon
@@ -2755,7 +3170,7 @@ def forward(
                 "tokens",
                 "act_embed",
             )
-    cos, sin = rope_table(position_ids, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
+    cos, sin = _rope_tables(position_ids, cfg)
     # Dense path: build the [T,T] mask ONCE here (outside the per-layer remat
     # region); flash/ring never materialise it.
     # (a mixed stack: one mask for each kind of layer that runs dense)
@@ -2846,7 +3261,7 @@ def _pp_stage_fn(cfg: ModelConfig):
 
     def stage_fn(layers_local, h, aux_t):
         pos, seg = aux_t
-        cos, sin = rope_table(pos, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
+        cos, sin = _rope_tables(pos, cfg)
         horizon = block_horizon(pos, cfg.block_length_)
         more = () if horizon is None else (horizon,)
 
@@ -3161,13 +3576,14 @@ _PAGED_KERNELS = {"full": "paged_attention", "window": "paged_attention_window"}
 def _moe_load_len(cfg: ModelConfig) -> int:
     """Entries of `moe_mlp`'s load vector."""
     return ((3 if cfg.num_experts_published_ != cfg.num_experts else 2)
-            + (2 if cfg.moe_n_group > 1 else 0))
+            + (2 if cfg.moe_grouped else 0))
 
 
 def decode_load_len(cfg: ModelConfig) -> int:
     """Entries of the vector `decode_step_paged` returns under `moe_load`:
     `moe_mlp`'s, for a mixed stack the cached rows read by the full and by
-    the window layers, and where it has linear layers their state updates."""
+    the window layers, where it has linear layers their state updates (one
+    entry), and where it has latent layers the latent rows read."""
     return (_moe_load_len(cfg) + (2 if cfg.mixed else 0)
             + (1 if cfg.cache_layers["state"] else 0)
             + (1 if cfg.latent else 0))
@@ -3317,7 +3733,7 @@ def prefill(
     if rope_cos is not None:
         cos, sin = rope_cos, rope_sin
     else:
-        cos, sin = rope_table(position_ids, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
+        cos, sin = _rope_tables(position_ids, cfg)
     T = input_ids.shape[0]
     with_prefix = prefix_k is not None
     # above `PREFILL_DENSE_MAX` tokens no [T, T] score tensor is built: the
@@ -3389,7 +3805,7 @@ def prefill(
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         if cfg.layer_linear(li):
             with jax.named_scope("attn"):
-                out, cache = gated_delta_net(
+                out, cache = _linear_mixers(cfg)[0](
                     layer_p["attn"], h, seq_segments, cfg, true_len
                 )
             return _attn_out_mlp(
@@ -3721,7 +4137,7 @@ def decode_step_paged(
     nH, hd = cfg.num_attention_heads, cfg.head_dim_
     x = _embed_tokens(params, tokens, positions, cfg)  # [R, H]
     rope_pos = positions if rope_offset is None else positions + rope_offset
-    cos, sin = rope_table(rope_pos, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
+    cos, sin = _rope_tables(rope_pos, cfg)
     valid = jnp.arange(span)[None, :] <= positions[:, None]  # [R, span]
     mixed = isinstance(k_pool, dict)
     if cfg.sliding_window is not None and not mixed:
@@ -3760,7 +4176,7 @@ def decode_step_paged(
         with jax.named_scope("attn"):
             if cfg.layer_linear(li):
                 # no rows to write or read: the slot's state, updated in place
-                attn_out, state = gated_delta_step(
+                attn_out, state = _linear_mixers(cfg)[1](
                     layer_p["attn"], h, kp["state"], index[li][1], active, cfg,
                     attn_impl,
                 )
@@ -3823,7 +4239,7 @@ def decode_step_paged(
                 load = jnp.concatenate([load, updates[None].astype(load.dtype)])
             if cfg.latent:
                 # cached latent rows read: live slots' rows x latent layers
-                rows = (where["latent"][3] & live).sum() * cfg.num_hidden_layers
+                rows = (where["latent"][3] & live).sum() * len(cfg.cache_layers["latent"])
                 load = jnp.concatenate([load, rows[None].astype(load.dtype)])
         return logits, k_pool, v_pool, load
     return logits, k_pool, v_pool
@@ -3872,7 +4288,7 @@ def _qlen_step_paged(
     rope_pos = (
         positions if rope_offset is None else positions + rope_offset[:, None]
     ).reshape(-1)
-    cos, sin = rope_table(rope_pos, cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)
+    cos, sin = _rope_tables(rope_pos, cfg)
     if block:
         # block-causal: every query of a block sees to the block's last row
         B = cfg.block_length_

@@ -1,5 +1,5 @@
-"""Gated DeltaNet decode step: one recurrent update of every live slot's
-state, IN PLACE.
+"""Gated DeltaNet / Kimi Delta Attention decode step: one recurrent update
+of every live slot's state, IN PLACE.
 
 A linear layer's cache is no rows of keys and values but a state a slot:
 `S` [linear layers, 1 + slots, Hv, dk, dv] float32 (row 0 the null slot,
@@ -7,10 +7,12 @@ which stays zero). One token step of layer `ci` is, per slot and value head,
 
     S <- exp(g) S;  d = beta (v - S^T k);  S <- S + k d^T;  o = S^T q
 
-(float32 throughout). The step's bytes are the state itself, once in and
-once out, whatever the context's length: like the paged pool the op takes
-the WHOLE array and a layer index, so that a scan's carry is updated in
-place and never sliced.
+(float32 throughout), with `g` one number a head (Gated DeltaNet: `g`
+[R, Hv]) or a vector over the head's key lanes (Kimi Delta Attention: `g`
+[R, Hv, dk], `S <- Diag(exp(g)) S`); the shape of `g` chooses. The step's
+bytes are the state itself, once in and once out, whatever the context's
+length: like the paged pool the op takes the WHOLE array and a layer index,
+so that a scan's carry is updated in place and never sliced.
 
 Two implementations behind one signature, selected like `paged_attention`'s:
 
@@ -22,7 +24,9 @@ Two implementations behind one signature, selected like `paged_attention`'s:
   copies the (zero) slab through and computes nothing. The arithmetic is
   on the vector unit, exact float32: `k` and `q` come transposed
   `[slot, head block, dk, heads]` so that a head's key is a column that broadcasts along
-  the state's lanes, and `exp(g)`, `beta` and `v` come as rows.
+  the state's lanes, and `exp(g)`, `beta` and `v` come as rows; a vector
+  decay comes as a column block beside `k` and `q`, the call named `kda_step`
+  so that a trace tells the two apart.
 - `"xla"` (CPU / tests): the same arithmetic in `jax.numpy` over the layer's
   rows, written back with one dynamic-update-slice.
 """
@@ -45,8 +49,10 @@ HEADS_PER_STEP = 8
 
 def gdn_step_reference(S, q, k, v, g, beta):
     """The update on bare rows: S [R, Hv, dk, dv], q, k [R, Hv, dk],
-    v [R, Hv, dv], g, beta [R, Hv], float32. Returns (o [R, Hv, dv], S)."""
-    S = S * jnp.exp(g)[..., None, None]
+    v [R, Hv, dv], beta [R, Hv], g [R, Hv] or a lane [R, Hv, dk], float32.
+    Returns (o [R, Hv, dv], S)."""
+    S = S * (jnp.exp(g)[..., None, None] if g.ndim == beta.ndim
+             else jnp.exp(g)[..., None])
     m = jnp.sum(S * k[..., :, None], axis=-2)
     d = beta[..., None] * (v - m)
     S = S + k[..., :, None] * d[..., None, :]
@@ -63,7 +69,7 @@ def _xla_step(S, q, k, v, g, beta, layer, active):
 
 
 def _kernel(row_ref, s_ref, qt_ref, kt_ref, v_ref, decay_ref, beta_ref,
-            o_ref, s_out_ref, *, heads: int):
+            o_ref, s_out_ref, *, heads: int, decay_lanes: bool):
     r = pl.program_id(0)
 
     @pl.when(row_ref[r] == 0)
@@ -75,7 +81,10 @@ def _kernel(row_ref, s_ref, qt_ref, kt_ref, v_ref, decay_ref, beta_ref,
     @pl.when(row_ref[r] != 0)
     def _():
         for h in range(heads):
-            S = s_ref[0, 0, h] * decay_ref[0, h:h + 1, :]  # [dk, dv] * [1, dv]
+            if decay_lanes:  # a factor a key lane: [dk, dv] * [dk, 1]
+                S = s_ref[0, 0, h] * decay_ref[0, 0, :, h:h + 1]
+            else:
+                S = s_ref[0, 0, h] * decay_ref[0, h:h + 1, :]  # [dk, dv] * [1, dv]
             k_col = kt_ref[0, 0, :, h:h + 1]  # [dk, 1]
             m = jnp.sum(S * k_col, axis=0, keepdims=True)  # [1, dv]
             d = beta_ref[0, h:h + 1, :] * (v_ref[0, h:h + 1, :] - m)
@@ -98,8 +107,13 @@ def _pallas_step(S, q, k, v, g, beta, layer, active, interpret):
     qt, kt = (
         jnp.swapaxes(t.reshape(R, Hv // hb, hb, dk), 2, 3) for t in (q, k)
     )
-    # exp(g) and beta as rows over the state's lanes: [R, Hv, dv]
-    decay = jnp.broadcast_to(jnp.exp(g)[..., None], (R, Hv, dv))
+    # exp(g) and beta as rows over the state's lanes: [R, Hv, dv]; a decay a
+    # key lane as columns like q^T and k^T
+    decay_lanes = g.ndim == 3
+    if decay_lanes:
+        decay = jnp.swapaxes(jnp.exp(g).reshape(R, Hv // hb, hb, dk), 2, 3)
+    else:
+        decay = jnp.broadcast_to(jnp.exp(g)[..., None], (R, Hv, dv))
     beta_b = jnp.broadcast_to(beta[..., None], (R, Hv, dv))
 
     def per_head(r, j, row):  # q^T / k^T: heads on the lanes
@@ -119,7 +133,8 @@ def _pallas_step(S, q, k, v, g, beta, layer, active, interpret):
             pl.BlockSpec((1, 1, dk, hb), per_head),
             pl.BlockSpec((1, 1, dk, hb), per_head),
             pl.BlockSpec((1, hb, dv), per_row),
-            pl.BlockSpec((1, hb, dv), per_row),
+            (pl.BlockSpec((1, 1, dk, hb), per_head) if decay_lanes
+             else pl.BlockSpec((1, hb, dv), per_row)),
             pl.BlockSpec((1, hb, dv), per_row),
         ],
         out_specs=[
@@ -128,7 +143,7 @@ def _pallas_step(S, q, k, v, g, beta, layer, active, interpret):
         ],
     )
     o, S = pl.pallas_call(
-        functools.partial(_kernel, heads=hb),
+        functools.partial(_kernel, heads=hb, decay_lanes=decay_lanes),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((R, Hv, dv), jnp.float32),
@@ -137,7 +152,7 @@ def _pallas_step(S, q, k, v, g, beta, layer, active, interpret):
         # operand 0 is the scalar-prefetch vector; the state is operand 1
         input_output_aliases={1: 1},
         interpret=interpret,
-        name="gdn_step",
+        name="kda_step" if decay_lanes else "gdn_step",
     )(slot_row, S, qt, kt, v, decay, beta_b)
     return o, S
 
@@ -148,8 +163,8 @@ def gdn_step(S, q, k, v, g, beta, layer: int, active=None, *,
 
     S [n_lin, 1 + R, Hv, dk, dv] float32 (row 0 the null slot); q, k
     [R, Hv, dk] and v [R, Hv, dv] float32 (q and k normalised, q scaled);
-    g [R, Hv] float32 log decay (<= 0), beta [R, Hv] float32; `active`
-    [R] bool: a slot that is not active keeps its state (its output is
+    g [R, Hv] float32 log decay (<= 0), or a key lane [R, Hv, dk]; beta
+    [R, Hv] float32; `active` [R] bool: a slot that is not active keeps its state (its output is
     unspecified). Returns (o [R, Hv, dv] float32, S)."""
     q, k, v, g, beta = (t.astype(jnp.float32) for t in (q, k, v, g, beta))
     if resolve_impl(impl) != "pallas":

@@ -490,7 +490,10 @@ def test_decode_under_foreign_global_mesh(cpu_devices):
             timeout=2400,
         )
         assert resp.output_len == 5
-        expected = greedy_reference(eng.params, prompt, 5)
+        # (the reference is this thread's eager `forward` over the engine's
+        # one-device params: under no mesh, as the engine's own thread is)
+        with mesh_lib.mesh_scope(None):
+            expected = greedy_reference(eng.params, prompt, 5)
         assert resp.output_tokens == expected
     finally:
         if eng is not None:

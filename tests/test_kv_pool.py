@@ -353,14 +353,17 @@ import os  # noqa: E402
 
 from test_deepseek_v2 import FULL as LATENT  # noqa: E402
 from test_kexaone import FULL as RING  # noqa: E402 — window 8: 3 pages of 4, slack 4
+from test_kimi_linear import FULL as STATE_LATENT  # noqa: E402 — a state AND latent rows a slot
 from test_qwen3next import FULL as STATE  # noqa: E402
 from test_sdar import CFG as BLOCK  # noqa: E402 — blocks of 4
 
 from areal_tpu.engine import kv_pool  # noqa: E402
 from areal_tpu.engine.kv_pool import SlotCache  # noqa: E402
 
-CACHES = {"uniform": TINY, "ring": RING, "state": STATE, "latent": LATENT, "block": BLOCK}
+CACHES = {"uniform": TINY, "ring": RING, "state": STATE, "latent": LATENT, "block": BLOCK,
+          "state_latent": STATE_LATENT}
 KINDS = sorted(CACHES)
+HAS_STATE = ("state", "state_latent")  # one recurrent state a slot: good for one length
 R, BS, NB = 4, 4, 65
 
 
@@ -380,7 +383,8 @@ def _published(name, **over):
 def test_cache_kinds_and_what_is_cached_at_admission(kind):
     cache = _cache(kind)
     assert cache.kinds == {"uniform": (), "ring": ("pools", "window"), "state": ("pools", "state"),
-                           "latent": ("pools", "latent"), "block": ("block",)}[kind]
+                           "latent": ("pools", "latent"), "block": ("block",),
+                           "state_latent": ("pools", "state", "latent")}[kind]
     if kind == "block":
         # whole blocks; a slot's rows are its tokens
         assert [cache.cover(n) for n in (3, 4, 9, 16)] == [0, 4, 8, 16]
@@ -402,7 +406,7 @@ def test_what_a_prefill_scatters_through(kind):
     if kind == "ring":
         assert len(tables) == 2
         np.testing.assert_array_equal(tables[1], cache.ring.blocks(2))
-    elif kind == "state":
+    elif kind in HAS_STATE:
         assert len(tables) == 2 and tables[1] == cache.state.row(2) == 3
         assert tables[1].dtype == np.int32
     else:
@@ -419,14 +423,14 @@ def test_whether_a_slot_still_holds_a_prefix(kind):
     lengths = np.array([0, 24, 0, 0])
     active = np.arange(R) == 1
     cache.written(active, lengths)
-    assert cache.holds(1, 20) == (kind != "state")
+    assert cache.holds(1, 20) == (kind not in HAS_STATE)
     assert cache.holds(1, 24)
     # written past the window's room: rows 13.. are gone from the ring
     cache.written(active, np.array([0, 40, 0, 0]))
-    assert cache.holds(1, 20) == (kind not in ("state", "ring"))
+    assert cache.holds(1, 20) == (kind not in (*HAS_STATE, "ring"))
     # the slot starts over with no prefill
     copies = cache.zero(1)
-    assert [fn.__name__ for fn, *_ in copies] == (["zero"] if kind == "state" else [])
+    assert [fn.__name__ for fn, *_ in copies] == (["zero"] if kind in HAS_STATE else [])
     assert cache.holds(1, 0) and cache.holds(1, 20) == (kind in ("uniform", "latent", "block"))
 
 
@@ -437,7 +441,9 @@ def test_what_a_fork_aliases_and_what_it_copies(kind, monkeypatch):
     cache = _cache(kind)
     assert cache.alloc.ensure(0, 12)
     cache.rewritten(0, 10)
-    extra = {"ring": ["fork_ring"], "state": ["fork_state"]}.get(kind, [])
+    # (a state beside latent rows: the blocks aliased and the state copied, in one fork)
+    extra = {"ring": ["fork_ring"], "state": ["fork_state"],
+             "state_latent": ["fork_state"]}.get(kind, [])
     # 10 rows: two blocks aliased, the third (2 rows) copied
     copies = cache.fork(0, 1, 10)
     assert [fn.__name__ for fn, *_ in copies] == ["fork_block"] + extra
@@ -445,11 +451,11 @@ def test_what_a_fork_aliases_and_what_it_copies(kind, monkeypatch):
     np.testing.assert_array_equal(cache.alloc.tables[1, :2], cache.alloc.tables[0, :2])
     assert copies[0][1] == cache.alloc.tables[0, 2] and copies[0][2] == cache.alloc.tables[1, 2]
     # the copy holds what the donor held: a state its one length
-    assert cache.holds(1, 10) and cache.holds(1, 8) == (kind != "state")
+    assert cache.holds(1, 10) and cache.holds(1, 8) == (kind not in HAS_STATE)
     if kind == "ring":
         np.testing.assert_array_equal(copies[1][1], cache.ring.blocks(0))
         np.testing.assert_array_equal(copies[1][2], cache.ring.blocks(1))
-    if kind == "state":
+    if kind in HAS_STATE:
         assert copies[1][1:] == (1, 2)
     # a block-aligned boundary copies no block; a slot onto itself nothing at all
     assert [fn.__name__ for fn, *_ in cache.fork(0, 2, 8)] == extra
@@ -483,6 +489,13 @@ def test_the_pools_are_the_trees_the_programs_take(kind):
         assert shapes(v) == {"full": ((2, NB, *row), "float32")}
         assert shapes(k) == {**shapes(v), "state": {
             "S": ((6, 1 + R, 8, 16, 16), "float32"), "conv": ((6, 1 + R, 3, 256), "float32")}}
+    elif kind == "state_latent":
+        # the K-side dict holds the latent pool (the attention layers alone) and
+        # the state; nothing on the V side
+        assert v == {} and shapes(k) == {
+            "latent": ((1, NB, BS, cfg.latent_row_lanes), "float32"),
+            "state": {"S": ((3, 1 + R, 4, 16, 16), "float32"),
+                      "conv": ((3, 1 + R, 3, 192), "float32")}}
     else:
         assert shapes(k) == {"latent": ((L, NB, BS, cfg.latent_row_lanes), "float32")} and v == {}
     assert all(not np.asarray(a).any() for a in jax.tree.leaves((k, v)))
@@ -492,7 +505,7 @@ def test_the_pools_are_the_trees_the_programs_take(kind):
 
     live, pages = cache.walk(np.array([1, 9, 64]), 16)
     np.testing.assert_array_equal(live, [1, 3, 16])
-    if kind == "latent":
+    if kind in ("latent", "state_latent"):
         assert pages == paged_attention_latent.PAGES_PER_GROUP
     else:
         pool = k["full"] if isinstance(k, dict) else k
@@ -516,8 +529,11 @@ def test_an_int8_pool_has_its_scale_pools_and_a_uniform_window_its_columns():
 
 @pytest.mark.parametrize("kind,tail,want", [
     ("ring", [5, 7], dict(full=5, window=7, latent=0, state=0)),
-    ("state", [5, 0, 3, 4], dict(full=5, window=0, latent=0, state=7)),
+    # ONE entry of state updates: all `models/qwen2.py:decode_load_len` emits
+    ("state", [5, 0, 7], dict(full=5, window=0, latent=0, state=7)),
     ("latent", [0, 0, 9], dict(full=0, window=0, latent=9, state=0)),
+    # state updates, then latent rows (models/qwen2.py: decode_step_paged)
+    ("state_latent", [0, 0, 3, 9], dict(full=0, window=0, latent=9, state=3)),
 ])
 def test_a_chunks_counters_of_rows_read_by_kind(kind, tail, want):
     assert _cache(kind).rows_read(tail) == want
@@ -531,6 +547,9 @@ def test_a_chunks_counters_of_rows_read_by_kind(kind, tail, want):
     ("deepseek-v2", {}, 1280, None, 0),
     # PERF.md section 3: a linear layer's state update a slot, in and out
     ("qwen3-next-80b-a3b", {}, 2 * 2 * 256 * 2, None, 4_292_608),
+    # the same 640-lane row over the 2 latent layers, and a KDA layer's update:
+    # 2 MiB of state and 72 KiB of convolution rows, in and out
+    ("kimi-linear-48b-a3b", {}, 1280, None, 4_341_760),
     ("k-exaone-236b-a23b", {}, 2 * 8 * 128 * 2, None, 0),
     ("sdar-30b-a3b-chat", {}, 2 * 4 * 128 * 2, None, 0),
 ])
@@ -563,6 +582,8 @@ REFUSED = {
     "state":   "R R R R R . . .",
     "latent":  "R R R R R R R R",
     "block":   "R R R R R . . R",
+    # the union of the `state` and the `latent` rows
+    "state_latent": "R R R R R R R R",
 }
 
 

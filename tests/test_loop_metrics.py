@@ -311,6 +311,12 @@ def test_decode_idle_is_open_while_nothing_is_queued(engine):
         assert {"decode/pass", "decode/admit", "decode/prefill", "decode/dispatch_chunk",
                 "decode/consume_chunk", "decode/wait_device", "decode/idle"} <= names
         _generate(eng)  # traffic ends the idle period
+        # (the response returns before the pass ends: on busy cores the thread's
+        # next idle span, the second, may not have begun yet)
+        deadline = time.monotonic() + 30.0
+        while time.monotonic() < deadline and sum(
+                s["name"] == "decode/idle" for s in rec.snapshot()) < 2:
+            time.sleep(0.01)
     idle = [s for s in rec.snapshot() if s["name"] == "decode/idle"]
     assert len(idle) >= 2 and not idle[0]["open"]
     assert idle[0]["end_ns"] - idle[0]["start_ns"] >= 0.09e9

@@ -171,7 +171,10 @@ def test_every_path_takes_the_rotary_width_from_the_config():
     import inspect
 
     src = inspect.getsource(qwen2)
-    assert src.count("cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)") == 5
+    # (one place builds it, `_rope_tables`, which a model with no positional
+    # encoding answers with no table; the five paths call that)
+    assert src.count("cfg.rotary_dim, cfg.rope_theta, cfg.rope_scaling_)") == 1
+    assert src.count("_rope_tables(") == 1 + 5
     assert "cfg.head_dim_, cfg.rope_theta" not in src
 
 

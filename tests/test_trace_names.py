@@ -167,15 +167,17 @@ def _drive_the_loop(eng, params, rec):
     from areal_tpu.api.io_struct import WeightUpdateMeta
 
     eng.prepare_batch(_Loader(), workflow=_OneRequest())
-    eng.pause()
+    eng.pause()  # (the loop's: it stops admitting episodes, not the engine's generation)
+    eng.update_weights_from_distributed(WeightUpdateMeta(type="memory"), params, TINY)
+    eng.set_version(1)
     # the push of a tiny tree is over before a scheduler thread on busy cores
-    # has looked at the flag: hold the pause until the thread is in it
+    # has looked at the flag: hold a pause of generation until the thread is in it
+    eng.pause_generation()
     deadline = time.monotonic() + 30.0
     while time.monotonic() < deadline and not any(
             s["name"] == "decode/paused" for s in rec.snapshot()):
         time.sleep(0.005)
-    eng.update_weights_from_distributed(WeightUpdateMeta(type="memory"), params, TINY)
-    eng.set_version(1)
+    eng.continue_generation()
     eng.resume()
     # with nothing left to serve the thread goes idle, and closes its spans
     # when it gets a core: wait for the last of them as for the pause above
@@ -690,6 +692,101 @@ def test_gdn_step_kernel_is_named_at_qwen3_nexts_state_shape(one_chip):
     state = f"f32[{n},{1 + R},{Hv},{dk},{dv}]"
     copies = [ln for ln in hlo.splitlines() if " copy(" in ln and state in ln]
     assert not copies, copies
+
+
+def test_kda_step_kernel_is_named_at_kimi_linears_state_shape(one_chip):
+    """128 slots x 32 heads of 128 x 128 float32, six KDA layers in one pool
+    (Kimi-Linear), the decay a vector over the key lanes: ONE Mosaic call
+    named `%kda_step` (the Gated DeltaNet's frame, the decay a column block
+    beside q^T and k^T), the whole pool its operand and (aliased) its result,
+    no copy of its 1.6 GB beside the call."""
+    from areal_tpu.ops.gdn_step import gdn_step
+
+    n, R, Hv, dk, dv = 6, 128, 32, 128, 128
+    f32 = jnp.float32
+
+    def step(S, q, k, v, g, beta, active):
+        with jax.named_scope("layer"):
+            return gdn_step(S, q, k, v, g, beta, 4, active, impl="pallas", interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((n, 1 + R, Hv, dk, dv), f32), ((R, Hv, dk), f32), ((R, Hv, dk), f32),
+        ((R, Hv, dv), f32), ((R, Hv, dk), f32), ((R, Hv), f32), ((R,), jnp.bool_))]
+    hlo = jax.jit(step, donate_argnums=0).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    kernels = _mosaic_kernels(hlo)
+    assert len(kernels) == 1 and kernels[0].startswith("kda_step") and " while(" not in hlo
+    assert "%kda_step" in hlo and "%gdn_step" not in hlo and "%layer" not in hlo
+    state = f"f32[{n},{1 + R},{Hv},{dk},{dv}]"
+    copies = [ln for ln in hlo.splitlines() if " copy(" in ln and state in ln]
+    assert not copies, copies
+
+
+def test_latent_kernel_is_named_at_kimi_linears_widths(one_chip):
+    """32 heads against the same 640-lane row at 128 slots, two latent layers
+    in the pool (Kimi-Linear: 60 FLOP a byte where DeepSeek-V2's 128 heads
+    give 242): the same one Mosaic call named `%paged_attention_latent`, the
+    cell's pool read in place."""
+    from areal_tpu.ops.paged_attention_latent import paged_attention_latent
+
+    R, nH, D, dv, bsz, nb, L = 128, 32, 640, 512, 128, 64, 2
+    blocks = 1048576 // bsz + 1
+    pool = ((L, blocks, bsz, D), jnp.bfloat16)
+
+    def step(q, kp, bt, valid, li, lo, hi):
+        with jax.named_scope("layer"):
+            return paged_attention_latent(q, kp, bt, valid, li, dv=dv, sm_scale=192 ** -0.5,
+                                          impl="pallas", interpret=False, live=(lo, hi))
+
+    hlo = _compile(
+        step, one_chip, ((R, nH, D), jnp.bfloat16), pool, ((R, nb), jnp.int32),
+        ((R, nb * bsz), jnp.bool_), ((), jnp.int32), ((R,), jnp.int32), ((R,), jnp.int32))
+    assert "tpu_custom_call" in hlo and "%layer" not in hlo
+    assert _one_paged_kernel(hlo, R).startswith("paged_attention_latent")
+    big = [ln for ln in hlo.splitlines() if f"bf16[{L},{blocks},{bsz},{D}]" in ln
+           and " copy(" in ln]
+    assert not big, big
+
+
+def test_kimi_linears_prefill_has_a_width_the_compiler_takes_at_1536(one_chip):
+    """XLA:TPU (libtpu 0.0.34) refused one prompt bucket of this model at
+    COMPILE time and killed the scheduler in the warm-up (PR 45, first chip
+    call): 1,536 tokens, a fusion of its own around `mlp/dispatch`'s row
+    gather out of scoped VMEM. The engine's rule for a refused prefill
+    (`_PrefillOrWider`: the same prompt as a pass a bucket or two wider, rows
+    cut back) ends on a program the compiler takes, at the published widths'
+    first four layers (KDA dense, KDA, KDA, MLA); which width that is belongs
+    to the compiler."""
+    import json
+    import os
+
+    from areal_tpu.engine.jax_decode import _PrefillOrWider
+    from areal_tpu.models import qwen2
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark/configs/kimi-linear-48b-a3b.json")) as f:
+        hf = dict(json.load(f), num_hidden_layers=4)
+    cfg = ModelConfig.from_hf_config(hf, dtype="bfloat16", param_dtype="bfloat16")
+    T = 1536
+    p = jax.eval_shape(lambda: qwen2.init_params(cfg, jax.random.PRNGKey(0)))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), p)
+    ids = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+
+    def program(tokens):
+        def prefill_batched(p, ids_b, lens_b):  # one prompt a program, as the engine builds it
+            ids_b = jnp.pad(ids_b, ((0, 0), (0, tokens - T)))
+            _, k, *rest = jax.vmap(lambda ids, n: qwen2.prefill(
+                p, ids, jnp.arange(tokens), cfg, valid=jnp.arange(tokens) < n,
+                with_logits=False))(ids_b, lens_b)
+            return k[:, :, :T], rest
+
+        return lambda *args: jax.jit(prefill_batched).trace(*args).lower(
+            lowering_platforms=("tpu",)).compile()
+
+    taken = _PrefillOrWider(program, T)
+    assert "ragged-dot" in taken(p, ids, lens).as_text()
+    assert T <= taken.tokens <= T + 128
 
 
 def test_paged_kernel_is_named_at_qwen3_nexts_head_shape(one_chip):

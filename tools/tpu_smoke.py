@@ -405,7 +405,7 @@ def paged_block_case(nH=32, nKV=4, hd=128, B=4, R=16, nb=4, L=3, layer=2):
     return err < 0.03 and bool(jnp.all(jnp.isfinite(out))), f"maxerr={err:.4f}"
 
 
-def latent_case(mix, nH=128, C=512, rope=64, R=16, nb=10, L=5, layer=3):
+def latent_case(mix, nH=128, C=512, rope=64, R=16, nb=10, L=5, layer=3, scale=0.11472):
     """The latent decode kernel (`ops/paged_attention_latent.py`) at
     DeepSeek-V2's widths: 128 heads against ONE shared row of 512 + 64 lanes
     stored at 640, the first 512 summed, over a five-layer pool read at a
@@ -431,7 +431,6 @@ def latent_case(mix, nH=128, C=512, rope=64, R=16, nb=10, L=5, layer=3):
         active = r % 3 != 1
     valid = jnp.arange(span)[None, :] < jnp.asarray(length)[:, None]
     active = jnp.asarray(active)
-    scale = 0.11472
     kernel = jax.jit(lambda q, pool, valid: paged_attention_latent(
         q, pool, bt, valid, jnp.int32(layer), dv=C, sm_scale=scale, impl="pallas",
         interpret=False, live=live_block_range(valid, PAGE, active)))
@@ -455,11 +454,13 @@ def latent_case(mix, nH=128, C=512, rope=64, R=16, nb=10, L=5, layer=3):
                 f"us_per_live_page={1e3 * ms / pages:.3f} ({pages} pages)")
 
 
-def gdn_step_case(n=6, layer=4, R=64, Hv=32, dk=128, dv=128, dead_every=7):
+def gdn_step_case(n=6, layer=4, R=64, Hv=32, dk=128, dv=128, dead_every=7, lanes=False):
     """The Gated DeltaNet decode step (`ops/gdn_step.py`) at Qwen3-Next's
     state shape: six linear layers' float32 states in one pool, the kernel
     against the op's `jax.numpy` arithmetic; some slots not active, whose
-    rows, the other layers' and the null row must come back to the bit."""
+    rows, the other layers' and the null row must come back to the bit.
+    `lanes`: the decay a vector over the key lanes (Kimi Delta Attention:
+    the call named `kda_step`)."""
     from areal_tpu.ops.gdn_step import gdn_step
 
     ks = jax.random.split(jax.random.PRNGKey(7), 6)
@@ -468,7 +469,7 @@ def gdn_step_case(n=6, layer=4, R=64, Hv=32, dk=128, dv=128, dead_every=7):
     k = jax.random.normal(ks[2], (R, Hv, dk))
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     v = jax.random.normal(ks[3], (R, Hv, dv))
-    g = -4.0 * jax.random.uniform(ks[4], (R, Hv))
+    g = -4.0 * jax.random.uniform(ks[4], (R, Hv, dk) if lanes else (R, Hv))
     beta = jax.random.uniform(ks[5], (R, Hv))
     active = jnp.arange(R) % dead_every != 3
     o, S1 = jax.jit(lambda *a: gdn_step(*a, layer, active, impl="pallas", interpret=False))(
@@ -718,6 +719,10 @@ def cases():
     # attention's head shape through the paged kernel (decode and ragged)
     out.append(("gdn_step 6 layers x 64 slots x 32 heads of 128x128 float32, in place",
                 "default for models with linear layers", gdn_step_case))
+    # Kimi-Linear: the same frame under a vector decay, at the cell's 128 slots
+    out.append(("kda_step 6 layers x 128 slots x 32 heads of 128x128 float32, decay a key lane",
+                "default for models with Kimi Delta Attention layers",
+                lambda: gdn_step_case(R=128, lanes=True)))
     out.append(("paged_attention W=1 bf16 16/2/256", "default for qwen3_next",
                 lambda: paged_case(1, False, 16, 2, 256)))
     out.append(("paged_attention live range, 30% W=1 bf16 16/2/256 nb=10",
@@ -756,6 +761,10 @@ def cases():
     for mix in ("ragged", "deep"):
         out.append((f"paged_attention_latent bf16 128 heads x (512 + 64), {mix} page={PAGE}",
                     "default for latent-attention models", lambda mix=mix: latent_case(mix)))
+    # Kimi-Linear: the same kernel at 32 heads, two latent layers, no rotation
+    out.append((f"paged_attention_latent bf16 32 heads x (512 + 64), ragged page={PAGE}",
+                "default for kimi_linear",
+                lambda: latent_case("ragged", nH=32, L=2, layer=1, scale=192 ** -0.5)))
     out.append((
         "moe_mlp top-6 of 160, 20 held (ragged_dot) T=64 5120x1536",
         "default for MoE models with a share of the experts held",

@@ -14,7 +14,11 @@ an `.xplane.pb`, or the Chrome JSON that `AREAL_TPU_PERF_TRACE=1` leaves in
              the `op_name` the trace keeps in each operation's metadata (a
              linear layer's mixer shows as `layer/attn/gdn_chunk_scan` in a
              prefill, `layer/attn/gdn_step` and `layer/attn/conv_state` in a
-             decode step, beside `attention_full` of the gated layers)
+             decode step, beside `attention_full` of the gated layers; a Kimi
+             Delta Attention layer as `layer/attn/` + `qkv`, `conv`,
+             `kda_gate`, `kda_chunk_scan` or `conv_state`, `kda_step`, then
+             `out_gate`, `out_proj`, beside the latent layers' `q_proj`,
+             `kv_latent`, `absorb_q`, `latent_attention`, `absorb_out`)
   spans      the program's `areal/` spans (and the benchmark's `bench/`): count,
              total and self time (total minus the spans nested in it)
   idle gaps  every gap of chip 0 over `--gap-ms`, summed by the innermost
