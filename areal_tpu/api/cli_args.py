@@ -136,9 +136,6 @@ class JaxEngineConfig:
 
     # Which mesh axes shard parameters ZeRO-style; () replicates.
     fsdp_axes: list[str] = field(default_factory=lambda: ["fsdp"])
-    # jax.checkpoint policy: "none" | "full" | "dots_saveable" |
-    # "dots_with_no_batch_dims_saveable"
-    remat_policy: str = "full"
     # Fused LM-head loss: apply the head + cross-entropy in vocab chunks
     # (ops/fused_xent.py) so the f32 [tokens, vocab] logits never
     # materialize — lifts the micro-batch HBM ceiling the dense path hits
@@ -206,6 +203,9 @@ class TrainEngineConfig:
     mb_spec: MicroBatchSpec = field(default_factory=MicroBatchSpec)
     pad_to_maximum: bool = False
     disable_dropout: bool = True
+    # the trainer may recompute a layer's forward in its backward. What it
+    # keeps instead is computed a grad-step shape from the chip's room
+    # (engine/jax_engine.py:_remat_kept); false keeps everything.
     gradient_checkpointing: bool = True
     dtype: str = "bfloat16"
     # dtype of the cross-micro-batch gradient accumulator. It is SHARDED

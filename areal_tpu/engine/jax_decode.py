@@ -989,6 +989,14 @@ class JaxDecodeEngine(InferenceEngine):
             target=self._scheduler_loop, daemon=True, name="jax-decode-scheduler"
         )
         self._thread.start()
+        # for a trainer on these chips to plan its step's memory around
+        # (utils/hbm.py:declare_resident): the weights and the pools
+        from areal_tpu.utils import hbm
+
+        hbm.declare_resident(self, hbm.sharded_bytes(jax.tree.leaves((
+            self.params, self._vision_params, self._k_cache, self._v_cache,
+            self._k_scale, self._v_scale,
+        ))))
         return self
 
     def state_pool(self) -> dict | None:
@@ -1002,6 +1010,9 @@ class JaxDecodeEngine(InferenceEngine):
 
     def destroy(self):
         self._shutdown.set()
+        from areal_tpu.utils import hbm
+
+        hbm.declare_resident(self, 0)
         if self._thread is not None:
             self._thread.join(timeout=5)
             self._thread = None

@@ -30,6 +30,7 @@ Design (TPU-first):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import threading
 import time
@@ -64,7 +65,7 @@ from areal_tpu.models.qwen2 import (
 from areal_tpu.ops.flash_attention import live_block_counts
 from areal_tpu.ops.ring_attention import cp_ring_shards, zigzag_eligible
 from areal_tpu.parallel import mesh as mesh_lib
-from areal_tpu.utils import logging, name_resolve, names, perf_tracer
+from areal_tpu.utils import hbm, logging, name_resolve, names, perf_tracer
 from areal_tpu.utils.data import (
     MicroBatchList,
     split_padded_tensor_dict_into_mb_list,
@@ -361,6 +362,9 @@ class JaxTrainEngine(TrainEngine):
         self._opt_shardings = None
         self._mb_sharding = None
         self._grad_step_cache: dict[int, Callable] = {}
+        # (micro-batch tokens, fused head) -> (sets of `hbm.REMAT_SETS` a grad
+        # step of that shape keeps, their bytes a chip): see _remat_kept
+        self._remat_choice: dict[tuple[int, bool], tuple[int, int]] = {}
         self._fwd_cache: dict[int, Callable] = {}
         # (program, jitted fn, shape key) dispatched at least once: see _run
         self._programs_seen: set[tuple] = set()
@@ -424,7 +428,6 @@ class JaxTrainEngine(TrainEngine):
                 dtype=cfg.dtype,
                 param_dtype=cfg.dtype,
                 remat=cfg.gradient_checkpointing,
-                remat_policy=cfg.jax.remat_policy,
                 scan_layers=cfg.jax.scan_layers,
                 is_critic=cfg.is_critic,
                 attn_impl=attn_impl,
@@ -477,6 +480,9 @@ class JaxTrainEngine(TrainEngine):
                 out_shardings=self._opt_state_shardings(),
             )(self._trainable_sub(self.params))
             self.opt_state = opt_state
+        # for the other engines on these chips (a critic, a reference, a
+        # colocated decode engine) to plan around: _remat_kept
+        hbm.declare_resident(self, self._own_bytes())
 
     def _build_shardings(self) -> None:
         """Mesh rules → param/micro-batch NamedShardings (shared by real
@@ -591,8 +597,13 @@ class JaxTrainEngine(TrainEngine):
                 self._grad_shardings(),
             )
             weight = jax.ShapeDtypeStruct((), jnp.float32)
+            kept_sets, _ = self._remat_kept(
+                mb_tokens, fused_head=self._wants_hidden(loss_fn)
+            )
             grad_compiled = (
-                self._get_grad_step(loss_fn).lower(abstract, acc, weight, mb)
+                self._get_grad_step(loss_fn).lower(
+                    abstract, acc, weight, mb, kept=hbm.REMAT_SETS[kept_sets]
+                )
             ).compile()
 
             report = {"grad_step": _memory_analysis_dict(grad_compiled)}
@@ -652,6 +663,7 @@ class JaxTrainEngine(TrainEngine):
             # — even when .compile() raises (surfacing those errors is this
             # function's advertised use)
             self._grad_step_cache.clear()
+            self._remat_choice.clear()
             self._apply_update_fn = None
             self.params = None
             self._opt_shardings = None
@@ -724,8 +736,10 @@ class JaxTrainEngine(TrainEngine):
     def destroy(self):
         self.params = None
         self.opt_state = None
+        hbm.declare_resident(self, 0)
         self._opt_shardings = None
         self._grad_step_cache.clear()
+        self._remat_choice.clear()
         self._fwd_cache.clear()
         # Compiled programs hold NamedShardings bound to this mesh/optimizer;
         # a re-initialized engine must not reuse them.
@@ -1491,7 +1505,7 @@ class JaxTrainEngine(TrainEngine):
         aux_mode = self._returns_aux(loss_fn)
         lora_mode = self._lora
 
-        def loss_of(trainable, frozen, mb):
+        def loss_of(trainable, frozen, mb, *, kept):
             params = (
                 {**frozen, "lora": trainable} if lora_mode else trainable
             )
@@ -1510,6 +1524,7 @@ class JaxTrainEngine(TrainEngine):
                 model_cfg,
                 with_aux=with_aux,
                 return_hidden=hidden_mode,
+                remat_kept=kept,
             )
             x, aux = out if with_aux else (out, None)
             if hidden_mode:
@@ -1523,7 +1538,9 @@ class JaxTrainEngine(TrainEngine):
 
         grad_sh = self._grad_shardings()
 
-        def grad_step(params, acc, weight, mb):
+        def grad_step(params, acc, weight, mb, kept=()):
+            # `kept` (static): the named intermediates each layer's backward
+            # keeps, decided a shape before it is traced (_run_grad_step)
             if lora_mode:
                 trainable = params["lora"]
                 frozen = jax.lax.stop_gradient(
@@ -1531,9 +1548,9 @@ class JaxTrainEngine(TrainEngine):
                 )
             else:
                 trainable, frozen = params, {}
-            (loss, stats), grads = jax.value_and_grad(loss_of, has_aux=True)(
-                trainable, frozen, mb
-            )
+            (loss, stats), grads = jax.value_and_grad(
+                functools.partial(loss_of, kept=kept), has_aux=True
+            )(trainable, frozen, mb)
             # Pin gradients to their parameter's layout BEFORE accumulation:
             # left free, XLA may lay the backward's psum outputs out
             # differently from the donated accumulator and fall back to
@@ -1548,6 +1565,7 @@ class JaxTrainEngine(TrainEngine):
         fn = jax.jit(
             grad_step,
             donate_argnums=(1,),
+            static_argnames=("kept",),
             out_shardings=(
                 mesh_lib.replicated(self.mesh),
                 mesh_lib.replicated(self.mesh),
@@ -1556,6 +1574,135 @@ class JaxTrainEngine(TrainEngine):
         )
         self._grad_step_cache[key] = fn
         return fn
+
+    def _own_bytes(self) -> int:
+        """Bytes a chip holds of this engine from step to step: parameters,
+        optimizer state and the gradient accumulator, each as sharded (of
+        real or abstract arrays). Arithmetic on shapes and shardings: the
+        same in every run and on every process of a multi-host mesh."""
+        leaves = jax.tree.leaves
+        trainable = self._trainable_sub(self.params)
+        total = hbm.sharded_bytes(leaves(self.params))
+        if self.optimizer is None:  # a reference: no accumulator, no state
+            return total
+        total += hbm.sharded_bytes(
+            leaves(trainable), leaves(self._grad_shardings()),
+            self.config.grad_reduce_dtype,
+        )
+        if self.opt_state is not None:
+            return total + hbm.sharded_bytes(leaves(self.opt_state))
+        return total + hbm.sharded_bytes(  # a plan check: abstract state
+            leaves(jax.eval_shape(self.optimizer.init, trainable)),
+            leaves(self._opt_state_shardings()),
+        )
+
+    def _live_bytes(self) -> int | None:
+        """What the backend says is in use on one of this process's chips of
+        the mesh; None where it says nothing (the CPU). Read to CHECK the
+        account `_remat_kept` plans from, never to plan: it moves with the
+        allocator from run to run, differs between hosts, and a device of
+        another process cannot be asked at all."""
+        here = jax.process_index()
+        for dev in self.mesh.devices.flat:
+            if dev.process_index == here:
+                stats = dev.memory_stats() or {}
+                return stats.get("bytes_in_use")
+        return None
+
+    def _kept_shape(self, tokens: int) -> dict:
+        """What `hbm.remat_kept_bytes` needs of a micro-batch of `tokens` on
+        this mesh: its tokens a chip, the ring's steps, the tp degree."""
+        shape = self.mesh.shape
+        ring = resolve_attn_impl(self.model_config) == "ring"
+        return dict(
+            tokens=max(1, tokens // (
+                shape.get(mesh_lib.AXIS_DP, 1) * shape.get(mesh_lib.AXIS_SP, 1)
+            )),
+            ring_steps=max(cp_ring_shards(tokens, self.mesh), 1) if ring else 1,
+            tp=shape.get(mesh_lib.AXIS_TP, 1),
+        )
+
+    def _remat_kept(self, tokens: int, *, fused_head: bool = True) -> tuple[int, int]:
+        """(how many of `hbm.REMAT_SETS` a grad step over `tokens` packed
+        tokens keeps of each layer in place of recomputing them, their bytes
+        a chip): the largest set that fits what the chip has left beside
+        what is resident (this engine's state by `_own_bytes`, the other
+        engines' as they declared it: `hbm.declare_resident`) and the
+        full-recompute step (`hbm.choose_remat_kept`). Arithmetic on the
+        shape, the mesh and the chip's kind, made before the shape's program
+        is traced: no compile, the same answer in every run and on every
+        process. Decided once a shape and remembered (`_run_grad_step` lowers
+        it if the chip refuses the program). A chip of unknown capacity and
+        a model without `gradient_checkpointing` keep nothing."""
+        cfg = self.model_config
+        if not cfg.remat:
+            return 0, 0
+        if (tokens, fused_head) not in self._remat_choice:
+            shape = self.mesh.shape
+            est = hbm.estimate_train_hbm(
+                cfg,
+                dp=shape.get(mesh_lib.AXIS_DP, 1),
+                tp=shape.get(mesh_lib.AXIS_TP, 1),
+                sp=shape.get(mesh_lib.AXIS_SP, 1),
+                microbatch_tokens=tokens,
+                fused_lm_head=fused_head,
+            )
+            try:
+                capacity = hbm.hbm_bytes(self.mesh.devices.flat[0].device_kind)
+            except ValueError:  # no capacity known for this device
+                capacity = 0
+            resident = self._own_bytes() + hbm.declared_resident_bytes(but=self)
+            room = capacity and hbm.train_room_bytes(
+                capacity, resident,
+                est.activation_bytes + est.logits_bytes + est.grad_transient_bytes,
+            )
+            at = self._kept_shape(tokens)
+            n, kept = hbm.choose_remat_kept(cfg, room_bytes=room, **at)
+            self._remat_choice[tokens, fused_head] = (n, kept)
+            logger.info(
+                f"grad_step T={at['tokens']}/chip: keeping "
+                f"{' + '.join(('attention', 'mlp')[:n]) or 'nothing'}, "
+                f"{kept / 1e9:.2f} GB of {max(room, 0) / 1e9:.2f} GB room"
+            )
+            live = self._live_bytes() if n else None
+            if live is not None and live - resident > capacity // 16:
+                logger.warning(
+                    f"the chip has {live / 1e9:.2f} GB in use where this "
+                    f"engine and what others declared come to "
+                    f"{resident / 1e9:.2f} GB: something on it is not in the "
+                    "account (hbm.declare_resident) the kept set was chosen from"
+                )
+        return self._remat_choice[tokens, fused_head]
+
+    def _run_grad_step(self, grad_step, fused_head: bool, tokens: int, acc, *args):
+        """Dispatch `grad_step` for a micro-batch of `tokens`, keeping what
+        `_remat_kept` chose for the shape. If the chip refuses for memory a
+        program that has never run (XLA's plan does not fit, or its first
+        execution finds no room: RESOURCE_EXHAUSTED) and the accumulator was
+        not consumed, the shape's choice is lowered by one set for good, the
+        log says so, and the same call is made again: at nothing kept the
+        program is full recompute's, and its error is raised as it came."""
+        while True:
+            n, _ = self._remat_kept(tokens, fused_head=fused_head)
+            first = ("grad_step", id(grad_step), tokens) not in self._programs_seen
+            try:
+                return self._run(
+                    "grad_step", grad_step, tokens, self.params, acc, *args,
+                    kept=hbm.REMAT_SETS[n],
+                )
+            except jax.errors.JaxRuntimeError as e:
+                consumed = any(x.is_deleted() for x in jax.tree.leaves(acc))
+                if not (first and n and "RESOURCE_EXHAUSTED" in str(e)) or consumed:
+                    raise
+                at = self._kept_shape(tokens)
+                self._remat_choice[tokens, fused_head] = (
+                    n - 1, hbm.remat_kept_bytes(self.model_config, n_sets=n - 1, **at)
+                )
+                logger.warning(
+                    f"grad_step T={at['tokens']}/chip: the chip refused the program "
+                    f"that keeps {n} of {len(hbm.REMAT_SETS) - 1} sets "
+                    f"({str(e).splitlines()[0][:200]}); keeping {n - 1}"
+                )
 
     def _get_apply_update(self) -> Callable:
         if self._apply_update_fn is not None:
@@ -1611,18 +1758,23 @@ class JaxTrainEngine(TrainEngine):
         return self._run("zero_grads", self._zero_grads_fn, None,
                          self._trainable_sub(self.params))
 
-    def _run(self, program: str, fn: Callable, shape_key, *args):
+    def _run(self, program: str, fn: Callable, shape_key, *args, **static):
         """Dispatch a jitted program. The first call of a new (program,
         shape) traces and compiles (or loads from the persistent cache)
         inside the call: it gets a `train/compile` span and counts in
-        `train_batch`'s `compiles`."""
+        `train_batch`'s `compiles`. A first call that raises has run
+        nothing: the next one is a first call again."""
         key = (program, id(fn), shape_key)
         if key in self._programs_seen:
-            return fn(*args)
+            return fn(*args, **static)
         self._programs_seen.add(key)
-        with perf_tracer.span("train/compile", program=program,
-                              shape=str(shape_key)):
-            return fn(*args)
+        try:
+            with perf_tracer.span("train/compile", program=program,
+                                  shape=str(shape_key)):
+                return fn(*args, **static)
+        except BaseException:
+            self._programs_seen.discard(key)
+            raise
 
     def train_batch(
         self,
@@ -1646,6 +1798,7 @@ class JaxTrainEngine(TrainEngine):
         perf_tracer.maybe_xprof_step(self._step_count, owner=id(self))
         span = perf_tracer.span
         seen_before = len(self._programs_seen)
+        fused_head = self._wants_hidden(loss_fn)
         aux_stats: dict[str, float] = {}
         with perf_tracer.step_span("train/train_batch", self._step_count):
             with span("train/split_mbs"):
@@ -1685,9 +1838,8 @@ class JaxTrainEngine(TrainEngine):
                     with span("train/upload_mb", tokens=n_tok):
                         dev_mb = self._device_mb(mb)
                     with span("train/grad_step", tokens=n_tok):
-                        loss, mb_stats, acc = self._run(
-                            "grad_step", grad_step, n_tok,
-                            self.params, acc, w, dev_mb,
+                        loss, mb_stats, acc = self._run_grad_step(
+                            grad_step, fused_head, n_tok, acc, w, dev_mb
                         )
                     losses.append(loss)
                     # keep device arrays — float() here would sync per
@@ -1711,6 +1863,9 @@ class JaxTrainEngine(TrainEngine):
             # flash kernels' block pairs that can hold a valid (query, key)
             # pair, and the share their walks take (the rest cost no step)
             live_pct, walked_pct = self._attn_block_pcts(mb_list.mbs)
+            kept_sets, kept_bytes = self._remat_choice.get(
+                (max(mb_tokens), fused_head), (0, 0)
+            )
             with span("train/wait_device"):
                 gnorm_f = float(gnorm)  # blocks until the step is done on device
             step_time = time.perf_counter() - t_start
@@ -1732,6 +1887,10 @@ class JaxTrainEngine(TrainEngine):
                     padded_tokens=float(sum(mb_tokens)),
                     attn_live_block_pct=live_pct,
                     attn_walked_block_pct=walked_pct,
+                    # what the largest micro-batch's grad step keeps of its
+                    # layers in place of recomputing them (0: full recompute)
+                    remat_kept_sets=kept_sets,
+                    remat_kept_bytes=float(kept_bytes),
                     # programs this step ran for the first time (a steady-state
                     # step that compiles names itself here)
                     compiles=len(self._programs_seen) - seen_before,

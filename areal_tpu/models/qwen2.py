@@ -42,6 +42,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from typing import Any
 
 PADDING_SEGMENT = -1
@@ -129,13 +130,11 @@ class ModelConfig:
     param_dtype: str = "bfloat16"
     # compile-time toggles
     scan_layers: bool = True
+    # each decoder layer a `jax.checkpoint` region: the backward may
+    # recompute. WHAT it keeps in place of recomputing is not configured:
+    # `forward(remat_kept=)` takes the names, which the trainer computes from
+    # the step's shapes and the chip's room (`utils/hbm.py:choose_remat_kept`)
     remat: bool = False
-    # jax.checkpoint policy under remat: "full" recomputes everything
-    # (min HBM); "dots_saveable" / "dots_with_no_batch_dims_saveable" keep
-    # matmul outputs resident and recompute only the cheap elementwise ops
-    # — ~25% fewer FLOPs per step when activations fit (cli_args
-    # JaxEngineConfig.remat_policy wires this from YAML).
-    remat_policy: str = "full"
     # attention implementation: "dense" materialises the [T,T] score matrix
     # (fine for short packs / CPU tests); "flash" uses the Pallas
     # online-softmax kernel (areal_tpu/ops/flash_attention.py) — O(T) memory,
@@ -2034,6 +2033,29 @@ def _qk_norm(q, k, layer_p: dict, cfg: ModelConfig):
     return full(q, layer_p["q_norm"]), full(k, layer_p["k_norm"])
 
 
+def _keep(x: jax.Array, name: str, kept: tuple[str, ...]) -> jax.Array:
+    """`x`, named `name` for the backward of a checkpointed layer that keeps
+    `name` (`kept`: names of `utils/hbm.py:REMAT_SETS`, handed down from
+    `_maybe_remat`). A `checkpoint_name` is an equation of the traced
+    program and moves the numbering of its lowered text, so a name nothing
+    keeps is not emitted: the decode engine's programs, `jit_fwd_step` and a
+    grad step that keeps nothing stay text for text what they were (the
+    hashes of `tests/test_kimi_linear_engine.py` and its siblings). The
+    flash kernels' `o` and `lse` need no such care: their names are in the
+    kernels' forward RULE (`ops/flash_attention.py:_flash`), which is traced
+    only under differentiation."""
+    return checkpoint_name(x, name) if name in kept else x
+
+
+def _keep_heads(t: jax.Array, name: str, kept: tuple[str, ...]) -> jax.Array:
+    """`_keep` of `t` [T, n, hd], kept as `[T, n * hd]`: a TPU stores an
+    array's two minor dimensions in (16, 128) tiles, so kept by heads 14
+    heads of 64 would hold 2.3 times their bytes and 2 heads 16 times."""
+    if name not in kept:
+        return t
+    return checkpoint_name(t.reshape(t.shape[0], -1), name).reshape(t.shape)
+
+
 @jax.named_scope("attn")
 def attention(
     layer_p: dict,
@@ -2045,11 +2067,13 @@ def attention(
     cfg: ModelConfig,
     li: int | None = None,
     horizon: jax.Array | None = None,
+    kept: tuple[str, ...] = (),
 ) -> jax.Array:
     """Packed multi-head GQA attention over one 1-D token stream [T, H].
     `li`: the layer's index in a mixed stack (its window, and whether it
     rotates q and k); `mask` is then the mask of that layer's kind.
-    `horizon`: `block_horizon` of a block-causal model."""
+    `horizon`: `block_horizon` of a block-causal model. `kept`: the names a
+    checkpointed layer's backward keeps (`_keep`)."""
     window = cfg.layer_window(li)
     if cfg.latent:
         return _cstr(
@@ -2075,9 +2099,11 @@ def attention(
         with jax.named_scope("rope"):
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-    q = _cstr(q, "tokens", "act_heads", None)
-    k = _cstr(k, "tokens", "act_kv_heads", None)
-    v = _cstr(v, "tokens", "act_kv_heads", None)
+    # (what a checkpointed layer's backward may keep, `_maybe_remat`; the
+    # flash kernels' `o` and `lse` are named in their forward rule)
+    q = _keep_heads(_cstr(q, "tokens", "act_heads", None), "attn_q", kept)
+    k = _keep_heads(_cstr(k, "tokens", "act_kv_heads", None), "attn_k", kept)
+    v = _keep_heads(_cstr(v, "tokens", "act_kv_heads", None), "attn_v", kept)
     nH, nKV, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     T = x.shape[0]
     impl = resolve_attn_impl(cfg, window)
@@ -2101,11 +2127,11 @@ def attention(
         elif impl == "chunked":
             from areal_tpu.ops.chunked_attention import chunked_attention
 
-            out = chunked_attention(
+            out = _keep(chunked_attention(
                 q, k, v, segment_ids, sliding_window=window, **(
                     {} if horizon is None else {"q_horizon": horizon}
                 )
-            )
+            ), "attn_out", kept)
         else:
             # GQA: broadcast kv heads to query heads via grouped einsum.
             group = nH // nKV
@@ -2117,7 +2143,7 @@ def attention(
             scores = jnp.where(mask[None, None, :, :], scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
             out = jnp.einsum("kgts,skd->tkgd", probs, v)
-            out = out.reshape(T, nH, hd)
+            out = _keep(out.reshape(T, nH, hd), "attn_out", kept)
     out = _cstr(_gate_attn_out(out, gate), "tokens", "act_heads", None)
     with jax.named_scope("o_proj"):
         proj = _w_einsum("tnd,ndh->th", out, layer_p["o_kernel"], 2)
@@ -2298,19 +2324,23 @@ def _with_lora(layer_p, leaf, y, x, cfg):
 
 
 @jax.named_scope("mlp")
-def mlp(layer_p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+def mlp(
+    layer_p: dict, x: jax.Array, cfg: ModelConfig, kept: tuple[str, ...] = ()
+) -> jax.Array:
     act = act_fn(cfg)
     if cfg.mlp_style == "fc":
         h1 = _w_einsum("th,hm->tm", x, layer_p["fc1_kernel"], 1)
-        h1 = _with_lora(layer_p, "fc1_kernel", h1, x, cfg)
+        h1 = _keep(_with_lora(layer_p, "fc1_kernel", h1, x, cfg), "mlp_up", kept)
         h = _cstr(act(h1 + layer_p["fc1_bias"]), "tokens", "act_mlp")
         out = _w_einsum("tm,mh->th", h, layer_p["fc2_kernel"], 1)
         out = _with_lora(layer_p, "fc2_kernel", out, h, cfg)
         return _cstr(out + layer_p["fc2_bias"], "tokens", "act_embed")
     gate = _w_einsum("th,hm->tm", x, layer_p["gate_kernel"], 1)
-    gate = _with_lora(layer_p, "gate_kernel", gate, x, cfg)
+    gate = _keep(
+        _with_lora(layer_p, "gate_kernel", gate, x, cfg), "mlp_gate", kept
+    )
     up = _w_einsum("th,hm->tm", x, layer_p["up_kernel"], 1)
-    up = _with_lora(layer_p, "up_kernel", up, x, cfg)
+    up = _keep(_with_lora(layer_p, "up_kernel", up, x, cfg), "mlp_up", kept)
     h = _cstr(act(gate) * up, "tokens", "act_mlp")
     out = _w_einsum("tm,mh->th", h, layer_p["down_kernel"], 1)
     out = _with_lora(layer_p, "down_kernel", out, h, cfg)
@@ -3041,25 +3071,18 @@ def _linear_mixers(cfg: ModelConfig) -> tuple:
     return gated_delta_net, gated_delta_step
 
 
-_REMAT_POLICIES = {
-    "full": None,
-    "dots_saveable": "dots_saveable",
-    "dots_with_no_batch_dims_saveable": "dots_with_no_batch_dims_saveable",
-}
-
-
-def _maybe_remat(layer_fn, cfg: ModelConfig):
+def _maybe_remat(layer_fn, cfg: ModelConfig, kept: tuple[str, ...] = ()):
+    """`layer_fn` as a `jax.checkpoint` region under `cfg.remat`; its
+    backward keeps the intermediates named in `kept` (names of
+    `utils/hbm.py:REMAT_SETS`, which `layer_fn` takes as `kept=` and names
+    where they are made) and recomputes the rest. No names: the whole
+    forward again, the program it has always been."""
     if not cfg.remat:
         return layer_fn
-    if cfg.remat_policy not in _REMAT_POLICIES:
-        raise ValueError(
-            f"remat_policy={cfg.remat_policy!r} not in "
-            f"{sorted(_REMAT_POLICIES)}"
-        )
-    policy_name = _REMAT_POLICIES[cfg.remat_policy]
-    policy = (
-        getattr(jax.checkpoint_policies, policy_name) if policy_name else None
-    )
+    policy = None
+    if kept:
+        layer_fn = functools.partial(layer_fn, kept=kept)
+        policy = jax.checkpoint_policies.save_only_these_names(*kept)
     return jax.checkpoint(layer_fn, static_argnums=(6, 7), policy=policy)
 
 
@@ -3074,25 +3097,29 @@ def decoder_layer(
     cfg: ModelConfig,
     li: int | None = None,
     horizon: jax.Array | None = None,
+    *,
+    kept: tuple[str, ...] = (),
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (hidden [T, H], router aux loss scalar — 0 for dense).
     `li` (static) is the layer's index in an unstacked tree; `horizon` is
-    `block_horizon` of a block-causal model."""
+    `block_horizon` of a block-causal model; `kept` the names this layer's
+    backward keeps when it is a checkpoint region (`_maybe_remat`)."""
     h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
     if cfg.layer_linear(li):
         with jax.named_scope("attn"):
             x = x + _linear_mixers(cfg)[0](layer_p["attn"], h, segment_ids, cfg)
     else:
         x = x + attention(
-            layer_p["attn"], h, cos, sin, segment_ids, mask, cfg, li, horizon
+            layer_p["attn"], h, cos, sin, segment_ids, mask, cfg, li, horizon, kept
         )
+    x = _keep(x, "attn_residual", kept)
     h = _norm(x, layer_p["post_attn_norm"], cfg, layer_p.get("post_attn_norm_bias"))
     if cfg.layer_sparse(li):
         y, aux = moe_mlp(
             layer_p["mlp"], h, cfg, valid=segment_ids != PADDING_SEGMENT
         )
     else:
-        y, aux = mlp(layer_p["mlp"], h, cfg), jnp.float32(0.0)
+        y, aux = mlp(layer_p["mlp"], h, cfg, kept), jnp.float32(0.0)
     return x + y, aux
 
 
@@ -3112,6 +3139,7 @@ def forward(
     *,
     with_aux: bool = False,
     return_hidden: bool = False,
+    remat_kept: tuple[str, ...] = (),
 ) -> jax.Array:
     """Packed forward: [T] ids → [T, V] logits (f32).
 
@@ -3123,6 +3151,9 @@ def forward(
     hidden states instead of logits — the fused-LM-loss path (LMHead +
     ops/fused_xent.py) applies the head in vocab chunks so the f32 [T, V]
     tensor never exists.
+
+    `remat_kept`: under `cfg.remat`, the named intermediates each layer's
+    backward keeps in place of recomputing them (`_maybe_remat`).
     """
     compute_dtype = jnp.dtype(cfg.dtype)
     # Zig-zag context parallelism: when ring attention will shard the token
@@ -3183,7 +3214,7 @@ def forward(
     }
     mask = masks.get(cfg.sliding_window)
 
-    layer_fn = _maybe_remat(decoder_layer, cfg)
+    layer_fn = _maybe_remat(decoder_layer, cfg, remat_kept)
     # the causal models' call is what it was: no ninth argument
     more = () if horizon is None else (horizon,)
 

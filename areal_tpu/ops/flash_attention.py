@@ -83,6 +83,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.custom_batching import custom_vmap
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -721,6 +722,11 @@ def _flash(sm_scale: float, block_q: int, block_k: int, interpret: bool):
 
     def flash_fwd(q4, k4, v4, seg_q, seg_k, qpos, kpos, runs):
         o, lse = fwd_call(q4, k4, v4, seg_q, seg_k, qpos, kpos, *runs[:2])
+        # named for a `jax.checkpoint` region around the caller: one whose
+        # policy keeps these two (`utils/hbm.py:KEEP_ATTENTION`) runs no
+        # second `%flash_fwd` in its backward; `o` is also what the caller
+        # goes on with, and a ring names every step's partials here
+        o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "attn_lse")
         return (o, lse), (q4, k4, v4, seg_q, seg_k, qpos, kpos, runs, o, lse)
 
     def flash_bwd(res, cts):

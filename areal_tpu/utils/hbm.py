@@ -14,8 +14,19 @@ Per chip, a training step holds
                 (L/pp) x T_local x d x act_bytes
                 plus ONE layer's recompute working set
                 T_local x (3d + 2ff/tp + 2*nH*hd/tp) x act_bytes
+                plus what the backward KEEPS of every layer in place of
+                recomputing it (`remat_kept_bytes`; `choose_remat_kept`
+                picks the largest of `REMAT_SETS` that fits the room)
   logits        fused vocab-chunked head: T_local x chunk x 4;
                 unfused: T_local x V x 4  (f32 logits)
+  grad transient  a backward's gradients before they are added into the
+                accumulator, n_params x param_bytes / shards; the head's,
+                which the fused loss sums over token chunks in float32,
+                V x d x 4 whole on every chip; under fsdp the embedding
+                table gathered whole, V x d x act_bytes
+                (read off XLA:TPU's plan for `jit_grad_step` at 0.5B on one
+                chip and 1.5B over dp=4: 2.64 and 2.62 GB of temporaries
+                where activations and logits alone give 0.85 and 0.70)
   pp stash      1f1b keeps (2*pp-1) stage inputs alive between a
                 microbatch's forward and backward; the interleaved
                 schedule v*(2*pp-1) virtual-chunk inputs — each entry
@@ -43,6 +54,8 @@ v4 32 GiB, v6e 32 GiB.
 
 from __future__ import annotations
 
+import math
+import weakref
 from dataclasses import dataclass
 
 GiB = 1024**3
@@ -169,6 +182,9 @@ class HBMEstimate:
     # inputs alive between forward and backward — 2*pp-1 entries for plain
     # 1f1b, v*(2*pp-1) for the interleaved schedule
     stash_bytes: int = 0
+    # a backward's own gradients before accumulation, the head's float32
+    # gradient and (fsdp) the gathered embedding table: see the module doc
+    grad_transient_bytes: int = 0
     # informational: bytes the ZeRO-1 dp-sharded optimizer update freed
     # per chip vs a dp-replicated opt state (already subtracted from
     # opt_bytes; NOT part of total_bytes)
@@ -188,6 +204,7 @@ class HBMEstimate:
             + self.logits_bytes
             + self.kv_bytes
             + self.stash_bytes
+            + self.grad_transient_bytes
         )
 
     def breakdown(self) -> dict:
@@ -199,6 +216,7 @@ class HBMEstimate:
             "logits_gib": round(self.logits_bytes / GiB, 3),
             "kv_gib": round(self.kv_bytes / GiB, 3),
             "stash_gib": round(self.stash_bytes / GiB, 3),
+            "grad_transient_gib": round(self.grad_transient_bytes / GiB, 3),
             "total_gib": round(self.total_bytes / GiB, 3),
         }
         if self.opt_freed_bytes:
@@ -206,6 +224,156 @@ class HBMEstimate:
         if self.weight_freed_bytes:
             out["wquant_freed_gib"] = round(self.weight_freed_bytes / GiB, 3)
         return out
+
+
+# -- what a layer's backward keeps ------------------------------------------
+# Under `gradient_checkpointing` a decoder layer is a `jax.checkpoint` region:
+# its backward starts from the layer's input and runs the forward again. The
+# intermediates below carry a `jax.ad_checkpoint.checkpoint_name` where they
+# are made (`models/qwen2.py`: `attention`, `decoder_layer`, `mlp`;
+# `ops/flash_attention.py:_flash`'s forward rule for the kernel's `o` and
+# `lse`, a ring's partials each step); a region built with
+# `save_only_these_names(*kept)` keeps the named ones and recomputes the rest
+# (norms, rotation, `act(gate) * up`: elementwise). Ordered by seconds saved a
+# byte kept: the attention part ends the flash kernel's second run and the
+# qkv / o projections' for 2(nH + nKV) hd + d elements a token; gate and up
+# end the MLP's two wide matmuls' for 2 ff.
+KEEP_ATTENTION = (
+    "attn_q", "attn_k", "attn_v", "attn_out", "attn_lse", "attn_residual",
+)
+KEEP_MLP = ("mlp_gate", "mlp_up")
+REMAT_SETS: tuple[tuple[str, ...], ...] = (
+    (),  # full recompute
+    KEEP_ATTENTION,
+    KEEP_ATTENTION + KEEP_MLP,
+)
+# Share of the chip's capacity a training step may plan to fill, sized for
+# the closed form's worst error against XLA:TPU's own plan for
+# `jit_grad_step` with both sets kept (`bench_artifacts/pr47/aot_memory.py`,
+# compiled for a described v5e): 13.67 GB read for 15.07 planned at 0.5B's
+# 8,192-token micro-batch on one chip (10% under: a backward's working set at
+# 64-lane heads, and 0.8 GB not explained), 13.86 for 13.32 a chip at 1.5B's
+# 16,384 over dp=4. A plan filled to 0.85 and read 10% low is 0.935 of the
+# chip, under the 0.98 a v5e's allocator has; a heavier loss than the fused
+# SFT one those plans were made with (PPO's: about 0.5 GB more) takes the
+# rest. A shape the compiler refuses all the same steps down a set
+# (`engine/jax_engine.py:_run_grad_step`).
+REMAT_ROOM_MARGIN = 0.85
+
+
+def _lanes(n: int) -> int:
+    """A TPU stores an array's minor dimension in tiles of 128 lanes."""
+    return -(-n // 128) * 128
+
+
+def remat_kept_bytes(
+    model_cfg,
+    tokens: int,
+    n_sets: int,
+    *,
+    ring_steps: int = 1,
+    tp: int = 1,
+) -> int:
+    """Bytes a chip holds between forward and backward for the first `n_sets`
+    of `REMAT_SETS`, `tokens` a chip, summed over the layers. Sized by each layer's
+    STRUCTURE: a layer whose mixer is not plain attention (linear, latent)
+    names only the residual, a routed MLP names nothing, and what has no
+    name is recomputed."""
+    if n_sets <= 0:
+        return 0
+    abytes = _dtype_bytes(getattr(model_cfg, "dtype", "bfloat16"))
+    d = model_cfg.hidden_size
+    nH = model_cfg.num_attention_heads
+    nKV = model_cfg.num_key_value_heads
+    hd = getattr(model_cfg, "head_dim_", None) or d // nH
+    linear = getattr(model_cfg, "layer_linear", lambda i: False)
+    sparse = getattr(model_cfg, "layer_sparse", lambda i: False)
+    latent = getattr(model_cfg, "latent", False)
+    glu = getattr(model_cfg, "mlp_style", "glu") == "glu"
+    total = 0
+    for i in range(model_cfg.num_hidden_layers):
+        per_token = d * abytes  # the residual after attention
+        if not (latent or linear(i)):
+            # q, k, v after the rotation, named `[T, heads * hd]`; the
+            # kernel's o by heads, `[nH, T, hd]` (a ring keeps every step's
+            # partial), and its float32 lse
+            per_token += (nH + 2 * nKV) * hd * abytes // tp
+            per_token += ring_steps * (nH * _lanes(hd) * abytes + nH * 4) // tp
+        if n_sets >= 2 and not sparse(i):
+            per_token += (2 if glu else 1) * model_cfg.intermediate_size * abytes // tp
+        total += per_token * tokens
+    return total
+
+
+def choose_remat_kept(
+    model_cfg,
+    tokens: int,
+    room_bytes: int,
+    *,
+    ring_steps: int = 1,
+    tp: int = 1,
+) -> tuple[int, int]:
+    """(how many of `REMAT_SETS` the backward keeps, their bytes a chip): the
+    largest set whose bytes fit `room_bytes`, what the chip has left beside
+    the resident state and the full-recompute step (`train_room_bytes`).
+    Arithmetic on static shapes: no compile, the same answer every time."""
+    best = (0, 0)
+    for n in range(1, len(REMAT_SETS)):
+        need = remat_kept_bytes(model_cfg, tokens, n, ring_steps=ring_steps, tp=tp)
+        if need > room_bytes:
+            break
+        best = (n, need)
+    return best
+
+
+def train_room_bytes(
+    capacity_bytes: int, resident_bytes: int, step_bytes: int
+) -> int:
+    """What a chip of `capacity_bytes` has left for kept intermediates:
+    `REMAT_ROOM_MARGIN` of it, less what is resident (parameters, gradients
+    and optimizer state as sharded, whatever else lives on the chip) and the
+    full-recompute step's own activations, logits and gradient transients."""
+    return int(capacity_bytes * REMAT_ROOM_MARGIN) - resident_bytes - step_bytes
+
+
+def sharded_bytes(leaves, shardings=None, dtype=None) -> int:
+    """Bytes a chip holds of `leaves` (arrays, real or abstract) as sharded:
+    each by its own `.sharding`, or by the matching entry of `shardings`;
+    counted in `dtype` if given. Arithmetic on shapes: the same on every
+    process of a multi-host mesh, whichever devices it can address."""
+    if shardings is None:
+        shardings = [getattr(x, "sharding", None) for x in leaves]
+
+    def itemsize(dt) -> int:
+        return getattr(dt, "itemsize", None) or _dtype_bytes(dt)
+
+    return sum(
+        math.prod(sh.shard_shape(x.shape) if sh is not None else x.shape)
+        * itemsize(dtype or x.dtype)
+        for x, sh in zip(leaves, shardings)
+    )
+
+
+# What each engine of this process holds of a chip from step to step, by its
+# own account (a trainer: parameters, accumulator, optimizer state; a decode
+# engine: weights and pools). A trainer plans its step's memory around the
+# others' (`engine/jax_engine.py:_remat_kept`): declared bytes are the same
+# in every run and on every process, which an allocator's live reading is
+# not. An engine that is collected, or declares 0, is gone from the account.
+_DECLARED: "weakref.WeakKeyDictionary[object, int]" = weakref.WeakKeyDictionary()
+
+
+def declare_resident(owner, nbytes: int) -> None:
+    """`owner` holds `nbytes` a chip from now on (0: nothing any more)."""
+    if nbytes:
+        _DECLARED[owner] = int(nbytes)
+    else:
+        _DECLARED.pop(owner, None)
+
+
+def declared_resident_bytes(*, but=None) -> int:
+    """Bytes a chip that engines other than `but` have declared."""
+    return sum(n for owner, n in _DECLARED.items() if owner is not but)
 
 
 def estimate_train_hbm(
@@ -229,6 +397,9 @@ def estimate_train_hbm(
 
     `microbatch_tokens` is the GLOBAL token count of one microbatch (the
     unit `train_batch` runs per dispatch); dp and sp shard it.
+    Under `remat` this is the FULL-recompute step; what the trainer then
+    keeps of each layer (`choose_remat_kept`) it fits into the room this
+    leaves, so it never moves a verdict made from `total_bytes`.
 
     Sharding regimes: `fsdp=True` dp-shards params, grads AND opt state
     (the ZeRO-3-ish default the estimator has always priced). With
@@ -279,6 +450,10 @@ def estimate_train_hbm(
     opt_freed = 0
     if zero1 and not fsdp and dp > 1:
         opt_freed = opt_mult * n * 4 // (tp * pp) - opt
+    head = model_cfg.vocab_size * d
+    grad_transient = n * pbytes // shard + (head * 4 if fused_lm_head else 0)
+    if fsdp and dp > 1:
+        grad_transient += head * abytes
     return HBMEstimate(
         params_bytes=n * pbytes // shard,
         grads_bytes=n * pbytes // shard,
@@ -286,6 +461,7 @@ def estimate_train_hbm(
         activation_bytes=act,
         logits_bytes=logits,
         stash_bytes=stash,
+        grad_transient_bytes=grad_transient,
         opt_freed_bytes=opt_freed,
     )
 

@@ -170,6 +170,165 @@ def test_interleaved_stash_pricing():
     assert flat.stash_bytes == 0 and "stash_gib" in flat.breakdown()
 
 
+QWEN25_15B = ModelConfig(
+    vocab_size=151936,
+    hidden_size=1536,
+    intermediate_size=8960,
+    num_hidden_layers=28,
+    num_attention_heads=12,
+    num_key_value_heads=2,
+    tie_word_embeddings=True,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+)
+V5E = 16 * hbm.GiB
+
+
+def _trainer_resident(cfg, dp: int) -> int:
+    """What the trainer holds of `cfg` a chip from step to step as the
+    benchmark's cells run it: bf16 parameters, float32 gradient accumulator,
+    AdamW's float32 mu and bf16 nu, all sharded over dp."""
+    return hbm.param_count(cfg) * (2 + 4 + 4 + 2) // dp
+
+
+def _room(cfg, tokens: int, dp: int, capacity: int = V5E) -> tuple[int, int]:
+    est = hbm.estimate_train_hbm(cfg, dp=dp, microbatch_tokens=tokens)
+    step = est.activation_bytes + est.logits_bytes + est.grad_transient_bytes
+    resident = _trainer_resident(cfg, dp)
+    return hbm.train_room_bytes(capacity, resident, step), resident + step
+
+
+@pytest.mark.parametrize(
+    "cfg,tokens,dp",
+    [(QWEN25_05B, 8192, 1), (QWEN25_15B, 16384, 4)],
+    ids=["train-0.5b-gsm8k", "train-1.5b-fsdp4"],
+)
+def test_remat_choice_keeps_something_at_the_train_cells_shapes(cfg, tokens, dp):
+    """At the cells' largest micro-batches on 16 GiB chips the chosen set is
+    not empty, and with the resident state and the step's own bytes it stays
+    inside the margin."""
+    room, held = _room(cfg, tokens, dp)
+    n, kept = hbm.choose_remat_kept(cfg, tokens // dp, room, ring_steps=dp)
+    assert n >= 1 and kept == hbm.remat_kept_bytes(cfg, tokens // dp, n, ring_steps=dp)
+    assert 0 < kept <= room
+    assert held + kept <= hbm.REMAT_ROOM_MARGIN * V5E
+
+
+def test_check_fit_counts_a_backwards_transients():
+    """`total_bytes` now holds `grad_transient_bytes` (a backward's gradients
+    before accumulation, the head's float32 gradient, the gathered table
+    under fsdp: XLA:TPU's plan has them, the old closed form did not), so a
+    verdict of `check_fit` / `AllocationMode.check_hbm` can move: 7B over 8
+    v5e chips was passed at 10.97 GiB a chip and is refused at 15.79; over
+    16 it fits either way."""
+    import dataclasses
+
+    from areal_tpu.api.alloc_mode import AllocationMode, AllocationValidationError
+
+    kind = "TPU v5 lite"
+    over8 = hbm.estimate_train_hbm(QWEN25_7B, dp=8, microbatch_tokens=8192)
+    n = hbm.param_count(QWEN25_7B)
+    head = QWEN25_7B.vocab_size * QWEN25_7B.hidden_size
+    assert over8.grad_transient_bytes == n * 2 // 8 + head * 4 + head * 2
+    before = dataclasses.replace(over8, grad_transient_bytes=0)
+    assert over8.total_bytes - before.total_bytes == over8.grad_transient_bytes
+    assert round(before.total_bytes / hbm.GiB, 2) == 10.97
+    assert round(over8.total_bytes / hbm.GiB, 2) == 15.79
+    hbm.check_fit(before, kind)
+    with pytest.raises(MemoryError, match="15.79 GiB/chip"):
+        hbm.check_fit(over8, kind)
+    with pytest.raises(AllocationValidationError, match="train half"):
+        AllocationMode.from_str("jax:d1t8+d8").check_hbm(
+            QWEN25_7B, kind, decode_context=4096)
+    report = AllocationMode.from_str("jax:d1t8+d16").check_hbm(
+        QWEN25_7B, kind, decode_context=4096)
+    assert report["train"]["grad_transient_gib"] == round(
+        (n * 2 // 16 + head * 6) / hbm.GiB, 3)
+    # one chip, no fsdp gather: the table is not counted twice
+    one = hbm.estimate_train_hbm(QWEN25_05B, microbatch_tokens=8192)
+    head05 = QWEN25_05B.vocab_size * QWEN25_05B.hidden_size
+    assert one.grad_transient_bytes == hbm.param_count(QWEN25_05B) * 2 + head05 * 4
+
+
+def test_remat_choice_keeps_nothing_where_the_state_fills_the_chip():
+    """1.5B whole on one chip: 18.5 GB of trainer state leave no room."""
+    room, _ = _room(QWEN25_15B, 8192, 1)
+    assert room < 0
+    assert hbm.choose_remat_kept(QWEN25_15B, 8192, room) == (0, 0)
+    assert hbm.REMAT_SETS[0] == ()
+
+
+@pytest.mark.parametrize("cfg", [QWEN25_05B, QWEN25_15B], ids=["0.5b", "1.5b"])
+def test_less_room_never_keeps_more(cfg):
+    """Over every room from none to the whole chip the chosen count never
+    falls as the room grows, each set is reached, and the sets are ordered:
+    a later one holds the earlier one's names and more bytes."""
+    tokens = 4096
+    seen = []
+    for room in range(-hbm.GiB, V5E, 64 * 1024 * 1024):
+        n, kept = hbm.choose_remat_kept(cfg, tokens, room)
+        assert kept <= max(room, 0)
+        seen.append(n)
+    assert seen == sorted(seen) and set(seen) == {0, 1, 2}
+    sizes = [hbm.remat_kept_bytes(cfg, tokens, n) for n in range(len(hbm.REMAT_SETS))]
+    assert sizes[0] == 0 and sizes == sorted(sizes) and len(set(sizes)) == 3
+    for a, b in zip(hbm.REMAT_SETS, hbm.REMAT_SETS[1:]):
+        assert set(a) < set(b)
+    # a ring keeps every step's partial output: more bytes a token
+    assert hbm.remat_kept_bytes(cfg, tokens, 1, ring_steps=4) > sizes[1]
+
+
+def test_kept_bytes_follow_the_layers_structure():
+    """A layer keeps what the arithmetic can size: a routed MLP adds nothing
+    to the second set, a linear or latent mixer only the residual."""
+    import dataclasses
+
+    T = 1024
+    dense = [hbm.remat_kept_bytes(QWEN25_05B, T, n) for n in (1, 2)]
+    sparse_cfg = dataclasses.replace(
+        QWEN25_05B, num_experts=8, num_experts_per_tok=2, moe_intermediate_size=512)
+    sparse = [hbm.remat_kept_bytes(sparse_cfg, T, n) for n in (1, 2)]
+    assert sparse[0] == dense[0] and sparse[1] == sparse[0] < dense[1]
+    first_dense = dataclasses.replace(sparse_cfg, first_k_dense=1)
+    one_layer_mlp = 2 * QWEN25_05B.intermediate_size * 2 * T
+    assert hbm.remat_kept_bytes(first_dense, T, 2) == sparse[0] + one_layer_mlp
+    latent = dataclasses.replace(QWEN25_05B, kv_lora_rank=64)
+    residual_only = QWEN25_05B.num_hidden_layers * QWEN25_05B.hidden_size * 2 * T
+    assert hbm.remat_kept_bytes(latent, T, 1) == residual_only
+
+
+@pytest.mark.parametrize("sets", [1, 2], ids=["attention", "attention_mlp"])
+def test_kept_bytes_match_xla_memory_analysis(sets):
+    """What XLA plans for the gradient of a small stack grows, from full
+    recompute to each kept set, by the closed form's bytes (the tolerance of
+    the 7B plan's cross-check: a factor of two either way)."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from areal_tpu.models.qwen2 import forward
+
+    cfg = dataclasses.replace(
+        TINY, hidden_size=128, intermediate_size=512, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, remat=True, attn_impl="dense")
+    T = 1024
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    ints = jax.ShapeDtypeStruct((T,), jnp.int32)
+
+    def temp(kept):
+        def step(p, ids, pos, seg):
+            return jax.grad(
+                lambda p: forward(p, ids, pos, seg, cfg, remat_kept=kept).sum())(p)
+        ma = jax.jit(step).lower(params, ints, ints, ints).compile().memory_analysis()
+        if ma is None or not ma.temp_size_in_bytes:
+            pytest.skip("this backend plans no temporaries")
+        return ma.temp_size_in_bytes
+
+    grown = temp(hbm.REMAT_SETS[sets]) - temp(hbm.REMAT_SETS[0])
+    closed = hbm.remat_kept_bytes(cfg, T, sets)
+    assert 0.5 < grown / closed < 2.0, (grown, closed)
+
+
 def test_device_kind_spellings():
     """GKE-style v5e spellings must not fall through to the v5p row."""
     for kind in ("TPU v5 lite", "tpu-v5-lite-podslice", "v5litepod", "V5E"):
@@ -264,6 +423,5 @@ def test_full_depth_7b_plan_compiles(cpu_devices):
 def dataclasses_replace_scan(cfg):
     import dataclasses
 
-    return dataclasses.replace(
-        cfg, scan_layers=True, remat=True, remat_policy="full"
-    )
+    # remat with nothing kept (`hbm.REMAT_SETS[0]`): `forward`'s default
+    return dataclasses.replace(cfg, scan_layers=True, remat=True)

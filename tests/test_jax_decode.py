@@ -62,6 +62,27 @@ def greedy_reference(params, prompt, n_new):
 
 
 @pytest.mark.slow
+def test_engine_declares_what_it_holds_of_a_chip(engine):
+    """A live engine is on the account a trainer on the same chips plans its
+    step's memory around (`utils/hbm.py:declare_resident`): its weights and
+    its pools, as sharded; a destroyed one is off it."""
+    from areal_tpu.utils import hbm
+
+    held = sum(
+        x.nbytes for x in jax.tree.leaves((engine.params, engine._k_cache, engine._v_cache))
+    )
+    assert hbm._DECLARED[engine] == held > 0
+    assert hbm.declared_resident_bytes(but=engine) == hbm.declared_resident_bytes() - held
+    other = JaxDecodeEngine(engine.config, InferenceEngineConfig())
+    other.set_model(engine.params, TINY)
+    other.initialize()
+    try:
+        assert hbm._DECLARED[other] == held
+    finally:
+        other.destroy()
+    assert other not in hbm._DECLARED and engine in hbm._DECLARED
+
+
 def test_greedy_decode_matches_forward(engine):
     prompt = [1, 5, 9, 13, 2]
     n_new = 11

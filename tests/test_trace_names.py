@@ -1003,6 +1003,44 @@ def test_flash_kernels_are_named_at_the_0p5b_head_shape(one_chip):
     _flash_operands_stay_as_given(hlo, T, hd)
 
 
+@pytest.mark.parametrize("sets", [0, 1, 2], ids=["nothing", "attention", "attention_mlp"])
+def test_grad_step_runs_one_flash_forward_once_the_attention_part_is_kept(
+        one_chip, monkeypatch, sets):
+    """The model's gradient at `train-0.5b-gsm8k`'s layer (896 wide, 14/2
+    heads of 64, 8,192 tokens; two layers under the scan), checkpointed, with
+    each of `hbm.REMAT_SETS` kept: full recompute holds `%flash_fwd` twice (the
+    forward's and the backward's second run of it), the attention part kept
+    holds it ONCE; the kernels' names are the ones the benchmark's readers
+    match whatever is kept, and no wrapper names one."""
+    from areal_tpu.models.qwen2 import forward
+    from areal_tpu.ops import flash_attention as fa
+    from areal_tpu.utils import hbm
+
+    monkeypatch.setattr(fa, "_default_interpret", lambda: False)
+    cfg = ModelConfig(
+        vocab_size=512, hidden_size=896, intermediate_size=4864, num_hidden_layers=2,
+        num_attention_heads=14, num_key_value_heads=2, dtype="bfloat16",
+        param_dtype="bfloat16", remat=True, attn_impl="flash")
+    T = 8192
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    ints = jax.ShapeDtypeStruct((T,), jnp.int32, sharding=one_chip)
+
+    def step(p, ids, pos, seg):
+        return jax.grad(lambda p: forward(
+            p, ids, pos, seg, cfg, remat_kept=hbm.REMAT_SETS[sets],
+        ).astype(jnp.float32).sum())(p)
+
+    hlo = jax.jit(step).trace(params, ints, ints, ints).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    # (a wrapper that named a kernel, `%checkpoint.N` or
+    # `%rematted_computation.N`, would show here in a kernel's place)
+    kernels = sorted(k.split(".")[0] for k in _mosaic_kernels(hlo))
+    want = ["flash_dkv", "flash_dq", "flash_fwd"] + ["flash_fwd"] * (sets == 0)
+    assert kernels == sorted(want), kernels
+
+
 @pytest.mark.parametrize("nH,nKV", [(12, 2), (16, 16)], ids=["1p5b", "olmoe"])
 def test_flash_kernel_batches_under_vmap_at_the_rollout_head_shapes(one_chip, nH, nKV):
     """The decode engine's batched prefill `vmap`s the model forward, each
