@@ -36,7 +36,15 @@ TPU-first design:
   slot retired while its run-ahead chunk is in flight reconciles at
   arrival: the speculative tokens are discarded and the device lengths
   rewound. pause_generation drains every dispatched chunk, fencing weight
-  commits and abort_all.
+  commits and abort_all. **When a slot changes hands**: a request whose
+  whole `max_new_tokens` is covered by dispatched chunks is spent; with a
+  request queued and no slot free, admission takes the spent slot at once
+  (`_hand_over_spent_slot`) and the old request completes from the record
+  of its last chunk when that is read back, in no slot meanwhile
+  (`running_requests` counts occupied slots). Else a slot is freed when
+  its request's last chunk is read back, and then registers the
+  conversation as a prefix donor. Never where the projection is an upper
+  bound (verify and block-diffusion chunks), never at depth 0.
 - **Sampling on device**: temperature / top-p / greedy per slot inside the
   jit; logprob of the chosen token returned per step.
 - **Draft-free speculative decoding** (`spec_decode="ngram"`): a host-side
@@ -447,6 +455,11 @@ class _Inflight:
     # prompt gave it
     steps: Any = None
     blocks: Any = None  # jax [R] (NumPy once consumed)
+    # slots whose request gave its slot to the next one after this chunk was
+    # dispatched (`_hand_over_spent_slot`): `items` still names the request
+    # these tokens belong to, and they complete it, where any other occupant
+    # that left its slot (retired, preempted, aborted) has them discarded
+    handed: set = field(default_factory=set)
 
 
 class JaxDecodeEngine(InferenceEngine):
@@ -661,6 +674,8 @@ class JaxDecodeEngine(InferenceEngine):
         self._chunk_wall_itl_ms: deque = deque(maxlen=512)
         self._chunks_dispatched = 0
         self._runahead_discarded = 0  # run-ahead tokens dropped at reconcile
+        # admissions into a slot whose request was still waiting for its last chunk
+        self._n_handed_over = 0
         # MoE models: token-expert pairs the decode chunks computed (live
         # slots, all layers and token steps), and those of the busiest
         # expert of each layer and step: their ratio x E is max-over-mean load
@@ -2835,6 +2850,63 @@ class JaxDecodeEngine(InferenceEngine):
         self._release_slot_blocks(slot)
         return slot
 
+    def _spent(self, slot: int, item: _Slot) -> bool:
+        """Whether the chunks dispatched so far, consumed or not, cover the
+        slot's request's whole `max_new_tokens` (an upper bound where
+        `SlotCache.projection_exact` is false)."""
+        return self._slot_cache.generated(
+            int(self._slot_lengths[slot]), len(item.prompt)
+        ) >= item.gconfig.max_new_tokens
+
+    def _hand_over_spent_slot(self) -> int | None:
+        """Take a slot whose request is SPENT: the chunks already dispatched
+        cover its whole `max_new_tokens` (the saturation mask's test, which
+        keeps it out of every later chunk), so all the slot still does is
+        wait for its last chunk to be read back. The request leaves the slot
+        table here and stays in the in-flight records that hold its tokens
+        (`_Inflight.handed`), where `_apply_chunk` completes it; the slot's
+        index is returned, free as a retired donor's slot is free: it keeps
+        the claim it had (the old request's prompt rows, which no chunk
+        writes) and the blocks under it until the admission that takes it
+        frees them, or finds its own prompt there and leaves them in place.
+
+        Device order keeps this safe: the chunk that still writes the old
+        request's rows was enqueued, with the block table it was dispatched
+        with, before anything the next occupant's admission enqueues, and
+        every pool program chains through the donated pools.
+
+        Only where the projection is a fact (`SlotCache.projection_exact`:
+        never for a scheduler that dispatches verify or block-diffusion
+        chunks, which project an upper bound and take the rest back), and
+        only with a chunk in flight at all:
+        `decode_runahead_chunks` 0 has none here, and retires at the read-back
+        as ever. A stop before `max_new_tokens` only ends the old request
+        earlier. Its whole conversation is not registered as `_retire` would
+        (its tokens are not on the host yet): the caller is about to
+        overwrite the slot, which would have unregistered it."""
+        if not self._inflight or not self._slot_cache.projection_exact(self.config):
+            return None
+        for i, s in enumerate(self._slots):
+            if s is None or not self._spent(i, s):
+                continue
+            holding = [
+                rec for rec in self._inflight
+                if rec.items[i] is s and rec.active[i]
+                and rec.epochs[i] == self._slot_epoch[i]
+            ]
+            if not holding:
+                continue
+            for rec in holding:
+                rec.handed.add(i)
+            self._slots[i] = None
+            if self._slot_prefix[i] is None:
+                self._alloc.free_slot(i)  # (no claim to hold the blocks for)
+            self._slot_lengths[i] = 0
+            self._mark_slot_dirty(i)
+            self._n_handed_over += 1
+            return i
+        return None
+
     def _reclaim_blocks(self, protect: frozenset[int] = frozenset()) -> bool:
         """Free SOME blocks under pool pressure, cheapest casualty first:
         (1) a donor registration held by a free slot (only prefix-reuse
@@ -3103,7 +3175,11 @@ class JaxDecodeEngine(InferenceEngine):
             if resumed is None:
                 free = self._free_slots()
                 if not free:
-                    evicted = self._evict_parked_lru()
+                    # a slot that only waits for its last chunk, before a
+                    # parked request's cache (whose resume would re-prefill)
+                    evicted = self._hand_over_spent_slot()
+                    if evicted is None:
+                        evicted = self._evict_parked_lru()
                     if evicted is None:
                         # no capacity at all: hold the request for the next
                         # scheduler pass (order preserved via _overflow)
@@ -3675,9 +3751,14 @@ class JaxDecodeEngine(InferenceEngine):
                         admitted = False
                         if self._overflow or not self._request_q.empty():
                             # (with nothing queued _admit does nothing)
-                            with perf_tracer.span("decode/admit"), \
+                            with perf_tracer.span("decode/admit") as admit_span, \
                                     self._sched_state("admit"):
+                                before = self._n_handed_over
                                 admitted = self._admit()
+                                # (known when the span ends: in the record,
+                                # not in a device trace's annotation)
+                                admit_span.ids["handed_over"] = (
+                                    self._n_handed_over - before)
                         active = self._active_mask()
                         dispatched = False
                         if active.any():
@@ -3735,9 +3816,19 @@ class JaxDecodeEngine(InferenceEngine):
             logger.error(
                 f"decode scheduler died: {e}\n{traceback.format_exc()}"
             )
+            # (a request that handed its slot over is in no slot: it is in
+            # the records of the chunks it waits for)
+            # (one entry a request, whatever the depth)
+            handed = {
+                id(rec.items[i]): rec.items[i]
+                for rec in self._inflight for i in rec.handed
+            }
             self._inflight.clear()
             # fail all outstanding futures
             e = self._dead_error()
+            for s in handed.values():
+                if s.future is not None and not s.future.done():
+                    s.loop.call_soon_threadsafe(s.future.set_exception, e)
             for i, s in enumerate(self._slots):
                 if s is not None and s.future is not None and not s.future.done():
                     s.loop.call_soon_threadsafe(s.future.set_exception, e)
@@ -3842,10 +3933,7 @@ class JaxDecodeEngine(InferenceEngine):
             if s is None:
                 active[i] = False
                 continue
-            projected_gen = self._slot_cache.generated(
-                int(self._slot_lengths[i]), len(s.prompt)
-            )
-            if projected_gen >= s.gconfig.max_new_tokens:
+            if self._spent(i, s):
                 active[i] = False
         if not active.any():
             return None
@@ -4230,7 +4318,13 @@ class JaxDecodeEngine(InferenceEngine):
         for i, s in enumerate(rec.items):
             if s is None or not rec.active[i]:
                 continue
-            if s is not self._slots[i] or rec.epochs[i] != self._slot_epoch[i]:
+            # a request that handed its slot over (`_hand_over_spent_slot`)
+            # is no longer in the table, and these are its tokens all the
+            # same, unless an earlier chunk's stop has completed it already
+            handed = i in rec.handed and s.stop_reason is None
+            if not handed and (
+                s is not self._slots[i] or rec.epochs[i] != self._slot_epoch[i]
+            ):
                 # reconcile: the host retired/preempted this slot after the
                 # chunk was dispatched — its run-ahead tokens never
                 # happened (the length rewind at retire already un-claimed
@@ -4287,7 +4381,13 @@ class JaxDecodeEngine(InferenceEngine):
                 if blocks is not None:
                     # what the committed blocks held beyond a stop
                     self._dfn_tokens_discarded += e - (len(s.tokens) - n_before)
-            if s.stop_reason is not None:
+            if handed:
+                # the slot is the next request's: nothing of it to rewind or
+                # retire. (Its last chunk always ends it: the projection that
+                # handed the slot over was exact.)
+                if s.stop_reason is not None:
+                    self._complete(s, stop_reason=s.stop_reason)
+            elif s.stop_reason is not None:
                 # rewind the slot length to the true end: KV rows cover
                 # prompt[:-1] plus every *consumed* token (cache positions
                 # past it are never attended again before overwrite); a
@@ -5907,6 +6007,9 @@ class JaxDecodeEngine(InferenceEngine):
             "decode_runahead_chunks": int(self.config.decode_runahead_chunks),
             "chunks_dispatched_total": chunks_dispatched,
             "runahead_discarded_tokens_total": runahead_discarded,
+            # admissions that took the slot of a request whose last chunk was
+            # dispatched and not yet read back (`_hand_over_spent_slot`)
+            "slots_handed_over_total": self._n_handed_over,
             # MoE decode: pairs computed for live slots, and the busiest
             # expert's share of them per layer and token step (0 for dense)
             "moe_pairs_total": moe_pairs,

@@ -538,6 +538,18 @@ class SlotCache:
         causal model's rows lag its tokens by one, a block's are its tokens."""
         return rows - prompt_len + (self.block_length == 1)
 
+    def projection_exact(self, config) -> bool:
+        """Whether the rows the engine projects for the chunks it has
+        dispatched are the rows they will have written: a chunk of token
+        steps writes its depth for every live slot. Not under a block mask
+        (a diffusion chunk projects its depth and takes back the blocks it
+        did not commit), and not for a scheduler that drafts (`config`'s
+        `spec_decode`: a verify chunk projects its width and takes back what
+        was rejected; its draftless passes dispatch plain chunks, which are
+        exact, but a scheduler that may have either in flight is kept out
+        whole)."""
+        return self.block_length == 1 and config.spec_decode == "off"
+
     def tables(self, slot: int, nb: int):
         """What a prefill program scatters a slot's rows through: its
         block-table row, and with it the slot's ring blocks and state row

@@ -324,6 +324,54 @@ def test_moe_engine_counts_expert_load(moe_programs):
     assert m["moe_hot_expert_pairs_total"] * 2 == m["moe_pairs_total"]
 
 
+def test_a_handed_over_slot_is_counted_and_named_on_its_admit_span(cpu_devices, tmp_path):
+    """`slots_handed_over_total` (admissions into a slot whose request's last
+    chunk was dispatched and not yet read back; ISSUE 48) and the same count a
+    pass as `handed_over` on every `decode/admit` span of a record, which
+    `tools/trace_report.py` reads beside the `request/queue` spans: one slot,
+    three requests of two chunks, so the second and the third take the slot
+    of the one before."""
+    import asyncio
+    import os
+    import sys
+
+    from areal_tpu.engine.jax_decode import JaxDecodeEngine
+    from areal_tpu.utils import perf_tracer
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import trace_report
+
+    with perf_tracer.recording() as rec:
+        eng = JaxDecodeEngine(
+            JaxDecodeConfig(context_length=64, max_running_requests=1, new_tokens_per_chunk=4,
+                            dtype="float32", kv_cache_dtype="float32"),
+            InferenceEngineConfig())
+        eng.set_model(init_params(TINY, jax.random.PRNGKey(0)), TINY)
+        eng.initialize()
+        try:
+            async def go():
+                eng.pause_generation()
+                tasks = [asyncio.ensure_future(eng.agenerate(ModelRequest(
+                    input_ids=[1 + i, 5, 9, 13, 2],
+                    gconfig=GenerationHyperparameters(greedy=True, max_new_tokens=8))))
+                    for i in range(3)]
+                await asyncio.sleep(0)
+                eng.continue_generation()
+                return await asyncio.wait_for(asyncio.gather(*tasks), 300)
+
+            assert [len(r.output_tokens) for r in asyncio.run(go())] == [8, 8, 8]
+            m = eng.get_metrics()
+        finally:
+            eng.destroy()
+        path = rec.save(str(tmp_path / "record.json"))
+    assert m["slots_handed_over_total"] == 2 and m["running_requests"] == 0
+    admits = [s for s in rec.snapshot() if s["name"] == "decode/admit"]
+    assert admits and all("handed_over" in s["ids"] for s in admits)
+    assert sum(s["ids"]["handed_over"] for s in admits) == 2
+    assert trace_report.report(path)["admissions"] == {"requests": 3, "handed_over": 2}
+
+
 def test_engine_counts_live_block_columns(cpu_devices):
     """`paged_block_columns_live_total` / `_visited_total` (what the paged
     kernel walks: its live columns, and a step for each slot with none)

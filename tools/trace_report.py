@@ -28,7 +28,10 @@ an `.xplane.pb`, or the Chrome JSON that `AREAL_TPU_PERF_TRACE=1` leaves in
              span: what the trainer and what the scheduler was in; with
              `--spans` also the five longest, each named by both
 
-and from a span record the spans table alone. Device and host events of one
+and from a span record the spans table alone, and under it the decode
+scheduler's admissions: how many requests it admitted and the share of them
+that took a slot handed over before its request's last chunk was read back
+(`decode/admit`'s `handed_over`). Device and host events of one
 trace share a clock. `--spans` lays `perf_tracer`'s record (the Chrome JSON)
 over the trace's own `areal/` events: it also holds the spans known after the
 fact and those open when the profiler started or stopped. The record is on
@@ -83,9 +86,10 @@ def load(path: str) -> tuple[dict, str | None]:
 
 
 def spans_record(chrome: dict) -> dict:
-    """`perf_tracer`'s Chrome JSON as one host plane, a line per thread."""
-    return {"planes": [program_spans.as_host_plane(
-        program_spans.from_chrome(chrome), PROGRAM_PREFIX)]}
+    """`perf_tracer`'s Chrome JSON as one host plane, a line per thread, and
+    the spans themselves (`record`: their ids are not in a plane's events)."""
+    spans = program_spans.from_chrome(chrome)
+    return {"planes": [program_spans.as_host_plane(spans, PROGRAM_PREFIX)], "record": spans}
 
 
 def with_record(trace: dict, record_path: str) -> tuple[dict, dict]:
@@ -376,6 +380,21 @@ def gaps_by_thread(trace: dict, gaps: list[dict]) -> dict[str, list[tuple]]:
     return out
 
 
+def admissions(spans: list[dict]) -> dict | None:
+    """{"requests", "handed_over"}: the requests the decode scheduler admitted
+    (a `request/queue` span each) and those of them that took a SPENT slot,
+    one whose request's last chunk was dispatched and not yet read back (the
+    `handed_over` of the `decode/admit` spans; ISSUE 48). From a span record
+    alone: a device trace's annotation is made when a span opens. None where
+    the record holds no admission."""
+    requests = sum(s["name"] == "request/queue" for s in spans)
+    if not requests:
+        return None
+    return {"requests": requests,
+            "handed_over": sum(int(s["ids"].get("handed_over", 0)) for s in spans
+                               if s["name"] == "decode/admit")}
+
+
 def report(path: str, gap_ms: float = 1.0, top: int = 20, depth: int = 4,
            spans: str | None = None) -> dict:
     """Everything `main` prints, as data."""
@@ -384,6 +403,8 @@ def report(path: str, gap_ms: float = 1.0, top: int = 20, depth: int = 4,
     if spans:
         trace, out["record_clock"] = with_record(trace, spans)
     out["spans"] = span_table(trace)
+    if "record" in trace:
+        out["admissions"] = admissions(trace["record"])
     if not xplane.device_planes(trace):
         return out
     lo, hi = xplane.window(trace)
@@ -441,6 +462,11 @@ def main(argv) -> int:
     print(f"  {'count':>6} {'total s':>10} {'self s':>10}  name")
     for name, n, total, own in r["spans"]:
         print(f"  {n:6d} {total:10.4f} {own:10.4f}  {name}")
+    if r.get("admissions"):
+        a = r["admissions"]
+        print(f"\nadmissions: {a['requests']} requests admitted, {a['handed_over']} "
+              f"({100.0 * a['handed_over'] / a['requests']:.1f}%) into a slot whose request's last "
+              "chunk was dispatched and not yet read back")
     if "gaps" in r:
         gaps = r["gaps"]
         print(f"\nidle gaps of chip 0 over {args.gap_ms} ms: {len(gaps)}, "
