@@ -104,6 +104,7 @@ from areal_tpu.engine.kv_pool import (
 from areal_tpu.models import hf_io
 from areal_tpu.models.qwen2 import (
     PREFILL_DENSE_MAX,
+    decode_counts,
     decode_load_len,
     ModelConfig,
     decode_step_paged,
@@ -1544,7 +1545,7 @@ class JaxDecodeEngine(InferenceEngine):
         cfg = self.model_config
         n_chunk = self.config.new_tokens_per_chunk
         paged_impl = self._paged_impl
-        moe = bool(cfg.num_experts)
+        moe = decode_counts(cfg)
 
         # sampler shared with the speculative verify chunk (see
         # _make_sample_fn) — per-slot exactness and the top_p==1 primary-key
@@ -4148,7 +4149,7 @@ class JaxDecodeEngine(InferenceEngine):
             kq, vq, self._dev_last, self._dev_lengths, toks, logps, *rest = out
             if use_freq:
                 self._freq_counts = rest.pop(0)
-            moe_load = rest.pop(0) if self.model_config.num_experts else None
+            moe_load = rest.pop(0) if decode_counts(self.model_config) else None
             self._set_kv_operands(kq, vq)
         # start the device-to-host copies now; _consume_chunk's np.asarray
         # then only waits for data that isn't already on the host

@@ -464,13 +464,13 @@ class SlotCache:
         self._paged = "latent" if cfg.latent else "full" if cfg.mixed else None
         paged_layers = len(layers[self._paged]) if cfg.mixed else cfg.num_hidden_layers
         self.block_nbytes = paged_layers * self.alloc.block_size * self.row_nbytes
-        # the recurrent state a slot and linear layer, row 0 the null slot
+        # the recurrent state a slot and recurrent layer, row 0 the null slot:
+        # what a slot keeps for a layer is the model's to say
+        # (`ModelConfig.slot_state_shapes`), float32 whatever the cache's dtype
         self._state_shapes = {
-            "S": ((len(layers["state"]), 1 + slots, cfg.linear_num_value_heads,
-                   cfg.linear_key_head_dim, cfg.linear_value_head_dim),
-                  jnp.dtype(jnp.float32)),
-            "conv": ((len(layers["state"]), 1 + slots, cfg.linear_conv_kernel_dim - 1,
-                      cfg.linear_conv_channels), jnp.dtype(kv_dtype)),
+            name: ((len(layers["state"]), 1 + slots, *shape),
+                   jnp.dtype(jnp.float32 if name == "S" else kv_dtype))
+            for name, shape in cfg.slot_state_shapes.items()
         } if self.state else {}
         # bytes one linear layer's state update moves for one slot: its state
         # and convolution rows, once in and once out
@@ -517,7 +517,13 @@ class SlotCache:
 
         k, v = side(), {} if self._paged == "latent" else side()
         if self.state is not None:
-            k["state"] = {name: jnp.zeros(shape, dtype)
+            # placed from the start (whole on every chip of the mesh), as a
+            # program's output is: a state first seen unplaced made the first
+            # prefill program compile a second time when it next ran
+            place = (lambda a: a) if cache_sharding is None else (
+                lambda a: jax.device_put(a, jax.sharding.NamedSharding(
+                    cache_sharding.mesh, jax.sharding.PartitionSpec())))
+            k["state"] = {name: place(jnp.zeros(shape, dtype))
                           for name, (shape, dtype) in self._state_shapes.items()}
         k_scale, v_scale = (
             jax.device_put(jnp.zeros(self._scales, jnp.float32), scale_sharding)

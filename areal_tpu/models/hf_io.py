@@ -50,6 +50,26 @@ def _iter_hf_tensors(model_dir: str):
                 yield name, f.get_tensor(name)
 
 
+# Jamba's state-space mixer: our leaf -> its name under `mamba.`
+_JAMBA_MIXER = {
+    "in_kernel": "in_proj.weight",
+    "conv_kernel": "conv1d.weight",
+    "conv_bias": "conv1d.bias",
+    "x_kernel": "x_proj.weight",
+    "dt_norm": "dt_layernorm.weight",
+    "b_norm": "b_layernorm.weight",
+    "c_norm": "c_layernorm.weight",
+    "dt_kernel": "dt_proj.weight",
+    "dt_bias": "dt_proj.bias",
+    "ssm_A_log": "A_log",
+    "D": "D",
+    "out_kernel": "out_proj.weight",
+}
+# those of them a checkpoint holds transposed ([out, in]; `A_log` [channels,
+# state lanes]) and its Conv1d kernel [channels, 1, width]
+_JAMBA_TRANSPOSED = ("in_kernel", "x_kernel", "dt_kernel", "ssm_A_log", "out_kernel")
+
+
 def hf_name_to_ours(name: str) -> tuple[str, ...] | None:
     """Map one HF tensor name to a path in our (unstacked) param tree.
 
@@ -58,7 +78,7 @@ def hf_name_to_ours(name: str) -> tuple[str, ...] | None:
     name = name.removeprefix("model.")
     if name == "embed_tokens.weight":
         return ("embed", "embedding")
-    if name == "norm.weight":
+    if name in ("norm.weight", "final_layernorm.weight"):  # (the second: Jamba's)
         return ("final_norm",)
     if name == "lm_head.weight":
         return ("lm_head", "kernel")
@@ -135,6 +155,15 @@ def hf_name_to_ours(name: str) -> tuple[str, ...] | None:
             "block_sparse_moe.shared_experts.gate_proj.weight": ("mlp", "shared_gate_kernel"),
             "block_sparse_moe.shared_experts.up_proj.weight": ("mlp", "shared_up_kernel"),
             "block_sparse_moe.shared_experts.down_proj.weight": ("mlp", "shared_down_kernel"),
+            # Jamba's state-space mixer, its dense MLP and its second norm
+            # (its modelling code's names as ISSUE 50's author knew them: no
+            # checkpoint here to read them from,
+            # benchmark/configs/ai21-jamba2-3b.json `assumed`)
+            **{f"mamba.{hf}": ("attn", ours) for ours, hf in _JAMBA_MIXER.items()},
+            "feed_forward.gate_proj.weight": ("mlp", "gate_kernel"),
+            "feed_forward.up_proj.weight": ("mlp", "up_kernel"),
+            "feed_forward.down_proj.weight": ("mlp", "down_kernel"),
+            "pre_ff_layernorm.weight": ("post_attn_norm",),
             # Mixtral router
             "block_sparse_moe.gate.weight": ("mlp", "router_kernel"),
             "input_layernorm.weight": ("input_norm",),
@@ -238,6 +267,11 @@ def _convert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) -> n
             return w.reshape(w.shape[0], w.shape[-1])
         if leaf == "out_kernel":
             return np.ascontiguousarray(w.T)
+    if cfg.model_type == "jamba" and path[-2:-1] == ("attn",):
+        if leaf in _JAMBA_TRANSPOSED:
+            return np.ascontiguousarray(w.T)
+        if leaf == "conv_kernel":
+            return w.reshape(w.shape[0], w.shape[-1])
     if leaf in _KDA_MATRICES:
         return np.ascontiguousarray(w.T)
     if leaf in _KDA_CONVS:
@@ -279,6 +313,11 @@ def _unconvert_tensor(path: tuple[str, ...], w: np.ndarray, cfg: ModelConfig) ->
             return w.reshape(w.shape[0], 1, w.shape[1])
         if leaf == "out_kernel":
             return np.ascontiguousarray(w.T)
+    if cfg.model_type == "jamba" and path[-2:-1] == ("attn",):
+        if leaf in _JAMBA_TRANSPOSED:
+            return np.ascontiguousarray(w.T)
+        if leaf == "conv_kernel":
+            return w.reshape(w.shape[0], 1, w.shape[1])
     if leaf in _KDA_MATRICES:
         return np.ascontiguousarray(w.T)
     if leaf in _KDA_CONVS:
@@ -437,8 +476,18 @@ def assemble_params(
             if not p[0].startswith("layers_"):
                 put(out, p, cast(w))
     else:
+        # (a run of like layers is held stacked under its own key)
+        run_of = {f"layers_{i}": (key, a) for key, a, b in cfg.stack_plan
+                  if b - a > 1 for i in range(a, b)}
+        runs: dict[tuple, dict[int, np.ndarray]] = {}
         for p, w in flat.items():
-            put(out, p, cast(w))
+            if p[0] in run_of:
+                key, a = run_of[p[0]]
+                runs.setdefault((key,) + p[1:], {})[int(p[0][7:]) - a] = w
+            else:
+                put(out, p, cast(w))
+        for p, by_idx in runs.items():
+            put(out, p, cast(np.stack([by_idx[j] for j in range(len(by_idx))])))
 
     _validate_against_shapes(out, cfg)
     return out
@@ -486,6 +535,16 @@ def flatten_params(params: dict, cfg: ModelConfig) -> dict[tuple[str, ...], np.n
             if p[0] == "layers":
                 for i in range(cfg.num_hidden_layers):
                     out[(f"layers_{i}",) + p[1:]] = w[i]
+            else:
+                out[p] = w
+        flat = out
+    elif cfg.layer_runs:
+        starts = {key: a for key, a, b in cfg.stack_plan if b - a > 1}
+        out = {}
+        for p, w in flat.items():
+            if p[0] in starts:
+                for j in range(w.shape[0]):
+                    out[(f"layers_{starts[p[0]] + j}",) + p[1:]] = w[j]
             else:
                 out[p] = w
         flat = out
@@ -575,10 +634,18 @@ def ours_name_to_hf(path: tuple[str, ...], model_type: str = "qwen2") -> str:
                f"block_sparse_moe.shared_experts.{proj}_proj.weight"
                for proj in ("gate", "up", "down")},
         })
+    if model_type == "jamba":
+        leaf_table.update({
+            **{("attn", ours): f"mamba.{hf}" for ours, hf in _JAMBA_MIXER.items()},
+            **{("mlp", f"{proj}_kernel"): f"feed_forward.{proj}_proj.weight"
+               for proj in ("gate", "up", "down")},
+            ("post_attn_norm",): "pre_ff_layernorm.weight",
+        })
     if path == ("embed", "embedding"):
         return "model.embed_tokens.weight"
     if path == ("final_norm",):
-        return "model.norm.weight"
+        return ("model.final_layernorm.weight" if model_type == "jamba"
+                else "model.norm.weight")
     if path == ("lm_head", "kernel"):
         return "lm_head.weight"
     if path == ("value_head", "kernel"):

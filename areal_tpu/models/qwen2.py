@@ -63,12 +63,16 @@ def _cstr(x: jax.Array, *logical_axes: str | None) -> jax.Array:
 MODEL_TYPES = (
     "qwen2", "qwen3", "llama", "mistral", "gemma", "gemma2", "gpt2",
     "mixtral", "qwen2_moe", "qwen3_moe", "olmoe", "exaone_moe", "qwen3_next",
-    "sdar_moe", "deepseek_v2", "kimi_linear",
+    "sdar_moe", "deepseek_v2", "kimi_linear", "jamba",
 )
 
 # `layer_types` entries of a mixed stack (HF's names)
 _WINDOW_LAYER, _FULL_LAYER = "sliding_attention", "full_attention"
 _LINEAR_LAYER = "linear_attention"
+_SSM_LAYER = "mamba"
+# A run of like layers at least this long is held stacked and scanned
+# (`ModelConfig.layer_runs`); shorter runs stay unstacked, a Python loop.
+SCAN_RUN_MIN = 4
 
 
 @dataclass(frozen=True)
@@ -189,7 +193,8 @@ class ModelConfig:
     # sparse ones. Hashable tuples/ints, so the frozen config stays a jit
     # static. A mixed stack has no uniform per-layer pytree: its layers
     # live unstacked (`layers_{i}`, scan_layers=False) and the layer loops
-    # read each layer's kind from here, statically.
+    # read each layer's kind from here, statically; its long runs of like
+    # recurrent layers are held stacked and scanned (`layer_runs`).
     layer_types: tuple | None = None
     nope_full_layers: bool = False
     first_k_dense: int = 0
@@ -211,6 +216,20 @@ class ModelConfig:
     # Diag(exp(g)) S`), q, k and v from projections and convolutions of
     # their own, two low-rank gates and a sigmoid on the output.
     linear_decay_lanes: bool = False
+    # -- Jamba-class. A fourth layer kind, "mamba": Mamba-1's selective
+    # state-space mixer (`mamba_mixer`), `ssm_inner` = `ssm_expand` x hidden
+    # channels behind a depthwise causal convolution of width
+    # `linear_conv_kernel_dim` (with a bias under `ssm_conv_bias`), each
+    # channel a diagonal recurrence over `ssm_state_size` lanes whose step
+    # `dt`, input `B` and output `C` are functions of the token (through a
+    # `ssm_dt_rank` bottleneck; Jamba norms the three). Its cache is a
+    # float32 state `[ssm_state_size, ssm_inner]` a slot and the
+    # convolution's last rows, as a linear layer's (`slot_state_shapes`).
+    # 0 lanes: no such layers.
+    ssm_state_size: int = 0
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 0
+    ssm_conv_bias: bool = True
     attn_output_gate: bool = False
     partial_rotary_factor: float = 1.0
     # -- SDAR-class (generation by diffusion over blocks). `block_length` B > 1:
@@ -315,8 +334,66 @@ class ModelConfig:
         return self.sliding_window if self.layer_types[i] == _WINDOW_LAYER else None
 
     def layer_linear(self, i) -> bool:
-        """Whether layer i's mixer is the Gated DeltaNet and not attention."""
-        return self.layer_types is not None and self.layer_types[i] == _LINEAR_LAYER
+        """Whether layer i's mixer is a recurrence (the Gated DeltaNet, Kimi
+        Delta Attention, a state-space mixer) and not attention: its cache is
+        a state a slot."""
+        return self.layer_types is not None and self.layer_types[i] in (
+            _LINEAR_LAYER, _SSM_LAYER)
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of a state-space mixer."""
+        return self.ssm_expand * self.hidden_size
+
+    @property
+    def slot_state_shapes(self) -> dict:
+        """What ONE slot keeps for ONE recurrent layer, whichever mixer the
+        model's recurrent layers are: {"S": the float32 state's shape,
+        "conv": the convolution's cached rows' (pre-convolution channels, in
+        the cache's dtype)}. The one place that knows; the slot cache, the
+        step kernels' callers and the byte counts ask here."""
+        K = self.linear_conv_kernel_dim
+        if self.ssm_state_size:
+            # the state's lanes are the channels, its sublanes the state's
+            return {"S": (self.ssm_state_size, self.ssm_inner),
+                    "conv": (K - 1, self.ssm_inner)}
+        return {"S": (self.linear_num_value_heads, self.linear_key_head_dim,
+                      self.linear_value_head_dim),
+                "conv": (K - 1, self.linear_conv_channels)}
+
+    @property
+    def layer_runs(self) -> tuple:
+        """The runs `(first, past_last)` of an unstacked tree that are held
+        STACKED and scanned: maximal runs, at least `SCAN_RUN_MIN` long, of
+        recurrent layers under a dense MLP (their body takes the layer's
+        place in the state pool traced). A property of the stack: every
+        other layer stays `layers_{i}`, one body a layer in the program."""
+        if self.scan_layers or self.layer_types is None:
+            return ()
+        like = [self.layer_linear(i) and not self.layer_sparse(i)
+                for i in range(self.num_hidden_layers)]
+        runs, a = [], None
+        for i, ok in enumerate(like + [False]):
+            if ok and a is None:
+                a = i
+            elif not ok and a is not None:
+                if i - a >= SCAN_RUN_MIN:
+                    runs.append((a, i))
+                a = None
+        return tuple(runs)
+
+    @property
+    def stack_plan(self) -> tuple:
+        """An unstacked tree's top-level layer entries in layer order, as
+        `(key, first, past_last)`: `layers_{i}` for one layer, `run_{a}_{b}`
+        for a stacked run (`layer_runs`)."""
+        plan, i = [], 0
+        starts = dict(self.layer_runs)
+        while i < self.num_hidden_layers:
+            b = starts.get(i, i + 1)
+            plan.append((f"run_{i}_{b}" if i in starts else f"layers_{i}", i, b))
+            i = b
+        return tuple(plan)
 
     @property
     def linear_conv_channels(self) -> int:
@@ -568,6 +645,8 @@ class ModelConfig:
             kw.update(_deepseek_v2_kw(hf))
         elif model_type == "kimi_linear":
             kw.update(_kimi_linear_kw(hf))
+        elif model_type == "jamba":
+            kw.update(_jamba_kw(hf))
         elif model_type == "gemma":
             # Gemma-1 (reference: realhf/api/from_hf/gemma.py — GeGLU MLP,
             # zero-centered RMSNorm, sqrt(H)-scaled embeddings, tied head).
@@ -975,6 +1054,48 @@ def _kimi_linear_kw(hf: dict) -> dict:
     )
 
 
+def _jamba_kw(hf: dict) -> dict:
+    """Jamba (`jamba`): Mamba-1 state-space layers with an attention layer
+    every `attn_layer_period` (at `attn_layer_offset` within the period) that
+    takes NO positional encoding, a dense SwiGLU MLP in every layer
+    (`num_experts` 1: the "expert" layers are the same dense MLP). Raises, by
+    the key's name, on what is not served."""
+    if int(hf.get("num_experts", 1)) > 1:
+        raise NotImplementedError(
+            f"jamba with num_experts={hf['num_experts']}: the routed MLP of "
+            "its expert layers is not implemented (served: num_experts 1, a "
+            "dense MLP in every layer)"
+        )
+    if hf.get("sliding_window") is not None:
+        raise NotImplementedError(
+            f"jamba with sliding_window={hf['sliding_window']}: windowed "
+            "attention beside state-space layers is not implemented (served: null)"
+        )
+    if hf.get("mamba_proj_bias", False):
+        raise NotImplementedError(
+            "jamba with mamba_proj_bias true: a bias on the mixer's in and out "
+            "projections is not implemented (served: false)"
+        )
+    L, H = hf["num_hidden_layers"], hf["hidden_size"]
+    period, offset = int(hf["attn_layer_period"]), int(hf["attn_layer_offset"])
+    dt_rank = hf.get("mamba_dt_rank", "auto")
+    return dict(
+        pos_embed="none",
+        layer_types=tuple(
+            _FULL_LAYER if i % period == offset else _SSM_LAYER for i in range(L)
+        ),
+        ssm_state_size=int(hf.get("mamba_d_state", 16)),
+        ssm_expand=int(hf.get("mamba_expand", 2)),
+        ssm_dt_rank=-(-H // 16) if dt_rank == "auto" else int(dt_rank),
+        ssm_conv_bias=bool(hf.get("mamba_conv_bias", True)),
+        linear_conv_kernel_dim=int(hf.get("mamba_d_conv", 4)),
+        tie_word_embeddings=hf.get("tie_word_embeddings", True),
+        # attention layers among runs of state-space ones: an unstacked tree
+        # whose runs are held stacked (`ModelConfig.layer_runs`)
+        scan_layers=False,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Parameter tree + logical sharding axes
 # ---------------------------------------------------------------------------
@@ -1025,7 +1146,34 @@ def _kda_shapes(cfg: ModelConfig) -> dict:
     }
 
 
+def _ssm_shapes(cfg: ModelConfig) -> dict:
+    """The state-space mixer's leaves: the channels u and the output gate z
+    from one projection (columns [u | z]), the depthwise convolution and its
+    bias, the token's step, input and output vectors from another ([dt_r | B
+    | C]) with a norm each (Jamba's), the step's expansion to the channels
+    with its bias, the decay `A = -exp(ssm_A_log)` held `[state lanes,
+    channels]` (a checkpoint's `A_log` transposed: the channels on the TPU's
+    lanes), the skip `D` and the output projection."""
+    H, Di, N, Rk = cfg.hidden_size, cfg.ssm_inner, cfg.ssm_state_size, cfg.ssm_dt_rank
+    return {
+        "in_kernel": (H, 2 * Di),
+        "conv_kernel": (Di, cfg.linear_conv_kernel_dim),
+        **({"conv_bias": (Di,)} if cfg.ssm_conv_bias else {}),
+        "x_kernel": (Di, Rk + 2 * N),
+        "dt_norm": (Rk,),
+        "b_norm": (N,),
+        "c_norm": (N,),
+        "dt_kernel": (Rk, Di),
+        "dt_bias": (Di,),
+        "ssm_A_log": (N, Di),
+        "D": (Di,),
+        "out_kernel": (Di, H),
+    }
+
+
 def _linear_shapes(cfg: ModelConfig) -> dict:
+    if cfg.ssm_state_size:
+        return _ssm_shapes(cfg)
     return _kda_shapes(cfg) if cfg.linear_decay_lanes else _gdn_shapes(cfg)
 
 
@@ -1156,6 +1304,16 @@ _LAYER_AXES = {
         "g_a_kernel": ("embed", None),
         "g_b_kernel": (None, None),
         "o_norm": ("norm",),
+        # the state-space mixer
+        "in_kernel": ("embed", None),
+        "conv_bias": ("norm",),
+        "x_kernel": (None, None),
+        "dt_norm": ("norm",),
+        "b_norm": ("norm",),
+        "c_norm": ("norm",),
+        "dt_kernel": (None, None),
+        "ssm_A_log": (None, None),
+        "D": ("norm",),
         # latent attention: the low-rank axes stay whole, heads split
         "q_a_kernel": ("embed", None),
         "q_a_norm": ("norm",),
@@ -1411,9 +1569,12 @@ def param_shapes(cfg: ModelConfig) -> dict:
         layers = jax.tree.map(lambda s: (L, *s), layer, is_leaf=lambda x: isinstance(x, tuple))
         layers_tree = {"layers": layers}
     else:
+        # (a run of like layers: its first layer's leaves, stacked)
         layers_tree = {
-            f"layers_{i}": _layer_shapes(cfg, i)
-            for i in range(cfg.num_hidden_layers)
+            key: _layer_shapes(cfg, a) if b - a == 1 else jax.tree.map(
+                lambda s: (b - a, *s), _layer_shapes(cfg, a),
+                is_leaf=lambda x: isinstance(x, tuple))
+            for key, a, b in cfg.stack_plan
         }
     out = {
         "embed": {"embedding": (cfg.vocab_size, cfg.hidden_size)},
@@ -1434,8 +1595,8 @@ def param_shapes(cfg: ModelConfig) -> dict:
 
 
 def param_logical_axes(cfg: ModelConfig) -> dict:
-    def prefix_layers(axes_tree):
-        if cfg.scan_layers:
+    def prefix_layers(axes_tree, stacked=cfg.scan_layers):
+        if stacked:
             return jax.tree.map(
                 lambda a: ("layers", *a),
                 axes_tree,
@@ -1463,7 +1624,8 @@ def param_logical_axes(cfg: ModelConfig) -> dict:
         layers_tree = {"layers": prefix_layers(layer_axes())}
     else:
         layers_tree = {
-            f"layers_{i}": layer_axes(i) for i in range(cfg.num_hidden_layers)
+            key: prefix_layers(layer_axes(a), b - a > 1)
+            for key, a, b in cfg.stack_plan
         }
     out = {
         "embed": {"embedding": ("vocab", "embed")},
@@ -1509,8 +1671,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     # effective scale is 1 + weight
     def zero_special(path, x):
         name = path[-1].key if hasattr(path[-1], "key") else ""
-        if name == "dt_bias":  # the Gated DeltaNet's, as its module starts it
-            return x
+        if name == "dt_bias":
+            if cfg.ssm_state_size:  # a step of 0.01: softplus^-1
+                return jnp.full_like(x, math.log(math.expm1(0.01)))
+            return x  # the Gated DeltaNet's, as its module starts it
+        if name == "ssm_A_log":  # Mamba's: A[n, c] = -(n + 1)
+            lanes = jnp.log(jnp.arange(1, x.shape[-2] + 1, dtype=jnp.float32))
+            return jnp.broadcast_to(lanes[:, None], x.shape).astype(x.dtype)
         if name.endswith("_bias") or name == "bias":
             return jnp.zeros_like(x)
         if cfg.norm_zero_centered and name.endswith("norm"):
@@ -1583,8 +1750,8 @@ def map_quant_kernels(params: dict, fn) -> dict:
     if "layers" in params:
         out["layers"] = _map_wq_layer(params["layers"], fn, stacked=True)
     for k in params:
-        if k.startswith("layers_"):
-            out[k] = _map_wq_layer(params[k], fn, stacked=False)
+        if k.startswith(("layers_", "run_")):
+            out[k] = _map_wq_layer(params[k], fn, stacked=k.startswith("run_"))
     return out
 
 
@@ -2684,10 +2851,12 @@ def _gdn_output(layer_p: dict, o: jax.Array, z: jax.Array, cfg: ModelConfig):
         )
 
 
-def _gdn_conv(u: jax.Array, kernel: jax.Array, segment_ids: jax.Array):
+def _gdn_conv(u: jax.Array, kernel: jax.Array, segment_ids: jax.Array,
+              bias: jax.Array | None = None):
     """Depthwise causal convolution over one packed stream: u [T, C] ->
-    silu(sum_j kernel[:, j] * u[t - (K-1) + j]) in float32, rows of another
-    segment (and those before the stream's start) counting as zeros."""
+    silu(sum_j kernel[:, j] * u[t - (K-1) + j] (+ bias [C], where the model
+    has one)) in float32, rows of another segment (and those before the
+    stream's start) counting as zeros."""
     K = kernel.shape[1]
     T = u.shape[0]
     u32 = u.astype(jnp.float32)
@@ -2698,6 +2867,8 @@ def _gdn_conv(u: jax.Array, kernel: jax.Array, segment_ids: jax.Array):
         seg = jnp.pad(segment_ids, (back, 0), constant_values=PADDING_SEGMENT)[:T]
         same = (seg == segment_ids)[:, None]
         acc = acc + jnp.where(same, shifted, 0.0) * w[:, K - 1 - back]
+    if bias is not None:
+        acc = acc + bias.astype(jnp.float32)
     return jax.nn.silu(acc)
 
 
@@ -2815,20 +2986,22 @@ def gated_delta_net(layer_p: dict, x: jax.Array, segment_ids: jax.Array,
     return out, (S, rows)
 
 
-def _conv_step(conv: jax.Array, ci: int, u: jax.Array, kernel: jax.Array,
-               active: jax.Array | None):
+def _conv_step(conv: jax.Array, ci, u: jax.Array, kernel: jax.Array,
+               active: jax.Array | None, bias: jax.Array | None = None):
     """One token of the depthwise convolution for R slots from their cached
     rows: conv [n_lin, 1 + R, K-1, C], u [R, C] this token's pre-convolution
-    channels, kernel [C, K]. Returns (silu of the mixed channels [R, C]
-    float32, conv with the slots' rows moved on; a slot not `active` keeps
-    its rows)."""
+    channels, kernel [C, K], `bias` [C] where the model has one; `ci` the
+    layer's place in the pool (traced inside a scanned run). Returns (silu
+    of the mixed channels [R, C] float32, conv with the slots' rows moved on;
+    a slot not `active` keeps its rows)."""
     with jax.named_scope("conv_state"):
         rows = conv[ci, 1:]  # [R, K-1, C]
         window = jnp.concatenate([rows, u[:, None].astype(rows.dtype)], axis=1)
         w = kernel.astype(jnp.float32)  # [C, K]
-        mixed = jax.nn.silu(
-            jnp.einsum("rkc,ck->rc", window.astype(jnp.float32), w)
-        )
+        mixed = jnp.einsum("rkc,ck->rc", window.astype(jnp.float32), w)
+        if bias is not None:
+            mixed = mixed + bias.astype(jnp.float32)
+        mixed = jax.nn.silu(mixed)
         new_rows = window[:, 1:]
         if active is not None:
             new_rows = jnp.where(active[:, None, None], new_rows, rows)
@@ -3063,9 +3236,163 @@ def kimi_delta_step(layer_p: dict, x: jax.Array, state: dict, ci: int,
     return _kda_output(layer_p, o, x, cfg), {"S": S, "conv": conv}
 
 
+# -- Mamba-1's selective state-space mixer (Jamba's recurrent layers) ----------
+# A channel c of `ssm_inner` and a state lane n of `ssm_state_size`, float32:
+#   h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] B_t[n] u_t[c]
+#   y_t[c] = sum_n C_t[n] h_t[n, c] + D[c] u_t[c]
+# diagonal, no matrix product in it: u behind a depthwise convolution (the
+# linear mixers' `_gdn_conv` / `_conv_step`, here with a bias), dt, B and C
+# functions of the token. The same two forms as the linear mixers:
+# `_ssm_chunk_scan` over a packed stream and one step a slot
+# (`ops/ssm_step.py`), the state held `[state lanes, channels]`.
+
+SSM_CHUNK = 64
+
+
+def _ssm_project(layer_p: dict, x: jax.Array, cfg: ModelConfig):
+    """x [..., H] -> (u [..., Di] pre-convolution channels, z [..., Di] the
+    output gate's input)."""
+    with jax.named_scope("in_proj"):
+        uz = jnp.einsum("...h,hc->...c", x, layer_p["in_kernel"])
+    return uz[..., : cfg.ssm_inner], uz[..., cfg.ssm_inner :]
+
+
+@jax.named_scope("ssm_params")
+def _ssm_params(layer_p: dict, u: jax.Array, cfg: ModelConfig):
+    """Post-convolution channels u [..., Di] float32 -> (dt [..., Di] > 0,
+    B [..., N], C [..., N]) float32: one projection to [dt_r | B | C], an
+    RMSNorm each (Jamba's), dt_r expanded to the channels, a bias, softplus.
+    The matmuls take the compute dtype's operands and accumulate float32."""
+    Rk, N = cfg.ssm_dt_rank, cfg.ssm_state_size
+    dtype, f32 = jnp.dtype(cfg.dtype), jnp.float32
+    proj = jnp.einsum("...c,cr->...r", u.astype(dtype), layer_p["x_kernel"],
+                      preferred_element_type=f32)
+    eps = cfg.rms_norm_eps
+    dt_r = rms_norm(proj[..., :Rk], layer_p["dt_norm"], eps)
+    B = rms_norm(proj[..., Rk : Rk + N], layer_p["b_norm"], eps)
+    C = rms_norm(proj[..., Rk + N :], layer_p["c_norm"], eps)
+    dt = jnp.einsum("...r,rc->...c", dt_r.astype(dtype), layer_p["dt_kernel"],
+                    preferred_element_type=f32)
+    return jax.nn.softplus(dt + layer_p["dt_bias"].astype(f32)), B, C
+
+
+def _ssm_decay(layer_p: dict) -> jax.Array:
+    """A [N, Di] float32, negative."""
+    return -jnp.exp(layer_p["ssm_A_log"].astype(jnp.float32))
+
+
+def _ssm_output(layer_p: dict, y: jax.Array, z: jax.Array, cfg: ModelConfig):
+    """y [..., Di] float32 -> [..., H]: `y * silu(z)` in float32, then the
+    output projection."""
+    with jax.named_scope("out_gate"):
+        y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("out_proj"):
+        return jnp.einsum("...c,ch->...h", y, layer_p["out_kernel"])
+
+
+@jax.named_scope("ssm_scan")
+def _ssm_chunk_scan(u, dt, B, C, A, segment_ids, chunk: int = SSM_CHUNK):
+    """The selective scan over one packed stream, a chunk of tokens at a time.
+
+    u, dt [T, Di], B, C [T, N], A [N, Di], all float32; `segment_ids` [T]:
+    the state starts from zero at each segment's first token; a token of
+    `PADDING_SEGMENT` must come with dt = 0 and then leaves the state as it
+    is (decay 1, input 0). Returns (y [T, Di] without the skip term, h [N,
+    Di] after the last token). Inside a chunk the recurrence's pairs (decay,
+    input) go through `associative_scan`, every factor in (0, 1], the decay
+    0 at a segment's first token; the scan over chunks carries h alone, and
+    its backward recomputes a chunk from the h it started with."""
+    T, Di = u.shape
+    N = B.shape[-1]
+    seg = _padding_joins_the_segment_before(segment_ids)
+    first = seg != jnp.concatenate(
+        [jnp.full((1,), PADDING_SEGMENT - 1, seg.dtype), seg[:-1]])
+    pad = (-T) % chunk
+    if pad:
+        u, dt, B, C = (jnp.pad(a, ((0, pad), (0, 0))) for a in (u, dt, B, C))
+        first = jnp.pad(first, (0, pad))
+    n = (T + pad) // chunk
+    u, dt, B, C, first = (
+        a.reshape(n, chunk, *a.shape[1:]) for a in (u, dt, B, C, first))
+
+    def combine(left, right):
+        return left[0] * right[0], right[0] * left[1] + right[1]
+
+    def step(h, xs):  # h [N, Di]
+        u_c, dt_c, B_c, C_c, first_c = xs
+        decay = jnp.where(first_c[:, None, None], 0.0,
+                          jnp.exp(dt_c[:, None, :] * A[None]))  # [chunk, N, Di]
+        write = (dt_c * u_c)[:, None, :] * B_c[:, :, None]
+        decay, write = jax.lax.associative_scan(combine, (decay, write), axis=0)
+        hs = decay * h[None] + write
+        return hs[-1], jnp.sum(hs * C_c[:, :, None], axis=1)
+
+    h, y = jax.lax.scan(
+        jax.checkpoint(step), jnp.zeros((N, Di), jnp.float32),
+        (u, dt, B, C, first))
+    return y.reshape(n * chunk, Di)[:T], h
+
+
+def mamba_mixer(layer_p: dict, x: jax.Array, segment_ids: jax.Array,
+                cfg: ModelConfig, true_len: jax.Array | None = None):
+    """The state-space mixer over one packed stream x [T, H]:
+    `gated_delta_net`'s contract (segments reset the state and the
+    convolution, padding does not enter the state, and with `true_len` the
+    cache to hand over: h [N, Di] float32 at the last real token and the
+    last K-1 real pre-convolution rows [K-1, Di]). `forward` (and its
+    gradient) runs `_ssm_chunk_scan`; a prefill `ops/ssm_scan.py`, whose XLA
+    form is the same `_ssm_chunk_scan`."""
+    u, z = _ssm_project(layer_p, x, cfg)
+    with jax.named_scope("conv"):
+        uc = _gdn_conv(u, layer_p["conv_kernel"], segment_ids,
+                       layer_p.get("conv_bias"))
+    dt, B, C = _ssm_params(layer_p, uc, cfg)
+    dt = jnp.where((segment_ids != PADDING_SEGMENT)[:, None], dt, 0.0)
+    if true_len is None:
+        y, h = _ssm_chunk_scan(uc, dt, B, C, _ssm_decay(layer_p), segment_ids)
+    else:
+        # a prefill, one sequence from a zero state: on a TPU the scan as a
+        # kernel, the state never leaving the chip's vector memory
+        from areal_tpu.ops.ssm_scan import ssm_scan
+
+        y, h = ssm_scan(uc, dt, B, C, _ssm_decay(layer_p), scan=functools.partial(
+            _ssm_chunk_scan, segment_ids=segment_ids))
+    out = _ssm_output(
+        layer_p, y + layer_p["D"].astype(jnp.float32) * uc, z, cfg)
+    if true_len is None:
+        return out
+    K = cfg.linear_conv_kernel_dim
+    with jax.named_scope("conv_state"):
+        rows = jax.lax.dynamic_slice_in_dim(
+            jnp.pad(u, ((K - 1, 0), (0, 0))), true_len, K - 1, axis=0
+        )
+    return out, (h, rows)
+
+
+def mamba_step(layer_p: dict, x: jax.Array, state: dict, ci,
+               active: jax.Array | None, cfg: ModelConfig,
+               impl: str = "auto", live: tuple | None = None):
+    """One decode step of state-space layer number `ci` (among the recurrent
+    layers; traced inside a scanned run) for R slots: `gated_delta_step`'s
+    contract, `state["S"]` [n, 1 + R, N, Di] float32. `live`: the kernel's
+    work list (`ops/ssm_step.py:live_slots`), taken once a token step."""
+    from areal_tpu.ops.ssm_step import ssm_step
+
+    u, z = _ssm_project(layer_p, x, cfg)
+    uc, conv = _conv_step(state["conv"], ci, u, layer_p["conv_kernel"], active,
+                          layer_p.get("conv_bias"))
+    dt, B, C = _ssm_params(layer_p, uc, cfg)
+    with jax.named_scope("ssm_step"):
+        y, S = ssm_step(state["S"], dt, uc, B, C, _ssm_decay(layer_p),
+                        layer_p["D"], ci, active, impl=impl, live=live)
+    return _ssm_output(layer_p, y, z, cfg), {"S": S, "conv": conv}
+
+
 def _linear_mixers(cfg: ModelConfig) -> tuple:
     """(the mixer over a packed stream, its decode step) of the model's
-    linear layers."""
+    recurrent layers."""
+    if cfg.ssm_state_size:
+        return mamba_mixer, mamba_step
     if cfg.linear_decay_lanes:
         return kimi_delta_attention, kimi_delta_step
     return gated_delta_net, gated_delta_step
@@ -3231,10 +3558,22 @@ def forward(
         )
     else:
         aux_total = jnp.float32(0.0)
-        for i in range(cfg.num_hidden_layers):
+        for key, i, past in cfg.stack_plan:
+            if past - i > 1:
+                # a run of like layers, stacked: one body, scanned
+                def run_body(carry, layer_p, i=i):
+                    h, aux = layer_fn(
+                        layer_p, carry[0], cos, sin, segment_ids,
+                        masks.get(cfg.layer_window(i)), cfg, i, *more,
+                    )
+                    return (h, carry[1] + aux), None
+
+                (x, aux_total), _ = jax.lax.scan(
+                    run_body, (x, aux_total), params[key])
+                continue
             with _layer_scope(cfg, i):
                 x, aux = layer_fn(
-                    params[f"layers_{i}"], x, cos, sin, segment_ids,
+                    params[key], x, cos, sin, segment_ids,
                     masks.get(cfg.layer_window(i)), cfg, i, *more,
                 )
             aux_total = aux_total + aux
@@ -3610,6 +3949,14 @@ def _moe_load_len(cfg: ModelConfig) -> int:
             + (2 if cfg.moe_grouped else 0))
 
 
+def decode_counts(cfg: ModelConfig) -> bool:
+    """Whether a decode chunk has a load vector to return: a model with
+    experts (their pairs) or a mixed stack (the rows and state updates its
+    kinds of layer read); a dense uniform stack has none and its chunk
+    carries no such leaf."""
+    return bool(cfg.num_experts) or cfg.mixed
+
+
 def decode_load_len(cfg: ModelConfig) -> int:
     """Entries of the vector `decode_step_paged` returns under `moe_load`:
     `moe_mlp`'s, for a mixed stack the cached rows read by the full and by
@@ -3655,6 +4002,8 @@ def _project_qkv(layer_p: dict, x: jax.Array, cos, sin, cfg: ModelConfig,
         q, *gate = _split_output_gate(q, cfg)
         if cfg.qk_norm:
             q, k = _qk_norm(q, k, layer_p, cfg)
+    if cos is None:  # a model with no positional encoding has no tables
+        return q, k, v, *gate
     cos_b = cos[..., None, :].astype(q.dtype)
     sin_b = sin[..., None, :].astype(q.dtype)
 
@@ -3894,18 +4243,34 @@ def prefill(
     else:
         # attention layers hand over rows of keys and values (stacked in
         # layer order), linear layers their state
+        # (a state layer's cache beside whether it is a run's stack)
         rows, states = [], []
-        for i in range(cfg.num_hidden_layers):
+        for key, i, past in cfg.stack_plan:
+            if past - i > 1:
+                # a run of recurrent layers, stacked: one body, scanned, its
+                # layers' caches stacked as the scan's outputs
+                x, cache = jax.lax.scan(
+                    lambda x, layer_p, i=i: layer(x, (layer_p,), i), x, params[key]
+                )
+                states.append((True, cache))
+                continue
             with _layer_scope(cfg, i):
                 x, cache = layer(
-                    x, (params[f"layers_{i}"], *(p[i] for p in prefix)), i
+                    x, (params[key], *(p[i] for p in prefix)), i
                 )
-            (states if cfg.layer_linear(i) else rows).append(cache)
+            if cfg.layer_linear(i):
+                states.append((False, cache))
+            else:
+                rows.append(cache)
         ks, vs = (jnp.stack(t) for t in zip(*rows))
 
     logits = _final_logits(params, x, cfg) if with_logits else None
+    if has_state and cfg.layer_runs:
+        S, conv = (jnp.concatenate(t) for t in zip(*(
+            c if stacked else tuple(t[None] for t in c) for stacked, c in states)))
+        return logits, ks, vs, {"S": S, "conv": conv}
     if has_state:
-        S, conv = (jnp.stack(t) for t in zip(*states))
+        S, conv = (jnp.stack(t) for t in zip(*(c for _, c in states)))
         return logits, ks, vs, {"S": S, "conv": conv}
     return logits, ks, vs
 
@@ -3986,9 +4351,18 @@ def _scan_layers_carrying(layer, carry, params, cfg: ModelConfig):
             cfg,
             jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32),
         )[0]
-    for i in range(cfg.num_hidden_layers):
+    for key, i, past in cfg.stack_plan:
+        if past - i > 1:
+            # a run of like layers, stacked: one body, scanned, the pools in
+            # the carry whole and the layer's place in its pool traced (the
+            # run's first layer names the kind, `at` counts from it)
+            carry = jax.lax.scan(
+                lambda c, xs, i=i: (layer(c, xs[0], i, xs[1]), None), carry,
+                (params[key], jnp.arange(past - i, dtype=jnp.int32)),
+            )[0]
+            continue
         with _layer_scope(cfg, i):
-            carry = layer(carry, params[f"layers_{i}"], i)
+            carry = layer(carry, params[key], i)
     return carry
 
 
@@ -4198,8 +4572,15 @@ def decode_step_paged(
     else:
         live = live_of(valid, k_pool)
 
+    # a state-space step's work list over the live slots, once a token step
+    state_kw = {}
+    if cfg.ssm_state_size:
+        from areal_tpu.ops.ssm_step import live_slots
+
+        state_kw["live"] = live_slots(active, R)
+
     @jax.named_scope("layer")
-    def layer(carry, layer_p, li):
+    def layer(carry, layer_p, li, at=0):
         x, kp, vp, load = carry
         h = _norm(x, layer_p["input_norm"], cfg, layer_p.get("input_norm_bias"))
         # (a mixer whose output is projected already: linear, latent)
@@ -4207,9 +4588,10 @@ def decode_step_paged(
         with jax.named_scope("attn"):
             if cfg.layer_linear(li):
                 # no rows to write or read: the slot's state, updated in place
+                # (`at`: the layer's place in a scanned run, traced)
                 attn_out, state = _linear_mixers(cfg)[1](
-                    layer_p["attn"], h, kp["state"], index[li][1], active, cfg,
-                    attn_impl,
+                    layer_p["attn"], h, kp["state"], index[li][1] + at, active,
+                    cfg, attn_impl, **state_kw,
                 )
                 kp = {**kp, "state": state}
             elif cfg.latent:

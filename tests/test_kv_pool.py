@@ -352,6 +352,7 @@ import json  # noqa: E402
 import os  # noqa: E402
 
 from test_deepseek_v2 import FULL as LATENT  # noqa: E402
+from test_jamba import CFG as STATE_SPACE  # noqa: E402 — a state-space state a slot, runs stacked
 from test_kexaone import FULL as RING  # noqa: E402 — window 8: 3 pages of 4, slack 4
 from test_kimi_linear import FULL as STATE_LATENT  # noqa: E402 — a state AND latent rows a slot
 from test_qwen3next import FULL as STATE  # noqa: E402
@@ -361,9 +362,9 @@ from areal_tpu.engine import kv_pool  # noqa: E402
 from areal_tpu.engine.kv_pool import SlotCache  # noqa: E402
 
 CACHES = {"uniform": TINY, "ring": RING, "state": STATE, "latent": LATENT, "block": BLOCK,
-          "state_latent": STATE_LATENT}
+          "state_latent": STATE_LATENT, "state_space": STATE_SPACE}
 KINDS = sorted(CACHES)
-HAS_STATE = ("state", "state_latent")  # one recurrent state a slot: good for one length
+HAS_STATE = ("state", "state_latent", "state_space")  # one recurrent state a slot: good for one length
 R, BS, NB = 4, 4, 65
 
 
@@ -384,7 +385,8 @@ def test_cache_kinds_and_what_is_cached_at_admission(kind):
     cache = _cache(kind)
     assert cache.kinds == {"uniform": (), "ring": ("pools", "window"), "state": ("pools", "state"),
                            "latent": ("pools", "latent"), "block": ("block",),
-                           "state_latent": ("pools", "state", "latent")}[kind]
+                           "state_latent": ("pools", "state", "latent"),
+                           "state_space": ("pools", "state")}[kind]
     if kind == "block":
         # whole blocks; a slot's rows are its tokens
         assert [cache.cover(n) for n in (3, 4, 9, 16)] == [0, 4, 8, 16]
@@ -443,7 +445,7 @@ def test_what_a_fork_aliases_and_what_it_copies(kind, monkeypatch):
     cache.rewritten(0, 10)
     # (a state beside latent rows: the blocks aliased and the state copied, in one fork)
     extra = {"ring": ["fork_ring"], "state": ["fork_state"],
-             "state_latent": ["fork_state"]}.get(kind, [])
+             "state_latent": ["fork_state"], "state_space": ["fork_state"]}.get(kind, [])
     # 10 rows: two blocks aliased, the third (2 rows) copied
     copies = cache.fork(0, 1, 10)
     assert [fn.__name__ for fn, *_ in copies] == ["fork_block"] + extra
@@ -489,6 +491,13 @@ def test_the_pools_are_the_trees_the_programs_take(kind):
         assert shapes(v) == {"full": ((2, NB, *row), "float32")}
         assert shapes(k) == {**shapes(v), "state": {
             "S": ((6, 1 + R, 8, 16, 16), "float32"), "conv": ((6, 1 + R, 3, 256), "float32")}}
+    elif kind == "state_space":
+        # the shapes are the model's to say (`ModelConfig.slot_state_shapes`):
+        # [state lanes, channels] a slot, ONE kv head a row of the paged pool
+        assert row == (BS, 12) and cfg.slot_state_shapes == {"S": (16, 96), "conv": (3, 96)}
+        assert shapes(v) == {"full": ((2, NB, *row), "float32")}
+        assert shapes(k) == {**shapes(v), "state": {
+            "S": ((10, 1 + R, 16, 96), "float32"), "conv": ((10, 1 + R, 3, 96), "float32")}}
     elif kind == "state_latent":
         # the K-side dict holds the latent pool (the attention layers alone) and
         # the state; nothing on the V side
@@ -531,6 +540,7 @@ def test_an_int8_pool_has_its_scale_pools_and_a_uniform_window_its_columns():
     ("ring", [5, 7], dict(full=5, window=7, latent=0, state=0)),
     # ONE entry of state updates: all `models/qwen2.py:decode_load_len` emits
     ("state", [5, 0, 7], dict(full=5, window=0, latent=0, state=7)),
+    ("state_space", [5, 0, 7], dict(full=5, window=0, latent=0, state=7)),
     ("latent", [0, 0, 9], dict(full=0, window=0, latent=9, state=0)),
     # state updates, then latent rows (models/qwen2.py: decode_step_paged)
     ("state_latent", [0, 0, 3, 9], dict(full=0, window=0, latent=9, state=3)),
@@ -550,6 +560,9 @@ def test_a_chunks_counters_of_rows_read_by_kind(kind, tail, want):
     # the same 640-lane row over the 2 latent layers, and a KDA layer's update:
     # 2 MiB of state and 72 KiB of convolution rows, in and out
     ("kimi-linear-48b-a3b", {}, 1280, None, 4_341_760),
+    # ONE kv head of 128 a side over the 2 attention layers, and a state-space
+    # layer's update: 327,680 B of float32 state and 30,720 B of rows, in and out
+    ("ai21-jamba2-3b", {}, 2 * 1 * 128 * 2, None, 716_800),
     ("k-exaone-236b-a23b", {}, 2 * 8 * 128 * 2, None, 0),
     ("sdar-30b-a3b-chat", {}, 2 * 4 * 128 * 2, None, 0),
 ])
@@ -584,6 +597,8 @@ REFUSED = {
     "block":   "R R R R R . . R",
     # the union of the `state` and the `latent` rows
     "state_latent": "R R R R R R R R",
+    # the `state` row and nothing new
+    "state_space": "R R R R R . . .",
 }
 
 

@@ -770,6 +770,124 @@ def test_kda_step_kernel_is_named_at_kimi_linears_state_shape(one_chip):
     assert not copies, copies
 
 
+def test_ssm_step_kernel_is_named_at_jamba2s_state_shape_inside_a_scanned_run(one_chip):
+    """256 slots x [16 state lanes, 5,120 channels] float32, 26 state-space
+    layers in one pool (AI21-Jamba2-3B), the layer's place in the pool the
+    counter of a scan over a run of layers: ONE Mosaic call named
+    `%ssm_step` inside the loop, the layer index and the live slots' work
+    list its scalar-prefetch operands, the whole pool its operand and
+    (aliased, through the scan's carry) its result, no copy of its 2.2 GB
+    beside the call."""
+    from areal_tpu.ops.ssm_step import live_slots, ssm_step
+
+    n, R, N, Di = 26, 256, 16, 5120
+    f32 = jnp.float32
+
+    def run(S, dt, u, B, C, A, D, active):
+        live = live_slots(active, R)
+
+        def layer(S, i):
+            with jax.named_scope("layer"):
+                y, S = ssm_step(S, dt, u, B, C, A, D, i, active, impl="pallas", live=live,
+                                interpret=False)
+            return S, y
+
+        return jax.lax.scan(layer, S, 7 + jnp.arange(13, dtype=jnp.int32))
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((n, 1 + R, N, Di), f32), ((R, Di), f32), ((R, Di), f32), ((R, N), f32), ((R, N), f32),
+        ((N, Di), f32), ((Di,), f32), ((R,), jnp.bool_))]
+    hlo = jax.jit(run, donate_argnums=0).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    kernels = _mosaic_kernels(hlo)
+    assert len(kernels) == 1 and kernels[0].startswith("ssm_step") and hlo.count(" while(") == 1
+    assert "%ssm_step" in hlo and "%gdn_step" not in hlo and "%kda_step" not in hlo
+    operands = calls[0].split("operand_layout_constraints={")[1].split("}}")[0]
+    assert operands.split(", ")[:2] == ["s32[2]{0}", f"s32[{R}]{{0}}"], operands
+    state = f"f32[{n},{1 + R},{N},{Di}]"
+    assert state in calls[0]
+    copies = [ln for ln in hlo.splitlines() if " copy(" in ln and state in ln]
+    assert not copies, copies
+
+
+def test_ssm_scan_kernel_is_named_at_jamba2s_widths_alone_and_under_vmap(one_chip):
+    """A prefill's selective scan at AI21-Jamba2-3B's widths, 1,024 tokens of
+    5,120 channels x 16 state lanes, one sequence and a wave of eight
+    (`jax.vmap`): ONE Mosaic call named `%ssm_scan`, no loop around it."""
+    from areal_tpu.ops.ssm_scan import ssm_scan
+
+    T, N, Di = 1024, 16, 5120
+    f32 = jnp.float32
+    for wave in (0, 8):
+        def scan(u, dt, B, C, A):
+            one = lambda u, dt, B, C: ssm_scan(  # noqa: E731
+                u, dt, B, C, A, scan=None, impl="pallas", interpret=False)
+            return jax.vmap(one)(u, dt, B, C) if wave else one(u, dt, B, C)
+
+        lead = (wave,) if wave else ()
+        hlo = _compile(scan, one_chip, (lead + (T, Di), f32), (lead + (T, Di), f32),
+                       (lead + (T, N), f32), (lead + (T, N), f32), ((N, Di), f32))
+        kernels = _mosaic_kernels(hlo)
+        assert len(kernels) == 1 and kernels[0].startswith("ssm_scan"), kernels
+        assert " while(" not in hlo
+
+
+def test_jamba2s_decode_step_scans_its_runs_with_the_state_in_place(one_chip, monkeypatch):
+    """The whole decode step at AI21-Jamba2-3B's published configuration, 256
+    slots: three scanned runs (7, 13 and 6 state-space layers), each ONE
+    `%ssm_step` call inside its loop, the two attention layers' `%paged_attention`
+    in line (20 query heads against one kv head of 128), no copy of the state
+    pool or of the paged pools anywhere, and no `rope` scope in the program."""
+    import json as _json
+    import os
+
+    from areal_tpu.models.qwen2 import decode_step_paged, param_shapes
+    from areal_tpu.ops import paged_attention as pa
+    from areal_tpu.ops import ssm_step as ss
+
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    monkeypatch.setattr(pa, "_default_interpret", lambda: False)
+    monkeypatch.setattr(ss, "_default_interpret", lambda: False)
+    with open(os.path.join(REPO, "benchmark/configs/ai21-jamba2-3b.json")) as f:
+        cfg = ModelConfig.from_hf_config(_json.load(f), dtype="bfloat16", param_dtype="bfloat16")
+    R, nb, bsz = 256, 24, 128
+    bf, f32 = jnp.bfloat16, jnp.float32
+    arg = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(lambda s: arg(s, bf), param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    assert sorted(k for k in params if k.startswith(("run_", "layers_"))) == [
+        "layers_21", "layers_7", "run_0_7", "run_22_28", "run_8_21"]
+    pool = arg((2, R * nb + 1, bsz, 128), bf)
+    state = {"S": arg((26, 1 + R, 16, 5120), f32), "conv": arg((26, 1 + R, 3, 5120), bf)}
+
+    def step(params, kp, vp, bt, tokens, positions, active):
+        return decode_step_paged(params, tokens, positions, kp, vp, bt, cfg, active=active,
+                                 attn_impl="pallas", moe_load=True)
+
+    lowered = jax.jit(step, donate_argnums=(1, 2)).trace(
+        params, {"full": pool, "state": state}, {"full": pool}, arg((R, nb), jnp.int32),
+        arg((R,), jnp.int32), arg((R,), jnp.int32), arg((R,), jnp.bool_),
+    ).lower(lowering_platforms=("tpu",))
+    assert "rope" not in lowered.as_text(debug_info=True).replace(
+        "test_jamba2s_decode_step", "")
+    hlo = lowered.compile().as_text()
+    kernels = sorted(k.split(".")[0] for k in _mosaic_kernels(hlo))
+    assert kernels == ["paged_attention"] * 2 + ["ssm_step"] * 3, kernels
+    assert hlo.count(" while(") == 3
+    for big in ("f32[26,257,16,5120]", f"bf16[2,{R * nb + 1},128,128]"):
+        copies = [ln for ln in hlo.splitlines() if " copy(" in ln and big in ln]
+        assert not copies, copies
+    # the convolution's rows (3 a slot: XLA keeps them in a layout of its own
+    # inside the loops, slots on the sublanes) change layout at the program's
+    # entry and exit alone, never inside a run's loop
+    entry = hlo[hlo.index("\nENTRY "):]
+    inside = [ln for ln in hlo[:hlo.index("\nENTRY ")].splitlines()
+              if " copy(" in ln and "bf16[26,257,3,5120]" in ln]
+    assert not inside and entry.count("bf16[26,257,3,5120]") >= 2, inside
+
+
 def test_latent_kernel_is_named_at_kimi_linears_widths(one_chip):
     """32 heads against the same 640-lane row at 128 slots, two latent layers
     in the pool (Kimi-Linear: 60 FLOP a byte where DeepSeek-V2's 128 heads

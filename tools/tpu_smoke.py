@@ -483,6 +483,36 @@ def gdn_step_case(n=6, layer=4, R=64, Hv=32, dk=128, dv=128, dead_every=7, lanes
     return err < 1e-4 and kept and bool(jnp.all(jnp.isfinite(o))), f"maxerr={err:.2e} kept={kept}"
 
 
+def ssm_step_case(n=26, layer=19, R=256, N=16, Di=5120, dead_every=7):
+    """The Mamba-1 decode step (`ops/ssm_step.py`) at AI21-Jamba2-3B's state
+    shape and the cell's 256 slots: 26 state-space layers' float32 states in
+    one pool, the layer's place traced (as inside a scanned run), the kernel
+    over the live slots' work list against the op's `jax.numpy` arithmetic;
+    some slots not active, whose rows, the other layers' and the null row
+    must come back to the bit and whose outputs read 0."""
+    from areal_tpu.ops.ssm_step import ssm_step
+
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    S = jax.random.normal(ks[0], (n, 1 + R, N, Di), jnp.float32).at[:, 0].set(0)
+    dt = jax.random.uniform(ks[1], (R, Di), jnp.float32, 1e-3, 0.1)
+    u = jax.random.normal(ks[2], (R, Di))
+    B, C = jax.random.normal(ks[3], (R, N)), jax.random.normal(ks[4], (R, N))
+    A = -jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32)[:, None], (N, Di))
+    D = jax.random.normal(ks[5], (Di,))
+    active = jnp.arange(R) % dead_every != 3
+    li = jnp.int32(layer)
+    y, S1 = jax.jit(lambda S, li: ssm_step(S, dt, u, B, C, A, D, li, active, impl="pallas",
+                                           interpret=False))(S, li)
+    y_ref, S_ref = jax.jit(lambda S, li: ssm_step(S, dt, u, B, C, A, D, li, active,
+                                                  impl="xla"))(S, li)
+    err = max(_maxerr(S1[layer], S_ref[layer]), _maxerr(y, y_ref))
+    kept = (bool(jnp.array_equal(S1[:, 0], S[:, 0]))
+            and bool(jnp.array_equal(jnp.delete(S1, layer, axis=0), jnp.delete(S, layer, axis=0)))
+            and bool(jnp.array_equal(S1[layer, 1:][~active], S[layer, 1:][~active]))
+            and bool(jnp.all(y[~active] == 0)))
+    return err < 1e-4 and kept and bool(jnp.all(jnp.isfinite(y))), f"maxerr={err:.2e} kept={kept}"
+
+
 def moe_case(T, layers=1, li=0, H=2048, M=1024, E=64, K=8, dead_every=5, published=None):
     """The exact MoE mixture (`models/qwen2.py:moe_mlp`: pairs sorted by
     expert, XLA's grouped matmul for `jax.lax.ragged_dot`) at OLMoE's
@@ -723,6 +753,12 @@ def cases():
     out.append(("kda_step 6 layers x 128 slots x 32 heads of 128x128 float32, decay a key lane",
                 "default for models with Kimi Delta Attention layers",
                 lambda: gdn_step_case(R=128, lanes=True)))
+    # AI21-Jamba2-3B: the state-space layers' update over the live slots' work
+    # list, and its two attention layers' head shape (20 query heads, ONE kv head)
+    out.append(("ssm_step 26 layers x 256 slots x [16, 5120] float32, in place, layer traced",
+                "default for models with state-space layers", ssm_step_case))
+    out.append(("paged_attention W=1 bf16 20/1/128", "default for jamba",
+                lambda: paged_case(1, False, 20, 1, 128)))
     out.append(("paged_attention W=1 bf16 16/2/256", "default for qwen3_next",
                 lambda: paged_case(1, False, 16, 2, 256)))
     out.append(("paged_attention live range, 30% W=1 bf16 16/2/256 nb=10",

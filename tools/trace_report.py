@@ -18,7 +18,12 @@ an `.xplane.pb`, or the Chrome JSON that `AREAL_TPU_PERF_TRACE=1` leaves in
              Delta Attention layer as `layer/attn/` + `qkv`, `conv`,
              `kda_gate`, `kda_chunk_scan` or `conv_state`, `kda_step`, then
              `out_gate`, `out_proj`, beside the latent layers' `q_proj`,
-             `kv_latent`, `absorb_q`, `latent_attention`, `absorb_out`)
+             `kv_latent`, `absorb_q`, `latent_attention`, `absorb_out`; a
+             state-space layer as `layer/attn/` + `in_proj`, `conv`,
+             `ssm_params`, `ssm_scan` in a prefill or `conv_state`,
+             `ssm_params`, `ssm_step` in a decode step, then `out_gate`,
+             `out_proj`: the `while/body` of a scanned run of layers is
+             folded away like a chunk's)
   spans      the program's `areal/` spans (and the benchmark's `bench/`): count,
              total and self time (total minus the spans nested in it)
   idle gaps  every gap of chip 0 over `--gap-ms`, summed by the innermost
