@@ -69,6 +69,7 @@ import asyncio
 import dataclasses
 import os
 import queue
+import statistics
 import threading
 import time
 import traceback
@@ -141,6 +142,10 @@ _GUARDED_BY = {
     "JaxDecodeEngine._slot_epoch": "_sched_lock",
     "JaxDecodeEngine._admission_seq": "_sched_lock",
     "JaxDecodeEngine._inflight": "_sched_lock",
+    # a held dispatch's readings: written where a chunk is dispatched or
+    # read back (the scheduler's pass, a pause's drain on its caller's thread)
+    "JaxDecodeEngine._chunk_dev_s": "_sched_lock",
+    "JaxDecodeEngine._dispatch_host_s": "_sched_lock",
     "JaxDecodeEngine._overflow": "_sched_lock",
     "JaxDecodeEngine._parked": "_sched_lock",
     "JaxDecodeEngine._parked_tokens": "_sched_lock",
@@ -413,7 +418,32 @@ class _Slot:
 DIFFUSION_STRATEGIES = ("low_confidence_static", "low_confidence_dynamic")
 
 SCHED_STATES = ("admit", "prefill", "dispatch", "consume", "wait_device",
-                "paused", "idle", "other")
+                "hold", "paused", "idle", "other")
+
+# A held dispatch (`_scheduler_loop`): the next chunk goes out when the device
+# is about to need it. The estimate of a chunk's device time is the smallest
+# of the last `_HOLD_READINGS` of its program (a reading runs from the chunk's
+# start to its end, so it holds the prefills and forks enqueued ahead of it:
+# the smallest holds the fewest, and the fewest live slots); the lead before
+# the estimated end is the host's own dispatch time, the median of its last
+# few, and a margin of `_HOLD_MARGIN` of the estimate. While it waits the
+# thread looks every `_HOLD_POLL_S` whether the chunk in flight has ended
+# already (an estimate that overshot costs the device that much and the
+# dispatch, not the overshoot); an arrival wakes it at once.
+_HOLD_READINGS = 8
+_HOLD_MARGIN = 0.05
+_HOLD_POLL_S = 0.005
+
+
+@dataclass
+class _PrefillBudget:
+    """What is left of `max_prefill_tokens` before the next dispatched chunk:
+    one budget a chunk, however many calls of `_admit` precede it (a held
+    dispatch admits on every arrival). The first prefill on a budget always
+    goes through, whatever its size."""
+
+    tokens: int
+    spent: bool = False  # some prompt was prefilled on it
 
 
 @dataclass
@@ -461,6 +491,28 @@ class _Inflight:
     # these tokens belong to, and they complete it, where any other occupant
     # that left its slot (retired, preempted, aborted) has them discarded
     handed: set = field(default_factory=set)
+    # the compiled program that ran it, the object `_get_chunk_fn` and its
+    # siblings cache (one a sampler variant, `nb` bucket, verify width): its
+    # device times are kept under it (`_chunk_dev_s`)
+    program: Any = None
+    # when it started on the device, where the host knows: its dispatch, if the
+    # device had nothing before it then, else the end of the chunk before it,
+    # if the host saw that (`_apply_chunk`)
+    t_start: float | None = None
+    # when it ended, where the host saw it happen: it was waiting for it
+    # (`_consume_chunk`) or looking (`_wait_held`); none where the host came
+    # to read it back and found it ended some time ago
+    t_ended: float | None = None
+
+
+@dataclass
+class _Hold:
+    """A dispatch held back until the device is about to need it: what one
+    hold carries from pass to pass of the scheduler."""
+
+    rec: _Inflight  # the newest chunk in flight when the hold began
+    deadline: float  # when the next chunk goes out, on `_clock`
+    budget: _PrefillBudget  # of the chunk held: its admissions draw on it
 
 
 class JaxDecodeEngine(InferenceEngine):
@@ -482,6 +534,12 @@ class JaxDecodeEngine(InferenceEngine):
         self._request_q: queue.Queue = queue.Queue()
         self._shutdown = threading.Event()
         self._gen_paused = threading.Event()
+        # set by whatever a held dispatch must notice at once: a request
+        # queued, a pause asked for, shutdown (`_wait_held` clears it)
+        self._wake = threading.Event()
+        # the host clock of the dispatch / ready stamps and of a hold's
+        # deadline (a test drives it by hand)
+        self._clock: Callable[[], float] = time.monotonic
         # Serialises scheduler work (admit + chunk) against pause/abort.
         # pause_generation sets the flag then acquires this lock once: any
         # in-flight chunk has finished, and the flag is re-checked under the
@@ -677,6 +735,19 @@ class JaxDecodeEngine(InferenceEngine):
         self._runahead_discarded = 0  # run-ahead tokens dropped at reconcile
         # admissions into a slot whose request was still waiting for its last chunk
         self._n_handed_over = 0
+        # -- held dispatches (`_hold_dispatch`) ---------------------------
+        # device seconds of the last chunks of each program (`_Inflight.program`),
+        # start to end where the host saw both (`_apply_chunk`)
+        self._chunk_dev_s: dict[Any, deque] = {}
+        # wall seconds of the last calls of `_dispatch_chunk`
+        self._dispatch_host_s: deque = deque(maxlen=8)
+        self._n_admissions = 0  # requests given a slot, by any path
+        self._n_chunks_held = 0  # dispatches that waited for their deadline
+        self._n_held_admissions = 0  # requests admitted while one waited
+        # held, and the chunk before had ended already: the estimate overshot;
+        # the pass's admission ran past the deadline
+        self._n_chunks_late = 0
+        self._n_chunks_late_in_admit = 0
         # MoE models: token-expert pairs the decode chunks computed (live
         # slots, all layers and token steps), and those of the busiest
         # expert of each layer and step: their ratio x E is max-over-mean load
@@ -932,6 +1003,8 @@ class JaxDecodeEngine(InferenceEngine):
         self._slot_keys = np.zeros((R, 2), dtype=np.uint32)
         self._slot_epoch = np.zeros(R, dtype=np.int64)
         self._inflight = deque()
+        self._chunk_dev_s = {}
+        self._dispatch_host_s = deque(maxlen=8)
         self._ctl_cache = None
         self._ctl_dirty = True
         self._dev_active = None
@@ -1026,6 +1099,7 @@ class JaxDecodeEngine(InferenceEngine):
 
     def destroy(self):
         self._shutdown.set()
+        self._wake.set()
         from areal_tpu.utils import hbm
 
         hbm.declare_resident(self, 0)
@@ -3010,19 +3084,24 @@ class JaxDecodeEngine(InferenceEngine):
         except queue.Empty:
             return None
 
-    def _admit(self) -> bool:
+    def _fresh_budget(self) -> _PrefillBudget:
+        return _PrefillBudget(max(int(self.config.max_prefill_tokens), _PREFILL_BUCKET))
+
+    def _admit(self, budget: _PrefillBudget | None = None) -> bool:
         """Admit queued requests into free slots, prefilling their prompts.
 
-        Prefill work per scheduler pass is capped at
+        Prefill work per DISPATCHED CHUNK is capped at
         `config.max_prefill_tokens` (the chunked-prefill budget policy of
         SGLang-grade continuous batching): a burst of long-prompt
         admissions must not stall running slots for more than one budget's
         worth of prefill before the next decode chunk runs. Requests over
         budget stay queued, order preserved, and admit on later passes.
+        `budget` is what earlier calls before the same chunk left (a held
+        dispatch admits on every arrival); none: a fresh one.
         """
         admitted = False
-        prefill_budget = max(int(self.config.max_prefill_tokens), _PREFILL_BUCKET)
-        did_prefill = False
+        if budget is None:
+            budget = self._fresh_budget()
         # Wave batching: full prefills collected during the loop and
         # dispatched together afterwards (vmapped when >=2 share a
         # bucket); same-wave duplicate prompts fork the wave's primary
@@ -3160,11 +3239,11 @@ class JaxDecodeEngine(InferenceEngine):
                         self._overflow.insert(0, item)
                         break
             if (
-                did_prefill
+                budget.spent
                 and donor is None
                 and not is_wave_dup  # duplicates are memcpy forks: free
                 and not host_hit  # a promotion is an upload, not prefill
-                and needs_prefill_bucket > prefill_budget
+                and needs_prefill_bucket > budget.tokens
             ):
                 # budget exhausted for this pass; run the decode chunk first
                 self._overflow.insert(0, item)
@@ -3294,8 +3373,8 @@ class JaxDecodeEngine(InferenceEngine):
                     self._register_prefix(slot_idx, list(prompt[:C]))
             elif resumed is None and C > 0 and partial is not None:
                 donor_slot, plen, sb = partial
-                prefill_budget -= sb
-                did_prefill = True
+                budget.tokens -= sb
+                budget.spent = True
                 self._n_suffix_prefills += 1
                 if partial_fabric:
                     # device dedup rung attribution: blocks another local
@@ -3353,8 +3432,8 @@ class JaxDecodeEngine(InferenceEngine):
                         break
                 nb_w = -(-bucket // self._alloc.block_size)
                 if item.image_data:
-                    prefill_budget -= bucket
-                    did_prefill = True
+                    budget.tokens -= bucket
+                    budget.spent = True
                     self._n_prefills += 1
                     ids = np.zeros(bucket, dtype=np.int32)
                     ids[:pre] = prompt[:C]
@@ -3389,8 +3468,8 @@ class JaxDecodeEngine(InferenceEngine):
                     )
                     self._n_prefix_forks += 1
                 else:
-                    prefill_budget -= bucket
-                    did_prefill = True
+                    budget.tokens -= bucket
+                    budget.spent = True
                     self._n_prefills += 1
                     ids = np.zeros(bucket, dtype=np.int32)
                     ids[:pre] = prompt[:C]
@@ -3437,6 +3516,7 @@ class JaxDecodeEngine(InferenceEngine):
                 self._slot_keys[slot_idx] = seq.generate_state(2, np.uint32)
                 item.base_key = np.array(self._slot_keys[slot_idx])
             self._mark_slot_dirty(slot_idx)
+            self._n_admissions += 1
             admitted = True
         self._flush_wave(wave_pending, wave_forks)
         # Prefill-only requests (disaggregated prefill role) retire NOW —
@@ -3715,12 +3795,192 @@ class JaxDecodeEngine(InferenceEngine):
         finally:
             self._sched_clock.switch(around)
 
+    def _chunk_ready(self, rec: "_Inflight") -> bool:
+        """Whether the chunk has ended on the device (not to be known: yes)."""
+        ready = getattr(rec.toks, "is_ready", None)
+        return True if ready is None else bool(ready())
+
+    def _chunk_estimate(self, rec: "_Inflight") -> float | None:
+        """Device seconds the chunk's program takes: the smallest of its last
+        readings (`_apply_chunk`: each holds whatever else the device ran
+        between two chunks, so the smallest errs early, which costs nothing);
+        none while the program has no reading."""
+        seen = self._chunk_dev_s.get(rec.program)
+        return min(seen) if seen else None
+
+    def _dispatch_deadline(self) -> float | None:
+        """When the next chunk must go out for the device to have it queued
+        before the chunks in flight end: the instant the oldest of them
+        started, their programs' device times, less the lead (the host's own
+        dispatch time and a margin). Where the host does not know the start
+        (the chunk before ended some time between this one's dispatch and the
+        host's coming to read it) the dispatch is taken: too early costs
+        nothing. None where a program has no reading."""
+        oldest = self._inflight[0]
+        start = oldest.t_dispatch if oldest.t_start is None else oldest.t_start
+        total = 0.0
+        for rec in self._inflight:
+            est = self._chunk_estimate(rec)
+            if est is None:
+                return None
+            total += est
+        # (the median: a dispatch that found the device's launch queue full
+        # waited for the device, which says nothing of the next one)
+        host = statistics.median(self._dispatch_host_s) if self._dispatch_host_s else 0.0
+        return start + (1.0 - _HOLD_MARGIN) * total - host
+
+    def _hold_dispatch(self, hold: "_Hold | None",
+                       budget: _PrefillBudget) -> "_Hold | None":
+        """Whether the next chunk's dispatch waits (the hold, begun here or
+        carried on) or goes out now (None). Under `_sched_lock`, after the
+        pass's admission.
+
+        A chunk dispatched the moment the one before is read back is queued a
+        whole chunk before the device can start it, and a request that arrives
+        a millisecond later (a closed loop's successor to what that read-back
+        completed) waits for the chunk after. So the dispatch is held until
+        the device is about to need it (`_dispatch_deadline`), arrivals
+        admitted meanwhile (their prefills queue behind the chunk in flight,
+        as they would have behind the next), but only where that can gain:
+        a chunk is in flight (never at run-ahead 0), admission left nothing
+        behind (what it did waits for a slot or for the prefill budget: an
+        arrival would only queue behind it; a request queued SINCE the
+        admission is an arrival, and the wait returns for it at once), some
+        slot would run the next chunk empty (free, parked, or spent where a
+        spent slot can be handed over), some slot is live (else there is
+        nothing to dispatch), and the deadline is known and ahead. Run-ahead
+        stays what it is: the device has the next chunk queued before it ends
+        the current one."""
+        if not self._inflight or self._overflow:
+            return None
+        newest = self._inflight[-1]
+        if hold is not None and hold.rec is not newest:
+            return None  # drained by a pause that came and went
+        hand_over = self._slot_cache.projection_exact(self.config)
+        empty = live = False
+        for i, s in enumerate(self._slots):
+            if s is None:
+                empty = True
+            elif self._spent(i, s):
+                empty = empty or hand_over
+            else:
+                live = True
+        if not (empty and live):
+            return None
+        if hold is None:
+            deadline = self._dispatch_deadline()
+            if deadline is None:
+                return None
+            hold = _Hold(rec=newest, deadline=deadline, budget=budget)
+        if self._clock() >= hold.deadline or self._chunk_ready(newest):
+            return None
+        return hold
+
+    def _wait_held(self, hold: "_Hold") -> None:
+        """A held dispatch's wait, OUTSIDE `_sched_lock` (a pause, an abort, a
+        weight commit take it meanwhile): until a request is queued, a pause
+        or shutdown is asked for (each sets `_wake`), the deadline, or the
+        chunk in flight is found ended (the estimate overshot)."""
+        while True:
+            self._wake.clear()
+            if (self._shutdown.is_set() or self._gen_paused.is_set()
+                    or not self._request_q.empty()):
+                return
+            now = self._clock()
+            if now >= hold.deadline:
+                return
+            if self._chunk_ready(hold.rec):
+                hold.rec.t_ended = now  # (to the poll's grain)
+                return
+            self._wake.wait(min(hold.deadline - now, _HOLD_POLL_S))
+
+    def _pass_locked(self, runahead: int,
+                     hold: "_Hold | None") -> "tuple[bool, bool, _Hold | None]":
+        """One scheduler pass, under `_sched_lock`: admit, then dispatch the
+        next chunk and read the one before back, or hold the dispatch
+        (`_hold_dispatch`). `hold` is the hold the last pass ended in, if it
+        did. Returns (paused, worked, the hold this pass ends in)."""
+        if self._gen_paused.is_set():
+            # fence: never leave a chunk dispatched while a
+            # pause holder swaps weights/aborts under us
+            self._drain_inflight_locked()
+            return True, False, None
+        was, held = hold, hold is not None
+        # one budget a dispatched chunk: a held dispatch's passes share it
+        budget = was.budget if held else self._fresh_budget()
+        admitted = overran = False
+        if self._overflow or not self._request_q.empty():
+            # (with nothing queued _admit does nothing)
+            with perf_tracer.span("decode/admit") as admit_span, \
+                    self._sched_state("admit"):
+                before, n0 = self._n_handed_over, self._n_admissions
+                admitted = self._admit(budget)
+                # (known when the span ends: in the record,
+                # not in a device trace's annotation)
+                admit_span.ids["handed_over"] = self._n_handed_over - before
+                if held:
+                    self._n_held_admissions += self._n_admissions - n0
+                    # (an admission of some dozens of programs can wait inside
+                    # a prefill's or a fork's call for the chunk in flight to
+                    # end, held or not: the device takes only so many ahead)
+                    overran = self._clock() >= was.deadline
+        hold = self._hold_dispatch(was, budget)
+        if hold is not None:
+            return False, True, hold
+        active = self._active_mask()
+        dispatched = False
+        if active.any():
+            # the chunk before has ended (or there is none): this one starts
+            # when it is dispatched. Held and ended all the same: the device
+            # stands idle, because the estimate overshot (`_wait_held` saw it
+            # end) or because this pass's admission ran past the deadline
+            after_idle = not self._inflight or self._chunk_ready(self._inflight[-1])
+            late = held and bool(self._inflight) and after_idle
+            with perf_tracer.span(
+                "decode/dispatch_chunk",
+                chunk=self._chunks_dispatched + 1,
+                active=int(active.sum()),
+                version=self._version,
+            ), self._sched_state("dispatch"):
+                t0 = self._clock()
+                rec = self._dispatch_chunk(active)
+                self._dispatch_host_s.append(self._clock() - t0)
+            if rec is not None:
+                rec.t_start = rec.t_dispatch if after_idle else None
+                self._inflight.append(rec)
+                dispatched = True
+                if held:
+                    self._n_chunks_held += 1
+                if late and overran:
+                    self._n_chunks_late_in_admit += 1
+                elif late:
+                    self._n_chunks_late += 1
+        # Consume down to the run-ahead depth AFTER the new
+        # dispatch: the host work for chunk k (stop scan,
+        # retire, completions) runs while the device
+        # executes chunk k+1. Depth 0 degenerates to the
+        # legacy synchronous dispatch-then-consume.
+        while len(self._inflight) > runahead:
+            self._consume_chunk(self._inflight.popleft())
+        drained = False
+        if not dispatched:
+            # no new device work: drain stragglers so the
+            # last completions aren't held back a pass
+            drained = bool(self._inflight)
+            self._drain_inflight_locked()
+            if not self._active_mask().any():
+                # engine idle — gaps from here on are lack
+                # of traffic, not scheduler overhead
+                with self._metrics_lock:
+                    self._last_ready_t = None
+        return False, dispatched or admitted or drained, None
+
     def _scheduler_loop(self):
         debug = bool(os.environ.get("AREAL_DECODE_DEBUG"))
         last_dbg = time.monotonic()
-        R = self.config.max_running_requests
         runahead = max(int(self.config.decode_runahead_chunks), 0)
         self._sched_clock.switch("other")
+        hold = None
         try:
             while not self._shutdown.is_set():
                 if debug and time.monotonic() - last_dbg > 5.0:
@@ -3738,61 +3998,19 @@ class JaxDecodeEngine(InferenceEngine):
                 # Re-bound per pass because set_model can install a sharded
                 # mesh after the thread starts.
                 # (`decode/pass` is around the wait for the lock and the
-                # bookkeeping between the spans below: with `decode/paused`
-                # and `decode/idle` no instant of this thread is unmarked)
+                # bookkeeping between the spans below: with `decode/hold`,
+                # `decode/paused` and `decode/idle` no instant of this thread
+                # is unmarked)
                 with perf_tracer.span("decode/pass"), \
                         mesh_lib.mesh_scope(self.mesh), self._sched_lock:
-                    if self._gen_paused.is_set():
-                        # fence: never leave a chunk dispatched while a
-                        # pause holder swaps weights/aborts under us
-                        self._drain_inflight_locked()
-                        paused, worked = True, False
-                    else:
-                        paused = False
-                        admitted = False
-                        if self._overflow or not self._request_q.empty():
-                            # (with nothing queued _admit does nothing)
-                            with perf_tracer.span("decode/admit") as admit_span, \
-                                    self._sched_state("admit"):
-                                before = self._n_handed_over
-                                admitted = self._admit()
-                                # (known when the span ends: in the record,
-                                # not in a device trace's annotation)
-                                admit_span.ids["handed_over"] = (
-                                    self._n_handed_over - before)
-                        active = self._active_mask()
-                        dispatched = False
-                        if active.any():
-                            with perf_tracer.span(
-                                "decode/dispatch_chunk",
-                                chunk=self._chunks_dispatched + 1,
-                                active=int(active.sum()),
-                                version=self._version,
-                            ), self._sched_state("dispatch"):
-                                rec = self._dispatch_chunk(active)
-                            if rec is not None:
-                                self._inflight.append(rec)
-                                dispatched = True
-                        # Consume down to the run-ahead depth AFTER the new
-                        # dispatch: the host work for chunk k (stop scan,
-                        # retire, completions) runs while the device
-                        # executes chunk k+1. Depth 0 degenerates to the
-                        # legacy synchronous dispatch-then-consume.
-                        while len(self._inflight) > runahead:
-                            self._consume_chunk(self._inflight.popleft())
-                        drained = False
-                        if not dispatched:
-                            # no new device work: drain stragglers so the
-                            # last completions aren't held back a pass
-                            drained = bool(self._inflight)
-                            self._drain_inflight_locked()
-                            if not self._active_mask().any():
-                                # engine idle — gaps from here on are lack
-                                # of traffic, not scheduler overhead
-                                with self._metrics_lock:
-                                    self._last_ready_t = None
-                        worked = dispatched or admitted or drained
-                if paused:
+                    paused, worked, hold = self._pass_locked(runahead, hold)
+                if hold is not None:
+                    # one span a wait; an arrival ends it, and the pass that
+                    # admits it holds again until the same deadline
+                    with perf_tracer.span("decode/hold", chunk=hold.rec.chunk + 1), \
+                            self._sched_state("hold"):
+                        self._wait_held(hold)
+                elif paused:
                     # one span for the whole pause (a weight commit, an
                     # abort), not one per poll; nothing can be dispatched
                     # meanwhile, so there is nothing to drain again
@@ -4059,7 +4277,7 @@ class JaxDecodeEngine(InferenceEngine):
         accepted = None
         if spec_w:
             verify_fn = self._get_verify_fn(use_topp, nb, spec_w)
-            t_dispatch = time.monotonic()
+            t_dispatch = self._clock()
             with self._weight_lock:
                 kq, vq = self._kv_operands()
                 (
@@ -4111,6 +4329,7 @@ class JaxDecodeEngine(InferenceEngine):
                 t_dispatch=t_dispatch,
                 n_chunk=spec_w,
                 chunk=self._chunks_dispatched,
+                program=verify_fn,
                 spec_w=spec_w,
                 accepted=accepted,
                 draft_lens=dlens_np,
@@ -4118,7 +4337,7 @@ class JaxDecodeEngine(InferenceEngine):
         if self._diffusion:
             return self._dispatch_diffusion_chunk(active, use_topp, nb, ctl)
         chunk_fn = self._get_chunk_fn(use_topp, use_freq, nb)
-        t_dispatch = time.monotonic()
+        t_dispatch = self._clock()
         with self._weight_lock:
             kq, vq = self._kv_operands()
             args = [
@@ -4176,6 +4395,7 @@ class JaxDecodeEngine(InferenceEngine):
             t_dispatch=t_dispatch,
             n_chunk=n_chunk,
             chunk=self._chunks_dispatched,
+            program=chunk_fn,
             moe_load=moe_load,
         )
 
@@ -4210,7 +4430,7 @@ class JaxDecodeEngine(InferenceEngine):
         R = self.config.max_running_requests
         n_chunk = self.config.new_tokens_per_chunk
         version_at_chunk = self._version
-        t_dispatch = time.monotonic()
+        t_dispatch = self._clock()
         toks, logps, steps, blocks, load = self._call_diffusion_chunk(
             use_topp, nb, self._dev_active, ctl
         )
@@ -4238,6 +4458,7 @@ class JaxDecodeEngine(InferenceEngine):
             t_dispatch=t_dispatch,
             n_chunk=n_chunk,
             chunk=self._chunks_dispatched,
+            program=self._get_diffusion_chunk_fn(use_topp, nb),
             moe_load=load,
             steps=steps,
             blocks=blocks,
@@ -4248,6 +4469,7 @@ class JaxDecodeEngine(InferenceEngine):
                               version=rec.version), self._sched_state("consume"):
             with perf_tracer.span("decode/wait_device", chunk=rec.chunk), \
                     self._sched_state("wait_device"):
+                running = not self._chunk_ready(rec)
                 toks = np.asarray(rec.toks)  # [n_chunk, R]
                 logps = np.asarray(rec.logps)
                 acc = np.asarray(rec.accepted) if rec.spec_w > 0 else None
@@ -4282,6 +4504,8 @@ class JaxDecodeEngine(InferenceEngine):
                             self._gdn_state_updates += read["state"]
             with self._metrics_lock:
                 self._consumed_steps += int(rec.n_chunk)
+            if running:
+                rec.t_ended = self._clock()  # (the host was waiting for it: it saw it end)
             self._apply_chunk(rec, toks, logps, acc)
 
     def _apply_chunk(self, rec: "_Inflight", toks: np.ndarray,
@@ -4295,11 +4519,13 @@ class JaxDecodeEngine(InferenceEngine):
             B = self.model_config.block_length_
             with self._metrics_lock:
                 self._dfn_blocks += int(blocks[rec.active].sum())
-        t_ready = time.monotonic()
+        t_ready = self._clock()
         n_chunk = rec.n_chunk
         # dispatch→ready is the device window; anything between the
         # previous chunk's ready and this dispatch is device idle (the
         # host gap the run-ahead path exists to hide)
+        # (a held dispatch may have seen the chunk end before this read-back)
+        dev_ready = t_ready if rec.t_ended is None else rec.t_ended
         with self._metrics_lock:
             prev_ready = self._last_ready_t
             if (
@@ -4312,9 +4538,19 @@ class JaxDecodeEngine(InferenceEngine):
                 busy_start = self._last_ready_t
             else:
                 busy_start = rec.t_dispatch
-            dev_s = max(t_ready - busy_start, 0.0)
+            dev_s = max(dev_ready - busy_start, 0.0)
             self._dev_busy_s += dev_s
-            self._last_ready_t = t_ready
+            self._last_ready_t = dev_ready
+        if rec.t_ended is not None:
+            if rec.t_start is not None:
+                # start to end, both seen as they happened: its program's
+                # device time, and that of whatever else was enqueued ahead of
+                # it since (prefills, forks): `_chunk_estimate`
+                self._chunk_dev_s.setdefault(rec.program, deque(maxlen=_HOLD_READINGS)).append(
+                    max(rec.t_ended - rec.t_start, 0.0))
+            if self._inflight and self._inflight[0].t_start is None:
+                # the next chunk, dispatched while this one ran, started then
+                self._inflight[0].t_start = rec.t_ended
         emitted_counts: list[int] = []
         for i, s in enumerate(rec.items):
             if s is None or not rec.active[i]:
@@ -4465,6 +4701,7 @@ class JaxDecodeEngine(InferenceEngine):
         if os.environ.get("AREAL_DECODE_DEBUG"):
             logger.info(f"[agen {id(self):#x}] enqueue rid={item.rid}")
         self._request_q.put(item)
+        self._wake.set()  # (a held dispatch admits it at once)
         # The death handler sets _thread_exc BEFORE draining the queue once,
         # so a put that races past the drain is always caught here — without
         # this, such a request would wait forever on a future nobody
@@ -4506,6 +4743,7 @@ class JaxDecodeEngine(InferenceEngine):
             prefill_only=True,
         )
         self._request_q.put(item)
+        self._wake.set()  # (a held dispatch admits it at once)
         if self._thread_exc is not None:
             raise self._dead_error()
         return await future
@@ -4580,6 +4818,7 @@ class JaxDecodeEngine(InferenceEngine):
         this returns no dispatched computation references the current
         weights or KV, so weight swaps / abort_all are fenced."""
         self._gen_paused.set()
+        self._wake.set()  # (a held dispatch ends here)
         with self._sched_lock:
             # the scheduler thread drains on the pause flag too, but it may
             # already be parked between passes — drain here so the fence
@@ -6011,6 +6250,16 @@ class JaxDecodeEngine(InferenceEngine):
             # admissions that took the slot of a request whose last chunk was
             # dispatched and not yet read back (`_hand_over_spent_slot`)
             "slots_handed_over_total": self._n_handed_over,
+            # dispatches held until the device was about to need them
+            # (`_hold_dispatch`), the requests admitted while one waited (each
+            # a chunk sooner than it would have been), and the held dispatches
+            # that found the chunk before ended already: the estimate overshot
+            # (`_wait_held` saw it end: the seconds are in `device_idle_s`),
+            # or the hold's last admission was still running at the deadline
+            "chunks_held_total": self._n_chunks_held,
+            "held_admissions_total": self._n_held_admissions,
+            "chunks_dispatched_late_total": self._n_chunks_late,
+            "chunks_late_in_admit_total": self._n_chunks_late_in_admit,
             # MoE decode: pairs computed for live slots, and the busiest
             # expert's share of them per layer and token step (0 for dense)
             "moe_pairs_total": moe_pairs,

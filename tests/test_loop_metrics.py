@@ -231,7 +231,7 @@ def test_every_metric_is_a_number():
 # -- the decode engine ---------------------------------------------------
 
 SCHED_STATES = ("admit", "prefill", "dispatch", "consume", "wait_device",
-                "paused", "idle", "other")
+                "hold", "paused", "idle", "other")
 
 
 @pytest.fixture(scope="module")
@@ -266,7 +266,7 @@ def _sched(m):
 
 
 def test_scheduler_states_sum_to_the_threads_life(engine):
-    """Work, then a wait for traffic, then a pause: the eight exclusive
+    """Work, then a wait for traffic, then a pause: the nine exclusive
     states account for the whole stretch within 1%, and each of the three
     phases shows where it should."""
     eng = engine[0]

@@ -372,6 +372,80 @@ def test_a_handed_over_slot_is_counted_and_named_on_its_admit_span(cpu_devices, 
     assert trace_report.report(path)["admissions"] == {"requests": 3, "handed_over": 2}
 
 
+def test_a_held_dispatch_has_its_span_its_state_and_its_counters(cpu_devices):
+    """ISSUE 51: the wait of a dispatch held until the device is about to need
+    it is the span `decode/hold` and the scheduler state `hold`
+    (`sched_hold_secs_total`): while it lasts it is the one span the thread has
+    open, so no instant of the thread is unmarked; and the counters
+    `chunks_held_total`, `held_admissions_total`,
+    `chunks_dispatched_late_total` (the estimate overshot) and
+    `chunks_late_in_admit_total` (the hold's admission outlasted the deadline)
+    beside `chunks_dispatched_total`. The clock
+    stands still and every chunk is said to be running, so the thread, once
+    in a hold, stays in it until the arrival."""
+    import concurrent.futures
+    import time
+
+    from areal_tpu.engine import jax_decode
+    from areal_tpu.utils import perf_tracer
+
+    assert "hold" in jax_decode.SCHED_STATES and jax_decode.SCHED_STATES[-1] == "other"
+
+    class Now:
+        @staticmethod
+        def call_soon_threadsafe(fn, *args):
+            fn(*args)
+
+    def queue(eng, rid, first, n):
+        item = jax_decode._Slot(
+            rid=rid, prompt=[first, 5, 9, 13, 2],
+            gconfig=GenerationHyperparameters(greedy=True, max_new_tokens=n),
+            future=concurrent.futures.Future(), loop=Now())
+        eng._request_q.put(item)
+        eng._wake.set()
+        return item
+
+    def until(what, said):
+        deadline = time.monotonic() + 120.0
+        while not what():
+            assert time.monotonic() < deadline, said
+            time.sleep(0.002)
+
+    with perf_tracer.recording() as rec:
+        eng = jax_decode.JaxDecodeEngine(
+            JaxDecodeConfig(context_length=64, max_running_requests=2, new_tokens_per_chunk=4,
+                            dtype="float32", kv_cache_dtype="float32"),
+            InferenceEngineConfig())
+        eng.set_model(init_params(TINY, jax.random.PRNGKey(0)), TINY)
+        eng.initialize()
+        eng._clock = lambda: 100.0
+        eng._chunk_ready = lambda rec: False
+        eng._chunk_estimate = lambda rec: 1.0
+        try:
+            queue(eng, "a", 1, 40)
+            until(lambda: eng.get_metrics()["sched_hold_secs_total"] > 0.0, "never held")
+            mine = [s for s in rec.snapshot() if s["name"].startswith("decode/")]
+            thread = {s["thread"] for s in mine}
+            assert len(thread) == 1
+            assert [s["name"] for s in mine if s["open"]] == ["decode/hold"]
+            assert eng.get_metrics()["chunks_held_total"] == 0
+            b = queue(eng, "b", 2, 4)
+            until(lambda: eng.get_metrics()["chunks_held_total"] == 1, "the arrival ended no hold")
+            eng._chunk_ready = lambda rec: True  # (no hold from here on: what is left runs out)
+            eng._wake.set()
+            b.future.result(timeout=120.0)
+            m = eng.get_metrics()
+        finally:
+            eng.destroy()
+    assert m["chunks_held_total"] >= 1 and m["held_admissions_total"] == 1
+    assert m["chunks_dispatched_late_total"] <= 1  # (the hold the switch above ended)
+    assert m["chunks_late_in_admit_total"] == 0  # (the clock stands still: no deadline passes)
+    assert m["chunks_dispatched_total"] > m["chunks_held_total"]
+    assert set(f"sched_{k}_secs_total" for k in jax_decode.SCHED_STATES) <= set(m)
+    holds = [s for s in rec.snapshot() if s["name"] == "decode/hold"]
+    assert holds and all(s["parent"] is None and "chunk" in s["ids"] for s in holds)
+
+
 def test_engine_counts_live_block_columns(cpu_devices):
     """`paged_block_columns_live_total` / `_visited_total` (what the paged
     kernel walks: its live columns, and a step for each slot with none)
